@@ -22,6 +22,7 @@ import sys
 import tempfile
 import time
 
+import jax
 import numpy as np
 
 from mxnet_tpu import metering, telemetry
@@ -36,7 +37,10 @@ def main():
     def replica(i):
         srv = DecodeServer(model, params, seq_ladder=[32, 64],
                            max_new_tokens=12, window=8, page_size=16,
-                           pool_pages=256, name="rep-%d" % i)
+                           pool_pages=256, name="rep-%d" % i,
+                           # one replica per device, round robin
+                           device=jax.local_devices()[
+                               i % jax.local_device_count()])
         srv.warmup()
         return srv
 

@@ -247,16 +247,13 @@ def _get_backward_fn(struct, instrs, head_refs):
             grads, = vjp_fn(tuple(cotangents))
             return outs, grads
         # ``struct`` (op names + attr keys + bindings) IS the program
-        # content this closure bakes in, so its digest makes the
-        # persistent compile cache safe across processes: two tapes
-        # with identical shapes but different ops cannot collide.
-        # storm=False — each distinct tape is a new program by design
-        # (specialization, not churn).
+        # content this closure bakes in; its digest is the watch's
+        # program identity. storm=False — each distinct tape is a new
+        # program by design (specialization, not churn).
         token = hashlib.sha256(
             repr((struct, head_refs)).encode()).hexdigest()
         fn = compile_watch.jit(fwd_bwd, "autograd:backward",
-                               statics=token[:16], storm=False,
-                               cache_token=token)
+                               statics=token[:16], storm=False)
         with _bwd_cache_lock:
             _bwd_cache[key] = fn
     return fn

@@ -143,17 +143,6 @@ class CachedOp:
             needs_rng=bool(n_rng),
             mutable_inputs=mutable,
             description="CachedOp(%s)" % sym.list_outputs())
-        # content fingerprint for the persistent compile cache: the
-        # display name's instance counter is process-local (a rebuilt
-        # block in the SAME process gets a new N, an identical block in
-        # the NEXT process gets the old one back) — the graph hash is
-        # what actually identifies the program on disk
-        from .compile_cache import graph_token
-        try:
-            self._op.cache_token = graph_token(sym.tojson())
-        except Exception:
-            self._op.cache_token = None   # unserializable graph:
-            # registry opts the op out of the disk cache
 
     def __call__(self, *inputs):
         from .ndarray.ndarray import invoke_nd

@@ -75,7 +75,7 @@ import time
 import warnings
 from collections import deque
 
-from . import compile_cache, envs
+from . import envs
 
 __all__ = ["enabled", "enable", "disable", "reset", "maybe_enable",
            "jit", "stats", "site_stats", "recent_mfu", "peak_table",
@@ -90,10 +90,12 @@ _watch = None          # the active _Watch; module-global None check
 # peak-performance tables
 # ---------------------------------------------------------------------------
 
-# Peak FLOP/s per chip (bf16 MXU peak for TPUs — public chip specs).
-# CPU has no meaningful single number; the placeholder below keeps the
-# MFU math defined and is expected to be overridden via
-# MXNET_DEVICE_PEAK_FLOPS for any real CPU measurement.
+# Peak FLOP/s per chip, keyed by ``jax.Device.device_kind`` (bf16 MXU
+# peak — Google Cloud TPU documentation, the per-generation "system
+# architecture" pages; "TPU v5 lite" is what a v5e chip reports). The
+# ONE peak table in the tree: bench.py reads it through peak_table().
+# The "cpu" row is a placeholder that keeps the MFU math defined on
+# the CPU test mesh; no CPU figure is ever reported as a device metric.
 PEAK_FLOPS = {
     "TPU v2": 45e12, "TPU v3": 123e12, "TPU v4": 275e12,
     "TPU v5 lite": 197e12, "TPU v5e": 197e12, "TPU v5p": 459e12,
@@ -159,26 +161,18 @@ def _key_compute_dtype(key):
     return "int8" if saw_int8 else None
 
 
-def _lookup_peak(table, kind, platform):
-    if kind in table:
+def _lookup_peak(table, kind):
+    """The table row for ``kind`` — a device that is not in the table
+    is an error, never a default: a utilization figure against a
+    guessed peak is worse than none."""
+    try:
         return table[kind]
-    for k, v in table.items():
-        if k != "cpu" and (kind.startswith(k) or k.startswith(kind)):
-            return v
-    if platform != "cpu" and kind not in _warned_kinds:
-        # unknown accelerator: there is no honest builtin — fall back
-        # to the placeholder row and tell the operator once to pin the
-        # real peak via the env overrides
-        _warned_kinds.add(kind)
-        warnings.warn(
-            "compile_watch: no builtin peak table entry for device "
-            "kind %r; using the placeholder row — set "
-            "MXNET_DEVICE_PEAK_FLOPS/MXNET_DEVICE_PEAK_BW for "
-            "meaningful MFU/BW figures" % kind)
-    return table["cpu"]
-
-
-_warned_kinds = set()
+    except KeyError:
+        raise KeyError(
+            "compile_watch: device kind %r is not in the peak table "
+            "(known: %s) — add its published peak with the source, or "
+            "set MXNET_DEVICE_PEAK_FLOPS / MXNET_DEVICE_PEAK_BW"
+            % (kind, ", ".join(sorted(table)))) from None
 
 
 def peak_table():
@@ -187,12 +181,11 @@ def peak_table():
     benchmarks so there is exactly one peak table in the tree."""
     import jax
     devices = jax.local_devices()
-    kind = devices[0].device_kind if devices else "cpu"
-    platform = devices[0].platform if devices else "cpu"
+    kind = devices[0].device_kind
     flops = envs.get_float("MXNET_DEVICE_PEAK_FLOPS") or \
-        _lookup_peak(PEAK_FLOPS, kind, platform)
+        _lookup_peak(PEAK_FLOPS, kind)
     bw = envs.get_float("MXNET_DEVICE_PEAK_BW") or \
-        _lookup_peak(PEAK_BW, kind, platform)
+        _lookup_peak(PEAK_BW, kind)
     return float(flops), float(bw), kind, max(1, len(devices))
 
 
@@ -210,8 +203,6 @@ class _Watch:
         self.t0 = time.time()
         self.compile_count = 0
         self.compile_total_s = 0.0
-        self.cache_hits = 0      # programs loaded from the disk cache
-        self.cache_hit_s = 0.0   # (deserialize time, not XLA compiles)
         self.programs = {}      # site -> per-program dict
         self.storms = []        # [{"program","arg","compiles","steps"}]
         self.degraded = 0       # staged calls that fell back to jit
@@ -269,7 +260,6 @@ def enable():
     from . import telemetry
     telemetry._util_probe = _step_probe
     telemetry._util_reset = step_reset
-    compile_cache.maybe_enable()   # MXNET_COMPILE_CACHE_DIR rides too
     return _watch
 
 
@@ -429,58 +419,17 @@ def _memory_of(compiled):
 # the watched jit wrapper
 # ---------------------------------------------------------------------------
 
-_donation_warned = False
-
-
-def _warn_donation_stripped(site):
-    """One-time, discoverable record of the compile-cache/donation
-    tradeoff: a job that OOMs after MXNET_COMPILE_CACHE_DIR was set
-    must be able to connect the dots from its own logs/telemetry, not
-    from a source comment."""
-    global _donation_warned
-    from . import telemetry
-    telemetry.note("compile_cache_donation_stripped")
-    if _donation_warned:
-        return
-    _donation_warned = True
-    warnings.warn(
-        "compile_cache: buffer donation is disabled while the "
-        "persistent compile cache is active (first affected program: "
-        "%r) — donated buffers and deserialized executables do not "
-        "mix. Expect one extra transient copy of donated buffers "
-        "(params/optimizer state) per step; unset "
-        "MXNET_COMPILE_CACHE_DIR if device memory is tighter than "
-        "restart time." % site)
-
-
 class WatchedFunction:
     """A ``jax.jit`` twin that stages compilation explicitly when the
     watch is on. Callable exactly like the jitted function (positional
     args only — every framework site is positional)."""
 
     __slots__ = ("_jitted", "_site", "_describe", "_cache", "_mu",
-                 "_broken", "_counter", "_statics", "_storm", "_opts",
-                 "_ctoken", "_csite", "_cache_ok", "_donated")
+                 "_broken", "_counter", "_statics", "_storm")
 
     def __init__(self, fn, site, describe=None, counter=None,
-                 statics=None, storm=True, cache=True,
-                 cache_token=None, cache_site=None, **jit_kwargs):
+                 statics=None, storm=True, **jit_kwargs):
         import jax
-        # donation and the persistent disk cache do not mix: donated
-        # buffers flowing BETWEEN deserialized executables intermit-
-        # tently corrupt the heap (observed on the CPU PJRT client —
-        # wrong values, then free()/segfault at teardown). With the
-        # cache active at wrapper creation, the program compiles
-        # WITHOUT donation — a bounded transient-memory cost the
-        # operator traded for restart speed; donation is an
-        # optimization, never semantics, so results are unchanged.
-        # A donating wrapper (cache enabled later) never touches disk.
-        self._donated = bool(jit_kwargs.get("donate_argnums"))
-        if self._donated and cache and compile_cache.enabled():
-            jit_kwargs = {k: v for k, v in jit_kwargs.items()
-                          if k != "donate_argnums"}
-            self._donated = False
-            _warn_donation_stripped(site)
         self._jitted = jax.jit(fn, **jit_kwargs)
         self._site = site
         self._describe = describe
@@ -488,22 +437,6 @@ class WatchedFunction:
         self._cache = {}             # compile ms at this site
         self._statics = statics      # program identity = (site, statics)
         self._storm = bool(storm)    # storm-track this program?
-        # the jit options are part of the COMPILED program's identity
-        # (donation, out_shardings, compiler options) — they join the
-        # persistent-cache key so an option flip is a natural miss
-        self._opts = repr(sorted(jit_kwargs.items(), key=lambda kv:
-                                 kv[0])) if jit_kwargs else None
-        # persistent-cache participation: ``cache_token`` carries the
-        # CONTENT this program closes over (a symbol-graph hash, an
-        # artifact digest) — site + statics + signature alone cannot
-        # distinguish two different models with identical shapes;
-        # ``cache_site`` overrides the on-disk site component when the
-        # display site embeds a process-local counter; ``cache=False``
-        # opts a program whose content has no stable fingerprint (an
-        # arbitrary user callable) out of the disk cache entirely
-        self._ctoken = cache_token
-        self._csite = cache_site or site
-        self._cache_ok = bool(cache)
         self._mu = threading.Lock()
         self._broken = False
 
@@ -513,13 +446,7 @@ class WatchedFunction:
 
     def __call__(self, *args, **kwargs):
         w = _watch
-        if (w is None and (compile_cache._cache is None
-                           or not self._cache_ok)) \
-                or self._broken or kwargs:
-            # the persistent disk cache rides the same staged path, so
-            # it works with or without the watch's accounting — a
-            # serving replica with only MXNET_COMPILE_CACHE_DIR set
-            # still warms from disk
+        if w is None or self._broken or kwargs:
             return self._jitted(*args, **kwargs)
         return self._watched_call(w, args)
 
@@ -542,9 +469,8 @@ class WatchedFunction:
             if entry is None:        # staging failed: degraded fallback
                 return self._jitted(*args)
         out = entry["fn"](*args)
-        if w is not None:
-            _accrue(w, entry["flops"], entry["flops_norm"],
-                    entry["bytes"], self._site)
+        _accrue(w, entry["flops"], entry["flops_norm"], entry["bytes"],
+                self._site)
         return out
 
     def _compile(self, w, key, args):
@@ -552,50 +478,31 @@ class WatchedFunction:
         # threads racing on the same signature (decode-pool workers
         # hitting a shared eager-op wrapper) must produce ONE compile,
         # one record, one storm-clock entry — not N duplicates
-        from_disk = False
         with self._mu:
             entry = self._cache.get(key)
             if entry is not None:
                 return entry
-            ckey = None
-            compiled = None
             t0 = time.perf_counter()
-            if self._cache_ok and not self._donated \
-                    and compile_cache.enabled():
-                ckey = compile_cache.entry_key(
-                    self._csite, self._statics, key,
-                    (self._opts, self._ctoken))
-                # deserialize-before-compile: a hit means the
-                # executable came off disk — no XLA compile happened,
-                # and none is recorded as fresh (the warm-restart
-                # zero-fresh-compiles oracle)
-                compiled = compile_cache.lookup(ckey)
-                from_disk = compiled is not None
-            if compiled is None:
-                try:
-                    compiled = self._jitted.lower(*args).compile()
-                except Exception:
-                    # never let the observability layer change what
-                    # the program raises: re-run through the plain jit
-                    # twin (a genuinely bad call re-raises identically;
-                    # a staging-only failure permanently degrades this
-                    # wrapper instead of the job)
-                    self._broken = True
-                    if w is not None:
-                        with _lock:
-                            w.degraded += 1
-                    warnings.warn(
-                        "compile_watch: staged compile failed for %r; "
-                        "falling back to plain jax.jit for this "
-                        "program (compile accounting degraded)"
-                        % self._site)
-                    return None
-                if ckey is not None:
-                    # serialize-after-compile, off the hot thread
-                    compile_cache.store(ckey, compiled)
+            try:
+                compiled = self._jitted.lower(*args).compile()
+            except Exception:
+                # never let the observability layer change what the
+                # program raises: re-run through the plain jit twin (a
+                # genuinely bad call re-raises identically; a
+                # staging-only failure permanently degrades this
+                # wrapper instead of the job)
+                self._broken = True
+                with _lock:
+                    w.degraded += 1
+                warnings.warn(
+                    "compile_watch: staged compile failed for %r; "
+                    "falling back to plain jax.jit for this "
+                    "program (compile accounting degraded)"
+                    % self._site)
+                return None
             dur = time.perf_counter() - t0
             flops, nbytes = _cost_of(compiled)
-            mem = None if from_disk else _memory_of(compiled)
+            mem = _memory_of(compiled)
             try:
                 desc = self._describe(*args) \
                     if self._describe is not None \
@@ -607,31 +514,20 @@ class WatchedFunction:
             entry = {"fn": compiled, "flops": flops, "bytes": nbytes,
                      "flops_norm": flops / factor, "dtype": cdtype}
             self._cache[key] = entry
-        if w is None:
-            # cache-only mode (no watch): the disk counters already
-            # ticked; there is no compile accounting to fold into
-            return entry
-        if from_disk:
-            event = _record_cache_hit(w, self._site, self._statics,
-                                      dur, desc)
-        else:
-            event = _record_compile(w, self._site, self._statics,
-                                    self._storm, dur, desc, flops,
-                                    nbytes, mem)
-            if cdtype is not None:
-                event["compute_dtype"] = cdtype
-            if ckey is not None:
-                event["cache"] = "miss"
-            if self._counter:
-                from . import profiler
-                profiler.increment_counter(self._counter, dur * 1e3)
+        event = _record_compile(w, self._site, self._statics,
+                                self._storm, dur, desc, flops, nbytes,
+                                mem)
+        if cdtype is not None:
+            event["compute_dtype"] = cdtype
+        if self._counter:
+            from . import profiler
+            profiler.increment_counter(self._counter, dur * 1e3)
         _emit_compile_record(event)
         return entry
 
 
 def jit(fn, site, describe=None, counter=None, statics=None,
-        storm=True, cache=True, cache_token=None, cache_site=None,
-        **jit_kwargs):
+        storm=True, **jit_kwargs):
     """Wrap ``fn`` exactly like ``jax.jit(fn, **jit_kwargs)`` but
     observable: ``site`` names the logical program (recompiles of the
     same (site, statics) identity are diffed/storm-tracked across
@@ -643,18 +539,13 @@ def jit(fn, site, describe=None, counter=None, statics=None,
     over every param shape is specialization, not churn) out of the
     storm warning while keeping its compiles in the log.
 
-    Persistent-cache contract (``mxnet_tpu.compile_cache``): the disk
-    key is (cache_site or site, statics, full argument signature, jit
-    options, cache_token, jax/device versions). A site whose program
-    closes over content the key cannot see MUST pass ``cache_token``
-    (e.g. a symbol-graph hash) or ``cache=False`` — otherwise two
-    different models with identical shapes would share an entry."""
+    The staged ``lower().compile()`` goes through JAX's own persistent
+    compilation cache (``runtime.enable_compile_cache``) like any
+    ``jax.jit`` call: a warm process still records each program here
+    once, with the time it took to load instead of to compile."""
     maybe_enable()
-    compile_cache.maybe_enable()   # MXNET_COMPILE_CACHE_DIR rides too
     return WatchedFunction(fn, site, describe=describe, counter=counter,
-                           statics=statics, storm=storm, cache=cache,
-                           cache_token=cache_token,
-                           cache_site=cache_site, **jit_kwargs)
+                           statics=statics, storm=storm, **jit_kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -744,25 +635,6 @@ def _record_compile(w, site, statics, storm_track, dur, desc, flops,
     if mem:
         event["memory"] = mem
     return event
-
-
-def _record_cache_hit(w, site, statics, dur, desc):
-    """Fold one persistent-cache hit into the program's stats: the
-    program exists (so the site shows up in reports) but its fresh
-    ``count`` stays untouched — ``site_stats`` counting zero fresh
-    compiles on a warm restart IS the cache's acceptance oracle. The
-    hit sets ``last_desc`` so a later genuine recompile diffs against
-    the signature actually loaded, and never ticks the storm clock
-    (loading from disk is the opposite of churn)."""
-    with _lock:
-        w.cache_hits += 1
-        w.cache_hit_s += dur
-        p = w.program(site, statics)
-        p["cache_hits"] = p.get("cache_hits", 0) + 1
-        p["last_desc"] = desc
-    return {"type": "compile", "program": site,
-            "dur_ms": round(dur * 1e3, 3), "cause": "disk_cache",
-            "cache": "hit"}
 
 
 def _emit_compile_record(event):
@@ -915,9 +787,6 @@ def stats():
             agg["count"] += p["count"]
             agg["total_s"] = round(agg["total_s"] + p["total_s"], 6)
             agg["specializations"] += 1
-            if p.get("cache_hits"):
-                agg["cache_hits"] = agg.get("cache_hits", 0) \
-                    + p["cache_hits"]
             for k, v in p["causes"].items():
                 agg["causes"][k] = agg["causes"].get(k, 0) + v
             if p["churn"]:
@@ -929,8 +798,6 @@ def stats():
         out = {
             "compiles": w.compile_count,
             "compile_total_s": round(w.compile_total_s, 6),
-            "cache_hits": w.cache_hits,
-            "cache_hit_s": round(w.cache_hit_s, 6),
             "programs": programs,
             "storms": [dict(s) for s in w.storms],
             "dispatches": w.dispatches,
@@ -972,13 +839,6 @@ def site_stats(prefix=None):
             agg = out.setdefault(site, {"count": 0, "total_s": 0.0})
             agg["count"] += p["count"]
             agg["total_s"] = round(agg["total_s"] + p["total_s"], 6)
-            if p.get("cache_hits"):
-                # programs loaded from the persistent disk cache: the
-                # site is live but its fresh count stays 0 — the key
-                # is only present when hits happened, so cache-less
-                # runs keep the historical dict shape exactly
-                agg["cache_hits"] = agg.get("cache_hits", 0) \
-                    + p["cache_hits"]
     return out
 
 
@@ -1017,9 +877,6 @@ def summary_blocks():
         compile_block["storms"] = s["storms"]
     if s["degraded"]:
         compile_block["degraded"] = s["degraded"]
-    cache = compile_cache.stats()
-    if cache is not None:
-        compile_block["cache"] = cache
     util_block = {
         "device_kind": s["device_kind"],
         "n_devices": s["n_devices"],

@@ -78,17 +78,22 @@ class Context:
         import jax
         dt = self.device_type
         if dt in ('cpu', 'cpu_pinned', 'cpu_shared'):
-            try:
-                return jax.devices('cpu')[self.device_id]
-            except (RuntimeError, IndexError):
-                # Platform-restricted process (e.g. JAX_PLATFORMS=tpu):
-                # fall back to default devices.
-                return jax.devices()[0]
+            cpus = jax.devices('cpu')   # raises where JAX_PLATFORMS
+            if self.device_id >= len(cpus):     # excludes the host
+                raise MXNetError(
+                    "context %s: only %d cpu device(s) available"
+                    % (self, len(cpus)))
+            return cpus[self.device_id]
         # gpu/tpu: use the default backend's devices (on this stack that is
         # the TPU / accelerator backend; 'gpu' accepted for compat).
         devs = jax.devices()
-        if devs and devs[0].platform == 'cpu' and dt in ('gpu', 'tpu'):
-            # No accelerator present (e.g. CPU-only test runs): place on cpu.
+        if devs[0].platform == 'cpu':
+            # No accelerator in this process (the CPU test mesh): an
+            # accelerator context lands on a host device. Counted, so
+            # a run that must be on the chip can assert it never
+            # happened (chip_smoke.py does) instead of trusting it.
+            from . import profiler
+            profiler.increment_counter("context_accelerator_on_cpu")
             return devs[self.device_id % len(devs)]
         if self.device_id >= len(devs):
             raise MXNetError(
@@ -117,12 +122,11 @@ def _initial_default_context() -> "Context":
     override = envs.get_str("MXNET_DEFAULT_CONTEXT").lower()
     if override:
         return Context(override, 0)
-    try:
-        import jax
-        if jax.devices()[0].platform != 'cpu':
-            return Context('tpu', 0)
-    except Exception:  # backend init failure → host arrays still work
-        pass
+    import jax
+    # a backend that fails to initialise raises here: the default
+    # context never quietly becomes the host
+    if jax.devices()[0].platform != 'cpu':
+        return Context('tpu', 0)
     return Context('cpu', 0)
 
 
@@ -156,10 +160,7 @@ def num_gpus():
 
 def num_tpus():
     import jax
-    try:
-        return len([d for d in jax.devices() if d.platform != 'cpu'])
-    except RuntimeError:
-        return 0
+    return len([d for d in jax.devices() if d.platform != 'cpu'])
 
 
 def gpu_memory_info(device_id=0):

@@ -434,10 +434,7 @@ def load_compiled(path):
     format-3 file reads as format 2 whose programs happen to run the
     int8 graph). Needs only jax — not the framework's model code or
     parameter files."""
-    import hashlib
-
     from jax import export as jexport
-    digest = hashlib.sha256()
     with open(path, "rb") as f:
         magic = f.read(len(_MAGIC))
         if magic != _MAGIC:
@@ -446,7 +443,6 @@ def load_compiled(path):
         (mlen,) = struct.unpack("<I", f.read(4))
         meta_bytes = f.read(mlen)
         meta = json.loads(meta_bytes.decode())
-        digest.update(meta_bytes)
         if meta.get("format", 1) >= 2 and meta.get("programs"):
             programs = []
             for p in meta["programs"]:
@@ -455,19 +451,11 @@ def load_compiled(path):
                     raise MXNetError(
                         "%s is truncated: program for bucket %s is "
                         "short" % (path, p.get("batch")))
-                digest.update(blob)
                 programs.append((int(p["batch"]),
                                  jexport.deserialize(blob)))
         else:                              # format 1: one trailing blob
             blob = f.read()
-            digest.update(blob)
             shape0 = (meta.get("inputs") or [{}])[0].get("shape") or []
             batch = int(shape0[0]) if shape0 else 1
             programs = [(batch, jexport.deserialize(blob))]
-    pred = Predictor(programs, meta)
-    # content fingerprint for the persistent compile cache: the meta
-    # records shapes, the BLOBS carry the baked weights — two exports
-    # of the same architecture with different parameters must never
-    # share a cached serving executable
-    pred.content_token = digest.hexdigest()
-    return pred
+    return Predictor(programs, meta)

@@ -460,9 +460,6 @@ class FusedStepExecutor(_FusedCore):
             program, site, describe=describe,
             counter="fused_step_compile_ms",
             statics=statics,
-            # the program embeds the executor's forward+backward — the
-            # graph hash keeps two same-shaped models apart on disk
-            cache_token=getattr(self._ex, "cw_cache_token", None),
             donate_argnums=(0, 1),
             compiler_options=compiler_options(self._ex._ctx))
         self._cache[key] = fn

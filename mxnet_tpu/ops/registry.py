@@ -254,20 +254,9 @@ def _get_jitted(op: OpDef, nattrs: Dict[str, Any], n_inputs: int):
         # — one hybridized program, site "op:_cachedopN.<head>" —
         # participate in recompile-storm detection.
         is_cached = op.name.startswith("_cachedop")
-        token = getattr(op, "cache_token", None)
-        cache_site = None
-        if is_cached and token is not None:
-            # the display site's instance counter is process-local;
-            # on disk the program is (head, graph hash, attrs, sig) —
-            # so a rebuilt identical block hits, and creation order
-            # can never map an entry to the wrong graph
-            cache_site = "op:_cachedop.%s" % op.name.split(".", 1)[-1]
         fn = compile_watch.jit(raw, "op:%s" % op.name,
                                describe=describe, statics=key[1:],
-                               storm=is_cached,
-                               cache=token is not None or not is_cached,
-                               cache_token=token,
-                               cache_site=cache_site)
+                               storm=is_cached)
         with _jit_lock:
             _jit_cache[key] = fn
     return fn

@@ -67,22 +67,11 @@ def _watched(prim, mesh, statics, build):
 
 def _shard_map():
     import jax
-    import functools
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental import shard_map as _sm
-        sm = _sm.shard_map
 
     def wrapped(f, **kwargs):
-        # psum outputs are replicated but the static checker can't always
-        # infer it; disable the check (arg name varies across versions)
-        for flag in ("check_vma", "check_rep"):
-            try:
-                return sm(f, **dict(kwargs, **{flag: False}))
-            except TypeError:
-                continue
-        return sm(f, **kwargs)
+        # psum outputs are replicated but the static checker can't
+        # always infer it; disable the check
+        return jax.shard_map(f, check_vma=False, **kwargs)
     return wrapped
 
 

@@ -256,17 +256,13 @@ def make_data_parallel_step(loss_fn: Callable, mesh, optimizer_update=None,
     jit_kwargs = {}
     if donate:
         jit_kwargs["donate_argnums"] = (0,)
-    # staged for compile telemetry/storm detection; cache=False
-    # because the step closes over an arbitrary user ``loss_fn`` /
-    # ``optimizer_update`` — there is no stable content fingerprint,
-    # so a persistent-cache entry could collide two different models
-    # with identical shapes (the compile_watch.jit contract)
+    # staged for compile telemetry/storm detection
     from .. import compile_watch
     return (compile_watch.jit(
         step, "data_parallel:step",
         statics=("overlap" if overlap else "plain",
                  "shard" if shard_on else "rep"),
-        cache=False, **jit_kwargs), batch_sharding)
+        **jit_kwargs), batch_sharding)
 
 
 class DistributedTrainer:
@@ -390,19 +386,6 @@ class DistributedTrainer:
         label_sym = sym_mod.var("label")
         out_sym = net(data_sym)
         loss_sym = loss_blk(out_sym, label_sym)
-        # content fingerprint for the persistent compile cache: the
-        # symbol graph IS this trainer's program content (unlike
-        # make_data_parallel_step's arbitrary callables), so a
-        # supervised restart warms from disk instead of recompiling
-        from .. import compile_cache
-        compile_cache.maybe_enable()
-        self._cw_token = None
-        if compile_cache.enabled():
-            try:
-                self._cw_token = compile_cache.graph_token(
-                    loss_sym.tojson())
-            except Exception:
-                self._cw_token = None
         fn, arg_names, aux_names, n_rng, n_out = \
             build_graph_callable(loss_sym)
         params = {p.name: p for p in net.collect_params().values()}
@@ -628,17 +611,11 @@ class DistributedTrainer:
             return d
 
         if not self._mh:
-            ctoken = getattr(self, "_cw_token", None)
             self._step_fn = compile_watch.jit(
                 step, site, describe=describe,
                 counter="fused_step_compile_ms",
                 statics=(plan.signature(), shard_sig,
                          self._opt.fused_static_key()),
-                # the step embeds the traced symbol graph — its hash
-                # is the content fingerprint that keeps two
-                # same-shaped models apart on disk (no token = no
-                # active cache = opt out)
-                cache=ctoken is not None, cache_token=ctoken,
                 donate_argnums=(0, 1, 2))
         else:
             self._build_multihost(fn, arg_names, aux_names, roster,
@@ -715,16 +692,11 @@ class DistributedTrainer:
                                      [data_v, label_v, n_rows]))
             return d
 
-        ctoken = getattr(self, "_cw_token", None)
         self._mh_grad_fn = compile_watch.jit(
             grad_stacked, "fused_step:mh_grad",
             describe=describe_grad,
             counter="fused_step_compile_ms",
-            statics=(plan.signature(), self._opt.fused_static_key()),
-            # the symbol-graph hash keeps two same-shaped models apart
-            # on disk; without an active cache there is no token and
-            # the program opts out
-            cache=ctoken is not None, cache_token=ctoken)
+            statics=(plan.signature(), self._opt.fused_static_key()))
 
         def mh_apply(g_tot, param_vals, state_vals, scalars, poisons):
             new_ws, new_sts, _ = apply_fn(g_tot, param_vals,
@@ -748,7 +720,6 @@ class DistributedTrainer:
             describe=describe_apply,
             counter="fused_step_compile_ms",
             statics=(plan.signature(), self._opt.fused_static_key()),
-            cache=ctoken is not None, cache_token=ctoken,
             donate_argnums=(1, 2))
         # the built marker every property/entry point checks
         self._step_fn = self._mh_apply_fn

@@ -101,15 +101,23 @@ def create_mesh(axis_sizes: Dict[str, int], devices=None):
     order. Product must equal the device count used."""
     import jax
     from jax.sharding import Mesh
-    devices = devices if devices is not None else jax.devices()
     names = list(axis_sizes.keys())
     sizes = [int(axis_sizes[n]) for n in names]
     total = int(np.prod(sizes))
-    if total != len(devices):
+    n_have = len(devices) if devices is not None else len(jax.devices())
+    if total != n_have:
         raise ValueError(
             "mesh axes %s product %d != device count %d"
-            % (axis_sizes, total, len(devices)))
-    dev_array = np.asarray(devices).reshape(sizes)
+            % (axis_sizes, total, n_have))
+    if devices is None:
+        # topology-aware on TPU: jax.devices() is id order, which on a
+        # 2x2 puts a diagonal (two-hop) step in a 1-D axis (ids 0,1,2,3
+        # sit at (0,0),(1,0),(0,1),(1,1)); mesh_utils orders the axis
+        # along physical neighbours. Off-TPU it is the plain reshape.
+        from jax.experimental import mesh_utils
+        dev_array = mesh_utils.create_device_mesh(sizes)
+    else:
+        dev_array = np.asarray(devices).reshape(sizes)
     return Mesh(dev_array, names)
 
 

@@ -54,10 +54,7 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, *, mesh,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    if hasattr(jax, "shard_map"):
-        shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map
+    shard_map = jax.shard_map
 
     n_stages = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     n_micro = microbatches.shape[0]
@@ -110,8 +107,5 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, *, mesh,
         mb_spec = P()
     kwargs = dict(mesh=mesh, in_specs=(param_specs, mb_spec),
                   out_specs=mb_spec)
-    try:
-        sharded = shard_map(schedule, check_vma=False, **kwargs)
-    except TypeError:       # older jax spells it check_rep
-        sharded = shard_map(schedule, check_rep=False, **kwargs)
+    sharded = shard_map(schedule, check_vma=False, **kwargs)
     return sharded(stacked_params, microbatches)

@@ -76,7 +76,7 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    shard_map = jax.shard_map if hasattr(jax, 'shard_map') else __import__('jax.experimental.shard_map', fromlist=['shard_map']).shard_map
+    shard_map = jax.shard_map
 
     if mesh is None or axis not in getattr(mesh, "axis_names", ()):
         return local_attention(q, k, v, causal=causal, scale=scale)
@@ -127,7 +127,7 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", causal=False,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    shard_map = jax.shard_map if hasattr(jax, 'shard_map') else __import__('jax.experimental.shard_map', fromlist=['shard_map']).shard_map
+    shard_map = jax.shard_map
 
     if mesh is None or axis not in getattr(mesh, "axis_names", ()):
         return local_attention(q, k, v, causal=causal, scale=scale)

@@ -150,11 +150,9 @@ class GroupedProgram:
         # keys) on the segment's device, so the compiled program runs
         # there — jit(device=...) is deprecated in this jax.
         # Staged through compile_watch so cross-group execution shows
-        # up in compile telemetry; the cache token digests the
+        # up in compile telemetry; the program identity digests the
         # segment's op/attr/binding plan (the content this closure
-        # bakes in), and the argument signature carries the device
-        # placement, so persistent-cache entries cannot collide
-        # across different groupings.
+        # bakes in).
         import hashlib
 
         from . import compile_watch
@@ -164,8 +162,7 @@ class GroupedProgram:
                     plan[pi][2:]) for pi in idxs],
              ext)).encode()).hexdigest()
         fn = compile_watch.jit(seg_run, "placement:seg%d" % si,
-                               statics=token[:16], storm=False,
-                               cache_token=token)
+                               statics=token[:16], storm=False)
         self._seg_fns[key] = fn
         return fn
 

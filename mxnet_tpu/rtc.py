@@ -173,9 +173,10 @@ class PallasKernel:
                     call_args.append(ins[const_pos.index(i)])
             self._fn(*call_args)
 
-        platform = jax.devices()[0].platform \
-            if ctx is None else ctx.device_type
-        interpret = platform != "tpu"
+        # interpret mode is for devices Mosaic cannot target; decided
+        # from the RESOLVED device — mx.gpu(0) is the TPU on this stack
+        device = jax.devices()[0] if ctx is None else ctx.jax_device()
+        interpret = device.platform != "tpu"
         out_shapes = [jax.ShapeDtypeStruct(a._data.shape,
                                            jnp.dtype(self._dtypes[i]))
                       for i, a in out_specs]

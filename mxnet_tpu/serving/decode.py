@@ -501,13 +501,12 @@ class DecodeServer:
             else self._prefill_fn
         self._decode_prog = compile_watch.jit(
             decode_fn, "%s:step" % site,
-            statics=(site, self._window, self._max_pages),
-            cache=False, **donate)
+            statics=(site, self._window, self._max_pages), **donate)
         self._prefill_progs = {}
         for rung in self._seq_ladder.buckets:
             self._prefill_progs[rung] = compile_watch.jit(
                 prefill_fn, "%s:prefill:s%d" % (site, rung),
-                statics=(site, "prefill", rung), cache=False, **donate)
+                statics=(site, "prefill", rung), **donate)
         # the copy-on-write page copy: one more fixed program, only
         # ever compiled when the prefix cache is on (warmup covers it)
         cow_fn = self._cow_fn_q8 if self._pool.quantized \
@@ -518,7 +517,7 @@ class DecodeServer:
                           if self._pool.quantized else (0, 1)}
         self._cow_prog = compile_watch.jit(
             cow_fn, "%s:cow" % site, statics=(site, "cow"),
-            cache=False, **cow_donate)
+            **cow_donate)
 
         self._cond = threading.Condition()
         self._queue = deque()
@@ -551,6 +550,12 @@ class DecodeServer:
         livemetrics.maybe_start()
         if start:
             self.start()
+
+    @property
+    def pool(self):
+        """The :class:`KVCachePool` this server decodes against (its
+        ``.k``/``.v`` are the live device arrays)."""
+        return self._pool
 
     # -- compiled programs -------------------------------------------------
     def _prefill_fn(self, params, tokens, n_valid, page_table, k_pages,

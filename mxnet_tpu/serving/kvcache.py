@@ -310,7 +310,6 @@ class KVCachePool:
 
     def __init__(self, n_layers, n_heads, head_dim, *, page_size=None,
                  n_pages=None, dtype=None, device=None):
-        import jax
         import jax.numpy as jnp
         self.page_size = int(page_size) if page_size is not None \
             else envs.get_int("MXNET_KV_PAGE_SIZE")
@@ -336,22 +335,16 @@ class KVCachePool:
         dtype = jnp.dtype(dtype)
         self.dtype = dtype
         self.quantized = dtype == jnp.int8
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
-        k_scale = v_scale = None
+        # allocated ON the target device: a replica's pool must never
+        # be staged through the first chip's memory on its way there
+        self.k = jnp.zeros(shape, dtype, device=device)
+        self.v = jnp.zeros(shape, dtype, device=device)
+        self.k_scale = self.v_scale = None
         if self.quantized:
-            k_scale = jnp.zeros(shape[:2], jnp.float32)
-            v_scale = jnp.zeros(shape[:2], jnp.float32)
-        if device is not None:
-            k = jax.device_put(k, device)
-            v = jax.device_put(v, device)
-            if self.quantized:
-                k_scale = jax.device_put(k_scale, device)
-                v_scale = jax.device_put(v_scale, device)
-        self.k = k
-        self.v = v
-        self.k_scale = k_scale
-        self.v_scale = v_scale
+            self.k_scale = jnp.zeros(shape[:2], jnp.float32,
+                                     device=device)
+            self.v_scale = jnp.zeros(shape[:2], jnp.float32,
+                                     device=device)
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
         self.head_dim = int(head_dim)

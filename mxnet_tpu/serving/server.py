@@ -249,12 +249,6 @@ class InferenceServer:
 
         self.name = name
         site = "serving" if not name else "serving:%s" % name
-        # persistent-cache participation: an artifact's digest (meta +
-        # program blobs, i.e. the baked weights) fingerprints what the
-        # bucket programs close over; an in-process callable has no
-        # stable content identity, so it stays out of the disk cache
-        ctoken = getattr(predictor, "content_token", None) \
-            if predictor is not None else None
         self._programs = {}
         for b in ladder.buckets:
             if predictor is not None:
@@ -267,14 +261,12 @@ class InferenceServer:
             # programs by construction (statics carry the bucket)
             if seq_ladder is None:
                 self._programs[b] = compile_watch.jit(
-                    fn, "%s:b%d" % (site, b), statics=(site, b),
-                    cache=ctoken is not None, cache_token=ctoken)
+                    fn, "%s:b%d" % (site, b), statics=(site, b))
             else:
                 for s in seq_ladder.buckets:
                     self._programs[(b, s)] = compile_watch.jit(
                         fn, "%s:b%d:s%d" % (site, b, s),
-                        statics=(site, b, s),
-                        cache=ctoken is not None, cache_token=ctoken)
+                        statics=(site, b, s))
 
         import jax
         replicas = int(replicas)
@@ -414,12 +406,6 @@ class InferenceServer:
         taking traffic, so no live request ever pays an XLA compile.
         Artifact-backed servers build zero samples from the meta;
         callable models need one ``example`` sample array per input.
-        With ``MXNET_COMPILE_CACHE_DIR`` set, every ladder rung loads
-        from the persistent compile cache when a previous replica (or
-        a previous life of this one) already built it — a warm
-        replica restart compiles NOTHING fresh — and freshly-built
-        programs are flushed to disk before this returns, so even a
-        replica killed right after warmup leaves a warm cache behind.
         Returns the number of (bucket, device) programs readied."""
         import jax
         if example:
@@ -459,8 +445,6 @@ class InferenceServer:
                               for s in warm]
                     jax.block_until_ready(self._programs[key](*inputs))
                     n += 1
-        from .. import compile_cache
-        compile_cache.flush()
         return n
 
     # -- admission ---------------------------------------------------------

@@ -366,19 +366,6 @@ def format_telemetry(tel):
                 "%s=%s" % (k[len("fused_step_"):],
                            round(v, 1) if isinstance(v, float) else v)
                 for k, v in sorted(fused.items())))
-        cache = sum_compile.get("cache") or {}
-        if cache:
-            lines.append(
-                "compile-cache: %d hit(s) / %d miss(es), "
-                "%s read / %s written, %d entr%s (%s on disk), "
-                "%d evicted, %d error(s)"
-                % (cache.get("hits", 0), cache.get("misses", 0),
-                   _fmt_bytes(cache.get("bytes_read", 0)),
-                   _fmt_bytes(cache.get("bytes_written", 0)),
-                   cache.get("entries", 0),
-                   "y" if cache.get("entries", 0) == 1 else "ies",
-                   _fmt_bytes(cache.get("size_bytes", 0)),
-                   cache.get("evictions", 0), cache.get("errors", 0)))
 
     # -- hardware utilization (MFU / memory bandwidth) ------------------
     utils = tel.get("utilization") or []
@@ -1158,8 +1145,7 @@ def telemetry_json(tel):
                            "churn": dict(s.get("churn") or {})}
     out["compilation"] = {
         "programs": progs,
-        "storms": sum_compile.get("storms") or [],
-        "cache": sum_compile.get("cache") or None} \
+        "storms": sum_compile.get("storms") or []} \
         if (progs or sum_compile) else None
     utils = tel.get("utilization") or []
     sum_util = summary.get("utilization") or {}

@@ -33,6 +33,17 @@ free choice). ``--events-file`` appends one JSON line per supervisor
 event (worker death, teardown, restart, give-up) — the
 detection-to-restart timing source for ``bench.py --multihost``.
 
+**Not for several workers on one TPU host.** ``_spawn_workers`` hands
+every local worker the parent's environment plus the DMLC_* contract
+and nothing that gives it a chip of its own (no per-worker visible-
+device setting), so N local workers each claim every chip of the
+host — and a chip belongs to one process at a time: the second
+worker fails or hangs at backend start-up. Local multi-worker launches
+are for the CPU backend (the tests and ``bench.py --multihost`` pin
+``JAX_PLATFORMS=cpu``). On a TPU host run ONE process that drives all
+its chips (``chip_smoke.py --chips 4`` does), or one launched worker
+per host.
+
 Only the ``local`` launcher is implemented: multi-host jobs on TPU
 pods are started by the cluster scheduler (GKE/xmanager), which
 provides its own coordinator wiring — ssh/mpi/sge/yarn trackers exist

@@ -60,7 +60,7 @@ def load_jit_allowlist():
 
 @rule("jit-staging",
       "every jax.jit stages through compile_watch.jit (compile "
-      "telemetry, storm detection, persistent compile cache)")
+      "telemetry, storm detection)")
 def check_jit_staging(ctx):
     if ctx.relpath in _JIT_EXEMPT_FILES:
         return
@@ -81,9 +81,8 @@ def check_jit_staging(ctx):
 
     msg = ("raw jax.jit — stage through compile_watch.jit("
            "fn, site=...) so this program joins compile "
-           "telemetry, storm detection and the persistent "
-           "compile cache (or add a jit_allowlist.json entry "
-           "with a rationale)")
+           "telemetry and storm detection (or add a "
+           "jit_allowlist.json entry with a rationale)")
     # decorator forms: @jax.jit / @jit / @partial(jax.jit, ...) —
     # the most common jit idiom must not bypass the gate
     dec_calls = set()
@@ -172,13 +171,11 @@ def check_atomic_write(ctx):
 # += / -= on one of these OUTSIDE a with-lock is exactly the PR 3
 # racy-counter bug shape.  Bare local names are never flagged.
 _COUNTER_ATTRS = frozenset({
-    "compile_count", "compile_total_s", "cache_hits", "cache_hit_s",
-    "degraded", "dispatches", "step_flops", "step_bytes",
-    "step_dispatches", "step_compiles", "step_compile_s",
-    "total_flops", "total_bytes", "hits", "misses", "errors",
-    "evictions", "stores", "stores_dropped", "bytes_read",
-    "bytes_written", "hit_s", "saves", "failures", "records_dropped",
-    "dropped", "steps", "samples",
+    "compile_count", "compile_total_s", "degraded", "dispatches",
+    "step_flops", "step_bytes", "step_dispatches", "step_compiles",
+    "step_compile_s", "total_flops", "total_bytes", "hits", "misses",
+    "errors", "saves", "failures", "records_dropped", "dropped",
+    "steps", "samples",
 })
 
 # dict containers whose item-writes count as counter mutations
@@ -190,8 +187,7 @@ _LOCKISH = re.compile(r"lock|_mu\b|mutex|cond", re.IGNORECASE)
 # stack + its writers); elsewhere ad-hoc counters are local state
 _COUNTER_MODULES = (
     "mxnet_tpu/profiler.py", "mxnet_tpu/telemetry.py",
-    "mxnet_tpu/compile_watch.py", "mxnet_tpu/compile_cache.py",
-    "mxnet_tpu/livemetrics.py", "mxnet_tpu/tracing.py",
+    "mxnet_tpu/compile_watch.py", "mxnet_tpu/livemetrics.py", "mxnet_tpu/tracing.py",
     "mxnet_tpu/checkpoint.py", "mxnet_tpu/serving/",
     "mxnet_tpu/bucketing/record.py",
 )
@@ -257,7 +253,7 @@ def check_counter_lock(ctx):
 
 _PIPELINE_MODULES = (
     "mxnet_tpu/io/", "mxnet_tpu/serving/", "mxnet_tpu/checkpoint.py",
-    "mxnet_tpu/compile_cache.py", "mxnet_tpu/bucketing/",
+    "mxnet_tpu/bucketing/",
     "mxnet_tpu/kvstore_server.py", "mxnet_tpu/livemetrics.py",
 )
 

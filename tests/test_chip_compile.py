@@ -1,0 +1,143 @@
+"""Sandbox compiles for the chip: every ``pallas_call`` of
+``parallel/flash_attention.py`` and the decode step program, compiled by
+the TPU's own compiler for a DESCRIBED v5e (no chip attached, nothing
+runs). Interpret mode on the CPU accepts block shapes Mosaic refuses —
+these cases are what keeps the kernels loadable between chip runs.
+A compile that passes is not a chip run: results are checked by
+``chip_smoke.py`` on the chip."""
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu.parallel  # noqa: F401 — the package re-exports the
+fa = sys.modules["mxnet_tpu.parallel.flash_attention"]  # function
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A SingleDeviceSharding on one described v5e chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:                       # no libtpu here
+        pytest.skip("cannot describe a v5e topology: %s" % exc)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _persistent_cache_off():
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+B, H, D = 4, 8, 128
+SCALE = 1.0 / np.sqrt(D)
+
+
+def _attn(seg):
+    return lambda q, k, v, *s: fa._flash(
+        q, k, v, s[0] if seg else None, SCALE, True, 512, 512, False)
+
+
+def _attn_grad(seg):
+    f = _attn(seg)
+    return jax.grad(lambda *a: f(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+def _decode(quant):
+    def run(q, k, v, lens, *scales):
+        ks, vs = scales if quant else (None, None)
+        return fa._pallas_decode(q, k, v, lens, SCALE, 128, False,
+                                 k_scale=ks, v_scale=vs)
+    return run
+
+
+def _attn_args(T, dtype, seg):
+    qkv = [((B, T, H, D), dtype)] * 3
+    return qkv + ([((B, T), jnp.int32)] if seg else [])
+
+
+def _decode_args(T, dtype, quant):
+    cache = jnp.int8 if quant else dtype
+    args = [((B * H, 1, D), dtype), ((B * H, T, D), cache),
+            ((B * H, T, D), cache), ((B * H,), jnp.int32)]
+    return args + ([((B * H, T), jnp.float32)] * 2 if quant else [])
+
+
+# kind -> (function, pallas_calls in its program, argument builder,
+#          the builder's segments/quantized flag)
+KINDS = {
+    "forward": (_attn(False), 1, _attn_args, False),
+    "forward_grad": (_attn_grad(False), 3, _attn_args, False),
+    "segments_forward": (_attn(True), 1, _attn_args, True),
+    "segments_grad": (_attn_grad(True), 3, _attn_args, True),
+    "decode": (_decode(False), 1, _decode_args, False),
+    "decode_int8": (_decode(True), 1, _decode_args, True),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("T", [512, 2048])
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_kernel_compiles_for_v5e(chip, kind, T, dtype):
+    fn, n_kernels, make_args, flag = KINDS[kind]
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+            for shape, dt in make_args(T, dtype, flag)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # the Mosaic kernels are IN the program: not a jnp path, not
+    # interpret mode
+    assert text.count('custom_call_target="tpu_custom_call"') == n_kernels
+
+
+def test_decode_step_program_compiles_and_fits(chip, monkeypatch):
+    """The ``decode:step`` program at chip_smoke's widths — page gather,
+    ToyDecoderLM.decode over the Pallas decode kernel, token scatter —
+    compiled for one v5e from ``jax.eval_shape``-made shapes, inside the
+    chip's 16 GB. The platform predicate is steered here, in the test:
+    the sandbox's JAX sees a CPU."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+    from mxnet_tpu.serving import DecodeServer, ToyDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = chip_smoke.FULL
+    model = ToyDecoderLM(**cfg["lm"])
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    L, Hh, Dh = model.n_layers, model.n_heads, model.head_dim
+    W, S = cfg["window"], cfg["page_size"]
+    M = -(-(max(cfg["ladder"]) + cfg["new_tokens"]) // S)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = spec((L, cfg["pool_pages"], S, Hh, Dh), jnp.float32)
+    step = DecodeServer._decode_fn       # unbound: only self._model
+    holder = type("S", (), {"_model": model})()
+    compiled = jax.jit(
+        lambda *a: step(holder, *a), donate_argnums=(4, 5)).lower(
+        jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params),
+        spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W, M), jnp.int32), pool, pool).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == L
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < 16e9, mem

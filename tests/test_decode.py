@@ -45,11 +45,14 @@ def _toy(n_layers=1, use_pallas=False, seed=3, max_len=128):
 def _reference(model, params, prompt, n):
     """Greedy generation by one FULL-sequence forward at each length —
     the oracle stepwise cached decode must reproduce token-for-token."""
+    import jax
     import jax.numpy as jnp
     toks = [int(t) for t in prompt]
+    # one compiled program per length: op-by-op dispatch would compile
+    # every primitive again at every new length
+    prefill = jax.jit(model.prefill)
     for _ in range(n):
-        logits, _, _ = model.prefill(
-            params, jnp.asarray([toks], jnp.int32))
+        logits, _, _ = prefill(params, jnp.asarray([toks], jnp.int32))
         toks.append(int(np.argmax(np.asarray(logits)[0, len(toks) - 1])))
     return toks[len(prompt):]
 
@@ -164,7 +167,11 @@ def test_eos_stops_generation_early():
     model, params = _toy()
     prompt = np.arange(1, 6)
     ref = _reference(model, params, prompt, 12)
-    eos = ref[3]                              # stop at the 4th token
+    # stop at the first token the reference emits exactly once up to
+    # there: random weights repeat tokens, and an eos that also shows
+    # up earlier rightly stops the stream earlier
+    stop = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
+    eos = ref[stop]
     srv = DecodeServer(model, params, seq_ladder=[16], max_new_tokens=12,
                        window=2, page_size=8, pool_pages=16,
                        start=False)
@@ -172,7 +179,8 @@ def test_eos_stops_generation_early():
         req = srv.submit(prompt, max_new_tokens=12, eos_id=eos)
         _drain(srv, req)
         got = [int(t) for t in req.result(timeout=1)]
-        assert got == ref[:4] and got[-1] == eos
+        assert 1 < len(got) < len(ref)
+        assert got == ref[:stop + 1] and got[-1] == eos
     finally:
         srv.stop()
 
@@ -518,9 +526,11 @@ def test_decode_metrics_gauges():
 
 def test_flash_decode_matches_full_attention_rows():
     """The query-length-1 cached-KV kernel agrees with the full causal
-    forward at every position — bit-exact on the Pallas path (same
-    block accumulation order), allclose on the jnp path (same math,
-    different reduction-tree shapes)."""
+    forward at every position on both paths. Same math and block
+    order, but two differently-shaped programs (a (1, bk) score row vs
+    one row of a (bq, bk) tile), so XLA's reduction trees may differ in
+    the last ulps: a few-ulp fp32 tolerance, far tighter than any
+    lower-precision compute would pass."""
     import jax.numpy as jnp
     from mxnet_tpu.parallel.flash_attention import (flash_attention,
                                                     flash_decode)
@@ -538,8 +548,9 @@ def test_flash_decode_matches_full_attention_rows():
         dec_p = flash_decode(q[:, n - 1:n], kc, vc, lens,
                              force_pallas=True)
         dec_j = flash_decode(q[:, n - 1:n], kc, vc, lens)
-        assert np.array_equal(np.asarray(dec_p),
-                              np.asarray(full_p[:, n - 1:n]))
+        np.testing.assert_allclose(np.asarray(dec_p),
+                                   np.asarray(full_p[:, n - 1:n]),
+                                   rtol=2e-6, atol=2e-7)
         np.testing.assert_allclose(np.asarray(dec_j),
                                    np.asarray(full_j[:, n - 1:n]),
                                    rtol=2e-6, atol=2e-7)

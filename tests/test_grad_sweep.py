@@ -283,6 +283,15 @@ CASES = {
                       (5e-2, 5e-3)),   # fp32-internal DFT
     "_contrib_fft": ({}, {"data": _sym(2, 8)}, ("data",),
                      (5e-2, 5e-3)),    # fp32-internal DFT
+    # serving's cached-KV decode step: swept on the jnp composition
+    # (the Pallas decode kernel is forward-only, pinned against this
+    # same composition in test_decode / test_chip_compile)
+    "_contrib_decode_attention": (
+        {"impl": "dense"},
+        {"query": _sym(2, 1, 2, 4), "key_cache": _sym(2, 6, 2, 4),
+         "value_cache": _sym(2, 6, 2, 4),
+         "lengths": np.array([4., 6.])},
+        ("query", "key_cache", "value_cache")),
     "where": ({}, {"condition": np.array([[1., 0.], [0., 1.],
                                           [1., 1.]]),
                    "x": _sym(3, 2), "y": _sym(3, 2)},
@@ -517,6 +526,11 @@ def test_numeric_gradient(name, case):
             float(f(*vals))                # forward-only smoke
             return
         analytic = jax.grad(f, argnums=tuple(gpos))(*vals)
+        # the FD loop evaluates f twice per element: past a few dozen
+        # elements one compile of f is far cheaper than that many
+        # eager op-by-op evaluations (the deformable ops took minutes)
+        n_fd = sum(int(np.asarray(vals[p]).size) for p in gpos)
+        f_fd = jax.jit(f) if n_fd > 64 else f
 
         for gi, p in enumerate(gpos):
             base = np.asarray(vals[p], np.float64)
@@ -531,7 +545,8 @@ def test_numeric_gradient(name, case):
                 a_p[p] = jnp.asarray(vp.reshape(base.shape))
                 a_m = list(vals)
                 a_m[p] = jnp.asarray(vm.reshape(base.shape))
-                num[j] = (float(f(*a_p)) - float(f(*a_m))) / (2 * EPS)
+                num[j] = (float(f_fd(*a_p)) - float(f_fd(*a_m))) \
+                    / (2 * EPS)
             np.testing.assert_allclose(
                 an.ravel(), num, rtol=rtol, atol=atol,
                 err_msg="%s: d/d%s mismatch" % (name, arg_order[p]))

@@ -476,7 +476,7 @@ class TestOutput:
         assert main(["--envs"]) == 0
         out = capsys.readouterr().out
         assert "MXNET_TELEMETRY_RING" in out
-        assert "MXNET_COMPILE_CACHE_DIR" in out
+        assert "MXNET_COMPILE_WATCH" in out
         assert out.count("MXNET_") >= 50
 
     def test_parse_error_is_a_finding_not_a_crash(self, tmp_path):

@@ -58,11 +58,12 @@ def _router(n=2, **kw):
 def _reference(prompt, n):
     """Greedy generation by one FULL-sequence forward at each length —
     the oracle a failed-over stream must still reproduce."""
+    import jax
     import jax.numpy as jnp
     toks = [int(t) for t in prompt]
+    prefill = jax.jit(_MODEL.prefill)    # one program per length
     for _ in range(n):
-        logits, _, _ = _MODEL.prefill(
-            _PARAMS, jnp.asarray([toks], jnp.int32))
+        logits, _, _ = prefill(_PARAMS, jnp.asarray([toks], jnp.int32))
         toks.append(int(np.argmax(np.asarray(logits)[0, len(toks) - 1])))
     return toks[len(prompt):]
 
