@@ -146,7 +146,9 @@ declare("MXNET_TELEMETRY_LIVE_BUFFERS", "int", 1,
         "Keep the last N flushed record buffers live for /metrics "
         "scrapes.", _G)
 declare("MXNET_TRACE", "bool", False,
-        "Arm the always-on request/step tracer.", _G)
+        "Arm the Chrome-JSON trace ring (tracing.export). The program's "
+        "mx: spans need no variable: they are in any jax.profiler "
+        "trace.", _G)
 declare("MXNET_TRACE_FILE", "path", "",
         "Perfetto-JSON sink the tracer exports to at exit/dump.", _G)
 declare("MXNET_TRACE_RING", "int", 200000,

@@ -358,19 +358,12 @@ class FusedStepExecutor(_FusedCore):
                             guard, inject, scale_loss)
         if poisons is None:
             poisons = self._zero_poisons(len(fns))
-        from . import telemetry, tracing
-        t_tr = tracing.now() if tracing._tracer is not None else None
+        from . import tracing
         # this is THE "optimizer" span of a fused-mode Module step —
         # module.update()'s fused branch opens none of its own
-        with telemetry.span("optimizer"):
+        with tracing.span("fused_step.dispatch", phase="optimizer"):
             outs, new_aux, new_ws, new_sts, mask = fn(
                 weights, states, others, aux, rngs, scalars, poisons)
-        if t_tr is not None:
-            # the trace names the fused dispatch itself (the phase
-            # span above only says "optimizer"): one X event per step
-            # on the training thread's track
-            tracing.add("fused_step:dispatch", "dispatch", t_tr,
-                        tracing.now() - t_tr, args=tracing.context())
         self.dispatch_count += 1
         _count("fused_step_dispatches")
         ex._store_outputs(outs)
@@ -623,8 +616,8 @@ class FusedUpdater(_FusedCore):
                                  mode=mode)
         if poisons is None:
             poisons = self._zero_poisons(len(fns))
-        from . import telemetry
-        with telemetry.span("optimizer"):
+        from . import telemetry, tracing
+        with tracing.span("fused_step.dispatch", phase="optimizer"):
             new_ws, new_sts, mask = fn(grads, weights, states, scalars,
                                        poisons)
         self.dispatch_count += 1
@@ -791,8 +784,8 @@ class FusedUpdater(_FusedCore):
                             inject, tuple(indices))
         if poisons is None:
             poisons = self._zero_poisons(len(fns))
-        from . import telemetry
-        with telemetry.span("optimizer"):
+        from . import tracing
+        with tracing.span("fused_step.dispatch", phase="optimizer"):
             new_ws, new_sts, mask = fn(grads, weights, states, scalars,
                                        poisons)
         self.dispatch_count += 1

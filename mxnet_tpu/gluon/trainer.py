@@ -185,33 +185,35 @@ class Trainer:
         step spans from the previous ``step``), with the cross-worker
         reduce under the ``sync`` phase and the parameter update under
         ``optimizer`` (README "Observability")."""
-        from .. import telemetry
+        from .. import telemetry, tracing
         telemetry.maybe_start(meta={"source": "gluon.Trainer"})
-        self._step_rescale(batch_size)
-        if not self._kv_initialized:
-            self._init_kvstore()
-        if self._kvstore is not None:
-            with telemetry.span("sync"):
-                self.allreduce_grads()
-        with telemetry.span("optimizer"):
-            self._apply_updates(ignore_stale_grad)
+        with tracing.span("trainer.step"):
+            self._step_rescale(batch_size)
+            if not self._kv_initialized:
+                self._init_kvstore()
+            if self._kvstore is not None:
+                with tracing.span("step.sync", phase="sync"):
+                    self.allreduce_grads()
+            with tracing.span("step.optimizer", phase="optimizer"):
+                self._apply_updates(ignore_stale_grad)
         telemetry.step_tick(samples=batch_size)
 
     def update(self, batch_size, ignore_stale_grad=False):
         """Update only — the caller already ran allreduce_grads
         (reference: trainer.py:363)."""
-        from .. import telemetry
+        from .. import telemetry, tracing
         telemetry.maybe_start(meta={"source": "gluon.Trainer"})
-        if not self._kv_initialized:
-            self._init_kvstore()
-        if self._kvstore and self._update_on_kvstore:
-            raise AssertionError(
-                'update() when parameters are updated on kvstore is '
-                'not supported. Try setting `update_on_kvstore` to '
-                'False when creating trainer.')
-        self._step_rescale(batch_size)
-        with telemetry.span("optimizer"):
-            self._apply_updates(ignore_stale_grad)
+        with tracing.span("trainer.step"):
+            if not self._kv_initialized:
+                self._init_kvstore()
+            if self._kvstore and self._update_on_kvstore:
+                raise AssertionError(
+                    'update() when parameters are updated on kvstore '
+                    'is not supported. Try setting `update_on_kvstore` '
+                    'to False when creating trainer.')
+            self._step_rescale(batch_size)
+            with tracing.span("step.optimizer", phase="optimizer"):
+                self._apply_updates(ignore_stale_grad)
         telemetry.step_tick(samples=batch_size)
 
     def _sync_rescale(self, scale):

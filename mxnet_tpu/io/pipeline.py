@@ -53,7 +53,7 @@ import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
-from .. import envs
+from .. import envs, tracing
 from .io import DataBatch, DataIter
 
 __all__ = ["AsyncInputPipeline", "data_workers", "pipeline_enabled",
@@ -115,8 +115,6 @@ def _put_one(nd_arr, target, name):
     transfer-completion barrier the consumer would otherwise pay
     inside its first op; either way the batch's bytes and the wait are
     accounted under h2d."""
-    import time
-
     import jax
 
     from .. import telemetry
@@ -131,25 +129,20 @@ def _put_one(nd_arr, target, name):
         getattr(target, "device_kind", None) is not None
         and getattr(data, "devices", None) is not None
         and data.devices() == {target})
-    from .. import tracing
-    t0 = time.perf_counter()
-    out = nd_arr
-    if not resident:
-        data = jax.device_put(data, target)
-        out = NDArray(data, ctx=nd_arr._ctx)
-    data.block_until_ready()
-    dur = time.perf_counter() - t0
     nbytes = int(getattr(data, "nbytes", 0) or 0)
-    telemetry.h2d(name, nbytes, dur)
-    if tracing._tracer is not None:
-        # the placer runs AHEAD of consumption by design; the context
-        # token parents the transfer to the step that was open while
-        # it ran — explicit args, not thread identity (this thread is
-        # off the accounting thread on purpose)
-        args = tracing.context() or {}
-        args["bytes"] = nbytes
-        tracing.add("h2d:%s" % name, "io", t0, dur,
-                    tid=tracing.track("io:h2d"), args=args)
+    out = nd_arr
+    # the placer runs AHEAD of consumption by design; while the ring is
+    # on, the context token parents the transfer to the step that was
+    # open while it ran — explicit args, not thread identity (this
+    # thread is off the accounting thread on purpose)
+    with tracing.span("pipeline.h2d", "io", tid=tracing.track("io:h2d"),
+                      bytes=nbytes, name=name,
+                      **(tracing.context() or {})) as sp:
+        if not resident:
+            data = jax.device_put(data, target)
+            out = NDArray(data, ctx=nd_arr._ctx)
+        data.block_until_ready()
+    telemetry.h2d(name, nbytes, sp.t1 - sp.t0)
     return out
 
 
@@ -357,37 +350,27 @@ class AsyncInputPipeline(DataIter):
         """Stage-1 driver: pull work from the source IN ORDER (the
         source itself is never touched concurrently), fan decode out to
         the pool, and emit futures/batches in submission order."""
-        from .. import tracing
         stop = self._stop
         src = self._source
         try:
             while not stop.is_set():
-                tracing_on = tracing._tracer is not None
                 try:
+                    # the context is captured HERE (the scheduling
+                    # thread) and handed to the pool worker as an
+                    # explicit token: the decode span is parented to the
+                    # step that triggered the fetch, never to the worker
                     if self._pool is not None:
-                        raw = src.next_raw()
-                        if tracing_on:
-                            # context captured HERE (the scheduling
-                            # thread) and handed to the pool worker as
-                            # an explicit token — the decode span is
-                            # parented to the step that triggered the
-                            # fetch, never to the worker thread
-                            item = self._pool.submit(
-                                self._decode_traced, raw,
-                                tracing.context())
-                        else:
-                            item = self._pool.submit(src.decode_raw,
-                                                     raw)
+                        item = self._pool.submit(
+                            self._decode, src.next_raw(),
+                            tracing.context())
                     elif self._split:
                         # one worker: still use the split so randomness
                         # is drawn serially (bit-identical to eager)
-                        if tracing_on:
-                            item = self._decode_traced(
-                                src.next_raw(), tracing.context())
-                        else:
-                            item = src.decode_raw(src.next_raw())
+                        item = self._decode(src.next_raw(),
+                                            tracing.context())
                     else:
-                        item = src.next()
+                        with self._decode_span(tracing.context()):
+                            item = src.next()
                 except StopIteration:
                     break
                 except Exception as exc:        # surface in consumer
@@ -398,17 +381,16 @@ class AsyncInputPipeline(DataIter):
         finally:
             self._stop_aware_put(self._decode_q, _SENTINEL)
 
-    def _decode_traced(self, raw, ctx):
-        """Decode one work item with its trace span, parented to the
-        triggering step via the explicitly-propagated ``ctx`` token."""
-        import time as _time
+    @staticmethod
+    def _decode_span(ctx):
+        return tracing.span("pipeline.decode", "io",
+                            tid=tracing.track("io:decode"), **(ctx or {}))
 
-        from .. import tracing
-        t0 = _time.perf_counter()
-        out = self._source.decode_raw(raw)
-        tracing.add("decode", "io", t0, _time.perf_counter() - t0,
-                    tid=tracing.track("io:decode"), args=ctx)
-        return out
+    def _decode(self, raw, ctx):
+        """Decode one work item (pool or scheduler thread) under its
+        span, parented to the triggering step by the ``ctx`` token."""
+        with self._decode_span(ctx):
+            return self._source.decode_raw(raw)
 
     def _placer(self):
         """Stage-2 driver: resolve decode results in order, commit them
@@ -507,8 +489,7 @@ class AsyncInputPipeline(DataIter):
             # must measure only true queue-dry time
             item = self._ready_q.get_nowait()
         except queue.Empty:
-            from .. import telemetry
-            with telemetry.span("data_wait"):
+            with tracing.span("pipeline.wait", phase="data_wait"):
                 item = self._blocking_get()
         if item is _SENTINEL:
             self._exhausted = True

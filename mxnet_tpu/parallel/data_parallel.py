@@ -733,7 +733,7 @@ class DistributedTrainer:
         loss value lazily. In a multi-process job each process feeds
         its OWN rank's slice of the global batch."""
         from .. import random as _random
-        from .. import telemetry
+        from .. import telemetry, tracing
         from ..fused_step import pack_step_scalars
         from ..ndarray import NDArray
         from . import grad_sync, multihost
@@ -745,19 +745,24 @@ class DistributedTrainer:
             # ensure params are materialized
             _ = self._net(data)
             self._build(data, label)
-        data_v = _put_unless_placed(data._data, self._batch_sharding)
-        label_v = _put_unless_placed(label._data, self._batch_sharding)
-        scalars = pack_step_scalars(self._opt,
-                                    list(range(len(self._roster))))
-        if self._mh:
-            loss, new_ws, new_sts, new_aux = self._mh_step(
-                data_v, label_v, scalars)
-        else:
-            with telemetry.span("compute"):
-                loss, new_ws, new_sts, new_aux = self._step_fn(
-                    tuple(self._param_vals), tuple(self._state_vals),
-                    tuple(self._aux_vals), data_v, label_v,
-                    _random.new_key(), scalars, self._poisons_zero)
+        with tracing.span("trainer.step"):
+            data_v = _put_unless_placed(data._data,
+                                        self._batch_sharding)
+            label_v = _put_unless_placed(label._data,
+                                         self._batch_sharding)
+            scalars = pack_step_scalars(
+                self._opt, list(range(len(self._roster))))
+            if self._mh:
+                loss, new_ws, new_sts, new_aux = self._mh_step(
+                    data_v, label_v, scalars)
+            else:
+                with tracing.span("step.compute", phase="compute"):
+                    loss, new_ws, new_sts, new_aux = self._step_fn(
+                        tuple(self._param_vals),
+                        tuple(self._state_vals),
+                        tuple(self._aux_vals), data_v, label_v,
+                        _random.new_key(), scalars,
+                        self._poisons_zero)
         self._param_vals = list(new_ws)
         self._state_vals = list(new_sts)
         self._aux_vals = list(new_aux)
@@ -786,11 +791,10 @@ class DistributedTrainer:
         the flat mesh's reduction grouping, bit for bit) → local
         bucketed update program. Loss is the global mean (the stacked
         per-device means ride the same exchange)."""
-        import time as _time
         import numpy as _np
         import jax.numpy as jnp
         from .. import random as _random
-        from .. import telemetry
+        from .. import telemetry, tracing
         from . import multihost
         from .mesh import link_split
         world = max(int(getattr(self, "_mh_world", 1)), 1)
@@ -799,37 +803,34 @@ class DistributedTrainer:
         # divisor that makes each device's gradient rows the flat
         # mesh's exact psum leaves
         n_rows = _np.float32(int(data_v.shape[0]) * world)
-        with telemetry.span("compute"):
+        with tracing.span("step.compute", phase="compute"):
             losses, grads, new_aux = self._mh_grad_fn(
                 tuple(self._param_vals), tuple(self._aux_vals),
                 data_v, label_v, _random.new_key(), n_rows)
-        with telemetry.span("sync"):
-            t0 = _time.perf_counter()
+        with tracing.span("step.sync", phase="sync") as sync:
             stacks = [_np.asarray(losses)] + [_np.asarray(g)
                                               for g in grads]
             folded = multihost.cross_host_sum("grad", stacks)
-            dt = _time.perf_counter() - t0
-            # per-device rows are local_sum/global_rows, so the fold
-            # IS the global mean
-            loss = folded[0]
-            g_tot = folded[1:]
-            if telemetry.enabled():
-                payload = sum(int(s.nbytes) for s in stacks[1:])
-                # the exchange itself: every peer's payload crossed
-                # the host boundary once (pure dcn); the local
-                # stacked fold is host arithmetic, not a link
-                telemetry.comm("grad_sync", "dcn_exchange",
-                               nbytes=payload * (world - 1),
-                               seconds=dt)
-                audit = self._mesh_global
-                if audit is not None:
-                    try:
-                        ici, dcn = link_split(audit, "dp",
-                                              2 * payload)
-                        telemetry.comm_links("grad_sync", ici, dcn)
-                    except ValueError:
-                        pass
-        with telemetry.span("optimizer"):
+        # per-device rows are local_sum/global_rows, so the fold IS
+        # the global mean
+        loss = folded[0]
+        g_tot = folded[1:]
+        if telemetry.enabled():
+            payload = sum(int(s.nbytes) for s in stacks[1:])
+            # the exchange itself: every peer's payload crossed the
+            # host boundary once (pure dcn); the local stacked fold is
+            # host arithmetic, not a link
+            telemetry.comm("grad_sync", "dcn_exchange",
+                           nbytes=payload * (world - 1),
+                           seconds=sync.t1 - sync.t0)
+            audit = self._mesh_global
+            if audit is not None:
+                try:
+                    ici, dcn = link_split(audit, "dp", 2 * payload)
+                    telemetry.comm_links("grad_sync", ici, dcn)
+                except ValueError:
+                    pass
+        with tracing.span("step.optimizer", phase="optimizer"):
             new_ws, new_sts = self._mh_apply_fn(
                 tuple(jnp.asarray(g) for g in g_tot),
                 tuple(self._param_vals), tuple(self._state_vals),

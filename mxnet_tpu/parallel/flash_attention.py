@@ -362,6 +362,20 @@ def _row_spec(block, index_map):
     return pl.BlockSpec((None, 1, block), index_map)
 
 
+def _kernel_name(kernel, q, k):
+    """``mx_<kernel>.bh<B*H>.q<Tq>.k<Tk>.d<D>.<cache dtype>``: the name
+    a ``pallas_call`` carries into the program. XLA names the Mosaic
+    custom call after it (``%mx_flash_fwd.bh32.q512.k512.d128.bfloat16.1
+    = ... custom-call``; under differentiation with ``jvp_`` or
+    ``transpose_jvp_`` in front) and a profile names an operation's
+    event by that line, so a reader of the trace finds the kernel and
+    the call's static shapes (padded lengths) in the event's name."""
+    import jax.numpy as jnp
+    return "mx_%s.bh%d.q%d.k%d.d%d.%s" % (
+        kernel, q.shape[0], q.shape[1], k.shape[1], q.shape[2],
+        jnp.dtype(k.dtype).name)
+
+
 def _pallas_forward(q, k, v, seg, scale, causal, block_q, block_k,
                     kv_len, interpret):
     """Padded/flattened forward; returns (out, lse) at PADDED length,
@@ -405,6 +419,7 @@ def _pallas_forward(q, k, v, seg, scale, causal, block_q, block_k,
                    jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32)],
         scratch_shapes=scratch,
         interpret=interpret,
+        name=_kernel_name("flash_fwd", q, k),
     )(*inputs)
     return out, lse
 
@@ -453,6 +468,7 @@ def _pallas_backward(q, k, v, do, o, lse, seg, scale, causal, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        name=_kernel_name("flash_bwd_dkdv", q, k),
     )(*inputs)
 
     in_specs = [
@@ -479,6 +495,7 @@ def _pallas_backward(q, k, v, do, o, lse, seg, scale, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name=_kernel_name("flash_bwd_dq", q, k),
     )(*inputs)
     return dq, dk, dv
 
@@ -680,6 +697,8 @@ def _pallas_decode(q, k, v, lengths, scale, block_k, interpret,
                             pltpu.VMEM((1, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
         interpret=interpret,
+        name=_kernel_name("flash_decode_q8" if quant else "flash_decode",
+                          q, k),
     )(*((lengths, q, k, v)
         + ((k_scale[:, None, :], v_scale[:, None, :]) if quant
            else ())))

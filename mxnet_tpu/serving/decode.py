@@ -141,7 +141,7 @@ class DecodeRequest:
     __slots__ = ("prompt", "max_new", "priority", "deadline", "eos_id",
                  "request_id", "t_submit", "pages", "generated",
                  "params", "state", "_cancelled", "_stream", "_event",
-                 "_error", "_last_emit", "_t_first", "trace_args",
+                 "_error", "_last_emit", "trace_args",
                  "_t_trace", "pending", "pending_pos", "prefix_cached")
 
     def __init__(self, prompt, max_new, priority, deadline, eos_id,
@@ -152,7 +152,7 @@ class DecodeRequest:
         self.deadline = deadline
         self.eos_id = eos_id
         self.request_id = request_id
-        self.t_submit = time.monotonic()
+        self.t_submit = tracing.now()
         self.pages = []
         self.generated = []
         self.params = None            # _ParamsVersion, set at prefill
@@ -162,11 +162,10 @@ class DecodeRequest:
         self._stream = _queue_mod.Queue(maxsize=max_new + 2)
         self._event = threading.Event()
         self._error = None
-        self._last_emit = None
-        self._t_first = None
+        self._last_emit = None    # last token's stamp; None before the first
         self.trace_args = None    # span args while traced (carries an
                                   # adopted router request_id, if any)
-        self._t_trace = None      # trace-clock submit stamp
+        self._t_trace = None      # where the open ring phase began
         # prefix-cache suffix feed: tokens still to run through the
         # decode-step program (their outputs are discarded until the
         # last one, which IS the first generated token), and the
@@ -532,13 +531,15 @@ class DecodeServer:
                        "tokens_out": 0, "queue_peak": 0, "swaps": 0,
                        "prefix_hits": 0, "prefix_misses": 0,
                        "prefix_hit_tokens": 0, "cow_splits": 0,
-                       "cow_degraded": 0, "cross_preempts": 0}
+                       "cow_degraded": 0, "cross_preempts": 0,
+                       "admitted": 0, "queue_wait_s": 0.0,
+                       "prefill_s": 0.0}
         self._shed_by_priority = {}
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
         self._intervals = deque(maxlen=ring)    # inter-token ms
         self._ttft = deque(maxlen=ring)         # submit -> first token
         self._steps_since_record = 0
-        self._t0 = time.perf_counter()
+        self._t0 = tracing.now()
         self._stopping = False
         self._drain = True
         self._closed = False
@@ -685,7 +686,7 @@ class DecodeServer:
         if self._closed:
             raise ServerClosedError("DecodeServer already stopped")
         self._started = True
-        self._t0 = time.perf_counter()
+        self._t0 = tracing.now()
         self._thread = threading.Thread(
             target=self._loop, name="mxnet-decode-scheduler",
             daemon=True)
@@ -862,7 +863,7 @@ class DecodeServer:
                     joined = adopted["request_id"]
             args["request_id"] = joined
             req.trace_args = args
-            req._t_trace = tracing.now()
+            req._t_trace = req.t_submit
         victim = None
         shed = stopping = False
         with self._cond:
@@ -988,7 +989,8 @@ class DecodeServer:
                 while not self._stopping and (self._warming or
                                               (not self._queue
                                                and not self._active)):
-                    self._cond.wait(1.0)
+                    with tracing.span("decode.wait"):
+                        self._cond.wait(1.0)
                 if self._stopping and (not self._drain
                                        or (not self._queue
                                            and not self._active)):
@@ -996,7 +998,7 @@ class DecodeServer:
             if not self._tick():
                 # head-of-line blocked (pool pressure) or a reap-only
                 # pass: don't spin hot
-                with self._cond:
+                with self._cond, tracing.span("decode.wait"):
                     self._cond.wait(0.002)
 
     def _tick(self):
@@ -1009,30 +1011,37 @@ class DecodeServer:
                 return False
             asks = self._preempt_asks
             self._preempt_asks = 0
-        # service co-tenant give-back asks FIRST: preempting one of our
-        # own active requests frees pages a higher-pool-priority model
-        # is starving for (its alloc retries on its next tick)
-        for _ in range(asks):
-            victim = self._pick_victim(below=self._levels)
-            if victim is None:
-                break
-            self._preempt(victim)
-        self._reap()
-        did = self._admit_one()
-        did = self._decode_once() or did
-        if metering.enabled():
-            # integrate KV page holdings at the step boundary: each
-            # active request's pages x dt accrue to its tenant AND to
-            # the meter's pool total in one dual-entry pass
-            with self._cond:
-                entries = [(metering.inner_key(self, r.request_id),
-                            len(r.pages)) for r in self._active]
-            metering.request_pages(entries, time.monotonic())
-        if did:
-            self._steps_since_record += 1
-            if self._steps_since_record >= self._record_every:
-                self._steps_since_record = 0
-                self._emit_record()
+            active, queued = len(self._active), len(self._queue)
+        with tracing.span("decode.tick", active=active, queued=queued):
+            with tracing.span("decode.reap"):
+                # service co-tenant give-back asks FIRST: preempting
+                # one of our own active requests frees pages a
+                # higher-pool-priority model is starving for (its
+                # alloc retries on its next tick)
+                for _ in range(asks):
+                    victim = self._pick_victim(below=self._levels)
+                    if victim is None:
+                        break
+                    self._preempt(victim)
+                self._reap()
+            did = self._admit_one()
+            did = self._decode_once() or did
+            with tracing.span("decode.record"):
+                if metering.enabled():
+                    # integrate KV page holdings at the step boundary:
+                    # each active request's pages x dt accrue to its
+                    # tenant AND to the meter's pool total in one
+                    # dual-entry pass
+                    with self._cond:
+                        entries = [
+                            (metering.inner_key(self, r.request_id),
+                             len(r.pages)) for r in self._active]
+                    metering.request_pages(entries, time.monotonic())
+                if did:
+                    self._steps_since_record += 1
+                    if self._steps_since_record >= self._record_every:
+                        self._steps_since_record = 0
+                        self._emit_record()
         return did
 
     def _reap(self):
@@ -1059,7 +1068,7 @@ class DecodeServer:
                     "request %s deadline passed after %.1f ms "
                     "(%d/%d tokens generated)"
                     % (r.request_id,
-                       (now - r.t_submit) * 1e3,
+                       (tracing.now() - r.t_submit) * 1e3,
                        len(r.generated), r.max_new)))
 
     def _finish(self, req, error, cancelled=False):
@@ -1144,7 +1153,15 @@ class DecodeServer:
             ver = self._params    # pinned BEFORE the index lookup —
                                   # a racing swap must not mismatch
                                   # the namespace and the weights
+        with tracing.span("decode.admit", request_id=req.request_id,
+                          prompt_len=len(req.prompt)) as sp:
+            return self._admit(req, ver, sp)
+
+    def _admit(self, req, ver, sp):
+        """The head of the queue, under its ``decode.admit`` span
+        ``sp``: pages (shared prefix pages first), activation, prefill."""
         P = len(req.prompt)
+        rung = self._seq_ladder.bucket_for(P)
         shared, cached = [], 0
         if self._prefix_on:
             shared, cached = self._pool.prefix_lookup(
@@ -1162,6 +1179,8 @@ class DecodeServer:
                 metering.request_prefix(
                     metering.inner_key(self, req.request_id), cached,
                     cached * self._pool.token_bytes)
+        sp.set(rung=rung, cached=cached,
+               queue_wait_us=round((sp.t0 - req.t_submit) * 1e6, 1))
         need = self._pool.pages_for(P + 1) - len(shared)
         pages = self._pool.alloc(need, owner=self._owner)
         while pages is None:
@@ -1189,6 +1208,8 @@ class DecodeServer:
                 req.params = ver
                 req.prefix_cached = cached
                 self._active.append(req)
+                self._stats["admitted"] += 1
+                self._stats["queue_wait_s"] += sp.t0 - req.t_submit
                 pages_back = None
         if pages_back is not None:
             self._pool.free(pages_back)
@@ -1206,58 +1227,55 @@ class DecodeServer:
             req.pending_pos = start
             return True
         # run the prefill program at the prompt's rung
-        t_pre = tracing.now() if req.trace_args is not None else None
-        rung = self._seq_ladder.bucket_for(P)
         tokens = _np.zeros((1, rung), _np.int32)
         tokens[0, :P] = req.prompt
         pt = _np.zeros((self._max_pages,), _np.int32)
         pt[:len(req.pages)] = req.pages
-        try:
-            with self._pool.step_lock:
-                out = self._prefill_progs[rung](
-                    req.params.tree, tokens, _np.int32(P), pt,
-                    *self._pool_args())
-                token = self._adopt_pool(out)[0]
-        except Exception as exc:       # noqa: BLE001 — model errors
-            with self._cond:           # belong to the request
-                if req in self._active:
-                    self._active.remove(req)
-            self._finish(req, exc)
-            return True
-        if metering.enabled():
-            # a prefill batch is this one request: the whole program
-            # cost (compile-watch cost_analysis) is its share
-            cost = compile_watch.last_dispatch(
-                "%s:prefill:s%d" % (self._site, rung))
-            if cost is not None:
-                metering.request_flops(
-                    metering.inner_key(self, req.request_id),
-                    cost["flops"], cost["bytes"])
-        if self._prefix_on:
-            # the prefill just wrote K/V for every prompt position:
-            # register the full pages so the NEXT same-prefix prompt
-            # shares them (the index retains its own reference)
-            self._pool.prefix_insert(self._namespace(ver), req.prompt,
-                                     req.pages)
-        tok = int(token)
-        now = time.perf_counter()
-        req._t_first = now
-        req._last_emit = now
-        if req.trace_args is not None and t_pre is not None:
+        with tracing.span("decode.prefill", rung=rung) as pre:
+            try:
+                with self._pool.step_lock:
+                    out = self._prefill_progs[rung](
+                        req.params.tree, tokens, _np.int32(P), pt,
+                        *self._pool_args())
+                    token = self._adopt_pool(out)[0]
+            except Exception as exc:   # noqa: BLE001 — model errors
+                with self._cond:       # belong to the request
+                    if req in self._active:
+                        self._active.remove(req)
+                self._finish(req, exc)
+                return True
+            if metering.enabled():
+                # a prefill batch is this one request: the whole
+                # program cost (compile-watch cost_analysis) is its
+                # share
+                cost = compile_watch.last_dispatch(
+                    "%s:prefill:s%d" % (self._site, rung))
+                if cost is not None:
+                    metering.request_flops(
+                        metering.inner_key(self, req.request_id),
+                        cost["flops"], cost["bytes"])
+            if self._prefix_on:
+                # the prefill just wrote K/V for every prompt position:
+                # register the full pages so the NEXT same-prefix
+                # prompt shares them (the index retains its own
+                # reference)
+                self._pool.prefix_insert(self._namespace(ver),
+                                         req.prompt, req.pages)
+            tok = int(token)
+        req._last_emit = pre.t1
+        if req.trace_args is not None:
             rtid = tracing.track("req %s" % req.trace_args["request_id"])
-            if req._t_trace is not None:
-                tracing.add("queue", "decode", req._t_trace,
-                            t_pre - req._t_trace, tid=rtid,
-                            args=req.trace_args)
-            tracing.add("prefill", "decode", t_pre,
-                        tracing.now() - t_pre, tid=rtid,
-                        args=dict(req.trace_args, rung=rung))
-            req._t_trace = tracing.now()
+            tracing.add("queue", "decode", req._t_trace,
+                        pre.t0 - req._t_trace, tid=rtid,
+                        args=req.trace_args)
+            tracing.add("prefill", "decode", pre.t0, pre.t1 - pre.t0,
+                        tid=rtid, args=dict(req.trace_args, rung=rung))
+            req._t_trace = pre.t1
         with self._cond:
             self._stats["prefill_steps"] += 1
+            self._stats["prefill_s"] += pre.t1 - pre.t0
             self._stats["tokens_out"] += 1
-            self._ttft.append(
-                (time.monotonic() - req.t_submit) * 1e3)
+            self._ttft.append((pre.t1 - req.t_submit) * 1e3)
         req.generated.append(tok)
         req._push(tok)
         if len(req.generated) >= req.max_new or \
@@ -1389,7 +1407,8 @@ class DecodeServer:
             with self._cond:
                 self._stats["decode_faults"] += 1
             return True
-        rows = self._ensure_pages(rows)
+        with tracing.span("decode.pages"):
+            rows = self._ensure_pages(rows)
         if not rows:
             return True
         groups = {}
@@ -1401,26 +1420,26 @@ class DecodeServer:
 
     def _decode_group(self, ver, rows):
         D, M = self._window, self._max_pages
-        tokens = _np.zeros((D,), _np.int32)
-        positions = _np.zeros((D,), _np.int32)
-        pts = _np.zeros((D, M), _np.int32)
-        for i, r in enumerate(rows):
-            if r.pending:
-                # prefix-cache suffix feed: the next un-cached token
-                # runs through the same step program at its own
-                # absolute position
-                tokens[i] = r.pending[0]
-                positions[i] = r.pending_pos
-            else:
-                tokens[i] = r.generated[-1]
-                positions[i] = len(r.prompt) + len(r.generated) - 1
-            pts[i, :len(r.pages)] = r.pages
+        with tracing.span("decode.build"):
+            tokens = _np.zeros((D,), _np.int32)
+            positions = _np.zeros((D,), _np.int32)
+            pts = _np.zeros((D, M), _np.int32)
+            for i, r in enumerate(rows):
+                if r.pending:
+                    # prefix-cache suffix feed: the next un-cached
+                    # token runs through the same step program at its
+                    # own absolute position
+                    tokens[i] = r.pending[0]
+                    positions[i] = r.pending_pos
+                else:
+                    tokens[i] = r.generated[-1]
+                    positions[i] = len(r.prompt) + len(r.generated) - 1
+                pts[i, :len(r.pages)] = r.pages
         try:
-            with self._pool.step_lock:
-                out = self._decode_prog(
+            with tracing.span("decode.dispatch"), self._pool.step_lock:
+                toks = self._adopt_pool(self._decode_prog(
                     ver.tree, tokens, positions, pts,
-                    *self._pool_args())
-                toks = self._adopt_pool(out)[0]
+                    *self._pool_args()))[0]
         except Exception as exc:       # noqa: BLE001 — model errors
             with self._cond:           # belong to the batch's requests
                 for r in rows:
@@ -1429,56 +1448,64 @@ class DecodeServer:
             for r in rows:
                 self._finish(r, exc)
             return
-        toks = _np.asarray(toks)
-        if metering.enabled():
-            # the dispatched step program ran ONE batch over these
-            # rows: each request is billed its share of the program's
-            # cost_analysis FLOPs (equal rows, equal shares)
-            cost = compile_watch.last_dispatch("%s:step" % self._site)
-            if cost is not None:
-                share = 1.0 / len(rows)
-                for r in rows:
-                    metering.request_flops(
-                        metering.inner_key(self, r.request_id),
-                        cost["flops"] * share, cost["bytes"] * share)
-        now = time.perf_counter()
-        emitting = []
-        for i, r in enumerate(rows):
-            if r.pending:
-                r.pending.popleft()
-                r.pending_pos += 1
+        with tracing.span("decode.readback") as back:
+            # the last reference to the step's device token array goes
+            # here, so that freeing it (0.3 ms on the chip) is timed
+            toks = _np.asarray(toks)
+        now = back.t1
+        with tracing.span("decode.emit", rows=len(rows)) as emit:
+            if metering.enabled():
+                # the dispatched step program ran ONE batch over these
+                # rows: each request is billed its share of the
+                # program's cost_analysis FLOPs (equal rows, equal
+                # shares)
+                cost = compile_watch.last_dispatch(
+                    "%s:step" % self._site)
+                if cost is not None:
+                    share = 1.0 / len(rows)
+                    for r in rows:
+                        metering.request_flops(
+                            metering.inner_key(self, r.request_id),
+                            cost["flops"] * share,
+                            cost["bytes"] * share)
+            emitting = []
+            for i, r in enumerate(rows):
                 if r.pending:
-                    continue   # mid-suffix: the output is discarded
-                r.pending = None
-            emitting.append((i, r))
-        finished = []
-        with self._cond:
-            self._stats["decode_steps"] += 1
-            for i, r in emitting:
-                self._stats["tokens_out"] += 1
-                if r._last_emit is not None:
-                    self._intervals.append((now - r._last_emit) * 1e3)
-                elif r._t_first is None:
-                    # a prefix-hit row's FIRST token lands here, not
-                    # in a prefill — this is its time-to-first-token
-                    r._t_first = now
-                    self._ttft.append(
-                        (time.monotonic() - r.t_submit) * 1e3)
-                r._last_emit = now
-        for i, r in emitting:
-            tok = int(toks[i])
-            r.generated.append(tok)
-            r._push(tok)
-            if len(r.generated) >= r.max_new or \
-                    (r.eos_id is not None and tok == r.eos_id):
-                finished.append(r)
-        if finished:
+                    r.pending.popleft()
+                    r.pending_pos += 1
+                    if r.pending:
+                        continue   # mid-suffix: the output is discarded
+                    r.pending = None
+                emitting.append((i, r))
+            emit.set(emitted=len(emitting))
+            finished = []
             with self._cond:
+                self._stats["decode_steps"] += 1
+                for i, r in emitting:
+                    self._stats["tokens_out"] += 1
+                    if r._last_emit is not None:
+                        self._intervals.append(
+                            (now - r._last_emit) * 1e3)
+                    else:
+                        # a prefix-hit row's FIRST token lands here,
+                        # not in a prefill — this is its
+                        # time-to-first-token
+                        self._ttft.append((now - r.t_submit) * 1e3)
+                    r._last_emit = now
+            for i, r in emitting:
+                tok = int(toks[i])
+                r.generated.append(tok)
+                r._push(tok)
+                if len(r.generated) >= r.max_new or \
+                        (r.eos_id is not None and tok == r.eos_id):
+                    finished.append(r)
+            if finished:
+                with self._cond:
+                    for r in finished:
+                        if r in self._active:
+                            self._active.remove(r)
                 for r in finished:
-                    if r in self._active:
-                        self._active.remove(r)
-            for r in finished:
-                self._finish(r, None)
+                    self._finish(r, None)
 
     # -- stats & telemetry -------------------------------------------------
     def stats(self):
@@ -1488,7 +1515,7 @@ class DecodeServer:
         swap/version state — the ``decode`` telemetry record, the
         diagnose Decode table, and the /metrics gauges all render
         this."""
-        elapsed = max(time.perf_counter() - self._t0, 1e-9)
+        elapsed = max(tracing.now() - self._t0, 1e-9)
         with self._cond:
             s = dict(self._stats)
             intervals = list(self._intervals)
@@ -1524,6 +1551,9 @@ class DecodeServer:
             if steps else None,
             "tokens_out": s["tokens_out"],
             "tokens_per_sec": round(s["tokens_out"] / elapsed, 3),
+            "admitted": s["admitted"],
+            "queue_wait_s": s["queue_wait_s"],
+            "prefill_s": s["prefill_s"],
             "kv": self._pool.stats(),
             "swaps": s["swaps"],
             "weight_version": version,

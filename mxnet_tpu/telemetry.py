@@ -514,7 +514,8 @@ class _Span:
         self.run = run
         self.phase = phase
 
-    def __enter__(self):
+    def claim(self):
+        """Take the phase for this span, if it may count."""
         run = self.run
         with _lock:
             if threading.get_ident() != run._thread:
@@ -530,22 +531,31 @@ class _Span:
             else:
                 run.open_phases.add(self.phase)
                 self.active = True
+        return self
+
+    def __enter__(self):
+        self.claim()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *a):
+        self.end(self.t0, time.perf_counter() - self.t0)
+        return False
+
+    def end(self, t0, dur, tid=None, args=None):
+        """Close the span over ``dur`` seconds from ``t0``: its event
+        in the trace ring, and the phase's account if this span took
+        it."""
         if tracing._tracer is not None:
             # the trace records EVERY span — including the nested and
             # off-accounting-thread ones the exclusive-phase accounting
             # (rightly) ignores: nesting shows up as time containment
             # on the emitting thread's own track. steps + 1 = the step
             # this span will close under, in begin/end AND tick mode
-            tracing.add(self.phase, "phase", self.t0,
-                        time.perf_counter() - self.t0,
-                        args={"step": self.run.steps + 1})
+            tracing.add(self.phase, "phase", t0, dur, tid=tid,
+                        args=dict(args or (), step=self.run.steps + 1))
         if not self.active:
-            return False
-        dur = time.perf_counter() - self.t0
+            return
         run = self.run
         with _lock:
             run.open_phases.discard(self.phase)
@@ -562,7 +572,6 @@ class _Span:
             profiler._emit("telemetry.%s" % self.phase, "telemetry", "X",
                            ts=profiler._now_us() - int(dur_us),
                            dur=int(dur_us))
-        return False
 
 
 def span(phase):
@@ -574,6 +583,17 @@ def span(phase):
     if run is None:
         return _NULL
     return _Span(run, phase)
+
+
+def claim_phase(phase):
+    """The accounting half of :func:`span` for a caller that keeps the
+    stamps itself (``tracing.span(..., phase=)``): the phase taken now,
+    to be closed with ``.end(t0, seconds)``; None when telemetry is
+    off."""
+    run = _run
+    if run is None:
+        return None
+    return _Span(run, phase).claim()
 
 
 # ---------------------------------------------------------------------------

@@ -1,38 +1,36 @@
-"""End-to-end request/step tracing with Perfetto-loadable export —
-the live, per-event half of the observability stack (the reference
-framework's ``MXNET_PROFILER_*`` chrome://tracing dumps, grown to
-cover causality across threads and subsystems).
+"""The program's spans: one primitive, three sinks.
 
-The telemetry layer (PR 3) aggregates: phase totals, percentiles,
-counters — you learn *how much*, never *which one*. This module
-records *events*: every serving request gets a trace id at
-``InferenceServer.submit`` and causally-linked spans across its whole
-lifetime (queue wait → batch formation → replica dispatch → pad →
-device compute → slice/respond), and every training step gets a step
-span with its phase spans nested inside — now *including* the
-off-thread work telemetry's exclusive-phase accounting deliberately
-excludes: async-input-pipeline decode and H2D placement, and the
-checkpoint writer's durable saves, each parented to the step that
-triggered them via an explicit context token captured on the
-triggering thread (:func:`context`), never via thread identity.
-Compile events (``compile_watch``) and gradient-sync bucket events
-(``parallel/grad_sync``) land as duration/instant events on their own
-tracks.
+:func:`span` is the one call a loop-level site makes. Every span it
+opens
 
-Storage is a bounded ring (``MXNET_TRACE_RING`` events, default
-200000): a week-long run keeps the most recent window, and
-:func:`stats` reports how many events the bound dropped.
-:func:`export` writes the ring as Chrome trace-event JSON
-(``{"traceEvents": [...]}``) loadable in Perfetto / chrome://tracing —
-``X`` complete events nest by time containment per track, serving
-requests each get their own named synthetic track, and the write is
-atomic (tmp + ``os.replace``).
+- is a ``jax.profiler.TraceAnnotation`` named ``mx:<name>`` while a
+  ``jax.profiler`` session is running (``start_trace`` or the profiler
+  server), so it lands on its thread's line of ``/host:CPU`` in the
+  xplane, on the device's clock, beside the programs it dispatched;
+  keyword arguments arrive there as the event's stats. No switch of
+  the program's own governs this sink;
+- is recorded in the Chrome-JSON ring while the ring is on
+  (``MXNET_TRACE=1`` / :func:`enable`), as an ``X`` event on the
+  calling thread's track;
+- accounts a telemetry phase (``phase=``) while a telemetry run is
+  active, under telemetry's exclusive-phase rules
 
-Always cheap when off — the telemetry discipline: every hook is one
-module-global ``None`` check, and :func:`span` returns a shared no-op
-singleton (zero allocation). Enable with ``MXNET_TRACE=1`` (picked up
-at ``telemetry.start``) or explicitly via :func:`enable`; set
-``MXNET_TRACE_FILE`` to auto-export at ``disable``/atexit.
+from ONE pair of ``perf_counter`` stamps, which the site reads back as
+``sp.t0`` / ``sp.t1`` where a counter of its own needs them. With no
+session, no ring and no run a span is one small object and two clock
+reads. Spans go at loop-level boundaries only, never inside an eager
+operator.
+
+The ring (``MXNET_TRACE_RING`` events, default 200000, oldest dropped
+and counted) also takes events that are not a lexical scope, through
+:func:`add` and :func:`instant`: a serving request's lifetime on a
+track of its own (:func:`track`), compile and gradient-sync events,
+the checkpoint writer's saves parented to the step that triggered them
+by an explicit token (:func:`context`). :func:`export` writes it as
+Chrome trace-event JSON for Perfetto, atomically; ``MXNET_TRACE_FILE``
+exports at :func:`disable` and at exit; :func:`wire_context`,
+:func:`adopt_context` and :func:`merge_exports` join the rings of
+several processes.
 """
 from __future__ import annotations
 
@@ -41,6 +39,8 @@ import os
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from . import envs
 
@@ -82,21 +82,6 @@ class _Trace:
         # each pairs a peer's wall stamp with ours, so merge_exports
         # and diagnose can cross-check the wall-anchor alignment
         self.wire_samples = deque(maxlen=64)
-
-
-class _NullSpan:
-    """Shared no-op span — the whole cost of :func:`span` when tracing
-    is off. Zero allocation: one module-level singleton."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-_NULL = _NullSpan()
 
 
 # ---------------------------------------------------------------------------
@@ -176,10 +161,10 @@ def maybe_enable():
 # recording
 # ---------------------------------------------------------------------------
 
-def now():
-    """The tracer's clock (``time.perf_counter`` — the same clock
-    telemetry stamps with, so step/phase/trace timestamps agree)."""
-    return time.perf_counter()
+# The tracer's clock: every span and every site that hands a stamp to
+# :func:`add` reads this one (telemetry stamps with the same clock, so
+# step, phase and trace timestamps agree).
+now = time.perf_counter
 
 
 def track(label):
@@ -258,31 +243,58 @@ def instant(name, cat, tid=None, args=None, t_at=None):
 
 
 class _Span:
-    __slots__ = ("name", "cat", "tid", "args", "t0")
+    """One open span; ``t0`` and, after exit, ``t1`` are its
+    ``perf_counter`` stamps."""
 
-    def __init__(self, name, cat, tid, args):
+    __slots__ = ("name", "cat", "tid", "phase", "args", "t0", "t1",
+                 "_ann", "_claim")
+
+    def __init__(self, name, cat, tid, phase, args):
         self.name = name
         self.cat = cat
         self.tid = tid
+        self.phase = phase
         self.args = args
+        self._ann = self._claim = None
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        self.t0 = now()
+        if _Annotation.is_enabled():
+            self._ann = _Annotation("mx:" + self.name, **self.args)
+            self._ann.__enter__()
+        if self.phase is not None:
+            from . import telemetry
+            self._claim = telemetry.claim_phase(self.phase)
         return self
 
-    def __exit__(self, *a):
-        add(self.name, self.cat, self.t0,
-            time.perf_counter() - self.t0, tid=self.tid,
-            args=self.args)
+    def set(self, **args):
+        """Arguments that are known only inside the scope."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        self.t1 = t1 = now()
+        if self._claim is not None:
+            # a phase keeps the ring name it always had, and says which
+            # span it was
+            self._claim.end(self.t0, t1 - self.t0, self.tid,
+                            dict(self.args, span=self.name))
+        elif _tracer is not None:
+            add(self.name, self.cat, self.t0, t1 - self.t0,
+                tid=self.tid, args=self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
-def span(name, cat="span", tid=None, args=None):
-    """A context manager recording one ``X`` event around its body.
-    The shared no-op singleton when tracing is off."""
-    if _tracer is None:
-        return _NULL
-    return _Span(name, cat, tid, args)
+def span(name, /, cat="span", tid=None, phase=None, **args):
+    """A context manager around one loop-level scope: a
+    ``TraceAnnotation("mx:" + name, **args)`` while a ``jax.profiler``
+    session runs, a ring ``X`` event while the ring is on (``cat``,
+    ``tid`` as :func:`add`), the telemetry ``phase`` while a run is
+    active — all from its own two stamps, ``t0`` and ``t1``."""
+    return _Span(name, cat, tid, phase, args)
 
 
 def context():

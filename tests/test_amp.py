@@ -127,8 +127,10 @@ def _run_amp(optimizer, opt_params, fused, monkeypatch, steps=5):
         trainer.step(len(x))
     from mxnet_tpu.amp import master_params
     weights = [p.data().asnumpy().copy() for p in params.values()]
+    # in the trainer's parameter order, not by name: names carry a
+    # process-wide counter, and dense9 / dense10 sort the other way round
     masters = [m.asnumpy().copy()
-               for _, m in sorted(master_params(trainer).items())]
+               for m in master_params(trainer).values()]
     return weights, masters, trainer
 
 

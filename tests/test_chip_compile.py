@@ -6,6 +6,7 @@ these cases are what keeps the kernels loadable between chip runs.
 A compile that passes is not a chip run: results are checked by
 ``chip_smoke.py`` on the chip."""
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -80,16 +81,29 @@ def _decode_args(T, dtype, quant):
     return args + ([((B * H, T), jnp.float32)] * 2 if quant else [])
 
 
-# kind -> (function, pallas_calls in its program, argument builder,
+FWD = ("flash_fwd",)
+GRAD = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+# kind -> (function, the kernels its program calls, argument builder,
 #          the builder's segments/quantized flag)
 KINDS = {
-    "forward": (_attn(False), 1, _attn_args, False),
-    "forward_grad": (_attn_grad(False), 3, _attn_args, False),
-    "segments_forward": (_attn(True), 1, _attn_args, True),
-    "segments_grad": (_attn_grad(True), 3, _attn_args, True),
-    "decode": (_decode(False), 1, _decode_args, False),
-    "decode_int8": (_decode(True), 1, _decode_args, True),
+    "forward": (_attn(False), FWD, _attn_args, False),
+    "forward_grad": (_attn_grad(False), GRAD, _attn_args, False),
+    "segments_forward": (_attn(True), FWD, _attn_args, True),
+    "segments_grad": (_attn_grad(True), GRAD, _attn_args, True),
+    "decode": (_decode(False), ("flash_decode",), _decode_args, False),
+    "decode_int8": (_decode(True), ("flash_decode_q8",), _decode_args,
+                    True),
 }
+
+
+def _named_calls(text, kernel):
+    """The Mosaic calls of a compiled program that carry ``kernel``'s
+    stable name and shapes as their instruction's name (differentiation
+    puts ``jvp_`` / ``transpose_jvp_`` in front): what a profile shows
+    as the operation's event."""
+    return re.findall(
+        r"^\s*(?:ROOT )?%%\w*?mx_%s\.bh\d+\.q\d+\.k\d+\.d\d+\.[a-z]+\d+"
+        r"[._\d]* = .* custom-call\(" % kernel, text, re.M)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -97,13 +111,17 @@ KINDS = {
 @pytest.mark.parametrize("T", [512, 2048])
 @pytest.mark.parametrize("kind", sorted(KINDS))
 def test_kernel_compiles_for_v5e(chip, kind, T, dtype):
-    fn, n_kernels, make_args, flag = KINDS[kind]
+    fn, kernels, make_args, flag = KINDS[kind]
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=chip)
             for shape, dt in make_args(T, dtype, flag)]
     text = jax.jit(fn).lower(*args).compile().as_text()
     # the Mosaic kernels are IN the program: not a jnp path, not
     # interpret mode
-    assert text.count('custom_call_target="tpu_custom_call"') == n_kernels
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == len(kernels)
+    # and each under the name a reader of a profile looks for
+    for kernel in kernels:
+        assert len(_named_calls(text, kernel)) == 1, kernel
 
 
 def test_decode_step_program_compiles_and_fits(chip, monkeypatch):
@@ -137,6 +155,7 @@ def test_decode_step_program_compiles_and_fits(chip, monkeypatch):
         spec((W, M), jnp.int32), pool, pool).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == L
+    assert len(_named_calls(text, "flash_decode")) == L
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

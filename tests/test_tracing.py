@@ -41,8 +41,15 @@ def _events(name=None, cat=None, ph=None):
 
 def test_off_by_default_zero_allocation_span():
     assert not tracing.enabled()
-    # the off-path span is ONE shared singleton — zero allocation
-    assert tracing.span("a") is tracing.span("b")
+    # with no profiler session, no ring and no telemetry run a span is
+    # its own small object and two stamps: no annotation, no phase
+    # claim, no ring event, nothing kept once it is dropped
+    import sys
+    with tracing.span("a", phase="compute", k=1) as sp:
+        assert sp._ann is None and sp._claim is None
+    assert sp.t1 >= sp.t0
+    assert sys.getrefcount(sp) == 2          # ours and the call's
+    assert tracing.stats() is None and not telemetry.enabled()
     # every other hook is a None-check no-op
     assert tracing.track("x") is None
     assert tracing.context() is None
@@ -242,11 +249,14 @@ def test_pipeline_decode_and_h2d_events_carry_context():
     finally:
         pipe.close()
     telemetry.stop()
-    decodes = _events(name="decode", cat="io")
+    decodes = _events(name="pipeline.decode", cat="io")
     assert len(decodes) == 4
-    h2ds = [e for e in _events(cat="io", ph="X")
-            if e["name"].startswith("h2d:")]
-    assert h2ds and all(e["args"].get("bytes", 0) > 0 for e in h2ds)
+    h2ds = _events(name="pipeline.h2d", cat="io")
+    assert h2ds and all(e["args"]["bytes"] > 0 for e in h2ds)
+    assert {e["args"]["name"] for e in h2ds} >= {"data"}
+    # both are parented to a step by the explicit token: the one open
+    # when the work was triggered (1), or the next once it had closed
+    assert all(e["args"]["step"] in (1, 2) for e in decodes + h2ds)
     # decode/h2d tracks are their own (named) synthetic tracks
     meta_names = {e["args"]["name"]
                   for e in tracing.export()["traceEvents"]
