@@ -15,7 +15,8 @@ non-zero and no ``"ok"`` line is printed):
   bf16 AMP policy, hybridized, ``gluon.Trainer`` SGD+momentum through the
   fused step; five steps on one fixed batch.
 - **kernels** ``flash_attention`` (causal, with/without segment ids,
-  forward and grad) and ``flash_decode`` (fp32 and int8 cache) at
+  forward and grad), ``flash_decode`` and the paged decode kernel of
+  ``kvcache.paged_attention`` (fp32 and int8 cache each) at
   (B,T,H,D)=(4,2048,8,128) against the jnp references, with the Mosaic
   custom call asserted in the lowered program.
 - **serve**   ``DecodeServer`` over ``ToyDecoderLM`` (vocab 32000, 4 layers,
@@ -125,7 +126,8 @@ class Compiles:
 
 
 ATTENTION_PATHS = ("flash_attention_pallas", "flash_attention_jnp",
-                   "flash_decode_pallas", "flash_decode_jnp")
+                   "flash_decode_pallas", "flash_decode_jnp",
+                   "paged_decode_pallas", "paged_decode_jnp")
 
 
 def counted_since(before, names):
@@ -263,12 +265,13 @@ def kernels_phase(cfg, seed, on_chip):
     from mxnet_tpu import profiler
     from mxnet_tpu.parallel.flash_attention import (
         _jnp_decode, _jnp_reference, flash_attention, flash_decode)
+    from mxnet_tpu.serving import kvcache
     t0 = time.perf_counter()
     before = profiler.counters()
     B, T, H, D = cfg["attn"]
     scale = 1.0 / math.sqrt(D)
     force = not on_chip        # the rehearsal interprets the kernels
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 24))
     results = {}
 
     def compare(name, dtype, kernel, reference, args, ref_args, where=None):
@@ -349,13 +352,57 @@ def kernels_phase(cfg, seed, on_chip):
                 lengths, scale),
             (q, k8, v8), (q, k8, v8))
 
+    # the same rows through the paged kernel: the cache cut into the
+    # serve phase's pages behind a dump page (layer 1 of a two-layer
+    # pool), each row's last live key handed over as the new token
+    S = cfg["page_size"]
+    M = T // S
+    table = jnp.arange(1, B * M + 1, dtype=jnp.int32).reshape(B, M)
+    at = lengths - 1
+    rows = jnp.arange(B)
+    ksp, vsp = (jax.random.uniform(next(keys), (B, M), jnp.float32,
+                                   0.005, 0.02) for _ in range(2))
+
+    def pool(cache):
+        pages = cache.reshape(B * M, S, H, D)
+        pages = jnp.concatenate([jnp.zeros_like(pages[:1]), pages])
+        return jnp.stack([jnp.zeros_like(pages), pages])
+
+    def page_scales(scales):
+        flat = jnp.concatenate([jnp.ones((1,)), scales.reshape(-1)])
+        return jnp.stack([jnp.ones_like(flat), flat])
+
+    def paged(q, k, v, k_scale=None, v_scale=None):
+        new = [c[rows, at].astype(jnp.float32) for c in (k, v)]
+        if k_scale is not None:
+            new = [n * s[rows, at // S][:, None, None]
+                   for n, s in zip(new, (k_scale, v_scale))]
+            k_scale, v_scale = page_scales(k_scale), page_scales(v_scale)
+        return kvcache.paged_attention(
+            pool(k), pool(v), table, at, 1, q[:, 0], *new,
+            force_pallas=force, k_scale=k_scale, v_scale=v_scale)[:, None]
+
+    compare("decode_paged", "float32", paged,
+            lambda q, k, v: _jnp_decode(q, k, v, lengths, scale),
+            (q, kc, vc), (q, kc, vc))
+    compare("decode_paged_int8", "float32",
+            lambda q, k, v: paged(q, k, v, ksp, vsp),
+            lambda q, k, v: _jnp_decode(
+                q, k.astype(jnp.float32)
+                * jnp.repeat(ksp, S, axis=1)[:, :, None, None],
+                v.astype(jnp.float32)
+                * jnp.repeat(vsp, S, axis=1)[:, :, None, None],
+                lengths, scale),
+            (q, k8, v8), (q, k8, v8))
+
     paths = counted_since(before, ATTENTION_PATHS)
     emit({"phase": "kernels", "shape": [B, T, H, D], "errors": results,
           "paths": paths, "mosaic_asserted": bool(on_chip),
           "wall_s": round(time.perf_counter() - t0, 3),
           "device": device_record()})
     check(paths["flash_attention_jnp"] == 0
-          and paths["flash_decode_jnp"] == 0,
+          and paths["flash_decode_jnp"] == 0
+          and paths["paged_decode_jnp"] == 0,
           "kernels: the jnp path was taken: %s" % paths)
 
 
@@ -519,10 +566,12 @@ def _serve_phase(cfg, seed, on_chip, device, default_err):
           "serve: compiled after warmup (%d -> %d)"
           % (warm_compiles, total_compiles))
     if on_chip:
+        # prefill takes flash_attention, the step the paged decode
+        # kernel; the contiguous flash_decode is not on this path
         check(paths["flash_attention_jnp"] == 0
-              and paths["flash_decode_jnp"] == 0
+              and paths["paged_decode_jnp"] == 0
               and paths["flash_attention_pallas"] > 0
-              and paths["flash_decode_pallas"] > 0,
+              and paths["paged_decode_pallas"] > 0,
               "serve: attention did not take the kernels: %s" % paths)
     for c in checked:
         check(c["max_shortfall"] <= TOKEN_LOGIT_SLACK,

@@ -325,8 +325,8 @@ declare("MXNET_KV_POOL_PAGES", "int", 256,
         "reserved dump page).", _G)
 declare("MXNET_KV_DTYPE", "str", "float32",
         "Storage dtype of the paged KV-cache pool: float32 | "
-        "bfloat16 | int8 (int8 adds per-page scales and dequantizes "
-        "on gather).", _G)
+        "bfloat16 | int8 (int8 adds per-page scales, applied where "
+        "attention reads the pages).", _G)
 declare("MXNET_KV_PREFIX_CACHE", "bool", False,
         "Prefix-aware KV page sharing: completed prefills register "
         "their page-aligned token runs in a content-hashed index, a "

@@ -31,6 +31,12 @@ Pallas interpret mode (``force_pallas=True``);
 ``tests/test_chip_compile.py`` compiles every ``pallas_call`` here for
 a described v5e, and ``chip_smoke.py`` runs them on the chip.
 
+The serving decode step does not use ``flash_decode``'s contiguous
+cache: :func:`_pallas_paged_decode` (behind
+``serving.kvcache.paged_attention``, which holds its plain reference)
+reads the paged KV pool's pages where they lie, steered by the page
+table in SMEM, so the step copies no cache.
+
 Per-row planes (logsumexp, rowsum(dO*O), segment ids, int8 scales)
 are rank-3 — ``(BH, T, 1)`` columns on the q side, ``(BH, 1, T)`` rows
 on the k side — because the TPU lowering refuses a rank-2 ``(None,
@@ -703,6 +709,155 @@ def _pallas_decode(q, k, v, lengths, scale, block_k, interpret,
         + ((k_scale[:, None, :], v_scale[:, None, :]) if quant
            else ())))
     return out
+
+
+def _paged_decode_kernel(tbl_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref,
+                         v_ref, *refs, scale, page_size, n_pages, chunk,
+                         quant):
+    """Grid = (rows, head blocks, table columns), columns innermost: one
+    program instance attends ``hb`` heads of one row to ONE page, read
+    where it lies in the pool — ``k_ref``/``v_ref`` are the page's
+    ``(S, hb, D)`` block, token-major as the pool stores it. A query of
+    length 1 is a matrix-vector product a head, so the VPU does it in
+    that layout with no relayout and in full fp32: ``sum_d k*q`` along
+    the lanes gives ``(tokens, hb, 1)`` score columns, the softmax
+    reduces over the leading token axis, and ``sum_t p*v`` accumulates
+    ``(hb, D)`` — :func:`_decode_accumulate`'s streaming softmax,
+    ``chunk`` tokens a time so the carried max/sum/accumulator stay in
+    registers.
+
+    ``len_ref[b]`` counts the row's keys IN THE POOL; the new token's
+    own key/value (``kn_ref``/``vn_ref``, not in the pool yet) open the
+    accumulation as the first key. Columns at or past ``ceil(len /
+    S)`` are dead: the caller's table re-names the last live page there,
+    so the pipeline fetches nothing, and ``pl.when`` skips the arithmetic.
+    An int8 pool brings its pages' scales as ``(rows, columns)`` SMEM
+    scalars: ``q·(k*s) == (q·k)*s`` and ``p@(v*s) == (p@v)*s``, applied
+    to the chunk's scores and partial sum."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    if quant:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        o_ref, acc_ref, m_ref, l_ref = refs
+    b = pl.program_id(0)
+    j = pl.program_id(2)
+    length = len_ref[b]
+    q = q_ref[...].astype(jnp.float32) * scale            # (hb, D)
+
+    @pl.when(j == 0)
+    def _init():
+        kn = kn_ref[...].astype(jnp.float32)
+        m_ref[...] = jnp.sum(q * kn, axis=-1, keepdims=True)
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = vn_ref[...].astype(jnp.float32)
+
+    @pl.when(j * page_size < length)
+    def _step():
+        def body(c, carry):
+            m_prev, l_prev, acc = carry
+            rows = pl.ds(c * chunk, chunk)
+            k = k_ref[rows].astype(jnp.float32)           # (chunk, hb, D)
+            v = v_ref[rows].astype(jnp.float32)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)
+            if quant:
+                s = s * ks_ref[b, j]
+            pos = j * page_size + c * chunk \
+                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            s = jnp.where(pos < length, s, _NEG)          # (chunk, hb, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[None])
+            pv = jnp.sum(p * v, axis=0)                   # (hb, D)
+            if quant:
+                pv = pv * vs_ref[b, j]
+            return (m_new, l_prev * alpha + jnp.sum(p, axis=0),
+                    acc * alpha + pv)
+
+        m_ref[...], l_ref[...], acc_ref[...] = jax.lax.fori_loop(
+            0, page_size // chunk, body,
+            (m_ref[...], l_ref[...], acc_ref[...]))
+
+    @pl.when(j == n_pages - 1)
+    def _finish():
+        # the new token is always live, so l >= its weight > 0
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+_PAGE_BLOCK_BYTES = 2 << 20
+_PAGE_CHUNK = 16     # tokens of a page the kernel folds in at a time
+
+
+def _heads_per_block(n_heads, page_size, head_dim, dtype):
+    """Heads a program instance of the paged kernel takes: the most
+    that keep a page's ``(S, hb, D)`` block inside 2 MB (K and V, each
+    double-buffered, then fill half of the 16 MB a kernel may use of
+    VMEM) — all of them, or a divisor of ``H`` that fills the dtype's
+    sublane tile (8 rows of 32 bits); all of them too when none fits."""
+    import jax.numpy as jnp
+    itemsize = jnp.dtype(dtype).itemsize
+    tile = 8 * 4 // itemsize
+    for hb in range(n_heads, 0, -1):
+        if n_heads % hb == 0 and (hb == n_heads or hb % tile == 0) \
+                and page_size * hb * head_dim * itemsize \
+                <= _PAGE_BLOCK_BYTES:
+            return hb
+    return n_heads
+
+
+def _pallas_paged_decode(q, k_new, v_new, k_pages, v_pages, layer,
+                         page_table, lengths, scale, interpret,
+                         k_scale=None, v_scale=None):
+    """``q``/``k_new``/``v_new`` (B, H, D); the WHOLE pools ``(L, P, S,
+    H, D)`` — ``layer`` (static) is picked in the index map, so no
+    operand is a slice of the pool that XLA would have to copy;
+    ``page_table`` (B, M) and ``lengths`` (B,) int32 — the keys each
+    row has IN the pool — ride scalar prefetch (SMEM) and steer the
+    page fetches; ``k_scale``/``v_scale`` (B, M) fp32 are an int8
+    pool's page scales as the table names them."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, D = q.shape
+    S = k_pages.shape[2]
+    M = page_table.shape[1]
+    hb = _heads_per_block(H, S, D, k_pages.dtype)
+    quant = k_scale is not None
+
+    # a dead column names the row's last live page again: the block
+    # index does not change, so the pipeline fetches nothing for it
+    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
+    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
+    page_table = jnp.take_along_axis(page_table, columns, axis=1)
+
+    row = pl.BlockSpec((None, hb, D), lambda b, h, j, tbl, lens: (b, h, 0))
+    page = pl.BlockSpec(
+        (None, None, S, hb, D),
+        lambda b, h, j, tbl, lens: (layer, tbl[b * M + j], 0, h, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel, scale=scale, page_size=S,
+                          n_pages=M, chunk=math.gcd(S, _PAGE_CHUNK),
+                          quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H // hb, M),
+            in_specs=[row, row, row, page, page]
+            + ([smem, smem] if quant else []),
+            out_specs=row,
+            scratch_shapes=[pltpu.VMEM((hb, D), jnp.float32),
+                            pltpu.VMEM((hb, 1), jnp.float32),
+                            pltpu.VMEM((hb, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        interpret=interpret,
+        # the contiguous kernel's name at the table's full width: a
+        # reader of the profile finds both under mx_flash_decode
+        name="mx_flash_decode.bh%d.q1.k%d.d%d.%s.paged" % (
+            B * H, M * S, D, jnp.dtype(k_pages.dtype).name),
+    )(*((page_table.reshape(-1), lengths, q, k_new, v_new, k_pages,
+         v_pages) + ((k_scale, v_scale) if quant else ())))
 
 
 def flash_decode(q, k, v, lengths, scale=None, block_k=128,

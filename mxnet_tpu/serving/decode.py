@@ -12,9 +12,10 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   ``decode:prefill:s<rung>``), writing its K/V into the paged pool and
   emitting the first token; every subsequent token comes from the ONE
   decode-step program (``decode:step``): a fixed-width batch of
-  query-length-1 rows, page-table gather → cached attention
-  (``parallel.flash_attention.flash_decode``) → new-token K/V scatter,
-  all inside the compiled program. ``compile_watch.site_stats
+  query-length-1 rows, each layer attending the pool's pages through
+  the page table (``kvcache.paged_attention``: the paged Pallas kernel
+  reads them where they lie; nothing is gathered) → new-token K/V row
+  writes, all inside the compiled program. ``compile_watch.site_stats
   ("decode")`` is the oracle: ``1 + len(ladder)`` programs under ANY
   request mix, zero steady-state recompiles.
 - **Paged KV cache** (``serving.kvcache``) — fixed-size pages, per
@@ -77,13 +78,17 @@ implementation):
   B, L, H, D)``. Rows at/after the true prompt length may be garbage
   (the server routes their K/V to the dump page and never reads their
   logits).
-- ``model.decode(params, tokens, positions, k_cache, v_cache) ->
-  (logits, k_new, v_new)`` — ``tokens (B,)``/``positions (B,)``
-  int32; caches ``(n_layers, B, T, H, D)`` gathered from the pool,
-  NOT yet containing the new token: the model inserts ``k_new``/
-  ``v_new`` at ``positions`` before attending (cache index == absolute
-  position), masking keys at or beyond ``positions + 1``. ``logits
-  (B, V)``; ``k_new``/``v_new`` ``(n_layers, B, H, D)``.
+- ``model.decode(params, tokens, positions, attend) -> (logits,
+  k_new, v_new)`` — ``tokens (B,)``/``positions (B,)`` int32. The
+  model never sees the cache: for each layer it asks the server's
+  ``attend(layer, q, k_new, v_new, scale=None, force_pallas=False)``
+  — ``q``/``k_new``/``v_new`` ``(B, H, D)``, the step's new token —
+  which attends each row's ``positions`` earlier keys in the pool plus
+  the new token's own at ``positions`` (``kvcache.paged_attention``
+  bound to the step's pools, page tables and positions) and returns
+  ``(B, H, D)``. ``logits (B, V)``; ``k_new``/``v_new`` ``(n_layers,
+  B, H, D)``, which the server writes into the pool after the last
+  layer.
 - ``model.n_layers`` / ``model.n_heads`` / ``model.head_dim`` size the
   pool.
 
@@ -94,6 +99,7 @@ contract (``tests/test_decode.py``, on the jnp AND Pallas paths).
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import queue as _queue_mod
 import threading
@@ -255,9 +261,9 @@ class ToyDecoderLM:
     """A minimal pre-LN transformer LM implementing the decode-model
     contract — the reference the server's tests, example, and bench
     drive. Prefill attention is ``flash_attention(causal=True)``;
-    decode attention is the query-length-1 cached-KV path
-    (``flash_decode``); ``use_pallas`` forces the Pallas kernels in
-    interpret mode off-TPU so both kernel paths are testable on CPU.
+    decode attention is whatever the server's ``attend`` does over its
+    pool; ``use_pallas`` forces the Pallas kernels in interpret mode
+    off-TPU so both kernel paths are testable on CPU.
     Parameters are a FLAT ``{name: array}`` dict, so a checkpoint
     manifest round-trips them by name (the hot-swap recipe)."""
 
@@ -333,28 +339,23 @@ class ToyDecoderLM:
             @ params["wout"]
         return logits, jnp.stack(ks), jnp.stack(vs)
 
-    def decode(self, params, tokens, positions, k_cache, v_cache):
+    def decode(self, params, tokens, positions, attend):
         import jax
         import jax.numpy as jnp
-        from ..parallel.flash_attention import flash_decode
         B = tokens.shape[0]
         H, Dh = self.n_heads, self.head_dim
-        rows = jnp.arange(B)
         h = params["embed"][tokens] + params["pos"][positions]
         k_new, v_new = [], []
         for i in range(self.n_layers):
             x = self._ln(h, params["l%d.att_g" % i],
                          params["l%d.att_b" % i])
-            q = (x @ params["l%d.wq" % i]).reshape(B, 1, H, Dh)
+            q = (x @ params["l%d.wq" % i]).reshape(B, H, Dh)
             k = (x @ params["l%d.wk" % i]).reshape(B, H, Dh)
             v = (x @ params["l%d.wv" % i]).reshape(B, H, Dh)
-            # the new token's K/V joins the cache at its own position
-            # BEFORE attending — cache index == absolute position
-            kc = k_cache[i].at[rows, positions].set(k)
-            vc = v_cache[i].at[rows, positions].set(v)
-            a = flash_decode(q, kc, vc, positions + 1,
-                             scale=self._scale,
-                             force_pallas=self.use_pallas)
+            # the cache attends: the row's earlier keys from the pool
+            # plus this token's own K/V at its position
+            a = attend(i, q, k, v, scale=self._scale,
+                       force_pallas=self.use_pallas)
             h = h + a.reshape(B, -1) @ params["l%d.wo" % i]
             x = self._ln(h, params["l%d.ffn_g" % i],
                          params["l%d.ffn_b" % i])
@@ -533,7 +534,8 @@ class DecodeServer:
                        "prefix_hit_tokens": 0, "cow_splits": 0,
                        "cow_degraded": 0, "cross_preempts": 0,
                        "admitted": 0, "queue_wait_s": 0.0,
-                       "prefill_s": 0.0}
+                       "prefill_s": 0.0, "decode_pages_live": 0,
+                       "decode_pages_table": 0}
         self._shed_by_priority = {}
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
         self._intervals = deque(maxlen=ring)    # inter-token ms
@@ -577,10 +579,10 @@ class DecodeServer:
     def _decode_fn(self, params, tokens, positions, page_tables,
                    k_pages, v_pages):
         import jax.numpy as jnp
-        k_cache = kvcache.gather_pages(k_pages, page_tables)
-        v_cache = kvcache.gather_pages(v_pages, page_tables)
+        attend = functools.partial(kvcache.paged_attention, k_pages,
+                                   v_pages, page_tables, positions)
         logits, k_new, v_new = self._model.decode(
-            params, tokens, positions, k_cache, v_cache)
+            params, tokens, positions, attend)
         k_pages = kvcache.scatter_token(k_pages, page_tables,
                                         positions, k_new)
         v_pages = kvcache.scatter_token(v_pages, page_tables,
@@ -591,10 +593,11 @@ class DecodeServer:
         return tokens_out, k_pages, v_pages
 
     # int8-pool variants: same program shape, with per-page fp32
-    # scales riding alongside the pages. Gather DEQUANTIZES (the model
-    # contract stays fp32 caches), scatter quantizes — both inside the
-    # one compiled program, so the fixed-program-set oracle
-    # (site_stats("decode")) is identical to the fp32 pool's.
+    # scales riding alongside the pages. Attention applies them page by
+    # page (the model contract stays fp32 q/k_new/v_new), scatter
+    # quantizes — both inside the one compiled program, so the
+    # fixed-program-set oracle (site_stats("decode")) is identical to
+    # the fp32 pool's.
     def _prefill_fn_q8(self, params, tokens, n_valid, page_table,
                        k_pages, v_pages, k_scales, v_scales):
         import jax.numpy as jnp
@@ -610,12 +613,11 @@ class DecodeServer:
     def _decode_fn_q8(self, params, tokens, positions, page_tables,
                       k_pages, v_pages, k_scales, v_scales):
         import jax.numpy as jnp
-        k_cache = kvcache.gather_pages_q8(k_pages, k_scales,
-                                          page_tables)
-        v_cache = kvcache.gather_pages_q8(v_pages, v_scales,
-                                          page_tables)
+        attend = functools.partial(kvcache.paged_attention, k_pages,
+                                   v_pages, page_tables, positions,
+                                   k_scale=k_scales, v_scale=v_scales)
         logits, k_new, v_new = self._model.decode(
-            params, tokens, positions, k_cache, v_cache)
+            params, tokens, positions, attend)
         k_pages, k_scales = kvcache.scatter_token_q8(
             k_pages, k_scales, page_tables, positions, k_new)
         v_pages, v_scales = kvcache.scatter_token_q8(
@@ -1435,8 +1437,14 @@ class DecodeServer:
                     tokens[i] = r.generated[-1]
                     positions[i] = len(r.prompt) + len(r.generated) - 1
                 pts[i, :len(r.pages)] = r.pages
+            # the pages that hold this step's live keys, of the table
+            # the step program is compiled for: what a kernel that
+            # reads pages where they lie has to stream
+            pages_live = int((positions[:len(rows)]
+                              // self._pool.page_size + 1).sum())
         try:
-            with tracing.span("decode.dispatch"), self._pool.step_lock:
+            with tracing.span("decode.dispatch", pages_live=pages_live), \
+                    self._pool.step_lock:
                 toks = self._adopt_pool(self._decode_prog(
                     ver.tree, tokens, positions, pts,
                     *self._pool_args()))[0]
@@ -1481,6 +1489,8 @@ class DecodeServer:
             finished = []
             with self._cond:
                 self._stats["decode_steps"] += 1
+                self._stats["decode_pages_live"] += pages_live
+                self._stats["decode_pages_table"] += D * M
                 for i, r in emitting:
                     self._stats["tokens_out"] += 1
                     if r._last_emit is not None:
@@ -1554,6 +1564,8 @@ class DecodeServer:
             "admitted": s["admitted"],
             "queue_wait_s": s["queue_wait_s"],
             "prefill_s": s["prefill_s"],
+            "decode_pages_live": s["decode_pages_live"],
+            "decode_pages_table": s["decode_pages_table"],
             "kv": self._pool.stats(),
             "swaps": s["swaps"],
             "weight_version": version,

@@ -7,17 +7,25 @@ each in-flight request holds a *page table*, a short list of page ids
 covering its token positions in order. Every compiled program then
 sees only fixed shapes:
 
-- **gather** (:func:`gather_pages`) — indexing the pool with a
-  ``(batch, max_pages)`` page table yields a ``(batch, max_pages *
-  page_size, ...)`` contiguous view per request, where a token's cache
-  index IS its absolute position. Unallocated table tail entries point
-  at the reserved **dump page 0**, whose garbage is masked to
-  exact-zero attention weight by the per-row ``lengths`` argument of
-  ``parallel.flash_attention.flash_decode``.
+- **attend** (:func:`paged_attention`) — one layer's decode attention
+  reads the pool THROUGH the ``(batch, max_pages)`` page table: on the
+  TPU, for pages the chip tiles, the paged Pallas kernel of
+  ``parallel.flash_attention`` streams each row's live pages where
+  they lie (``ceil(len / page_size)`` of them; the table's tail is
+  neither fetched nor computed) and folds the step's new token in as
+  one more key, so the decode step holds no copy of the cache.
+- **gather** (:func:`gather_pages`) — the plain reference path, which
+  the kernel is tested against and which every shape the chip cannot
+  tile (and a CPU process) still takes: indexing the pool with the
+  page table yields a ``(batch, max_pages * page_size, ...)``
+  contiguous view per request, where a token's cache index IS its
+  absolute position. Unallocated table tail entries point at the
+  reserved **dump page 0**, whose garbage is masked to exact-zero
+  attention weight by the per-row lengths.
 - **scatter** (:func:`scatter_token` / :func:`scatter_prefill`) — new
-  K/V rows write back through the same table, functionally
-  (``.at[].set``), so the whole decode step stays one compiled
-  program: gather → attend → scatter, no host round-trip per token.
+  K/V rows write back through the same table, functionally, into the
+  donated pool, so the whole decode step stays one compiled program:
+  attend → write the token's rows, no host round-trip per token.
 
 Page *accounting* is host-side and lives here too: an allocate/free
 free-list under a lock, with peak/eviction counters for the ``decode``
@@ -39,8 +47,10 @@ pool): K/V pages store int8 with one fp32 scale per ``(layer, page)``
 the same traced, functional shapes as the fp32 ones, so the decode
 server's program set stays fixed:
 
-- :func:`gather_pages_q8` dequantizes on gather — the per-page scale
-  broadcasts across its page's token slots;
+- :func:`paged_attention` hands the kernel each page's scale as one
+  scalar, applied to the page's scores and weighted values in VMEM;
+  :func:`gather_pages_q8` (the reference path) dequantizes on gather —
+  the per-page scale broadcasts across its page's token slots;
 - :func:`scatter_token_q8` grows a page's scale monotonically as
   tokens land (``max(old, |new|/127)``) and REQUANTIZES the page body
   under the grown scale in-program — except on a page's FIRST slot,
@@ -90,7 +100,8 @@ from .. import envs, fault
 from ..base import MXNetError
 
 __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
-           "scatter_token", "scatter_prefill", "pages_for",
+           "paged_attention", "scatter_token", "scatter_prefill",
+           "pages_for",
            "gather_pages_q8", "scatter_token_q8",
            "scatter_prefill_q8"]
 
@@ -118,19 +129,94 @@ def gather_pages(pages, page_table):
                      *shape[4:])
 
 
+def paged_attention(k_pages, v_pages, page_table, positions, layer, q,
+                    k_new, v_new, *, scale=None, force_pallas=False,
+                    k_scale=None, v_scale=None):
+    """One layer's decode attention over the pool — what the server's
+    step hands a model as ``attend(layer, q, k_new, v_new)`` (the
+    leading five arguments bound). ``q``/``k_new``/``v_new`` ``(B, H,
+    D)`` are the step's new token; its K/V is NOT in the pool yet
+    (:func:`scatter_token` writes it at the step's end) and is attended
+    at ``positions (B,)`` with the row's ``positions`` earlier keys.
+    Returns ``(B, H, D)``.
+
+    On the TPU, for pages the chip tiles (``head_dim`` and ``page_size``
+    multiples of 128), the Pallas kernel of ``parallel.flash_attention``
+    reads each row's live pages where they lie: nothing pool-sized or
+    cache-sized is copied, whatever the table's width. Every other
+    shape, and a CPU process without ``force_pallas``, takes the plain
+    reference the kernel is tested against: :func:`gather_pages` the
+    layer to a contiguous cache, insert the new token, masked softmax
+    (``_jnp_decode``). The choice is ``flash_attention._choose_path``'s,
+    counted as ``paged_decode_pallas`` / ``paged_decode_jnp``. An int8
+    pool passes its ``(L, P)`` page scales; the new token is attended
+    unquantized on both paths, a float pool's rounded to the pool's
+    dtype as the pool will hold it."""
+    import math
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import (_dispatch, _jnp_decode,
+                                            _pallas_paged_decode)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    quant = k_scale is not None
+    pos = jnp.asarray(positions, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    if not quant:
+        k_new = k_new.astype(k_pages.dtype)
+        v_new = v_new.astype(v_pages.dtype)
+
+    def composed(q, k_new, v_new, k_pages, v_pages, table, pos, ks, vs):
+        one = slice(layer, layer + 1)
+        if quant:
+            kc = gather_pages_q8(k_pages[one], ks[one], table)[0]
+            vc = gather_pages_q8(v_pages[one], vs[one], table)[0]
+        else:
+            kc = gather_pages(k_pages[one], table)[0]
+            vc = gather_pages(v_pages[one], table)[0]
+        rows = jnp.arange(q.shape[0])
+        # cache index == absolute position: the new token joins the
+        # gathered copy at its own before attending
+        kc = kc.at[rows, pos].set(k_new)
+        vc = vc.at[rows, pos].set(v_new)
+        return _jnp_decode(q[:, None], kc, vc, pos + 1,
+                           scale)[:, 0].astype(q.dtype)
+
+    def kernel(interpret, q, k_new, v_new, k_pages, v_pages, table, pos,
+               ks, vs):
+        if quant:
+            ks, vs = ks[layer][table], vs[layer][table]     # (B, M)
+        return _pallas_paged_decode(
+            q, k_new, v_new, k_pages, v_pages, layer, table, pos, scale,
+            interpret, k_scale=ks, v_scale=vs)
+
+    return _dispatch("paged_decode", q.shape[-1], (k_pages.shape[2],),
+                     force_pallas, kernel, composed, q, k_new, v_new,
+                     k_pages, v_pages, table, pos, k_scale, v_scale)
+
+
 def scatter_token(pages, page_table, positions, new):
     """Write one decode step's new K (or V) rows into the pool:
     ``new (L, B, H, D)`` lands at each row's absolute ``positions
     (B,)`` through its ``page_table (B, M)`` row. Inactive batch rows
     must carry an all-zero table row — their write lands in the dump
     page. Functional: returns the updated pool."""
+    import jax
     import jax.numpy as jnp
     S = pages.shape[2]
     pos = jnp.asarray(positions, jnp.int32)
     pidx = jnp.take_along_axis(
         jnp.asarray(page_table, jnp.int32), (pos // S)[:, None],
         axis=1)[:, 0]                          # (B,)
-    return pages.at[:, pidx, pos % S].set(new)
+    slot = pos % S
+    new = new.astype(pages.dtype)
+
+    def write_row(b, pages):
+        row = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)
+        return jax.lax.dynamic_update_slice(
+            pages, row[:, :, None], (0, pidx[b], slot[b], 0, 0))
+
+    # one in-place row write a batch row, not a scatter: XLA's TPU
+    # scatter widens a 16-bit pool to float32 and back, whole
+    return jax.lax.fori_loop(0, new.shape[1], write_row, pages)
 
 
 def scatter_prefill(pages, page_table_row, seq, n_valid):

@@ -5,6 +5,7 @@ runs). Interpret mode on the CPU accepts block shapes Mosaic refuses —
 these cases are what keeps the kernels loadable between chip runs.
 A compile that passes is not a chip run: results are checked by
 ``chip_smoke.py`` on the chip."""
+import json
 import os
 import re
 import sys
@@ -69,6 +70,15 @@ def _decode(quant):
     return run
 
 
+def _decode_paged(quant):
+    def run(q, k_new, v_new, k_pages, v_pages, table, lens, *scales):
+        ks, vs = scales if quant else (None, None)
+        return fa._pallas_paged_decode(q, k_new, v_new, k_pages, v_pages,
+                                       1, table, lens, SCALE, False,
+                                       k_scale=ks, v_scale=vs)
+    return run
+
+
 def _attn_args(T, dtype, seg):
     qkv = [((B, T, H, D), dtype)] * 3
     return qkv + ([((B, T), jnp.int32)] if seg else [])
@@ -79,6 +89,16 @@ def _decode_args(T, dtype, quant):
     args = [((B * H, 1, D), dtype), ((B * H, T, D), cache),
             ((B * H, T, D), cache), ((B * H,), jnp.int32)]
     return args + ([((B * H, T), jnp.float32)] * 2 if quant else [])
+
+
+def _decode_paged_args(T, dtype, quant):
+    """A two-layer pool of 128-token pages, a table ``T`` keys wide."""
+    M = T // 128
+    pool = ((2, B * M + 1, 128, H, D), jnp.int8 if quant else dtype)
+    new = ((B, H, D), jnp.float32 if quant else dtype)
+    args = [((B, H, D), jnp.float32), new, new, pool, pool,
+            ((B, M), jnp.int32), ((B,), jnp.int32)]
+    return args + ([((B, M), jnp.float32)] * 2 if quant else [])
 
 
 FWD = ("flash_fwd",)
@@ -93,6 +113,12 @@ KINDS = {
     "decode": (_decode(False), ("flash_decode",), _decode_args, False),
     "decode_int8": (_decode(True), ("flash_decode_q8",), _decode_args,
                     True),
+    # the paged kernel carries the contiguous one's name, ".paged" after
+    # the pool's dtype
+    "decode_paged": (_decode_paged(False), ("flash_decode",),
+                     _decode_paged_args, False),
+    "decode_paged_int8": (_decode_paged(True), ("flash_decode",),
+                          _decode_paged_args, True),
 }
 
 
@@ -103,7 +129,7 @@ def _named_calls(text, kernel):
     as the operation's event."""
     return re.findall(
         r"^\s*(?:ROOT )?%%\w*?mx_%s\.bh\d+\.q\d+\.k\d+\.d\d+\.[a-z]+\d+"
-        r"[._\d]* = .* custom-call\(" % kernel, text, re.M)
+        r"(?:\.paged)?[._\d]* = .* custom-call\(" % kernel, text, re.M)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -124,28 +150,52 @@ def test_kernel_compiles_for_v5e(chip, kind, T, dtype):
         assert len(_named_calls(text, kernel)) == 1, kernel
 
 
-def test_decode_step_program_compiles_and_fits(chip, monkeypatch):
-    """The ``decode:step`` program at chip_smoke's widths — page gather,
-    ToyDecoderLM.decode over the Pallas decode kernel, token scatter —
-    compiled for one v5e from ``jax.eval_shape``-made shapes, inside the
-    chip's 16 GB. The platform predicate is steered here, in the test:
-    the sandbox's JAX sees a CPU."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _smoke_shapes():
+    sys.path.insert(0, ROOT)
     import chip_smoke
+    cfg = chip_smoke.FULL
+    return (cfg["lm"], cfg["window"], cfg["page_size"], cfg["pool_pages"],
+            max(cfg["ladder"]) + cfg["new_tokens"])
+
+
+def _benchmark_shapes():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "opt-6.7b.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    return (cfg["model"]["kwargs"], srv["window"], srv["page_size"],
+            srv["pool_pages"],
+            max(srv["seq_ladder"]) + srv["max_new_tokens"])
+
+
+@pytest.mark.parametrize("shapes", [_smoke_shapes, _benchmark_shapes],
+                         ids=["chip_smoke", "opt-6.7b"])
+def test_decode_step_program_compiles_and_fits(chip, monkeypatch, shapes):
+    """The ``decode:step`` program — ToyDecoderLM.decode attending the
+    pool through the paged Pallas kernel, one kernel call a layer, then
+    the token's row writes — at chip_smoke's widths and at the
+    benchmark's own (4 layers, window 8, 16 pages a row, 160 float32
+    pool pages), compiled for one v5e from ``jax.eval_shape``-made
+    shapes: inside the chip's 16 GB, the donated pools updated in place,
+    and no copy of the pool or of a gathered cache among its
+    temporaries (PR 22's step planned 4.57 GB of them). The platform
+    predicate is steered here, in the test: the sandbox's JAX sees a
+    CPU."""
     from mxnet_tpu.serving import DecodeServer, ToyDecoderLM
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    cfg = chip_smoke.FULL
-    model = ToyDecoderLM(**cfg["lm"])
+    lm, W, S, pool_pages, context = shapes()
+    model = ToyDecoderLM(**lm)
     params = jax.eval_shape(lambda: model.init_params(seed=0))
     L, Hh, Dh = model.n_layers, model.n_heads, model.head_dim
-    W, S = cfg["window"], cfg["page_size"]
-    M = -(-(max(cfg["ladder"]) + cfg["new_tokens"]) // S)
+    M = -(-context // S)
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    pool = spec((L, cfg["pool_pages"], S, Hh, Dh), jnp.float32)
+    pool = spec((L, pool_pages, S, Hh, Dh), jnp.float32)
     step = DecodeServer._decode_fn       # unbound: only self._model
     holder = type("S", (), {"_model": model})()
     compiled = jax.jit(
@@ -156,7 +206,11 @@ def test_decode_step_program_compiles_and_fits(chip, monkeypatch):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == L
     assert len(_named_calls(text, "flash_decode")) == L
+    assert ".k%d.d%d.float32.paged" % (M * S, Dh) in text
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < 16e9, mem
+    pools = 2 * L * pool_pages * S * Hh * Dh * 4
+    assert mem.alias_size_in_bytes >= pools, mem
+    assert mem.temp_size_in_bytes < 0.5e9, mem

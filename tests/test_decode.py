@@ -558,6 +558,139 @@ def test_flash_decode_matches_full_attention_rows():
         flash_decode(q[:, :2], kc, vc, jnp.ones((B,), jnp.int32))
 
 
+# ---------------------------------------------------------------------------
+# the paged decode kernel (reads the pool's pages where they lie) against
+# the plain reference: gather_pages + insert + _jnp_decode
+# ---------------------------------------------------------------------------
+
+_PG = dict(L=2, P=12, S=8, H=2, D=8, M=3)       # pool pages 1..11 usable
+
+
+def _paged_pools(dtype, seed=0):
+    """Random K and V pools of ``dtype`` (and an int8 pool's page
+    scales, else None): every page holds finite garbage, the dump
+    page too."""
+    import jax.numpy as jnp
+    g = _PG
+    rs = np.random.RandomState(seed)
+    shape = (g["L"], g["P"], g["S"], g["H"], g["D"])
+    if dtype == "int8":
+        k, v = (jnp.asarray(rs.randint(-127, 128, size=shape), jnp.int8)
+                for _ in range(2))
+        ks, vs = (jnp.asarray(rs.uniform(0.005, 0.03, size=shape[:2]),
+                              jnp.float32) for _ in range(2))
+        return k, v, ks, vs
+    k, v = (jnp.asarray(rs.randn(*shape), dtype) for _ in range(2))
+    return k, v, None, None
+
+
+def _paged_both_paths(dtype, table, positions, layer=1, seed=0):
+    """(kernel, reference, v_new) of one layer's decode attention over
+    the same pools, table and positions."""
+    import jax.numpy as jnp
+    from mxnet_tpu.serving import kvcache
+    k, v, ks, vs = _paged_pools(dtype, seed)
+    rs = np.random.RandomState(seed + 1)
+    B = len(positions)
+    q, k_new, v_new = (jnp.asarray(rs.randn(B, _PG["H"], _PG["D"]),
+                                   jnp.float32) for _ in range(3))
+    args = (k, v, jnp.asarray(table, jnp.int32),
+            jnp.asarray(positions, jnp.int32), layer, q, k_new, v_new)
+    got = kvcache.paged_attention(*args, force_pallas=True, k_scale=ks,
+                                  v_scale=vs)
+    want = kvcache.paged_attention(*args, k_scale=ks, v_scale=vs)
+    assert got.shape == want.shape == (B, _PG["H"], _PG["D"])
+    return np.asarray(got), np.asarray(want), np.asarray(v_new)
+
+
+_POOL_DTYPES = ["float32", "bfloat16", "int8"]
+# same values read on both paths, fp32 arithmetic on both: what differs
+# is the order of the sums (16 tokens a time against one softmax)
+_PAGED_TOL = dict(rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("dtype", _POOL_DTYPES)
+@pytest.mark.parametrize(
+    "length", [1, _PG["S"] - 1, _PG["S"], _PG["S"] + 1,
+               _PG["M"] * _PG["S"]],
+    ids=["len1", "S-1", "S", "S+1", "full_table"])
+def test_paged_decode_kernel_matches_gather_reference(length, dtype):
+    """Every length around a page boundary, up to the full table: the
+    kernel attends ``length - 1`` keys from the pool's pages plus the
+    new token, as gather + insert + masked softmax does."""
+    table = [[1, 2, 3], [4, 5, 6]]
+    got, want, _ = _paged_both_paths(dtype, table, [length - 1] * 2)
+    np.testing.assert_allclose(got, want, **_PAGED_TOL)
+
+
+@pytest.mark.parametrize("dtype", _POOL_DTYPES)
+@pytest.mark.parametrize("case", ["mixed_lengths", "inactive_row",
+                                  "shared_page"])
+def test_paged_decode_kernel_rows_and_tables(case, dtype):
+    S = _PG["S"]
+    if case == "mixed_lengths":
+        # rows of different lengths in one batch, unallocated table
+        # tails on the dump page
+        table = [[1, 0, 0], [2, 3, 0], [4, 5, 6], [7, 8, 9]]
+        positions = [3, S, 3 * S - 1, 2 * S + 1]
+    elif case == "inactive_row":
+        # an inactive row: all-zero table, position 0 — it attends its
+        # own new token and nothing of the dump page
+        table = [[0, 0, 0], [1, 2, 0]]
+        positions = [0, S + 2]
+    else:
+        # two rows on one shared (prefix) page, diverging after it
+        table = [[1, 2, 0], [1, 3, 4]]
+        positions = [S + 3, 2 * S + 5]
+    got, want, v_new = _paged_both_paths(dtype, table, positions,
+                                         layer=0, seed=3)
+    np.testing.assert_allclose(got, want, **_PAGED_TOL)
+    if case == "inactive_row":
+        alone = v_new[0] if dtype == "int8" else np.asarray(
+            v_new[0].astype(dtype), np.float32)
+        np.testing.assert_array_equal(got[0], alone)
+
+
+def test_paged_decode_path_is_counted_and_blocks_fit():
+    """``_choose_path`` counts the paged op under its own name, and a
+    program instance takes all heads of a page up to 2 MB."""
+    import jax.numpy as jnp
+    from mxnet_tpu import profiler
+    from mxnet_tpu.parallel.flash_attention import _heads_per_block
+    before = dict(profiler.counters())
+    _paged_both_paths("float32", [[1, 0, 0]], [2])
+    after = profiler.counters()
+    for path in ("pallas", "jnp"):
+        name = "paged_decode_" + path
+        assert after[name] == before.get(name, 0) + 1
+    assert _heads_per_block(32, 128, 128, jnp.float32) == 32    # 2 MB
+    assert _heads_per_block(64, 128, 128, jnp.float32) == 32
+    assert _heads_per_block(64, 128, 128, jnp.bfloat16) == 64
+    assert _heads_per_block(64, 128, 128, jnp.int8) == 64
+    assert _heads_per_block(12, 512, 128, jnp.float32) == 12    # no
+    assert _heads_per_block(2, 8, 8, jnp.float32) == 2          # divisor
+
+
+def test_decode_stats_count_live_pages_of_the_table():
+    """``decode_pages_live`` sums ceil((pos + 1) / S) over the rows of
+    every step, ``decode_pages_table`` the table the step program is
+    compiled for; the dispatch span carries the step's count."""
+    model, params = _toy()
+    srv = DecodeServer(model, params, seq_ladder=[16], max_new_tokens=8,
+                       window=2, page_size=8, pool_pages=16,
+                       start=False)
+    try:
+        req = srv.submit(np.arange(1, 7), max_new_tokens=5)   # 6 tokens
+        _drain(srv, req)
+        st = srv.stats()
+        # four decode steps write positions 6, 7 (one page) and 8, 9 (two)
+        assert st["decode_steps"] == 4
+        assert st["decode_pages_live"] == 1 + 1 + 2 + 2
+        assert st["decode_pages_table"] == 4 * 2 * srv._max_pages
+    finally:
+        srv.stop()
+
+
 def test_decode_attention_registered_op():
     import jax.numpy as jnp
     from mxnet_tpu.parallel.flash_attention import _jnp_decode

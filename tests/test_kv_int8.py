@@ -7,6 +7,8 @@ gather ops quantize and dequantize IN-PROGRAM (traced, no recompiles),
 page scales only ever grow within a tenant (monotone requantization)
 and reset on reuse (a freed page's stale scale never leaks), and the
 decode logits stay within quantization tolerance of the fp32 path."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -164,8 +166,8 @@ def test_q8_gather_matches_fp32_gather_within_tolerance():
 # ---------------------------------------------------------------------------
 
 def test_q8_decode_logits_close_to_fp32():
-    """One decode step over a gathered int8 cache lands within
-    quantization tolerance of the same step over the fp32 cache."""
+    """One decode step attending an int8 pool lands within
+    quantization tolerance of the same step over the fp32 pool."""
     import jax.numpy as jnp
     model = ToyDecoderLM(vocab=32, n_layers=1, n_heads=2, head_dim=8,
                          max_len=128)
@@ -190,12 +192,13 @@ def test_q8_decode_logits_close_to_fp32():
     positions = jnp.asarray([Lr], jnp.int32)
     ref_logits, _, _ = model.decode(
         params, tokens, positions,
-        kvcache.gather_pages(fk, table[None, :]),
-        kvcache.gather_pages(fv, table[None, :]))
+        functools.partial(kvcache.paged_attention, fk, fv,
+                          table[None, :], positions))
     q8_logits, _, _ = model.decode(
         params, tokens, positions,
-        kvcache.gather_pages_q8(qk, qks, table[None, :]),
-        kvcache.gather_pages_q8(qv, qvs, table[None, :]))
+        functools.partial(kvcache.paged_attention, qk, qv,
+                          table[None, :], positions, k_scale=qks,
+                          v_scale=qvs))
     np.testing.assert_allclose(np.asarray(q8_logits),
                                np.asarray(ref_logits),
                                rtol=0, atol=0.05)
@@ -279,11 +282,12 @@ def test_server_int8_tokens_match_full_forward_q8_oracle(monkeypatch):
     want = [cur]
     pos = len(prompt)
     for _ in range(3):
+        at = jnp.asarray([pos], jnp.int32)
         lg, k_new, v_new = model.decode(
-            params, jnp.asarray([cur], jnp.int32),
-            jnp.asarray([pos], jnp.int32),
-            kvcache.gather_pages_q8(qk, qks, table[None, :]),
-            kvcache.gather_pages_q8(qv, qvs, table[None, :]))
+            params, jnp.asarray([cur], jnp.int32), at,
+            functools.partial(kvcache.paged_attention, qk, qv,
+                              table[None, :], at, k_scale=qks,
+                              v_scale=qvs))
         qk, qks = kvcache.scatter_token_q8(
             qk, qks, table[None, :], jnp.asarray([pos], jnp.int32),
             k_new)

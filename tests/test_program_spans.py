@@ -203,6 +203,15 @@ def test_emit_counts_rows_and_tokens(lines):
     assert sum(e["emitted"] for e in emits) == 4 + 5 + 6 - 3
 
 
+def test_dispatch_carries_the_steps_live_pages(lines):
+    lines, _ = lines
+    steps = [ev[3] for _, ev in _named(lines, "mx:decode.dispatch")]
+    assert steps and all(1 <= s["pages_live"] <= 2 * 2 for s in steps)
+    # prompts of 5, 6, 7 tokens decode at positions 5..7, 6..9, 7..11:
+    # ceil((pos + 1) / 8) pages each, however the rows shared steps
+    assert sum(s["pages_live"] for s in steps) == 3 + 6 + 9
+
+
 def test_h2d_carries_bytes_and_the_array_name(lines):
     lines, _ = lines
     h2d = [ev[3] for _, ev in _named(lines, "mx:pipeline.h2d")]
