@@ -200,7 +200,7 @@ def run(ctx, build):
             steps_until(w0 + min(traffic["trace_after_s"],
                                  ctx.seconds / 3.0))
             sync()
-            # starting, stopping and reducing the trace hold this loop
+            # starting and stopping the trace hold this loop
             t = now()
             with harness.profiler_slice(ctx):
                 held = now() - t
@@ -233,29 +233,23 @@ def run(ctx, build):
         update_rel_err_at=update_at, unnamed_gap="unattributed")
     rel = abs(first_loss - ref_loss) / max(abs(ref_loss), 1e-6)
     ctx.raw["first_loss_rel_diff"] = rel
-    problems = []
-    if not (math.isfinite(rel) and rel <= traffic["loss_rel_tol"]):
-        problems.append("first loss %.5f against the float32 reference's "
-                        "%.5f: off by %.4f, allowed %.4f"
-                        % (first_loss, ref_loss, rel,
-                           traffic["loss_rel_tol"]))
-    if not update_err <= traffic["update_rel_tol"]:
-        problems.append("the first step moved %s off the reference's "
-                        "step by %.4f of the move, allowed %.4f"
-                        % (update_at, update_err,
-                           traffic["update_rel_tol"]))
-    if not last_loss <= traffic["loss_fall_ratio"] * first_loss:
-        problems.append("the loss went from %.4f to %.4f over the run, "
-                        "not under %.2f of where it began"
-                        % (first_loss, last_loss,
-                           traffic["loss_fall_ratio"]))
-    if ctx.raw["dispatches"] != state["steps"]:
-        problems.append("%d dispatches for %d steps"
-                        % (ctx.raw["dispatches"], state["steps"]))
-    if ctx.raw["fallbacks"]:
-        problems.append("%d eager fallbacks" % ctx.raw["fallbacks"])
-    if ctx.raw["compiles_in_window"]:
-        problems.append("%d compilations inside the window"
-                        % ctx.raw["compiles_in_window"])
+    # what decides ``correct``, each beside its limit; the losses and
+    # the leaf behind a number over its limit are in ``raw``
+    compared = {
+        "first_loss_rel_diff": {"value": rel,
+                                "limit": traffic["loss_rel_tol"]},
+        "update_rel_err": {"value": update_err,
+                           "limit": traffic["update_rel_tol"]},
+        "last_over_first_loss": {"value": last_loss / first_loss,
+                                 "limit": traffic["loss_fall_ratio"]},
+        "dispatches_beside_steps": {
+            "value": abs(ctx.raw["dispatches"] - state["steps"]),
+            "limit": 0},
+        "eager_fallbacks": {"value": ctx.raw["fallbacks"], "limit": 0},
+        "compiles_in_window": {"value": ctx.raw["compiles_in_window"],
+                               "limit": 0},
+        "nonfinite_losses": {"value": bad, "limit": 0}}
+    problems = harness.over_limit(compared)
     return {"attempted": state["steps"], "failed": bad,
-            "correct": not problems and not bad, "problems": problems}
+            "correct": not problems, "problems": problems,
+            "compared": compared}

@@ -1,23 +1,27 @@
-"""Output tokens a second, as clients received them inside the window:
-the median, over every run of ``BLOCK`` consecutive tokens, of ``BLOCK``
-over the time the run took. A median of some thousands of readings,
-because the one-chip machine's host now and then stops the whole process
-for one to three seconds (PERF.md, findings of PR 22), and tokens over
-the window's seconds then swings by what the machine did, not the
-program. With fewer than two blocks of tokens it is tokens over
-seconds."""
-import numpy as np
+"""Output tokens a second, as clients received them: every token
+received inside the window over the window's seconds, all the work over
+all the time. A stop of the machine, a stall of the program and a slow
+stretch of the host all move it, as they move what an operator is paid
+for. The steadier reading that leaves such stretches out, the median
+over blocks of consecutive tokens, stands beside it as the per-layer
+metric ``serve_block_tok_per_s``, and ``serve_stall_share`` is the
+distance between the two."""
 
 NAME, UNIT = "serve_tok_per_s", "tokens/s"
-BLOCK = 400
 
 
-def compute(ctx):
+def received(ctx):
+    """The times, from the window's start, of every token received
+    inside it, in order; None for a run that served no stream."""
     if "streams" not in ctx.raw:
         return None
     w = ctx.raw["window_s"]
-    t = np.sort([t for s in ctx.raw["streams"] for t in s["times"]
-                 if 0.0 <= t < w])
-    if len(t) < 2 * BLOCK:
-        return len(t) / w
-    return float(np.median(BLOCK / (t[BLOCK:] - t[:-BLOCK])))
+    return sorted(t for s in ctx.raw["streams"] for t in s["times"]
+                  if 0.0 <= t < w)
+
+
+def compute(ctx):
+    times = received(ctx)
+    if times is None:
+        return None
+    return len(times) / ctx.raw["window_s"]

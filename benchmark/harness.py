@@ -89,6 +89,19 @@ class CompileCounter:
             self.seconds += duration
 
 
+def over_limit(compared):
+    """The problems of a run from ``{name: {value, limit}}``, the numbers
+    that decide ``correct``: a number is sound when it is there and not
+    over its limit (a NaN is over every limit)."""
+    return ["%s %s over its limit %s" % (name, pair["value"], pair["limit"])
+            for name, pair in compared.items()
+            if pair["value"] is None or not pair["value"] <= pair["limit"]]
+
+
+def _trace_dir(ctx):
+    return os.path.join(OUT, "trace", ctx.cell["name"])
+
+
 def span(name):
     """A host span in the profiler's own trace, on the device's clock;
     costs next to nothing while no trace is being taken."""
@@ -98,11 +111,13 @@ def span(name):
 
 @contextlib.contextmanager
 def profiler_slice(ctx):
-    """Trace what runs inside into ``benchmark/out/trace/<cell>`` and
-    leave the reduced trace in ``ctx.trace``."""
+    """Trace what runs inside into ``benchmark/out/trace/<cell>``.
+    ``reduce_trace`` reads it back once the driver has returned: that is
+    seconds of Python, which inside the window held the loop or starved
+    the threads that offer load (the open-loop generator ran 19 and
+    103 ms late behind it, my chip run, PR 26)."""
     import jax
-    from . import trace_reduce
-    trace_dir = os.path.join(OUT, "trace", ctx.cell["name"])
+    trace_dir = _trace_dir(ctx)
     shutil.rmtree(trace_dir, ignore_errors=True)
     os.makedirs(trace_dir, exist_ok=True)
     options = jax.profiler.ProfileOptions()
@@ -113,6 +128,13 @@ def profiler_slice(ctx):
             yield
     finally:
         jax.profiler.stop_trace()
+
+
+def reduce_trace(ctx):
+    """The slice ``profiler_slice`` wrote, reduced into ``ctx.trace``;
+    nothing where the run took no trace."""
+    from . import trace_reduce
+    trace_dir = _trace_dir(ctx)
     path = trace_reduce.find_xplane(trace_dir)
     if path is not None:
         ctx.trace = trace_reduce.Trace(trace_reduce.load(path))

@@ -211,6 +211,22 @@ def idle(ctx):
     return ctx.program_idle
 
 
+def idle_gaps(ctx, n=10):
+    """``[[span or "unattributed", seconds], ...]``, the ``n`` largest:
+    ``idle`` as the result line's ``breakdown.idle_gaps`` wants it.
+    None where ``idle`` gives nothing, and for a configuration that
+    names no ``trace_names.step_module``: the leads are paired for a loop
+    that launches nothing while its step runs, and a configuration whose
+    loop is such names that step."""
+    if not ctx.config.get("trace_names", {}).get("step_module"):
+        return None
+    by_name = idle(ctx)
+    if not by_name:
+        return None
+    return [[name or "unattributed", ns / 1e9] for name, ns in sorted(
+        by_name.items(), key=lambda kv: -kv[1])[:n]]
+
+
 def idle_ms_per_step(ctx, names):
     """Device-idle milliseconds under the spans ``names`` for each
     decode program of the slice."""

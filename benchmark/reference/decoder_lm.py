@@ -10,6 +10,12 @@ in ``configs/opt-6.7b.json``): no biases on the linear layers, an output
 head that is not tied to the embedding, no +2 offset on the positions.
 
 Parameters are the flat ``{name: array}`` dict ``ToyDecoderLM`` uses.
+
+``low=True`` is the CONTROL, not the reference: the same equations with
+every weight and every activation in bfloat16 at the default precision,
+the nearest precision below the float32 the configuration states. The
+comparison that decides ``correct`` has to tell it from the reference
+(``benchmark/tests/test_correct.py``); no benchmark run computes it.
 """
 from __future__ import annotations
 
@@ -18,10 +24,12 @@ import math
 import numpy as np
 
 
-def hidden_states(params, tokens, n_layers, n_heads, head_dim):
+def hidden_states(params, tokens, n_layers, n_heads, head_dim, low=False):
     """``tokens (L,)`` -> final-LayerNorm hidden states ``(L, d_model)``."""
     import jax
     import jax.numpy as jnp
+    if low:
+        params = {k: v.astype(jnp.bfloat16) for k, v in params.items()}
 
     def ln(x, g, b):
         mu = jnp.mean(x, -1, keepdims=True)
@@ -29,7 +37,7 @@ def hidden_states(params, tokens, n_layers, n_heads, head_dim):
         return (x - mu) / jnp.sqrt(var + 1e-5) * g + b
 
     L = tokens.shape[0]
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision("default" if low else "highest"):
         h = params["embed"][tokens] + params["pos"][:L]
         causal = jnp.tril(jnp.ones((L, L), bool))
         for i in range(n_layers):
@@ -49,36 +57,53 @@ def hidden_states(params, tokens, n_layers, n_heads, head_dim):
 
 
 def logits_rows(params, tokens, first_row, n_rows, n_layers, n_heads,
-                head_dim):
+                head_dim, low=False):
     """Logits ``(n_rows, vocab)`` of positions ``first_row ..`` of the
     sequence ``tokens``. Tokens after the rows asked for cannot reach
     them (causal), so a sequence may be padded to a fixed length."""
     import jax
-    h = hidden_states(params, tokens, n_layers, n_heads, head_dim)
+    h = hidden_states(params, tokens, n_layers, n_heads, head_dim, low)
     rows = jax.lax.dynamic_slice_in_dim(h, first_row, n_rows, axis=0)
+    if low:
+        return rows @ params["wout"].astype(rows.dtype)
     with jax.default_matmul_precision("highest"):
         return rows @ params["wout"]
 
 
-def teacher_forced_shortfall(params, prompt, served, n_check, padded_len,
-                             n_layers, n_heads, head_dim):
+def teacher_forced(params, prompt, served, padded_len, n_rows, n_layers,
+                   n_heads, head_dim, control=False):
     """One dense forward over prompt + served tokens: position
-    ``P-1+i`` must predict served token ``i``. Returns, over the first
-    ``n_check`` served tokens: how many are the reference's own argmax,
-    the largest shortfall (reference's best logit minus the logit of the
-    served token) and the standard deviation of the logits, which gives
-    the shortfall its scale."""
+    ``P-1+i`` must predict served token ``i``. Over ALL the served
+    tokens, in units of the standard deviation of the reference's
+    logits: ``worst``, the widest gap by which a served token's logit
+    lies below the reference's best, and ``mean``, the mean gap (0 where
+    the served token is the reference's own). ``padded_len`` and
+    ``n_rows`` only fix the compiled shapes (a causal model's rows do
+    not see what follows them). With ``control`` the same two numbers
+    for the tokens the bfloat16 control puts first at each position of
+    the same sequence, under ``control_worst`` and ``control_mean``."""
     import jax
     import jax.numpy as jnp
-    P = len(prompt)
+    P, n = len(prompt), len(served)
     seq = np.zeros((padded_len,), np.int32)
     seq[:P] = prompt
-    seq[P:P + n_check - 1] = served[:n_check - 1]
-    fn = jax.jit(logits_rows, static_argnums=(3, 4, 5, 6))
-    rows = np.asarray(fn(params, jnp.asarray(seq), jnp.int32(P - 1),
-                         n_check, n_layers, n_heads, head_dim))
-    got = np.asarray(served[:n_check])
-    shortfall = rows.max(axis=1) - rows[np.arange(n_check), got]
-    return {"exact": int((rows.argmax(axis=1) == got).sum()),
-            "of": int(n_check), "max_shortfall": float(shortfall.max()),
-            "logit_std": float(rows.std()), "prompt_len": int(P)}
+    seq[P:P + n - 1] = served[:n - 1]
+    fn = jax.jit(logits_rows, static_argnums=(3, 4, 5, 6, 7))
+    args = (params, jnp.asarray(seq), jnp.int32(P - 1), n_rows, n_layers,
+            n_heads, head_dim)
+    rows = np.asarray(fn(*args))[:n]
+    std = float(rows.std())
+
+    def gaps(tokens):
+        return (rows.max(axis=1) - rows[np.arange(n), tokens]) / std
+
+    got = gaps(np.asarray(served))
+    out = {"tokens": int(n), "prompt_len": int(P), "logit_std": std,
+           "exact": int((got == 0).sum()), "worst": float(got.max()),
+           "mean": float(got.mean())}
+    if control:
+        low = gaps(np.asarray(fn(*args, True))[:n].argmax(axis=1))
+        out.update(control_exact=int((low == 0).sum()),
+                   control_worst=float(low.max()),
+                   control_mean=float(low.mean()))
+    return out

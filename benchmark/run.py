@@ -43,6 +43,10 @@ def parse(argv):
     ap.add_argument("--seconds", type=int, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--control", action="store_true",
+                    help="put the lower-precision control in the program's "
+                         "place where `correct` is decided: the run has to "
+                         "come out not correct (never in a benchmark run)")
     return ap.parse_args(argv)
 
 
@@ -87,7 +91,7 @@ def main(argv=None):
         os.environ["JAX_PLATFORMS"] = "cpu"
     elif args.seconds is None:
         args.seconds = spec["run_seconds"]
-    from benchmark import harness, peaks
+    from benchmark import harness, peaks, program_spans
     cell = harness.by_name(spec["workloads"], args.workload, "workload")
     config_entry = harness.by_name(spec["configs"], cell["config"],
                                    "configuration")
@@ -102,7 +106,11 @@ def main(argv=None):
     from mxnet_tpu import runtime
     cache_dir = runtime.enable_compile_cache()
     import jax
+    # where set-up's seconds go: seconds since the process started,
+    # taken when the named part ended; a driver adds its own
+    stamps = {"imported": time.perf_counter() - T_START}
     devices = jax.devices()
+    stamps["devices"] = time.perf_counter() - T_START
     if not args.rehearse:
         if devices[0].platform != "tpu":
             sys.exit("benchmark: needs a TPU, JAX found %s (%s); "
@@ -113,6 +121,7 @@ def main(argv=None):
                  % (cell["name"], cell["chips"], len(devices)))
 
     ctx = harness.Context(spec, cell, config, traffic, args, T_START)
+    ctx.raw["setup_stamps"] = stamps
     if not args.rehearse:
         ctx.peak = peaks.peak(devices[0].device_kind)
     driver = importlib.import_module(
@@ -120,6 +129,8 @@ def main(argv=None):
     verdict = driver.run(ctx)
 
     if args.trace:
+        # read back once the window has closed and the driver is done
+        harness.reduce_trace(ctx)
         metrics = measure(ctx, spec["per_layer"], "layer_metrics",
                           args.rehearse)
     else:
@@ -131,12 +142,19 @@ def main(argv=None):
               "failed": int(verdict["failed"]),
               "metrics": metrics, "device": device}
     if ctx.trace is not None and ctx.trace.devices:
+        # idle time under the program's own spans, the device's lead
+        # taken off, where the trace lets that be read; the benchmark's
+        # own spans where it does not
         result["breakdown"] = {
             "device_ops": ctx.trace.top_ops(10),
-            "idle_gaps": ctx.trace.idle_gaps(
+            "idle_gaps": program_spans.idle_gaps(ctx, 10)
+            or ctx.trace.idle_gaps(
                 10, unnamed=ctx.raw.get("unnamed_gap", "unattributed"))}
     if args.rehearse:
         result["rehearsal"] = True
+    # every number that decided ``correct`` beside its limit: the last
+    # key of the line and the last lines of standard error
+    result["compared"] = verdict.get("compared", {})
     # what a person debugging wants, on an earlier line and in out/
     detail = {"cell": cell["name"], "seed": args.seed,
               "seconds": args.seconds, "compile_cache": cache_dir,
@@ -150,6 +168,11 @@ def main(argv=None):
     with open(os.path.join(harness.OUT, "%s.seed%d.trace%d.json" % (
             cell["name"], args.seed, args.trace)), "w") as f:
         json.dump(dict(detail, raw=ctx.raw, result=result), f, default=str)
+    sys.stdout.flush()
+    for name, pair in result["compared"].items():
+        print("compared %s %s limit %s" % (name, pair["value"],
+                                           pair["limit"]), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
 
 

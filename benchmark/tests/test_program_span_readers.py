@@ -282,6 +282,30 @@ def test_reader_leaves_its_metric_out_where_the_program_has_no_spans(name):
     assert _reader(name).compute(untraced) is None
 
 
+def test_the_breakdowns_idle_gaps_name_the_programs_spans():
+    """``breakdown.idle_gaps`` of a serving cell: the idle time of the
+    recorded slice by the program's innermost span, the lead taken off,
+    largest first; the benchmark's own spans said "scheduler" 40 ms and
+    "submit" 3. Nothing where the lead cannot be measured (the recorded
+    training slice) or the configuration names no step whose loop the
+    leads are paired for: ``run.py`` then falls back to the ``bench:``
+    spans, by what the trace and the configuration hold and by no flag."""
+    ctx = _ctx("serve")
+    gaps = program_spans.idle_gaps(ctx, 10)
+    assert [name for name, _ in gaps[:2]] == ["decode.wait", "decode.emit"]
+    assert gaps == sorted(gaps, key=lambda g: -g[1]) and len(gaps) == 10
+    by = dict(program_spans.idle_gaps(ctx, 99))
+    assert "scheduler" not in by and "unattributed" in by
+    assert abs(sum(by.values()) - 0.043) < 1e-9
+    emit = by["decode.readback"] + by["decode.emit"] + by["decode.record"]
+    assert abs(emit - 6.4e-3) < 1e-9            # gap_emit_ms's three spans
+    assert program_spans.idle_gaps(_ctx("train"), 10) is None
+    unnamed = _ctx("serve")
+    unnamed.config = {"trace_names": {}}
+    assert program_spans.idle_gaps(unnamed, 10) is None
+    assert ctx.trace.idle_gaps(10, unnamed="scheduler")[0][0] == "scheduler"
+
+
 def test_rehearsal_prints_the_programs_own_spans_as_null():
     """On the CPU the profile has no device, so what needs device 0 is
     left out; what the program's spans and counters alone give is there,

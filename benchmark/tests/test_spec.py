@@ -93,3 +93,52 @@ def test_the_full_check_fits_its_time():
     spec = _spec()
     runs = 2 + 14 * 24
     assert runs * (spec["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+def _load(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return json.load(f)
+
+
+def test_a_configurations_cut_is_said_the_same_everywhere():
+    """``reduced`` in BENCHMARK.json, ``reduced_from`` and the key itself
+    in the file, the kwargs the model is built with and the sentence
+    that gives the reason all name one depth."""
+    served = 0
+    for entry in _spec()["configs"]:
+        cfg = _load(entry["file"])
+        assert sorted(entry["reduced"]) == sorted(cfg.get("reduced_from", {}))
+        for key, published in cfg.get("reduced_from", {}).items():
+            assert cfg[key] < published
+        if "num_hidden_layers" in cfg.get("reduced_from", {}):
+            served += 1
+            depth = cfg["num_hidden_layers"]
+            assert cfg["model"]["kwargs"]["n_layers"] == depth >= 6
+            assert cfg["reduced_from"]["num_hidden_layers"] == 32
+            assert "depth %d" % depth in cfg["why_reduced"].lower()
+            assert "depth %d" % (depth + 1) in cfg["why_reduced"].lower()
+            assert "GB" in cfg["why_reduced"]
+            # the served sizes, which no depth may change
+            kw = cfg["model"]["kwargs"]
+            assert kw["n_heads"] * kw["head_dim"] == cfg["hidden_size"]
+            assert kw["d_ff"] == cfg["ffn_dim"]
+            assert kw["vocab"] == cfg["vocab_size"]
+    assert served == 1
+
+
+def test_an_open_loops_rate_is_four_fifths_of_the_knee_its_reason_names():
+    for cell in _spec()["workloads"]:
+        mix = _load("benchmark", "traffic", cell["traffic"] + ".json")
+        arrivals = mix.get("arrivals", {})
+        if arrivals.get("kind") != "poisson":
+            continue
+        rate = arrivals["rate_per_s"]
+        knee = re.search(r"[Kk]nee (\d+(?:\.\d+)?) req/s", mix["rate_why"])
+        assert knee, mix["rate_why"]
+        assert abs(rate - 0.8 * float(knee.group(1))) < 1e-9
+        said = "%g req/s" % rate
+        assert said in mix["rate_why"] and said in mix["what"]
+        assert said in cell["why"], cell["why"]
+        # the sweep is printed: every rate tried, with what it read
+        assert len(re.findall(r"\d+(?:\.\d+)?: \d+ / \d+",
+                              mix["rate_why"])) >= 5, mix["rate_why"]

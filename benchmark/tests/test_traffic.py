@@ -54,15 +54,6 @@ def test_lognormal_median_is_the_median():
     assert abs(np.median(draws) - 900) < 45
 
 
-def test_poisson_arrivals_repeat_and_hold_their_rate():
-    arr = {"kind": "poisson", "rate_per_s": 10.0}
-    a, b = (traffic.arrival_times(5, arr, 200.0) for _ in range(2))
-    assert np.array_equal(a, b)
-    assert not np.array_equal(a, traffic.arrival_times(6, arr, 200.0)[:len(a)])
-    assert abs(len(a) / 200.0 - 10.0) < 0.5
-    assert (np.diff(a) > 0).all() and a.max() < 200.0
-
-
 def test_image_ring_repeats():
     x1, y1 = traffic.image_ring(3, 4, 8, 10)
     x2, y2 = traffic.image_ring(3, 4, 8, 10)
@@ -77,3 +68,45 @@ def test_image_ring_repeats():
     assert np.isfinite(values).all()
     assert 2 ** -7 <= np.abs(values).min() and np.abs(values).max() < 2
     assert abs(values.mean()) < 0.02
+
+
+def test_an_open_loop_offers_every_seed_the_same_work_in_another_order():
+    """``schedule``: every seed offers the same arrivals' gaps and the
+    same pairs of lengths, shuffled, and repeats itself; a closed loop's
+    clients draw their own. Seeds past 2**31 are seeds like any other."""
+    spec = _load("longprompt-steady.json")
+    horizon, rate = 32.0, spec["arrivals"]["rate_per_s"]
+    runs = []
+    for seed in (3, 3, 4, 2 ** 31 + 11):
+        got = list(traffic.schedule(seed, spec, 50272, horizon))
+        assert len(got) == round(rate * horizon)
+        times = np.array([at for at, _, _ in got])
+        assert 0.0 < times[0] and times[-1] < horizon
+        assert (np.diff(times) > 0).all()
+        assert all(p.dtype == np.int32 and 0 <= p.min() and p.max() < 50272
+                   for _, p, _ in got)
+        runs.append((np.diff(times, prepend=0.0),
+                     [(len(p), k) for _, p, k in got], got[0][1]))
+    (gaps_a, pairs_a, first_a), again, (gaps_b, pairs_b, first_b), \
+        (gaps_c, pairs_c, _) = runs
+    assert np.array_equal(gaps_a, again[0]) and pairs_a == again[1] \
+        and np.array_equal(first_a, again[2])
+    assert pairs_a != pairs_b and sorted(pairs_a) == sorted(pairs_b) \
+        == sorted(pairs_c)
+    assert not np.array_equal(first_a[:32], first_b[:32])
+    assert not np.allclose(gaps_a, gaps_b)
+    assert np.allclose(np.sort(gaps_a), np.sort(gaps_b))
+    assert np.allclose(np.sort(gaps_a), np.sort(gaps_c))
+    # the gaps are a Poisson process's: their deviation is their mean
+    assert 0.8 < gaps_a.std() / gaps_a.mean() < 1.25
+    assert abs(gaps_a.mean() - 1.0 / rate) < 0.01 / rate
+    # the lengths keep to the file's distributions
+    lens = np.array([n for n, _ in pairs_a])
+    assert lens.min() >= spec["prompt_len"]["min"]
+    assert lens.max() == spec["prompt_len"]["max"]
+    assert abs(np.median(lens) - spec["prompt_len"]["median"]) < 90
+    # a closed loop's clients: fresh draws from the seed
+    closed = _load("decode-batch.json")
+    a = [len(p) for p, _ in _take(3, 0, closed, 200)]
+    b = [len(p) for p, _ in _take(4, 0, closed, 200)]
+    assert sorted(a) != sorted(b)

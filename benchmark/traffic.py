@@ -7,6 +7,15 @@ serving cell it gives ``arrivals`` (``closed`` with ``clients``, or
 and ``output_len``; for a training cell the batch and the size of the
 host ring. A distribution is ``{"dist": "uniform" | "lognormal", ...}``;
 every draw is an integer clipped to ``min``..``max``.
+
+A closed loop's clients draw their requests from ``--seed`` without
+end (``requests``). An open loop offers ONE fixed draw of work
+(``schedule``): the gaps of a Poisson process at the file's rate and as
+many pairs of lengths are drawn once from ``OPEN_LOOP_DRAW``, whatever
+the seed, and ``--seed`` only orders them (and draws the token ids).
+With a fresh draw for every seed, two seeds differ in how many requests a window
+holds and how many of them on the longest rung, which a tail reads as a
+difference between runs (PERF.md section 6, PR 26).
 """
 from __future__ import annotations
 
@@ -31,6 +40,9 @@ def draw(gen, spec):
     return int(min(max(int(round(float(value))), spec["min"]), spec["max"]))
 
 
+OPEN_LOOP_DRAW = 26     # the one draw of an open loop's work (PR 26)
+
+
 def requests(seed, stream, traffic, vocab):
     """Endless requests of one stream: ``(prompt int32 array,
     max_new_tokens)``. Token ids are uniform over the vocabulary."""
@@ -41,14 +53,26 @@ def requests(seed, stream, traffic, vocab):
         yield prompt, draw(gen, traffic["output_len"])
 
 
-def arrival_times(seed, arrivals, horizon_s):
-    """Times in ``[0, horizon_s)`` of a Poisson process of the rate
-    ``rate_per_s``."""
-    rate = float(arrivals["rate_per_s"])
-    gen = rng(seed, 2)
-    n = int(rate * horizon_s * 1.5 + 50)
-    times = np.cumsum(gen.exponential(1.0, size=n)) / rate
-    return times[times < horizon_s]
+def schedule(seed, traffic, vocab, horizon_s):
+    """The requests of an open loop over ``horizon_s`` seconds, in
+    order: ``(time, prompt int32 array, max_new_tokens)``, ``rate *
+    horizon`` of them. The gaps between arrivals are those of a Poisson
+    process of the rate ``arrivals.rate_per_s``, scaled so that the last
+    arrival falls inside the horizon; gaps and pairs of lengths are the
+    fixed draw, ``seed`` orders both and draws the token ids: every seed
+    offers as many requests, as long and as unevenly spaced."""
+    n = int(round(float(traffic["arrivals"]["rate_per_s"]) * horizon_s))
+    gaps = rng(OPEN_LOOP_DRAW, 2).exponential(1.0, size=n)
+    gaps *= horizon_s * n / (n + 1.0) / gaps.sum()
+    lengths = rng(OPEN_LOOP_DRAW, 1, 0)
+    pairs = [(draw(lengths, traffic["prompt_len"]),
+              draw(lengths, traffic["output_len"])) for _ in range(n)]
+    times = np.cumsum(rng(seed, 2).permutation(gaps))
+    gen = rng(seed, 1, 0)
+    for at, i in zip(times, gen.permutation(n)):
+        size, asked = pairs[i]
+        yield float(at), gen.integers(0, vocab, size=size,
+                                      dtype=np.int32), asked
 
 
 def image_ring(seed, images, image, classes):
