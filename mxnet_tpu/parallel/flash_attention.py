@@ -860,6 +860,180 @@ def _pallas_paged_decode(q, k_new, v_new, k_pages, v_pages, layer,
          v_pages) + ((k_scale, v_scale) if quant else ())))
 
 
+# ---------------------------------------------------------------------------
+# latent (MLA) decode over a one-array paged cache
+# ---------------------------------------------------------------------------
+
+def _jnp_latent_decode(q, kv, lengths, rank):
+    """The absorbed-form reference: ``q (B, H, W)`` already scaled, ``kv
+    (B, T, W)`` one latent row a token, shared by every head — its first
+    ``rank`` columns are the compressed K/V, the rest the shared rotary
+    key. Scores over all ``W`` columns, float32 softmax over the row's
+    ``lengths`` live tokens, the weighted sum over the first ``rank``
+    columns only: ``(B, H, rank)`` float32."""
+    import jax.numpy as jnp
+    qf, kf = q.astype(jnp.float32), kv.astype(jnp.float32)
+    s = jnp.einsum("bhw,btw->bht", qf, kf)
+    live = jax.lax.iota(jnp.int32, kv.shape[1])[None, :] \
+        < jnp.asarray(lengths, jnp.int32)[:, None]
+    s = jnp.where(live[:, None, :], s, _NEG)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bht,btc->bhc", p, kf[..., :rank])
+
+
+def _mla_decode_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
+                       acc_ref, m_ref, l_ref, *, page_size, n_pages, rank):
+    """Grid = (rows, table columns), columns innermost: one program
+    instance attends ALL heads of one row to ONE page of the latent pool,
+    read where it lies — ``page_ref`` is the page's ``(S, W)`` block, one
+    row a token, the same for every head. That is what makes this an MXU
+    kernel where the per-head paged kernel is VPU arithmetic: the scores
+    of a page are one ``(H, W) x (W, S)`` product and the weighted sum
+    one ``(H, S) x (S, rank)``, bf16 operands, float32 accumulation,
+    float32 running softmax.
+
+    **How the columns are laid.** A token's row is ``W`` = 640 columns
+    for the 576 = 512 + 64 = 4.5 lane tiles it holds: the compressed
+    K/V first, four whole 128-lane tiles, which the value product reads
+    with no relayout; the 64 rotary columns in the first half of the
+    fifth tile; the second half zero. A tiled row-major array whose
+    rows are 576 wide takes 640 columns of HBM all the same, and XLA,
+    to avoid that padding, lays a ``(..., 128, 576)`` array out with the
+    TOKEN axis minor and copies the whole pool to row-major in front of
+    every kernel call and back (three 0.68 GB copies a step in the
+    sandbox compile for a v5e). Declared 640 wide, the pool is
+    row-major by default, the score is ONE product over all five tiles
+    (the query's padding columns are zero) and nothing is copied.
+
+    ``len_ref[b]`` counts the row's tokens IN THE POOL; the step's own
+    latent (``new_ref``, not in the pool yet) opens the accumulation as
+    the first key, as in the per-head paged kernel. Columns at or past
+    ``ceil(len / S)`` name the last live page again (nothing is
+    fetched) and compute nothing."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        new = new_ref[...].astype(jnp.float32)                # (1, W)
+        m_ref[...] = jnp.sum(q_ref[...].astype(jnp.float32) * new,
+                             axis=-1, keepdims=True)
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
+
+    @pl.when(j * page_size < length)
+    def _step():
+        lat = page_ref[:, :rank]                              # (S, rank)
+        s = _dot(q_ref[...], page_ref[...], _NT)              # (H, S)
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        s = jnp.where(pos < length, s, _NEG)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(lat.dtype),
+                                                   lat)
+
+    @pl.when(j == n_pages - 1)
+    def _finish():
+        # the new token is always live, so l >= its weight > 0
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _pallas_latent_decode(q, kv_new, kv_pages, layer, page_table, lengths,
+                          rank, interpret):
+    """``q (B, H, W)`` scaled, in the pool's dtype; ``kv_new (B, 1, W)``;
+    the WHOLE pool ``(L, P, S, W)`` (``layer`` is picked in the index
+    map); ``page_table (B, M)`` and ``lengths (B,)`` ride scalar
+    prefetch. Returns ``(B, H, rank)`` float32."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    S = kv_pages.shape[2]
+    M = page_table.shape[1]
+    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
+    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
+    page_table = jnp.take_along_axis(page_table, columns, axis=1)
+
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, page_size=S, n_pages=M,
+                          rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, M),
+            in_specs=[
+                pl.BlockSpec((None, H, W), lambda b, j, tbl, lens: (b, 0, 0)),
+                pl.BlockSpec((None, 1, W), lambda b, j, tbl, lens: (b, 0, 0)),
+                pl.BlockSpec((None, None, S, W),
+                             lambda b, j, tbl, lens:
+                             (layer, tbl[b * M + j], 0, 0))],
+            out_specs=pl.BlockSpec((None, H, rank),
+                                   lambda b, j, tbl, lens: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, rank), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
+        interpret=interpret,
+        # rows x heads, one query, the table's full width in keys, the
+        # latent's width: what kernel_costs.shapes reads from the event
+        name="mx_mla_decode.bh%d.q1.k%d.d%d.%s.r%d.paged" % (
+            B * H, M * S, W, jnp.dtype(kv_pages.dtype).name, rank),
+    )(page_table.reshape(-1), lengths, q, kv_new, kv_pages)
+
+
+def _latent_write_kernel(pg_ref, slot_ref, new_ref, page_ref, out_ref):
+    """One program instance puts one row's new latent of one layer into
+    its page: the page comes in whole, leaves whole, and differs in the
+    one row ``slot_ref[b]`` (a select over the block, so no store at a
+    dynamic offset into packed 16-bit rows)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    del pg_ref
+    rows = jax.lax.broadcasted_iota(jnp.int32, page_ref.shape, 0)
+    out_ref[...] = jnp.where(rows == slot_ref[pl.program_id(0)],
+                             new_ref[...], page_ref[...])
+
+
+def _pallas_latent_write(pages, page_idx, slot, new, interpret):
+    """The step's new latent rows ``new (L, B, W)`` into the pool
+    ``(L, P, S, W)``, in place (the pool is aliased to the result): row
+    ``b`` lands in page ``page_idx[b]`` at ``slot[b]``, in every layer.
+    XLA's own row writes would do, but for a 4-D pool its layout
+    assignment moves the LAYER axis next to the lanes for them and
+    copies the whole pool there and back every step."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    L, P, S, W = pages.shape
+    B = new.shape[1]
+    page = pl.BlockSpec((None, None, S, W),
+                        lambda b, l, pg, sl: (l, pg[b], 0, 0))
+    return pl.pallas_call(
+        _latent_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, L),
+            in_specs=[pl.BlockSpec((None, None, 1, W),
+                                   lambda b, l, pg, sl: (l, b, 0, 0)),
+                      page],
+            out_specs=page),
+        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        name="mx_latent_write.b%d.l%d.s%d.d%d.%s" % (
+            B, L, S, W, pages.dtype.name),
+    )(page_idx, slot, new.reshape(L, B, 1, W), pages)
+
+
 def flash_decode(q, k, v, lengths, scale=None, block_k=128,
                  force_pallas=False, k_scale=None, v_scale=None):
     """One autoregressive decode step of attention: a single cached-KV

@@ -1,24 +1,39 @@
-"""Mixture-of-Experts with top-k routing over the ``ep`` mesh axis.
+"""Mixture-of-Experts over the ``ep`` mesh axis: two expert layers.
 
-Switch/GShard-style static-capacity dispatch, built for the MXU: the
-token→expert routing is expressed as two dense einsums (dispatch and
-combine) over a one-hot (token, expert, slot) tensor, so the whole MoE
-layer is batched matmuls with static shapes — no scatter, no dynamic
-shapes, nothing XLA can't tile. Experts live on the ``ep`` axis via the
-``(E, D, F)`` leading-dim sharding of the expert weights; XLA inserts
-the all-to-all implied by tokens-sharded-by-dp meeting
-experts-sharded-by-ep.
+**Which is which.**
 
-The reference framework has no MoE (SURVEY §5.7: capability extension);
-routing semantics follow the public Switch Transformer recipe: top-k
-gating with probability renormalisation, capacity factor, load-balance
-auxiliary loss.
+- :func:`moe_ffn` / :func:`topk_route` — Switch/GShard-style
+  static-capacity dispatch (softmax gate, top-k, capacity factor,
+  load-balance loss). Routing is two dense einsums over a one-hot
+  (token, expert, slot) tensor, so the layer is batched matmuls with
+  static shapes; a token that overflows an expert's capacity is DROPPED
+  for that expert. Kept for training-style tests
+  (``tests/test_pipeline_moe.py``); experts live on the ``ep`` axis via
+  the ``(E, D, F)`` leading-dim sharding, XLA inserts the all-to-all.
+- :func:`route_grouped_sigmoid` + :func:`expert_ffn` — the DROPLESS
+  expert layer the serving path uses (``serving.latent_moe``): a
+  sigmoid router with group-limited top-k choice (scores for choice
+  carry a correction bias, weights do not), and an expert layer that is
+  TOLD WHICH EXPERTS IT HOLDS (``held=(lo, hi)`` of the router's full
+  width), routes over all of them, and computes its own experts' part
+  of the result: token slots sorted by expert into a grouped matmul
+  that reads only the experts some token chose. On one chip it runs
+  without its exchange — what the absent experts would have added is
+  simply not in the result, and nothing stands in for the absent chips.
+  ``sharding_rules.held_experts`` gives ``held`` from the ``ep`` axis.
+
+The grouped matmul is the Pallas kernel ``mx_grouped_matmul.…`` on the
+TPU where the shapes tile (:func:`_gmm_tiles`), ``jax.lax.ragged_dot``
+elsewhere (the CPU, and the kernel's test reference); the choice is
+``flash_attention._choose_path``'s, counted at trace time as
+``grouped_matmul_pallas`` / ``grouped_matmul_jnp``.
 """
 from __future__ import annotations
 
 import math
 
-__all__ = ["topk_route", "moe_ffn", "load_balance_loss"]
+__all__ = ["topk_route", "moe_ffn", "load_balance_loss",
+           "route_grouped_sigmoid", "expert_ffn", "expert_load"]
 
 
 def topk_route(gate_logits, k, capacity):
@@ -101,3 +116,232 @@ def moe_ffn(x, gate_w, w1, w2, *, k=2, capacity_factor=1.25, mesh=None,
     expert_out = jnp.einsum("ecf,efd->ecd", h, w2)
     out = jnp.einsum("sec,ecd->sd", combine, expert_out)
     return out.reshape(B, T, D), aux
+
+
+# ---------------------------------------------------------------------------
+# the dropless expert layer (serving)
+# ---------------------------------------------------------------------------
+
+def route_grouped_sigmoid(x, w_gate, bias, *, n_group, topk_group, top_k,
+                          scaling=1.0):
+    """Sigmoid router with group-limited choice, in float32 at
+    "highest" (a tie decided by a bfloat16 pass would send a token to
+    another expert than the reference's).
+
+    ``x (T, D)``; ``w_gate (D, E)``; ``bias (E,)`` — the score
+    correction, used for the CHOICE only. ``s = sigmoid(x @ w_gate)``;
+    the choice is made on ``s + bias``: the ``E`` experts form
+    ``n_group`` groups, a group's score is the sum of its two best, the
+    ``topk_group`` best groups stay (the others' scores are put to 0,
+    as published), and the ``top_k`` best experts of what is left are
+    chosen. The weights are the chosen experts' ``s`` (without the
+    bias) over their sum (+1e-20), times ``scaling``. Returns ``(topi
+    (T, top_k) int32, topw (T, top_k) float32)``; ties go to the lower
+    index (``jax.lax.top_k``)."""
+    import jax
+    import jax.numpy as jnp
+    T = x.shape[0]
+    E = w_gate.shape[-1]
+    # the scope goes into every operation's op_name (an HLO dump and a
+    # profiler's op view show it; a profile's raw event names do not)
+    with jax.named_scope("mx_moe_route"):
+        with jax.default_matmul_precision("highest"):
+            s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                       w_gate.astype(jnp.float32)))
+        choice = s + bias.astype(jnp.float32)
+        grouped = choice.reshape(T, n_group, E // n_group)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)   # (T, G)
+        _, keep = jax.lax.top_k(group_score, topk_group)
+        group_mask = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        masked = jnp.where(group_mask[:, :, None], grouped,
+                           0.0).reshape(T, E)
+        _, topi = jax.lax.top_k(masked, top_k)
+        topw = jnp.take_along_axis(s, topi, axis=1)
+        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20) * scaling
+        return topi.astype(jnp.int32), topw
+
+
+def expert_load(topi, held):
+    """Tokens each HELD expert was sent: ``(hi - lo,)`` int32 from the
+    router's choice ``topi (T, k)`` — the step's own count, which the
+    serving model hands on as its counters."""
+    import jax.numpy as jnp
+    lo, hi = held
+    local = topi.reshape(-1) - lo
+    return jnp.sum(local[:, None] == jnp.arange(hi - lo)[None, :],
+                   axis=0).astype(jnp.int32)
+
+
+_GMM_ROWS = 16        # rows a tile of token slots holds (a bf16 sublane tile)
+_GMM_VMEM = 64 << 20  # of the chip's 128 MiB; the default scoped limit is 16
+
+
+def _gmm_block_n(K, N, itemsize, n_weights):
+    """Columns of the weight block: the widest multiple of 128 dividing
+    ``N`` whose ``(K, bn)`` blocks, double-buffered, keep to 40 MB (all
+    of ``N`` where it is no multiple of 128: the forced kernel of a
+    small test)."""
+    best = None if N % 128 == 0 else N
+    for bn in range(128, N + 1, 128):
+        if N % bn == 0 and 2 * n_weights * K * bn * itemsize <= 40 << 20:
+            best = bn
+    return best
+
+
+def _gmm_tiles(K, N, itemsize=2, n_weights=1):
+    """The kernel's shape predicate: both matrix dims whole lanes and a
+    weight block that fits."""
+    return K % 128 == 0 and N % 128 == 0 \
+        and _gmm_block_n(K, N, itemsize, n_weights) is not None
+
+
+def _gmm_kernel(tile_expert_ref, n_live_ref, x_ref, *refs, gated):
+    """Grid = (column blocks, row tiles), tiles innermost. One program
+    instance multiplies one tile of ``_GMM_ROWS`` token slots, all of
+    one expert, by that expert's ``(K, bn)`` weight block: bf16
+    operands on the MXU, float32 accumulation. Consecutive tiles of one
+    expert name the same weight block, so it is fetched once a column
+    block; tiles at or past ``n_live`` name the last live tile's blocks
+    again (nothing is fetched) and compute nothing. ``gated``: two
+    weights, ``silu(x @ w0) * (x @ w1)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    del tile_expert_ref
+    o_ref = refs[-1]
+
+    @pl.when(pl.program_id(1) < n_live_ref[0])
+    def _():
+        x = x_ref[...]
+        a = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
+        if gated:
+            b = jnp.dot(x, refs[1][...],
+                        preferred_element_type=jnp.float32)
+            a = jax.nn.silu(a) * b
+        o_ref[...] = a.astype(o_ref.dtype)
+
+
+def _pallas_grouped_matmul(x, weights, tile_expert, n_live, out_dtype,
+                           interpret):
+    """``x (R, K)`` rows in tiles of ``_GMM_ROWS``, tile ``i`` all of
+    expert ``tile_expert[i]``; ``weights``: one ``(E, K, N)`` stack, or
+    two for the gated form. Rows of tiles at or past ``n_live`` are
+    left unwritten."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    R, K = x.shape
+    E, _, N = weights[0].shape
+    bn = _gmm_block_n(K, N, weights[0].dtype.itemsize, len(weights))
+    tiles = R // _GMM_ROWS
+
+    def live(i, n_live):
+        return jnp.minimum(i, jnp.maximum(n_live[0], 1) - 1)
+
+    rows = pl.BlockSpec((_GMM_ROWS, K),
+                        lambda j, i, te, nl: (live(i, nl), 0))
+    wblk = pl.BlockSpec((None, K, bn),
+                        lambda j, i, te, nl: (te[live(i, nl)], 0, j))
+    out = pl.BlockSpec((_GMM_ROWS, bn),
+                       lambda j, i, te, nl: (live(i, nl), j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, gated=len(weights) == 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N // bn, tiles),
+            in_specs=[rows] + [wblk] * len(weights), out_specs=out),
+        out_shape=jax.ShapeDtypeStruct((R, N), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_GMM_VMEM),
+        interpret=interpret,
+        name="mx_grouped_matmul.e%d.m%d.k%d.n%d.%s%s" % (
+            E, R, K, N, jnp.dtype(weights[0].dtype).name,
+            ".gated" if len(weights) == 2 else ""),
+    )(tile_expert, n_live, x, *weights)
+
+
+def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
+    """The held experts' part of a routed gated-MLP layer, dropless.
+
+    ``x (T, D)``; ``weights``: ``{"w_gate": (E_held, D, F), "w_up":
+    (E_held, D, F), "w_down": (E_held, F, D)}``, the stacks of experts
+    ``held = (lo, hi)`` of the router's width; ``topi``/``topw`` ``(T,
+    k)`` from :func:`route_grouped_sigmoid` over ALL experts. Returns
+    ``(T, D)`` float32: ``sum_j topw[t, j] * expert_{topi[t, j]}(x[t])``
+    over the chosen experts that are held here; a token none of whose
+    experts is held gets zeros. Every (token, held expert) pair is
+    computed, whatever the load (no capacity).
+
+    The slots are sorted by expert and each expert's group padded to
+    whole tiles of ``_GMM_ROWS`` rows, so that a tile belongs to one
+    expert: the grouped matmul then reads an expert's matrices once and
+    never those of an expert no token chose. The same layout feeds
+    ``jax.lax.ragged_dot`` on the plain path."""
+    import jax
+    import jax.numpy as jnp
+    from .flash_attention import _dispatch
+    lo, hi = held
+    E = hi - lo
+    T, D = x.shape
+    k = topi.shape[1]
+    n_slots = T * k
+    w_gate, w_up, w_down = (weights[n] for n in ("w_gate", "w_up",
+                                                 "w_down"))
+    F = w_gate.shape[-1]
+    m = _GMM_ROWS
+    # the most rows the padded layout can need: every slot held, and
+    # every group's last tile all but empty
+    R = (-(-n_slots // m) + E) * m
+
+    local = topi.reshape(-1) - lo
+    is_held = jnp.logical_and(local >= 0, local < E)
+    key = jnp.where(is_held, local, E)                  # unheld last
+    sizes = expert_load(topi, held)
+    padded = -(-sizes // m) * m
+    start = jnp.cumsum(padded) - padded                 # (E,) row starts
+    order = jnp.argsort(key, stable=True)
+    rank = jnp.zeros((n_slots,), jnp.int32).at[order].set(
+        jnp.arange(n_slots, dtype=jnp.int32))
+    first = jnp.cumsum(sizes) - sizes                   # sorted starts
+    safe = jnp.minimum(key, E - 1)
+    # a held slot's row in the padded layout; an unheld one's is R (dropped)
+    row = jnp.where(is_held, start[safe] + rank - first[safe], R)
+    src = jnp.zeros((R,), jnp.int32).at[row].set(
+        jnp.arange(n_slots, dtype=jnp.int32) // k, mode="drop")
+    xs = x[src].astype(w_gate.dtype)                    # (R, D)
+    tile_ends = jnp.cumsum(padded) // m
+    n_live = tile_ends[-1:].astype(jnp.int32)           # (1,)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(R // m), side="right"),
+        E - 1).astype(jnp.int32)
+
+    def composed(xs, w_gate, w_up, w_down, tile_expert, n_live):
+        groups = padded.astype(jnp.int32)
+        g = jax.lax.ragged_dot(xs, w_gate, groups,
+                               preferred_element_type=jnp.float32)
+        u = jax.lax.ragged_dot(xs, w_up, groups,
+                               preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(w_down.dtype)
+        return jax.lax.ragged_dot(h, w_down, groups,
+                                  preferred_element_type=jnp.float32)
+
+    def kernel(interpret, xs, w_gate, w_up, w_down, tile_expert, n_live):
+        h = _pallas_grouped_matmul(xs, (w_gate, w_up), tile_expert,
+                                   n_live, w_down.dtype, interpret)
+        return _pallas_grouped_matmul(h, (w_down,), tile_expert, n_live,
+                                      jnp.float32, interpret)
+
+    tiles = _gmm_tiles(D, F, w_gate.dtype.itemsize, 2) \
+        and _gmm_tiles(F, D, w_down.dtype.itemsize, 1)
+    # the chooser's shape test is head_dim % 128 and blocks % 128: hand
+    # it the verdict of this kernel's own predicate
+    ys = _dispatch("grouped_matmul", 128 if tiles else 1, (), force_pallas,
+                   kernel, composed, xs, w_gate, w_up, w_down, tile_expert,
+                   n_live)
+    # back to (token, choice): rows of dead tiles are unwritten, so an
+    # unheld slot is masked by a select, never by a product
+    got = ys[jnp.minimum(row, R - 1)].reshape(T, k, D)
+    got = jnp.where(is_held.reshape(T, k, 1), got, 0.0)
+    return jnp.sum(got * topw[:, :, None].astype(jnp.float32), axis=1)

@@ -48,7 +48,7 @@ from __future__ import annotations
 import numpy as _np
 
 __all__ = ["SpecLayout", "parameter_spec_from_name", "ShardingRules",
-           "ParamShardPlan", "param_shard_enabled"]
+           "ParamShardPlan", "param_shard_enabled", "held_experts"]
 
 
 def param_shard_enabled():
@@ -69,13 +69,16 @@ class SpecLayout:
     entry point builds, ``data`` and ``fsdp`` BOTH resolve to ``dp``
     (ZeRO: the data-parallel workers are the shard holders)."""
 
-    __slots__ = ("data_axis", "fsdp_axis", "tp_axis")
+    __slots__ = ("data_axis", "fsdp_axis", "tp_axis", "ep_axis")
 
     def __init__(self, data_axis="data", fsdp_axis="fsdp",
-                 tp_axis="tp"):
+                 tp_axis="tp", ep_axis=None):
         self.data_axis = data_axis
         self.fsdp_axis = fsdp_axis
         self.tp_axis = tp_axis
+        # the expert axis: stacked expert weights ``(E, ...)`` divide
+        # their leading dim over it (no default name: a layout says so)
+        self.ep_axis = ep_axis
 
     @classmethod
     def for_mesh(cls, mesh):
@@ -83,7 +86,8 @@ class SpecLayout:
         ``fsdp`` prefers a literal ``fsdp`` axis, else rides ``dp``;
         ``tp`` only survives when the mesh has a ``tp`` axis of size
         > 1 (a trivial axis would annotate without sharding);
-        ``data`` prefers ``data``, else ``dp``."""
+        ``data`` prefers ``data``, else ``dp``; ``ep`` is the mesh's
+        ``ep`` axis where it has one of size > 1."""
         names = tuple(getattr(mesh, "axis_names", ()))
         sizes = dict(zip(names, mesh.devices.shape)) if names else {}
         data = "data" if "data" in names else \
@@ -91,11 +95,13 @@ class SpecLayout:
         fsdp = "fsdp" if "fsdp" in names else \
             ("dp" if "dp" in names else None)
         tp = "tp" if sizes.get("tp", 0) > 1 else None
-        return cls(data_axis=data, fsdp_axis=fsdp, tp_axis=tp)
+        ep = "ep" if sizes.get("ep", 0) > 1 else None
+        return cls(data_axis=data, fsdp_axis=fsdp, tp_axis=tp,
+                   ep_axis=ep)
 
     def __repr__(self):
-        return "SpecLayout(data=%r, fsdp=%r, tp=%r)" % (
-            self.data_axis, self.fsdp_axis, self.tp_axis)
+        return "SpecLayout(data=%r, fsdp=%r, tp=%r, ep=%r)" % (
+            self.data_axis, self.fsdp_axis, self.tp_axis, self.ep_axis)
 
 
 # name fragments that mark a parameter as replicated regardless of
@@ -114,12 +120,36 @@ _PROJECTION_ROLES = ("q_proj", "k_proj", "v_proj", "o_proj", "qkv",
 _EMBEDDING_ROLES = ("embed", "embedding", "lookup_table", "wte",
                     "wpe")
 
+# a stack of routed experts' matrices, ``(E, ...)``: the leading dim is
+# the expert, and goes over ``ep`` and nothing else
+_EXPERT_ROLES = ("experts.",)
+
+
+def held_experts(n_experts, ep_size=1, ep_rank=0):
+    """``(lo, hi)``: the experts of ``n_experts`` that shard ``ep_rank``
+    of an ``ep`` axis of ``ep_size`` holds — the contiguous block the
+    ``P(ep)`` rule of an expert stack gives it, and what
+    ``parallel.moe.expert_ffn`` is told as ``held``. On an axis of one
+    it is every expert."""
+    n_experts, ep_size, ep_rank = int(n_experts), int(ep_size), \
+        int(ep_rank)
+    if ep_size < 1 or n_experts % ep_size or not 0 <= ep_rank < ep_size:
+        raise ValueError(
+            "held_experts: %d experts do not divide over an ep axis of "
+            "%d (rank %d)" % (n_experts, ep_size, ep_rank))
+    share = n_experts // ep_size
+    return ep_rank * share, (ep_rank + 1) * share
+
 
 def parameter_spec_from_name(name, shape=None, layout=None):
     """Heuristic PartitionSpec for one parameter name (SNIPPETS.md
     [3]'s ``parameter_spec_from_name`` shape, adapted to this repo's
     naming). Precedence:
 
+    0. a stack of routed experts (``experts.`` in the name, rank >= 3)
+       → its leading (expert) dim over ``ep`` when the layout has one,
+       else replicated: never over ``fsdp`` (:func:`held_experts`
+       names the block a shard then holds);
     1. rank ≤ 1 (when ``shape`` is known) → replicated — there is no
        row dim worth sharding and 1-D tensors are noise-sized;
     2. replicated roles (bias/beta/gamma/norm stats/scales) → ``P()``;
@@ -133,11 +163,16 @@ def parameter_spec_from_name(name, shape=None, layout=None):
     Returns a :class:`jax.sharding.PartitionSpec`."""
     from jax.sharding import PartitionSpec as P
     layout = layout or SpecLayout()
+    low = name.lower()
+    if any(r in low for r in _EXPERT_ROLES) \
+            and (shape is None or len(shape) >= 3):
+        # rule 0: an expert stack's leading dim over ``ep``; without a
+        # live ``ep`` axis (a mesh of one) it stays whole
+        return P(layout.ep_axis) if layout.ep_axis is not None else P()
     if layout.fsdp_axis is None:
         return P()
     if shape is not None and len(shape) <= 1:
         return P()
-    low = name.lower()
     if any(r in low for r in _REPLICATED_ROLES):
         return P()
     if any(r in low for r in _EMBEDDING_ROLES):

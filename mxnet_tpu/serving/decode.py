@@ -71,26 +71,46 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   ``/metrics`` gauges (``mxnet_tpu.livemetrics``).
 
 The model contract (see :class:`ToyDecoderLM`, the reference
-implementation):
+implementation; ``serving.latent_moe.LatentMoEDecoderLM`` is the other
+model in the tree). A model DECLARES what it caches and the server's
+pool, programs and copy-on-write follow the declaration:
 
-- ``model.prefill(params, tokens) -> (logits, k, v)`` — ``tokens (B,
-  L)`` int32, causal; ``logits (B, L, V)``; ``k``/``v`` ``(n_layers,
-  B, L, H, D)``. Rows at/after the true prompt length may be garbage
-  (the server routes their K/V to the dump page and never reads their
+- ``model.cache_arrays`` — ``((name, trailing shape[, dtype]), ...)``,
+  one entry a pool array ``(n_layers, pages, page_size, *trailing)``.
+  ``ToyDecoderLM`` declares per-head K and V, ``(("k", (H, D)), ("v",
+  (H, D)))`` (a model with ``n_heads``/``head_dim`` and no declaration
+  is taken to mean that); a latent-attention model declares ONE array
+  whose rows are the compressed K/V and the shared rotary key,
+  ``(("kv", (576,), "bfloat16"),)``. A declared dtype is the pool's;
+  without one the pool keeps its default (``MXNET_KV_DTYPE``).
+- ``model.prefill(params, tokens) -> (logits, *seqs)`` — ``tokens (B,
+  L)`` int32, causal; ``logits (B, L, V)``; one ``seq`` a declared
+  array, ``(n_layers, B, L, *trailing)`` (K and V: ``(n_layers, B, L,
+  H, D)``). Rows at/after the true prompt length may be garbage (the
+  server routes their rows to the dump page and never reads their
   logits).
 - ``model.decode(params, tokens, positions, attend) -> (logits,
-  k_new, v_new)`` — ``tokens (B,)``/``positions (B,)`` int32. The
+  *new[, counters])`` — ``tokens (B,)``/``positions (B,)`` int32. The
   model never sees the cache: for each layer it asks the server's
-  ``attend(layer, q, k_new, v_new, scale=None, force_pallas=False)``
-  — ``q``/``k_new``/``v_new`` ``(B, H, D)``, the step's new token —
-  which attends each row's ``positions`` earlier keys in the pool plus
-  the new token's own at ``positions`` (``kvcache.paged_attention``
-  bound to the step's pools, page tables and positions) and returns
-  ``(B, H, D)``. ``logits (B, V)``; ``k_new``/``v_new`` ``(n_layers,
-  B, H, D)``, which the server writes into the pool after the last
+  ``attend``. For K and V that is ``attend(layer, q, k_new, v_new,
+  scale=None, force_pallas=False)`` — ``q``/``k_new``/``v_new`` ``(B,
+  H, D)``, the step's new token — which attends each row's
+  ``positions`` earlier keys in the pool plus the new token's own at
+  ``positions`` (``kvcache.paged_attention`` bound to the step's
+  pools, page tables and positions) and returns ``(B, H, D)``; for a
+  latent pool ``attend(layer, q, kv_new, rank=, scale=,
+  force_pallas=False)`` (``kvcache.paged_latent_attention``).
+  ``logits (B, V)``; one ``new`` a declared array, ``(n_layers, B,
+  *trailing)``, which the server writes into the pool after the last
   layer.
-- ``model.n_layers`` / ``model.n_heads`` / ``model.head_dim`` size the
-  pool.
+- ``model.step_counters`` (optional) — ``(group, (name, ...))``: the
+  model's ``decode`` then returns, last, an int32 vector with one
+  count a name (what the step's routing did, say). It leaves the
+  device with the step's tokens, rides the ``mx:decode.readback`` span
+  as arguments (it does not exist before the read-back) and
+  accumulates in ``stats()[group]`` (a name that starts with ``max``
+  keeps the largest, the others add up).
+- ``model.n_layers`` sizes the pool with the declaration.
 
 Sampling is greedy (argmax, in-program): deterministic by
 construction, which is what makes "prefill + stepwise cached decode
@@ -278,6 +298,9 @@ class ToyDecoderLM:
         self.max_len = int(max_len)
         self.use_pallas = bool(use_pallas)
         self._scale = 1.0 / float(self.head_dim) ** 0.5
+        # what the server's pool holds for this model: per-head K and V
+        self.cache_arrays = (("k", (self.n_heads, self.head_dim)),
+                             ("v", (self.n_heads, self.head_dim)))
 
     def init_params(self, seed=0):
         import jax
@@ -394,14 +417,35 @@ class DecodeServer:
                  start=True):
         import jax
         from .. import compile_watch
-        for attr in ("prefill", "decode", "n_layers", "n_heads",
-                     "head_dim"):
+        for attr in ("prefill", "decode", "n_layers"):
             if not hasattr(model, attr):
                 raise MXNetError(
                     "DecodeServer: model lacks %r — the decode-model "
-                    "contract is prefill/decode plus "
-                    "n_layers/n_heads/head_dim (see "
-                    "serving.decode.ToyDecoderLM)" % attr)
+                    "contract is prefill/decode, n_layers and the "
+                    "declaration of what it caches, cache_arrays = "
+                    "((name, trailing shape[, dtype]), ...) (see "
+                    "serving.decode.ToyDecoderLM, which declares "
+                    "per-head K and V, and serving.latent_moe, which "
+                    "declares one latent array)" % attr)
+        cache = getattr(model, "cache_arrays", None)
+        if cache is None:
+            if not (hasattr(model, "n_heads")
+                    and hasattr(model, "head_dim")):
+                raise MXNetError(
+                    "DecodeServer: model declares no cache_arrays = "
+                    "((name, trailing shape[, dtype]), ...) and has no "
+                    "n_heads/head_dim to mean per-head K and V by (see "
+                    "serving.decode.ToyDecoderLM)")
+            cache = (("k", (model.n_heads, model.head_dim)),
+                     ("v", (model.n_heads, model.head_dim)))
+        specs = tuple((str(c[0]), tuple(int(d) for d in c[1]))
+                      for c in cache)
+        dtypes = {c[2] for c in cache if len(c) > 2}
+        if len(dtypes) > 1:
+            raise MXNetError(
+                "DecodeServer: the arrays of one pool share a dtype, "
+                "the model declares %s" % sorted(dtypes))
+        self._counters = getattr(model, "step_counters", None)
         self._model = model
         self.name = name
         self._device = device if device is not None else jax.devices()[0]
@@ -426,23 +470,22 @@ class DecodeServer:
                     "DecodeServer: page_size=%d does not match the "
                     "shared pool's %d" % (int(page_size),
                                           pool.page_size))
-            if (pool.n_layers, pool.n_heads, pool.head_dim) != \
-                    (int(model.n_layers), int(model.n_heads),
-                     int(model.head_dim)):
+            if (pool.n_layers, pool.array_specs) != \
+                    (int(model.n_layers), specs):
                 raise MXNetError(
                     "DecodeServer: shared pool geometry (layers=%d, "
-                    "heads=%d, head_dim=%d) does not match the "
-                    "model's (%d, %d, %d) — co-tenant models must "
-                    "agree on the page shape"
-                    % (pool.n_layers, pool.n_heads, pool.head_dim,
-                       model.n_layers, model.n_heads, model.head_dim))
+                    "arrays=%s) does not match the model's (%d, %s) — "
+                    "co-tenant models must agree on the page shape"
+                    % (pool.n_layers, pool.array_specs, model.n_layers,
+                       specs))
             self._pool = pool
             self._own_pool = False
         else:
-            self._pool = KVCachePool(model.n_layers, model.n_heads,
-                                     model.head_dim,
+            self._pool = KVCachePool(model.n_layers, arrays=specs,
                                      page_size=page_size,
                                      n_pages=pool_pages,
+                                     dtype=dtypes.pop() if dtypes
+                                     else None,
                                      device=self._device)
             self._own_pool = True
         self._owner = self._pool.attach(
@@ -492,9 +535,9 @@ class DecodeServer:
         # accelerators; the CPU PJRT client cannot donate (it would
         # only warn per compile), and correctness never depends on it
         donate = {}
+        n_pool = 4 if self._pool.quantized else len(self._pool.arrays)
         if jax.default_backend() not in ("cpu",):
-            donate = {"donate_argnums": (4, 5, 6, 7)
-                      if self._pool.quantized else (4, 5)}
+            donate = {"donate_argnums": tuple(range(4, 4 + n_pool))}
         decode_fn = self._decode_fn_q8 if self._pool.quantized \
             else self._decode_fn
         prefill_fn = self._prefill_fn_q8 if self._pool.quantized \
@@ -513,8 +556,7 @@ class DecodeServer:
             else self._cow_fn
         cow_donate = {}
         if jax.default_backend() not in ("cpu",):
-            cow_donate = {"donate_argnums": (0, 1, 2, 3)
-                          if self._pool.quantized else (0, 1)}
+            cow_donate = {"donate_argnums": tuple(range(n_pool))}
         self._cow_prog = compile_watch.jit(
             cow_fn, "%s:cow" % site, statics=(site, "cow"),
             **cow_donate)
@@ -537,6 +579,7 @@ class DecodeServer:
                        "prefill_s": 0.0, "decode_pages_live": 0,
                        "decode_pages_table": 0}
         self._shed_by_priority = {}
+        self._counted = {}        # the model's step counters, summed
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
         self._intervals = deque(maxlen=ring)    # inter-token ms
         self._ttft = deque(maxlen=ring)         # submit -> first token
@@ -557,40 +600,38 @@ class DecodeServer:
     @property
     def pool(self):
         """The :class:`KVCachePool` this server decodes against (its
-        ``.k``/``.v`` are the live device arrays)."""
+        ``.arrays`` — ``.k``/``.v`` for per-head K and V — are the live
+        device arrays)."""
         return self._pool
 
     # -- compiled programs -------------------------------------------------
-    def _prefill_fn(self, params, tokens, n_valid, page_table, k_pages,
-                    v_pages):
+    def _prefill_fn(self, params, tokens, n_valid, page_table, *pools):
         import jax.numpy as jnp
-        logits, k_seq, v_seq = self._model.prefill(params, tokens)
-        k_pages = kvcache.scatter_prefill(k_pages, page_table,
-                                          k_seq[:, 0], n_valid)
-        v_pages = kvcache.scatter_prefill(v_pages, page_table,
-                                          v_seq[:, 0], n_valid)
+        logits, *seqs = self._model.prefill(params, tokens)
+        pools = kvcache.write_prefill(pools, page_table, seqs, n_valid)
         # greedy sampling in-program; only the token leaves the
         # device — returning the logits too would make XLA
         # materialize a dead (vocab,)-sized output per prefill
         last = jnp.take(logits[0], n_valid - 1, axis=0)
         token = jnp.argmax(last).astype(jnp.int32)
-        return token, k_pages, v_pages
+        return (token, *pools)
 
-    def _decode_fn(self, params, tokens, positions, page_tables,
-                   k_pages, v_pages):
+    def _decode_fn(self, params, tokens, positions, page_tables, *pools):
         import jax.numpy as jnp
-        attend = functools.partial(kvcache.paged_attention, k_pages,
-                                   v_pages, page_tables, positions)
-        logits, k_new, v_new = self._model.decode(
+        attend = kvcache.attend_for(pools, page_tables, positions)
+        logits, *new = self._model.decode(
             params, tokens, positions, attend)
-        k_pages = kvcache.scatter_token(k_pages, page_tables,
-                                        positions, k_new)
-        v_pages = kvcache.scatter_token(v_pages, page_tables,
-                                        positions, v_new)
+        pools = kvcache.write_tokens(
+            pools, page_tables, positions, new,
+            getattr(self._model, "use_pallas", False))
         # only the argmax tokens leave the device: a (window, vocab)
         # logits output would be dead weight on the per-token hot path
         tokens_out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tokens_out, k_pages, v_pages
+        if len(new) > len(pools):
+            # the model's step counters leave with the tokens, one array
+            tokens_out = jnp.concatenate(
+                [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
+        return (tokens_out, *pools)
 
     # int8-pool variants: same program shape, with per-page fp32
     # scales riding alongside the pages. Attention applies them page by
@@ -627,10 +668,10 @@ class DecodeServer:
 
     # copy-on-write page copy — the whole split is one traced program
     # (src/dst ride as traced scalars, so any page pair reuses it)
-    def _cow_fn(self, k_pages, v_pages, src, dst):
-        k_pages = k_pages.at[:, dst].set(k_pages[:, src])
-        v_pages = v_pages.at[:, dst].set(v_pages[:, src])
-        return k_pages, v_pages
+    def _cow_fn(self, *args):
+        *pools, src, dst = args
+        return tuple(pages.at[:, dst].set(pages[:, src])
+                     for pages in pools)
 
     def _cow_fn_q8(self, k_pages, v_pages, k_scales, v_scales, src,
                    dst):
@@ -669,7 +710,7 @@ class DecodeServer:
         if self._pool.quantized:
             return (self._pool.k, self._pool.v, self._pool.k_scale,
                     self._pool.v_scale)
-        return (self._pool.k, self._pool.v)
+        return tuple(self._pool.arrays)
 
     def _adopt_pool(self, out):
         """Re-point the pool at a step program's functionally-updated
@@ -678,8 +719,9 @@ class DecodeServer:
             (self._pool.k, self._pool.v, self._pool.k_scale,
              self._pool.v_scale) = out[-4:]
             return out[:-4]
-        self._pool.k, self._pool.v = out[-2:]
-        return out[:-2]
+        n = len(self._pool.arrays)
+        self._pool.arrays[:] = out[-n:]
+        return out[:-n]
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
@@ -1460,6 +1502,13 @@ class DecodeServer:
             # the last reference to the step's device token array goes
             # here, so that freeing it (0.3 ms on the chip) is timed
             toks = _np.asarray(toks)
+            counts = None
+            if self._counters is not None:
+                # what the model counted in this step (its routing)
+                # exists only now: it rides this span, not the dispatch
+                counts = dict(zip(self._counters[1],
+                                  (int(c) for c in toks[D:])))
+                back.set(**counts)
         now = back.t1
         with tracing.span("decode.emit", rows=len(rows)) as emit:
             if metering.enabled():
@@ -1491,6 +1540,8 @@ class DecodeServer:
                 self._stats["decode_steps"] += 1
                 self._stats["decode_pages_live"] += pages_live
                 self._stats["decode_pages_table"] += D * M
+                if counts is not None:
+                    self._count_step(counts)
                 for i, r in emitting:
                     self._stats["tokens_out"] += 1
                     if r._last_emit is not None:
@@ -1517,6 +1568,17 @@ class DecodeServer:
                 for r in finished:
                     self._finish(r, None)
 
+    def _count_step(self, counts):
+        """One decode step's model counters into the running totals
+        (under ``self._cond``): a name that starts with ``max`` keeps
+        the largest, the others add up; ``last`` is the step's own."""
+        tot = self._counted
+        tot["steps"] = tot.get("steps", 0) + 1
+        for name, value in counts.items():
+            tot[name] = max(tot.get(name, 0), value) \
+                if name.startswith("max") else tot.get(name, 0) + value
+        tot["last"] = counts
+
     # -- stats & telemetry -------------------------------------------------
     def stats(self):
         """Cumulative decode-serving snapshot: request counts, token
@@ -1537,6 +1599,7 @@ class DecodeServer:
                         if r.params is not None}
             versions.add(id(self._params))
             shed_pri = dict(self._shed_by_priority)
+            counted = dict(self._counted)
         steps = s["prefill_steps"] + s["decode_steps"]
         out = {
             "name": getattr(self, "_metrics_label", None)
@@ -1585,6 +1648,8 @@ class DecodeServer:
                 "p50": round(telemetry.percentile(ttft, 50), 3),
                 "p99": round(telemetry.percentile(ttft, 99), 3),
             }
+        if self._counters is not None:
+            out[self._counters[0]] = counted
         if shed_pri:
             out["shed_by_priority"] = {str(k): v for k, v
                                        in sorted(shed_pri.items())}

@@ -27,6 +27,16 @@ sees only fixed shapes:
   donated pool, so the whole decode step stays one compiled program:
   attend → write the token's rows, no host round-trip per token.
 
+**Layouts.** What a page holds is the model's declaration
+(``DecodeServer``'s contract): by default two arrays, per-head K and V,
+``(n_layers, n_pages, page_size, n_heads, head_dim)`` each; a
+latent-attention model declares ONE array ``(n_layers, n_pages,
+page_size, W)`` whose row is the compressed K/V and the shared rotary
+key (:func:`paged_latent_attention`, :func:`write_prefill_pages`,
+:func:`write_token_rows`). The page accounting below — alloc, free,
+refcounts, copy-on-write, preemption, the prefix index — never looks
+inside a page and is the same for both.
+
 Page *accounting* is host-side and lives here too: an allocate/free
 free-list under a lock, with peak/eviction counters for the ``decode``
 telemetry record and the ``/metrics`` gauges. Page reclaim visits the
@@ -100,7 +110,10 @@ from .. import envs, fault
 from ..base import MXNetError
 
 __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
-           "paged_attention", "scatter_token", "scatter_prefill",
+           "paged_attention", "paged_latent_attention", "attend_for",
+           "write_prefill", "write_tokens",
+           "scatter_token", "scatter_prefill", "write_prefill_pages",
+           "write_token_rows",
            "pages_for",
            "gather_pages_q8", "scatter_token_q8",
            "scatter_prefill_q8"]
@@ -195,8 +208,9 @@ def paged_attention(k_pages, v_pages, page_table, positions, layer, q,
 
 def scatter_token(pages, page_table, positions, new):
     """Write one decode step's new K (or V) rows into the pool:
-    ``new (L, B, H, D)`` lands at each row's absolute ``positions
-    (B,)`` through its ``page_table (B, M)`` row. Inactive batch rows
+    ``new (L, B, H, D)`` (``(L, B, W)`` for a latent pool) lands at each
+    row's absolute ``positions (B,)`` through its ``page_table (B, M)``
+    row. Inactive batch rows
     must carry an all-zero table row — their write lands in the dump
     page. Functional: returns the updated pool."""
     import jax
@@ -212,7 +226,8 @@ def scatter_token(pages, page_table, positions, new):
     def write_row(b, pages):
         row = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)
         return jax.lax.dynamic_update_slice(
-            pages, row[:, :, None], (0, pidx[b], slot[b], 0, 0))
+            pages, row[:, :, None],
+            (0, pidx[b], slot[b]) + (0,) * (pages.ndim - 3))
 
     # one in-place row write a batch row, not a scatter: XLA's TPU
     # scatter widens a 16-bit pool to float32 and back, whole
@@ -233,6 +248,139 @@ def scatter_prefill(pages, page_table_row, seq, n_valid):
     pidx = jnp.asarray(page_table_row, jnp.int32)[pos // S]
     pidx = jnp.where(pos < n_valid, pidx, 0)
     return pages.at[:, pidx, pos % S].set(seq)
+
+
+def write_prefill_pages(pages, page_table_row, seq, n_valid):
+    """:func:`scatter_prefill` as in-place page writes, for a float pool
+    of any width (the latent pool's prefill): ``seq (L, Lr, ...)``, a
+    whole number of pages (rungs are page-aligned), goes in page by
+    page with ``dynamic_update_slice``, as :func:`scatter_token` writes
+    rows — XLA's TPU scatter widens a 16-bit pool to float32 and back,
+    whole. A page that starts at or past ``n_valid`` is all padding and
+    goes to the dump page. The page that holds position ``n_valid``
+    takes the rung's padding rows after it too: finite garbage at
+    positions no query attends before a decode step has overwritten
+    them, and never part of a shared prefix (the index takes full pages
+    of true tokens only)."""
+    import jax
+    import jax.numpy as jnp
+    S = pages.shape[2]
+    n = seq.shape[1] // S
+    table = jnp.asarray(page_table_row, jnp.int32)
+    seq = seq.astype(pages.dtype)
+    tail = (0,) * (pages.ndim - 3)
+    for c in range(n):
+        pidx = jnp.where(c * S < n_valid, table[c], 0)
+        chunk = jax.lax.slice_in_dim(seq, c * S, (c + 1) * S, axis=1)
+        pages = jax.lax.dynamic_update_slice(
+            pages, chunk[:, None], (0, pidx, 0) + tail)
+    return pages
+
+
+def write_token_rows(pages, page_table, positions, new,
+                     force_pallas=False):
+    """:func:`scatter_token` for a latent pool ``(L, P, S, W)``: ``new
+    (L, B, W)`` at each row's ``positions`` through its table row. On
+    the TPU, where the page tiles, a Pallas kernel rewrites each row's
+    page in place (``mx_latent_write``); elsewhere
+    :func:`scatter_token`'s row writes, the kernel's test reference.
+    Counted as ``latent_write_pallas`` / ``latent_write_jnp``."""
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import _dispatch, _pallas_latent_write
+    S = pages.shape[2]
+    pos = jnp.asarray(positions, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    new = new.astype(pages.dtype)
+
+    def composed(pages, table, pos, new):
+        return scatter_token(pages, table, pos, new)
+
+    def kernel(interpret, pages, table, pos, new):
+        pidx = jnp.take_along_axis(table, (pos // S)[:, None],
+                                   axis=1)[:, 0]
+        return _pallas_latent_write(pages, pidx, pos % S, new, interpret)
+
+    return _dispatch("latent_write", pages.shape[-1], (S,), force_pallas,
+                     kernel, composed, pages, table, pos, new)
+
+
+def paged_latent_attention(kv_pages, page_table, positions, layer, q,
+                           kv_new, *, rank, scale, force_pallas=False):
+    """:func:`paged_attention`'s sibling for a latent pool — one array
+    ``(L, P, S, W)``, a token's row the compressed K/V (``rank``
+    columns) and the shared rotary key (the other ``W - rank``), the
+    same for every head. ``q (B, H, W)`` is the absorbed query (its
+    first ``rank`` columns the no-position part carried through the
+    key up-projection, the rest the rotary part), ``kv_new (B, W)`` the
+    step's own latent, NOT in the pool yet and attended at
+    ``positions`` with the row's ``positions`` earlier tokens. Returns
+    the softmax-weighted sum of the first ``rank`` columns, ``(B, H,
+    rank)`` float32 — the caller carries it through the value
+    up-projection.
+
+    Operands in the pool's dtype, accumulation and softmax in float32.
+    On the TPU, where ``rank`` and the page size are multiples of 128,
+    the Pallas kernel ``mx_mla_decode`` reads each row's live pages
+    where they lie; elsewhere :func:`gather_pages` + jnp, the kernel's
+    test reference. Counted as ``mla_decode_pallas`` /
+    ``mla_decode_jnp``."""
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import (_dispatch, _jnp_latent_decode,
+                                            _pallas_latent_decode)
+    pos = jnp.asarray(positions, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    q = (q * scale).astype(kv_pages.dtype)
+    kv_new = kv_new.astype(kv_pages.dtype)
+
+    def composed(q, kv_new, kv_pages, table, pos):
+        kc = gather_pages(kv_pages[layer:layer + 1], table)[0]
+        kc = kc.at[jnp.arange(q.shape[0]), pos].set(kv_new)
+        return _jnp_latent_decode(q, kc, pos + 1, rank)
+
+    def kernel(interpret, q, kv_new, kv_pages, table, pos):
+        return _pallas_latent_decode(q, kv_new[:, None], kv_pages, layer,
+                                     table, pos, rank, interpret)
+
+    return _dispatch("mla_decode", rank, (kv_pages.shape[2],),
+                     force_pallas, kernel, composed, q, kv_new, kv_pages,
+                     table, pos)
+
+
+# what a float pool's layout decides, in one place: two arrays are
+# per-head K and V, one is a latent pool
+
+def _latent(pools):
+    return len(pools) == 1
+
+
+def attend_for(pools, page_tables, positions):
+    """The ``attend`` a decode step hands its model: :func:`paged_attention`
+    over K and V, :func:`paged_latent_attention` over a latent pool."""
+    import functools
+    fn = paged_latent_attention if _latent(pools) else paged_attention
+    return functools.partial(fn, *pools, page_tables, positions)
+
+
+def write_prefill(pools, page_table_row, seqs, n_valid):
+    """One request's prefill sequences ``(L, B=1, Lr, ...)`` into their
+    pools: :func:`scatter_prefill` for K and V,
+    :func:`write_prefill_pages` (in place for a 16-bit pool) for a
+    latent pool."""
+    fn = write_prefill_pages if _latent(pools) else scatter_prefill
+    return tuple(fn(pages, page_table_row, seq[:, 0], n_valid)
+                 for pages, seq in zip(pools, seqs))
+
+
+def write_tokens(pools, page_tables, positions, new, force_pallas=False):
+    """One decode step's new rows into their pools:
+    :func:`scatter_token` for K and V, :func:`write_token_rows` for a
+    latent pool."""
+    if _latent(pools):
+        return tuple(write_token_rows(pages, page_tables, positions, rows,
+                                      force_pallas)
+                     for pages, rows in zip(pools, new))
+    return tuple(scatter_token(pages, page_tables, positions, rows)
+                 for pages, rows in zip(pools, new))
 
 
 # ---------------------------------------------------------------------------
@@ -394,8 +542,9 @@ class KVCachePool:
     order is deterministic, so tests can predict table contents. Page
     0 is reserved as the dump page and never allocated."""
 
-    def __init__(self, n_layers, n_heads, head_dim, *, page_size=None,
-                 n_pages=None, dtype=None, device=None):
+    def __init__(self, n_layers, n_heads=None, head_dim=None, *,
+                 arrays=None, page_size=None, n_pages=None, dtype=None,
+                 device=None):
         import jax.numpy as jnp
         self.page_size = int(page_size) if page_size is not None \
             else envs.get_int("MXNET_KV_PAGE_SIZE")
@@ -408,8 +557,15 @@ class KVCachePool:
             raise MXNetError(
                 "KVCachePool: need at least 2 pages (page 0 is the "
                 "reserved dump page), got %d" % self.n_pages)
-        shape = (int(n_layers), self.n_pages, self.page_size,
-                 int(n_heads), int(head_dim))
+        # the layout: ``(name, trailing shape)`` an array. Per-head K
+        # and V by default; a latent model declares ONE array whose
+        # rows are the compressed K/V and the shared rotary key
+        if arrays is None:
+            arrays = (("k", (int(n_heads), int(head_dim))),
+                      ("v", (int(n_heads), int(head_dim))))
+        self.array_specs = tuple(
+            (str(name), tuple(int(d) for d in trailing))
+            for name, trailing in arrays)
         if dtype is None:
             name = envs.get_str("MXNET_KV_DTYPE") or "float32"
             try:
@@ -421,22 +577,27 @@ class KVCachePool:
         dtype = jnp.dtype(dtype)
         self.dtype = dtype
         self.quantized = dtype == jnp.int8
+        if self.quantized and len(self.array_specs) != 2:
+            raise MXNetError(
+                "KVCachePool: int8 pages with per-page scales exist for "
+                "the per-head K/V layout only")
+        lead = (int(n_layers), self.n_pages, self.page_size)
         # allocated ON the target device: a replica's pool must never
         # be staged through the first chip's memory on its way there
-        self.k = jnp.zeros(shape, dtype, device=device)
-        self.v = jnp.zeros(shape, dtype, device=device)
+        self.arrays = [jnp.zeros(lead + trailing, dtype, device=device)
+                       for _name, trailing in self.array_specs]
         self.k_scale = self.v_scale = None
         if self.quantized:
-            self.k_scale = jnp.zeros(shape[:2], jnp.float32,
+            self.k_scale = jnp.zeros(lead[:2], jnp.float32,
                                      device=device)
-            self.v_scale = jnp.zeros(shape[:2], jnp.float32,
+            self.v_scale = jnp.zeros(lead[:2], jnp.float32,
                                      device=device)
         self.n_layers = int(n_layers)
-        self.n_heads = int(n_heads)
-        self.head_dim = int(head_dim)
+        self.n_heads = int(n_heads) if n_heads is not None else None
+        self.head_dim = int(head_dim) if head_dim is not None else None
         self._lock = threading.Lock()
         # serializes co-tenant servers' compiled steps on the shared
-        # functional arrays — two schedulers must never fork .k/.v
+        # functional arrays — two schedulers must never fork the arrays
         self.step_lock = threading.Lock()
         self._free = list(range(self.n_pages - 1, 0, -1))  # pop() -> 1
         self._used_peak = 0
@@ -450,9 +611,32 @@ class KVCachePool:
         self._cow_splits = 0
         self._quota_denials = 0
         self.prefix = PrefixIndex(self.page_size)
-        # bytes one token's K+V occupies across all layers
-        self.token_bytes = (2 * self.n_layers * self.n_heads
-                            * self.head_dim * self.dtype.itemsize)
+        # bytes one token occupies across all layers and arrays
+        values = 0
+        for _name, trailing in self.array_specs:
+            width = 1
+            for d in trailing:
+                width *= d
+            values += width
+        self.token_bytes = self.n_layers * values * self.dtype.itemsize
+
+    # the per-head layout's two arrays by name (the int8 paths, the
+    # tests and the tools read them)
+    @property
+    def k(self):
+        return self.arrays[0]
+
+    @k.setter
+    def k(self, value):
+        self.arrays[0] = value
+
+    @property
+    def v(self):
+        return self.arrays[1]
+
+    @v.setter
+    def v(self, value):
+        self.arrays[1] = value
 
     @property
     def usable_pages(self):
@@ -701,6 +885,8 @@ class KVCachePool:
                 "page_size": self.page_size,
                 "pages": self.usable_pages,
                 "dtype": str(self.dtype),
+                "arrays": {n: list(t) for n, t in self.array_specs},
+                "token_bytes": self.token_bytes,
                 "free": free,
                 "used": self.usable_pages - free,
                 "peak_used": self._used_peak,

@@ -177,8 +177,9 @@ def test_decode_step_program_compiles_and_fits(chip, monkeypatch, shapes):
     """The ``decode:step`` program — ToyDecoderLM.decode attending the
     pool through the paged Pallas kernel, one kernel call a layer, then
     the token's row writes — at chip_smoke's widths and at the
-    benchmark's own (4 layers, window 8, 16 pages a row, 160 float32
-    pool pages), compiled for one v5e from ``jax.eval_shape``-made
+    benchmark's own (depth 8, window 8, 16 pages a row, 160 float32
+    pool pages: ``benchmark/configs/opt-6.7b.json``), compiled for one
+    v5e from ``jax.eval_shape``-made
     shapes: inside the chip's 16 GB, the donated pools updated in place,
     and no copy of the pool or of a gathered cache among its
     temporaries (PR 22's step planned 4.57 GB of them). The platform
@@ -214,3 +215,83 @@ def test_decode_step_program_compiles_and_fits(chip, monkeypatch, shapes):
     pools = 2 * L * pool_pages * S * Hh * Dh * 4
     assert mem.alias_size_in_bytes >= pools, mem
     assert mem.temp_size_in_bytes < 0.5e9, mem
+
+
+def test_latent_moe_programs_compile_and_fit(chip, monkeypatch):
+    """``benchmark/configs/dots.vlm1.inst.json`` at its published widths
+    (7168 wide, 128 heads of 128+64 / 128, ranks 1536 and 512, experts
+    of 2048, 16 of 256 held, 1 dense + 5 expert layers, window 64, 768
+    bf16 pages of 128 x 640): the ``decode:step`` and the 256-rung
+    ``decode:prefill`` programs compiled for one described v5e. In each:
+    the Mosaic kernels under the names a profile's reader looks for —
+    the paged latent decode kernel and the in-place row write (step),
+    the flash kernel at 256-wide heads (prefill), the two grouped
+    matmuls of every expert layer — the planned bytes inside the chip
+    with room for the reference that decides ``correct`` beside the
+    weights, the donated pool updated in place and NO copy of it among
+    the temporaries (declared 576 wide, XLA laid the pool out token-minor
+    and copied 0.68 GB three times a step; written by XLA's own row
+    writes, it moved the layer axis next to the lanes and copied twice)."""
+    from mxnet_tpu.serving import DecodeServer
+    from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "dots.vlm1.inst.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    rung = max(srv["seq_ladder"])
+    M = -(-(rung + srv["max_new_tokens"]) // S)
+    model = LatentMoEDecoderLM(**cfg["model"]["kwargs"])
+    assert model.held == (0, 16) and model.row_width == 640
+    L, moe_layers = model.n_layers, model.n_moe_layers
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert 10.9e9 < weights < 11.1e9
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    pool = spec((L, pages, S, model.row_width), jnp.bfloat16)
+    pool_bytes = L * pages * S * model.row_width * 2
+    holder = type("S", (), {"_model": model})()
+
+    def named(text, kernel):
+        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                          % kernel, text, re.M)
+
+    step = jax.jit(lambda *a: DecodeServer._decode_fn(holder, *a),
+                   donate_argnums=(4,)).lower(
+        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W, M), jnp.int32), pool).compile()
+    text = step.as_text()
+    assert len(named(text, "mla_decode")) == L
+    assert ".k%d.d%d.bfloat16.r%d.paged" % (
+        M * S, model.row_width, model.kv_rank) in text
+    assert len(named(text, "latent_write")) == 1
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == L + 1 + 2 * moe_layers
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    assert mem.temp_size_in_bytes < 0.1e9, mem      # no pool copy
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11.5e9 < planned < 12.5e9, mem
+
+    prefill = jax.jit(lambda *a: DecodeServer._prefill_fn(holder, *a),
+                      donate_argnums=(4,)).lower(
+        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
+        spec((M,), jnp.int32), pool).compile()
+    text = prefill.as_text()
+    assert len(named(text, "flash_fwd")) == L
+    assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    mem = prefill.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert planned < 12.5e9, mem

@@ -1,0 +1,631 @@
+"""The latent-attention / routed-expert decoder on ``DecodeServer``:
+the model (``serving.latent_moe``), the one-array latent pool
+(``serving.kvcache``), the dropless expert layer (``parallel.moe``), the
+``ep`` rule (``parallel.sharding_rules``) and the Pallas kernels
+(interpret mode), against the benchmark's plain float32 reference
+(``benchmark/reference/latent_moe_lm.py``) at a small size with seeded
+bf16 weights. The widened decode-model contract leaves ``ToyDecoderLM``'s
+programs as they were."""
+import functools
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+from benchmark.reference import latent_moe_lm as ref      # noqa: E402
+from mxnet_tpu import compile_watch, fault, profiler, telemetry  # noqa: E402
+from mxnet_tpu.parallel import moe, sharding_rules        # noqa: E402
+from mxnet_tpu.serving import (DecodeServer, KVCachePool,   # noqa: E402
+                               ServerOverloadedError, ToyDecoderLM,
+                               kvcache)
+from mxnet_tpu.serving.latent_moe import (LatentMoEDecoderLM,  # noqa: E402
+                                          yarn_inv_freq, yarn_mscale)
+
+YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+        "type": "yarn"}
+# the published block's shape at a test's size: latent rank 128 (whole
+# lane tiles, so the Pallas paths tile as at the real 512), 32 experts
+# in 4 groups, 2 kept, top 4, one dense layer in front
+CFG = dict(vocab_size=256, hidden_size=128, num_hidden_layers=3,
+           num_attention_heads=4, q_lora_rank=64, kv_lora_rank=128,
+           qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+           intermediate_size=256, moe_intermediate_size=128,
+           n_routed_experts=32, n_shared_experts=1, num_experts_per_tok=4,
+           n_group=4, topk_group=2, routed_scaling_factor=2.5,
+           first_k_dense_replace=1, rope_theta=10000, rope_scaling=YARN,
+           rms_norm_eps=1e-6, max_position_embeddings=512)
+
+
+@pytest.fixture(autouse=True)
+def _clean_state():
+    fault.reset()
+    telemetry.reset()
+    compile_watch.disable()
+    yield
+    fault.reset()
+    telemetry.reset()
+    compile_watch.disable()
+
+
+@functools.lru_cache(maxsize=None)
+def _model(ep=(0, 2), use_pallas=False, seed=3):
+    model = LatentMoEDecoderLM(**CFG, ep=ep, use_pallas=use_pallas)
+    return model, model.init_params(seed=seed)
+
+
+def _drain(srv, *reqs, limit=800):
+    n = 0
+    while not all(r.done() for r in reqs):
+        srv._tick()
+        n += 1
+        assert n < limit, "scheduler made no progress"
+
+
+def _cached_logits(model, params, tokens, n_prompt, page_size=16):
+    """Logits of positions ``n_prompt - 1 ..`` from the SERVING path:
+    one prefill over the prompt written into a paged latent pool, then
+    one decode step a token through the server's own ``attend`` and row
+    writes — what ``DecodeServer``'s two programs compute, with the
+    logits kept."""
+    L = len(tokens)
+    rung = -(-n_prompt // page_size) * page_size
+    n_pages = -(-L // page_size) + 1
+    pool = KVCachePool(model.n_layers, arrays=[c[:2] for c in
+                                               model.cache_arrays],
+                       dtype=model.cache_arrays[0][2], page_size=page_size,
+                       n_pages=n_pages + 1)
+    pages = pool.arrays[0]
+    table = np.zeros((n_pages,), np.int32)
+    table[:] = np.arange(1, n_pages + 1)
+    padded = np.zeros((1, rung), np.int32)
+    padded[0, :n_prompt] = tokens[:n_prompt]
+    logits, rows = jax.jit(model.prefill)(params, padded)
+    pages = kvcache.write_prefill_pages(pages, table, rows[:, 0], n_prompt)
+    out = [np.asarray(logits[0, n_prompt - 1])]
+
+    @jax.jit
+    def step(pages, tok, pos):
+        attend = kvcache.attend_for((pages,), table[None], pos)
+        logits, new, _ = model.decode(params, tok, pos, attend)
+        return logits[0], kvcache.write_token_rows(
+            pages, table[None], pos, new, model.use_pallas)
+
+    for p in range(n_prompt, L):
+        lg, pages = step(pages, jnp.asarray(tokens[p:p + 1]),
+                         jnp.asarray([p], jnp.int32))
+        out.append(np.asarray(lg))
+    return np.stack(out)
+
+
+# ---------------------------------------------------------------------------
+# the model against the reference
+# ---------------------------------------------------------------------------
+
+# The program rounds every activation to bf16 in front of a product (8
+# bits of mantissa: 2**-9 relative a rounding, a few dozen roundings deep)
+# and the reference none: at these widths a position's logits lie within
+# 0.03 deviations of the reference's (the worst of a position's logits,
+# over seeds and both paths). The router is discrete: where two experts'
+# scores are closer than that rounding the choice flips, and the position
+# is a whole expert off (0.9 deviations seen) — a flip of a near-tie is
+# not an error, so up to one position in twenty may be over. The control
+# (the reference with weights and latent rounded to float8_e4m3fn, 3 bits
+# of mantissa) is 0.3 deviations and more off at EVERY position. 0.08 is
+# three times the program's worst unflipped position and a quarter of the
+# control's best.
+LOGIT_TOLERANCE = 0.08
+
+
+def _position_errors(got, want):
+    """Per position: the worst logit's distance, in deviations of the
+    reference's logits."""
+    return np.abs(got - want).max(axis=1) / want.std()
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_prefill_then_cached_decode_agrees_with_the_reference_on_logits(
+        use_pallas):
+    model, params = _model(use_pallas=use_pallas)
+    tokens = np.random.default_rng(1).integers(
+        0, model.vocab, size=56).astype(np.int32)
+    n_prompt = 21
+    n_rows = len(tokens) - n_prompt + 1
+    got = _cached_logits(model, params, tokens, n_prompt)
+    want = np.asarray(ref.logits_rows(
+        params, jnp.asarray(tokens), n_prompt - 1, n_rows, CFG, model.held))
+    err = _position_errors(got, want)
+    assert np.percentile(err, 90) < LOGIT_TOLERANCE, err
+    assert (err > LOGIT_TOLERANCE).mean() <= 0.05, err
+    # tight enough that the next precision down fails it, everywhere
+    low = np.asarray(ref.logits_rows(
+        params, jnp.asarray(tokens), n_prompt - 1, n_rows, CFG, model.held,
+        low=True))
+    assert _position_errors(low, want).min() > 2 * LOGIT_TOLERANCE
+
+
+def test_absorbed_and_published_attention_forms_agree():
+    """Decode (absorbed: the query through the key up-projection, the
+    weighted latent through the value up-projection) against prefill
+    (published: keys and values expanded for every head) at the same
+    positions of one sequence."""
+    model, params = _model()
+    tokens = np.random.default_rng(2).integers(
+        0, model.vocab, size=40).astype(np.int32)
+    cached = _cached_logits(model, params, tokens, 9)
+    padded = np.zeros((1, 48), np.int32)
+    padded[0, :40] = tokens
+    full = np.asarray(jax.jit(model.prefill)(params, padded)[0][0, 8:40])
+    err = _position_errors(cached, full)
+    assert np.percentile(err, 90) < 0.05, err
+    assert (err > 0.05).mean() <= 0.05, err
+
+
+def test_served_tokens_are_the_references_own_or_near_ties():
+    model, params = _model()
+    srv = DecodeServer(model, params, seq_ladder=[32], max_new_tokens=24,
+                       page_size=16, window=4, pool_pages=32, start=False)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, model.vocab, size=n).astype(np.int32)
+               for n in (7, 19, 32)]
+    reqs = [srv.submit(p, max_new_tokens=24) for p in prompts]
+    _drain(srv, *reqs)
+    for prompt, req in zip(prompts, reqs):
+        out = ref.teacher_forced(params, prompt, req.result(), 64, 24, CFG,
+                                 model.held)
+        # a served token is the reference's own, or lies a rounding
+        # under it: the mean gap is a hundredth of a deviation at most
+        assert out["mean"] < 0.01 and out["exact"] >= 20, out
+    st = srv.stats()
+    assert st["kv"]["arrays"] == {"kv": [model.row_width]}
+    assert st["kv"]["token_bytes"] == model.n_layers * model.row_width * 2
+    assert st["kv"]["dtype"] == "bfloat16"
+    moe_st = st["moe"]
+    assert moe_st["steps"] == st["decode_steps"] > 0
+    # 4 rows x top 4 x 2 expert layers; a held share of 16 of 32
+    assert 0 < moe_st["moe_slots"] <= moe_st["steps"] * 4 * 4 * 2
+    assert 0 < moe_st["experts_touched"] <= moe_st["steps"] * 16 * 2
+    assert 1 <= moe_st["max_load"] <= 4
+    assert set(moe_st["last"]) == {"moe_slots", "experts_touched",
+                                   "max_load"}
+    srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# the router, YaRN, the shares
+# ---------------------------------------------------------------------------
+
+def _route_both(x, w, b, **kw):
+    mine = moe.route_grouped_sigmoid(x, w, b, **kw)
+    theirs = ref.route(x, w, b, **kw)
+    return [np.asarray(a) for a in (*mine, *theirs)]
+
+
+def test_router_against_the_reference_groups_scaling_and_ties():
+    kw = dict(n_group=4, topk_group=2, top_k=4, scaling=2.5)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(keys[0], (64, 32))
+    w = jax.random.normal(keys[1], (32, 32)) * 0.3
+    b = jax.random.normal(keys[2], (32,)) * 0.05
+    ti, tw, ri, rw = _route_both(x, w, b, **kw)
+    assert (np.sort(ti, -1) == np.sort(ri, -1)).all()
+    np.testing.assert_allclose(np.sort(tw, -1), np.sort(rw, -1), rtol=1e-6)
+    # the weights are the chosen scores over their sum, times the factor
+    np.testing.assert_allclose(tw.sum(-1), 2.5, rtol=1e-6)
+    # every chosen expert lies in one of 2 groups of 8
+    assert all(len(set(row // 8)) <= 2 for row in ti)
+    # the bias moves the CHOICE, never the weights
+    s = np.asarray(jax.nn.sigmoid(x @ w))
+    np.testing.assert_allclose(
+        tw, 2.5 * np.take_along_axis(s, ti, 1)
+        / np.take_along_axis(s, ti, 1).sum(-1, keepdims=True), rtol=1e-5)
+    pushed, _, _, _ = _route_both(x, w, b.at[31].set(10.0), **kw)
+    assert (pushed == 31).any(axis=1).all()
+    # all scores tied: the lower index wins, groups 0 and 1, experts 0-3
+    ti, tw, ri, rw = _route_both(x, jnp.zeros((32, 32)), jnp.zeros(32), **kw)
+    assert (ti == np.arange(4)).all() and (ri == np.arange(4)).all()
+    np.testing.assert_allclose(tw, 2.5 / 4, rtol=1e-6)
+
+
+def test_yarn_frequencies_and_scale_against_hand_values():
+    f = yarn_inv_freq(64, 10000.0, 40.0, 4096, 32.0, 1.0)
+    assert f.shape == (32,)
+    # correction range: 64 ln(4096 / (32 * 2 pi)) / (2 ln 1e4) = 10.47,
+    # 64 ln(4096 / (2 pi)) / (2 ln 1e4) = 22.51 -> dims 0..10 keep their
+    # frequency, 23..31 are slowed 40 times, a ramp of 13 steps between
+    hand = 10000.0 ** (-np.arange(0, 64, 2) / 64.0)
+    np.testing.assert_allclose(f[:11], hand[:11], rtol=1e-6)
+    np.testing.assert_allclose(f[23:], hand[23:] / 40.0, rtol=1e-6)
+    ramp = (16 - 10) / 13.0
+    np.testing.assert_allclose(
+        f[16], hand[16] * (1 - ramp) + hand[16] / 40.0 * ramp, rtol=1e-6)
+    np.testing.assert_allclose(f, ref.inv_freq(dict(CFG, qk_rope_head_dim=64)),
+                               rtol=1e-6)
+    assert abs(yarn_mscale(40, 1) - (0.1 * math.log(40) + 1)) < 1e-12
+    assert yarn_mscale(1, 1) == 1.0
+    model = LatentMoEDecoderLM(**dict(CFG, qk_nope_head_dim=128,
+                                      qk_rope_head_dim=64))
+    assert abs(model.scale - 192 ** -0.5 * 1.3688879 ** 2) < 1e-6
+    assert abs(model.scale - ref.score_scale(dict(
+        CFG, qk_nope_head_dim=128, qk_rope_head_dim=64))) < 1e-9
+    assert model.rope_gain == 1.0
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer():
+    """Expert parallelism's contract at a small size: the 16 shares'
+    routed parts, and the shared expert counted ONCE, add up to what
+    the uncut reference gives for the whole layer."""
+    model, params = _model(ep=(0, 1))
+    assert model.held == (0, 32)
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, CFG["hidden_size"]))
+    whole, _ = ref.moe_layer(x, params, "l1.", CFG, (0, 32))
+    topi, topw = moe.route_grouped_sigmoid(
+        x, params["l1.router_w"], params["l1.router_b"], n_group=4,
+        topk_group=2, top_k=4, scaling=2.5)
+    total = model._gated(x, params, "l1.shared.")
+    for rank in range(16):
+        lo, hi = sharding_rules.held_experts(32, 16, rank)
+        share = {n: params["l1.experts." + n][lo:hi]
+                 for n in ("w_gate", "w_up", "w_down")}
+        total = total + moe.expert_ffn(x, share, topi, topw, (lo, hi))
+        assert int(moe.expert_load(topi, (lo, hi)).sum()) \
+            == int(((topi >= lo) & (topi < hi)).sum())
+    assert np.abs(np.asarray(total - whole)).max() \
+        / np.asarray(whole).std() < 0.03
+    # one share alone is NOT the layer
+    one = model._gated(x, params, "l1.shared.") + moe.expert_ffn(
+        x, {n: params["l1.experts." + n][:2]
+            for n in ("w_gate", "w_up", "w_down")}, topi, topw, (0, 2))
+    assert np.abs(np.asarray(one - whole)).max() \
+        / np.asarray(whole).std() > 0.3
+
+
+def test_ep_rule_and_held_experts():
+    from jax.sharding import PartitionSpec as P
+    layout = sharding_rules.SpecLayout(ep_axis="ep")
+    spec = sharding_rules.parameter_spec_from_name
+    assert spec("l3.experts.w_gate", (16, 8, 4), layout) == P("ep")
+    # no live ep axis (a mesh of one): the stack stays whole
+    assert spec("l3.experts.w_gate", (16, 8, 4)) == P()
+    assert spec("l3.shared.w_gate", (8, 4), layout) != P("ep")
+    assert sharding_rules.held_experts(256, 16, 0) == (0, 16)
+    assert sharding_rules.held_experts(256, 16, 15) == (240, 256)
+    assert sharding_rules.held_experts(16) == (0, 16)
+    with pytest.raises(ValueError):
+        sharding_rules.held_experts(10, 4, 0)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                             ("dp", "ep"))
+    rules = sharding_rules.ShardingRules(mesh)
+    assert rules.layout.ep_axis == "ep"
+    plan = rules.plan("l1.experts.w_down", (32, 128, 128))
+    assert plan.spec == P("ep", None, None)
+    one = sharding_rules.ShardingRules(jax.sharding.Mesh(
+        np.array(jax.devices()[:1]), ("ep",)))
+    assert one.plan("l1.experts.w_down", (32, 128, 128)).spec \
+        == P(None, None, None)
+
+
+# ---------------------------------------------------------------------------
+# the latent pool: page accounting unchanged
+# ---------------------------------------------------------------------------
+
+def test_latent_pool_layout_and_bytes():
+    pool = KVCachePool(6, arrays=(("kv", (640,)),), dtype="bfloat16",
+                       page_size=128, n_pages=4)
+    assert [a.shape for a in pool.arrays] == [(6, 4, 128, 640)]
+    assert pool.arrays[0].dtype == jnp.bfloat16
+    assert pool.token_bytes == 6 * 640 * 2
+    kv = KVCachePool(2, 2, 8, page_size=8, n_pages=4)
+    assert kv.array_specs == (("k", (2, 8)), ("v", (2, 8)))
+    assert kv.k.shape == kv.v.shape == (2, 4, 8, 2, 8)
+    assert kv.token_bytes == 2 * 2 * 2 * 8 * 4
+    with pytest.raises(Exception):
+        KVCachePool(2, arrays=(("kv", (64,)),), dtype="int8",
+                    page_size=8, n_pages=4)
+
+
+def test_prefill_writes_do_not_widen_a_bf16_pool():
+    """The latent pool's prefill writes are in-place page writes: no
+    scatter (XLA's TPU scatter widens a 16-bit pool to float32, whole),
+    and no float32 copy of the pool anywhere in the program."""
+    pages = jnp.zeros((2, 5, 16, 128), jnp.bfloat16)
+    seq = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 128))
+    table = jnp.asarray([3, 1, 0, 0], jnp.int32)
+    fn = jax.jit(kvcache.write_prefill_pages)
+    text = fn.lower(pages, table, seq, 20).as_text()
+    assert "scatter" not in text and "dynamic_update_slice" in text
+    assert "tensor<2x5x16x128xf32>" not in text
+    out = np.asarray(fn(pages, table, seq, 20).astype(jnp.float32))
+    want = np.asarray(seq.astype(jnp.bfloat16).astype(jnp.float32))
+    assert (out[:, 3] == want[:, :16]).all()
+    assert (out[:, 1, :4] == want[:, 16:20]).all()
+    assert (out[:, [2, 4]] == 0).all()
+    # a page wholly past the prompt goes to the dump page
+    out = np.asarray(fn(pages, table, seq, 16).astype(jnp.float32))
+    assert (out[:, 1] == 0).all() and (out[:, 0] == want[:, 16:]).all()
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_prefix_lookup_and_copy_on_write_on_a_latent_pool(use_pallas):
+    model, params = _model(use_pallas=use_pallas)
+    rng = np.random.default_rng(6)
+    system = rng.integers(0, model.vocab, size=32).astype(np.int32)
+    prompts = [np.concatenate([system, rng.integers(
+        0, model.vocab, size=n).astype(np.int32)]) for n in (5, 9)]
+    prompts.append(system.copy())      # fully cached, page-aligned: COW
+
+    def run(prefix_cache):
+        srv = DecodeServer(model, params, seq_ladder=[48],
+                           max_new_tokens=10, page_size=16, window=2,
+                           pool_pages=24, prefix_cache=prefix_cache,
+                           start=False)
+        outs = []
+        for p in prompts:               # one after the other: the first
+            r = srv.submit(p, max_new_tokens=10)   # fills the index
+            _drain(srv, r)
+            outs.append(r.result().tolist())
+        st = srv.stats()
+        srv.stop()
+        return outs, st
+
+    shared, st = run(True)
+    private, _ = run(False)
+    # a hit feeds the un-cached suffix through the decode step (the
+    # absorbed form), a miss through prefill (the published form): the
+    # same model under another rounding, so the two streams are each
+    # the reference's own up to near-ties, not token-identical as
+    # ToyDecoderLM's bit-matched float32 paths are
+    for prompt, a, b in zip(prompts, shared, private):
+        for served in (a, b):
+            out = ref.teacher_forced(params, prompt, np.asarray(served),
+                                     64, 10, CFG, model.held)
+            # the reference's own token at 8 of 10 positions at the
+            # least: a router's near-tie that flips costs one
+            assert out["exact"] >= 8, out
+        assert a[0] == b[0]
+    assert st["prefix"]["hits"] == 2 and st["prefix"]["hit_tokens"] == 64
+    assert st["prefix"]["cow_splits"] >= 1
+    assert st["prefix"]["bytes_saved"] == 64 * st["kv"]["token_bytes"]
+
+
+def test_pool_pressure_preempts_on_a_latent_pool():
+    model, params = _model()
+    srv = DecodeServer(model, params, seq_ladder=[16], max_new_tokens=40,
+                       page_size=16, window=3, pool_pages=6, start=False)
+    rng = np.random.default_rng(8)
+    low = [srv.submit(rng.integers(0, model.vocab, size=12).astype(np.int32),
+                      max_new_tokens=40, priority=0) for _ in range(2)]
+    high = srv.submit(rng.integers(0, model.vocab, size=12).astype(np.int32),
+                      max_new_tokens=40, priority=1)
+    _drain(srv, *low, high)
+    assert len(high.result()) == 40
+    failed = [r for r in low if r._error is not None]
+    assert failed and all(isinstance(r._error, ServerOverloadedError)
+                          for r in failed)
+    st = srv.stats()
+    assert st["preempted"] == len(failed)
+    assert st["kv"]["used"] == 0 and st["kv"]["free"] == 5
+    srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# the widened contract leaves ToyDecoderLM where it was
+# ---------------------------------------------------------------------------
+
+def _old_decode_fn(model, params, tokens, positions, page_tables, k_pages,
+                   v_pages):
+    """``DecodeServer._decode_fn`` as it stood before the contract was
+    widened (PR 24), kept here as the oracle."""
+    attend = functools.partial(kvcache.paged_attention, k_pages, v_pages,
+                               page_tables, positions)
+    logits, k_new, v_new = model.decode(params, tokens, positions, attend)
+    k_pages = kvcache.scatter_token(k_pages, page_tables, positions, k_new)
+    v_pages = kvcache.scatter_token(v_pages, page_tables, positions, v_new)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), k_pages, v_pages
+
+
+def _old_prefill_fn(model, params, tokens, n_valid, page_table, k_pages,
+                    v_pages):
+    logits, k_seq, v_seq = model.prefill(params, tokens)
+    k_pages = kvcache.scatter_prefill(k_pages, page_table, k_seq[:, 0],
+                                      n_valid)
+    v_pages = kvcache.scatter_prefill(v_pages, page_table, v_seq[:, 0],
+                                      n_valid)
+    last = jnp.take(logits[0], n_valid - 1, axis=0)
+    return jnp.argmax(last).astype(jnp.int32), k_pages, v_pages
+
+
+def test_toy_decoder_runs_the_programs_it_ran():
+    model = ToyDecoderLM(vocab=32, n_layers=2, n_heads=2, head_dim=8,
+                         max_len=128)
+    assert model.cache_arrays == (("k", (2, 8)), ("v", (2, 8)))
+    params = model.init_params(seed=3)
+    holder = type("S", (), {"_model": model})()
+    pool = jnp.zeros((2, 24, 8, 2, 8), jnp.float32)
+    step_args = (params, jnp.zeros((3,), jnp.int32),
+                 jnp.zeros((3,), jnp.int32), jnp.zeros((3, 6), jnp.int32),
+                 pool, pool)
+    new = jax.make_jaxpr(functools.partial(DecodeServer._decode_fn,
+                                           holder))(*step_args)
+    old = jax.make_jaxpr(functools.partial(_old_decode_fn,
+                                           model))(*step_args)
+    assert str(new) == str(old)
+    pre_args = (params, jnp.zeros((1, 16), jnp.int32), jnp.int32(5),
+                jnp.zeros((6,), jnp.int32), pool, pool)
+    new = jax.make_jaxpr(functools.partial(DecodeServer._prefill_fn,
+                                           holder))(*pre_args)
+    old = jax.make_jaxpr(functools.partial(_old_prefill_fn,
+                                           model))(*pre_args)
+    assert str(new) == str(old)
+
+
+# tokens the parent commit (a0dcd2b) served for this model, these prompts
+GOLDEN = [[19, 25, 19, 25, 19, 25, 19, 25, 1, 12, 11, 12],
+          [29, 14, 4, 19, 25, 19, 25, 19, 25, 19, 25, 19],
+          [7, 5, 12, 11, 12, 11, 12, 21, 6, 19, 22, 29]]
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_toy_decoder_same_program_set_same_tokens(use_pallas):
+    compile_watch.enable()
+    model = ToyDecoderLM(vocab=32, n_layers=2, n_heads=2, head_dim=8,
+                         max_len=128, use_pallas=use_pallas)
+    name = "gold%d" % use_pallas
+    srv = DecodeServer(model, model.init_params(seed=3),
+                       seq_ladder=[16, 32], max_new_tokens=12, page_size=8,
+                       window=3, pool_pages=24, name=name, start=False)
+    rng = np.random.default_rng(7)
+    reqs = [srv.submit(rng.integers(0, 32, size=n).astype(np.int32),
+                       max_new_tokens=12) for n in (5, 17, 30)]
+    _drain(srv, *reqs)
+    assert [r.result().tolist() for r in reqs] == GOLDEN
+    sites = compile_watch.site_stats("decode:" + name)
+    assert sorted(sites) == ["decode:%s:prefill:s16" % name,
+                             "decode:%s:prefill:s32" % name,
+                             "decode:%s:step" % name]
+    assert all(s["count"] == 1 for s in sites.values())
+    assert "moe" not in srv.stats()
+    srv.stop()
+
+
+def test_latent_model_fixed_program_set():
+    compile_watch.enable()
+    model, params = _model()
+    srv = DecodeServer(model, params, seq_ladder=[16, 32],
+                       max_new_tokens=8, page_size=16, window=2,
+                       pool_pages=16, name="lat", start=False)
+    rng = np.random.default_rng(9)
+    reqs = [srv.submit(rng.integers(0, model.vocab, size=n).astype(np.int32),
+                       max_new_tokens=8) for n in (3, 16, 20, 31)]
+    _drain(srv, *reqs)
+    sites = compile_watch.site_stats("decode:lat")
+    assert sorted(sites) == ["decode:lat:prefill:s16",
+                             "decode:lat:prefill:s32", "decode:lat:step"]
+    assert all(s["count"] == 1 for s in sites.values())
+    srv.stop()
+
+
+def test_contract_errors_name_the_widened_contract():
+    class NoCache:
+        n_layers = 1
+
+        def prefill(self, *a):
+            pass
+
+        def decode(self, *a):
+            pass
+
+    with pytest.raises(Exception, match="cache_arrays"):
+        DecodeServer(NoCache(), {}, start=False)
+    with pytest.raises(Exception, match="cache_arrays"):
+        DecodeServer(object(), {}, start=False)
+    model, params = _model()
+    pool = KVCachePool(3, 2, 8, page_size=16, n_pages=8)
+    with pytest.raises(Exception, match="geometry"):
+        DecodeServer(model, params, pool=pool, seq_ladder=[16],
+                     max_new_tokens=4, start=False)
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernels, interpreted, against the jnp paths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("positions", [[40, 16, 0], [63, 1, 15], [0, 0, 0]])
+def test_latent_decode_kernel_matches_gather_reference(positions):
+    L, P, S, W, R, H, B = 2, 9, 16, 256, 128, 4, 3
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    pool = jax.random.normal(k[0], (L, P, S, W)).astype(jnp.bfloat16)
+    q = jax.random.normal(k[1], (B, H, W))
+    new = jax.random.normal(k[2], (B, W))
+    table = jnp.asarray([[1, 2, 3, 7], [4, 5, 0, 0], [6, 0, 0, 0]],
+                        jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    before = dict(profiler.counters())
+    for layer in (0, 1):
+        a = kvcache.paged_latent_attention(pool, table, pos, layer, q, new,
+                                           rank=R, scale=0.05)
+        b = kvcache.paged_latent_attention(pool, table, pos, layer, q, new,
+                                           rank=R, scale=0.05,
+                                           force_pallas=True)
+        assert a.shape == b.shape == (B, H, R) and b.dtype == jnp.float32
+        # the kernel rounds the softmax weights to bf16 for the MXU
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 0.02
+    after = profiler.counters()
+    assert after.get("mla_decode_jnp", 0) - before.get("mla_decode_jnp", 0) \
+        == 2
+    assert after.get("mla_decode_pallas", 0) \
+        - before.get("mla_decode_pallas", 0) == 2
+    # position 0: nothing in the pool, the new token attends to itself
+    if positions[2] == 0:
+        assert np.abs(np.asarray(b[2])
+                      - np.asarray(new[2, :R].astype(jnp.bfloat16),
+                                   np.float32)).max() < 1e-6
+
+
+def test_latent_row_write_kernel_is_the_row_writes():
+    L, P, S, W, B = 2, 9, 16, 128, 3
+    k = jax.random.split(jax.random.PRNGKey(1), 2)
+    pool = jax.random.normal(k[0], (L, P, S, W)).astype(jnp.bfloat16)
+    new = jax.random.normal(k[1], (L, B, W))
+    table = jnp.asarray([[1, 2, 3, 7], [4, 5, 0, 0], [0, 0, 0, 0]],
+                        jnp.int32)
+    pos = jnp.asarray([40, 16, 0], jnp.int32)
+    a = kvcache.write_token_rows(pool, table, pos, new)
+    b = kvcache.write_token_rows(pool, table, pos, new, force_pallas=True)
+    assert a.dtype == b.dtype == jnp.bfloat16 and bool((a == b).all())
+    changed = np.asarray((a != pool).any(axis=-1))
+    assert changed.sum() == L * B
+    assert changed[:, 3, 8].all() and changed[:, 5, 0].all() \
+        and changed[:, 0, 0].all()
+
+
+@pytest.mark.parametrize("case", ["spread", "one_expert", "none_held"])
+def test_grouped_matmul_kernel_matches_ragged_dot(case):
+    T, D, F, E = 24, 128, 256, 8
+    held = (8, 16)
+    k = jax.random.split(jax.random.PRNGKey(2), 6)
+    x = jax.random.normal(k[0], (T, D))
+    w = {"w_gate": jax.random.normal(k[1], (E, D, F)) * D ** -0.5,
+         "w_up": jax.random.normal(k[2], (E, D, F)) * D ** -0.5,
+         "w_down": jax.random.normal(k[3], (E, F, D)) * F ** -0.5}
+    w = {n: v.astype(jnp.bfloat16) for n, v in w.items()}
+    if case == "spread":
+        topi = jax.random.randint(k[4], (T, 4), 0, 32)
+    elif case == "one_expert":       # every token on one held expert:
+        topi = jnp.full((T, 4), 11).at[:, 1:].set(  # several row tiles
+            jnp.asarray([0, 1, 2]))
+    else:
+        topi = jax.random.randint(k[4], (T, 4), 16, 32)
+    topw = jax.random.uniform(k[5], (T, 4)) + 0.1
+    before = dict(profiler.counters())
+    a = moe.expert_ffn(x, w, topi, topw, held)
+    b = moe.expert_ffn(x, w, topi, topw, held, force_pallas=True)
+    after = profiler.counters()
+    assert after.get("grouped_matmul_jnp", 0) \
+        - before.get("grouped_matmul_jnp", 0) == 1
+    assert after.get("grouped_matmul_pallas", 0) \
+        - before.get("grouped_matmul_pallas", 0) == 1
+    # a plain loop, float32 from the same bf16 operands
+    want = np.zeros((T, D), np.float32)
+    xb = np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))
+    wf = {n: np.asarray(v.astype(jnp.float32)) for n, v in w.items()}
+    for t in range(T):
+        for j in range(4):
+            e = int(topi[t, j]) - held[0]
+            if 0 <= e < E:
+                g, u = xb[t] @ wf["w_gate"][e], xb[t] @ wf["w_up"][e]
+                h = np.asarray(jnp.asarray(g / (1 + np.exp(-g)) * u)
+                               .astype(jnp.bfloat16).astype(jnp.float32))
+                want[t] += float(topw[t, j]) * (h @ wf["w_down"][e])
+    scale = max(np.abs(want).max(), 1.0)
+    assert np.abs(np.asarray(a) - want).max() / scale < 2e-3
+    assert np.abs(np.asarray(b) - want).max() / scale < 2e-3
+    if case == "none_held":
+        assert not np.asarray(a).any() and not np.asarray(b).any()
