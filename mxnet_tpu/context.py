@@ -15,13 +15,15 @@ XLA owns HBM — so the context is purely a placement annotation.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Optional
 
 from .base import MXNetError, classproperty
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
-           "num_gpus", "num_tpus", "gpu_memory_info"]
+           "num_gpus", "num_tpus", "gpu_memory_info", "placement_scope",
+           "current_placement"]
 
 
 class Context:
@@ -178,3 +180,28 @@ def gpu_memory_info(device_id=0):
 def current_context() -> Context:
     """The thread-local default context (reference: context.py:257)."""
     return Context.default_ctx
+
+
+@contextlib.contextmanager
+def placement_scope(placement):
+    """The thread-local default *placement*, beside the default context:
+    inside the scope, arrays this thread makes from host memory without
+    an explicit ``ctx`` (``mx.nd.array(host)``) are ``placement``'s
+    owner's to place. ``placement`` is whatever the owner resolves
+    later — a ``jax.Device``, a ``Sharding``, a per-array callable — so
+    the constructor sends the bytes nowhere and keeps them host-side.
+    The input pipeline opens it around a source's decode on its own
+    threads (``io/pipeline.py``); it never reaches another thread and
+    ends with the ``with`` block. ``None`` opens no scope."""
+    local = Context._default_ctx
+    outer = getattr(local, "placement", None)
+    local.placement = placement
+    try:
+        yield
+    finally:
+        local.placement = outer
+
+
+def current_placement():
+    """The placement scope open on this thread, or None."""
+    return getattr(Context._default_ctx, "placement", None)

@@ -21,14 +21,28 @@ compute/transfer-overlap argument of PyTorch DDP (Li et al., VLDB
    ``NDArrayIter``/``ImageRecordIter``) get true multi-worker decode;
    any other iterator degrades to serialized ``next()`` calls — still
    fully asynchronous with the consumer, like the old prefetcher.
-2. **Device prefetch** — a placer thread calls ``jax.device_put`` on
-   the next ``prefetch_depth`` batches (against the consumer's device
-   or ``Sharding`` when a mesh / data-parallel placement is active)
-   and *blocks until the transfer lands*, so H2D overlaps the current
-   step's compute and the consumer receives device-resident arrays.
-   Bytes and latency are accounted per array name under the telemetry
-   ``h2d`` kind (``tools.diagnose`` renders an H2D table showing how
-   much transfer ran off the critical path).
+2. **Device prefetch** — the pipeline owns where a batch goes. While a
+   placement is set, the source decodes inside a
+   ``context.placement_scope``: ``mx.nd.array(host)`` without a ``ctx``
+   sends the bytes nowhere and hands over the source's own host memory
+   (a ``HostStagedNDArray``). The placer thread then resolves the
+   placement for each array — a device, a ``Sharding``, or the
+   per-array callable, where name and shape are known — and calls
+   ``jax.device_put(host, target)``: a view of the host array for each
+   target chip and one host-to-chip transfer each, no stop on the
+   default device on the way. It *blocks until the transfer lands*, so
+   H2D overlaps the current step's compute and the consumer receives
+   device-resident arrays. An array that arrives already committed to
+   devices (built outside the decode threads, or with an explicit
+   ``ctx``) is resharded device to device as before, and counted:
+   ``profiler.counters()`` has ``pipeline_placed_from_host`` and
+   ``pipeline_resharded``, the ``pipeline.h2d`` span carries the
+   ``route``. Bytes and latency are accounted per array name under the
+   telemetry ``h2d`` kind (``tools.diagnose`` renders an H2D table
+   showing how much transfer ran off the critical path). A source must
+   not write to host memory it has handed to ``mx.nd.array`` in its
+   decode before the batch is delivered: until the placer has sent it,
+   the array IS that memory.
 3. **Backpressure-bounded buffering** — every queue is bounded
    (decode: workers+depth futures; ready: ``prefetch_depth``), every
    put is stop-aware (timeout loop checking the stop event), and
@@ -49,11 +63,13 @@ never pollutes the step timeline.
 """
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
 from .. import envs, tracing
+from ..context import placement_scope
 from .io import DataBatch, DataIter
 
 __all__ = ["AsyncInputPipeline", "data_workers", "pipeline_enabled",
@@ -107,28 +123,52 @@ def _placement_target(placement, name, data):
     return placement
 
 
-def _put_one(nd_arr, target, name):
-    """Commit one NDArray to ``target`` and block until it is resident
-    — on the placer thread, off the step critical path. When the array
-    already sits where asked (``nd_array``'s async ``jnp.asarray``
-    dispatched it to the default device), the block is still the
-    transfer-completion barrier the consumer would otherwise pay
-    inside its first op; either way the batch's bytes and the wait are
-    accounted under h2d."""
+# what profiler.counters() counts, by the route an array took
+_ROUTE_COUNTER = {"host": "pipeline_placed_from_host",
+                  "reshard": "pipeline_resharded"}
+
+
+def _put_one(nd_arr, placement, name):
+    """Send one NDArray where ``placement`` says and block until it is
+    resident — on the placer thread, off the step critical path. Three
+    routes, told apart by what the array in hand is:
+
+    - ``host``: still the source's host memory (made inside the
+      pipeline's placement scope). ``jax.device_put(host, target)``
+      takes a view per target chip and makes one host-to-chip transfer
+      each; with no target it goes to its own context's device.
+    - ``reshard``: already committed to devices, elsewhere. Today's
+      device-to-device ``device_put``, unchanged.
+    - ``resident``: already where asked. The block is still the
+      transfer-completion barrier the consumer would otherwise pay
+      inside its first op.
+
+    Either way the array's bytes and the wait are accounted under h2d.
+    """
     import jax
 
-    from .. import telemetry
+    from .. import profiler, telemetry
     from ..ndarray import NDArray
-    if target is None or getattr(nd_arr, "stype", "default") != "default":
+    if getattr(nd_arr, "stype", "default") != "default":
         return nd_arr            # sparse batches stay host-side
-    data = getattr(nd_arr, "_data", None)
+    host = getattr(nd_arr, "host", None)
+    data = host if host is not None else nd_arr._data
     if data is None:
         return nd_arr
-    sharding = getattr(data, "sharding", None)
-    resident = sharding == target or (
-        getattr(target, "device_kind", None) is not None
-        and getattr(data, "devices", None) is not None
-        and data.devices() == {target})
+    target = _placement_target(placement, name, data)
+    if host is not None:
+        route = "host"
+        if target is None:
+            target = nd_arr._ctx.jax_device()
+    elif target is None:
+        return nd_arr
+    elif getattr(data, "sharding", None) == target or (
+            getattr(target, "device_kind", None) is not None
+            and getattr(data, "devices", None) is not None
+            and data.devices() == {target}):
+        route = "resident"
+    else:
+        route = "reshard"
     nbytes = int(getattr(data, "nbytes", 0) or 0)
     out = nd_arr
     # the placer runs AHEAD of consumption by design; while the ring is
@@ -136,12 +176,14 @@ def _put_one(nd_arr, target, name):
     # open while it ran — explicit args, not thread identity (this
     # thread is off the accounting thread on purpose)
     with tracing.span("pipeline.h2d", "io", tid=tracing.track("io:h2d"),
-                      bytes=nbytes, name=name,
+                      bytes=nbytes, name=name, route=route,
                       **(tracing.context() or {})) as sp:
-        if not resident:
+        if route != "resident":
             data = jax.device_put(data, target)
             out = NDArray(data, ctx=nd_arr._ctx)
         data.block_until_ready()
+    if route in _ROUTE_COUNTER:
+        profiler.increment_counter(_ROUTE_COUNTER[route])
     telemetry.h2d(name, nbytes, sp.t1 - sp.t0)
     return out
 
@@ -157,9 +199,8 @@ def place_batch(batch, placement, data_names=None, label_names=None):
     if placement is None or batch is None:
         return batch
     if isinstance(batch, NDArray):
-        name = data_names[0] if data_names else "data"
-        return _put_one(batch, _placement_target(placement, name,
-                                                 batch._data), name)
+        return _put_one(batch, placement,
+                        data_names[0] if data_names else "data")
     if isinstance(batch, DataBatch):
         names_d = [d.name for d in batch.provide_data] \
             if batch.provide_data else list(data_names or [])
@@ -171,14 +212,12 @@ def place_batch(batch, placement, data_names=None, label_names=None):
                 return None
             out = []
             for i, a in enumerate(arrays):
-                data = getattr(a, "_data", None)
-                if not isinstance(a, NDArray) or data is None:
-                    out.append(a)    # numpy/sparse leaves stay host-side
+                if not isinstance(a, NDArray):
+                    out.append(a)    # numpy leaves stay host-side
                     continue
                 name = names[i] if i < len(names) else \
                     "%s%d" % (fallback, i)
-                out.append(_put_one(a, _placement_target(
-                    placement, name, data), name))
+                out.append(_put_one(a, placement, name))
             return out
 
         placed = DataBatch(put_roster(batch.data, names_d, "data"),
@@ -316,7 +355,9 @@ class AsyncInputPipeline(DataIter):
         """Adopt a new device/sharding target. Takes effect on the next
         batch the placer touches (attribute reads are atomic); batches
         already in the ready queue keep their old placement — consumers
-        transfer those themselves, exactly as before placement existed."""
+        transfer those themselves, exactly as before placement existed.
+        A batch decoded before the first placement was set is already on
+        the default device: the placer reshards it, and counts it."""
         self._placement = placement
 
     # -- lifecycle ---------------------------------------------------------
@@ -369,7 +410,7 @@ class AsyncInputPipeline(DataIter):
                         item = self._decode(src.next_raw(),
                                             tracing.context())
                     else:
-                        with self._decode_span(tracing.context()):
+                        with self._decoding(tracing.context()):
                             item = src.next()
                 except StopIteration:
                     break
@@ -381,15 +422,21 @@ class AsyncInputPipeline(DataIter):
         finally:
             self._stop_aware_put(self._decode_q, _SENTINEL)
 
-    @staticmethod
-    def _decode_span(ctx):
-        return tracing.span("pipeline.decode", "io",
-                            tid=tracing.track("io:decode"), **(ctx or {}))
+    @contextlib.contextmanager
+    def _decoding(self, ctx):
+        """What surrounds a source's decode on whichever thread runs it:
+        the ``pipeline.decode`` span, parented to the triggering step by
+        the ``ctx`` token, and — while the pipeline has a placement —
+        the thread's placement scope, so that the arrays the source
+        makes stay host memory for the placer to send (stage 2)."""
+        with tracing.span("pipeline.decode", "io",
+                          tid=tracing.track("io:decode"), **(ctx or {})), \
+                placement_scope(self._placement):
+            yield
 
     def _decode(self, raw, ctx):
-        """Decode one work item (pool or scheduler thread) under its
-        span, parented to the triggering step by the ``ctx`` token."""
-        with self._decode_span(ctx):
+        """Decode one work item (pool or scheduler thread)."""
+        with self._decoding(ctx):
             return self._source.decode_raw(raw)
 
     def _placer(self):

@@ -16,7 +16,7 @@ from __future__ import annotations
 import numpy as _np
 
 from ..base import MXNetError, numeric_types, integer_types
-from ..context import Context, current_context
+from ..context import Context, current_context, current_placement
 from .. import ops as _ops
 
 __all__ = ["NDArray", "invoke_nd", "array", "zeros", "ones", "full", "empty",
@@ -590,11 +590,56 @@ def _as_nd(x, ctx=None):
 
 
 def _device_put(data, ctx: Context):
+    """``data`` (host memory or a jax.Array) committed to ``ctx``'s
+    device. Where the context resolves to no device this process can
+    write to (none at all, or another process's: ``jax.devices()`` is
+    global under ``jax.distributed``), on the process's default device,
+    uncommitted."""
     import jax
     try:
-        return jax.device_put(data, ctx.jax_device())
+        device = ctx.jax_device()
+        if device.process_index == jax.process_index():
+            return jax.device_put(data, device)
     except Exception:
-        return data
+        pass
+    return jax.device_put(data)
+
+
+class HostStagedNDArray(NDArray):
+    """What :func:`array` returns inside a ``context.placement_scope``:
+    the source's own host memory (``host``, dtype settled), on no device
+    yet. The scope's owner — the input pipeline's placer — takes
+    ``host`` and sends it to its target, once (``io/pipeline.py``).
+    Whoever reads ``_data`` before that gets what :func:`array` gives
+    outside a scope: the array committed to ``ctx``'s device."""
+
+    def __init__(self, host, ctx):
+        self.host = host
+        super().__init__(None, ctx)
+
+    @property
+    def _data(self):
+        if self.host is not None:
+            self._placed, self.host = _device_put(self.host, self._ctx), None
+        return self._placed
+
+    @_data.setter
+    def _data(self, value):
+        self._placed = value
+        if value is not None:
+            self.host = None
+
+    # a source may ask what it made without sending it anywhere
+    def _held(self):
+        return self._placed if self.host is None else self.host
+
+    @property
+    def shape(self):
+        return tuple(self._held().shape)
+
+    @property
+    def dtype(self):
+        return _np.dtype(self._held().dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -657,8 +702,6 @@ def invoke_nd(op_name, inputs, attrs, out=None, ctx=None):
 # ---------------------------------------------------------------------------
 
 def array(source_array, ctx=None, dtype=None):
-    import jax.numpy as jnp
-    ctx = ctx or current_context()
     was_np = isinstance(source_array, (_np.ndarray, _np.generic, NDArray)) \
         or hasattr(source_array, "__jax_array__") \
         or type(source_array).__module__.startswith("jax")
@@ -676,10 +719,16 @@ def array(source_array, ctx=None, dtype=None):
             dtype = _np.float32
         else:
             dtype = canonical_dtype(src.dtype)
-    # canonical_dtype demotes EXPLICITLY so jax never emits its
-    # implicit-truncation warning (VERDICT r4 item 5)
-    data = jnp.asarray(src, dtype=canonical_dtype(dtype))
-    return NDArray(_device_put(data, ctx), ctx=ctx)
+    # the dtype is settled on the HOST (canonical_dtype demotes
+    # explicitly, so jax never emits its implicit-truncation warning,
+    # VERDICT r4 item 5; a no-op view when it already matches), and the
+    # host array goes straight to where the context says: one transfer,
+    # no stop on the process's default device on the way
+    host = src.astype(canonical_dtype(dtype), copy=False)
+    if ctx is None and current_placement() is not None:
+        return HostStagedNDArray(host, current_context())
+    ctx = ctx or current_context()
+    return NDArray(_device_put(host, ctx), ctx=ctx)
 
 
 def zeros(shape, ctx=None, dtype=None, **kwargs):

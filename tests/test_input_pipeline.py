@@ -447,3 +447,333 @@ class TestGluonDataLoaderDevicePrefetch:
         assert sum(1 for _ in loader) == 3
         gc.collect()
         assert _settle_threads(baseline) <= baseline
+
+
+# ---------------------------------------------------------------------------
+# host → target, once: the pipeline places by its placement
+# ---------------------------------------------------------------------------
+
+class _Probe:
+    """What the placer did while the pipeline ran: the routes of the
+    recorded ``pipeline.h2d`` spans, the two counters' increase, and
+    what the placer thread handed to every ``jax.device_put``."""
+
+    def __init__(self, monkeypatch):
+        import jax
+        from mxnet_tpu import profiler, tracing
+        self._tracing, self._profiler = tracing, profiler
+        self.put_inputs = []
+        real = jax.device_put
+
+        def spy(x, *args, **kwargs):
+            if threading.current_thread().name == "mxio-place":
+                self.put_inputs.append(x)
+            return real(x, *args, **kwargs)
+
+        monkeypatch.setattr(jax, "device_put", spy)
+
+    def __enter__(self):
+        self._tracing.reset()
+        self._tracing.enable()
+        self._before = dict(self._profiler.counters())
+        return self
+
+    def __exit__(self, *exc):
+        events = self._tracing.export()["traceEvents"]
+        self._tracing.reset()
+        self.routes = [e["args"]["route"] for e in events
+                       if e.get("name") == "pipeline.h2d"]
+        after = self._profiler.counters()
+        self.from_host, self.resharded = (
+            after.get(k, 0) - self._before.get(k, 0)
+            for k in ("pipeline_placed_from_host", "pipeline_resharded"))
+        return False
+
+
+def _mesh(n=None):
+    import jax
+    from jax.sharding import Mesh
+    devs = jax.devices()[:n]
+    if len(devs) < 2:
+        pytest.skip("needs the multi-device CPU mesh")
+    return Mesh(np.array(devs), ("dp",))
+
+
+def _eager(make_source):
+    return [(b.data[0].asnumpy(), b.label[0].asnumpy())
+            for b in _drain(make_source())]
+
+
+def _generic_source():
+    """No split protocol: ``next()`` runs on the scheduler thread."""
+    class Plain:
+        batch_size = 8
+        provide_data = [DataDesc("data", (8, 3))]
+        provide_label = [DataDesc("softmax_label", (8,))]
+
+        def __init__(self):
+            self._at = 0
+
+        def next(self):
+            if self._at >= 4:
+                raise StopIteration
+            rows = np.arange(self._at * 8, self._at * 8 + 8,
+                             dtype=np.float32)
+            self._at += 1
+            return DataBatch(
+                [mx.nd.array(rows[:, None] * 3 + np.arange(3,
+                                                           dtype=np.float32))],
+                [mx.nd.array(rows)], pad=0)
+
+        def reset(self):
+            self._at = 0
+    return Plain()
+
+
+class TestPlacedFromHostOnce:
+    @pytest.mark.parametrize("source", ["split", "generic"])
+    @pytest.mark.parametrize("target", ["sharded_pipeline", "named_sharding",
+                                        "one_device"])
+    def test_every_array_goes_from_host_memory_to_its_target(
+            self, monkeypatch, target, source):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        mesh = _mesh()
+        make = (lambda: _ndarray_iter(n=32, batch=8)) if source == "split" \
+            else _generic_source
+        want = _eager(make)
+        dp = NamedSharding(mesh, P("dp"))
+        with _Probe(monkeypatch) as probe:
+            if target == "sharded_pipeline":
+                pipe = make_sharded_pipeline(make(), mesh)
+                on = (dp, dp)       # 8 rows over 8 devices: both split
+            elif target == "named_sharding":
+                pipe = AsyncInputPipeline(make(), num_workers=2,
+                                          placement=dp)
+                on = (dp, dp)
+            else:
+                # not the default device, so a stop there would show
+                dev = jax.devices()[3]
+                pipe = AsyncInputPipeline(make(), num_workers=2,
+                                          placement=dev)
+                on = (dev, dev)
+            got = _drain(pipe)
+            pipe.close()
+        assert len(got) == len(want) == 4
+        for b, (x, y) in zip(got, want):
+            for arr, host, where in ((b.data[0], x, on[0]),
+                                     (b.label[0], y, on[1])):
+                assert type(arr) is mx.nd.NDArray
+                if target == "one_device":
+                    assert arr._data.devices() == {where}
+                    assert arr._data.committed
+                else:
+                    assert arr._data.sharding == where
+                np.testing.assert_array_equal(arr.asnumpy(), host)
+        assert probe.routes == ["host"] * 8
+        assert (probe.from_host, probe.resharded) == (8, 0)
+        # the placer handed jax nothing but host memory: no array was
+        # ever committed to the default device on the way
+        assert len(probe.put_inputs) == 8
+        assert all(type(x) is np.ndarray for x in probe.put_inputs)
+
+    def test_committed_arrays_are_resharded_and_counted(self, monkeypatch):
+        """A source that commits its arrays itself (an explicit ``ctx``)
+        keeps the device-to-device route, and is counted."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        mesh = _mesh()
+        dp = NamedSharding(mesh, P("dp"))
+
+        class Committed(_JitterSource):
+            def decode_raw(self, seq):
+                data = np.full((8, 1), seq, np.float32)
+                return DataBatch([mx.nd.array(data, ctx=mx.cpu(1))],
+                                 [mx.nd.array(data[:, 0], ctx=mx.cpu(1))],
+                                 pad=0)
+
+        with _Probe(monkeypatch) as probe:
+            pipe = AsyncInputPipeline(Committed(n=3, batch=8),
+                                      num_workers=2, placement=dp)
+            got = _drain(pipe)
+            pipe.close()
+        for seq, b in enumerate(got):
+            assert b.data[0]._data.sharding == dp
+            assert b.label[0]._data.sharding == dp
+            np.testing.assert_array_equal(
+                b.data[0].asnumpy(), np.full((8, 1), seq, np.float32))
+        assert probe.routes == ["reshard"] * 6
+        assert (probe.from_host, probe.resharded) == (0, 6)
+        assert not any(type(x) is np.ndarray for x in probe.put_inputs)
+
+    def test_arrays_already_on_the_target_are_left_there(self, monkeypatch):
+        import jax
+        dev = jax.devices()[2]
+
+        class OnTarget(_JitterSource):
+            def decode_raw(self, seq):
+                data = np.full((4, 1), seq, np.float32)
+                return DataBatch([mx.nd.array(data, ctx=mx.cpu(2))],
+                                 [mx.nd.array(data[:, 0])], pad=0)
+
+        with _Probe(monkeypatch) as probe:
+            pipe = AsyncInputPipeline(OnTarget(n=2), num_workers=1,
+                                      placement=dev)
+            got = _drain(pipe)
+            pipe.close()
+        assert probe.routes == ["resident", "host"] * 2
+        assert (probe.from_host, probe.resharded) == (2, 0)
+        assert all(b.data[0]._data.devices() == {dev} for b in got)
+
+    def test_module_placement_callable_resolves_by_name_and_shape(
+            self, monkeypatch):
+        """``placement_for_module``'s rule: names in ``batch_args``
+        whose leading dim splits go on the dp sharding, the rest are
+        replicated — resolved by the placer, where both are known."""
+        from mxnet_tpu.io.pipeline import placement_for_module
+        data = mx.sym.var("data")
+        net = mx.sym.SoftmaxOutput(
+            mx.sym.FullyConnected(data, num_hidden=4, name="fc"),
+            mx.sym.var("softmax_label"), name="softmax")
+        mod = mx.mod.Module(net, context=[mx.cpu(i) for i in range(4)])
+        mod.bind(data_shapes=[("data", (8, 3))],
+                 label_shapes=[("softmax_label", (8,))])
+        placement = placement_for_module(mod)
+        assert callable(placement)
+        rep, shard = mod._exec._dp_shardings()
+
+        class WithExtra(_JitterSource):
+            """``data`` splits, the odd-length ``extra`` cannot, and
+            ``aux`` is no batch argument of the executor."""
+            def __init__(self):
+                super().__init__(n=2, batch=8)
+                self.provide_data = [DataDesc("data", (8, 3)),
+                                     DataDesc("extra", (3,)),
+                                     DataDesc("aux", (8,))]
+
+            def decode_raw(self, seq):
+                return DataBatch(
+                    [mx.nd.array(np.full((8, 3), seq, np.float32)),
+                     mx.nd.array(np.arange(3, dtype=np.float32)),
+                     mx.nd.array(np.arange(8, dtype=np.float32))],
+                    [mx.nd.array(np.full((8,), seq, np.float32))], pad=0)
+
+        with _Probe(monkeypatch) as probe:
+            pipe = AsyncInputPipeline(WithExtra(), num_workers=2,
+                                      placement=placement)
+            got = _drain(pipe)
+            pipe.close()
+        for b in got:
+            assert b.data[0]._data.sharding == shard
+            assert b.data[1]._data.sharding == rep
+            assert b.data[2]._data.sharding == rep
+            assert b.label[0]._data.sharding == shard
+            np.testing.assert_array_equal(b.data[1].asnumpy(),
+                                          np.arange(3, dtype=np.float32))
+        assert probe.routes == ["host"] * 8
+        assert (probe.from_host, probe.resharded) == (8, 0)
+
+    def test_a_callable_with_no_target_lands_on_the_arrays_context(
+            self, monkeypatch):
+        import jax
+        with _Probe(monkeypatch) as probe:
+            pipe = AsyncInputPipeline(
+                _ndarray_iter(n=16, batch=8), num_workers=1,
+                placement=lambda name, arr: None)
+            got = _drain(pipe)
+            pipe.close()
+        assert probe.routes == ["host"] * 4
+        for b in got:
+            assert type(b.data[0]) is mx.nd.NDArray
+            assert b.data[0]._data.devices() == {jax.devices()[0]}
+
+    def test_the_scope_is_the_decode_threads_alone(self):
+        """The placement reaches the source's decode and nothing else:
+        not the consumer's thread, not a decode after ``close()`` or
+        across ``reset()``, not a pipeline whose placement was taken
+        away."""
+        import jax
+        from mxnet_tpu.context import current_placement
+        from mxnet_tpu.ndarray.ndarray import HostStagedNDArray
+        dev = jax.devices()[3]
+        seen = []
+
+        class Watching(_JitterSource):
+            def decode_raw(self, seq):
+                seen.append((threading.current_thread().name,
+                             current_placement()))
+                return super().decode_raw(seq)
+
+        src = Watching(n=6)
+        pipe = AsyncInputPipeline(src, num_workers=2, placement=dev)
+        first = pipe.next()
+        assert current_placement() is None
+        mine = mx.nd.array(np.ones(3, np.float32))
+        assert type(mine) is mx.nd.NDArray
+        assert mine._data.devices() == {jax.devices()[0]}
+        assert first.data[0]._data.devices() == {dev}
+        pipe.reset()
+        assert current_placement() is None
+        assert len(_drain(pipe)) == 6
+        assert seen and all(where is dev for _, where in seen)
+        assert all(name.startswith("mxio-") for name, _ in seen)
+        del seen[:]
+        pipe.set_placement(None)
+        pipe.reset()
+        assert len(_drain(pipe)) == 6
+        assert seen and all(where is None for _, where in seen)
+        pipe.close()
+        assert current_placement() is None
+        after = src.decode_raw(0)
+        assert not isinstance(after.data[0], HostStagedNDArray)
+        assert after.data[0]._data.devices() == {jax.devices()[0]}
+
+    def test_a_scope_that_raises_is_closed(self):
+        from mxnet_tpu.context import current_placement, placement_scope
+        with pytest.raises(KeyError):
+            with placement_scope("outer"):
+                with placement_scope("inner"):
+                    assert current_placement() == "inner"
+                    raise KeyError("x")
+        assert current_placement() is None
+
+    def test_a_staged_array_read_early_is_an_ordinary_array(self):
+        """Whoever touches a staged array before the placer does gets
+        what ``mx.nd.array`` gives outside a scope; an explicit ``ctx``
+        is honoured at once, scope or not."""
+        import jax
+        from mxnet_tpu.context import placement_scope
+        from mxnet_tpu.ndarray.ndarray import HostStagedNDArray
+        host = np.arange(6, dtype=np.float64).reshape(2, 3)
+        with placement_scope(jax.devices()[5]):
+            staged = mx.nd.array(host)
+            pinned = mx.nd.array(host, ctx=mx.cpu(2))
+            with mx.cpu(4):
+                scoped = mx.nd.array(host)
+        assert isinstance(staged, HostStagedNDArray)
+        assert staged.host.dtype == np.float32      # settled on the host
+        assert (staged.shape, staged.ndim, staged.size) == ((2, 3), 2, 6)
+        assert staged.dtype == np.float32 and staged.host is not None
+        assert type(pinned) is mx.nd.NDArray
+        assert pinned._data.devices() == {jax.devices()[2]}
+        assert scoped.context == mx.cpu(4)
+        total = (staged * 2).asnumpy()              # reads _data
+        np.testing.assert_array_equal(total, host.astype(np.float32) * 2)
+        assert staged.host is None
+        assert staged._data.devices() == {jax.devices()[0]}
+        assert staged._data.committed and staged.shape == (2, 3)
+        assert scoped._data.devices() == {jax.devices()[4]}
+        staged[0] = 9.0                             # the handle still swaps
+        assert staged.asnumpy()[0, 0] == 9.0
+
+    def test_sharded_input_pipeline_feeds_the_trainer_without_a_put(self):
+        """``DistributedTrainer.fit_batch``'s ``_put_unless_placed``
+        finds the batch on its own batch sharding."""
+        from mxnet_tpu.parallel.data_parallel import (
+            _put_unless_placed, sharded_input_pipeline)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        mesh = _mesh(4)
+        pipe = sharded_input_pipeline(_ndarray_iter(n=16, batch=8), mesh)
+        b = pipe.next()
+        pipe.close()
+        want = NamedSharding(mesh, P("dp"))
+        assert _put_unless_placed(b.data[0]._data, want) is b.data[0]._data

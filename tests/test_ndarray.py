@@ -299,3 +299,97 @@ def test_tile_repeat_pad():
                   constant_value=9)
     assert p.shape == (1, 1, 4, 4)
     assert p.asnumpy()[0, 0, 0, 0] == 9
+
+
+# ---------------------------------------------------------------------------
+# mx.nd.array's contract outside any input pipeline: dtype, values, ctx
+# and device of the result. The constructor settles the dtype on the host
+# and sends the host array straight to the context's device.
+# ---------------------------------------------------------------------------
+
+def _bf16(values):
+    import ml_dtypes
+    return np.asarray(values).astype(ml_dtypes.bfloat16)
+
+
+_ARRAY_CASES = {
+    # name: (source, kwargs, dtype, values, ctx, device index, committed)
+    "list": (lambda: [[1, 2], [3, 4]], {}, "float32",
+             [[1, 2], [3, 4]], mx.cpu(0), 0, True),
+    "list_of_floats": (lambda: [1.5, 2.5], {}, "float32", [1.5, 2.5],
+                       mx.cpu(0), 0, True),
+    "python_scalar": (lambda: 3, {}, "float32", 3, mx.cpu(0), 0, True),
+    "numpy_scalar": (lambda: np.float64(2.5), {}, "float32", 2.5,
+                     mx.cpu(0), 0, True),
+    "float64": (lambda: np.arange(4, dtype=np.float64) / 3, {}, "float32",
+                (np.arange(4) / 3).astype(np.float32), mx.cpu(0), 0, True),
+    "float32": (lambda: np.arange(4, dtype=np.float32), {}, "float32",
+                [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "int64_demoted": (lambda: np.array([1, 2 ** 31 + 5, -7], np.int64), {},
+                      "int32", [1, -2147483643, -7], mx.cpu(0), 0, True),
+    "int32": (lambda: np.arange(4, dtype=np.int32), {}, "int32",
+              [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "uint8_kept": (lambda: np.arange(4, dtype=np.uint8), {}, "uint8",
+                   [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "bool_kept": (lambda: np.array([True, False]), {}, "bool",
+                  [True, False], mx.cpu(0), 0, True),
+    "float16_kept": (lambda: np.arange(4, dtype=np.float16), {}, "float16",
+                     [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "bfloat16_kept": (lambda: _bf16([0, 1, 2, 3]), {}, "bfloat16",
+                      [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "ndarray_source": (lambda: mx.nd.array(np.arange(4, dtype=np.int32)), {},
+                       "int32", [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "jax_source": (lambda: __import__("jax").numpy.arange(4.0), {},
+                   "float32", [0, 1, 2, 3], mx.cpu(0), 0, True),
+    "explicit_ctx": (lambda: np.arange(4, dtype=np.float32),
+                     {"ctx": mx.cpu(2)}, "float32", [0, 1, 2, 3],
+                     mx.cpu(2), 2, True),
+    "accelerator_ctx_on_the_cpu_mesh": (
+        lambda: [1, 2], {"ctx": mx.gpu(3)}, "float32", [1, 2], mx.gpu(3), 3,
+        True),
+    "ctx_with_no_device": (lambda: [1, 2], {"ctx": mx.cpu(64)}, "float32",
+                           [1, 2], mx.cpu(64), 0, False),
+    "dtype_by_name": (lambda: [1.7, -2.2], {"dtype": "int32"}, "int32",
+                      [1, -2], mx.cpu(0), 0, True),
+    "dtype_float64_demoted": (lambda: np.arange(3, dtype=np.float32),
+                              {"dtype": np.float64}, "float32", [0, 1, 2],
+                              mx.cpu(0), 0, True),
+    "dtype_bfloat16": (lambda: np.array([1.001, 2.003], np.float32),
+                       {"dtype": "bfloat16"}, "bfloat16", [1.0, 2.0],
+                       mx.cpu(0), 0, True),
+    "not_contiguous": (
+        lambda: np.arange(12, dtype=np.float32).reshape(3, 4).T, {},
+        "float32", np.arange(12, dtype=np.float32).reshape(3, 4).T,
+        mx.cpu(0), 0, True),
+    "empty": (lambda: np.zeros((0, 3), np.float32), {}, "float32",
+              np.zeros((0, 3)), mx.cpu(0), 0, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ARRAY_CASES))
+def test_array_constructor_contract(case):
+    import jax
+    import warnings
+    source, kwargs, dtype, values, ctx, device, committed = \
+        _ARRAY_CASES[case]
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # no implicit-truncation warning
+        out = mx.nd.array(source(), **kwargs)
+    assert type(out) is mx.nd.NDArray
+    assert out.dtype == np.dtype(dtype)
+    assert out.context == ctx
+    np.testing.assert_array_equal(
+        out.asnumpy().astype(np.float64),
+        np.asarray(values).astype(np.float64))
+    assert out.shape == np.asarray(values).shape
+    assert out._data.devices() == {jax.devices()[device]}
+    assert out._data.committed is committed
+    assert not out._data.weak_type
+
+
+def test_array_follows_the_threads_default_context():
+    import jax
+    with mx.cpu(3):
+        out = mx.nd.array([1])
+    assert out.context == mx.cpu(3)
+    assert out._data.devices() == {jax.devices()[3]}
