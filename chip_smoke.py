@@ -522,8 +522,7 @@ def _serve_phase(cfg, seed, on_chip, device, default_err):
                 for p in prompts]
         served = [np.asarray(r.result(timeout=900)) for r in reqs]
         stats = srv.stats()
-        pool = srv.pool
-        pool_ok = on_device(pool.k, device) and on_device(pool.v, device)
+        pool_ok = all(on_device(a, device) for a in srv.pool.arrays)
         sites = compile_watch.site_stats("decode:smoke")
     finally:
         srv.stop()
@@ -668,7 +667,7 @@ def router_phase(cfg, seed, devices, on_chip):
     try:
         for srv in fleet:
             srv.warmup()
-        placed = [on_device(s.pool.k, d) and on_device(s.pool.v, d)
+        placed = [all(on_device(a, d) for a in s.pool.arrays)
                   for s, d in zip(fleet, devices)]
         # the one-replica run: the same requests on replica 0 alone
         alone = [fleet[0].submit(p, max_new_tokens=cfg["new_tokens"])

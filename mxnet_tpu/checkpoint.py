@@ -62,7 +62,7 @@ story (ROADMAP item 3):
 ``MXNET_ASYNC_CHECKPOINT=1`` (default) selects the background writer in
 ``Module.fit``; ``0`` runs the same subsystem synchronously on the
 training thread (identical files, identical trajectory — only the
-step-time p99 differs; see ``bench.py --checkpoint-overhead``).
+step-time tail differs; not measured on the chip).
 """
 from __future__ import annotations
 

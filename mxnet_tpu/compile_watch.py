@@ -93,7 +93,8 @@ _watch = None          # the active _Watch; module-global None check
 # Peak FLOP/s per chip, keyed by ``jax.Device.device_kind`` (bf16 MXU
 # peak — Google Cloud TPU documentation, the per-generation "system
 # architecture" pages; "TPU v5 lite" is what a v5e chip reports). The
-# ONE peak table in the tree: bench.py reads it through peak_table().
+# ONE peak table in the package, read through peak_table() (the
+# benchmark keeps its own with its source, ``benchmark/peaks.py``).
 # The "cpu" row is a placeholder that keeps the MFU math defined on
 # the CPU test mesh; no CPU figure is ever reported as a device metric.
 PEAK_FLOPS = {
@@ -823,8 +824,7 @@ def stats():
 def site_stats(prefix=None):
     """Per-site compile counts — ``{site: {"count", "total_s"}}``,
     optionally filtered to sites starting with ``prefix``. The serving
-    tests and ``bench.py --serving`` use this as the bounded-program-
-    cache oracle: under any request mix, ``site_stats("serving")``
+    tests use this as the bounded-program-cache oracle: under any request mix, ``site_stats("serving")``
     must hold exactly the bucket-ladder sites, each compiled once per
     replica device. None when the watch is off."""
     w = _watch

@@ -1,6 +1,7 @@
 """ResNet v1/v2 (parity: python/mxnet/gluon/model_zoo/vision/resnet.py).
 
-The flagship benchmark model (BASELINE.md ResNet-50). Structure matches
+The flagship benchmark model (``BENCHMARK.json``'s ``resnet50_v1``,
+the training cells). Structure matches
 the reference factories (resnet18-152, v1 and v2) so checkpoints and
 layer counts line up; implementation is idiomatic Gluon-on-XLA.
 """

@@ -227,8 +227,7 @@ class KVStore:
     def _tree_sum(vals):
         """The Reduce kernel of a list-push (CommDevice Reduce role,
         comm.h:451): sum the per-worker copies. Works on NDArrays or raw
-        device arrays and is jit-traceable, so bench.py can scan the
-        SAME aggregation program the kvstore compiles."""
+        device arrays and is jit-traceable."""
         agg = vals[0]
         for other in vals[1:]:
             agg = agg + other

@@ -358,7 +358,8 @@ class DistributedTrainer:
         """Resident parameter bytes per device: with FSDP on, each
         sharded param counts its padded shard; replicated params (and
         the whole roster with the gate closed) count their full
-        size — the 1/N claim ``bench.py --param-shard`` measures."""
+        size — the 1/N claim ``tests/test_param_shard.py`` holds it
+        to."""
         if self._param_vals is None:
             return 0
         total = 0

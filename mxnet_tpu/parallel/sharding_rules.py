@@ -343,7 +343,7 @@ class ShardingRules:
     def bytes_per_device(self, shapes, dtypes):
         """``(sharded_bytes, replicated_bytes)`` resident per device
         for a ``{name: shape}`` roster — the split the telemetry
-        memory table renders and the 1/N bench claim checks."""
+        memory table renders and the 1/N tests check."""
         sharded = replicated = 0
         for name, shape in shapes.items():
             plan = self.plan(name, shape)

@@ -31,7 +31,7 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   decode makes the shared stream token-identical to an unshared run —
   the same contract the stepwise-vs-full-forward oracle tests). The
   first write into a still-shared page copies it first (the ``:cow``
-  program; a q8 page's scales copy with it), and a planned ``kv_cow``
+  program, over every array the pool carries), and a planned ``kv_cow``
   raise degrades to a private re-prefill, never a wrong token. Several
   servers (several models / weight generations) can ``pool=`` ONE
   process-wide :class:`KVCachePool` under per-model quotas and pool
@@ -73,7 +73,11 @@ Orca/vLLM-style answer composed from machinery this tree already has:
 The model contract (see :class:`ToyDecoderLM`, the reference
 implementation; ``serving.latent_moe.LatentMoEDecoderLM`` is the other
 model in the tree). A model DECLARES what it caches and the server's
-pool, programs and copy-on-write follow the declaration:
+pool, programs and copy-on-write follow the declaration: the kind of
+cache is one layout object in ``serving.kvcache``, picked from the
+declaration and the pool's dtype, and the three programs here
+(prefill, step, page copy) are written once over whatever arrays that
+layout carries:
 
 - ``model.cache_arrays`` — ``((name, trailing shape[, dtype]), ...)``,
   one entry a pool array ``(n_layers, pages, page_size, *trailing)``.
@@ -119,7 +123,6 @@ contract (``tests/test_decode.py``, on the jnp AND Pallas paths).
 """
 from __future__ import annotations
 
-import functools
 import itertools
 import queue as _queue_mod
 import threading
@@ -279,11 +282,12 @@ class DecodeRequest:
 
 class ToyDecoderLM:
     """A minimal pre-LN transformer LM implementing the decode-model
-    contract — the reference the server's tests, example, and bench
-    drive. Prefill attention is ``flash_attention(causal=True)``;
-    decode attention is whatever the server's ``attend`` does over its
-    pool; ``use_pallas`` forces the Pallas kernels in interpret mode
-    off-TPU so both kernel paths are testable on CPU.
+    contract — the reference the server's tests, example and benchmark
+    (``benchmark/configs/opt-6.7b.json``) drive. Prefill attention is
+    ``flash_attention(causal=True)``; decode attention is whatever the
+    server's ``attend`` does over its pool; ``use_pallas`` forces the
+    Pallas kernels in interpret mode off-TPU so both kernel paths are
+    testable on CPU.
     Parameters are a FLAT ``{name: array}`` dict, so a checkpoint
     manifest round-trips them by name (the hot-swap recipe)."""
 
@@ -427,24 +431,7 @@ class DecodeServer:
                     "serving.decode.ToyDecoderLM, which declares "
                     "per-head K and V, and serving.latent_moe, which "
                     "declares one latent array)" % attr)
-        cache = getattr(model, "cache_arrays", None)
-        if cache is None:
-            if not (hasattr(model, "n_heads")
-                    and hasattr(model, "head_dim")):
-                raise MXNetError(
-                    "DecodeServer: model declares no cache_arrays = "
-                    "((name, trailing shape[, dtype]), ...) and has no "
-                    "n_heads/head_dim to mean per-head K and V by (see "
-                    "serving.decode.ToyDecoderLM)")
-            cache = (("k", (model.n_heads, model.head_dim)),
-                     ("v", (model.n_heads, model.head_dim)))
-        specs = tuple((str(c[0]), tuple(int(d) for d in c[1]))
-                      for c in cache)
-        dtypes = {c[2] for c in cache if len(c) > 2}
-        if len(dtypes) > 1:
-            raise MXNetError(
-                "DecodeServer: the arrays of one pool share a dtype, "
-                "the model declares %s" % sorted(dtypes))
+        specs, cache_dtype = kvcache.declared_arrays(model)
         self._counters = getattr(model, "step_counters", None)
         self._model = model
         self.name = name
@@ -479,15 +466,12 @@ class DecodeServer:
                     % (pool.n_layers, pool.array_specs, model.n_layers,
                        specs))
             self._pool = pool
-            self._own_pool = False
         else:
             self._pool = KVCachePool(model.n_layers, arrays=specs,
                                      page_size=page_size,
                                      n_pages=pool_pages,
-                                     dtype=dtypes.pop() if dtypes
-                                     else None,
+                                     dtype=cache_dtype,
                                      device=self._device)
-            self._own_pool = True
         self._owner = self._pool.attach(
             name or "model", quota=pool_quota, priority=pool_priority,
             preempt=self._pool_preempt_cb)
@@ -534,31 +518,23 @@ class DecodeServer:
         # donation makes each step update the pool in place on real
         # accelerators; the CPU PJRT client cannot donate (it would
         # only warn per compile), and correctness never depends on it
-        donate = {}
-        n_pool = 4 if self._pool.quantized else len(self._pool.arrays)
+        donate = cow_donate = {}
+        n_pool = len(self._pool.arrays)
         if jax.default_backend() not in ("cpu",):
             donate = {"donate_argnums": tuple(range(4, 4 + n_pool))}
-        decode_fn = self._decode_fn_q8 if self._pool.quantized \
-            else self._decode_fn
-        prefill_fn = self._prefill_fn_q8 if self._pool.quantized \
-            else self._prefill_fn
+            cow_donate = {"donate_argnums": tuple(range(n_pool))}
         self._decode_prog = compile_watch.jit(
-            decode_fn, "%s:step" % site,
+            self._decode_fn, "%s:step" % site,
             statics=(site, self._window, self._max_pages), **donate)
         self._prefill_progs = {}
         for rung in self._seq_ladder.buckets:
             self._prefill_progs[rung] = compile_watch.jit(
-                prefill_fn, "%s:prefill:s%d" % (site, rung),
+                self._prefill_fn, "%s:prefill:s%d" % (site, rung),
                 statics=(site, "prefill", rung), **donate)
         # the copy-on-write page copy: one more fixed program, only
         # ever compiled when the prefix cache is on (warmup covers it)
-        cow_fn = self._cow_fn_q8 if self._pool.quantized \
-            else self._cow_fn
-        cow_donate = {}
-        if jax.default_backend() not in ("cpu",):
-            cow_donate = {"donate_argnums": tuple(range(n_pool))}
         self._cow_prog = compile_watch.jit(
-            cow_fn, "%s:cow" % site, statics=(site, "cow"),
+            self._cow_fn, "%s:cow" % site, statics=(site, "cow"),
             **cow_donate)
 
         self._cond = threading.Condition()
@@ -600,15 +576,20 @@ class DecodeServer:
     @property
     def pool(self):
         """The :class:`KVCachePool` this server decodes against (its
-        ``.arrays`` — ``.k``/``.v`` for per-head K and V — are the live
-        device arrays)."""
+        ``.arrays`` are the live device arrays, every one the programs
+        carry)."""
         return self._pool
 
     # -- compiled programs -------------------------------------------------
+    # Three, for every kind of cache: ``pools`` is whatever the pool's
+    # layout carries (``KVCachePool.arrays``), and the layout — the
+    # pool's own object, found again from the model's declaration and
+    # the arrays' dtype — attends and writes.
     def _prefill_fn(self, params, tokens, n_valid, page_table, *pools):
         import jax.numpy as jnp
+        layout = kvcache.layout_for(self._model, pools)
         logits, *seqs = self._model.prefill(params, tokens)
-        pools = kvcache.write_prefill(pools, page_table, seqs, n_valid)
+        pools = layout.write_prefill(pools, page_table, seqs, n_valid)
         # greedy sampling in-program; only the token leaves the
         # device — returning the logits too would make XLA
         # materialize a dead (vocab,)-sized output per prefill
@@ -618,71 +599,30 @@ class DecodeServer:
 
     def _decode_fn(self, params, tokens, positions, page_tables, *pools):
         import jax.numpy as jnp
-        attend = kvcache.attend_for(pools, page_tables, positions)
+        layout = kvcache.layout_for(self._model, pools)
+        attend = layout.attend(pools, page_tables, positions)
         logits, *new = self._model.decode(
             params, tokens, positions, attend)
-        pools = kvcache.write_tokens(
+        pools = layout.write_tokens(
             pools, page_tables, positions, new,
             getattr(self._model, "use_pallas", False))
         # only the argmax tokens leave the device: a (window, vocab)
         # logits output would be dead weight on the per-token hot path
         tokens_out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if len(new) > len(pools):
+        if len(new) > len(layout.specs):
             # the model's step counters leave with the tokens, one array
             tokens_out = jnp.concatenate(
                 [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
         return (tokens_out, *pools)
 
-    # int8-pool variants: same program shape, with per-page fp32
-    # scales riding alongside the pages. Attention applies them page by
-    # page (the model contract stays fp32 q/k_new/v_new), scatter
-    # quantizes — both inside the one compiled program, so the
-    # fixed-program-set oracle (site_stats("decode")) is identical to
-    # the fp32 pool's.
-    def _prefill_fn_q8(self, params, tokens, n_valid, page_table,
-                       k_pages, v_pages, k_scales, v_scales):
-        import jax.numpy as jnp
-        logits, k_seq, v_seq = self._model.prefill(params, tokens)
-        k_pages, k_scales = kvcache.scatter_prefill_q8(
-            k_pages, k_scales, page_table, k_seq[:, 0], n_valid)
-        v_pages, v_scales = kvcache.scatter_prefill_q8(
-            v_pages, v_scales, page_table, v_seq[:, 0], n_valid)
-        last = jnp.take(logits[0], n_valid - 1, axis=0)
-        token = jnp.argmax(last).astype(jnp.int32)
-        return token, k_pages, v_pages, k_scales, v_scales
-
-    def _decode_fn_q8(self, params, tokens, positions, page_tables,
-                      k_pages, v_pages, k_scales, v_scales):
-        import jax.numpy as jnp
-        attend = functools.partial(kvcache.paged_attention, k_pages,
-                                   v_pages, page_tables, positions,
-                                   k_scale=k_scales, v_scale=v_scales)
-        logits, k_new, v_new = self._model.decode(
-            params, tokens, positions, attend)
-        k_pages, k_scales = kvcache.scatter_token_q8(
-            k_pages, k_scales, page_tables, positions, k_new)
-        v_pages, v_scales = kvcache.scatter_token_q8(
-            v_pages, v_scales, page_tables, positions, v_new)
-        tokens_out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tokens_out, k_pages, v_pages, k_scales, v_scales
-
     # copy-on-write page copy — the whole split is one traced program
-    # (src/dst ride as traced scalars, so any page pair reuses it)
+    # (src/dst ride as traced scalars, so any page pair reuses it).
+    # Axis 1 of every carried array is the page, so an int8 page's
+    # scales go with it and the private copy dequantizes bit-identically
     def _cow_fn(self, *args):
         *pools, src, dst = args
         return tuple(pages.at[:, dst].set(pages[:, src])
                      for pages in pools)
-
-    def _cow_fn_q8(self, k_pages, v_pages, k_scales, v_scales, src,
-                   dst):
-        # a q8 page's per-page scales are part of its content: the
-        # copy carries them, so the new private page dequantizes
-        # bit-identically to the shared one it forked from
-        k_pages = k_pages.at[:, dst].set(k_pages[:, src])
-        v_pages = v_pages.at[:, dst].set(v_pages[:, src])
-        k_scales = k_scales.at[:, dst].set(k_scales[:, src])
-        v_scales = v_scales.at[:, dst].set(v_scales[:, src])
-        return k_pages, v_pages, k_scales, v_scales
 
     def _namespace(self, ver):
         """The prefix-index namespace: share group (defaults to this
@@ -704,21 +644,9 @@ class DecodeServer:
             self._cond.notify_all()
         return True
 
-    def _pool_args(self):
-        """The pool arrays a step program takes (and returns): pages,
-        plus the per-page scales in quantized mode."""
-        if self._pool.quantized:
-            return (self._pool.k, self._pool.v, self._pool.k_scale,
-                    self._pool.v_scale)
-        return tuple(self._pool.arrays)
-
     def _adopt_pool(self, out):
         """Re-point the pool at a step program's functionally-updated
         arrays; returns the program's remaining (token) outputs."""
-        if self._pool.quantized:
-            (self._pool.k, self._pool.v, self._pool.k_scale,
-             self._pool.v_scale) = out[-4:]
-            return out[:-4]
         n = len(self._pool.arrays)
         self._pool.arrays[:] = out[-n:]
         return out[:-n]
@@ -821,7 +749,7 @@ class DecodeServer:
                     toks = _np.zeros((1, rung), _np.int32)
                     out = self._prefill_progs[rung](
                         self._params.tree, toks, _np.int32(0),
-                        zeros_pt, *self._pool_args())
+                        zeros_pt, *self._pool.arrays)
                     jax.block_until_ready(out[0])
                     self._adopt_pool(out)
                     n += 1
@@ -830,7 +758,7 @@ class DecodeServer:
                 pts = _np.zeros((self._window, self._max_pages),
                                 _np.int32)
                 out = self._decode_prog(self._params.tree, toks, pos,
-                                        pts, *self._pool_args())
+                                        pts, *self._pool.arrays)
                 jax.block_until_ready(out[0])
                 self._adopt_pool(out)
                 n += 1
@@ -838,7 +766,7 @@ class DecodeServer:
                     # the COW copy joins the fixed set only when the
                     # prefix cache can actually trigger it; dump page
                     # onto itself = a logical no-op
-                    out = self._cow_prog(*self._pool_args(),
+                    out = self._cow_prog(*self._pool.arrays,
                                          _np.int32(0), _np.int32(0))
                     jax.block_until_ready(out[0])
                     self._adopt_pool(out)
@@ -1280,7 +1208,7 @@ class DecodeServer:
                 with self._pool.step_lock:
                     out = self._prefill_progs[rung](
                         req.params.tree, tokens, _np.int32(P), pt,
-                        *self._pool_args())
+                        *self._pool.arrays)
                     token = self._adopt_pool(out)[0]
             except Exception as exc:   # noqa: BLE001 — model errors
                 with self._cond:       # belong to the request
@@ -1414,7 +1342,7 @@ class DecodeServer:
             pg = self._pool.alloc(1, owner=self._owner)
         old, new = int(r.pages[pidx]), int(pg[0])
         with self._pool.step_lock:
-            out = self._cow_prog(*self._pool_args(),
+            out = self._cow_prog(*self._pool.arrays,
                                  _np.int32(old), _np.int32(new))
             self._adopt_pool(out)
         self._pool.cow_release(old)
@@ -1489,7 +1417,7 @@ class DecodeServer:
                     self._pool.step_lock:
                 toks = self._adopt_pool(self._decode_prog(
                     ver.tree, tokens, positions, pts,
-                    *self._pool_args()))[0]
+                    *self._pool.arrays))[0]
         except Exception as exc:       # noqa: BLE001 — model errors
             with self._cond:           # belong to the batch's requests
                 for r in rows:

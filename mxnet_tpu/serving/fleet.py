@@ -61,7 +61,7 @@ class Replica:
         self.name = (name or getattr(server, "name", None)
                      or "replica-%d" % index)
         self.state = "up"        # up | draining | drained | lost
-        self.killed = False      # simulated abrupt loss (tests/bench)
+        self.killed = False      # simulated abrupt loss (tests)
         self.outstanding = 0     # tokens owed by bound sessions
         self.sessions = 0        # bound streaming sessions
         self.dispatched = 0      # sessions ever routed here
@@ -101,8 +101,8 @@ class Replica:
         return "up"
 
     def kill(self):
-        """Simulate abrupt replica loss (chaos tests, the bench's
-        mid-run kill): the scheduler exits WITHOUT completing or
+        """Simulate abrupt replica loss (the chaos tests' mid-run
+        kill): the scheduler exits WITHOUT completing or
         failing in-flight work — futures never resolve, KV pages are
         abandoned with the "process". Nothing announces the death; the
         fleet monitor must detect it and the router must replay the

@@ -28,14 +28,20 @@ sees only fixed shapes:
   attend → write the token's rows, no host round-trip per token.
 
 **Layouts.** What a page holds is the model's declaration
-(``DecodeServer``'s contract): by default two arrays, per-head K and V,
-``(n_layers, n_pages, page_size, n_heads, head_dim)`` each; a
-latent-attention model declares ONE array ``(n_layers, n_pages,
+(``DecodeServer``'s contract), and the kind of cache is ONE layout
+object that :func:`cache_layout` picks from that declaration and the
+pool's dtype: by default two arrays, per-head K and V, ``(n_layers,
+n_pages, page_size, n_heads, head_dim)`` each; under an int8 dtype the
+same two as int8 pages, with their per-page scales carried beside them;
+a latent-attention model declares ONE array ``(n_layers, n_pages,
 page_size, W)`` whose row is the compressed K/V and the shared rotary
 key (:func:`paged_latent_attention`, :func:`write_prefill_pages`,
-:func:`write_token_rows`). The page accounting below — alloc, free,
-refcounts, copy-on-write, preemption, the prefix index — never looks
-inside a page and is the same for both.
+:func:`write_token_rows`). The layout alone knows which arrays a
+program carries, what ``attend`` a step hands its model and how a
+prefill's sequences and a step's new rows reach their pages; the
+server's three programs are written over it, once. The page accounting
+below — alloc, free, refcounts, copy-on-write, preemption, the prefix
+index — never looks inside a page and is the same for every kind.
 
 Page *accounting* is host-side and lives here too: an allocate/free
 free-list under a lock, with peak/eviction counters for the ``decode``
@@ -53,7 +59,8 @@ mix.
 
 **Quantized storage** (``MXNET_KV_DTYPE=int8``, or ``dtype=`` on the
 pool): K/V pages store int8 with one fp32 scale per ``(layer, page)``
-(``.k_scale``/``.v_scale``, shape ``(L, P)``). The quantized ops are
+(``k_scale``/``v_scale``, shape ``(L, P)``, the last two of the pool's
+``.arrays``). The quantized ops are
 the same traced, functional shapes as the fp32 ones, so the decode
 server's program set stays fixed:
 
@@ -86,7 +93,8 @@ weight generations can never alias); a later prompt that walks the
 same chain enters decode with its page table pointing at the SHARED
 pages and computes only the un-cached suffix. The first write into a
 still-shared page triggers copy-on-write (the decode server's
-``:cow`` program — a q8 page's per-page scales copy with it). Index
+``:cow`` program — a q8 page's per-page scales are carried arrays like
+the pages, and copy with it). Index
 entries hold one reference each, so cached prefixes survive their
 requests; under pool pressure ``alloc`` evicts COLD entries — pages
 nobody holds beyond the index itself — through the counted
@@ -102,7 +110,9 @@ serializes the servers' compiled steps on the shared device arrays.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
+import math
 import threading
 from collections import OrderedDict
 
@@ -110,8 +120,8 @@ from .. import envs, fault
 from ..base import MXNetError
 
 __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
-           "paged_attention", "paged_latent_attention", "attend_for",
-           "write_prefill", "write_tokens",
+           "paged_attention", "paged_latent_attention", "cache_layout",
+           "declared_arrays", "layout_for",
            "scatter_token", "scatter_prefill", "write_prefill_pages",
            "write_token_rows",
            "pages_for",
@@ -165,7 +175,6 @@ def paged_attention(k_pages, v_pages, page_table, positions, layer, q,
     pool passes its ``(L, P)`` page scales; the new token is attended
     unquantized on both paths, a float pool's rounded to the pool's
     dtype as the pool will hold it."""
-    import math
     import jax.numpy as jnp
     from ..parallel.flash_attention import (_dispatch, _jnp_decode,
                                             _pallas_paged_decode)
@@ -346,43 +355,6 @@ def paged_latent_attention(kv_pages, page_table, positions, layer, q,
                      table, pos)
 
 
-# what a float pool's layout decides, in one place: two arrays are
-# per-head K and V, one is a latent pool
-
-def _latent(pools):
-    return len(pools) == 1
-
-
-def attend_for(pools, page_tables, positions):
-    """The ``attend`` a decode step hands its model: :func:`paged_attention`
-    over K and V, :func:`paged_latent_attention` over a latent pool."""
-    import functools
-    fn = paged_latent_attention if _latent(pools) else paged_attention
-    return functools.partial(fn, *pools, page_tables, positions)
-
-
-def write_prefill(pools, page_table_row, seqs, n_valid):
-    """One request's prefill sequences ``(L, B=1, Lr, ...)`` into their
-    pools: :func:`scatter_prefill` for K and V,
-    :func:`write_prefill_pages` (in place for a 16-bit pool) for a
-    latent pool."""
-    fn = write_prefill_pages if _latent(pools) else scatter_prefill
-    return tuple(fn(pages, page_table_row, seq[:, 0], n_valid)
-                 for pages, seq in zip(pools, seqs))
-
-
-def write_tokens(pools, page_tables, positions, new, force_pallas=False):
-    """One decode step's new rows into their pools:
-    :func:`scatter_token` for K and V, :func:`write_token_rows` for a
-    latent pool."""
-    if _latent(pools):
-        return tuple(write_token_rows(pages, page_tables, positions, rows,
-                                      force_pallas)
-                     for pages, rows in zip(pools, new))
-    return tuple(scatter_token(pages, page_tables, positions, rows)
-                 for pages, rows in zip(pools, new))
-
-
 # ---------------------------------------------------------------------------
 # quantized (int8 + per-page fp32 scale) variants — same traced shapes
 # ---------------------------------------------------------------------------
@@ -472,6 +444,172 @@ def scatter_prefill_q8(pages, scales, page_table_row, seq, n_valid):
 
 
 # ---------------------------------------------------------------------------
+# the layouts: one object a kind of cache
+# ---------------------------------------------------------------------------
+
+class _PerHeadKV:
+    """Per-head K and V in a float dtype, two arrays ``(L, P, S, H, D)``
+    — and the base of the other kinds. A layout alone knows how its
+    kind is stored, attended, written and copied: which arrays a
+    program carries (:meth:`arrays`), what ``attend`` a decode step
+    hands its model, how a prefill's sequences reach their pages and
+    how a step's new rows reach theirs. ``specs`` is the model's
+    declaration, ``((name, trailing shape), ...)``; :func:`cache_layout`
+    picks the class."""
+
+    def __init__(self, specs, dtype):
+        self.specs = specs
+        self.dtype = dtype
+
+    def arrays(self, n_layers, n_pages, page_size):
+        """``(name, shape, dtype)`` of every array a program carries, in
+        the order it carries (and returns) them. A page copy
+        (``DecodeServer._cow_fn``) is ``a.at[:, dst].set(a[:, src])``
+        over each: axis 1 of every carried array is the page."""
+        lead = (n_layers, n_pages, page_size)
+        return tuple((name, lead + trailing, self.dtype)
+                     for name, trailing in self.specs)
+
+    def token_bytes(self, n_layers):
+        """Bytes one token occupies across all layers and arrays."""
+        return n_layers * self.dtype.itemsize * sum(
+            math.prod(trailing) for _name, trailing in self.specs)
+
+    def attend(self, pools, page_tables, positions):
+        """The ``attend`` a decode step hands its model."""
+        return functools.partial(paged_attention, *pools, page_tables,
+                                 positions)
+
+    def write_prefill(self, pools, page_table_row, seqs, n_valid):
+        """One request's prefill sequences ``(L, B=1, Lr, ...)`` into
+        their pages; returns the carried arrays, updated."""
+        return tuple(scatter_prefill(pages, page_table_row, seq[:, 0],
+                                     n_valid)
+                     for pages, seq in zip(pools, seqs))
+
+    def write_tokens(self, pools, page_tables, positions, new,
+                     force_pallas=False):
+        """One decode step's new rows ``(L, B, ...)`` an array into their
+        pages (``new`` may carry more behind them: the model's step
+        counters); returns the carried arrays, updated."""
+        return tuple(scatter_token(pages, page_tables, positions, rows)
+                     for pages, rows in zip(pools, new))
+
+
+class _Latent(_PerHeadKV):
+    """One float array ``(L, P, S, W)``: a token's row is the compressed
+    K/V and the shared rotary key. Written in place (page by page, row
+    by row) so a 16-bit pool is never widened by a scatter."""
+
+    def attend(self, pools, page_tables, positions):
+        return functools.partial(paged_latent_attention, *pools,
+                                 page_tables, positions)
+
+    def write_prefill(self, pools, page_table_row, seqs, n_valid):
+        return tuple(write_prefill_pages(pages, page_table_row, seq[:, 0],
+                                         n_valid)
+                     for pages, seq in zip(pools, seqs))
+
+    def write_tokens(self, pools, page_tables, positions, new,
+                     force_pallas=False):
+        return tuple(write_token_rows(pages, page_tables, positions, rows,
+                                      force_pallas)
+                     for pages, rows in zip(pools, new))
+
+
+class _PerHeadKVInt8(_PerHeadKV):
+    """Per-head K and V as int8 pages with one fp32 scale a ``(layer,
+    page)``: a program carries ``k, v, k_scale, v_scale``. The scales
+    are part of a page's content (axis 1 is the page, so the page copy
+    carries them); the model's contract stays float ``q``/``k_new``/
+    ``v_new`` — attention applies the scales page by page, the writes
+    quantize."""
+
+    def arrays(self, n_layers, n_pages, page_size):
+        pages = super().arrays(n_layers, n_pages, page_size)
+        return pages + tuple(
+            (name + "_scale", (n_layers, n_pages), "float32")
+            for name, _shape, _dtype in pages)
+
+    def attend(self, pools, page_tables, positions):
+        k_pages, v_pages, k_scale, v_scale = pools
+        return functools.partial(paged_attention, k_pages, v_pages,
+                                 page_tables, positions, k_scale=k_scale,
+                                 v_scale=v_scale)
+
+    def write_prefill(self, pools, page_table_row, seqs, n_valid):
+        (k, k_scale), (v, v_scale) = (
+            scatter_prefill_q8(pages, scales, page_table_row, seq[:, 0],
+                               n_valid)
+            for pages, scales, seq in zip(pools[:2], pools[2:], seqs))
+        return k, v, k_scale, v_scale
+
+    def write_tokens(self, pools, page_tables, positions, new,
+                     force_pallas=False):
+        (k, k_scale), (v, v_scale) = (
+            scatter_token_q8(pages, scales, page_tables, positions, rows)
+            for pages, scales, rows in zip(pools[:2], pools[2:], new))
+        return k, v, k_scale, v_scale
+
+
+def declared_arrays(model):
+    """What ``model`` declares its cache to hold, as ``(specs, dtype)``:
+    ``specs`` the ``((name, trailing shape), ...)`` of
+    ``model.cache_arrays`` (per-head K and V by ``n_heads``/``head_dim``
+    for a model that predates the declaration), ``dtype`` the one the
+    declaration asks for, or None."""
+    cache = getattr(model, "cache_arrays", None)
+    if cache is None:
+        if not (hasattr(model, "n_heads") and hasattr(model, "head_dim")):
+            raise MXNetError(
+                "DecodeServer: model declares no cache_arrays = "
+                "((name, trailing shape[, dtype]), ...) and has no "
+                "n_heads/head_dim to mean per-head K and V by (see "
+                "serving.decode.ToyDecoderLM)")
+        cache = (("k", (model.n_heads, model.head_dim)),
+                 ("v", (model.n_heads, model.head_dim)))
+    specs = tuple((str(c[0]), tuple(int(d) for d in c[1])) for c in cache)
+    dtypes = {c[2] for c in cache if len(c) > 2}
+    if len(dtypes) > 1:
+        raise MXNetError(
+            "DecodeServer: the arrays of one pool share a dtype, the "
+            "model declares %s" % sorted(dtypes))
+    return specs, (dtypes.pop() if dtypes else None)
+
+
+@functools.lru_cache(maxsize=None)
+def cache_layout(specs, dtype):
+    """THE choice of cache kind, from the two things that can be
+    observed: the declaration ``specs`` (``((name, trailing shape),
+    ...)``) and the pool's ``dtype``. Two arrays of per-head vectors
+    ``(H, D)`` are K and V — int8 pages with per-page scales under an
+    int8 dtype; one array of rows ``(W,)`` is a latent pool. The pool
+    asks once, when it is built; a program being traced asks again with
+    the same two observations (:func:`layout_for`) and is handed the
+    same object."""
+    ranks = tuple(len(trailing) for _name, trailing in specs)
+    int8 = dtype == "int8"
+    if ranks == (2, 2):
+        return (_PerHeadKVInt8 if int8 else _PerHeadKV)(specs, dtype)
+    if int8:
+        raise MXNetError(
+            "KVCachePool: int8 pages with per-page scales exist for "
+            "the per-head K/V layout only, not for %s" % (specs,))
+    if ranks == (1,):
+        return _Latent(specs, dtype)
+    raise MXNetError(
+        "KVCachePool: no cache layout for %s — a model caches per-head "
+        "K and V, two arrays of (n_heads, head_dim), or one latent "
+        "array of (row_width,)" % (specs,))
+
+
+def layout_for(model, pools):
+    """The layout of the ``pools`` a program of ``model``'s was handed —
+    the pool's own (:func:`cache_layout` is cached)."""
+    return cache_layout(declared_arrays(model)[0], pools[0].dtype)
+
+
+# ---------------------------------------------------------------------------
 # the prefix index
 # ---------------------------------------------------------------------------
 
@@ -535,9 +673,10 @@ class PrefixIndex:
 class KVCachePool:
     """One model's paged KV storage + host-side page accounting.
 
-    The device arrays (``.k`` / ``.v``) are owned by the decode
-    server's scheduler thread: compiled steps take them as inputs and
-    the scheduler re-points them at the returned (functionally
+    The device arrays (``.arrays``: every one a program carries, in the
+    layout's order, an int8 pool's scales among them) are owned by the
+    decode server's scheduler thread: compiled steps take them as inputs
+    and the scheduler re-points them at the returned (functionally
     updated) arrays. Page ids are allocated lowest-first — allocation
     order is deterministic, so tests can predict table contents. Page
     0 is reserved as the dump page and never allocated."""
@@ -557,9 +696,9 @@ class KVCachePool:
             raise MXNetError(
                 "KVCachePool: need at least 2 pages (page 0 is the "
                 "reserved dump page), got %d" % self.n_pages)
-        # the layout: ``(name, trailing shape)`` an array. Per-head K
-        # and V by default; a latent model declares ONE array whose
-        # rows are the compressed K/V and the shared rotary key
+        # the model's declaration, ``(name, trailing shape)`` an array
+        # (per-head K and V by default), and the dtype pick the layout:
+        # the one object that knows this kind of cache
         if arrays is None:
             arrays = (("k", (int(n_heads), int(head_dim))),
                       ("v", (int(n_heads), int(head_dim))))
@@ -574,27 +713,18 @@ class KVCachePool:
                 raise MXNetError(
                     "KVCachePool: unknown MXNET_KV_DTYPE %r (one of "
                     "float32 | bfloat16 | int8)" % name)
-        dtype = jnp.dtype(dtype)
-        self.dtype = dtype
-        self.quantized = dtype == jnp.int8
-        if self.quantized and len(self.array_specs) != 2:
-            raise MXNetError(
-                "KVCachePool: int8 pages with per-page scales exist for "
-                "the per-head K/V layout only")
-        lead = (int(n_layers), self.n_pages, self.page_size)
-        # allocated ON the target device: a replica's pool must never
-        # be staged through the first chip's memory on its way there
-        self.arrays = [jnp.zeros(lead + trailing, dtype, device=device)
-                       for _name, trailing in self.array_specs]
-        self.k_scale = self.v_scale = None
-        if self.quantized:
-            self.k_scale = jnp.zeros(lead[:2], jnp.float32,
-                                     device=device)
-            self.v_scale = jnp.zeros(lead[:2], jnp.float32,
-                                     device=device)
+        self.dtype = jnp.dtype(dtype)
+        self.layout = cache_layout(self.array_specs, self.dtype)
+        carried = self.layout.arrays(int(n_layers), self.n_pages,
+                                     self.page_size)
+        # every array a program carries, in the layout's order (an int8
+        # pool's scales among them). Allocated ON the target device: a
+        # replica's pool must never be staged through the first chip's
+        # memory on its way there
+        self.names = tuple(name for name, _shape, _dtype in carried)
+        self.arrays = [jnp.zeros(shape, dt, device=device)
+                       for _name, shape, dt in carried]
         self.n_layers = int(n_layers)
-        self.n_heads = int(n_heads) if n_heads is not None else None
-        self.head_dim = int(head_dim) if head_dim is not None else None
         self._lock = threading.Lock()
         # serializes co-tenant servers' compiled steps on the shared
         # functional arrays — two schedulers must never fork the arrays
@@ -611,32 +741,12 @@ class KVCachePool:
         self._cow_splits = 0
         self._quota_denials = 0
         self.prefix = PrefixIndex(self.page_size)
-        # bytes one token occupies across all layers and arrays
-        values = 0
-        for _name, trailing in self.array_specs:
-            width = 1
-            for d in trailing:
-                width *= d
-            values += width
-        self.token_bytes = self.n_layers * values * self.dtype.itemsize
+        self.token_bytes = self.layout.token_bytes(self.n_layers)
 
-    # the per-head layout's two arrays by name (the int8 paths, the
-    # tests and the tools read them)
-    @property
-    def k(self):
-        return self.arrays[0]
-
-    @k.setter
-    def k(self, value):
-        self.arrays[0] = value
-
-    @property
-    def v(self):
-        return self.arrays[1]
-
-    @v.setter
-    def v(self, value):
-        self.arrays[1] = value
+    # the per-head kinds' pages by name, for tests and tools; the
+    # programs take ``.arrays`` whole
+    k = property(lambda self: self.arrays[0])
+    v = property(lambda self: self.arrays[1])
 
     @property
     def usable_pages(self):

@@ -1,6 +1,6 @@
 """KVStore push/pull bandwidth probe (parity:
-tools/bandwidth/measure.py — the harness behind BASELINE.md metric #2
-and docs/faq/perf.md:246).
+tools/bandwidth/measure.py, the harness behind the reference's
+docs/faq/perf.md:246).
 
 Measures aggregate GB/s of repeated push+pull rounds over layer-sized
 arrays (by default the weight shapes of a model-zoo network, like the

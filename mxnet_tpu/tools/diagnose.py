@@ -1,8 +1,8 @@
 """Environment diagnosis (parity: tools/diagnose.py, minus the
 network-reachability section — this environment has zero egress, so
 the equivalent signal is backend reachability: a short-timeout
-subprocess probe of the accelerator, the same probe bench.py and the
-TPU test lane use).
+subprocess probe of the accelerator, the same probe the TPU test
+lane uses).
 
 Run: ``python -m mxnet_tpu.tools.diagnose``.
 

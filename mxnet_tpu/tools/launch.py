@@ -30,8 +30,8 @@ set so workers resume instead of starting over. Backoff doubles from
 permits a degraded relaunch at N-1 workers when a replacement is not
 expected (the elastic manifest format makes the resumed topology a
 free choice). ``--events-file`` appends one JSON line per supervisor
-event (worker death, teardown, restart, give-up) — the
-detection-to-restart timing source for ``bench.py --multihost``.
+event (worker death, teardown, restart, give-up), each stamped: the
+source for a detection-to-restart time.
 
 **Not for several workers on one TPU host.** ``_spawn_workers`` hands
 every local worker the parent's environment plus the DMLC_* contract
@@ -39,8 +39,7 @@ and nothing that gives it a chip of its own (no per-worker visible-
 device setting), so N local workers each claim every chip of the
 host — and a chip belongs to one process at a time: the second
 worker fails or hangs at backend start-up. Local multi-worker launches
-are for the CPU backend (the tests and ``bench.py --multihost`` pin
-``JAX_PLATFORMS=cpu``). On a TPU host run ONE process that drives all
+are for the CPU backend (the tests pin ``JAX_PLATFORMS=cpu``). On a TPU host run ONE process that drives all
 its chips (``chip_smoke.py --chips 4`` does), or one launched worker
 per host.
 
@@ -227,8 +226,8 @@ def launch_local(num_workers, command, extra_env=(), port=None,
 
 
 class _Events:
-    """Append-only JSONL event log for the supervisor (bench + tests
-    read detection/restart timings from it)."""
+    """Append-only JSONL event log for the supervisor (the tests read
+    detection/restart timings from it)."""
 
     def __init__(self, path):
         self.path = path

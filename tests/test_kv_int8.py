@@ -1,5 +1,6 @@
-"""Int8-quantized paged KV cache (mxnet_tpu.serving.kvcache q8 ops,
-DecodeServer int8 programs, flash_decode in-kernel dequantization).
+"""Int8-quantized paged KV cache (mxnet_tpu.serving.kvcache q8 ops and
+int8 layout, DecodeServer's programs over it, flash_decode in-kernel
+dequantization).
 
 The contract under test: an int8 pool stores K/V pages at a quarter of
 the fp32 bytes with one fp32 scale per (layer, page); the q8 scatter /
@@ -29,6 +30,14 @@ def _clean_state():
     compile_watch.disable()
 
 
+INT8_NAMES = ("k", "v", "k_scale", "v_scale")
+
+
+def _carried(pool):
+    """The pool's carried arrays by name."""
+    return dict(zip(pool.names, pool.arrays))
+
+
 def _q8_pool_arrays(L=2, P=8, S=8, H=2, D=8):
     import jax.numpy as jnp
     pages = jnp.zeros((L, P, S, H, D), jnp.int8)
@@ -43,20 +52,21 @@ def _q8_pool_arrays(L=2, P=8, S=8, H=2, D=8):
 def test_pool_int8_env_and_explicit_dtype(monkeypatch):
     import jax.numpy as jnp
     pool = KVCachePool(2, 2, 8, page_size=8, n_pages=8)
-    assert not pool.quantized and pool.dtype == jnp.float32
-    assert pool.k_scale is None and pool.v_scale is None
+    assert pool.names == ("k", "v") and pool.dtype == jnp.float32
 
     monkeypatch.setenv("MXNET_KV_DTYPE", "int8")
     pool = KVCachePool(2, 2, 8, page_size=8, n_pages=8)
-    assert pool.quantized and pool.dtype == jnp.int8
-    assert pool.k.dtype == jnp.int8 and pool.v.dtype == jnp.int8
-    assert pool.k_scale.shape == (2, 8)
-    assert pool.k_scale.dtype == jnp.float32
+    assert pool.names == INT8_NAMES and pool.dtype == jnp.int8
+    arrays = _carried(pool)
+    assert arrays["k"].dtype == jnp.int8 and arrays["v"].dtype == jnp.int8
+    assert arrays["k_scale"].shape == arrays["v_scale"].shape == (2, 8)
+    assert arrays["k_scale"].dtype == jnp.float32
+    assert pool.k is arrays["k"] and pool.v is arrays["v"]
     assert pool.stats()["dtype"] == "int8"
 
     monkeypatch.delenv("MXNET_KV_DTYPE")
     pool = KVCachePool(2, 2, 8, page_size=8, n_pages=8, dtype="int8")
-    assert pool.quantized
+    assert pool.names == INT8_NAMES
 
     monkeypatch.setenv("MXNET_KV_DTYPE", "int7")
     with pytest.raises(mx.MXNetError):
@@ -217,7 +227,7 @@ def test_server_int8_completions_fixed_programs(monkeypatch):
     srv = DecodeServer(model, params, seq_ladder=[16, 32],
                        max_new_tokens=8, window=4, page_size=8,
                        pool_pages=32, start=False)
-    assert srv._pool.quantized
+    assert srv._pool.names == INT8_NAMES
     free0 = srv._pool.stats()["free"]
     srv.warmup()
     warm = compile_watch.site_stats("decode")
@@ -300,6 +310,49 @@ def test_server_int8_tokens_match_full_forward_q8_oracle(monkeypatch):
     assert got == want
 
 
+def test_int8_pool_carries_more_streams_at_the_same_bytes(monkeypatch):
+    """At the bytes of a 7-page float32 pool (its carried arrays
+    summed), the int8 pool — scales counted — holds 27 pages: 8 streams
+    of 3 pages where float32 holds 2, each run at its full ceiling with
+    no preemption and no failed allocation."""
+    model = ToyDecoderLM(vocab=32, n_layers=1, n_heads=2, head_dim=8,
+                         max_len=128)
+    params = model.init_params(seed=3)
+
+    def pool_bytes(dtype, pages):
+        return sum(a.nbytes for a in KVCachePool(
+            1, 2, 8, page_size=8, n_pages=pages, dtype=dtype).arrays)
+
+    budget = pool_bytes("float32", 7)
+    int8_pages = max(n for n in range(2, 64)
+                     if pool_bytes("int8", n) <= budget)
+    assert int8_pages == 27
+
+    def ceiling(dtype, pages):
+        monkeypatch.setenv("MXNET_KV_DTYPE", dtype)
+        cap = (pages - 1) // 3          # 14 prompt + 10 new = 3 pages
+        srv = DecodeServer(model, params, seq_ladder=[16],
+                           max_new_tokens=10, window=cap, page_size=8,
+                           pool_pages=pages, max_queue=cap + 4,
+                           start=False)
+        rs = np.random.RandomState(7)
+        reqs = [srv.submit(rs.randint(1, 32, size=14), max_new_tokens=10)
+                for _ in range(cap)]
+        n = 0
+        while not all(r.done() for r in reqs):
+            srv._tick()
+            n += 1
+            assert n < 500, "scheduler made no progress"
+        st = srv.stats()
+        srv.stop()
+        assert st["completed"] == cap and st["preempted"] == 0
+        assert st["kv"]["alloc_failures"] == 0
+        assert st["kv"]["dtype"] == dtype
+        return cap
+
+    assert (ceiling("float32", 7), ceiling("int8", int8_pages)) == (2, 8)
+
+
 # ---------------------------------------------------------------------------
 # flash_decode int8 kernel path
 # ---------------------------------------------------------------------------
@@ -366,8 +419,8 @@ def test_q8_shared_prefix_hit_deterministic_scales_untouched(
     _go(prompt)                                    # miss: fills index
     pages = [p for d, (p, _ns)
              in srv._pool.prefix._entries.items()]
-    ks0 = np.asarray(srv._pool.k_scale)[:, pages].copy()
-    vs0 = np.asarray(srv._pool.v_scale)[:, pages].copy()
+    ks0 = np.asarray(_carried(srv._pool)["k_scale"])[:, pages].copy()
+    vs0 = np.asarray(_carried(srv._pool)["v_scale"])[:, pages].copy()
     assert np.all(ks0 > 0) and np.all(vs0 > 0)
 
     hit1, r1 = _go(prompt)
@@ -379,16 +432,16 @@ def test_q8_shared_prefix_hit_deterministic_scales_untouched(
     # the SHARED pages' scales never moved: hit traffic wrote only
     # COW copies and fresh suffix pages
     np.testing.assert_array_equal(
-        np.asarray(srv._pool.k_scale)[:, pages], ks0)
+        np.asarray(_carried(srv._pool)["k_scale"])[:, pages], ks0)
     np.testing.assert_array_equal(
-        np.asarray(srv._pool.v_scale)[:, pages], vs0)
+        np.asarray(_carried(srv._pool)["v_scale"])[:, pages], vs0)
     srv.stop()
 
 
 def test_q8_cow_copy_carries_the_scales(monkeypatch):
-    """The q8 COW program copies page BODY and per-page scales
-    together — the private fork dequantizes bit-identically to the
-    shared page it split from."""
+    """The one COW program, over an int8 pool's carried arrays, copies
+    page BODY and per-page scales together — the private fork
+    dequantizes bit-identically to the shared page it split from."""
     import jax.numpy as jnp
     monkeypatch.setenv("MXNET_KV_DTYPE", "int8")
     model = ToyDecoderLM(vocab=32, n_layers=1, n_heads=2, head_dim=8,
@@ -398,17 +451,15 @@ def test_q8_cow_copy_carries_the_scales(monkeypatch):
                        max_new_tokens=4, window=2, page_size=8,
                        pool_pages=8, prefix_cache=True, start=False)
     rs = np.random.RandomState(1)
-    k = jnp.asarray(rs.randint(-127, 128, size=srv._pool.k.shape),
-                    jnp.int8)
-    v = jnp.asarray(rs.randint(-127, 128, size=srv._pool.v.shape),
-                    jnp.int8)
-    ks = jnp.asarray(rs.uniform(0.004, 0.02,
-                                size=srv._pool.k_scale.shape)
+    shapes = {n: a.shape for n, a in _carried(srv._pool).items()}
+    assert tuple(shapes) == INT8_NAMES
+    k = jnp.asarray(rs.randint(-127, 128, size=shapes["k"]), jnp.int8)
+    v = jnp.asarray(rs.randint(-127, 128, size=shapes["v"]), jnp.int8)
+    ks = jnp.asarray(rs.uniform(0.004, 0.02, size=shapes["k_scale"])
                      .astype(np.float32))
-    vs = jnp.asarray(rs.uniform(0.004, 0.02,
-                                size=srv._pool.v_scale.shape)
+    vs = jnp.asarray(rs.uniform(0.004, 0.02, size=shapes["v_scale"])
                      .astype(np.float32))
-    k2, v2, ks2, vs2 = srv._cow_fn_q8(k, v, ks, vs, 2, 5)
+    k2, v2, ks2, vs2 = srv._cow_fn(k, v, ks, vs, 2, 5)
     np.testing.assert_array_equal(np.asarray(k2)[:, 5],
                                   np.asarray(k)[:, 2])
     np.testing.assert_array_equal(np.asarray(v2)[:, 5],
@@ -422,7 +473,7 @@ def test_q8_cow_copy_carries_the_scales(monkeypatch):
         * np.asarray(s)[:, i, None, None, None]
     np.testing.assert_array_equal(deq(k2, ks2, 5), deq(k, ks, 2))
     # every other page untouched
-    untouched = [i for i in range(srv._pool.k.shape[1]) if i != 5]
+    untouched = [i for i in range(shapes["k"][1]) if i != 5]
     np.testing.assert_array_equal(np.asarray(k2)[:, untouched],
                                   np.asarray(k)[:, untouched])
     np.testing.assert_array_equal(np.asarray(ks2)[:, untouched],
@@ -476,11 +527,171 @@ def test_q8_recycled_shared_page_scale_resets(monkeypatch):
     assert len(common) == 2                # both of B's full pages
     tp = [te[d] for d in common]
     fp = [fe[d] for d in common]
-    np.testing.assert_array_equal(
-        np.asarray(tight._pool.k_scale)[:, tp],
-        np.asarray(fresh._pool.k_scale)[:, fp])
-    np.testing.assert_array_equal(
-        np.asarray(tight._pool.v_scale)[:, tp],
-        np.asarray(fresh._pool.v_scale)[:, fp])
+    for name in ("k_scale", "v_scale"):
+        np.testing.assert_array_equal(
+            np.asarray(_carried(tight._pool)[name])[:, tp],
+            np.asarray(_carried(fresh._pool)[name])[:, fp])
     tight.stop()
     fresh.stop()
+
+
+# ---------------------------------------------------------------------------
+# one cache kind, one place: the layout is picked once, and the one pair
+# of programs over the int8 layout is the pair the int8 twins were
+# ---------------------------------------------------------------------------
+
+K_V = (("k", (2, 8)), ("v", (2, 8)))
+
+
+@pytest.mark.parametrize("arrays, dtype, kind, names, token_bytes", [
+    (K_V, "float32", "_PerHeadKV", ("k", "v"), 3 * 2 * 16 * 4),
+    (K_V, "bfloat16", "_PerHeadKV", ("k", "v"), 3 * 2 * 16 * 2),
+    (K_V, "int8", "_PerHeadKVInt8", INT8_NAMES, 3 * 2 * 16),
+    ((("kv", (64,)),), "bfloat16", "_Latent", ("kv",), 3 * 64 * 2),
+    ((("kv", (64,)),), "int8", "int8 pages with per-page scales", None,
+     None),
+    ((("a", (2, 8)), ("b", (2, 8)), ("c", (2, 8))), "float32",
+     "no cache layout", None, None),
+], ids=["kv-f32", "kv-bf16", "kv-int8", "latent", "latent-int8-refused",
+        "three-arrays-refused"])
+def test_pool_picks_the_layout_from_declaration_and_dtype(
+        arrays, dtype, kind, names, token_bytes):
+    import jax.numpy as jnp
+    if names is None:
+        with pytest.raises(mx.MXNetError, match=kind):
+            KVCachePool(3, arrays=arrays, dtype=dtype, page_size=8,
+                        n_pages=4)
+        return
+    pool = KVCachePool(3, arrays=arrays, dtype=dtype, page_size=8,
+                       n_pages=4)
+    assert type(pool.layout).__name__ == kind
+    # chosen once: the same (declaration, dtype) is the same object, for
+    # another pool and for a program that asks from its model and arrays
+    assert kvcache.cache_layout(pool.array_specs, pool.dtype) \
+        is pool.layout
+    model = type("M", (), {"cache_arrays": arrays})()
+    assert kvcache.layout_for(model, pool.arrays) is pool.layout
+    assert pool.names == names and pool.array_specs == arrays
+    assert pool.token_bytes == token_bytes
+    pages = [a for n, a in _carried(pool).items()
+             if not n.endswith("_scale")]
+    assert [a.shape for a in pages] == [(3, 4, 8) + t for _n, t in arrays]
+    assert all(a.dtype == jnp.dtype(dtype) for a in pages)
+    st = pool.stats()
+    assert st["dtype"] == dtype and st["token_bytes"] == token_bytes
+    assert st["arrays"] == {n: list(t) for n, t in arrays}
+
+
+def _old_prefill_fn_q8(model, params, tokens, n_valid, page_table,
+                       k_pages, v_pages, k_scales, v_scales):
+    """``DecodeServer._prefill_fn_q8`` as it stood at 3913add, before
+    the int8 pool became a layout behind one ``_prefill_fn`` — the
+    oracle."""
+    import jax.numpy as jnp
+    logits, k_seq, v_seq = model.prefill(params, tokens)
+    k_pages, k_scales = kvcache.scatter_prefill_q8(
+        k_pages, k_scales, page_table, k_seq[:, 0], n_valid)
+    v_pages, v_scales = kvcache.scatter_prefill_q8(
+        v_pages, v_scales, page_table, v_seq[:, 0], n_valid)
+    last = jnp.take(logits[0], n_valid - 1, axis=0)
+    token = jnp.argmax(last).astype(jnp.int32)
+    return token, k_pages, v_pages, k_scales, v_scales
+
+
+def _old_decode_fn_q8(model, params, tokens, positions, page_tables,
+                      k_pages, v_pages, k_scales, v_scales):
+    import jax.numpy as jnp
+    attend = functools.partial(kvcache.paged_attention, k_pages,
+                               v_pages, page_tables, positions,
+                               k_scale=k_scales, v_scale=v_scales)
+    logits, k_new, v_new = model.decode(
+        params, tokens, positions, attend)
+    k_pages, k_scales = kvcache.scatter_token_q8(
+        k_pages, k_scales, page_tables, positions, k_new)
+    v_pages, v_scales = kvcache.scatter_token_q8(
+        v_pages, v_scales, page_tables, positions, v_new)
+    tokens_out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return tokens_out, k_pages, v_pages, k_scales, v_scales
+
+
+def _old_cow_fn_q8(k_pages, v_pages, k_scales, v_scales, src, dst):
+    k_pages = k_pages.at[:, dst].set(k_pages[:, src])
+    v_pages = v_pages.at[:, dst].set(v_pages[:, src])
+    k_scales = k_scales.at[:, dst].set(k_scales[:, src])
+    v_scales = v_scales.at[:, dst].set(v_scales[:, src])
+    return k_pages, v_pages, k_scales, v_scales
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["jnp", "pallas"])
+def test_int8_server_runs_the_programs_its_twins_ran(use_pallas):
+    """The one ``_prefill_fn`` / ``_decode_fn`` / ``_cow_fn`` over the
+    int8 layout trace to the jaxprs of the deleted ``*_q8`` twins: an
+    int8 server runs the programs it ran."""
+    import jax
+    import jax.numpy as jnp
+    model = ToyDecoderLM(vocab=32, n_layers=2, n_heads=2, head_dim=8,
+                         max_len=128, use_pallas=use_pallas)
+    params = model.init_params(seed=3)
+    holder = type("S", (), {"_model": model})()
+    pages, scales = _q8_pool_arrays(2, 24, 8, 2, 8)
+    pools = (pages, pages, scales, scales)
+    step_args = (params, jnp.zeros((3,), jnp.int32),
+                 jnp.zeros((3,), jnp.int32), jnp.zeros((3, 6), jnp.int32),
+                 *pools)
+    new = jax.make_jaxpr(functools.partial(DecodeServer._decode_fn,
+                                           holder))(*step_args)
+    old = jax.make_jaxpr(functools.partial(_old_decode_fn_q8,
+                                           model))(*step_args)
+    assert str(new) == str(old)
+    pre_args = (params, jnp.zeros((1, 16), jnp.int32), jnp.int32(5),
+                jnp.zeros((6,), jnp.int32), *pools)
+    new = jax.make_jaxpr(functools.partial(DecodeServer._prefill_fn,
+                                           holder))(*pre_args)
+    old = jax.make_jaxpr(functools.partial(_old_prefill_fn_q8,
+                                           model))(*pre_args)
+    assert str(new) == str(old)
+    cow_args = (*pools, jnp.int32(2), jnp.int32(5))
+    new = jax.make_jaxpr(functools.partial(DecodeServer._cow_fn,
+                                           holder))(*cow_args)
+    old = jax.make_jaxpr(_old_cow_fn_q8)(*cow_args)
+    assert str(new) == str(old)
+
+
+class _CountingLM(ToyDecoderLM):
+    """ToyDecoderLM that also declares a step counter."""
+    step_counters = ("toy", ("rows", "max_batch"))
+
+    def decode(self, params, tokens, positions, attend):
+        import jax.numpy as jnp
+        out = super().decode(params, tokens, positions, attend)
+        width = tokens.shape[0]
+        return (*out, jnp.asarray([width, width], jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_step_counters_leave_with_the_tokens_on_every_kind(
+        monkeypatch, dtype):
+    """What the float program had and the int8 twin had lost: a model's
+    step counters reach ``stats()`` whatever the pool's kind."""
+    monkeypatch.setenv("MXNET_KV_DTYPE", dtype)
+    model = _CountingLM(vocab=32, n_layers=1, n_heads=2, head_dim=8,
+                        max_len=128)
+    srv = DecodeServer(model, model.init_params(seed=3), seq_ladder=[16],
+                       max_new_tokens=6, window=2, page_size=8,
+                       pool_pages=16, start=False)
+    assert srv.stats()["kv"]["dtype"] == dtype
+    req = srv.submit(np.asarray([3, 9, 4, 1, 7, 2], np.int32),
+                     max_new_tokens=4)
+    n = 0
+    while not req.done():
+        srv._tick()
+        n += 1
+        assert n < 200
+    assert len(req.result(timeout=5)) == 4
+    st = srv.stats()
+    steps = st["decode_steps"]
+    assert steps >= 3
+    assert st["toy"] == {"steps": steps, "rows": 2 * steps, "max_batch": 2,
+                         "last": {"rows": 2, "max_batch": 2}}
+    srv.stop()

@@ -93,7 +93,7 @@ def _cached_logits(model, params, tokens, n_prompt, page_size=16):
 
     @jax.jit
     def step(pages, tok, pos):
-        attend = kvcache.attend_for((pages,), table[None], pos)
+        attend = pool.layout.attend((pages,), table[None], pos)
         logits, new, _ = model.decode(params, tok, pos, attend)
         return logits[0], kvcache.write_token_rows(
             pages, table[None], pos, new, model.use_pallas)

@@ -297,6 +297,36 @@ def test_shared_pages_survive_the_request_that_filled_them():
         srv.stop()
 
 
+@pytest.mark.parametrize("prefix", [False, True], ids=["off", "on"])
+def test_stream_ceiling_at_the_same_pool_with_and_without_sharing(prefix):
+    """The stream ceiling of one pool: 12 usable pages of 4 tokens, a
+    12-token header (3 pages) and a 1-token suffix a stream. Unshared,
+    a stream holds 4 pages (3 streams); shared, the header's 3 pages
+    once and one private page a stream (9 streams). Either way every
+    stream of a full window completes with no preemption and no failed
+    allocation."""
+    model, params = _toy()
+    usable, header_pages = 12, 3
+    cap = usable - header_pages if prefix else usable // (header_pages + 1)
+    srv = _srv(model, params, prefix=prefix, pool_pages=usable + 1,
+               window=cap, max_new_tokens=2, max_queue=cap + 4)
+    try:
+        if prefix:
+            _gen(srv, BASE, n=1)       # seed the index with the header
+        reqs = [srv.submit(np.concatenate([BASE, [1 + i]]).astype(np.int32),
+                           max_new_tokens=2) for i in range(cap)]
+        _drain(srv, *reqs)
+        assert all(r.state == "done" and len(r.result(timeout=1)) == 2
+                   for r in reqs)
+        st = srv.stats()
+        assert st["completed"] == cap + int(prefix)
+        assert st["preempted"] == 0 and st["kv"]["alloc_failures"] == 0
+        assert st["kv"]["peak_used"] <= usable
+        assert st["prefix"]["hits"] == (cap if prefix else 0)
+    finally:
+        srv.stop()
+
+
 # ---------------------------------------------------------------------------
 # multi-model pools: quotas, namespaces, cross-server preemption
 # ---------------------------------------------------------------------------
