@@ -38,10 +38,16 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   priorities, with cross-server preemption when a higher-pool-priority
   tenant starves; the pool's ``step_lock`` serializes their compiled
   steps on the shared arrays.
-- **Continuous batching** — one scheduler loop interleaves at most one
-  prefill with every decode step, so decode steps never starve behind
-  a burst of long prefills, and a newly-admitted request starts
-  decoding in the very next step alongside requests admitted long ago.
+- **Continuous batching, one step ahead of the host** — one scheduler
+  loop interleaves at most one prefill with every decode step, so
+  decode steps never starve behind a burst of long prefills, and a
+  newly-admitted request starts decoding in the very next step
+  alongside requests admitted long ago. The next step is dispatched
+  BEFORE the last one's tokens are read back, fed from that step's
+  token array where it lies on the device: the launch and everything
+  the host does with a step's tokens run under the next step
+  (``_tick`` has the order; ``stats()["decode_steps_ahead"]`` and
+  ``["decode_drains"]`` say how often it engages).
 - **Streaming + cancellation** — ``submit`` returns a
   :class:`DecodeRequest` future whose :meth:`DecodeRequest.tokens`
   iterator yields tokens as steps complete; :meth:`DecodeRequest.
@@ -159,6 +165,22 @@ class _ParamsVersion:
         self.tree = tree
 
 
+class _Step:
+    """One dispatched decode step whose tokens are still on the device:
+    the rows it ran (the index is the row's slot), which of them emit a
+    token (a mid-suffix feed's output is discarded), the device token
+    array, and what ``stats()`` counts when it is read back."""
+
+    __slots__ = ("rows", "emits", "toks", "pages_live", "ahead")
+
+    def __init__(self, rows, emits, toks, pages_live, ahead):
+        self.rows = rows
+        self.emits = emits
+        self.toks = toks
+        self.pages_live = pages_live
+        self.ahead = ahead
+
+
 class DecodeRequest:
     """One streaming generation: a future over the full token list
     plus a per-token stream. The server appends each generated token
@@ -171,7 +193,8 @@ class DecodeRequest:
                  "request_id", "t_submit", "pages", "generated",
                  "params", "state", "_cancelled", "_stream", "_event",
                  "_error", "_last_emit", "trace_args",
-                 "_t_trace", "pending", "pending_pos", "prefix_cached")
+                 "_t_trace", "pending", "pending_pos", "prefix_cached",
+                 "unread")
 
     def __init__(self, prompt, max_new, priority, deadline, eos_id,
                  request_id):
@@ -202,6 +225,10 @@ class DecodeRequest:
         self.pending = None
         self.pending_pos = 0
         self.prefix_cached = 0    # prompt tokens served from the index
+        # tokens of this request that a dispatched decode step has
+        # computed and the scheduler has not read back yet: what it
+        # plans from is ``len(generated) + unread``
+        self.unread = 0
 
     def done(self):
         return self._event.is_set()
@@ -518,14 +545,17 @@ class DecodeServer:
         # donation makes each step update the pool in place on real
         # accelerators; the CPU PJRT client cannot donate (it would
         # only warn per compile), and correctness never depends on it
-        donate = cow_donate = {}
+        donate = step_donate = cow_donate = {}
         n_pool = len(self._pool.arrays)
         if jax.default_backend() not in ("cpu",):
             donate = {"donate_argnums": tuple(range(4, 4 + n_pool))}
+            # the step's pools come after the fed-back token array and
+            # its slots, neither of which it may consume
+            step_donate = {"donate_argnums": tuple(range(6, 6 + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
         self._decode_prog = compile_watch.jit(
             self._decode_fn, "%s:step" % site,
-            statics=(site, self._window, self._max_pages), **donate)
+            statics=(site, self._window, self._max_pages), **step_donate)
         self._prefill_progs = {}
         for rung in self._seq_ladder.buckets:
             self._prefill_progs[rung] = compile_watch.jit(
@@ -546,8 +576,9 @@ class DecodeServer:
         self._stats = {"requests": 0, "completed": 0, "cancelled": 0,
                        "timeouts": 0, "shed": 0, "errors": 0,
                        "preempted": 0, "prefill_steps": 0,
-                       "decode_steps": 0, "decode_faults": 0,
-                       "tokens_out": 0, "queue_peak": 0, "swaps": 0,
+                       "decode_steps": 0, "decode_steps_ahead": 0,
+                       "decode_faults": 0, "tokens_out": 0,
+                       "queue_peak": 0, "swaps": 0,
                        "prefix_hits": 0, "prefix_misses": 0,
                        "prefix_hit_tokens": 0, "cow_splits": 0,
                        "cow_degraded": 0, "cross_preempts": 0,
@@ -556,6 +587,18 @@ class DecodeServer:
                        "decode_pages_table": 0}
         self._shed_by_priority = {}
         self._counted = {}        # the model's step counters, summed
+        # the decode step that is dispatched and not yet read back (the
+        # scheduler reads one step behind), why it was read before the
+        # next was planned whenever it was (``stats()["decode_drains"]``),
+        # a drain another thread asked for, and what a step with no
+        # step before it is handed as ``prev``
+        self._unread = None
+        self._drains = {}
+        self._drain_ask = None
+        n_counts = len(self._counters[1]) if self._counters else 0
+        self._no_prev = jax.device_put(
+            _np.zeros((self._window + n_counts,), _np.int32),
+            self._device)
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
         self._intervals = deque(maxlen=ring)    # inter-token ms
         self._ttft = deque(maxlen=ring)         # submit -> first token
@@ -597,7 +640,20 @@ class DecodeServer:
         token = jnp.argmax(last).astype(jnp.int32)
         return (token, *pools)
 
-    def _decode_fn(self, params, tokens, positions, page_tables, *pools):
+    def _decode_fn(self, params, tokens, positions, page_tables, prev,
+                   src, *pools):
+        """The step program. A row's input token is the one the step
+        before computed, taken where it lies on the device: ``prev`` is
+        that step's token array (not yet read back when this one is
+        dispatched) and ``src[i]`` the slot row ``i`` had in it; ``-1``
+        takes the host's ``tokens[i]`` (a row just admitted, a suffix
+        feed, the first step after an empty window)."""
+        import jax.numpy as jnp
+        tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+        return self._step_fn(params, tokens, positions, page_tables,
+                             *pools)
+
+    def _step_fn(self, params, tokens, positions, page_tables, *pools):
         import jax.numpy as jnp
         layout = kvcache.layout_for(self._model, pools)
         attend = layout.attend(pools, page_tables, positions)
@@ -708,6 +764,11 @@ class DecodeServer:
                 self._finish(r, ServerClosedError(
                     "server stopped; request %s dropped"
                     % r.request_id))
+            if not (self._started and self._thread.is_alive()):
+                # the step a non-draining stop found unread: its rows
+                # have just failed, so its output is dropped like that
+                # of any row ended while its step was unread
+                self._read_unread("stop")
         self._closed = True
         # NOTE: the prefix index is NOT released here — on a shared
         # pool the surviving co-tenant servers keep hitting the cached
@@ -757,10 +818,16 @@ class DecodeServer:
                 pos = _np.zeros((self._window,), _np.int32)
                 pts = _np.zeros((self._window, self._max_pages),
                                 _np.int32)
-                out = self._decode_prog(self._params.tree, toks, pos,
-                                        pts, *self._pool.arrays)
-                jax.block_until_ready(out[0])
-                self._adopt_pool(out)
+                src = _np.full((self._window,), -1, _np.int32)
+                # twice: fed nothing, then fed its own token array, the
+                # two kinds of ``prev`` a live step is handed
+                prev = self._no_prev
+                for _ in range(2):
+                    out = self._decode_prog(
+                        self._params.tree, toks, pos, pts, prev, src,
+                        *self._pool.arrays)
+                    jax.block_until_ready(out[0])
+                    prev = self._adopt_pool(out)[0]
                 n += 1
                 if self._prefix_on:
                     # the COW copy joins the fixed set only when the
@@ -938,6 +1005,9 @@ class DecodeServer:
             new_version = old.version + 1
             self._params = _ParamsVersion(new_version, new_tree)
             self._stats["swaps"] += 1
+            # the scheduler reads its unread step before it plans with
+            # two generations alive
+            self._drain_ask = "swap_weights"
         if self._prefix_on:
             # the old generation's cached prefixes can never be hit
             # again (the namespace carries the version) — release the
@@ -950,7 +1020,12 @@ class DecodeServer:
     # -- scheduler ---------------------------------------------------------
     def _has_work(self):
         with self._cond:
-            return bool(self._queue or self._active)
+            return not self._idle_locked()
+
+    def _idle_locked(self):
+        """Nothing queued, nothing active, no step unread."""
+        return not self._queue and not self._active \
+            and self._unread is None
 
     def _loop(self):
         while True:
@@ -958,14 +1033,15 @@ class DecodeServer:
                 # idle = no queued/active work (or warmup owns the
                 # pool): a plain long wait — submit/stop/warmup-end
                 # all notify, the 1 s belt only backstops a lost wake
-                while not self._stopping and (self._warming or
-                                              (not self._queue
-                                               and not self._active)):
+                # (a step still unread when warmup begins is read
+                # first: the tick does that and nothing else)
+                while not self._stopping and (
+                        self._idle_locked() or
+                        (self._warming and self._unread is None)):
                     with tracing.span("decode.wait"):
                         self._cond.wait(1.0)
                 if self._stopping and (not self._drain
-                                       or (not self._queue
-                                           and not self._active)):
+                                       or self._idle_locked()):
                     break
             if not self._tick():
                 # head-of-line blocked (pool pressure) or a reap-only
@@ -975,12 +1051,25 @@ class DecodeServer:
 
     def _tick(self):
         """One scheduler pass: reap cancellations/deadlines, admit at
-        most ONE prefill, run ONE decode step over every active
-        request — the interleave that keeps decode from starving
-        behind prefill bursts. Returns True when any step ran."""
+        most ONE prefill, dispatch ONE decode step over every active
+        request, then read back and hand out the tokens of the step
+        dispatched a pass EARLIER — the interleave that keeps decode
+        from starving behind prefill bursts, one step ahead of the
+        host. In the device's order: build N+1 → dispatch N+1 → read
+        back N → emit N → record → reap / admit / pages → build N+2;
+        everything the host does with step N's tokens, and the next
+        launch, run while step N+1 does. What is planned counts the
+        token in flight (``DecodeRequest.unread``): positions, the page
+        a write lands in, the end by ``max_new``. A prefill is
+        dispatched behind the step in flight and its first token read
+        at once (it is the request's time to first token). Returns
+        True when any step ran or was read."""
         with self._cond:
-            if self._warming:          # warmup owns the pool arrays
-                return False
+            warming = self._warming
+        if warming:                    # warmup owns the pool arrays:
+            self._read_unread("warmup")     # nothing is left in flight
+            return False
+        with self._cond:
             asks = self._preempt_asks
             self._preempt_asks = 0
             active, queued = len(self._active), len(self._queue)
@@ -1119,7 +1208,9 @@ class DecodeServer:
         with self._cond:
             if self._stopping and not self._drain:
                 return False
-            if not self._queue or len(self._active) >= self._window:
+            if not self._queue or sum(
+                    not self._ending(r)
+                    for r in self._active) >= self._window:
                 return False
             req = self._queue[0]
             ver = self._params    # pinned BEFORE the index lookup —
@@ -1211,10 +1302,7 @@ class DecodeServer:
                         *self._pool.arrays)
                     token = self._adopt_pool(out)[0]
             except Exception as exc:   # noqa: BLE001 — model errors
-                with self._cond:       # belong to the request
-                    if req in self._active:
-                        self._active.remove(req)
-                self._finish(req, exc)
+                self._retire([req], exc)   # belong to the request
                 return True
             if metering.enabled():
                 # a prefill batch is this one request: the whole
@@ -1252,10 +1340,7 @@ class DecodeServer:
         req._push(tok)
         if len(req.generated) >= req.max_new or \
                 (req.eos_id is not None and tok == req.eos_id):
-            with self._cond:
-                if req in self._active:
-                    self._active.remove(req)
-            self._finish(req, None)
+            self._retire([req], None)
         return True
 
     def _ensure_pages(self, rows):
@@ -1272,7 +1357,7 @@ class DecodeServer:
             failed = False
             while True:
                 wp = r.pending_pos if r.pending \
-                    else len(r.prompt) + len(r.generated) - 1
+                    else len(r.prompt) + len(r.generated) + r.unread - 1
                 needed = wp // self._pool.page_size + 1
                 while len(r.pages) < needed:
                     pg = self._pool.alloc(1, owner=self._owner)
@@ -1311,7 +1396,8 @@ class DecodeServer:
                 break
             if not failed:
                 survivors.append(r)
-        return survivors
+        # a drain on the way (a degraded split) may have ended a row
+        return [r for r in survivors if r.state == "active"]
 
     def _cow_row(self, r, pidx):
         """Copy-on-write split of ``r``'s still-shared page ``pidx``:
@@ -1325,6 +1411,11 @@ class DecodeServer:
         try:
             fault.inject("kv_cow")
         except fault.InjectedFault:
+            # the re-feed starts from what the row HAS generated: read
+            # the unread step first, which may be the row's last
+            self._read_unread("cow_degraded")
+            if r.state != "active":
+                return "died"
             with self._cond:
                 self._stats["cow_degraded"] += 1
             self._degrade_private(r)
@@ -1365,11 +1456,28 @@ class DecodeServer:
         r.pending_pos = 0
         r.prefix_cached = 0
 
+    def _ending(self, r):
+        """True for a row that needs no further step: it ends by count
+        with the token of the unread step, which is known before that
+        token is read. Such a row stays active until its step is read
+        and is in no later step; its slot is free for an admission."""
+        return len(r.generated) + r.unread >= r.max_new
+
     def _decode_once(self):
+        """Dispatch the next decode step over every row that needs
+        one, THEN read back the step before it: what the host does
+        with a step's tokens runs under the next step. Reading first
+        ("draining") is the same path at depth 0, taken when the
+        scheduler can see it must, and counted by cause."""
         with self._cond:
-            rows = list(self._active)
+            ask, self._drain_ask = self._drain_ask, None
+        if ask is not None:
+            self._read_unread(ask)
+        with self._cond:
+            rows = [r for r in self._active if not self._ending(r)]
         if not rows:
-            return False
+            # nothing to plan: an unread step is the last of its rows
+            return self._read_unread()
         try:
             fault.inject("serve_decode")
         except fault.InjectedFault:
@@ -1378,34 +1486,59 @@ class DecodeServer:
             # is how deadline tests drive the timeout+reclaim path
             with self._cond:
                 self._stats["decode_faults"] += 1
+            self._read_unread("fault")
             return True
+        if len({r.params for r in rows}) > 1:
+            # two weight generations cannot share a step, and a step
+            # is fed from ONE step before it: depth 0 until one drains
+            self._read_unread("versions")
         with tracing.span("decode.pages"):
             rows = self._ensure_pages(rows)
         if not rows:
+            self._read_unread()
             return True
         groups = {}
         for r in rows:
             groups.setdefault(r.params, []).append(r)
         for ver in sorted(groups, key=lambda v: v.version):
             self._decode_group(ver, groups[ver])
+            if len(groups) > 1:
+                self._read_unread("versions")
         return True
 
     def _decode_group(self, ver, rows):
         D, M = self._window, self._max_pages
+        prev = self._unread
         with tracing.span("decode.build"):
             tokens = _np.zeros((D,), _np.int32)
             positions = _np.zeros((D,), _np.int32)
             pts = _np.zeros((D, M), _np.int32)
+            src = _np.full((D,), -1, _np.int32)
+            slots = {} if prev is None else \
+                {id(r): i for i, r in enumerate(prev.rows)}
+            emits = []
             for i, r in enumerate(rows):
                 if r.pending:
                     # prefix-cache suffix feed: the next un-cached
                     # token runs through the same step program at its
-                    # own absolute position
-                    tokens[i] = r.pending[0]
+                    # own absolute position. The feed advances here,
+                    # at dispatch: nothing it plans from is computed
+                    tokens[i] = r.pending.popleft()
                     positions[i] = r.pending_pos
+                    r.pending_pos += 1
+                    if not r.pending:
+                        r.pending = None
+                    emits.append(r.pending is None)
                 else:
-                    tokens[i] = r.generated[-1]
-                    positions[i] = len(r.prompt) + len(r.generated) - 1
+                    if r.unread:
+                        # its input is the unread step's output: taken
+                        # on the device, from the slot it had there
+                        src[i] = slots[id(r)]
+                    else:
+                        tokens[i] = r.generated[-1]
+                    positions[i] = len(r.prompt) + len(r.generated) \
+                        + r.unread - 1
+                    emits.append(True)
                 pts[i, :len(r.pages)] = r.pages
             # the pages that hold this step's live keys, of the table
             # the step program is compiled for: what a kernel that
@@ -1413,61 +1546,110 @@ class DecodeServer:
             pages_live = int((positions[:len(rows)]
                               // self._pool.page_size + 1).sum())
         try:
-            with tracing.span("decode.dispatch", pages_live=pages_live), \
+            with tracing.span("decode.dispatch", pages_live=pages_live,
+                              ahead=int(prev is not None)), \
                     self._pool.step_lock:
                 toks = self._adopt_pool(self._decode_prog(
                     ver.tree, tokens, positions, pts,
+                    self._no_prev if prev is None else prev.toks, src,
                     *self._pool.arrays))[0]
         except Exception as exc:       # noqa: BLE001 — model errors
-            with self._cond:           # belong to the batch's requests
-                for r in rows:
-                    if r in self._active:
-                        self._active.remove(r)
-            for r in rows:
-                self._finish(r, exc)
+            # belong to the batch's requests, after what the step
+            # before computed for them has been handed out
+            self._read_unread("error")
+            self._retire(rows, exc)
             return
-        with tracing.span("decode.readback") as back:
-            # the last reference to the step's device token array goes
-            # here, so that freeing it (0.3 ms on the chip) is timed
-            toks = _np.asarray(toks)
-            counts = None
-            if self._counters is not None:
-                # what the model counted in this step (its routing)
-                # exists only now: it rides this span, not the dispatch
-                counts = dict(zip(self._counters[1],
-                                  (int(c) for c in toks[D:])))
-                back.set(**counts)
+        for r, emit in zip(rows, emits):
+            r.unread += emit
+        self._unread = _Step(rows, emits, toks, pages_live,
+                             prev is not None)
+        if prev is not None:
+            self._read(prev)
+
+    def _retire(self, rows, error):
+        """Take whichever of ``rows`` are still active off the active
+        list and finish them: cleanly, or with ``error``."""
+        with self._cond:
+            rows = [r for r in rows if r in self._active]
+            for r in rows:
+                self._active.remove(r)
+        for r in rows:
+            self._finish(r, error)
+
+    def _read_unread(self, cause=None):
+        """Read the unread step back now, with nothing dispatched
+        behind it: the loop at depth 0. ``cause`` says why the
+        scheduler had to (``stats()["decode_drains"]``); None where
+        there is no next step to plan. True when a step was read."""
+        step, self._unread = self._unread, None
+        if step is None:
+            return False
+        if cause is not None:
+            with self._cond:
+                self._drains[cause] = self._drains.get(cause, 0) + 1
+        self._read(step)
+        return True
+
+    def _read(self, step):
+        """Read one dispatched step's tokens back and hand them out.
+        A row that ended while the step was unread (cancelled, past
+        its deadline, preempted, stopped, or its ``eos_id`` in the
+        step before) ran one step too many: that output is dropped and
+        nothing is pushed after the end. Its row write landed at the
+        position AFTER everything it generated, in a page it owned at
+        dispatch: beyond the run ``_finish`` registers with the prefix
+        index, so no published page ever holds one, and whatever is
+        dispatched later into a freed page runs after it in the
+        device's order."""
+        D = self._window
+        for r, emits in zip(step.rows, step.emits):
+            r.unread -= emits
+        try:
+            with tracing.span("decode.readback") as back:
+                # the last reference of this side to the step's device
+                # token array goes here, so that freeing it (0.3 ms on
+                # the chip) is timed
+                toks, step.toks = _np.asarray(step.toks), None
+                counts = None
+                if self._counters is not None:
+                    # what the model counted in this step (its
+                    # routing) exists only now: it rides this span,
+                    # not the dispatch
+                    counts = dict(zip(self._counters[1],
+                                      (int(c) for c in toks[D:])))
+                    back.set(**counts)
+        except Exception as exc:       # noqa: BLE001 — the step's error
+            self._retire(step.rows, exc)
+            # whatever was fed from the failed step fails with it
+            self._read_unread("error")
+            return
         now = back.t1
-        with tracing.span("decode.emit", rows=len(rows)) as emit:
+        with tracing.span("decode.emit", rows=len(step.rows)) as emit:
+            emitting = [
+                (i, r) for i, (r, emits)
+                in enumerate(zip(step.rows, step.emits))
+                if emits and r.state == "active"]
             if metering.enabled():
-                # the dispatched step program ran ONE batch over these
-                # rows: each request is billed its share of the
-                # program's cost_analysis FLOPs (equal rows, equal
-                # shares)
+                # the dispatched step program ran ONE batch: each
+                # request it still serves is billed an equal share of
+                # the program's cost_analysis FLOPs
                 cost = compile_watch.last_dispatch(
                     "%s:step" % self._site)
-                if cost is not None:
-                    share = 1.0 / len(rows)
-                    for r in rows:
+                live = [r for r in step.rows if r.state == "active"]
+                if cost is not None and live:
+                    share = 1.0 / len(live)
+                    for r in live:
                         metering.request_flops(
                             metering.inner_key(self, r.request_id),
                             cost["flops"] * share,
                             cost["bytes"] * share)
-            emitting = []
-            for i, r in enumerate(rows):
-                if r.pending:
-                    r.pending.popleft()
-                    r.pending_pos += 1
-                    if r.pending:
-                        continue   # mid-suffix: the output is discarded
-                    r.pending = None
-                emitting.append((i, r))
             emit.set(emitted=len(emitting))
             finished = []
             with self._cond:
                 self._stats["decode_steps"] += 1
-                self._stats["decode_pages_live"] += pages_live
-                self._stats["decode_pages_table"] += D * M
+                self._stats["decode_steps_ahead"] += step.ahead
+                self._stats["decode_pages_live"] += step.pages_live
+                self._stats["decode_pages_table"] += D * self._max_pages
                 if counts is not None:
                     self._count_step(counts)
                 for i, r in emitting:
@@ -1488,13 +1670,7 @@ class DecodeServer:
                 if len(r.generated) >= r.max_new or \
                         (r.eos_id is not None and tok == r.eos_id):
                     finished.append(r)
-            if finished:
-                with self._cond:
-                    for r in finished:
-                        if r in self._active:
-                            self._active.remove(r)
-                for r in finished:
-                    self._finish(r, None)
+            self._retire(finished, None)
 
     def _count_step(self, counts):
         """One decode step's model counters into the running totals
@@ -1514,7 +1690,17 @@ class DecodeServer:
         percentiles, prefill-vs-decode step mix, KV-pool occupancy,
         swap/version state — the ``decode`` telemetry record, the
         diagnose Decode table, and the /metrics gauges all render
-        this."""
+        this. Decode steps are counted when they are READ BACK, one
+        pass after their dispatch: ``decode_steps_ahead`` of
+        ``decode_steps`` were dispatched while the step before them
+        was still unread (the host's share of a token hidden under the
+        device's), and ``decode_drains`` counts by cause the steps the
+        scheduler read before planning the next (``versions``: two
+        weight generations alive; ``swap_weights``, ``warmup``,
+        ``stop``; ``fault``: a planned ``serve_decode`` raise;
+        ``cow_degraded``; ``error``: a dispatch or read-back raised).
+        A token's stamp — ``inter_token_ms``, ``ttft_ms`` — is the
+        moment its step was read back."""
         elapsed = max(tracing.now() - self._t0, 1e-9)
         with self._cond:
             s = dict(self._stats)
@@ -1528,6 +1714,7 @@ class DecodeServer:
             versions.add(id(self._params))
             shed_pri = dict(self._shed_by_priority)
             counted = dict(self._counted)
+            drains = dict(self._drains)
         steps = s["prefill_steps"] + s["decode_steps"]
         out = {
             "name": getattr(self, "_metrics_label", None)
@@ -1547,6 +1734,8 @@ class DecodeServer:
             "window": self._window,
             "prefill_steps": s["prefill_steps"],
             "decode_steps": s["decode_steps"],
+            "decode_steps_ahead": s["decode_steps_ahead"],
+            "decode_drains": drains,
             "decode_faults": s["decode_faults"],
             "prefill_fraction": round(s["prefill_steps"] / steps, 4)
             if steps else None,

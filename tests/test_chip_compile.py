@@ -153,6 +153,14 @@ def test_kernel_compiles_for_v5e(chip, kind, T, dtype):
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _step_holder(model):
+    """What the step program needs of its server, unbound: the model
+    and the step's body."""
+    from mxnet_tpu.serving import DecodeServer
+    return type("S", (), {"_model": model,
+                          "_step_fn": DecodeServer._step_fn})()
+
+
 def _smoke_shapes():
     sys.path.insert(0, ROOT)
     import chip_smoke
@@ -197,13 +205,14 @@ def test_decode_step_program_compiles_and_fits(chip, monkeypatch, shapes):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     pool = spec((L, pool_pages, S, Hh, Dh), jnp.float32)
-    step = DecodeServer._decode_fn       # unbound: only self._model
-    holder = type("S", (), {"_model": model})()
+    holder = _step_holder(model)
     compiled = jax.jit(
-        lambda *a: step(holder, *a), donate_argnums=(4, 5)).lower(
+        lambda *a: DecodeServer._decode_fn(holder, *a),
+        donate_argnums=(6, 7)).lower(
         jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params),
         spec((W,), jnp.int32), spec((W,), jnp.int32),
-        spec((W, M), jnp.int32), pool, pool).compile()
+        spec((W, M), jnp.int32), spec((W,), jnp.int32),
+        spec((W,), jnp.int32), pool, pool).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == L
     assert len(_named_calls(text, "flash_decode")) == L
@@ -256,16 +265,18 @@ def test_latent_moe_programs_compile_and_fit(chip, monkeypatch):
     tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
     pool = spec((L, pages, S, model.row_width), jnp.bfloat16)
     pool_bytes = L * pages * S * model.row_width * 2
-    holder = type("S", (), {"_model": model})()
+    holder = _step_holder(model)
+    n_counts = len(model.step_counters[1])
 
     def named(text, kernel):
         return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
                           % kernel, text, re.M)
 
     step = jax.jit(lambda *a: DecodeServer._decode_fn(holder, *a),
-                   donate_argnums=(4,)).lower(
+                   donate_argnums=(6,)).lower(
         tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
-        spec((W, M), jnp.int32), pool).compile()
+        spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
+        spec((W,), jnp.int32), pool).compile()
     text = step.as_text()
     assert len(named(text, "mla_decode")) == L
     assert ".k%d.d%d.bfloat16.r%d.paged" % (

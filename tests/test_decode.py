@@ -7,6 +7,7 @@ The load-bearing contract: N tokens produced by prefill + stepwise
 cached decode are IDENTICAL to greedy generation by one full-sequence
 forward at each length — on the jnp reference attention path AND the
 Pallas flash kernels (interpret mode on CPU)."""
+import functools
 import json
 import os
 import subprocess
@@ -241,8 +242,13 @@ def test_streaming_iterator_and_cancel_frees_pages():
         rest = list(it)                       # stream just ends
         got = [int(t) for t in req.result(timeout=1)]
         # deterministic: each tick interleaves one prefill AND one
-        # decode step, so 2 ticks emitted exactly 3 tokens
-        assert seen + rest == got and len(got) == 3
+        # decode step, read back a tick later: 2 ticks emitted the
+        # prefill's token and the first step's, and the second step,
+        # unread when the cancel landed, ran one step too many — its
+        # token is dropped, nothing is pushed after the end
+        assert seen + rest == got and len(got) == 2
+        assert srv.stats()["decode_steps"] == 2
+        assert srv.stats()["tokens_out"] == 2
         assert srv.stats()["cancelled"] == 1
         # admission covered positions 0..7 with one 8-slot page; the
         # second decode step's write at position 8 grew a second —
@@ -765,3 +771,480 @@ def test_stop_with_wedged_scheduler_degrades_not_hangs(monkeypatch):
         if srv._thread is not None:          # let the sleeper retire
             srv._thread.join(2)
     assert srv._pool.stats()["used"] == 0    # pages reclaimed anyway
+
+
+# ---------------------------------------------------------------------------
+# one step behind: the next step is dispatched before the last one's
+# tokens are read (PR 30). Whatever happens to a row while its step is
+# unread, the served tokens are those of a one-row-at-a-time reference:
+# none lost, none after the end
+# ---------------------------------------------------------------------------
+
+def _cut(tokens, eos):
+    """A reference stream as a server with ``eos_id`` serves it."""
+    return tokens[:tokens.index(eos) + 1] if eos in tokens else tokens
+
+
+def _served(req):
+    """The request's tokens twice: the future's and the stream's (a
+    failed request's stream raises after the tokens that landed)."""
+    got = [int(t) for t in req.generated]
+    streamed = []
+    try:
+        for t in req.tokens(timeout=1):
+            streamed.append(int(t))
+    except Exception as exc:
+        assert exc is req._error
+    assert streamed == got
+    return got
+
+
+def _ahead_srv(model, params, **kw):
+    kw.setdefault("seq_ladder", [16, 32])
+    kw.setdefault("max_new_tokens", 24)
+    kw.setdefault("window", 4)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("pool_pages", 64)
+    return DecodeServer(model, params, start=False, **kw)
+
+
+def _prompts(n, lo=3, hi=14, seed=7):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 32, size=int(rng.integers(lo, hi)))
+            .astype(np.int32) for _ in range(n)]
+
+
+def _behind_count_and_eos(monkeypatch):
+    """Rows ending by count (known before their last token is read:
+    they are simply not in the next step) beside rows ending by
+    ``eos_id`` (known only once it is read: one step too many, its
+    output dropped)."""
+    model, params = _toy()
+    prompts = _prompts(7)
+    budgets = [3, 9, 1, 14, 6, 2, 11]
+    refs = [_reference(model, params, p, n)
+            for p, n in zip(prompts, budgets)]
+    # an eos some way into the reference stream of every other row
+    eos = [ref[len(ref) // 2] if i % 2 else None
+           for i, ref in enumerate(refs)]
+    srv = _ahead_srv(model, params)
+    try:
+        free0 = srv._pool.stats()["free"]
+        reqs = [srv.submit(p, max_new_tokens=n, eos_id=e)
+                for p, n, e in zip(prompts, budgets, eos)]
+        _drain(srv, *reqs)
+        want = [_cut(ref, e) if e is not None else ref
+                for ref, e in zip(refs, eos)]
+        assert [_served(r) for r in reqs] == want
+        st = srv.stats()
+        assert st["completed"] == 7 and st["errors"] == 0
+        assert st["tokens_out"] == sum(len(w) for w in want)
+        assert st["decode_steps_ahead"] > 0
+        assert st["decode_drains"] == {}
+        assert srv._pool.stats()["free"] == free0
+        assert srv._unread is None and not srv._has_work()
+    finally:
+        srv.stop()
+
+
+def _behind_row_ends_unread(how, monkeypatch):
+    """A row cancelled / past its deadline / preempted while its step
+    is unread: that step's output is dropped, its stream holds a prefix
+    of the reference and nothing after the end; its batch mate's stream
+    is whole."""
+    model, params = _toy()
+    victim_p = np.arange(1, 11, dtype=np.int32)
+    mate_p = np.arange(20, 25, dtype=np.int32)
+    big_p = np.arange(1, 16, dtype=np.int32)
+    ref_v = _reference(model, params, victim_p, 12)
+    ref_m = _reference(model, params, mate_p, 12)
+    # five usable pages: two rows of two pages each, then an arrival
+    # that needs two and outranks the victim
+    kw = {"pool_pages": 6} if how == "preempt" else {}
+    srv = _ahead_srv(model, params, seq_ladder=[16], max_new_tokens=12,
+                     **kw)
+    try:
+        srv.warmup()                  # no compile inside the deadline
+        free0 = srv._pool.stats()["free"]
+        victim = srv.submit(victim_p, max_new_tokens=12, priority=0,
+                            deadline_ms=300 if how == "deadline" else None)
+        srv._tick()
+        mate = srv.submit(mate_p, max_new_tokens=12, priority=1)
+        for _ in range(6):
+            srv._tick()
+        assert srv._unread is not None and victim in srv._unread.rows
+        n_before = len(victim.generated)
+        assert victim.unread == 1 and n_before >= 2
+        if how == "cancel":
+            victim.cancel()
+        elif how == "deadline":
+            time.sleep(0.35)
+        else:
+            # a higher-priority arrival the pool cannot hold beside it
+            big = srv.submit(big_p, max_new_tokens=8, priority=2)
+        srv._tick()
+        assert victim.done()
+        assert victim.state == ("cancelled" if how == "cancel"
+                                else "failed")
+        if how == "deadline":
+            assert isinstance(victim._error, RequestTimeoutError)
+        elif how == "preempt":
+            assert isinstance(victim._error, ServerOverloadedError)
+        _drain(srv, mate, *([big] if how == "preempt" else []))
+        got = _served(victim)
+        assert len(got) == n_before and got == ref_v[:n_before]
+        assert _served(mate) == ref_m
+        if how == "preempt":
+            assert _served(big) == _reference(model, params, big_p, 8)
+            assert srv.stats()["preempted"] == 1
+        assert srv._pool.stats()["free"] == free0
+    finally:
+        srv.stop()
+
+
+def _behind_prefix_suffix_feed(monkeypatch):
+    """A prefix hit feeds its un-cached suffix through the step program
+    from the HOST's tokens while its batch mates are fed from the
+    device; its first generated token is then fed from the device."""
+    model, params = _toy()
+    base = np.arange(1, 22, dtype=np.int32)          # 2 full pages + 5
+    other = np.concatenate([base[:16], [30, 29, 28, 27, 26]]) \
+        .astype(np.int32)
+    mate_p = _prompts(1, seed=5)[0]
+    srv = _ahead_srv(model, params, prefix_cache=True)
+    try:
+        first = srv.submit(base, max_new_tokens=6)
+        _drain(srv, first)
+        mate = srv.submit(mate_p, max_new_tokens=20)
+        for _ in range(3):
+            srv._tick()
+        hit = srv.submit(other, max_new_tokens=8)
+        _drain(srv, hit, mate)
+        assert hit.prefix_cached == 16
+        assert _served(first) == _reference(model, params, base, 6)
+        assert _served(hit) == _reference(model, params, other, 8)
+        assert _served(mate) == _reference(model, params, mate_p, 20)
+        st = srv.stats()
+        assert st["prefix"]["hits"] == 1 and st["prefill_steps"] == 2
+        assert st["decode_drains"] == {}
+    finally:
+        srv.stop()
+
+
+def _behind_cow(degrade, monkeypatch):
+    """A fully cached page-aligned prompt re-runs its last token, whose
+    write splits the shared page — dispatched behind the unread step;
+    with a planned ``kv_cow`` raise the row re-feeds privately from what
+    it HAS generated, so the unread step is read first."""
+    model, params = _toy()
+    base = np.arange(1, 17, dtype=np.int32)          # exactly 2 pages
+    mate_p = _prompts(1, seed=6)[0]
+    srv = _ahead_srv(model, params, prefix_cache=True)
+    if degrade:
+        fault.set_plan("kv_cow:step=1:raise")
+    try:
+        first = srv.submit(base, max_new_tokens=5)
+        _drain(srv, first)
+        mate = srv.submit(mate_p, max_new_tokens=20)
+        for _ in range(3):
+            srv._tick()
+        again = srv.submit(base, max_new_tokens=9)
+        _drain(srv, again, mate)
+        ref = _reference(model, params, base, 9)
+        assert _served(first) == ref[:5] and _served(again) == ref
+        assert _served(mate) == _reference(model, params, mate_p, 20)
+        st = srv.stats()
+        assert st["prefix"]["cow_degraded"] == int(degrade)
+        assert (st["prefix"]["cow_splits"] >= 1) == (not degrade)
+        assert st["decode_drains"] == \
+            ({"cow_degraded": 1} if degrade else {})
+    finally:
+        srv.stop()
+        fault.set_plan(None)
+
+
+def _behind_weight_swap(monkeypatch):
+    """A swap mid-stream: the unread step is read before the scheduler
+    plans with two generations alive, every step is read at once while
+    both are, and the loop runs ahead again when one is left."""
+    model, params_a = _toy(seed=3)
+    params_b = model.init_params(seed=99)
+    pa, pb = _prompts(2, seed=13)
+    srv = _ahead_srv(model, params_a)
+    try:
+        old = srv.submit(pa, max_new_tokens=10)
+        for _ in range(3):
+            srv._tick()
+        assert srv._unread is not None
+        srv.swap_weights(params_b)
+        new = srv.submit(pb, max_new_tokens=22)
+        _drain(srv, old, new)
+        assert _served(old) == _reference(model, params_a, pa, 10)
+        assert _served(new) == _reference(model, params_b, pb, 22)
+        st = srv.stats()
+        assert st["decode_drains"]["swap_weights"] == 1
+        assert st["decode_drains"]["versions"] >= 2 * 6
+        # ahead before the swap and after the old generation drained
+        assert 0 < st["decode_steps_ahead"] < st["decode_steps"]
+    finally:
+        srv.stop()
+
+
+def _behind_two_servers_one_pool(monkeypatch):
+    """Two models on one pool, their steps interleaved: each server's
+    unread step stays its own, and a page one frees while its step is
+    unread may go to the other at once."""
+    model, params_a = _toy(seed=3)
+    params_b = model.init_params(seed=99)
+    pool = KVCachePool(model.n_layers, model.n_heads, model.head_dim,
+                       page_size=8, n_pages=24)
+    a = _ahead_srv(model, params_a, pool=pool, pool_pages=None,
+                   page_size=None, name="a", window=2)
+    b = _ahead_srv(model, params_b, pool=pool, pool_pages=None,
+                   page_size=None, name="b", window=2)
+    try:
+        prompts = _prompts(6, seed=17)
+        budgets = [5, 12, 3, 9, 7, 4]
+        reqs = [(a if i % 2 else b).submit(p, max_new_tokens=n,
+                                            eos_id=None)
+                for i, (p, n) in enumerate(zip(prompts, budgets))]
+        n = 0
+        while not all(r.done() for r in reqs):
+            a._tick()
+            b._tick()
+            n += 1
+            assert n < 500
+        for i, (r, p, k) in enumerate(zip(reqs, prompts, budgets)):
+            assert _served(r) == _reference(
+                model, params_a if i % 2 else params_b, p, k)
+        assert a.stats()["decode_steps_ahead"] > 0
+        assert b.stats()["decode_steps_ahead"] > 0
+    finally:
+        a.stop()
+        b.stop()
+    assert pool.stats()["used"] == 0
+
+
+def _behind_int8_pool(monkeypatch):
+    """An int8 pool (pages and their scales ride the step): a window of
+    rows, ends by count and by eos, against the same rows served one at
+    a time."""
+    monkeypatch.setenv("MXNET_KV_DTYPE", "int8")
+    model, params = _toy()
+    prompts = _prompts(5, seed=19)
+    budgets = [4, 11, 7, 2, 9]
+    alone = []
+    one = _ahead_srv(model, params, window=1)
+    try:
+        for p, n in zip(prompts, budgets):
+            r = one.submit(p, max_new_tokens=n)
+            _drain(one, r)
+            alone.append(_served(r))
+    finally:
+        one.stop()
+    eos = [ref[len(ref) // 2] if i % 2 else None
+           for i, ref in enumerate(alone)]
+    srv = _ahead_srv(model, params, window=4)
+    try:
+        assert srv._pool.stats()["dtype"] == "int8"
+        reqs = [srv.submit(p, max_new_tokens=n, eos_id=e)
+                for p, n, e in zip(prompts, budgets, eos)]
+        _drain(srv, *reqs)
+        assert [_served(r) for r in reqs] == \
+            [_cut(ref, e) if e is not None else ref
+             for ref, e in zip(alone, eos)]
+        assert srv.stats()["decode_steps_ahead"] > 0
+    finally:
+        srv.stop()
+
+
+def _behind_ahead_share_closed_loop(monkeypatch):
+    """A full window refilled from a queue as rows end, as a closed
+    loop offers it: nine steps in ten and more are dispatched while the
+    step before is unread; an admission does not drain."""
+    model, params = _toy()
+    prompts = _prompts(12, seed=23)
+    srv = _ahead_srv(model, params, max_new_tokens=24, max_queue=16)
+    try:
+        reqs = [srv.submit(p, max_new_tokens=12 + i)
+                for i, p in enumerate(prompts)]
+        _drain(srv, *reqs)
+        for r, p, in zip(reqs, prompts):
+            assert _served(r) == _reference(model, params, p, r.max_new)
+        st = srv.stats()
+        assert st["admitted"] == 12 and st["decode_drains"] == {}
+        assert st["decode_steps_ahead"] / st["decode_steps"] > 0.9
+        assert st["decode_steps_ahead"] == st["decode_steps"] - 1
+    finally:
+        srv.stop()
+
+
+def _behind_every_step_drains(monkeypatch):
+    """Two weight generations alive from the second tick to the last:
+    every step is read before the next is planned, none runs ahead, and
+    the tokens are the same."""
+    model, params_a = _toy(seed=3)
+    params_b = model.init_params(seed=99)
+    pa, pb = _prompts(2, seed=29)
+    srv = _ahead_srv(model, params_a)
+    try:
+        old = srv.submit(pa, max_new_tokens=10)
+        srv._tick()
+        srv.swap_weights(params_b)
+        new = srv.submit(pb, max_new_tokens=9)
+        _drain(srv, old, new)
+        assert _served(old) == _reference(model, params_a, pa, 10)
+        assert _served(new) == _reference(model, params_b, pb, 9)
+        st = srv.stats()
+        assert st["decode_steps_ahead"] == 0 and st["decode_steps"] >= 16
+        assert sum(st["decode_drains"].values()) == st["decode_steps"]
+    finally:
+        srv.stop()
+
+
+def _behind_prefix_insert_sees_no_stale_write(monkeypatch):
+    """A row that ends by ``eos_id`` has one step too many in flight
+    when ``_finish`` registers its run with the prefix index: of every
+    step dispatched and not yet read at that moment, no row write may
+    land in a page the index publishes. A later prompt that continues
+    the conversation on those pages is served the reference's tokens."""
+    model, params = _toy()
+    srv = _ahead_srv(model, params, prefix_cache=True)
+    pool, S = srv._pool, 8
+    writes, published, finishing = [], [], []
+    prog, insert, finish = srv._decode_prog, pool.prefix_insert, srv._finish
+
+    def spying_prog(tree, tokens, positions, pts, *rest):
+        rows = np.flatnonzero(pts[:, 0])
+        writes.append({int(pts[i, positions[i] // S]) for i in rows})
+        return prog(tree, tokens, positions, pts, *rest)
+
+    def spying_insert(ns, run, pages):
+        # (a prefill's own insert publishes pages it has just written
+        # whole, after any stale write in the device's order)
+        if finishing:
+            unread = writes[srv.stats()["decode_steps"]:]
+            full = set(pages[:len(run) // S])
+            published.append((full, len(unread)))
+            assert not any(full & w for w in unread), (full, unread)
+        return insert(ns, run, pages)
+
+    def spying_finish(*args, **kwargs):
+        finishing.append(1)
+        try:
+            return finish(*args, **kwargs)
+        finally:
+            finishing.pop()
+
+    srv._decode_prog = spying_prog
+    pool.prefix_insert = spying_insert
+    srv._finish = spying_finish
+    try:
+        prompts = [np.arange(1 + i, 14 + i, dtype=np.int32)
+                   for i in range(4)]
+        refs = [_reference(model, params, p, 24) for p in prompts]
+        # ends that put the stale write first in a page, last, inside
+        ends = [3, 10, 11, 14]                # 13 + g - 1 = 15, 22, 23, 26
+        eos = [ref[g - 1] for ref, g in zip(refs, ends)]
+        reqs = [srv.submit(p, max_new_tokens=24, eos_id=e)
+                for p, e in zip(prompts, eos)]
+        _drain(srv, *reqs)
+        want = [_cut(ref, e) for ref, e in zip(refs, eos)]
+        assert [_served(r) for r in reqs] == want
+        # every request's finish published pages, with a step unread
+        finishes = [p for p in published if p[0]]
+        assert len(finishes) >= 4 and any(n for _, n in finishes)
+        # the conversation goes on: prompt + answer + a new turn
+        for p, w in zip(prompts, want):
+            cont = np.concatenate([p, w, [5, 6, 7]]).astype(np.int32)
+            if len(cont) > 32:
+                continue
+            r = srv.submit(cont, max_new_tokens=6)
+            _drain(srv, r)
+            assert r.prefix_cached >= 8
+            assert _served(r) == _reference(model, params, cont, 6)
+    finally:
+        srv.stop()
+
+
+def _behind_step_raises(where, monkeypatch):
+    """A dispatch or a read-back that raises fails the rows of THAT
+    step, after the step before has handed out what it computed; the
+    server goes on serving."""
+    model, params = _toy()
+    prompt = _prompts(1, seed=31)[0]
+    ref = _reference(model, params, prompt, 12)
+    srv = _ahead_srv(model, params)
+    prog = srv._decode_prog
+    calls = []
+
+    def failing(*args):
+        calls.append(1)
+        if len(calls) == 4:
+            raise RuntimeError("planned dispatch failure")
+        return prog(*args)
+
+    class _Numpy:
+        """numpy, but the fourth device array read back raises."""
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kwargs):
+            import jax
+            if isinstance(a, jax.Array):
+                calls.append(1)
+                if len(calls) == 4:
+                    raise RuntimeError("planned read-back failure")
+            return np.asarray(a, *args, **kwargs)
+
+    if where == "dispatch":
+        srv._decode_prog = failing
+    else:
+        from mxnet_tpu.serving import decode as decode_mod
+        monkeypatch.setattr(decode_mod, "_np", _Numpy())
+    try:
+        req = srv.submit(prompt, max_new_tokens=12)
+        _drain(srv, req)
+        with pytest.raises(RuntimeError, match="planned"):
+            req.result(timeout=1)
+        got = _served(req)
+        # the prefill's token and the three steps before the failed
+        # one; the step already dispatched behind a failed read-back
+        # is read at once and its output dropped
+        assert got == ref[:4] and srv._unread is None
+        st = srv.stats()
+        assert st["errors"] == 1 and st["decode_drains"] == {"error": 1}
+        assert srv._pool.stats()["used"] == 0
+        after = srv.submit(prompt, max_new_tokens=12)
+        _drain(srv, after)
+        assert _served(after) == ref
+    finally:
+        srv.stop()
+
+
+_BEHIND = {
+    "count_and_eos": _behind_count_and_eos,
+    "cancel_unread": functools.partial(_behind_row_ends_unread, "cancel"),
+    "deadline_unread": functools.partial(_behind_row_ends_unread,
+                                         "deadline"),
+    "preempt_unread": functools.partial(_behind_row_ends_unread,
+                                        "preempt"),
+    "prefix_suffix_feed": _behind_prefix_suffix_feed,
+    "cow_split": functools.partial(_behind_cow, False),
+    "cow_degraded": functools.partial(_behind_cow, True),
+    "weight_swap": _behind_weight_swap,
+    "two_servers_one_pool": _behind_two_servers_one_pool,
+    "int8_pool": _behind_int8_pool,
+    "ahead_share_closed_loop": _behind_ahead_share_closed_loop,
+    "every_step_drains": _behind_every_step_drains,
+    "prefix_insert_no_stale_write":
+        _behind_prefix_insert_sees_no_stale_write,
+    "dispatch_raises": functools.partial(_behind_step_raises, "dispatch"),
+    "readback_raises": functools.partial(_behind_step_raises, "readback"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BEHIND))
+def test_one_step_behind_serves_the_reference_tokens(case, monkeypatch):
+    _BEHIND[case](monkeypatch)

@@ -625,8 +625,8 @@ def _old_cow_fn_q8(k_pages, v_pages, k_scales, v_scales, src, dst):
 @pytest.mark.parametrize("use_pallas", [False, True],
                          ids=["jnp", "pallas"])
 def test_int8_server_runs_the_programs_its_twins_ran(use_pallas):
-    """The one ``_prefill_fn`` / ``_decode_fn`` / ``_cow_fn`` over the
-    int8 layout trace to the jaxprs of the deleted ``*_q8`` twins: an
+    """The one ``_prefill_fn`` / ``_step_fn`` (the body of the
+    ``_decode_fn`` program) / ``_cow_fn`` over the int8 layout trace to the jaxprs of the deleted ``*_q8`` twins: an
     int8 server runs the programs it ran."""
     import jax
     import jax.numpy as jnp
@@ -639,7 +639,7 @@ def test_int8_server_runs_the_programs_its_twins_ran(use_pallas):
     step_args = (params, jnp.zeros((3,), jnp.int32),
                  jnp.zeros((3,), jnp.int32), jnp.zeros((3, 6), jnp.int32),
                  *pools)
-    new = jax.make_jaxpr(functools.partial(DecodeServer._decode_fn,
+    new = jax.make_jaxpr(functools.partial(DecodeServer._step_fn,
                                            holder))(*step_args)
     old = jax.make_jaxpr(functools.partial(_old_decode_fn_q8,
                                            model))(*step_args)

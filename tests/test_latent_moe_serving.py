@@ -447,16 +447,27 @@ def test_toy_decoder_runs_the_programs_it_ran():
                          max_len=128)
     assert model.cache_arrays == (("k", (2, 8)), ("v", (2, 8)))
     params = model.init_params(seed=3)
-    holder = type("S", (), {"_model": model})()
+    holder = type("S", (), {"_model": model,
+                            "_step_fn": DecodeServer._step_fn})()
     pool = jnp.zeros((2, 24, 8, 2, 8), jnp.float32)
     step_args = (params, jnp.zeros((3,), jnp.int32),
                  jnp.zeros((3,), jnp.int32), jnp.zeros((3, 6), jnp.int32),
                  pool, pool)
-    new = jax.make_jaxpr(functools.partial(DecodeServer._decode_fn,
+    # the step's body; the program around it (``_decode_fn``, PR 30)
+    # only picks each row's input token: the step before's, where it
+    # lies on the device, or the host's
+    new = jax.make_jaxpr(functools.partial(DecodeServer._step_fn,
                                            holder))(*step_args)
     old = jax.make_jaxpr(functools.partial(_old_decode_fn,
                                            model))(*step_args)
     assert str(new) == str(old)
+    prev = jnp.asarray([7, 8, 9], jnp.int32)
+    fed = DecodeServer._decode_fn(
+        holder, params, jnp.asarray([1, 2, 3], jnp.int32), *step_args[2:4],
+        prev, jnp.asarray([2, -1, 0], jnp.int32), pool, pool)
+    same = DecodeServer._step_fn(
+        holder, params, jnp.asarray([9, 2, 7], jnp.int32), *step_args[2:])
+    assert all(bool((a == b).all()) for a, b in zip(fed, same))
     pre_args = (params, jnp.zeros((1, 16), jnp.int32), jnp.int32(5),
                 jnp.zeros((6,), jnp.int32), pool, pool)
     new = jax.make_jaxpr(functools.partial(DecodeServer._prefill_fn,
