@@ -35,7 +35,10 @@ The serving decode step does not use ``flash_decode``'s contiguous
 cache: :func:`_pallas_paged_decode` (behind
 ``serving.kvcache.paged_attention``, which holds its plain reference)
 reads the paged KV pool's pages where they lie, steered by the page
-table in SMEM, so the step copies no cache.
+table in SMEM, so the step copies no cache. :func:`_pallas_block_decode`
+(behind ``serving.kvcache.paged_block_attention``) is its sibling for a
+step that runs a block of query positions a row over fewer key/value
+than query heads: one MXU product a key/value head and live page.
 
 Per-row planes (logsumexp, rowsum(dO*O), segment ids, int8 scales)
 are rank-3 — ``(BH, T, 1)`` columns on the q side, ``(BH, 1, T)`` rows
@@ -1032,6 +1035,199 @@ def _pallas_latent_write(pages, page_idx, slot, new, interpret):
         name="mx_latent_write.b%d.l%d.s%d.d%d.%s" % (
             B, L, S, W, pages.dtype.name),
     )(page_idx, slot, new.reshape(L, B, 1, W), pages)
+
+
+# ---------------------------------------------------------------------------
+# block decode: a few query positions a row, grouped-query heads, over a
+# paged cache whose token row packs every key/value head
+# ---------------------------------------------------------------------------
+
+def _jnp_block_decode(q, kc, vc, k_new, v_new, lengths):
+    """The block-decode reference. ``q (B, Q, Hq, D)`` already scaled;
+    ``kc``/``vc (B, T, Hkv, D)`` the row's cache (position == index),
+    of which the first ``lengths[b]`` keys are live; ``k_new``/``v_new
+    (B, Q, Hkv, D)`` the block's own keys and values, NOT in the cache,
+    every one visible to every query of the block. Query head ``i``
+    reads key/value head ``i // (Hq // Hkv)``. Float32 softmax over the
+    live keys and the block's own: ``(B, Q, Hq, D)`` float32."""
+    import jax.numpy as jnp
+    B, Q, Hq, D = q.shape
+    Hkv = kc.shape[2]
+    f32 = jnp.float32
+    qg = q.astype(f32).reshape(B, Q, Hkv, Hq // Hkv, D)
+    s_old = jnp.einsum("bqhgd,bthd->bhgqt", qg, kc.astype(f32))
+    live = jax.lax.iota(jnp.int32, kc.shape[1])[None, :] \
+        < jnp.asarray(lengths, jnp.int32)[:, None]
+    s_old = jnp.where(live[:, None, None, None, :], s_old, _NEG)
+    s_new = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_new.astype(f32))
+    s = jnp.concatenate([s_old, s_new], -1)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    T = kc.shape[1]
+    out = jnp.einsum("bhgqt,bthd->bqhgd", p[..., :T], vc.astype(f32)) \
+        + jnp.einsum("bhgqk,bkhd->bqhgd", p[..., T:], v_new.astype(f32))
+    return out.reshape(B, Q, Hq, D)
+
+
+def _block_decode_kernel(tbl_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref,
+                         v_ref, o_ref, acc_ref, m_ref, l_ref, *, page_size,
+                         n_pages, n_kv, head_dim, n_new):
+    """Grid = (rows, table columns), columns innermost: one program
+    instance attends ALL query positions and heads of one row to ONE
+    page, read where it lies. A token's row in the pool packs its
+    ``n_kv`` key (or value) heads side by side, ``n_kv * head_dim``
+    lanes, so key/value head ``h`` of the page is the lane-aligned
+    ``(S, head_dim)`` slice ``[:, h * head_dim:(h + 1) * head_dim]``,
+    and the ``Q * (Hq // Hkv)`` query vectors that read it — every
+    position of the block, every query head of the group — are the rows
+    of ONE ``(R, head_dim) x (head_dim, S)`` product on the MXU, the
+    weighted sum one ``(R, S) x (S, head_dim)``: operands in the pool's
+    dtype, float32 accumulation, float32 running softmax. (The per-head
+    paged kernel is VPU arithmetic for one query vector a head.)
+
+    ``len_ref[b]`` counts the row's keys IN THE POOL, all visible to
+    every query of the block; the block's own ``n_new`` keys and values
+    (``kn_ref``/``vn_ref``, not in the pool) open the accumulation, on
+    the VPU, each visible to every query. Columns at or past ``ceil(len
+    / S)`` name the last live page again (nothing is fetched) and
+    compute nothing."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _init():
+        for h in range(n_kv):
+            q = q_ref[h].astype(f32)                          # (R, D)
+            kn = kn_ref[h].astype(f32)                        # (n_new, D)
+            vn = vn_ref[h].astype(f32)
+            s = [jnp.sum(q * kn[i:i + 1], axis=-1, keepdims=True)
+                 for i in range(n_new)]                       # (R, 1) each
+            m = functools.reduce(jnp.maximum, s)
+            p = [jnp.exp(si - m) for si in s]
+            m_ref[h] = m
+            l_ref[h] = functools.reduce(jnp.add, p)
+            acc_ref[h] = functools.reduce(
+                jnp.add, [pi * vn[i:i + 1] for i, pi in enumerate(p)])
+
+    @pl.when(j * page_size < length)
+    def _step():
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        for h in range(n_kv):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            k = k_ref[:, lanes]                               # (S, D)
+            v = v_ref[:, lanes]
+            s = _dot(q_ref[h], k, _NT)                        # (R, S)
+            s = jnp.where(pos < length, s, _NEG)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + _dot(p.astype(v.dtype), v)
+
+    @pl.when(j == n_pages - 1)
+    def _finish():
+        # the block's own keys are always live, so l > 0
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _pallas_block_decode(q, k_new, v_new, k_pages, v_pages, layer,
+                         page_table, lengths, interpret):
+    """``q (B, Hkv, R, D)`` scaled, in the pool's dtype, ``R`` = query
+    positions x query heads a group; ``k_new``/``v_new (B, Hkv, Q, D)``;
+    the WHOLE pools ``(L, P, S, Hkv * D)`` (``layer`` is picked in the
+    index map); ``page_table (B, M)`` and ``lengths (B,)`` ride scalar
+    prefetch. Returns ``(B, Hkv, R, D)`` float32."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Hkv, R, D = q.shape
+    Q = k_new.shape[2]
+    S = k_pages.shape[2]
+    M = page_table.shape[1]
+    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
+    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
+    page_table = jnp.take_along_axis(page_table, columns, axis=1)
+
+    row = pl.BlockSpec((None, Hkv, R, D), lambda b, j, tbl, lens: (b, 0, 0, 0))
+    new = pl.BlockSpec((None, Hkv, Q, D), lambda b, j, tbl, lens: (b, 0, 0, 0))
+    page = pl.BlockSpec((None, None, S, Hkv * D),
+                        lambda b, j, tbl, lens: (layer, tbl[b * M + j], 0, 0))
+    return pl.pallas_call(
+        functools.partial(_block_decode_kernel, page_size=S, n_pages=M,
+                          n_kv=Hkv, head_dim=D, n_new=Q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, M),
+            in_specs=[row, new, new, page, page],
+            out_specs=row,
+            scratch_shapes=[pltpu.VMEM((Hkv, R, D), jnp.float32),
+                            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, R, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), jnp.float32),
+        interpret=interpret,
+        # rows x query heads, the block's query positions, the table's
+        # full width in keys, the head size, then the key/value heads:
+        # what kernel_costs.shapes and a reader of the cost file take
+        # from the event's name
+        name="mx_block_decode.bh%d.q%d.k%d.d%d.%s.kv%d.paged" % (
+            B * Hkv * R // Q, Q, M * S, D,
+            jnp.dtype(k_pages.dtype).name, Hkv),
+    )(page_table.reshape(-1), lengths, q, k_new, v_new, k_pages, v_pages)
+
+
+def _block_write_kernel(pg_ref, slot_ref, new_ref, page_ref, out_ref, *,
+                        n_new):
+    """One program instance puts one row's ``n_new`` new token rows of
+    one layer into their page: the page comes in whole, leaves whole,
+    and differs in rows ``slot .. slot + n_new - 1`` (selects over the
+    block, so no store at a dynamic offset into packed 16-bit rows)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    del pg_ref
+    slot = slot_ref[pl.program_id(0)]
+    rows = jax.lax.broadcasted_iota(jnp.int32, page_ref.shape, 0)
+    out = page_ref[...]
+    for i in range(n_new):
+        out = jnp.where(rows == slot + i, new_ref[i:i + 1, :], out)
+    out_ref[...] = out
+
+
+def _pallas_block_write(pages, page_idx, slot, new, interpret):
+    """A block's new token rows ``new (L, B, Q, W)`` into the pool ``(L,
+    P, S, W)``, in place (the pool is aliased to the result): row ``b``'s
+    ``Q`` rows land in page ``page_idx[b]`` from ``slot[b]`` on, in every
+    layer — ``mx_latent_write`` for more than one row a row, and for the
+    same reason (XLA's own row writes into a 4-D pool copy it whole)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    L, P, S, W = pages.shape
+    B, Q = new.shape[1], new.shape[2]
+    page = pl.BlockSpec((None, None, S, W),
+                        lambda b, l, pg, sl: (l, pg[b], 0, 0))
+    return pl.pallas_call(
+        functools.partial(_block_write_kernel, n_new=Q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, L),
+            in_specs=[pl.BlockSpec((None, None, Q, W),
+                                   lambda b, l, pg, sl: (l, b, 0, 0)),
+                      page],
+            out_specs=page),
+        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        name="mx_block_write.b%d.q%d.l%d.s%d.d%d.%s" % (
+            B, Q, L, S, W, pages.dtype.name),
+    )(page_idx, slot, new, pages)
 
 
 def flash_decode(q, k, v, lengths, scale=None, block_k=128,

@@ -162,6 +162,27 @@ def route_grouped_sigmoid(x, w_gate, bias, *, n_group, topk_group, top_k,
         return topi.astype(jnp.int32), topw
 
 
+def route_softmax_topk(x, w_gate, *, top_k, renormalize=True):
+    """Softmax router with top-k choice, in float32 at "highest" (as
+    :func:`route_grouped_sigmoid`, and for the same reason). ``x (T,
+    D)``; ``w_gate (D, E)``. ``p = softmax(x @ w_gate)`` over all ``E``
+    experts; the ``top_k`` largest are chosen; with ``renormalize`` the
+    weights are the chosen experts' ``p`` over their sum, otherwise
+    their ``p`` as it is. Returns ``(topi (T, top_k) int32, topw (T,
+    top_k) float32)``; ties go to the lower index (``jax.lax.top_k``)."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("mx_moe_route"):
+        with jax.default_matmul_precision("highest"):
+            logits = jnp.dot(x.astype(jnp.float32),
+                             w_gate.astype(jnp.float32))
+        p = jax.nn.softmax(logits, axis=-1)
+        topw, topi = jax.lax.top_k(p, top_k)
+        if renormalize:
+            topw = topw / topw.sum(-1, keepdims=True)
+        return topi.astype(jnp.int32), topw
+
+
 def expert_load(topi, held):
     """Tokens each HELD expert was sent: ``(hi - lo,)`` int32 from the
     router's choice ``topi (T, k)`` — the step's own count, which the

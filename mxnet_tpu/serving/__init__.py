@@ -29,6 +29,12 @@ KV cache, streaming, priorities, live weight swap) lives in
         for tok in req.tokens():                     # streams live
             ...
 
+Three models implement the decode-model contract: ``ToyDecoderLM``
+(per-head K/V, one position a step), ``latent_moe.LatentMoEDecoderLM``
+(a latent cache, routed experts) and ``block_diffusion.
+BlockDiffusionMoEDecoderLM`` (the BLOCK form of the contract: generation
+by diffusion over blocks, grouped-query K/V, softmax-routed experts).
+
 Fleet serving — a :class:`Router` fronting N decode replicas with
 per-tenant weighted-fair quotas, graceful drain, and transparent
 session failover on replica loss (:mod:`mxnet_tpu.serving.router` /

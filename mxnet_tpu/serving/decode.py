@@ -122,6 +122,52 @@ layout carries:
   keeps the largest, the others add up).
 - ``model.n_layers`` sizes the pool with the declaration.
 
+**The block form of the contract** (``serving.block_diffusion.
+BlockDiffusionMoEDecoderLM``): a model that generates by diffusion over
+blocks declares ``block_length`` and, in ``decode``'s place,
+
+- ``model.decode_block(params, tokens, positions, attend) -> (logits,
+  *new[, counters])`` — ``tokens (B, Q)`` a block a row at positions
+  ``positions[b] ..``; ``attend(layer, q (B, Q, Hq, D), k_new, v_new
+  (B, Q, Hkv, D), scale=, force_pallas=)`` attends the row's
+  ``positions[b]`` committed keys and the block's own, every one
+  visible to every query of the block, over fewer key/value than query
+  heads (``kvcache.paged_block_attention``); ``logits (B, Q, V)``; one
+  ``new`` a declared array, ``(n_layers, B, Q, *trailing)``;
+- ``model.unmask(logits, tokens, masked) -> (tokens, masked)``, the
+  model's own unmasking rule by its own settings, and
+  ``model.mask_token_id``; ``model.prefill`` runs under the block-causal
+  mask.
+
+The server's ONE step program (``_block_decode_fn``) then runs, for
+every row, one pass over its block: a row with something masked
+DENOISES (the rule unmasks positions in the program; nothing is
+written), a row with nothing masked COMMITS (its keys and values are
+written, ``Q`` rows a row). The row's block — tokens, what is masked —
+lives on the device from step to step and is fed from the unread
+step's output, because under a rule that decides on the device how many
+positions a pass unmasks the host does not know how a step ended when
+it dispatches the next; only the block's tokens and two flags a row
+leave the device. A block's tokens are pushed together, in order, when
+the denoising pass that settled its last position is read back (one
+step before the commit, which emits nothing); ``max_new_tokens`` and
+``eos_id`` cut the last block, whose commit is never run. The prefill
+commits the whole blocks of the prompt and emits no token — it is
+dispatched and not waited for; the prompt's remainder opens the first
+block of the answer as positions already unmasked. ``stats()["block"]``
+sums the passes (``denoise_passes``, ``commit_passes``,
+``tokens_unmasked``, ``blocks_committed``, ``max_passes_a_block``),
+``DecodeRequest.unmask_pass`` keeps for each token the pass of its block
+that unmasked it, ``mx:decode.dispatch`` says how many rows the host
+knows to be ``committing`` / ``denoising`` (``undecided``: the program
+decides from the unread step's output) and ``keys_live``,
+``mx:decode.readback`` the step's ``tokens_unmasked`` and
+``blocks_committed``. Weight swaps, cancellation, deadlines, priorities
+and preemption work as for any model (a block in the making is never
+cached, so a row ended in mid-block leaves nothing behind); prefix
+sharing (its suffix feed is one token a step), an int8 pool and a
+latent pool are refused with a typed error when the server is built.
+
 Sampling is greedy (argmax, in-program): deterministic by
 construction, which is what makes "prefill + stepwise cached decode
 reproduces the full-sequence forward token-for-token" a testable
@@ -171,7 +217,7 @@ class _Step:
     token (a mid-suffix feed's output is discarded), the device token
     array, and what ``stats()`` counts when it is read back."""
 
-    __slots__ = ("rows", "emits", "toks", "pages_live", "ahead")
+    __slots__ = ("rows", "emits", "toks", "pages_live", "ahead", "slots")
 
     def __init__(self, rows, emits, toks, pages_live, ahead):
         self.rows = rows
@@ -179,6 +225,8 @@ class _Step:
         self.toks = toks
         self.pages_live = pages_live
         self.ahead = ahead
+        # {id(row): its slot} in this step's output
+        self.slots = {id(r): i for i, r in enumerate(rows)}
 
 
 class DecodeRequest:
@@ -194,7 +242,8 @@ class DecodeRequest:
                  "params", "state", "_cancelled", "_stream", "_event",
                  "_error", "_last_emit", "trace_args",
                  "_t_trace", "pending", "pending_pos", "prefix_cached",
-                 "unread")
+                 "unread", "blk_start", "blk_x", "blk_masked",
+                 "blk_when", "blk_pass", "unmask_pass")
 
     def __init__(self, prompt, max_new, priority, deadline, eos_id,
                  request_id):
@@ -229,6 +278,16 @@ class DecodeRequest:
         # computed and the scheduler has not read back yet: what it
         # plans from is ``len(generated) + unread``
         self.unread = 0
+        # a block model's row (``DecodeServer``'s docstring), as of the
+        # last step READ BACK: where the block it is working on starts,
+        # the block's tokens, which of them are still masked, the pass
+        # in which each was unmasked (-1: a prompt token) and the
+        # denoising passes the block has had; ``unmask_pass[i]`` is the
+        # pass of its block (0 the first) that unmasked ``generated[i]``
+        self.blk_start = 0
+        self.blk_x = self.blk_masked = self.blk_when = None
+        self.blk_pass = 0
+        self.unmask_pass = []
 
     def done(self):
         return self._event.is_set()
@@ -448,7 +507,10 @@ class DecodeServer:
                  start=True):
         import jax
         from .. import compile_watch
-        for attr in ("prefill", "decode", "n_layers"):
+        self._block = int(getattr(model, "block_length", 0) or 0)
+        for attr in ("prefill", "n_layers") + (
+                ("decode_block", "unmask", "mask_token_id") if self._block
+                else ("decode",)):
             if not hasattr(model, attr):
                 raise MXNetError(
                     "DecodeServer: model lacks %r — the decode-model "
@@ -457,7 +519,10 @@ class DecodeServer:
                     "((name, trailing shape[, dtype]), ...) (see "
                     "serving.decode.ToyDecoderLM, which declares "
                     "per-head K and V, and serving.latent_moe, which "
-                    "declares one latent array)" % attr)
+                    "declares one latent array; a model with a "
+                    "block_length has decode_block, unmask and "
+                    "mask_token_id in decode's place: "
+                    "serving.block_diffusion)" % attr)
         specs, cache_dtype = kvcache.declared_arrays(model)
         self._counters = getattr(model, "step_counters", None)
         self._model = model
@@ -507,6 +572,8 @@ class DecodeServer:
             else envs.get_bool("MXNET_KV_PREFIX_CACHE")
         self._share_group = share_group
         self._preempt_asks = 0    # co-tenant give-back requests pending
+        if self._block:
+            self._check_block_model()
         # prompt rungs fill whole pages; the table width covers the
         # longest prompt plus the full generation budget, so any
         # admitted request fits its table by construction
@@ -551,15 +618,23 @@ class DecodeServer:
             donate = {"donate_argnums": tuple(range(4, 4 + n_pool))}
             # the step's pools come after the fed-back token array and
             # its slots, neither of which it may consume
-            step_donate = {"donate_argnums": tuple(range(6, 6 + n_pool))}
+            # (a block step's after its block state, one array more)
+            first = 7 if self._block else 6
+            step_donate = {"donate_argnums": tuple(range(first,
+                                                         first + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
+        # ONE step program and one prefill program a rung, whatever the
+        # kind of model: a block model's are the block forms
         self._decode_prog = compile_watch.jit(
-            self._decode_fn, "%s:step" % site,
+            self._block_decode_fn if self._block else self._decode_fn,
+            "%s:step" % site,
             statics=(site, self._window, self._max_pages), **step_donate)
         self._prefill_progs = {}
         for rung in self._seq_ladder.buckets:
             self._prefill_progs[rung] = compile_watch.jit(
-                self._prefill_fn, "%s:prefill:s%d" % (site, rung),
+                self._block_prefill_fn if self._block
+                else self._prefill_fn,
+                "%s:prefill:s%d" % (site, rung),
                 statics=(site, "prefill", rung), **donate)
         # the copy-on-write page copy: one more fixed program, only
         # ever compiled when the prefix cache is on (warmup covers it)
@@ -587,6 +662,10 @@ class DecodeServer:
                        "decode_pages_table": 0}
         self._shed_by_priority = {}
         self._counted = {}        # the model's step counters, summed
+        # a block model's passes, summed over the rows of every step read
+        self._blocks = {"denoise_passes": 0, "commit_passes": 0,
+                        "tokens_unmasked": 0, "blocks_committed": 0,
+                        "max_passes_a_block": 0}
         # the decode step that is dispatched and not yet read back (the
         # scheduler reads one step behind), why it was read before the
         # next was planned whenever it was (``stats()["decode_drains"]``),
@@ -597,7 +676,8 @@ class DecodeServer:
         self._drain_ask = None
         n_counts = len(self._counters[1]) if self._counters else 0
         self._no_prev = jax.device_put(
-            _np.zeros((self._window + n_counts,), _np.int32),
+            _np.zeros((self._window * (self._block + 2 if self._block
+                                       else 1) + n_counts,), _np.int32),
             self._device)
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
         self._intervals = deque(maxlen=ring)    # inter-token ms
@@ -670,6 +750,87 @@ class DecodeServer:
             tokens_out = jnp.concatenate(
                 [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
         return (tokens_out, *pools)
+
+    # -- the block forms (a model with ``block_length``) ------------------
+    def _check_block_model(self):
+        """What a block model cannot do is refused here, when the server
+        is built, with a typed error — never a wrong token later."""
+        B, S = self._block, self._pool.page_size
+        if S % B:
+            raise MXNetError(
+                "DecodeServer: page_size %d is no multiple of the model's "
+                "block_length %d — a block's rows are written into ONE "
+                "page" % (S, B))
+        if not self._pool.layout.blocks:
+            raise MXNetError(
+                "DecodeServer: a block model (block_length %d) cannot "
+                "run over a %s pool: a commit writes %d rows a row, which "
+                "an int8 page would have to requantize, and there is no "
+                "block form of latent attention — give it a float "
+                "per-head pool" % (B, type(self._pool.layout).__name__, B))
+        if self._prefix_on:
+            raise MXNetError(
+                "DecodeServer: prefix sharing feeds a prompt's un-cached "
+                "suffix through the step ONE token at a time, which a "
+                "block model (block_length %d) has no step for — build "
+                "it with prefix_cache=False" % B)
+
+    def _block_prefill_fn(self, params, tokens, n_valid, page_table,
+                          *pools):
+        """A block model's prefill: the whole blocks of the prompt
+        (``n_valid`` tokens, a multiple of the block length) in one
+        block-causal pass, their keys and values written. It emits NO
+        token — the first block of the answer is denoised like every
+        other — so only the pools come back."""
+        layout = kvcache.layout_for(self._model, pools)
+        _logits, *seqs = self._model.prefill(params, tokens)
+        return layout.write_prefill(pools, page_table, seqs, n_valid)
+
+    def _block_decode_fn(self, params, x, state, positions, page_tables,
+                         prev, src, *pools):
+        """The block-step program: every row runs ONE pass over its
+        block of ``block_length`` positions, and the row's state says
+        which kind. ``x (D, B)`` are the block's tokens as they stand,
+        ``state (D,)`` the bits of the positions still masked (-1: no
+        row); a row whose state the host does not know yet — the step
+        before it, still unread, was a denoising pass under a rule that
+        decides on the device how many positions it unmasks — takes both
+        from that step's output where it lies, ``prev`` at slot
+        ``src[i]``. A row with something masked DENOISES: the model's
+        own rule (``model.unmask``) unmasks positions from the pass's
+        logits and nothing is written (its rows go to the dump page). A
+        row with nothing masked COMMITS: the block's keys and values are
+        written at its positions and its tokens stay. Out, a row: the
+        block's tokens, the bits still masked, the kind of pass (1
+        denoised, 2 committed, 0 no row); then the model's counters."""
+        import jax.numpy as jnp
+        D, B = self._window, self._block
+        fed = prev[:D * (B + 2)].reshape(D, B + 2)[jnp.maximum(src, 0)]
+        x = jnp.where((src >= 0)[:, None], fed[:, :B], x)
+        state = jnp.where(src >= 0, fed[:, B], state)
+        bits = jnp.maximum(state, 0)
+        masked = ((bits[:, None] >> jnp.arange(B, dtype=jnp.int32)) & 1) \
+            .astype(bool)
+        commit = state == 0
+        layout = kvcache.layout_for(self._model, pools)
+        attend = layout.attend_block(pools, page_tables, positions)
+        logits, *new = self._model.decode_block(
+            params, x, positions, attend)
+        pools = layout.write_block(
+            pools, page_tables, positions, new[:len(layout.specs)],
+            commit, getattr(self._model, "use_pallas", False))
+        x_new, still = self._model.unmask(logits, x, masked)
+        left = jnp.sum(still.astype(jnp.int32)
+                       << jnp.arange(B, dtype=jnp.int32), axis=1)
+        kind = jnp.where(state < 0, 0, jnp.where(commit, 2, 1))
+        out = jnp.concatenate(
+            [x_new.astype(jnp.int32),
+             jnp.where(state < 0, -1, left)[:, None],
+             kind[:, None]], axis=1).reshape(-1)
+        if len(new) > len(layout.specs):
+            out = jnp.concatenate(
+                [out, new[-1].astype(jnp.int32).reshape(-1)])
+        return (out, *pools)
 
     # copy-on-write page copy — the whole split is one traced program
     # (src/dst ride as traced scalars, so any page pair reuses it).
@@ -814,17 +975,23 @@ class DecodeServer:
                     jax.block_until_ready(out[0])
                     self._adopt_pool(out)
                     n += 1
-                toks = _np.zeros((self._window,), _np.int32)
                 pos = _np.zeros((self._window,), _np.int32)
                 pts = _np.zeros((self._window, self._max_pages),
                                 _np.int32)
                 src = _np.full((self._window,), -1, _np.int32)
+                if self._block:
+                    # no rows (state -1): every write goes to the dump page
+                    feed = (_np.zeros((self._window, self._block),
+                                      _np.int32),
+                            _np.full((self._window,), -1, _np.int32), pos)
+                else:
+                    feed = (_np.zeros((self._window,), _np.int32), pos)
                 # twice: fed nothing, then fed its own token array, the
                 # two kinds of ``prev`` a live step is handed
                 prev = self._no_prev
                 for _ in range(2):
                     out = self._decode_prog(
-                        self._params.tree, toks, pos, pts, prev, src,
+                        self._params.tree, *feed, pts, prev, src,
                         *self._pool.arrays)
                     jax.block_until_ready(out[0])
                     prev = self._adopt_pool(out)[0]
@@ -1244,7 +1411,11 @@ class DecodeServer:
                     cached * self._pool.token_bytes)
         sp.set(rung=rung, cached=cached,
                queue_wait_us=round((sp.t0 - req.t_submit) * 1e6, 1))
-        need = self._pool.pages_for(P + 1) - len(shared)
+        # a block model's first block of the answer starts inside the
+        # prompt's last (partial) block and is written whole
+        first = P // self._block * self._block if self._block else P
+        need = self._pool.pages_for(
+            max(P + 1, first + self._block)) - len(shared)
         pages = self._pool.alloc(need, owner=self._owner)
         while pages is None:
             victim = self._pick_victim(below=req.priority)
@@ -1297,10 +1468,9 @@ class DecodeServer:
         with tracing.span("decode.prefill", rung=rung) as pre:
             try:
                 with self._pool.step_lock:
-                    out = self._prefill_progs[rung](
-                        req.params.tree, tokens, _np.int32(P), pt,
-                        *self._pool.arrays)
-                    token = self._adopt_pool(out)[0]
+                    out = self._adopt_pool(self._prefill_progs[rung](
+                        req.params.tree, tokens, _np.int32(first), pt,
+                        *self._pool.arrays))
             except Exception as exc:   # noqa: BLE001 — model errors
                 self._retire([req], exc)   # belong to the request
                 return True
@@ -1314,6 +1484,17 @@ class DecodeServer:
                     metering.request_flops(
                         metering.inner_key(self, req.request_id),
                         cost["flops"], cost["bytes"])
+            if self._block:
+                # nothing to read: the prefill emits no token, so its
+                # dispatch is all the host does here (an error of the
+                # program surfaces where the next step is read back),
+                # and the answer's first block opens on the prompt's
+                # remainder, positions already unmasked
+                self._open_block(req, first, req.prompt[first:])
+                with self._cond:
+                    self._stats["prefill_steps"] += 1
+                    self._stats["prefill_s"] += tracing.now() - pre.t0
+                return True
             if self._prefix_on:
                 # the prefill just wrote K/V for every prompt position:
                 # register the full pages so the NEXT same-prefix
@@ -1321,7 +1502,7 @@ class DecodeServer:
                 # reference)
                 self._pool.prefix_insert(self._namespace(ver),
                                          req.prompt, req.pages)
-            tok = int(token)
+            tok = int(out[0])
         req._last_emit = pre.t1
         if req.trace_args is not None:
             rtid = tracing.track("req %s" % req.trace_args["request_id"])
@@ -1356,7 +1537,11 @@ class DecodeServer:
                 continue               # preempted earlier in this pass
             failed = False
             while True:
+                # (a block model: the last position of the block the
+                # next step works on, which it may commit)
                 wp = r.pending_pos if r.pending \
+                    else self._next_block(r)[0] + self._block - 1 \
+                    if self._block \
                     else len(r.prompt) + len(r.generated) + r.unread - 1
                 needed = wp // self._pool.page_size + 1
                 while len(r.pages) < needed:
@@ -1461,7 +1646,8 @@ class DecodeServer:
         with the token of the unread step, which is known before that
         token is read. Such a row stays active until its step is read
         and is in no later step; its slot is free for an admission."""
-        return len(r.generated) + r.unread >= r.max_new
+        return not self._block \
+            and len(r.generated) + r.unread >= r.max_new
 
     def _decode_once(self):
         """Dispatch the next decode step over every row that needs
@@ -1509,13 +1695,15 @@ class DecodeServer:
     def _decode_group(self, ver, rows):
         D, M = self._window, self._max_pages
         prev = self._unread
+        if self._block:
+            return self._dispatch_step(
+                ver, rows, *self._build_block_step(rows, prev), prev)
         with tracing.span("decode.build"):
             tokens = _np.zeros((D,), _np.int32)
             positions = _np.zeros((D,), _np.int32)
             pts = _np.zeros((D, M), _np.int32)
             src = _np.full((D,), -1, _np.int32)
-            slots = {} if prev is None else \
-                {id(r): i for i, r in enumerate(prev.rows)}
+            slots = {} if prev is None else prev.slots
             emits = []
             for i, r in enumerate(rows):
                 if r.pending:
@@ -1545,12 +1733,21 @@ class DecodeServer:
             # reads pages where they lie has to stream
             pages_live = int((positions[:len(rows)]
                               // self._pool.page_size + 1).sum())
+        self._dispatch_step(ver, rows, emits, (tokens, positions, pts),
+                            src, pages_live, {}, prev)
+
+    def _dispatch_step(self, ver, rows, emits, feed, src, pages_live,
+                       said, prev):
+        """Dispatch the step that was built (``feed``: the host's arrays
+        in front of ``prev`` in the program's signature; ``said``: what
+        else the ``decode.dispatch`` span carries), then read back the
+        step before it."""
         try:
             with tracing.span("decode.dispatch", pages_live=pages_live,
-                              ahead=int(prev is not None)), \
+                              ahead=int(prev is not None), **said), \
                     self._pool.step_lock:
                 toks = self._adopt_pool(self._decode_prog(
-                    ver.tree, tokens, positions, pts,
+                    ver.tree, *feed,
                     self._no_prev if prev is None else prev.toks, src,
                     *self._pool.arrays))[0]
         except Exception as exc:       # noqa: BLE001 — model errors
@@ -1565,6 +1762,162 @@ class DecodeServer:
                              prev is not None)
         if prev is not None:
             self._read(prev)
+
+    # -- a block model's rows ----------------------------------------------
+    # The host keeps each row's block AS OF THE LAST STEP READ BACK
+    # (``DecodeRequest.blk_*``). The step being built runs while the step
+    # before it is unread, and what that step did follows from the same
+    # state: with nothing masked it COMMITS the block, so the next step
+    # opens a fresh block whose state the host knows whole; with
+    # something masked it DENOISES, and under a rule that decides on the
+    # device how many positions a pass unmasks the host does not know how
+    # it ends — the next step takes the row's state from that step's
+    # output on the device, and the program decides there whether it
+    # denoises again or commits. Positions and pages are always known.
+    def _open_block(self, r, start, held=()):
+        """Row ``r``'s state at the opening of the block at ``start``:
+        ``held`` (prompt tokens) already unmasked, the rest masked."""
+        B = self._block
+        n = len(held)
+        r.blk_start = int(start)
+        r.blk_x = [int(t) for t in held] \
+            + [self._model.mask_token_id] * (B - n)
+        r.blk_masked = [False] * n + [True] * (B - n)
+        r.blk_when = [-1] * n + [None] * (B - n)
+        r.blk_pass = 0
+
+    def _next_block(self, r):
+        """``(start, known)`` of the block the NEXT step runs for ``r``:
+        where it starts, and ``"state"`` / ``"fresh"`` when the host
+        knows the block's state (as last read / a newly opened block),
+        None when it lies in the unread step's output."""
+        prev = self._unread
+        if prev is None or id(r) not in prev.slots:
+            return r.blk_start, "state"
+        if not any(r.blk_masked):         # the unread step commits it
+            return r.blk_start + self._block, "fresh"
+        return r.blk_start, None
+
+    def _build_block_step(self, rows, prev):
+        """The host's arrays of one block step, what its dispatch span
+        says, and the slots fed from the unread step."""
+        D, M, B = self._window, self._max_pages, self._block
+        with tracing.span("decode.build"):
+            x = _np.zeros((D, B), _np.int32)
+            state = _np.full((D,), -1, _np.int32)
+            positions = _np.zeros((D,), _np.int32)
+            pts = _np.zeros((D, M), _np.int32)
+            src = _np.full((D,), -1, _np.int32)
+            slots = {} if prev is None else prev.slots
+            fresh = (1 << B) - 1
+            for i, r in enumerate(rows):
+                positions[i], known = self._next_block(r)
+                if known == "fresh":
+                    x[i], state[i] = self._model.mask_token_id, fresh
+                elif known:
+                    x[i] = r.blk_x
+                    state[i] = sum(m << j for j, m
+                                   in enumerate(r.blk_masked))
+                else:
+                    src[i], state[i] = slots[id(r)], fresh
+                pts[i, :len(r.pages)] = r.pages
+            n = len(rows)
+            pages_live = int(((positions[:n] + B - 1)
+                              // self._pool.page_size + 1).sum())
+            fed = int((src[:n] >= 0).sum())
+            committing = int((state[:n] == 0).sum())
+        # rows whose kind the program decides from the unread step's
+        # output are neither yet: ``undecided``
+        said = {"committing": committing,
+                "denoising": n - committing - fed, "undecided": fed,
+                # the committed keys the step's rows attend to, in all
+                "keys_live": int(positions[:n].sum())}
+        return [0] * n, (x, state, positions, pts), src, pages_live, said
+
+    def _read_block(self, step):
+        """:meth:`_read` for a block step: every row's block after the
+        pass. A denoising pass that leaves nothing masked SETTLES the
+        block: its tokens are final (an unmasked token is never masked
+        again) and are pushed together, in order, when that pass is read
+        back — one step before the commit that caches them, which emits
+        nothing. ``max_new`` and ``eos_id`` cut the last block; a
+        request ends when its last block settles, uncommitted."""
+        D, B = self._window, self._block
+        try:
+            with tracing.span("decode.readback") as back:
+                toks, step.toks = _np.asarray(step.toks), None
+                out = toks[:D * (B + 2)].reshape(D, B + 2)
+                live = [(i, r) for i, r in enumerate(step.rows)
+                        if r.state == "active"]
+                unmasked = [
+                    [j for j in range(B)
+                     if r.blk_masked[j] and not out[i, B] >> j & 1]
+                    if out[i, B + 1] == 1 else [] for i, r in live]
+                counts = {"tokens_unmasked": sum(map(len, unmasked)),
+                          "blocks_committed": sum(
+                              int(out[i, B + 1] == 2) for i, _r in live)}
+                model_counts = None
+                if self._counters is not None:
+                    model_counts = dict(zip(
+                        self._counters[1],
+                        (int(c) for c in toks[D * (B + 2):])))
+                back.set(**counts, **(model_counts or {}))
+        except Exception as exc:       # noqa: BLE001 — the step's error
+            self._retire(step.rows, exc)
+            self._read_unread("error")
+            return
+        now = back.t1
+        with tracing.span("decode.emit", rows=len(step.rows)) as emit:
+            self._bill_step(step)
+            pushed, finished = [], []
+            for (i, r), newly in zip(live, unmasked):
+                if out[i, B + 1] == 2:
+                    self._open_block(r, r.blk_start + B)
+                    continue
+                for j in newly:
+                    r.blk_when[j] = r.blk_pass
+                r.blk_x = [int(t) for t in out[i, :B]]
+                r.blk_masked = [bool(out[i, B] >> j & 1)
+                                for j in range(B)]
+                r.blk_pass += 1
+                if any(r.blk_masked):
+                    continue
+                for j in range(max(len(r.prompt) - r.blk_start, 0), B):
+                    r.generated.append(r.blk_x[j])
+                    r.unmask_pass.append(r.blk_when[j])
+                    r._push(r.blk_x[j])
+                    pushed.append(r)
+                    if len(r.generated) >= r.max_new or \
+                            (r.eos_id is not None
+                             and r.blk_x[j] == r.eos_id):
+                        finished.append(r)
+                        break
+            emit.set(emitted=len(pushed))
+            with self._cond:
+                st, bl = self._stats, self._blocks
+                st["decode_steps"] += 1
+                st["decode_steps_ahead"] += step.ahead
+                st["decode_pages_live"] += step.pages_live
+                st["decode_pages_table"] += D * self._max_pages
+                if model_counts is not None:
+                    self._count_step(model_counts)
+                bl["commit_passes"] += counts["blocks_committed"]
+                bl["denoise_passes"] += \
+                    len(live) - counts["blocks_committed"]
+                bl["tokens_unmasked"] += counts["tokens_unmasked"]
+                bl["blocks_committed"] += counts["blocks_committed"]
+                for r in pushed:
+                    st["tokens_out"] += 1
+                    if r._last_emit is not None:
+                        self._intervals.append((now - r._last_emit) * 1e3)
+                    else:
+                        self._ttft.append((now - r.t_submit) * 1e3)
+                    r._last_emit = now
+                    # the passes of the block that just settled, with
+                    # the commit that follows
+                    bl["max_passes_a_block"] = max(
+                        bl["max_passes_a_block"], r.blk_pass + 1)
+            self._retire(finished, None)
 
     def _retire(self, rows, error):
         """Take whichever of ``rows`` are still active off the active
@@ -1601,6 +1954,8 @@ class DecodeServer:
         index, so no published page ever holds one, and whatever is
         dispatched later into a freed page runs after it in the
         device's order."""
+        if self._block:
+            return self._read_block(step)
         D = self._window
         for r, emits in zip(step.rows, step.emits):
             r.unread -= emits
@@ -1629,20 +1984,7 @@ class DecodeServer:
                 (i, r) for i, (r, emits)
                 in enumerate(zip(step.rows, step.emits))
                 if emits and r.state == "active"]
-            if metering.enabled():
-                # the dispatched step program ran ONE batch: each
-                # request it still serves is billed an equal share of
-                # the program's cost_analysis FLOPs
-                cost = compile_watch.last_dispatch(
-                    "%s:step" % self._site)
-                live = [r for r in step.rows if r.state == "active"]
-                if cost is not None and live:
-                    share = 1.0 / len(live)
-                    for r in live:
-                        metering.request_flops(
-                            metering.inner_key(self, r.request_id),
-                            cost["flops"] * share,
-                            cost["bytes"] * share)
+            self._bill_step(step)
             emit.set(emitted=len(emitting))
             finished = []
             with self._cond:
@@ -1671,6 +2013,21 @@ class DecodeServer:
                         (r.eos_id is not None and tok == r.eos_id):
                     finished.append(r)
             self._retire(finished, None)
+
+    def _bill_step(self, step):
+        """The dispatched step program ran ONE batch: each request it
+        still serves is billed an equal share of the program's
+        cost_analysis FLOPs (while metering is on)."""
+        if not metering.enabled():
+            return
+        cost = compile_watch.last_dispatch("%s:step" % self._site)
+        live = [r for r in step.rows if r.state == "active"]
+        if cost is not None and live:
+            share = 1.0 / len(live)
+            for r in live:
+                metering.request_flops(
+                    metering.inner_key(self, r.request_id),
+                    cost["flops"] * share, cost["bytes"] * share)
 
     def _count_step(self, counts):
         """One decode step's model counters into the running totals
@@ -1714,6 +2071,7 @@ class DecodeServer:
             versions.add(id(self._params))
             shed_pri = dict(self._shed_by_priority)
             counted = dict(self._counted)
+            blocks = dict(self._blocks)
             drains = dict(self._drains)
         steps = s["prefill_steps"] + s["decode_steps"]
         out = {
@@ -1767,6 +2125,8 @@ class DecodeServer:
             }
         if self._counters is not None:
             out[self._counters[0]] = counted
+        if self._block:
+            out["block"] = blocks
         if shed_pri:
             out["shed_by_priority"] = {str(k): v for k, v
                                        in sorted(shed_pri.items())}

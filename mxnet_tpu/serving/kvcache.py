@@ -36,7 +36,13 @@ same two as int8 pages, with their per-page scales carried beside them;
 a latent-attention model declares ONE array ``(n_layers, n_pages,
 page_size, W)`` whose row is the compressed K/V and the shared rotary
 key (:func:`paged_latent_attention`, :func:`write_prefill_pages`,
-:func:`write_token_rows`). The layout alone knows which arrays a
+:func:`write_token_rows`); per-head K and V whose FEW heads would not
+fill a 16-bit dtype's sublane tile (grouped-query attention: 4
+key/value heads under 32 query heads) are packed into one row of ``H *
+D`` lanes a token, ``(n_layers, n_pages, page_size, H * D)``
+(:class:`_PackedHeadKV`, :func:`paged_block_attention`,
+:func:`write_block_rows` — which also serve a step that runs a BLOCK of
+query positions a row, over either per-head form). The layout alone knows which arrays a
 program carries, what ``attend`` a step hands its model and how a
 prefill's sequences and a step's new rows reach their pages; the
 server's three programs are written over it, once. The page accounting
@@ -120,7 +126,8 @@ from .. import envs, fault
 from ..base import MXNetError
 
 __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
-           "paged_attention", "paged_latent_attention", "cache_layout",
+           "paged_attention", "paged_latent_attention",
+           "paged_block_attention", "write_block_rows", "cache_layout",
            "declared_arrays", "layout_for",
            "scatter_token", "scatter_prefill", "write_prefill_pages",
            "write_token_rows",
@@ -355,6 +362,112 @@ def paged_latent_attention(kv_pages, page_table, positions, layer, q,
                      table, pos)
 
 
+def paged_block_attention(k_pages, v_pages, page_table, positions, layer,
+                          q, k_new, v_new, *, scale=None,
+                          force_pallas=False):
+    """:func:`paged_attention` for a step that runs a BLOCK of query
+    positions a row over grouped-query heads — what a block model's step
+    is handed as ``attend(layer, q, k_new, v_new)``. ``q (B, Q, Hq, D)``;
+    ``k_new``/``v_new (B, Q, Hkv, D)``, the block's own keys and values,
+    NOT in the pool (a block is written when it is final) and every one
+    visible to every query of the block; ``positions (B,)`` the block's
+    first position = the row's keys in the pool, all visible. Query head
+    ``i`` reads key/value head ``i // (Hq // Hkv)``. A ``q (B, Hq, D)``
+    with ``k_new``/``v_new (B, Hkv, D)`` is a block of one. Returns
+    ``q``'s shape in float32.
+
+    The pools are per-head ``(L, P, S, Hkv, D)`` or packed ``(L, P, S,
+    Hkv * D)`` (:class:`_PackedHeadKV`). Operands in the pool's dtype,
+    softmax and accumulation in float32. On the TPU a packed pool whose
+    head size and pages are multiples of 128 takes the Pallas kernel
+    ``mx_block_decode`` (one MXU product a key/value head and live page);
+    everything else :func:`gather_pages` + jnp, the kernel's test
+    reference. Counted as ``block_decode_pallas`` / ``block_decode_jnp``."""
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import (_dispatch, _jnp_block_decode,
+                                            _pallas_block_decode)
+    single = q.ndim == 3
+    if single:
+        q, k_new, v_new = q[:, None], k_new[:, None], v_new[:, None]
+    B, Q, Hq, D = q.shape
+    Hkv = k_new.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    pos = jnp.asarray(positions, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    q = (q * scale).astype(k_pages.dtype)
+    k_new = k_new.astype(k_pages.dtype)
+    v_new = v_new.astype(v_pages.dtype)
+    packed = k_pages.ndim == 4
+
+    def composed(q, k_new, v_new, k_pages, v_pages, table, pos):
+        one = slice(layer, layer + 1)
+        kc = gather_pages(k_pages[one], table)[0]
+        vc = gather_pages(v_pages[one], table)[0]
+        shape = kc.shape[:2] + (Hkv, D)
+        return _jnp_block_decode(q, kc.reshape(shape), vc.reshape(shape),
+                                 k_new, v_new, pos)
+
+    def kernel(interpret, q, k_new, v_new, k_pages, v_pages, table, pos):
+        G = Hq // Hkv
+        # (B, Q, Hkv, G, D) -> (B, Hkv, Q * G, D): the rows of one product
+        qr = jnp.transpose(q.reshape(B, Q, Hkv, G, D),
+                           (0, 2, 1, 3, 4)).reshape(B, Hkv, Q * G, D)
+        out = _pallas_block_decode(
+            qr, jnp.swapaxes(k_new, 1, 2), jnp.swapaxes(v_new, 1, 2),
+            k_pages, v_pages, layer, table, pos, interpret)
+        return jnp.transpose(out.reshape(B, Hkv, Q, G, D),
+                             (0, 2, 1, 3, 4)).reshape(B, Q, Hq, D)
+
+    # the kernel reads a packed pool only: hand the chooser a head size
+    # it refuses for the per-head form
+    out = _dispatch("block_decode", D if packed else 1,
+                    (k_pages.shape[2],), force_pallas and packed, kernel,
+                    composed, q, k_new, v_new, k_pages, v_pages, table, pos)
+    return out[:, 0] if single else out
+
+
+def write_block_rows(pages, page_table, positions, new, commit,
+                     force_pallas=False):
+    """A block step's new rows into the pool: ``new (L, B, Q, ...)``, row
+    ``b``'s ``Q`` token rows, land at positions ``positions[b] ..
+    positions[b] + Q - 1`` through its table row where ``commit[b]``,
+    and in the dump page where not (a denoising pass writes nothing: its
+    rows are not final). A block lies inside one page (the page size is a
+    multiple of the block length and blocks start at multiples of it).
+    In-place row writes as :func:`scatter_token`'s; on the TPU a packed
+    or latent pool ``(L, P, S, W)`` whose page tiles takes the Pallas
+    kernel ``mx_block_write`` (XLA's own row writes into a 4-D pool copy
+    it whole). Counted as ``block_write_pallas`` / ``block_write_jnp``."""
+    import jax
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import _dispatch, _pallas_block_write
+    S = pages.shape[2]
+    pos = jnp.asarray(positions, jnp.int32)
+    pidx = jnp.take_along_axis(
+        jnp.asarray(page_table, jnp.int32), (pos // S)[:, None],
+        axis=1)[:, 0]
+    pidx = jnp.where(commit, pidx, 0)
+    slot = pos % S
+    new = new.astype(pages.dtype).reshape(
+        new.shape[:3] + pages.shape[3:])
+
+    def composed(pages, pidx, slot, new):
+        def write_row(b, pages):
+            rows = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)
+            return jax.lax.dynamic_update_slice(
+                pages, rows,
+                (0, pidx[b], slot[b]) + (0,) * (pages.ndim - 3))
+        return jax.lax.fori_loop(0, new.shape[1], write_row, pages)
+
+    def kernel(interpret, pages, pidx, slot, new):
+        return _pallas_block_write(pages, pidx, slot, new, interpret)
+
+    flat = pages.ndim == 4
+    return _dispatch("block_write", pages.shape[-1] if flat else 1, (S,),
+                     force_pallas and flat, kernel, composed, pages, pidx,
+                     slot, new)
+
+
 # ---------------------------------------------------------------------------
 # quantized (int8 + per-page fp32 scale) variants — same traced shapes
 # ---------------------------------------------------------------------------
@@ -495,11 +608,66 @@ class _PerHeadKV:
         return tuple(scatter_token(pages, page_tables, positions, rows)
                      for pages, rows in zip(pools, new))
 
+    # a block model's step (``DecodeServer``'s contract for a model with
+    # ``block_length``): a few query positions a row, grouped-query heads
+    blocks = True
+
+    def attend_block(self, pools, page_tables, positions):
+        """The ``attend`` a block step hands its model."""
+        return functools.partial(paged_block_attention, *pools,
+                                 page_tables, positions)
+
+    def write_block(self, pools, page_tables, positions, new, commit,
+                    force_pallas=False):
+        """A block step's new rows ``(L, B, Q, ...)`` an array into their
+        page where ``commit``, into the dump page where not."""
+        return tuple(write_block_rows(pages, page_tables, positions, rows,
+                                      commit, force_pallas)
+                     for pages, rows in zip(pools, new))
+
+
+class _PackedHeadKV(_PerHeadKV):
+    """Per-head K and V whose heads do not fill the dtype's sublane tile
+    (4 heads of bfloat16 where a tile holds 16 rows): a token's heads are
+    packed side by side into ONE row of ``H * D`` lanes, two arrays ``(L,
+    P, S, H * D)``. Stored ``(..., S, 4, 128)`` a tiled array pads the 4
+    to 16 and takes four times its bytes in HBM (or is laid out
+    token-minor and copied whole around every kernel call, as a 576-wide
+    latent row was). The model's declaration stays ``(H, D)``; only this
+    object knows the row is packed. Every step, of one position or of a
+    block, attends through :func:`paged_block_attention` and writes in
+    place."""
+
+    def arrays(self, n_layers, n_pages, page_size):
+        lead = (n_layers, n_pages, page_size)
+        return tuple((name, lead + (math.prod(trailing),), self.dtype)
+                     for name, trailing in self.specs)
+
+    attend = _PerHeadKV.attend_block
+
+    def write_prefill(self, pools, page_table_row, seqs, n_valid):
+        return tuple(
+            write_prefill_pages(
+                pages, page_table_row,
+                seq[:, 0].reshape(seq.shape[0], seq.shape[2], -1), n_valid)
+            for pages, seq in zip(pools, seqs))
+
+    def write_tokens(self, pools, page_tables, positions, new,
+                     force_pallas=False):
+        import jax.numpy as jnp
+        every = jnp.ones(jnp.shape(positions), bool)
+        return self.write_block(
+            pools, page_tables, positions,
+            [rows[:, :, None] for rows in new[:len(pools)]], every,
+            force_pallas)
+
 
 class _Latent(_PerHeadKV):
     """One float array ``(L, P, S, W)``: a token's row is the compressed
     K/V and the shared rotary key. Written in place (page by page, row
     by row) so a 16-bit pool is never widened by a scatter."""
+
+    blocks = False      # no block form of latent attention in the tree
 
     def attend(self, pools, page_tables, positions):
         return functools.partial(paged_latent_attention, *pools,
@@ -524,6 +692,8 @@ class _PerHeadKVInt8(_PerHeadKV):
     carries them); the model's contract stays float ``q``/``k_new``/
     ``v_new`` — attention applies the scales page by page, the writes
     quantize."""
+
+    blocks = False      # a block's rows would requantize their page
 
     def arrays(self, n_layers, n_pages, page_size):
         pages = super().arrays(n_layers, n_pages, page_size)
@@ -590,7 +760,14 @@ def cache_layout(specs, dtype):
     ranks = tuple(len(trailing) for _name, trailing in specs)
     int8 = dtype == "int8"
     if ranks == (2, 2):
-        return (_PerHeadKVInt8 if int8 else _PerHeadKV)(specs, dtype)
+        if int8:
+            return _PerHeadKVInt8(specs, dtype)
+        heads, width = specs[0][1]
+        # heads that would not fill a sublane tile of a 16-bit dtype (16
+        # rows) under a head size of whole lanes: pack them into one row
+        packs = dtype.itemsize == 2 and heads % 16 and width % 128 == 0 \
+            and specs[0][1] == specs[1][1]
+        return (_PackedHeadKV if packs else _PerHeadKV)(specs, dtype)
     if int8:
         raise MXNetError(
             "KVCachePool: int8 pages with per-page scales exist for "

@@ -306,3 +306,94 @@ def test_latent_moe_programs_compile_and_fit(chip, monkeypatch):
     planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert planned < 12.5e9, mem
+
+
+def test_block_diffusion_programs_compile_and_fit(chip, monkeypatch):
+    """``benchmark/configs/SDAR-30B-A3B-Chat.json`` at its published
+    widths (2048 wide, 32 query heads over 4 key/value heads of 128, 128
+    experts of 768, top 8, 151,936 rows, 7 layers, window 32, 512 bf16
+    pages of 128 tokens): the ONE block-step program and the 256-rung
+    prefill compiled for one described v5e. In each: the Mosaic kernels
+    under the names a profile's reader looks for — the paged block-decode
+    kernel a layer and the in-place block write an array (step), the two
+    grouped matmuls of every expert layer a program needs (the prefill
+    drops the last layer's: nothing reads its output) — the planned
+    bytes inside the chip with room for the reference that decides
+    ``correct`` beside the weights, the donated pools updated in place
+    and NO copy of them among the temporaries. The pool packs a token's
+    four key heads into one 512-lane row: the arrays are ``(7, 512, 128,
+    512)`` and what the compiler hands the step is their logical bytes
+    (declared ``(..., 4, 128)`` a bf16 array gets the tile T(4,128)(2,1)
+    here, so nothing is padded to 16 sublanes either way; the packed row
+    is what gives the kernel a lane-aligned ``(S, 128)`` operand a
+    head)."""
+    from mxnet_tpu.serving import DecodeServer, kvcache
+    from mxnet_tpu.serving.block_diffusion import BlockDiffusionMoEDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "SDAR-30B-A3B-Chat.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    rung = max(srv["seq_ladder"])
+    M = -(-(rung + srv["max_new_tokens"]) // S)
+    model = BlockDiffusionMoEDecoderLM(**cfg["model"]["kwargs"])
+    assert model.held == (0, 128) and model.block_length == 4
+    L, Q = model.n_layers, model.block_length
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert 9.9e9 < weights < 10.05e9
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    specs, dtype = kvcache.declared_arrays(model)
+    layout = kvcache.cache_layout(specs, jnp.dtype(dtype))
+    shapes = [shape for _n, shape, _d in layout.arrays(L, pages, S)]
+    assert shapes == [(L, pages, S, 512)] * 2
+    pool = spec(shapes[0], jnp.bfloat16)
+    pool_bytes = 2 * int(np.prod(shapes[0])) * 2
+    assert pool_bytes == pages * S * layout.token_bytes(L)
+    holder = type("S", (), {"_model": model, "_window": W, "_block": Q})()
+    n_counts = len(model.step_counters[1])
+
+    def named(text, kernel):
+        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                          % kernel, text, re.M)
+
+    step = jax.jit(lambda *a: DecodeServer._block_decode_fn(holder, *a),
+                   donate_argnums=(7, 8)).lower(
+        tree, spec((W, Q), jnp.int32), spec((W,), jnp.int32),
+        spec((W,), jnp.int32), spec((W, M), jnp.int32),
+        spec((W * (Q + 2) + n_counts,), jnp.int32), spec((W,), jnp.int32),
+        pool, pool).compile()
+    text = step.as_text()
+    assert len(named(text, "block_decode")) == L
+    assert ".bh%d.q%d.k%d.d128.bfloat16.kv4.paged" % (
+        W * model.n_heads, Q, M * S) in text
+    assert len(named(text, "block_write")) == 2
+    assert len(named(text, "grouped_matmul")) == 2 * L
+    assert ".e128.m3072.k2048.n768.bfloat16.gated" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 * L + 2
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    assert mem.temp_size_in_bytes < 0.1e9, mem      # no pool copy
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 10.8e9 < planned < 11.1e9, mem
+
+    prefill = jax.jit(
+        lambda *a: DecodeServer._block_prefill_fn(holder, *a),
+        donate_argnums=(4, 5)).lower(
+        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
+        spec((M,), jnp.int32), pool, pool).compile()
+    text = prefill.as_text()
+    assert len(named(text, "grouped_matmul")) == 2 * (L - 1)
+    mem = prefill.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    assert mem.temp_size_in_bytes < 0.3e9, mem
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert planned < 11.1e9, mem
