@@ -249,8 +249,8 @@ declare("MXNET_KVSTORE_RETRY_MAX_BACKOFF", "float", 2.0,
 
 _G = "parallel"
 declare("MXNET_GRAD_OVERLAP", "bool", False,
-        "Bucketed backward-ordered reduce-scatter + ZeRO-1 sharded "
-        "update inside the compiled step.", _G)
+        "Backward-ordered gradient buckets + ZeRO-1 sharded update "
+        "(state sharded by rows a chip) inside the compiled step.", _G)
 declare("MXNET_GRAD_BUCKET_MB", "float", 4.0,
         "Gradient-bucket size cap for the overlap path, MB.", _G)
 declare("MXNET_PARAM_SHARD", "bool", False,

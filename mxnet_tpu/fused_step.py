@@ -466,10 +466,10 @@ class FusedUpdater(_FusedCore):
 
     In-program sync mode (``MXNET_GRAD_OVERLAP=1`` + ``sync_mesh``):
     the update lowers through ``parallel.grad_sync`` — gradients are
-    bucketed, constrained to the dp axis (the partitioner's
-    reduce-scatter point), the update runs on each device's slice
-    against ZeRO-1 flat-sharded optimizer state, and only the updated
-    params all-gather back. Donation and the in-program fault guard
+    bucketed, laid out by rows a chip and constrained to the dp axis,
+    the update runs on each device's row against ZeRO-1 sharded
+    optimizer state in the same layout, and only the updated params
+    all-gather back, once a bucket. Donation and the in-program fault guard
     are intact; every ineligibility (sparse grads, non-mesh weights,
     unfusable optimizer/state layout) falls back to the plain fused
     or eager path exactly as before."""

@@ -10,12 +10,14 @@ compiler.
 
 With ``MXNET_GRAD_OVERLAP=1`` (or ``grad_overlap=True``) the step goes
 further (``parallel.grad_sync``): gradients are partitioned into
-backward-ordered size-capped buckets, each bucket's exchange lowers to
-a **reduce-scatter** instead of an all-reduce, the optimizer update
-runs on each device's slice against ZeRO-1 flat-sharded optimizer
-state (1/N per-device state memory), and only the updated parameters
-all-gather back — all inside the same compiled step, bit-exact against
-the unbucketed path.
+backward-ordered size-capped buckets, each bucket's flat buffer is laid
+out by rows a chip, the optimizer update runs on each device's row
+against ZeRO-1 sharded optimizer state in the same layout (1/N
+per-device state memory), and only the updated parameters all-gather
+back, once a bucket — all inside the same compiled step, bit-exact
+against the unbucketed path. (The gradients' sum itself compiles to
+all-reduces of whole tensors in both modes, not to a reduce-scatter:
+``grad_sync``'s docstring has what was read from the compiled step.)
 """
 from __future__ import annotations
 
@@ -155,11 +157,11 @@ def make_data_parallel_step(loss_fn: Callable, mesh, optimizer_update=None,
     padded-storage form is :class:`DistributedTrainer`'s).
 
     ``grad_overlap`` (None → the ``MXNET_GRAD_OVERLAP`` gate) switches
-    the gradient exchange + update to the bucketed reduce-scatter form:
-    each backward-ordered bucket of the flat gradient roster is
-    constrained to ``P('dp')`` (the partitioner's reduce-scatter
-    point), ``optimizer_update`` runs elementwise on the slice, and the
-    updated params all-gather back. Losses/gradients are identical
+    the gradient exchange + update to the bucketed form: each
+    backward-ordered bucket of the flat gradient roster is laid out by
+    rows a chip and constrained to ``P('dp', None)``,
+    ``optimizer_update`` runs elementwise on each chip's row, and the
+    updated params all-gather back, once a bucket. Losses/gradients are identical
     between modes (weights are pinned replicated before bucketing, so
     the forward/backward never re-partitions); the updated params may
     differ ~1 ULP because the gate-closed path keeps its original
@@ -188,7 +190,7 @@ def make_data_parallel_step(loss_fn: Callable, mesh, optimizer_update=None,
             return loss, new_params
     else:
         cap = int(bucket_mb * (1 << 20)) if bucket_mb else None
-        shard = NamedSharding(mesh, P("dp"))
+        shard = NamedSharding(mesh, P("dp", None))
         rep = NamedSharding(mesh, P())
         wsc = jax.lax.with_sharding_constraint
 
@@ -197,7 +199,7 @@ def make_data_parallel_step(loss_fn: Callable, mesh, optimizer_update=None,
             leaves_g, treedef = jax.tree_util.tree_flatten(grads)
             # pin weights replicated BEFORE bucketing (see
             # grad_sync.make_bucketed_apply): without the pin each
-            # bucket's flat-shard constraint back-propagates through
+            # bucket's row constraint back-propagates through
             # concatenate onto the weight nodes and re-partitions the
             # forward/backward
             leaves_p = [wsc(l, rep)
@@ -208,25 +210,21 @@ def make_data_parallel_step(loss_fn: Callable, mesh, optimizer_update=None,
                 axis_size=_axis_size(mesh, "dp"), cap_bytes=cap)
             new_leaves = [None] * len(leaves_p)
             for bucket in plan.buckets:
-                dt = jnp.dtype(bucket.dtype)
-                segs_g = [leaves_g[i].reshape(-1)
-                          for i in bucket.indices]
-                segs_p = [leaves_p[i].reshape(-1)
-                          for i in bucket.indices]
-                pad = bucket.padded_size - bucket.total
-                if pad:
-                    segs_g.append(jnp.zeros((pad,), dt))
-                    segs_p.append(jnp.zeros((pad,), dt))
-                gflat = wsc(jnp.concatenate(segs_g), shard)
-                pflat = wsc(jnp.concatenate(segs_p), shard)
+                # the bucket's row layout: row k is chip k's share of
+                # every member (grad_sync._Bucket)
+                gflat = wsc(bucket.pack(
+                    [wsc(leaves_g[i], rep).reshape(-1)
+                     for i in bucket.indices], jnp), shard)
+                pflat = wsc(bucket.pack(
+                    [leaves_p[i].reshape(-1) for i in bucket.indices],
+                    jnp), shard)
                 # update pinned shard-wise first, gathered after — the
                 # all-gather moves updated params only
                 new_flat = wsc(wsc(optimizer_update(pflat, gflat),
                                    shard), rep)
-                for i, off, size in zip(bucket.indices, bucket.offsets,
-                                        bucket.sizes):
-                    new_leaves[i] = new_flat[off:off + size] \
-                        .reshape(leaves_p[i].shape)
+                for i, seg in zip(bucket.indices,
+                                  bucket.unpack(new_flat)):
+                    new_leaves[i] = seg.reshape(leaves_p[i].shape)
             new_params = jax.tree_util.tree_unflatten(treedef,
                                                       new_leaves)
             return loss, new_params

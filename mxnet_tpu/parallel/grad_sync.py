@@ -1,4 +1,4 @@
-"""Overlapped gradient sync: bucketed reduce-scatter + ZeRO-1 sharded
+"""Overlapped gradient sync: bucketed gradient exchange + ZeRO-1 sharded
 optimizer update (ROADMAP item 4, the optimizer-state half of item 1).
 
 The reference framework overlapped communication with backprop by
@@ -12,21 +12,43 @@ optimizer-state sharding of ZeRO (Rajbhandari et al., SC 2020):
   size-capped, dtype-uniform buckets (``MXNET_GRAD_BUCKET_MB``) in
   *backward order* (late-layer grads first), so each bucket's exchange
   is ready as soon as its layers finish differentiating.
-- **In-program reduce-scatter** — inside the compiled step each
-  bucket's gradients are concatenated flat and constrained to
-  ``P(axis)`` (``jax.lax.with_sharding_constraint``): the SPMD
-  partitioner lowers the pending cross-device sum to a
-  ``reduce-scatter`` instead of an ``all-reduce``, and schedules it
-  against the remaining backward — the reference's engine-priority
-  overlap, decided by the compiler inside ONE XLA program.
+- **Rows a chip** — a bucket's flat buffer is ``(N, row_len)`` for an
+  axis of N chips, constrained to ``P(axis, None)``: every member's
+  flat segment is zero-padded to ``N`` equal shares and seen as ``(N,
+  cols)``, and the members lie side by side along axis 1
+  (:class:`_Bucket`: the large ones first, on whole tiles of 1,024
+  elements; the small ones behind them at their exact share, so the
+  padding does not grow with their number). Row ``k`` is chip ``k``'s
+  share OF EVERY MEMBER,
+  so each member's piece sits in a chip's row at an offset fixed when
+  the step is traced. (A 1-D roster-order buffer cut ``[k*T/N,
+  (k+1)*T/N)`` crosses members at offsets only the partition id
+  knows: XLA compiles that to a select out of ALL members for every
+  output vector — 26 ms of a 169 ms ResNet-50 step on four v5e chips,
+  and an all-gather for every member's slice; PERF.md, PR 32.)
+- **What the step compiles to** (read from ``compiled.as_text()`` for
+  a 2x2 of v5e chips, ResNet-50, 193 parameters in one bucket): the
+  pending cross-device sum of the gradients is exchanged whole, by
+  five combined ``all-reduce``s of several tensors each as the
+  backward pass yields them (every gradient is pinned replicated in
+  its own shape) — no ``reduce-scatter``, on the chip's pipeline or
+  the CPU's; each member's row is then a LOCAL
+  ``dynamic-slice`` out of the summed gradient (or the replicated
+  weight) at partition-id x cols, written into the chip's row by a
+  ``dynamic-update-slice`` at a constant offset; the lr / wd vectors
+  are one replicated row (a gather of one value a tile, broadcast,
+  and of one a column over the small members);
+  and the updated buffer comes back by ONE ``all-gather`` a bucket
+  (one more for each state slot where the state is resident
+  replicated), each parameter a static slice of its columns.
+  ``tests/test_grad_sync.py`` holds the gathers to a multiple of the
+  buckets and the step to no many-member concatenate cut at run time.
 - **ZeRO-1 sharded update** — the optimizer update
   (``Optimizer.fused_step_fn``; every supported rule is elementwise
-  and index-independent) runs on each device's reduce-scattered slice
-  with per-element lr/wd vectors built in-program, against optimizer
-  state that lives *permanently sharded* along the same flat bucket
-  layout (1/N per device — the memory win). Only the **updated
-  parameters** are all-gathered back to the step's replicated param
-  sharding.
+  and index-independent, so it does not care in what order elements
+  lie) runs on each device's row with a per-element lr/wd row built
+  in-program, against optimizer state that lives *permanently
+  sharded* in the same row layout (1/N per device — the memory win).
 - **Bit-exactness** — the sharded composition is float-identical to
   the per-parameter path: the collective sums the same N per-device
   contributions per element, the update rule applies the same scalar
@@ -42,13 +64,17 @@ a dp mesh, and the eager kvstore gradient exchange
 (:func:`bucketed_kvstore_sync`, used by ``model._update_params`` and
 ``gluon.Trainer.allreduce_grads`` — there the buckets are real
 host-timed ``grad_sync`` comm spans). Default off: every existing
-path is byte-identical with the gate closed.
+path is byte-identical with the gate closed (``DistributedTrainer``
+then runs the same machinery over ONE bucket with replicated state).
 
 Sharded optimizer state round-trips through ``checkpoint.py``'s
-per-shard manifest format: each bucket slot is one flat dp-sharded
-array whose pieces land in per-mesh-position shard files, and
-:meth:`ShardedOptState.load_host_flats` re-pads for the *current* axis
-size, so a run saved on N devices resumes on M.
+per-shard manifest format. What a checkpoint holds is NOT the row
+layout, which depends on N, but each bucket slot as one 1-D vector in
+roster order sharded over the axis (re-laid on the device at a save:
+:meth:`ShardedOptState.checkpoint_roster`), and
+:meth:`ShardedOptState.load_host_flats` lays it out by rows for the
+*current* axis size: a run saved on N devices resumes on M, and a
+checkpoint written before the row layout loads as it is.
 """
 from __future__ import annotations
 
@@ -78,26 +104,129 @@ def bucket_cap_bytes():
     return max(1, int(mb * (1 << 20)))
 
 
+TILE = 1024   # elements: a whole number of the chip's vector registers,
+              # bf16 and f32 alike
+
+
 class _Bucket:
     """One bucket of the flat gradient roster: member parameter
-    indices in exchange order, their flat sizes/offsets inside the
-    concatenated vector, and the zero-padded length that divides the
-    sync axis."""
-    __slots__ = ("indices", "sizes", "offsets", "total", "padded_size",
-                 "dtype", "nbytes")
+    indices in exchange order and the TWO layouts of its flat buffer.
+
+    - On the device the buffer is ``(axis_size, row_len)``: member
+      ``i``'s flat segment is zero-padded to ``axis_size * cols[i]``,
+      seen as ``(axis_size, cols[i])``, and the members' views lie
+      side by side along axis 1 at ``col_offsets``. Row ``k`` is chip
+      ``k``'s share OF EVERY MEMBER, so where each member's piece lies
+      in a chip's row is known when the program is traced
+      (:meth:`pack`, :meth:`unpack`, :meth:`spread`). A member whose
+      share of a chip is a tile or more comes first, its columns
+      rounded up to whole tiles, so each starts on a vector register
+      (``aligned_len`` columns in all). The smaller members (every
+      BatchNorm vector, every bias) follow at their exact share
+      ``ceil(size / axis_size)``, one against the other, and the row
+      is closed to a whole tile once.
+    - What leaves the device (checkpoints, the Updater interchange)
+      stays the 1-D roster-order vector: member ``i`` at ``offsets[i]``
+      of ``total`` elements, which does not depend on the axis size.
+
+    The padding (``padded_size - total``) is under ``axis_size``
+    elements a small member, under ``axis_size * TILE`` a large one
+    (less than the member itself) and once more for the row: it grows
+    with the axis, never with the axis times the number of small
+    members. ``nbytes`` is the logical payload (``total``), what the
+    bucket cap and the traffic ledger count."""
+    __slots__ = ("indices", "sizes", "offsets", "total", "rows", "cols",
+                 "col_offsets", "aligned_len", "row_len", "padded_size",
+                 "dtype", "nbytes", "_order")
 
     def __init__(self, indices, sizes, axis_size, dtype):
         self.indices = tuple(indices)
         self.sizes = tuple(sizes)
+        self.rows = int(axis_size)
         offs, off = [], 0
         for s in sizes:
             offs.append(off)
             off += s
         self.offsets = tuple(offs)
         self.total = off
-        self.padded_size = -(-off // axis_size) * axis_size
+        share = [-(-s // self.rows) for s in sizes]
+        cols = [-(-c // TILE) * TILE if c >= TILE else c for c in share]
+        # the large members in exchange order, then the small
+        large = [m for m, c in enumerate(share) if c >= TILE]
+        self._order = tuple(large + [m for m, c in enumerate(share)
+                                     if c < TILE])
+        self.aligned_len = sum(cols[m] for m in large)
+        coffs, coff = [0] * len(sizes), 0
+        for m in self._order:
+            coffs[m] = coff
+            coff += cols[m]
+        self.cols = tuple(cols)
+        self.col_offsets = tuple(coffs)
+        self.row_len = -(-coff // TILE) * TILE
+        self.padded_size = self.rows * self.row_len
         self.dtype = str(dtype)
-        self.nbytes = self.padded_size * _np.dtype(dtype).itemsize
+        self.nbytes = self.total * _np.dtype(dtype).itemsize
+
+    def pack(self, segs, xp):
+        """Members' flat segments (exchange order) -> the ``(rows,
+        row_len)`` buffer; ``xp`` is ``numpy`` at the host boundary
+        and ``jax.numpy`` inside a traced step (where the caller pins
+        every segment replicated first: see ``make_bucketed_apply``).
+        """
+        views = []
+        for m in self._order:
+            seg, c = segs[m], self.cols[m]
+            pad = self.rows * c - self.sizes[m]
+            if pad:
+                seg = xp.concatenate([seg, xp.zeros((pad,), seg.dtype)])
+            views.append(seg.reshape(self.rows, c))
+        close = self.row_len - sum(self.cols)
+        if close:
+            views.append(xp.zeros((self.rows, close), views[0].dtype))
+        return xp.concatenate(views, axis=1)
+
+    def unpack(self, buf):
+        """The ``(rows, row_len)`` buffer -> members' flat segments,
+        each a static slice of columns cut to its size."""
+        return [buf[:, off:off + c].reshape(-1)[:size]
+                for off, c, size in zip(self.col_offsets, self.cols,
+                                        self.sizes)]
+
+    def spread(self, values):
+        """One traced scalar a member -> the ``(row_len,)`` vector that
+        holds it over the member's columns: alike in every row, so one
+        row serves all chips. Over the aligned members it is one small
+        gather (a value a tile) broadcast over the tile; over the small
+        ones behind them a gather of a value a column — nothing of
+        ``row_len`` elements is assembled piece by piece. (The columns
+        that close the row hold zeros under any lr / wd.)"""
+        import jax.numpy as jnp
+        member_of_col = _np.zeros((self.row_len,), _np.int32)
+        for m, (off, c) in enumerate(zip(self.col_offsets, self.cols)):
+            member_of_col[off:off + c] = m
+        vals = jnp.stack(values)
+        parts = []
+        if self.aligned_len:
+            parts.append(jnp.repeat(
+                vals[member_of_col[:self.aligned_len:TILE]], TILE))
+        if self.aligned_len < self.row_len:
+            parts.append(vals[member_of_col[self.aligned_len:]])
+        return jnp.concatenate(parts)
+
+    def roster_order(self, buf):
+        """The ``(rows, row_len)`` buffer on the device -> the 1-D
+        roster-order vector a checkpoint holds, zero-padded to divide
+        the axis (traced: the save's re-layout program)."""
+        import jax.numpy as jnp
+        segs = self.unpack(buf)
+        segs.append(jnp.zeros((-self.total % self.rows,), buf.dtype))
+        return jnp.concatenate(segs)
+
+    def from_roster_order(self, flat):
+        """A checkpoint's 1-D roster-order host vector (any tail
+        padding) -> the ``(rows, row_len)`` host buffer."""
+        return self.pack([flat[off:off + size] for off, size in
+                          zip(self.offsets, self.sizes)], _np)
 
 
 class GradSyncPlan:
@@ -170,35 +299,43 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
     """The bucketed, sharded form of ``fused_step.make_apply`` — same
     call contract ``apply(grads, weights, states, scalars, poisons) ->
     (new_weights, new_states, finite_mask)`` over raw jax arrays,
-    except ``states`` is the flat bucket layout: ``n_slots`` sharded
-    ``(padded_size,)`` vectors per bucket, ordered
+    except ``states`` is the bucket row layout: ``n_slots`` sharded
+    ``(axis_size, row_len)`` buffers per bucket, ordered
     ``[b0s0..b0s{k-1}, b1s0, ...]``.
 
     Per bucket: splice poison / read the finite guard per parameter,
-    concatenate the flat gradients (zero pad), constrain to
-    ``P(axis)`` — the partitioner's reduce-scatter point — slice the
-    replicated weights the same way (free), run the bucket's update
-    rule once over the whole slice with in-program per-element lr/wd
-    vectors, and constrain the updated flat params back to replicated
-    — the all-gather of *updated params only*. Requires every member's
+    lay the flat gradients out by rows a chip (:meth:`_Bucket.pack`:
+    zero-padded members side by side) and constrain the buffer to
+    ``P(axis, None)`` — pushed through the concatenate this lands on
+    every member as its own row, a contiguous local copy — lay the
+    replicated weights out the same way (free), run the bucket's
+    update rule once over the whole row with one in-program lr/wd row,
+    and constrain the updated buffer back to replicated — ONE
+    all-gather of *updated params only*, out of which each parameter
+    is a static slice of columns. Every gradient is pinned replicated
+    in its own shape, as every weight is, so its cross-device sum is
+    an all-reduce of the whole gradient where the backward pass yields
+    it: on a 2x2 of v5e chips (and on the CPU) several tensors
+    combined in one, and no reduce-scatter. Requires every member's
     ``fused_step_fn`` to be index-independent, true of all compiled
     optimizers (the closures capture only optimizer-level
     hyperparameters).
 
     ``shard_state=False`` is the unbucketed baseline's state layout:
-    states arrive replicated, are sliced for the (identical) sharded
-    update, and the new states are all-gathered back to replicated —
-    full per-device state memory, the profile ZeRO-1 removes. The
-    update arithmetic itself ALWAYS runs on the sharded slices in both
-    layouts: XLA's codegen for replicated elementwise math contracts
-    FMAs that its partitioned codegen does not (measured ~1 ULP per
-    step on CPU), so computing shard-wise in every mode is what makes
-    bucketed-vs-monolithic trajectories bit-identical."""
+    states arrive replicated, each chip takes its row for the
+    (identical) sharded update, and the new states are all-gathered
+    back to replicated — full per-device state memory, the profile
+    ZeRO-1 removes. The update arithmetic itself ALWAYS runs on the
+    sharded rows in both layouts: XLA's codegen for replicated
+    elementwise math contracts FMAs that its partitioned codegen does
+    not (measured ~1 ULP per step on CPU), so computing shard-wise in
+    every mode is what makes bucketed-vs-monolithic trajectories
+    bit-identical."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    shard = NamedSharding(mesh, P(axis))
+    shard = NamedSharding(mesh, P(axis, None))
     rep = NamedSharding(mesh, P())
     wsc = jax.lax.with_sharding_constraint
     n = len(step_fns)
@@ -207,14 +344,21 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
     def apply(grads, weights, states, scalars, poisons):
         # Pin every weight replicated BEFORE the bucket machinery
         # touches it. Weights feed the forward matmuls AND the update:
-        # without the pin, each bucket's flat-shard constraint
+        # without the pin, each bucket's row constraint
         # back-propagates through concatenate onto the weight nodes
         # and re-partitions the forward/backward — monolithic vs
         # bucketed plans then produce ~1-ULP-different gradients
         # (measured on an 8-device CPU mesh) and trajectory identity
-        # dies. The pin stops the propagation at this edge; gradients
-        # are deliberately NOT pinned, so each bucket's pending
-        # cross-device sum still lowers to a reduce-scatter.
+        # dies. The pin stops the propagation at this edge. Every
+        # gradient is pinned the same way (below): the row constraint
+        # otherwise travels back through a member's zero padding into
+        # the backward pass, cuts a 64-element gradient's computation
+        # by the axis and puts it together again with a gather and a
+        # permute of its own (79 all-gathers in the ResNet-50 step).
+        # Pinned in its OWN shape, before it is flattened: pinned
+        # behind the reshape, the all-reduces move behind it too and
+        # the combiner makes ONE of all 25.5 M elements, which cannot
+        # start before the backward pass has ended.
         weights = [wsc(w, rep) for w in weights]
         rescale = scalars[2 * n]
         new_ws = [None] * n
@@ -223,9 +367,9 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
         si = 0
         for bucket in buckets:
             dt = jnp.dtype(bucket.dtype)
-            segs_g, segs_w, segs_lr, segs_wd = [], [], [], []
-            for i, size in zip(bucket.indices, bucket.sizes):
-                g = grads[i].reshape(-1)
+            segs_g = []
+            for i in bucket.indices:
+                g = wsc(grads[i], rep).reshape(-1)
                 if inject:
                     g = jnp.where(jnp.isfinite(poisons[i]), g,
                                   jnp.full_like(g, poisons[i]
@@ -233,32 +377,28 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
                 if guard:
                     oks[i] = jnp.isfinite(g).all()
                 segs_g.append(g)
-                segs_w.append(weights[i].reshape(-1))
-                segs_lr.append(jnp.full((size,),
-                                        scalars[i].astype(dt)))
-                segs_wd.append(jnp.full((size,),
-                                        scalars[n + i].astype(dt)))
-            pad = bucket.padded_size - bucket.total
-            if pad:
-                z = jnp.zeros((pad,), dt)
-                for lst in (segs_g, segs_w, segs_lr, segs_wd):
-                    lst.append(z)
-            # the reduce-scatter point: the pending cross-device sum of
-            # gflat lowers to a scatter onto P(axis); wflat is
-            # replicated, so its constraint is a free local slice
-            gflat = wsc(jnp.concatenate(segs_g), shard)
-            wflat = wsc(jnp.concatenate(segs_w), shard)
-            lr_v = wsc(jnp.concatenate(segs_lr), shard)
-            wd_v = wsc(jnp.concatenate(segs_wd), shard)
+            # the constraint lands on every member as ITS row k on
+            # chip k: a contiguous copy out of a replicated value (the
+            # weights) or out of the summed gradient, laid into the
+            # chip's row at an offset fixed at trace time
+            gflat = wsc(bucket.pack(segs_g, jnp), shard)
+            wflat = wsc(bucket.pack([weights[i].reshape(-1)
+                                     for i in bucket.indices], jnp),
+                        shard)
+            # lr / wd are alike in every row: one replicated row
+            lr_v = bucket.spread([scalars[i].astype(dt)
+                                  for i in bucket.indices])
+            wd_v = bucket.spread([scalars[n + i].astype(dt)
+                                  for i in bucket.indices])
             st = tuple(states[si + k] for k in range(n_slots))
             if not shard_state:
-                # replicated-resident baseline state: slice for the
-                # shard-wise update (free), gather back after
+                # replicated-resident baseline state: take the local
+                # row for the shard-wise update (free), gather after
                 st = tuple(wsc(s, shard) for s in st)
             fn = step_fns[bucket.indices[0]]
             nw, nst = fn(gflat, wflat, st, lr_v, wd_v,
                          rescale.astype(dt))
-            # Pin the update OUTPUTS to the shard layout before any
+            # Pin the update OUTPUTS to the row layout before any
             # replicated re-constraint: with replicated-resident
             # baseline state the partitioner would otherwise satisfy
             # the rep output constraint by gathering the INPUTS and
@@ -270,12 +410,7 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
             nw = wsc(nw, shard)
             nst = tuple(wsc(s, shard) for s in nst)
             if guard:
-                seg_ok = [jnp.full((size,), oks[i])
-                          for i, size in zip(bucket.indices,
-                                             bucket.sizes)]
-                if pad:
-                    seg_ok.append(jnp.ones((pad,), jnp.bool_))
-                ok_v = wsc(jnp.concatenate(seg_ok), shard)
+                ok_v = bucket.spread([oks[i] for i in bucket.indices])
                 nw = jnp.where(ok_v, nw, wflat)
                 nst = tuple(jnp.where(ok_v, s_new, s_old)
                             for s_new, s_old in zip(nst, st))
@@ -283,12 +418,11 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
             for k in range(n_slots):
                 new_sts[si + k] = wsc(nst[k], out_spec)
             si += n_slots
-            # the all-gather of UPDATED params only
-            full_w = wsc(nw, rep)
-            for i, off, size in zip(bucket.indices, bucket.offsets,
-                                    bucket.sizes):
-                new_ws[i] = full_w[off:off + size] \
-                    .reshape(weights[i].shape)
+            # ONE all-gather of the bucket's updated parameters; each
+            # member is then a static slice of columns
+            for i, seg in zip(bucket.indices,
+                              bucket.unpack(wsc(nw, rep))):
+                new_ws[i] = seg.reshape(weights[i].shape)
         mask = jnp.stack(oks) if guard else jnp.ones((n,), jnp.bool_)
         return tuple(new_ws), tuple(new_sts), mask
     return apply
@@ -301,11 +435,15 @@ def make_bucketed_apply(step_fns, n_slots, plan, mesh, axis="dp",
 class ShardedOptState:
     """Flat, bucket-aligned, axis-sharded optimizer state.
 
-    Each bucket contributes ``n_slots`` ``(padded_size,)`` arrays
-    placed with ``NamedSharding(mesh, P(axis))`` — every device holds
-    1/N of every state vector, the ZeRO-1 memory layout
+    Each bucket contributes ``n_slots`` ``(axis_size, row_len)``
+    arrays in the bucket's row layout (:class:`_Bucket`) placed with
+    ``NamedSharding(mesh, P(axis, None))`` — every device holds its
+    row, 1/N of every state buffer, the ZeRO-1 memory layout
     (``sharded=False`` keeps them replicated: the unbucketed
-    baseline's full-per-device memory profile). Slot count and dtypes
+    baseline's full-per-device memory profile). What leaves the device
+    — a checkpoint's ``opt:bucketBB.slotS``, the per-parameter
+    interchange — is in roster order and knows nothing of the axis
+    size. Slot count and dtypes
     are probed from the optimizer's own eager
     ``create_state_multi_precision`` (so RMSProp's fp32 accumulators
     stay fp32); initial values are zeros, matching every compiled
@@ -319,6 +457,7 @@ class ShardedOptState:
         self.n_slots = None
         self._slot_dtypes = None
         self._flats = None        # list over buckets of tuple(arrays)
+        self._to_roster = None    # every slot's rows -> roster order (jit)
 
     # -- layout probing ---------------------------------------------------
     def probe(self, optimizer, indices, weights_nd):
@@ -347,8 +486,13 @@ class ShardedOptState:
 
     def _sharding(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
-        return NamedSharding(self.mesh,
-                             P(self.axis) if self.sharded else P())
+        return NamedSharding(
+            self.mesh, P(self.axis, None) if self.sharded else P())
+
+    def _place(self, host_rows):
+        import jax
+        import jax.numpy as jnp
+        return jax.device_put(jnp.asarray(host_rows), self._sharding())
 
     # -- state roster ------------------------------------------------------
     def ensure(self):
@@ -363,7 +507,7 @@ class ShardedOptState:
             for bucket in self.plan.buckets:
                 flats.append(tuple(
                     jax.device_put(
-                        jnp.zeros((bucket.padded_size,),
+                        jnp.zeros((bucket.rows, bucket.row_len),
                                   jnp.dtype(dt)), sh)
                     for dt in self._slot_dtypes))
             self._flats = flats
@@ -400,34 +544,26 @@ class ShardedOptState:
         if self._flats is None:
             return out
         for bucket, slots in zip(self.plan.buckets, self._flats):
-            host = [_np.asarray(s) for s in slots]
-            for i, off, size in zip(bucket.indices, bucket.offsets,
-                                    bucket.sizes):
-                out[i] = [h[off:off + size].reshape(shapes[i])
-                          for h in host]
+            per_slot = [bucket.unpack(_np.asarray(s)) for s in slots]
+            for pos, i in enumerate(bucket.indices):
+                out[i] = [segs[pos].reshape(shapes[i])
+                          for segs in per_slot]
         return out
 
     def seed_per_param(self, per_param):
         """Populate the sharded flats from per-parameter state arrays
         (``{index: [slot numpy arrays]}``) — the resume/interchange
         path. Missing indices keep zeros."""
-        import jax
-        import jax.numpy as jnp
         assert self.n_slots is not None, "probe() before seeding"
-        sh = self._sharding()
         flats = []
         for bucket in self.plan.buckets:
             slots = []
             for k in range(self.n_slots):
                 dt = _np.dtype(self._slot_dtypes[k])
-                full = _np.zeros((bucket.padded_size,), dt)
-                for i, off, size in zip(bucket.indices, bucket.offsets,
-                                        bucket.sizes):
-                    st = per_param.get(i)
-                    if st is not None:
-                        full[off:off + size] = \
-                            _np.asarray(st[k]).reshape(-1)
-                slots.append(jax.device_put(jnp.asarray(full), sh))
+                segs = [_np.zeros((size,), dt) if per_param.get(i) is None
+                        else _np.asarray(per_param[i][k], dt).reshape(-1)
+                        for i, size in zip(bucket.indices, bucket.sizes)]
+                slots.append(self._place(bucket.pack(segs, _np)))
             flats.append(tuple(slots))
         self._flats = flats
 
@@ -435,7 +571,12 @@ class ShardedOptState:
     def checkpoint_roster(self):
         """``{'opt:bucketBB.slotS': sharded array}`` — handed to
         ``checkpoint.snapshot_params(extra=...)``; the manifest's piece
-        format records each shard's mesh position. An ``opt:layout``
+        format records each shard's mesh position. Each array is the
+        bucket slot in ROSTER ORDER, 1-D, re-laid on the device from
+        the row layout (one program for all of them, compiled at the
+        first save and run at a save, not in a step) and sharded over the axis as a checkpoint has held
+        it since before the row layout: a save on N chips restores on
+        M, and on a build that predates this layout. An ``opt:layout``
         fingerprint of the (topology-independent) bucket partition
         rides along so a restore under a different
         ``MXNET_GRAD_BUCKET_MB`` refuses instead of silently slicing
@@ -443,9 +584,24 @@ class ShardedOptState:
         out = {}
         if self._flats is None:
             return out
-        for b, slots in enumerate(self._flats):
-            for k, arr in enumerate(slots):
-                out["opt:bucket%02d.slot%d" % (b, k)] = arr
+        if self._to_roster is None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from .. import compile_watch
+            buckets, k = self.plan.buckets, self.n_slots
+
+            def to_roster(*rows):
+                return tuple(buckets[j // k].roster_order(r)
+                             for j, r in enumerate(rows))
+            # ONE program for every bucket and slot, kept: the first
+            # save compiles once, a later one not at all
+            self._to_roster = compile_watch.jit(
+                to_roster, "grad_sync:roster_order",
+                statics=(self.plan.signature(), k, self.sharded),
+                out_shardings=NamedSharding(
+                    self.mesh, P(self.axis) if self.sharded else P()))
+        flat = self._to_roster(*(a for b in self._flats for a in b))
+        for j, arr in enumerate(flat):
+            out["opt:bucket%02d.slot%d" % divmod(j, self.n_slots)] = arr
         out["opt:layout"] = self._layout_fingerprint()
         return out
 
@@ -457,11 +613,10 @@ class ShardedOptState:
 
     def load_host_flats(self, flat_dict):
         """Restore from a checkpoint's ``opt:bucketBB.slotS`` host
-        arrays (any save-time topology): strip the save-time padding,
-        re-pad for the CURRENT axis size, and shard onto the current
-        mesh — the elastic-resume leg for optimizer state."""
-        import jax
-        import jax.numpy as jnp
+        arrays (any save-time topology, roster order): strip the
+        save-time padding, lay the members out by rows for the CURRENT
+        axis size, and shard onto the current mesh — the
+        elastic-resume leg for optimizer state."""
         assert self.n_slots is not None, "probe() before restore"
         saved_layout = flat_dict.get("opt:layout")
         if saved_layout is not None and not _np.array_equal(
@@ -472,7 +627,6 @@ class ShardedOptState:
                 "partition differs from the current plan (different "
                 "MXNET_GRAD_BUCKET_MB / roster?) — refusing to slice "
                 "state into the wrong parameters")
-        sh = self._sharding()
         flats = []
         for b, bucket in enumerate(self.plan.buckets):
             slots = []
@@ -488,10 +642,10 @@ class ShardedOptState:
                         "sharded optimizer state: %s holds %d elements"
                         " but the roster needs %d (bucket layout "
                         "changed?)" % (key, host.size, bucket.total))
-                full = _np.zeros((bucket.padded_size,),
-                                 _np.dtype(self._slot_dtypes[k]))
-                full[:bucket.total] = host[:bucket.total]
-                slots.append(jax.device_put(jnp.asarray(full), sh))
+                host = host.astype(_np.dtype(self._slot_dtypes[k]),
+                                   copy=False)
+                slots.append(self._place(
+                    bucket.from_roster_order(host)))
             flats.append(tuple(slots))
         self._flats = flats
 
@@ -502,8 +656,9 @@ class ShardedOptState:
 
 def account_in_program_sync(plan, mesh=None, axis="dp"):
     """Ledger one compiled-step dispatch's bucket traffic: per-bucket
-    ``grad_sync`` comm records (reduce-scatter + updated-param
-    all-gather bytes; latency 0 — the exchange is scheduled INSIDE the
+    ``grad_sync`` comm records (the bucket's bytes once for the
+    gradients' exchange and once for the updated-param all-gather;
+    latency 0 — the exchange is scheduled INSIDE the
     program, overlapped with backward, so there is no host-observable
     span) plus run counters. With ``mesh`` given, the same bytes are
     additionally split per link — intra-host ``ici`` vs cross-host
@@ -526,8 +681,8 @@ def account_in_program_sync(plan, mesh=None, axis="dp"):
         return
     total = 0
     for b, bucket in enumerate(plan.buckets):
-        # RS moves (N-1)/N of the bucket in, AG the same out; account
-        # the logical payload once per direction
+        # the exchange moves the bucket in, the gather the same out;
+        # account the logical payload once per direction
         telemetry.comm("grad_sync", "bucket%02d" % b,
                        nbytes=2 * bucket.nbytes, seconds=0.0)
         total += 2 * bucket.nbytes
@@ -604,7 +759,7 @@ def bucketed_kvstore_sync(kvstore, items, cap_bytes=None):
         with telemetry.comm_span("grad_sync", "bucket%02d" % b,
                                  nbytes=2 * flat.nbytes):
             # 2x: bucket bytes once per direction (push + pull),
-            # matching the in-program RS+AG accounting
+            # matching the in-program exchange + gather accounting
             kvstore.push(key, flat_nd, priority=-b)
             kvstore.pull(key, flat_nd, priority=-b)
         if t_tr is not None:

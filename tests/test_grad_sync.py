@@ -15,6 +15,7 @@ the diagnose Sync table.
 """
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -54,7 +55,10 @@ def _clean_state(monkeypatch):
 
 def test_plan_backward_order_and_cap():
     """Buckets traverse the roster in REVERSE (late-layer grads reduce
-    first), close on the byte cap, and zero-pad to the axis size."""
+    first), close on the byte cap, and lay every member out by rows a
+    chip: a member that fills a tile of every row padded to whole
+    tiles, the smaller ones at their exact share behind them, the row
+    closed to a whole tile once."""
     shapes = [(100,), (50,), (200,), (10,)]
     plan = GradSyncPlan(shapes, ["float32"] * 4, axis_size=8,
                         cap_bytes=4 * 150)   # 150 f32 elements
@@ -62,15 +66,57 @@ def test_plan_backward_order_and_cap():
     assert plan.buckets[0].indices == (3,)
     assert plan.buckets[1].indices == (2,)
     assert plan.buckets[2].indices == (1, 0)
+    tile = grad_sync.TILE
     for b in plan.buckets:
-        assert b.padded_size % 8 == 0
-        assert b.padded_size - b.total < 8
+        assert b.padded_size == 8 * b.row_len
+        assert b.row_len % tile == 0 and b.row_len - sum(b.cols) < tile
+        for size, c in zip(b.sizes, b.cols):
+            assert c == -(-size // 8)     # all small: the exact share
         assert b.total == sum(b.sizes)
-    # offsets are a prefix sum of sizes
+        assert b.nbytes == 4 * b.total    # the logical payload
+    # roster-order offsets (what leaves the device) are a prefix sum of
+    # sizes; a chip's row holds the small members one against the other
     b = plan.buckets[2]
     assert b.offsets == (0, 50)
+    assert b.col_offsets == (0, 7) and b.aligned_len == 0
+    # a member with a tile or more a chip comes first, on whole tiles
+    b = GradSyncPlan([(3,), (8 * tile + 1,), (5,)], ["float32"] * 3, 8,
+                     cap_bytes=grad_sync.MONOLITH_CAP).buckets[0]
+    assert b.indices == (2, 1, 0) and b.cols == (1, 2 * tile, 1)
+    assert b.col_offsets == (2 * tile, 0, 2 * tile + 1)
+    assert b.aligned_len == 2 * tile and b.row_len == 3 * tile
     assert plan.signature() == GradSyncPlan(
         shapes, ["float32"] * 4, 8, cap_bytes=600).signature()
+
+
+@pytest.mark.parametrize("axis", [4, 256])
+def test_bucket_padding_does_not_grow_with_the_small_members(axis):
+    """A ResNet-like roster (many BatchNorm vectors and biases beside
+    a few large kernels) on a narrow and on a wide axis: a small
+    member pads by less than one element a chip, a large one by less
+    than a tile a chip, the row by a tile once — never a tile a chip
+    for EVERY small member (31 M elements on 256 chips)."""
+    sizes = [64, 64, 256, 256, 512, 2048, 1000] * 17 + \
+        [64 * 3 * 7 * 7, 512 * 512 * 9, 2048 * 1000, 256 * 1024 + 3]
+    b = GradSyncPlan([(s,) for s in sizes], ["bfloat16"] * len(sizes),
+                     axis, cap_bytes=grad_sync.MONOLITH_CAP).buckets[0]
+    tile = grad_sync.TILE
+    large = [s for s in sizes if -(-s // axis) >= tile]
+    small = len(sizes) - len(large)
+    assert b.padded_size - b.total < \
+        axis * (small + tile * (len(large) + 1))
+    assert b.padded_size < 2 * b.total + axis * tile
+    assert b.aligned_len % tile == 0 and b.row_len % tile == 0
+    for size, c, off in zip(b.sizes, b.cols, b.col_offsets):
+        assert axis * c >= size
+        assert (off % tile == 0 and off < b.aligned_len) == \
+            (-(-size // axis) >= tile) or off == b.aligned_len
+    # every column belongs to at most one member
+    taken = np.zeros((b.row_len,), np.int32)
+    for c, off in zip(b.cols, b.col_offsets):
+        taken[off:off + c] += 1
+    assert taken.max() == 1
+    assert b.nbytes == 2 * sum(sizes)
 
 
 def test_plan_dtype_split_and_monolith():
@@ -162,15 +208,30 @@ OPTIMIZERS = [("sgd", {"learning_rate": 0.05}),
 _INIT = {}
 
 
-def _dist_run(overlap, opt, opt_params, steps=5, bucket_mb=0.001):
-    mesh = local_mesh("dp")
+# (hidden, classes, inputs): EVEN is the roster these tests always had;
+# ODD gives every parameter an odd size (627, 33, 363, 11), so that no
+# member fills its rows and the per-parameter padding is exercised
+EVEN, ODD = (32, 10, 20), (33, 11, 19)
+WIDTHS = pytest.mark.parametrize("widths", [EVEN, ODD],
+                                 ids=["even", "odd"])
+
+
+def _dist_net(widths):
     # fixed prefix: roster names (and so checkpoint arg: keys) are
     # identical across runs instead of riding the global name counter
+    hidden, classes, inputs = widths
     net = nn.HybridSequential(prefix="gsync_")
     with net.name_scope():
-        net.add(nn.Dense(32, activation="relu"), nn.Dense(10))
+        net.add(nn.Dense(hidden, activation="relu"), nn.Dense(classes))
     net.initialize()
-    _ = net(mx.nd.array(np.zeros((16, 20), np.float32)))
+    _ = net(mx.nd.array(np.zeros((16, inputs), np.float32)))
+    return net
+
+
+def _dist_run(overlap, opt, opt_params, steps=5, bucket_mb=0.001,
+              widths=EVEN):
+    mesh = local_mesh("dp")
+    net = _dist_net(widths)
     plist = sorted(net.collect_params().items())
     key = tuple(tuple(p.data().shape) for _, p in plist)
     if key not in _INIT:
@@ -186,7 +247,7 @@ def _dist_run(overlap, opt, opt_params, steps=5, bucket_mb=0.001):
     rng = np.random.RandomState(3)
     losses = []
     for _ in range(steps):
-        data = mx.nd.array(rng.randn(16, 20).astype(np.float32))
+        data = mx.nd.array(rng.randn(16, widths[2]).astype(np.float32))
         label = mx.nd.array(
             rng.randint(0, 10, (16,)).astype(np.float32))
         losses.append(float(tr.fit_batch(data, label).asnumpy()))
@@ -222,7 +283,12 @@ def test_zero1_state_memory_is_one_over_n():
     _, _, t_on = _dist_run(True, "adam", {"learning_rate": 0.01},
                            steps=1)
     off_b, on_b = (t.state_bytes_per_device() for t in (t_off, t_on))
-    assert off_b > 0 and on_b * N_DEV == off_b
+    # the ledger is the resident arrays': all of each with the gate
+    # closed, a row of each with it open (each plan closes its own
+    # rows to a tile, so the two totals are not one number)
+    assert off_b == sum(a.nbytes for a in t_off._state_vals) > 0
+    assert on_b * N_DEV == sum(a.nbytes for a in t_on._state_vals)
+    assert on_b < off_b
     # the actual arrays agree with the ledger: one addressable shard
     # per device, 1/N (resp. full) of the vector each
     for arr in t_on._state_vals:
@@ -249,6 +315,159 @@ def test_distributed_trainer_params_placed_once():
     for v in tr._param_vals:
         assert hasattr(v, "sharding")
     assert tr._gluon_dirty is False        # sync_gluon_params ran
+
+
+# ---------------------------------------------------------------------------
+# the compiled mesh step: what the row layout is for
+# ---------------------------------------------------------------------------
+
+class _Lowered(Exception):
+    pass
+
+
+def _conv_step_text(n_blocks, overlap):
+    """The compiled text of a ``DistributedTrainer`` step on FOUR host
+    devices for a small convolutional net of ``4 * n_blocks + 2``
+    parameters, none of a size that divides by 4 (convolutions of 5, 6
+    and 7 channels with a bias, BatchNorm, a classifier of 7), and the
+    trainer: compiled, not run."""
+    mesh = create_mesh({"dp": 4}, devices=jax.devices()[:4])
+    net = nn.HybridSequential(prefix="rows%d_" % n_blocks)
+    with net.name_scope():
+        for b in range(n_blocks):
+            net.add(nn.Conv2D(5 + b % 3, 3, padding=1, use_bias=True),
+                    nn.BatchNorm(), nn.Activation("relu"))
+        net.add(nn.GlobalAvgPool2D(), nn.Flatten(), nn.Dense(7))
+    net.initialize()
+    x = mx.nd.array(np.zeros((8, 3, 9, 9), np.float32))
+    y = mx.nd.array(np.zeros((8,), np.float32))
+    _ = net(x)
+    tr = DistributedTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                            mesh, optimizer="sgd",
+                            optimizer_params={"learning_rate": 0.05,
+                                              "momentum": 0.9},
+                            grad_overlap=overlap, bucket_mb=0.002)
+    tr._build(x, y)
+    assert all(int(np.prod(v.shape)) % 4 for v in tr._param_vals)
+    jitted = tr._step_fn._jitted
+
+    def lower_only(*args):
+        raise _Lowered(jitted.lower(*args).compile().as_text())
+    tr._step_fn = lower_only
+    with pytest.raises(_Lowered) as caught:
+        tr.fit_batch(x, y)
+    return str(caught.value), tr
+
+
+_HLO_LINE = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\((.*)$")
+_HLO_HEAD = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_SAME_VALUES = {"bitcast", "reshape", "copy", "convert", "pad",
+                "transpose"}
+
+
+def _wide_concat_cut_at_run_time(text, widest=4):
+    """The ``dynamic-slice`` instructions of a compiled module that
+    cut, at an offset that is no constant (on a mesh: the partition
+    id's), a ``concatenate`` of more than ``widest`` operands — seen
+    through bitcasts, reshapes, pads and the boundaries of fusions.
+    That is the shape in which no output element knows at compile time
+    which operand it comes from."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        head = _HLO_HEAD.match(line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+            continue
+        m = _HLO_LINE.match(line)
+        if m and cur is not None:
+            root, name, op, rest = m.groups()
+            operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+            if op == "parameter":
+                operands = int(rest.split(")")[0])
+            calls = re.search(r"calls=%?([\w.\-]+)", rest)
+            cur.append((name, op, operands, calls and calls.group(1),
+                        bool(root)))
+
+    def scan(comp, wide_params=()):
+        wide, const, found, wide_root = set(), set(), [], False
+        for name, op, operands, calls, root in comps[comp]:
+            if op == "parameter":
+                if operands in wide_params:
+                    wide.add(name)
+            elif op == "constant":
+                const.add(name)
+            elif op == "concatenate" and len(operands) > widest:
+                wide.add(name)
+            elif op in _SAME_VALUES and operands[0] in wide:
+                wide.add(name)
+            elif op == "dynamic-slice" and operands[0] in wide and \
+                    not all(o in const for o in operands[1:]):
+                found.append(name)
+            elif op == "fusion" and calls in comps:
+                inner, inner_wide = scan(
+                    calls, [k for k, o in enumerate(operands)
+                            if o in wide])
+                found.extend(inner)
+                if inner_wide:
+                    wide.add(name)
+            wide_root = wide_root or (root and name in wide)
+        return found, wide_root
+
+    return scan(entry)[0]
+
+
+def test_the_checker_sees_a_wide_concatenate_cut_by_the_partition_id():
+    """The shape the row layout removes, written by hand: a 1-D
+    roster-order concatenate constrained to P('dp') compiles to a
+    concatenate of all members cut at partition-id x shard; the checker
+    of the next test has to see it there."""
+    mesh = create_mesh({"dp": 4}, devices=jax.devices()[:4])
+    rep, cut = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+
+    @jax.jit
+    def roster_order(*ws):
+        ws = [jax.lax.with_sharding_constraint(w, rep) for w in ws]
+        flat = jax.numpy.concatenate([w.reshape(-1) for w in ws])
+        return jax.lax.with_sharding_constraint(flat * 2, cut)
+    ws = [jax.device_put(np.ones((7 + i,), np.float32), rep)
+          for i in range(8)]           # 84 elements: 21 a chip
+    text = roster_order.lower(*ws).compile().as_text()
+    assert "partition-id" in text
+    assert _wide_concat_cut_at_run_time(text)
+
+
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["gate_closed", "gate_open"])
+def test_compiled_step_reads_its_rows_at_fixed_offsets(overlap):
+    """What the row layout is for, read from the compiled four-device
+    step in both gate positions: the updated buffers are gathered ONCE
+    a bucket (the parameters, and each state slot where the state is
+    resident replicated) — the count of all-gathers is a multiple of
+    the buckets and does not grow with the parameters — and nowhere is
+    a concatenate of many members cut at an offset only the partition
+    id knows (on the chip XLA fuses that into a select out of every
+    member for every output vector: 26 ms of a 169 ms step, ISSUE 32).
+    """
+    counts = {}
+    for n_blocks in (5, 10):                 # 22 and 42 parameters
+        text, tr = _conv_step_text(n_blocks, overlap)
+        n_params, buckets = len(tr._roster), len(tr._plan.buckets)
+        assert n_params == 4 * n_blocks + 2
+        assert (buckets == 1) != overlap
+        gathers = len(re.findall(r" all-gather(?:-start)?\(", text))
+        slots = tr._sync_state.n_slots
+        assert 1 <= gathers <= buckets * (slots + 1) + 2, \
+            (n_params, buckets, gathers)
+        counts[n_params] = gathers - buckets * (slots + 1)
+        assert "partition-id" in text        # it IS a partitioned step
+        assert _wide_concat_cut_at_run_time(text) == []
+        # the exchange itself, as read (not promised): whole gradients
+        # summed by all-reduce, no reduce-scatter on this pipeline
+        assert re.search(r" all-reduce(?:-start)?\(", text)
+    # twice the parameters, not one gather more over what the buckets
+    # account for
+    assert counts[42] <= counts[22]
 
 
 # ---------------------------------------------------------------------------
@@ -450,17 +669,18 @@ def test_module_fit_overlap_identity(tmp_path, monkeypatch):
 # sharded optimizer state through checkpoint.py (manifest format)
 # ---------------------------------------------------------------------------
 
-def test_checkpoint_roundtrip_sharded_state(tmp_path):
+@WIDTHS
+def test_checkpoint_roundtrip_sharded_state(tmp_path, widths):
     """Sharded optimizer state rides checkpoint.py's manifest as
     opt:bucketBB.slotS entries whose per-device pieces land in the
     per-mesh-position shard files; the resumed trajectory is
     bit-identical to the uninterrupted run."""
     prefix = str(tmp_path / "ck")
     l_ref, p_ref, _ = _dist_run(True, "adam", {"learning_rate": 0.01},
-                                steps=6)
+                                steps=6, widths=widths)
     # 3 steps → save → fresh trainer restores → 3 more steps
     _, _, tr1 = _dist_run(True, "adam", {"learning_rate": 0.01},
-                          steps=3)
+                          steps=3, widths=widths)
     tr1.save_checkpoint(prefix, 0)
     manifest = json.load(open("%s-0000.ckpt.json" % prefix))
     opt_keys = [k for k in manifest["params"]
@@ -469,17 +689,23 @@ def test_checkpoint_roundtrip_sharded_state(tmp_path):
     # sharded entries: every mesh position owns a piece
     assert any(len(manifest["params"][k]["pieces"]) == N_DEV
                for k in opt_keys)
+    # what the manifest holds is 1-D and in roster order, whatever the
+    # device layout: as long as the members and no longer than their
+    # sum padded to the axis
+    for b, bucket in enumerate(tr1._plan.buckets):
+        (length,) = manifest["params"]["opt:bucket%02d.slot0" % b]["shape"]
+        assert bucket.total <= length < bucket.total + N_DEV
 
     _, _, tr2 = _dist_run(True, "adam", {"learning_rate": 0.01},
-                          steps=0)
+                          steps=0, widths=widths)
     tr2.load_checkpoint(prefix, 0)
     rng = np.random.RandomState(3)
     for _ in range(3):
-        rng.randn(16, 20)
+        rng.randn(16, widths[2])
         rng.randint(0, 10, (16,))
     losses = []
     for _ in range(3):
-        data = mx.nd.array(rng.randn(16, 20).astype(np.float32))
+        data = mx.nd.array(rng.randn(16, widths[2]).astype(np.float32))
         label = mx.nd.array(
             rng.randint(0, 10, (16,)).astype(np.float32))
         losses.append(float(tr2.fit_batch(data, label).asnumpy()))
@@ -487,7 +713,8 @@ def test_checkpoint_roundtrip_sharded_state(tmp_path):
     assert losses == l_ref[3:]
 
 
-def test_killed_save_elastic_resume_sharded_state(tmp_path):
+@WIDTHS
+def test_killed_save_elastic_resume_sharded_state(tmp_path, widths):
     """The PR 6 tie-in end-to-end: a fault-injected kill during the
     sharded save leaves no usable epoch-1 manifest; resume falls back
     to epoch 0 and re-pads the flat sharded optimizer state for a
@@ -496,7 +723,7 @@ def test_killed_save_elastic_resume_sharded_state(tmp_path):
     from mxnet_tpu.model import latest_checkpoint_scan
     prefix = str(tmp_path / "kill")
     _, _, tr = _dist_run(True, "adam", {"learning_rate": 0.01},
-                         steps=2)
+                         steps=2, widths=widths)
     tr.save_checkpoint(prefix, 0)
     fault.set_plan("ckpt_write:step=1:raise")
     with pytest.raises(Exception):
@@ -508,20 +735,27 @@ def test_killed_save_elastic_resume_sharded_state(tmp_path):
 
     # resume the sharded state on a 2-device mesh
     mesh2 = create_mesh({"dp": 2}, devices=jax.devices()[:2])
-    net = nn.HybridSequential(prefix="gsync_")
-    with net.name_scope():
-        net.add(nn.Dense(32, activation="relu"), nn.Dense(10))
-    net.initialize()
-    _ = net(mx.nd.array(np.zeros((16, 20), np.float32)))
+    net = _dist_net(widths)
     loss = gluon.loss.SoftmaxCrossEntropyLoss()
     tr2 = DistributedTrainer(net, loss, mesh2, optimizer="adam",
                              optimizer_params={"learning_rate": 0.01},
                              grad_overlap=True, bucket_mb=0.001)
     tr2.load_checkpoint(prefix, 0)
     data = mx.nd.array(np.random.RandomState(0)
-                       .randn(16, 20).astype(np.float32))
+                       .randn(16, widths[2]).astype(np.float32))
     label = mx.nd.array(np.random.RandomState(0)
                         .randint(0, 10, (16,)).astype(np.float32))
+    # the moments the 8 chips saved are the moments the 2 chips hold,
+    # parameter by parameter: rows of another length, the same values
+    tr2._build(data, label)          # applies the staged restore
+    shapes = {i: tuple(v.shape) for i, v in enumerate(tr._param_vals)}
+    saved_state = tr._sync_state.export_per_param(shapes)
+    restored = tr2._sync_state.export_per_param(shapes)
+    assert sorted(saved_state) == sorted(restored) == sorted(shapes)
+    for i in shapes:
+        for a, b in zip(saved_state[i], restored[i]):
+            np.testing.assert_array_equal(a, b)
+            assert np.any(a != 0)
     tr2.fit_batch(data, label).asnumpy()     # steps fine post-restore
     # restored state values equal the saved ones (per-param layout
     # bridges the two plans/topologies)
@@ -552,13 +786,24 @@ def test_checkpoint_restore_rejects_changed_bucket_layout(tmp_path):
         np.testing.assert_array_equal(a, np.asarray(v))
 
 
-def test_sharded_state_seed_export_inverse():
+_ODD = [(5, 3), (9001,), (1,), (N_DEV * 1024,), (3, 3)]
+
+
+@pytest.mark.parametrize("shapes,cap", [
+    ([(5, 3), (7,), (2, 2)], 4 * 10),
+    # odd sizes; one fills more than a tile a row, one exactly a tile
+    (_ODD, 4 * 10),
+    # ... and all in ONE bucket: the large members first on whole
+    # tiles, the small ones behind them at their exact share
+    (_ODD, grad_sync.MONOLITH_CAP)],
+    ids=["small", "odd", "odd_one_bucket"])
+def test_sharded_state_seed_export_inverse(shapes, cap):
     """seed_per_param and export_per_param are inverses over the
     bucket layout (the Updater-pickle interchange bridge)."""
     mesh = local_mesh("dp")
-    shapes = [(5, 3), (7,), (2, 2)]
-    plan = GradSyncPlan(shapes, ["float32"] * 3, axis_size=N_DEV,
-                        cap_bytes=4 * 10)
+    n = len(shapes)
+    plan = GradSyncPlan(shapes, ["float32"] * n, axis_size=N_DEV,
+                        cap_bytes=cap)
     st = grad_sync.ShardedOptState(plan, mesh)
     st.n_slots = 2
     st._slot_dtypes = ["float32", "float32"]
@@ -568,12 +813,33 @@ def test_sharded_state_seed_export_inverse():
                  for i, s in enumerate(shapes)}
     st.seed_per_param(per_param)
     out = st.export_per_param({i: s for i, s in enumerate(shapes)})
-    for i in range(3):
+    for i in range(n):
         for k in range(2):
             np.testing.assert_array_equal(per_param[i][k], out[i][k])
+    # on the device: one row a chip
+    for bucket, slots in zip(plan.buckets, st._flats):
+        for arr in slots:
+            assert arr.shape == (N_DEV, bucket.row_len)
+            assert arr.addressable_shards[0].data.shape \
+                == (1, bucket.row_len)
     # checkpoint roster keys follow the manifest naming contract,
-    # plus the bucket-partition fingerprint guarding restores
-    roster = st.checkpoint_roster()
+    # plus the bucket-partition fingerprint guarding restores; the
+    # re-layout to roster order is ONE program for every bucket and
+    # slot, compiled at the first save and not at the second
+    from mxnet_tpu import compile_watch
+    was_on = compile_watch.enabled()
+    compile_watch.enable()
+    try:
+        def compiles():
+            return sum(s["count"] for s in compile_watch.site_stats(
+                "grad_sync:roster_order").values())
+        seen = compiles()
+        roster = st.checkpoint_roster()
+        st.checkpoint_roster()
+        assert compiles() == seen + 1
+    finally:
+        if not was_on:
+            compile_watch.disable()
     assert sorted(roster) == sorted(
         ["opt:bucket%02d.slot%d" % (b, k)
          for b in range(len(plan.buckets)) for k in range(2)]
@@ -581,12 +847,29 @@ def test_sharded_state_seed_export_inverse():
     # load_host_flats re-pads for the current axis: feed back the
     # host values with save-time padding stripped at a DIFFERENT size
     host = {k: np.asarray(v) for k, v in roster.items()}
+    # ... which hold each bucket slot 1-D in ROSTER ORDER, the members
+    # end to end, whatever the rows on the device look like
+    for b, bucket in enumerate(plan.buckets):
+        np.testing.assert_array_equal(
+            host["opt:bucket%02d.slot1" % b][:bucket.total],
+            np.concatenate([per_param[i][1].reshape(-1)
+                            for i in bucket.indices]))
     st2 = grad_sync.ShardedOptState(plan, mesh)
     st2.n_slots, st2._slot_dtypes = 2, ["float32", "float32"]
     st2.load_host_flats(host)
     out2 = st2.export_per_param({i: s for i, s in enumerate(shapes)})
-    for i in range(3):
+    for i in range(n):
         np.testing.assert_array_equal(out[i][0], out2[i][0])
+    # a checkpoint saved on another axis size (here: as one chip would
+    # pad it, not at all) restores to the same moments
+    st3 = grad_sync.ShardedOptState(plan, mesh)
+    st3.n_slots, st3._slot_dtypes = 2, ["float32", "float32"]
+    st3.load_host_flats({k: v if k == "opt:layout"
+                         else v[:plan.buckets[int(k[10:12])].total]
+                         for k, v in host.items()})
+    out3 = st3.export_per_param({i: s for i, s in enumerate(shapes)})
+    for i in range(n):
+        np.testing.assert_array_equal(out[i][1], out3[i][1])
 
 
 # ---------------------------------------------------------------------------
