@@ -873,8 +873,13 @@ def _jnp_latent_decode(q, kv, lengths, rank):
     ``rank`` columns are the compressed K/V, the rest the shared rotary
     key. Scores over all ``W`` columns, float32 softmax over the row's
     ``lengths`` live tokens, the weighted sum over the first ``rank``
-    columns only: ``(B, H, rank)`` float32."""
+    columns only: ``(B, H, rank)`` float32. A ``q (B, Q, H, W)`` is the
+    CAUSAL form over ``Q`` consecutive positions a row
+    (:func:`_jnp_latent_verify`): ``lengths`` then counts query 0's live
+    tokens."""
     import jax.numpy as jnp
+    if q.ndim == 4:
+        return _jnp_latent_verify(q, kv, lengths, rank)
     qf, kf = q.astype(jnp.float32), kv.astype(jnp.float32)
     s = jnp.einsum("bhw,btw->bht", qf, kf)
     live = jax.lax.iota(jnp.int32, kv.shape[1])[None, :] \
@@ -883,6 +888,39 @@ def _jnp_latent_decode(q, kv, lengths, rank):
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     return jnp.einsum("bht,btc->bhc", p, kf[..., :rank])
+
+
+def _mla_fold_page(j, length, q_ref, page_ref, o_ref, acc_ref, m_ref, l_ref,
+                   page_size, n_pages, rank):
+    """What both latent kernels do with table column ``j`` once the
+    step's own rows have opened the accumulation: fold the page's live
+    tokens into the running softmax (every query row of ``q_ref``
+    against the page in one product), and on the last column write the
+    normalised sum out."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    @pl.when(j * page_size < length)
+    def _step():
+        lat = page_ref[:, :rank]                              # (S, rank)
+        s = _dot(q_ref[...], page_ref[...], _NT)              # (rows, S)
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        s = jnp.where(pos < length, s, _NEG)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(lat.dtype),
+                                                   lat)
+
+    @pl.when(j == n_pages - 1)
+    def _finish():
+        # the step's own first row is always live, so l >= its weight > 0
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def _mla_decode_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
@@ -929,27 +967,8 @@ def _mla_decode_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
         l_ref[...] = jnp.ones_like(l_ref)
         acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
 
-    @pl.when(j * page_size < length)
-    def _step():
-        lat = page_ref[:, :rank]                              # (S, rank)
-        s = _dot(q_ref[...], page_ref[...], _NT)              # (H, S)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        s = jnp.where(pos < length, s, _NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(lat.dtype),
-                                                   lat)
-
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        # the new token is always live, so l >= its weight > 0
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    _mla_fold_page(j, length, q_ref, page_ref, o_ref, acc_ref, m_ref, l_ref,
+                   page_size, n_pages, rank)
 
 
 def _pallas_latent_decode(q, kv_new, kv_pages, layer, page_table, lengths,
@@ -1035,6 +1054,164 @@ def _pallas_latent_write(pages, page_idx, slot, new, interpret):
         name="mx_latent_write.b%d.l%d.s%d.d%d.%s" % (
             B, L, S, W, pages.dtype.name),
     )(page_idx, slot, new.reshape(L, B, 1, W), pages)
+
+
+def _jnp_latent_verify(q, kv, lengths, rank):
+    """:func:`_jnp_latent_decode` for ``Q`` consecutive query positions
+    a row, causal among themselves — the verify step of self-speculation
+    and the oracle of ``mx_mla_decode...q<Q>``. ``q (B, Q, H, W)`` scaled;
+    ``kv (B, T, W)`` the row's cache with the step's ``Q`` new rows
+    already in place at their positions; query ``j`` sees the first
+    ``lengths[b] + j`` tokens (its own row the last of them). Returns
+    ``(B, Q, H, rank)`` float32."""
+    import jax.numpy as jnp
+    qf, kf = q.astype(jnp.float32), kv.astype(jnp.float32)
+    s = jnp.einsum("bqhw,btw->bqht", qf, kf)
+    seen = jnp.asarray(lengths, jnp.int32)[:, None] \
+        + jax.lax.iota(jnp.int32, q.shape[1])[None, :]           # (B, Q)
+    live = jax.lax.iota(jnp.int32, kv.shape[1])[None, None, :] \
+        < seen[:, :, None]
+    s = jnp.where(live[:, :, None, :], s, _NEG)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bqht,btc->bqhc", p, kf[..., :rank])
+
+
+def _mla_verify_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
+                       acc_ref, m_ref, l_ref, *, page_size, n_pages, rank,
+                       n_new, n_heads):
+    """:func:`_mla_decode_kernel` for ``n_new`` consecutive query
+    positions a row: the ``n_new * n_heads`` query vectors of one row
+    (position-major: row ``j * n_heads + h``) are the rows of ONE
+    ``(Q*H, W) x (W, S)`` product against each live page — 64 rows at 2
+    positions of 32 heads, where one position fills a quarter of the
+    MXU's rows. ``len_ref[b]`` counts the row's tokens IN THE POOL, all
+    visible to every query; the step's own ``n_new`` latents
+    (``new_ref``, not in the pool yet) open the accumulation on the VPU,
+    folded in TRIANGULARLY: new row ``k`` is visible to query ``j`` iff
+    ``k <= j`` (row 0 to both, row 1 to the second only)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _init():
+        q = q_ref[...].astype(f32)                            # (Q*H, W)
+        new = new_ref[...].astype(f32)                        # (Q, W)
+        query = jax.lax.broadcasted_iota(
+            jnp.int32, (n_new * n_heads, 1), 0) // n_heads
+        s = [jnp.where(query >= k,
+                       jnp.sum(q * new[k:k + 1], axis=-1, keepdims=True),
+                       _NEG) for k in range(n_new)]
+        m = functools.reduce(jnp.maximum, s)     # row 0 is always seen
+        p = [jnp.exp(sk - m) for sk in s]
+        m_ref[...] = m
+        l_ref[...] = functools.reduce(jnp.add, p)
+        acc_ref[...] = functools.reduce(
+            jnp.add, [pk * new[k:k + 1, :rank] for k, pk in enumerate(p)])
+
+    _mla_fold_page(j, length, q_ref, page_ref, o_ref, acc_ref, m_ref, l_ref,
+                   page_size, n_pages, rank)
+
+
+def _pallas_latent_verify(q, kv_new, kv_pages, layer, page_table, lengths,
+                          rank, interpret):
+    """``q (B, Q, H, W)`` scaled, in the pool's dtype; ``kv_new (B, Q,
+    W)``; the WHOLE pool ``(L, P, S, W)``; ``page_table (B, M)`` and
+    ``lengths (B,)`` (the tokens in the pool, the same for every query
+    of a row) ride scalar prefetch. Returns ``(B, Q, H, rank)``
+    float32."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Q, H, W = q.shape
+    S = kv_pages.shape[2]
+    M = page_table.shape[1]
+    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
+    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
+    page_table = jnp.take_along_axis(page_table, columns, axis=1)
+
+    out = pl.pallas_call(
+        functools.partial(_mla_verify_kernel, page_size=S, n_pages=M,
+                          rank=rank, n_new=Q, n_heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, M),
+            in_specs=[
+                pl.BlockSpec((None, Q * H, W),
+                             lambda b, j, tbl, lens: (b, 0, 0)),
+                pl.BlockSpec((None, Q, W), lambda b, j, tbl, lens: (b, 0, 0)),
+                pl.BlockSpec((None, None, S, W),
+                             lambda b, j, tbl, lens:
+                             (layer, tbl[b * M + j], 0, 0))],
+            out_specs=pl.BlockSpec((None, Q * H, rank),
+                                   lambda b, j, tbl, lens: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((Q * H, rank), jnp.float32),
+                            pltpu.VMEM((Q * H, 1), jnp.float32),
+                            pltpu.VMEM((Q * H, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Q * H, rank), jnp.float32),
+        interpret=interpret,
+        # rows x query positions x heads, the query positions a row, the
+        # table's full width in keys, the latent's width
+        name="mx_mla_decode.bh%d.q%d.k%d.d%d.%s.r%d.paged" % (
+            B * Q * H, Q, M * S, W, jnp.dtype(kv_pages.dtype).name, rank),
+    )(page_table.reshape(-1), lengths, q.reshape(B, Q * H, W), kv_new,
+      kv_pages)
+    return out.reshape(B, Q, H, rank)
+
+
+def _latent_write_rows_kernel(pg_ref, base_ref, new_ref, page_ref, out_ref,
+                              *, n_new):
+    """One program instance puts one row's ``n_new`` consecutive new
+    latents of one layer into ONE of the pages they touch: the page
+    comes in whole and leaves whole. ``base_ref[b * n_new + k]`` is the
+    slot new row 0 WOULD have in the page that holds new row ``k`` (-1
+    in the second page of a pair that straddles a boundary), so the
+    same selects serve both pages; where all rows share a page the
+    instances of one row name the same block, compute the same page,
+    and it is fetched and written back once."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    del pg_ref
+    base = base_ref[pl.program_id(0) * n_new + pl.program_id(2)]
+    rows = jax.lax.broadcasted_iota(jnp.int32, page_ref.shape, 0)
+    out = page_ref[...]
+    for i in range(n_new):
+        out = jnp.where(rows == base + i, new_ref[i:i + 1, :], out)
+    out_ref[...] = out
+
+
+def _pallas_latent_write_rows(pages, page_idx, base, new, interpret):
+    """``mx_latent_write`` for ``Q`` consecutive rows a row that may
+    STRADDLE a page boundary: ``new (L, B, Q, W)`` into the pool ``(L, P,
+    S, W)``, in place. ``page_idx (B, Q)`` names the page of each new
+    row and ``base (B, Q)`` the slot new row 0 would have there
+    (:func:`_latent_write_rows_kernel`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    L, P, S, W = pages.shape
+    B, Q = new.shape[1], new.shape[2]
+    page = pl.BlockSpec((None, None, S, W),
+                        lambda b, l, k, pg, bs: (l, pg[b * Q + k], 0, 0))
+    return pl.pallas_call(
+        functools.partial(_latent_write_rows_kernel, n_new=Q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, L, Q),
+            in_specs=[pl.BlockSpec((None, None, Q, W),
+                                   lambda b, l, k, pg, bs: (l, b, 0, 0)),
+                      page],
+            out_specs=page),
+        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        name="mx_latent_write.b%d.q%d.l%d.s%d.d%d.%s" % (
+            B, Q, L, S, W, pages.dtype.name),
+    )(page_idx.reshape(-1), base.reshape(-1), new, pages)
 
 
 # ---------------------------------------------------------------------------

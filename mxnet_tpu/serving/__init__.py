@@ -31,9 +31,13 @@ KV cache, streaming, priorities, live weight swap) lives in
 
 Three models implement the decode-model contract: ``ToyDecoderLM``
 (per-head K/V, one position a step), ``latent_moe.LatentMoEDecoderLM``
-(a latent cache, routed experts) and ``block_diffusion.
-BlockDiffusionMoEDecoderLM`` (the BLOCK form of the contract: generation
-by diffusion over blocks, grouped-query K/V, softmax-routed experts).
+(a latent cache, routed experts; with ``hc_mult`` > 1 several residual
+streams mixed by hyper-connections, and with its next-token module the
+SPECULATIVE form of the contract: the module drafts one token, a
+two-position verify step accepts it or overwrites it) and
+``block_diffusion.BlockDiffusionMoEDecoderLM`` (the BLOCK form of the
+contract: generation by diffusion over blocks, grouped-query K/V,
+softmax-routed experts).
 
 Fleet serving — a :class:`Router` fronting N decode replicas with
 per-tenant weighted-fair quotas, graceful drain, and transparent

@@ -166,7 +166,60 @@ decides from the unread step's output) and ``keys_live``,
 and preemption work as for any model (a block in the making is never
 cached, so a row ended in mid-block leaves nothing behind); prefix
 sharing (its suffix feed is one token a step), an int8 pool and a
-latent pool are refused with a typed error when the server is built.
+latent pool (whose block form is causal, not all-see-all) are refused
+with a typed error when the server is built.
+
+**The speculative form of the contract** (``serving.latent_moe.
+LatentMoEDecoderLM`` with its next-token module): a model that drafts
+for itself declares ``draft_length`` (1: one draft a step) and, in
+``decode``'s place,
+
+- ``model.verify(params, tokens, positions, attend) -> (logits, hidden,
+  *new[, counters])`` — the main model over ``tokens (B, Q)``, ``Q =
+  draft_length + 1`` consecutive positions from ``positions[b]`` on,
+  CAUSAL; ``attend`` is the layout's causal block form
+  (``kvcache.paged_latent_causal_attention``); ``hidden (B, Q, D)`` is
+  what the drafter reads; one ``new`` a declared array, ``(n_layers, B,
+  Q, *trailing)``;
+- ``model.draft(params, hidden, tokens, positions, attend) -> (logits,
+  *new[, counters])`` — the drafter over the same ``Q`` positions:
+  position ``j`` reads ``hidden[:, j]`` and the token AFTER it,
+  ``tokens[:, j]``; its cache layers lie behind the main model's
+  (``model.cache_layers`` sizes the pool: ``n_layers`` + the drafter's);
+- ``model.prefill_draft(params, tokens) -> (logits, hidden, *seqs)`` and
+  ``model.draft_prefill(params, hidden, tokens) -> (logits, *seqs)``,
+  the same two over a whole prompt.
+
+Verification is greedy, so the served stream is the model's plain
+greedy stream token for token, whatever the drafter says. A row holds
+its last confirmed token ``x`` at position ``p`` and a draft ``d`` for
+``p + 1``. The server's ONE step program (``_spec_decode_fn``) runs, for
+every row: the main model over ``[x@p, d@p+1]``, whose argmax ``y1, y2``
+are the true tokens at ``p + 1`` and — if ``y1 == d``, the draft
+ACCEPTED — at ``p + 2``; then the drafter over ``[(h0, y1)@p, (h1,
+y2)@p+1]``, whose argmax at the second position (accepted) or the first
+(rejected) is the next draft. Out, a row: ``y1``, ``y2``, accepted, the
+next draft, the next position (``p + 1`` or ``p + 2``). Both new rows
+are always written, in every layer; nothing is rolled back: the next
+step starts at the first unconfirmed position and overwrites. **A row's
+position is decided on the device**: tokens, draft and position are fed
+from the unread step's output (``prev``/``src``), so the host, which
+dispatches a step ahead, keeps pages provisioned for the furthest case
+(through ``p + 3`` of the last position it has READ) and learns the
+truth a step late; the read-back hands out one or two tokens, and
+``max_new_tokens`` / ``eos_id`` cut the second. The prefill program
+runs main model and drafter over the prompt, fills both caches and
+emits the first token and the first draft. ``stats()["spec"]`` sums
+``drafts_verified``, ``drafts_accepted``, ``tokens_out`` and
+``positions_run``; ``DecodeRequest.drafts`` keeps for each token the
+draft that was verified against it (-1: none — the prefill's token, an
+accepted draft's bonus token); ``mx:decode.dispatch`` carries
+``keys_live`` (the keys the host KNOWS to be live: a lower bound) and
+``undecided`` (rows whose position the program takes from the unread
+step), ``mx:decode.readback`` ``accepted`` and ``tokens``. Prefix
+sharing (its suffix feed is one token a step), an int8 pool and a
+per-head pool (no causal block form) are refused with a typed error
+when the server is built.
 
 Sampling is greedy (argmax, in-program): deterministic by
 construction, which is what makes "prefill + stepwise cached decode
@@ -197,6 +250,9 @@ from .server import (RequestTimeoutError, ServerClosedError,
 __all__ = ["DecodeServer", "DecodeRequest", "ToyDecoderLM"]
 
 _DONE = object()          # stream sentinel
+# what a speculative step says of a row: y1, y2, accepted, the next
+# draft, the next position
+_SPEC_OUT = 5
 
 
 class _ParamsVersion:
@@ -243,7 +299,8 @@ class DecodeRequest:
                  "_error", "_last_emit", "trace_args",
                  "_t_trace", "pending", "pending_pos", "prefix_cached",
                  "unread", "blk_start", "blk_x", "blk_masked",
-                 "blk_when", "blk_pass", "unmask_pass")
+                 "blk_when", "blk_pass", "unmask_pass", "draft",
+                 "drafts")
 
     def __init__(self, prompt, max_new, priority, deadline, eos_id,
                  request_id):
@@ -288,6 +345,11 @@ class DecodeRequest:
         self.blk_x = self.blk_masked = self.blk_when = None
         self.blk_pass = 0
         self.unmask_pass = []
+        # a speculative model's row: the draft for the position after
+        # the last token READ BACK, and for each generated token the
+        # draft that was verified against it (-1: none)
+        self.draft = 0
+        self.drafts = []
 
     def done(self):
         return self._event.is_set()
@@ -508,9 +570,11 @@ class DecodeServer:
         import jax
         from .. import compile_watch
         self._block = int(getattr(model, "block_length", 0) or 0)
+        self._spec = int(getattr(model, "draft_length", 0) or 0)
         for attr in ("prefill", "n_layers") + (
                 ("decode_block", "unmask", "mask_token_id") if self._block
-                else ("decode",)):
+                else ("verify", "draft", "prefill_draft", "draft_prefill")
+                if self._spec else ("decode",)):
             if not hasattr(model, attr):
                 raise MXNetError(
                     "DecodeServer: model lacks %r — the decode-model "
@@ -522,8 +586,13 @@ class DecodeServer:
                     "declares one latent array; a model with a "
                     "block_length has decode_block, unmask and "
                     "mask_token_id in decode's place: "
-                    "serving.block_diffusion)" % attr)
+                    "serving.block_diffusion; a model with a "
+                    "draft_length has verify, draft, prefill_draft and "
+                    "draft_prefill: serving.latent_moe)" % attr)
         specs, cache_dtype = kvcache.declared_arrays(model)
+        # a model that drafts for itself caches its drafter's layers
+        # behind its own
+        n_layers = int(getattr(model, "cache_layers", model.n_layers))
         self._counters = getattr(model, "step_counters", None)
         self._model = model
         self.name = name
@@ -549,17 +618,15 @@ class DecodeServer:
                     "DecodeServer: page_size=%d does not match the "
                     "shared pool's %d" % (int(page_size),
                                           pool.page_size))
-            if (pool.n_layers, pool.array_specs) != \
-                    (int(model.n_layers), specs):
+            if (pool.n_layers, pool.array_specs) != (n_layers, specs):
                 raise MXNetError(
                     "DecodeServer: shared pool geometry (layers=%d, "
                     "arrays=%s) does not match the model's (%d, %s) — "
                     "co-tenant models must agree on the page shape"
-                    % (pool.n_layers, pool.array_specs, model.n_layers,
-                       specs))
+                    % (pool.n_layers, pool.array_specs, n_layers, specs))
             self._pool = pool
         else:
-            self._pool = KVCachePool(model.n_layers, arrays=specs,
+            self._pool = KVCachePool(n_layers, arrays=specs,
                                      page_size=page_size,
                                      n_pages=pool_pages,
                                      dtype=cache_dtype,
@@ -574,11 +641,16 @@ class DecodeServer:
         self._preempt_asks = 0    # co-tenant give-back requests pending
         if self._block:
             self._check_block_model()
+        if self._spec:
+            self._check_spec_model()
         # prompt rungs fill whole pages; the table width covers the
         # longest prompt plus the full generation budget, so any
-        # admitted request fits its table by construction
+        # admitted request fits its table by construction (a speculative
+        # step dispatched ahead of a row's last writes up to two
+        # positions past its budget: the table covers them too)
         self._seq_ladder = seq_ladder.aligned(self._pool.page_size)
-        self._max_context = self._seq_ladder.max_batch + self._max_new
+        self._max_context = self._seq_ladder.max_batch + self._max_new \
+            + (self._spec + 1 if self._spec else 0)
         model_reach = getattr(model, "max_len", None)
         if model_reach is not None and self._max_context > model_reach:
             raise MXNetError(
@@ -624,15 +696,18 @@ class DecodeServer:
                                                          first + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
         # ONE step program and one prefill program a rung, whatever the
-        # kind of model: a block model's are the block forms
+        # kind of model: a block model's are the block forms, a
+        # self-drafting model's the speculative ones
         self._decode_prog = compile_watch.jit(
-            self._block_decode_fn if self._block else self._decode_fn,
+            self._block_decode_fn if self._block
+            else self._spec_decode_fn if self._spec else self._decode_fn,
             "%s:step" % site,
             statics=(site, self._window, self._max_pages), **step_donate)
         self._prefill_progs = {}
         for rung in self._seq_ladder.buckets:
             self._prefill_progs[rung] = compile_watch.jit(
                 self._block_prefill_fn if self._block
+                else self._spec_prefill_fn if self._spec
                 else self._prefill_fn,
                 "%s:prefill:s%d" % (site, rung),
                 statics=(site, "prefill", rung), **donate)
@@ -666,6 +741,10 @@ class DecodeServer:
         self._blocks = {"denoise_passes": 0, "commit_passes": 0,
                         "tokens_unmasked": 0, "blocks_committed": 0,
                         "max_passes_a_block": 0}
+        # a speculative model's steps, summed over the rows of every
+        # step read
+        self._specs = {"drafts_verified": 0, "drafts_accepted": 0,
+                       "tokens_out": 0, "positions_run": 0}
         # the decode step that is dispatched and not yet read back (the
         # scheduler reads one step behind), why it was read before the
         # next was planned whenever it was (``stats()["decode_drains"]``),
@@ -677,6 +756,7 @@ class DecodeServer:
         n_counts = len(self._counters[1]) if self._counters else 0
         self._no_prev = jax.device_put(
             _np.zeros((self._window * (self._block + 2 if self._block
+                                       else _SPEC_OUT if self._spec
                                        else 1) + n_counts,), _np.int32),
             self._device)
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
@@ -765,9 +845,11 @@ class DecodeServer:
             raise MXNetError(
                 "DecodeServer: a block model (block_length %d) cannot "
                 "run over a %s pool: a commit writes %d rows a row, which "
-                "an int8 page would have to requantize, and there is no "
-                "block form of latent attention — give it a float "
-                "per-head pool" % (B, type(self._pool.layout).__name__, B))
+                "an int8 page would have to requantize, and the block "
+                "form of a latent pool is causal (a speculative step's), "
+                "not the all-see-all block of a diffusion model — give it "
+                "a float per-head pool"
+                % (B, type(self._pool.layout).__name__, B))
         if self._prefix_on:
             raise MXNetError(
                 "DecodeServer: prefix sharing feeds a prompt's un-cached "
@@ -830,6 +912,99 @@ class DecodeServer:
         if len(new) > len(layout.specs):
             out = jnp.concatenate(
                 [out, new[-1].astype(jnp.int32).reshape(-1)])
+        return (out, *pools)
+
+    # -- the speculative forms (a model with ``draft_length``) -------------
+    def _check_spec_model(self):
+        """What a self-drafting model cannot do is refused here, when the
+        server is built, with a typed error — never a wrong token later."""
+        if self._spec != 1:
+            raise MXNetError(
+                "DecodeServer: draft_length %d — the speculative step "
+                "verifies ONE draft a row (two positions)" % self._spec)
+        if not self._pool.layout.causal_blocks:
+            raise MXNetError(
+                "DecodeServer: a self-drafting model (draft_length %d) "
+                "cannot run over a %s pool: its step attends and writes "
+                "%d consecutive positions a row, causal among themselves, "
+                "and that block form exists for a float latent pool only "
+                "(an int8 page would have to requantize)"
+                % (self._spec, type(self._pool.layout).__name__,
+                   self._spec + 1))
+        if self._prefix_on:
+            raise MXNetError(
+                "DecodeServer: prefix sharing feeds a prompt's un-cached "
+                "suffix through the step ONE token at a time and leaves "
+                "the drafter's cache unfilled, which a self-drafting model "
+                "(draft_length %d) has no step for — build it with "
+                "prefix_cache=False" % self._spec)
+
+    def _spec_prefill_fn(self, params, tokens, n_valid, page_table,
+                         *pools):
+        """A self-drafting model's prefill: the main model over the
+        prompt, then the drafter over it — position ``i`` reads the main
+        model's state at ``i`` and the token after it, the last one the
+        first token the prefill itself emits. Both caches are written;
+        out, the first token and the first draft."""
+        import jax.numpy as jnp
+        layout = kvcache.layout_for(self._model, pools)
+        n = len(layout.specs)
+        logits, hidden, *seqs = self._model.prefill_draft(params, tokens)
+        token = jnp.argmax(jnp.take(logits[0], n_valid - 1, axis=0)) \
+            .astype(jnp.int32)
+        after = jnp.roll(tokens, -1, axis=1).at[0, n_valid - 1].set(token)
+        d_logits, *d_seqs = self._model.draft_prefill(params, hidden, after)
+        draft = jnp.argmax(jnp.take(d_logits[0], n_valid - 1, axis=0)) \
+            .astype(jnp.int32)
+        seqs = [jnp.concatenate([a, b]) for a, b in zip(seqs[:n], d_seqs)]
+        pools = layout.write_prefill(pools, page_table, seqs, n_valid)
+        return (jnp.stack([token, draft]), *pools)
+
+    def _spec_decode_fn(self, params, tokens, positions, page_tables,
+                        prev, src, *pools):
+        """The speculative step program (module docstring): every row
+        verifies its draft and drafts the next. ``tokens (D, 2)`` are
+        ``[x, d]``, the row's last confirmed token and its draft for
+        the position after, ``positions (D,)`` x's; a row whose step
+        before is unread takes all three from that step's output where
+        it lies, ``prev`` at slot ``src[i]`` — the host does not know
+        whether that step accepted. Out, a row: ``y1, y2, accepted, next
+        draft, next position``; then the model's counters."""
+        import jax.numpy as jnp
+        D = self._window
+        fed = prev[:D * _SPEC_OUT].reshape(D, _SPEC_OUT)[jnp.maximum(src, 0)]
+        ahead = src >= 0
+        x = jnp.where(ahead, jnp.where(fed[:, 2] > 0, fed[:, 1], fed[:, 0]),
+                      tokens[:, 0])
+        d = jnp.where(ahead, fed[:, 3], tokens[:, 1])
+        positions = jnp.where(ahead, fed[:, 4], positions)
+        layout = kvcache.layout_for(self._model, pools)
+        n = len(layout.specs)
+        attend = layout.attend_causal(pools, page_tables, positions)
+        logits, hidden, *new = self._model.verify(
+            params, jnp.stack([x, d], axis=1), positions, attend)
+        y = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # (D, 2)
+        accepted = y[:, 0] == d
+        d_logits, *d_new = self._model.draft(
+            params, hidden, y, positions, attend)
+        drafts = jnp.argmax(d_logits, axis=-1).astype(jnp.int32)
+        pools = layout.write_causal(
+            pools, page_tables, positions,
+            [jnp.concatenate([a, b]) for a, b in zip(new[:n], d_new)],
+            getattr(self._model, "use_pallas", False))
+        out = jnp.stack(
+            [y[:, 0], y[:, 1], accepted.astype(jnp.int32),
+             jnp.where(accepted, drafts[:, 1], drafts[:, 0]),
+             positions + 1 + accepted], axis=1).reshape(-1)
+        if len(new) > n:
+            # the two passes' counters as one: a name that starts with
+            # ``max`` keeps the larger, the others add up
+            a = new[-1].astype(jnp.int32).reshape(-1)
+            b = d_new[-1].astype(jnp.int32).reshape(-1)
+            largest = _np.asarray([name.startswith("max")
+                                   for name in self._counters[1]])
+            out = jnp.concatenate(
+                [out, jnp.where(largest, jnp.maximum(a, b), a + b)])
         return (out, *pools)
 
     # copy-on-write page copy — the whole split is one traced program
@@ -984,6 +1159,8 @@ class DecodeServer:
                     feed = (_np.zeros((self._window, self._block),
                                       _np.int32),
                             _np.full((self._window,), -1, _np.int32), pos)
+                elif self._spec:
+                    feed = (_np.zeros((self._window, 2), _np.int32), pos)
                 else:
                     feed = (_np.zeros((self._window,), _np.int32), pos)
                 # twice: fed nothing, then fed its own token array, the
@@ -1502,7 +1679,12 @@ class DecodeServer:
                 # reference)
                 self._pool.prefix_insert(self._namespace(ver),
                                          req.prompt, req.pages)
-            tok = int(out[0])
+            if self._spec:
+                # the first token and the first draft, one read
+                tok, req.draft = (int(t) for t in _np.asarray(out[0]))
+                req.drafts.append(-1)
+            else:
+                tok = int(out[0])
         req._last_emit = pre.t1
         if req.trace_args is not None:
             rtid = tracing.track("req %s" % req.trace_args["request_id"])
@@ -1538,10 +1720,15 @@ class DecodeServer:
             failed = False
             while True:
                 # (a block model: the last position of the block the
-                # next step works on, which it may commit)
+                # next step works on, which it may commit; a
+                # self-drafting one: the furthest its next step can
+                # write, two positions from where it starts, which is
+                # one or two past the start of a step still unread)
                 wp = r.pending_pos if r.pending \
                     else self._next_block(r)[0] + self._block - 1 \
                     if self._block \
+                    else self._spec_position(r) + 1 + 2 * r.unread \
+                    if self._spec \
                     else len(r.prompt) + len(r.generated) + r.unread - 1
                 needed = wp // self._pool.page_size + 1
                 while len(r.pages) < needed:
@@ -1698,6 +1885,9 @@ class DecodeServer:
         if self._block:
             return self._dispatch_step(
                 ver, rows, *self._build_block_step(rows, prev), prev)
+        if self._spec:
+            return self._dispatch_step(
+                ver, rows, *self._build_spec_step(rows, prev), prev)
         with tracing.span("decode.build"):
             tokens = _np.zeros((D,), _np.int32)
             positions = _np.zeros((D,), _np.int32)
@@ -1856,11 +2046,7 @@ class DecodeServer:
                 counts = {"tokens_unmasked": sum(map(len, unmasked)),
                           "blocks_committed": sum(
                               int(out[i, B + 1] == 2) for i, _r in live)}
-                model_counts = None
-                if self._counters is not None:
-                    model_counts = dict(zip(
-                        self._counters[1],
-                        (int(c) for c in toks[D * (B + 2):])))
+                model_counts = self._model_counts(toks, D * (B + 2))
                 back.set(**counts, **(model_counts or {}))
         except Exception as exc:       # noqa: BLE001 — the step's error
             self._retire(step.rows, exc)
@@ -1895,12 +2081,7 @@ class DecodeServer:
             emit.set(emitted=len(pushed))
             with self._cond:
                 st, bl = self._stats, self._blocks
-                st["decode_steps"] += 1
-                st["decode_steps_ahead"] += step.ahead
-                st["decode_pages_live"] += step.pages_live
-                st["decode_pages_table"] += D * self._max_pages
-                if model_counts is not None:
-                    self._count_step(model_counts)
+                self._note_step_locked(step, model_counts)
                 bl["commit_passes"] += counts["blocks_committed"]
                 bl["denoise_passes"] += \
                     len(live) - counts["blocks_committed"]
@@ -1917,6 +2098,99 @@ class DecodeServer:
                     # the commit that follows
                     bl["max_passes_a_block"] = max(
                         bl["max_passes_a_block"], r.blk_pass + 1)
+            self._retire(finished, None)
+
+    # -- a self-drafting model's rows ---------------------------------------
+    # The host keeps each row AS OF THE LAST STEP READ BACK: its tokens
+    # (the last is the next step's ``x``) and its draft. A step still
+    # unread starts at the position that follows from them; whether it
+    # accepts its draft is decided on the device, so the step dispatched
+    # behind it takes tokens, draft AND position from its output, and
+    # the host only knows that position to within one.
+    def _spec_position(self, r):
+        """Where the step after the last one READ starts: the position
+        of the row's last confirmed token."""
+        return len(r.prompt) + len(r.generated) - 1
+
+    def _build_spec_step(self, rows, prev):
+        """The host's arrays of one speculative step, what its dispatch
+        span says, and the slots fed from the unread step."""
+        D, M = self._window, self._max_pages
+        with tracing.span("decode.build"):
+            tokens = _np.zeros((D, 2), _np.int32)
+            positions = _np.zeros((D,), _np.int32)
+            pts = _np.zeros((D, M), _np.int32)
+            src = _np.full((D,), -1, _np.int32)
+            slots = {} if prev is None else prev.slots
+            for i, r in enumerate(rows):
+                # the least the row's position can be: one past the
+                # start of a step still unread
+                positions[i] = self._spec_position(r) + r.unread
+                if r.unread:
+                    src[i] = slots[id(r)]
+                else:
+                    tokens[i] = r.generated[-1], r.draft
+                pts[i, :len(r.pages)] = r.pages
+            n = len(rows)
+            pages_live = int(((positions[:n] + 1)
+                              // self._pool.page_size + 1).sum())
+        # ``keys_live``: the keys in the pool that the step's rows attend
+        # to AT THE LEAST (an undecided row may be one further on)
+        said = {"keys_live": int(positions[:n].sum()),
+                "undecided": int((src[:n] >= 0).sum())}
+        return [1] * n, (tokens, positions, pts), src, pages_live, said
+
+    def _read_spec(self, step):
+        """:meth:`_read` for a speculative step: a row hands out ``y1``
+        and, where its draft was accepted, ``y2``; ``max_new`` and
+        ``eos_id`` cut the second. The row's next draft is kept for the
+        step after the next one read."""
+        D = self._window
+        for r in step.rows:
+            r.unread -= 1
+        try:
+            with tracing.span("decode.readback") as back:
+                toks, step.toks = _np.asarray(step.toks), None
+                out = toks[:D * _SPEC_OUT].reshape(D, _SPEC_OUT)
+                live = [(i, r) for i, r in enumerate(step.rows)
+                        if r.state == "active"]
+                accepted = sum(int(out[i, 2]) for i, _r in live)
+                model_counts = self._model_counts(toks, D * _SPEC_OUT)
+                back.set(accepted=accepted, tokens=len(live) + accepted,
+                         **(model_counts or {}))
+        except Exception as exc:       # noqa: BLE001 — the step's error
+            self._retire(step.rows, exc)
+            self._read_unread("error")
+            return
+        now = back.t1
+        with tracing.span("decode.emit", rows=len(step.rows)) as emit:
+            self._bill_step(step)
+            pushed, finished = [], []
+            for i, r in live:
+                y1, y2, took, r_draft = (int(v) for v in out[i, :4])
+                for tok, against in ((y1, r.draft), (y2, -1))[:1 + took]:
+                    r.generated.append(tok)
+                    r.drafts.append(against)
+                    r._push(tok)
+                    pushed.append(r)
+                    if len(r.generated) >= r.max_new or \
+                            (r.eos_id is not None and tok == r.eos_id):
+                        finished.append(r)
+                        break
+                r.draft = r_draft
+            emit.set(emitted=len(pushed))
+            with self._cond:
+                st, sp = self._stats, self._specs
+                self._note_step_locked(step, model_counts)
+                sp["drafts_verified"] += len(live)
+                sp["drafts_accepted"] += accepted
+                sp["positions_run"] += len(live) * (self._spec + 1)
+                sp["tokens_out"] += len(pushed)
+                for r in pushed:
+                    st["tokens_out"] += 1
+                    if r._last_emit is not None:
+                        self._intervals.append((now - r._last_emit) * 1e3)
+                    r._last_emit = now
             self._retire(finished, None)
 
     def _retire(self, rows, error):
@@ -1956,6 +2230,8 @@ class DecodeServer:
         device's order."""
         if self._block:
             return self._read_block(step)
+        if self._spec:
+            return self._read_spec(step)
         D = self._window
         for r, emits in zip(step.rows, step.emits):
             r.unread -= emits
@@ -1965,14 +2241,10 @@ class DecodeServer:
                 # token array goes here, so that freeing it (0.3 ms on
                 # the chip) is timed
                 toks, step.toks = _np.asarray(step.toks), None
-                counts = None
-                if self._counters is not None:
-                    # what the model counted in this step (its
-                    # routing) exists only now: it rides this span,
-                    # not the dispatch
-                    counts = dict(zip(self._counters[1],
-                                      (int(c) for c in toks[D:])))
-                    back.set(**counts)
+                # what the model counted in this step (its routing)
+                # exists only now: it rides this span, not the dispatch
+                counts = self._model_counts(toks, D)
+                back.set(**(counts or {}))
         except Exception as exc:       # noqa: BLE001 — the step's error
             self._retire(step.rows, exc)
             # whatever was fed from the failed step fails with it
@@ -1988,12 +2260,7 @@ class DecodeServer:
             emit.set(emitted=len(emitting))
             finished = []
             with self._cond:
-                self._stats["decode_steps"] += 1
-                self._stats["decode_steps_ahead"] += step.ahead
-                self._stats["decode_pages_live"] += step.pages_live
-                self._stats["decode_pages_table"] += D * self._max_pages
-                if counts is not None:
-                    self._count_step(counts)
+                self._note_step_locked(step, counts)
                 for i, r in emitting:
                     self._stats["tokens_out"] += 1
                     if r._last_emit is not None:
@@ -2015,9 +2282,13 @@ class DecodeServer:
             self._retire(finished, None)
 
     def _bill_step(self, step):
-        """The dispatched step program ran ONE batch: each request it
-        still serves is billed an equal share of the program's
-        cost_analysis FLOPs (while metering is on)."""
+        """The dispatched step program ran ONE batch, every row of it
+        the same number of positions (one; a block; a draft and its
+        verification): each request it still serves is billed an equal
+        share of the program's cost_analysis FLOPs (while metering is
+        on) — by the positions it ran, not by the tokens it was handed:
+        a rejected draft cost what an accepted one did, and the tokens
+        are ``stats()["tokens_out"]``'s to count."""
         if not metering.enabled():
             return
         cost = compile_watch.last_dispatch("%s:step" % self._site)
@@ -2028,6 +2299,27 @@ class DecodeServer:
                 metering.request_flops(
                     metering.inner_key(self, r.request_id),
                     cost["flops"] * share, cost["bytes"] * share)
+
+    def _model_counts(self, toks, first):
+        """What the model counted in a step, by name, from the step's
+        output behind its ``first`` token values; None for a model that
+        counts nothing."""
+        if self._counters is None:
+            return None
+        return dict(zip(self._counters[1],
+                        (int(c) for c in toks[first:])))
+
+    def _note_step_locked(self, step, counts):
+        """One step READ BACK into ``stats()`` (under ``self._cond``):
+        the step itself, whether it was dispatched ahead, the pages it
+        had to stream, and the model's own counters."""
+        st = self._stats
+        st["decode_steps"] += 1
+        st["decode_steps_ahead"] += step.ahead
+        st["decode_pages_live"] += step.pages_live
+        st["decode_pages_table"] += self._window * self._max_pages
+        if counts is not None:
+            self._count_step(counts)
 
     def _count_step(self, counts):
         """One decode step's model counters into the running totals
@@ -2072,6 +2364,7 @@ class DecodeServer:
             shed_pri = dict(self._shed_by_priority)
             counted = dict(self._counted)
             blocks = dict(self._blocks)
+            specs = dict(self._specs)
             drains = dict(self._drains)
         steps = s["prefill_steps"] + s["decode_steps"]
         out = {
@@ -2127,6 +2420,8 @@ class DecodeServer:
             out[self._counters[0]] = counted
         if self._block:
             out["block"] = blocks
+        if self._spec:
+            out["spec"] = specs
         if shed_pri:
             out["shed_by_priority"] = {str(k): v for k, v
                                        in sorted(shed_pri.items())}

@@ -36,7 +36,10 @@ same two as int8 pages, with their per-page scales carried beside them;
 a latent-attention model declares ONE array ``(n_layers, n_pages,
 page_size, W)`` whose row is the compressed K/V and the shared rotary
 key (:func:`paged_latent_attention`, :func:`write_prefill_pages`,
-:func:`write_token_rows`); per-head K and V whose FEW heads would not
+:func:`write_token_rows` — and, for a step that runs a few CONSECUTIVE
+positions a row, causal among themselves, :func:`paged_latent_causal_
+attention` and :func:`write_latent_rows`: a speculative step's verify
+and draft passes); per-head K and V whose FEW heads would not
 fill a 16-bit dtype's sublane tile (grouped-query attention: 4
 key/value heads under 32 query heads) are packed into one row of ``H *
 D`` lanes a token, ``(n_layers, n_pages, page_size, H * D)``
@@ -127,7 +130,9 @@ from ..base import MXNetError
 
 __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
            "paged_attention", "paged_latent_attention",
-           "paged_block_attention", "write_block_rows", "cache_layout",
+           "paged_block_attention", "write_block_rows",
+           "paged_latent_causal_attention", "write_latent_rows",
+           "cache_layout",
            "declared_arrays", "layout_for",
            "scatter_token", "scatter_prefill", "write_prefill_pages",
            "write_token_rows",
@@ -360,6 +365,88 @@ def paged_latent_attention(kv_pages, page_table, positions, layer, q,
     return _dispatch("mla_decode", rank, (kv_pages.shape[2],),
                      force_pallas, kernel, composed, q, kv_new, kv_pages,
                      table, pos)
+
+
+def paged_latent_causal_attention(kv_pages, page_table, positions, layer,
+                                   q, kv_new, *, rank, scale,
+                                   force_pallas=False):
+    """:func:`paged_latent_attention` for a step that runs ``Q``
+    CONSECUTIVE query positions a row, causal among themselves — the
+    block form of the latent layout, what a speculative step's verify
+    and draft passes are handed as ``attend``. ``q (B, Q, H, W)`` the
+    absorbed queries, ``kv_new (B, Q, W)`` the step's own latents, NOT
+    in the pool yet; ``positions (B,)`` the first query's position = the
+    row's tokens in the pool, all visible to every query; new row ``k``
+    is visible to query ``j`` iff ``k <= j`` (a block model's block is
+    all-see-all: :func:`paged_block_attention`). Returns ``(B, Q, H,
+    rank)`` float32.
+
+    On the TPU, where ``rank`` and the page size are multiples of 128,
+    the Pallas kernel ``mx_mla_decode...q<Q>`` reads each row's live
+    pages where they lie, all ``Q * H`` query vectors of a row in one
+    product a page; elsewhere :func:`gather_pages` + jnp, the kernel's
+    test reference. Counted as ``mla_verify_pallas`` /
+    ``mla_verify_jnp``."""
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import (_dispatch, _jnp_latent_decode,
+                                            _pallas_latent_verify)
+    pos = jnp.asarray(positions, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    q = (q * scale).astype(kv_pages.dtype)
+    kv_new = kv_new.astype(kv_pages.dtype)
+
+    def composed(q, kv_new, kv_pages, table, pos):
+        kc = gather_pages(kv_pages[layer:layer + 1], table)[0]
+        rows = jnp.arange(q.shape[0])
+        for k in range(q.shape[1]):
+            kc = kc.at[rows, pos + k].set(kv_new[:, k])
+        return _jnp_latent_decode(q, kc, pos + 1, rank)
+
+    def kernel(interpret, q, kv_new, kv_pages, table, pos):
+        return _pallas_latent_verify(q, kv_new, kv_pages, layer, table,
+                                     pos, rank, interpret)
+
+    return _dispatch("mla_verify", rank, (kv_pages.shape[2],),
+                     force_pallas, kernel, composed, q, kv_new, kv_pages,
+                     table, pos)
+
+
+def write_latent_rows(pages, page_table, positions, new,
+                      force_pallas=False):
+    """:func:`write_token_rows` for ``Q`` consecutive rows a row: ``new
+    (L, B, Q, W)`` lands at positions ``positions[b] .. positions[b] + Q
+    - 1`` through the row's table row. The rows may STRADDLE a page
+    boundary (a speculative step starts wherever the last one was
+    accepted to; :func:`write_block_rows`' block lies inside one page).
+    Nothing is rolled back: a rejected position's row is simply
+    overwritten by the next step. On the TPU, where the page tiles, the
+    Pallas kernel ``mx_latent_write...q<Q>`` rewrites the one or two
+    pages in place; elsewhere :func:`scatter_token`'s row writes, one
+    pass a new row. Counted as ``latent_write2_pallas`` /
+    ``latent_write2_jnp``."""
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import (_dispatch,
+                                            _pallas_latent_write_rows)
+    S = pages.shape[2]
+    Q = new.shape[2]
+    pos = jnp.asarray(positions, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    new = new.astype(pages.dtype)
+
+    def composed(pages, table, pos, new):
+        for k in range(Q):
+            pages = scatter_token(pages, table, pos + k, new[:, :, k])
+        return pages
+
+    def kernel(interpret, pages, table, pos, new):
+        each = pos[:, None] + jnp.arange(Q, dtype=jnp.int32)[None]  # (B, Q)
+        pidx = jnp.take_along_axis(table, each // S, axis=1)
+        # the slot new row 0 would have in the page of new row k
+        base = pos[:, None] - (each // S) * S
+        return _pallas_latent_write_rows(pages, pidx, base, new, interpret)
+
+    return _dispatch("latent_write2", pages.shape[-1], (S,), force_pallas,
+                     kernel, composed, pages, table, pos, new)
 
 
 def paged_block_attention(k_pages, v_pages, page_table, positions, layer,
@@ -609,8 +696,11 @@ class _PerHeadKV:
                      for pages, rows in zip(pools, new))
 
     # a block model's step (``DecodeServer``'s contract for a model with
-    # ``block_length``): a few query positions a row, grouped-query heads
+    # ``block_length``): a few query positions a row, grouped-query heads,
+    # every position of the block visible to every other. The CAUSAL
+    # block of a speculative step exists for the latent layout only
     blocks = True
+    causal_blocks = False
 
     def attend_block(self, pools, page_tables, positions):
         """The ``attend`` a block step hands its model."""
@@ -667,11 +757,28 @@ class _Latent(_PerHeadKV):
     K/V and the shared rotary key. Written in place (page by page, row
     by row) so a 16-bit pool is never widened by a scatter."""
 
-    blocks = False      # no block form of latent attention in the tree
+    # its block form is CAUSAL inside the block (consecutive positions:
+    # a speculative step's verify and draft passes); the all-see-all
+    # block of a diffusion model is not written for it
+    blocks = False
+    causal_blocks = True
 
     def attend(self, pools, page_tables, positions):
         return functools.partial(paged_latent_attention, *pools,
                                  page_tables, positions)
+
+    def attend_causal(self, pools, page_tables, positions):
+        """The ``attend`` a speculative step hands its model."""
+        return functools.partial(paged_latent_causal_attention, *pools,
+                                 page_tables, positions)
+
+    def write_causal(self, pools, page_tables, positions, new,
+                     force_pallas=False):
+        """A speculative step's new rows ``(L, B, Q, W)`` into their
+        pages, from ``positions`` on; they may straddle a boundary."""
+        return tuple(write_latent_rows(pages, page_tables, positions, rows,
+                                       force_pallas)
+                     for pages, rows in zip(pools, new))
 
     def write_prefill(self, pools, page_table_row, seqs, n_valid):
         return tuple(write_prefill_pages(pages, page_table_row, seq[:, 0],

@@ -48,15 +48,51 @@ an expert axis (``sharding_rules.held_experts``); what the absent
 experts would have added is left out, and that partial result goes on.
 ``ep=(0, 1)`` holds them all.
 
-Precision: bf16 matrices (the router's matrix and bias float32), bf16
-pool; float32 accumulation, residual stream, norms, softmax and
-router. Parameters are a FLAT ``{name: array}`` dict.
+**The residual path** is ``h = h + F(norm(h))`` at ``hc_mult`` 1. At
+``hc_mult = n`` > 1 it is ``n`` STREAMS mixed by manifold-constrained
+hyper-connections (mHC, arXiv:2512.24880, after hyper-connections,
+arXiv:2409.19606): the state is ``X (n, C)`` a token, float32; in, the
+token's embedding in every stream; out, their sum. Every sublayer ``F``
+(``Attn o RMSNorm`` or ``FFN o RMSNorm``, the two above) has a mixing
+matrix ``hc_w (n C, 2 n + n n)`` (``[Phi_pre, Phi_post, Phi_res]``),
+biases ``hc_b`` and gates ``hc_a``:
+
+    x~     = vec(X) / sqrt(mean(vec(X)^2) + rms_norm_eps)     no gain
+    H_pre  = sigmoid(a_pre x~ Phi_pre + b_pre)                 (n)
+    H_post = 2 sigmoid(a_post x~ Phi_post + b_post)            (n)
+    H_res  = SK(clip(a_res mat(x~ Phi_res) + B_res, -30, 30))  (n, n)
+    u = H_pre X;   y = F(u);   X' = H_res X + H_post^T y
+
+``SK`` exponentiates and then, ``hc_sinkhorn_iters`` times, divides by
+the row sums and by the column sums (each ``+ hc_eps``): ``H_res`` is
+doubly stochastic, so the mix keeps what the streams sum to. The
+coefficient path is float32 with its product at "highest", as the
+router is, under ``jax.named_scope("mx_mhc")``, written in ``jnp``
+inside the step; the mixes are multiply-adds, never an MXU product.
+
+**The next-token module** (``num_nextn_predict_layers`` 1; DeepSeek-V3
+section 2.2, depth 1): position ``i`` reads the main model's ``h_i``
+(the streams' sum BEFORE the final norm) and the NEXT token, ``h'_i =
+W_p [RMSNorm(h_i); RMSNorm(Emb(t_{i+1}))]``, runs one block of the
+expert-layer kind over it (block ``n_layers``, inside the same stream
+mixing, its latent in a cache layer of its own: ``cache_layers =
+n_layers + 1``), then its own final norm and the main model's head:
+logits for ``t_{i+2}``. With it the model declares ``draft_length`` 1
+and is served in the SPECULATIVE form of the contract (``verify`` /
+``draft`` over two positions a row, ``prefill_draft`` /
+``draft_prefill`` over a prompt; ``serving.decode``'s docstring).
+
+Precision: bf16 matrices (the router's matrix and bias and the mixing
+matrices float32), bf16 pool; float32 accumulation, residual streams,
+mixing coefficients, norms, softmax and router. Parameters are a FLAT
+``{name: array}`` dict.
 """
 from __future__ import annotations
 
 import math
 
-__all__ = ["LatentMoEDecoderLM", "yarn_inv_freq", "yarn_mscale"]
+__all__ = ["LatentMoEDecoderLM", "yarn_inv_freq", "yarn_mscale",
+           "HC_GATES", "HC_RES_BIAS"]
 
 
 def yarn_mscale(factor, mscale):
@@ -86,12 +122,26 @@ def yarn_inv_freq(dim, theta, factor, original, beta_fast, beta_slow):
         .astype(np.float32)
 
 
+# how ``init_params`` draws the mixing of the residual streams: the
+# gates ``(alpha_pre, alpha_post, alpha_res)`` and the diagonal of
+# ``B_res``. ``exp(2)`` on the diagonal against ``exp(0.5 z)`` off it
+# leaves ``H_res`` leaning to the identity and moved by the token: the
+# largest entry of a row is 0.6-0.7 on average, neither the uniform 0.25
+# nor the identity's 1.
+HC_GATES = (1.0, 1.0, 0.5)
+HC_RES_BIAS = 2.0
+
+
 class LatentMoEDecoderLM:
     """The decode-model contract for a latent-attention, routed-expert
     decoder (module docstring). Keyword arguments are the keys of the
     published ``config.json``; ``ep=(rank, size)`` is the chip's share
     of the expert axis, ``use_pallas`` forces the Pallas kernels
-    (interpreted off the TPU) as in ``ToyDecoderLM``."""
+    (interpreted off the TPU) as in ``ToyDecoderLM``. ``hc_mult`` > 1
+    carries that many residual streams (hyper-connections, module
+    docstring); ``num_nextn_predict_layers`` 1 adds the next-token
+    module and makes this the SPECULATIVE form of the contract
+    (``draft_length`` 1: ``verify`` / ``draft`` in ``decode``'s place)."""
 
     step_counters = ("moe", ("moe_slots", "experts_touched", "max_load"))
 
@@ -103,7 +153,10 @@ class LatentMoEDecoderLM:
                  n_group, topk_group, routed_scaling_factor,
                  first_k_dense_replace, rope_theta, rope_scaling,
                  rms_norm_eps=1e-6, max_position_embeddings=4096,
-                 ep=(0, 1), use_pallas=False):
+                 hc_mult=1, hc_sinkhorn_iters=20, hc_eps=1e-6,
+                 mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
+                 num_nextn_predict_layers=0, ep=(0, 1), use_pallas=False):
+        from ..base import MXNetError
         from ..parallel.sharding_rules import held_experts
         self.vocab = int(vocab_size)
         self.d_model = int(hidden_size)
@@ -124,6 +177,23 @@ class LatentMoEDecoderLM:
         self.max_len = int(max_position_embeddings)
         self.use_pallas = bool(use_pallas)
         self.held = held_experts(self.n_experts, ep[1], ep[0])
+        self.hc = int(hc_mult)
+        self.hc_iters, self.hc_eps = int(hc_sinkhorn_iters), float(hc_eps)
+        self.hc_clamp = (float(mhc_h_res_clamp_min),
+                         float(mhc_h_res_clamp_max))
+        self.n_nextn = int(num_nextn_predict_layers)
+        if self.n_nextn not in (0, 1):
+            raise MXNetError(
+                "LatentMoEDecoderLM: num_nextn_predict_layers %d — the "
+                "next-token module is served at depth 1 (one draft a "
+                "step); a chain of modules needs a draft longer than "
+                "one token" % self.n_nextn)
+        if self.n_nextn:
+            # the speculative form of the decode-model contract
+            self.draft_length = 1
+        # the module's block keeps its latent in a cache layer of its
+        # own, behind the main model's
+        self.cache_layers = self.n_layers + self.n_nextn
         ys = dict(rope_scaling)
         self.inv_freq = yarn_inv_freq(
             self.rope, float(rope_theta), float(ys["factor"]),
@@ -147,53 +217,91 @@ class LatentMoEDecoderLM:
 
     @property
     def n_moe_layers(self):
-        return self.n_layers - self.n_dense
+        """Expert layers a step runs: the main model's and the
+        next-token module's block."""
+        return self.n_layers - self.n_dense + self.n_nextn
 
     # -- parameters ------------------------------------------------------
     def init_params(self, seed=0):
         """bf16 matrices at ``fan_in ** -0.5`` (the embedding at 1), the
-        router's matrix and bias float32, norm gains 1."""
+        router's matrix and bias float32, norm gains 1. Under
+        ``hc_mult`` > 1 every sublayer's mixing matrix ``hc_w (n C, 2 n
+        + n n)`` float32 at ``fan_in ** -0.5`` (``[Phi_pre, Phi_post,
+        Phi_res]`` side by side), its gates ``hc_a = (1, 1, 0.5)`` and
+        its biases ``hc_b = [0.., 0.., 2 I]``: ``H_res`` leans to the
+        identity and is moved by the token (:data:`HC_GATES`)."""
         import jax
         import jax.numpy as jnp
-        keys = iter(jax.random.split(jax.random.PRNGKey(seed),
-                                     16 * self.n_layers + 8))
+        base = jax.random.PRNGKey(seed)
+        keys = iter(jax.random.split(base, 16 * self.n_layers + 8))
 
         def w(*shape, dtype=jnp.bfloat16, std=None):
             std = shape[-2] ** -0.5 if std is None else std
             return (jax.random.normal(next(keys), shape, jnp.float32)
                     * std).astype(dtype)
 
-        D, H, E = self.d_model, self.n_heads, self.held[1] - self.held[0]
+        D = self.d_model
         ones = lambda n: jnp.ones((n,), jnp.float32)    # noqa: E731
         p = {"embed": w(self.vocab, D, std=1.0), "out_g": ones(D),
              "head": w(D, self.vocab)}
         for i in range(self.n_layers):
-            l = "l%d." % i
-            p.update({
-                l + "attn_g": ones(D),
-                l + "wq_a": w(D, self.q_rank),
-                l + "q_g": ones(self.q_rank),
-                l + "wq_b": w(self.q_rank, H * (self.nope + self.rope)),
-                l + "wkv_a": w(D, self.latent),
-                l + "kv_g": ones(self.kv_rank),
-                l + "wk_b": w(self.kv_rank, H * self.nope),
-                l + "wv_b": w(self.kv_rank, H * self.v_dim),
-                l + "wo": w(H * self.v_dim, D),
-                l + "ffn_g": ones(D)})
-            if i < self.n_dense:
-                p.update({l + "w_gate": w(D, self.d_ff),
-                          l + "w_up": w(D, self.d_ff),
-                          l + "w_down": w(self.d_ff, D)})
-                continue
-            F, Fs = self.d_expert, self.d_expert * self.n_shared
-            p.update({
-                l + "router_w": w(D, self.n_experts, dtype=jnp.float32),
-                l + "router_b": jnp.zeros((self.n_experts,), jnp.float32),
-                l + "shared.w_gate": w(D, Fs), l + "shared.w_up": w(D, Fs),
-                l + "shared.w_down": w(Fs, D),
-                l + "experts.w_gate": w(E, D, F),
-                l + "experts.w_up": w(E, D, F),
-                l + "experts.w_down": w(E, F, D)})
+            p.update(self._layer_params(i, w))
+        if self.hc == 1 and not self.n_nextn:
+            return p
+        # what the two extensions add draws from a key stream of its
+        # own: the parameters above are the same numbers with and
+        # without them
+        keys = iter(jax.random.split(jax.random.fold_in(base, 1),
+                                     4 * self.cache_layers + 24))
+        if self.n_nextn:
+            p.update(self._layer_params(self.n_layers, w))
+            p.update({"mtp.h_g": ones(D), "mtp.e_g": ones(D),
+                      "mtp.proj": w(2 * D, D), "mtp.out_g": ones(D)})
+        if self.hc > 1:
+            n = self.hc
+            bias = jnp.concatenate([
+                jnp.zeros((2 * n,), jnp.float32),
+                HC_RES_BIAS * jnp.eye(n, dtype=jnp.float32).reshape(-1)])
+            for i in range(self.cache_layers):
+                for sub in ("attn_", "ffn_"):
+                    l = "l%d.%s" % (i, sub)
+                    p.update({
+                        l + "hc_w": w(n * D, 2 * n + n * n,
+                                      dtype=jnp.float32),
+                        l + "hc_a": jnp.asarray(HC_GATES, jnp.float32),
+                        l + "hc_b": bias})
+        return p
+
+    def _layer_params(self, i, w):
+        import jax.numpy as jnp
+        D, H, E = self.d_model, self.n_heads, self.held[1] - self.held[0]
+        ones = lambda n: jnp.ones((n,), jnp.float32)    # noqa: E731
+        l = "l%d." % i
+        p = {
+            l + "attn_g": ones(D),
+            l + "wq_a": w(D, self.q_rank),
+            l + "q_g": ones(self.q_rank),
+            l + "wq_b": w(self.q_rank, H * (self.nope + self.rope)),
+            l + "wkv_a": w(D, self.latent),
+            l + "kv_g": ones(self.kv_rank),
+            l + "wk_b": w(self.kv_rank, H * self.nope),
+            l + "wv_b": w(self.kv_rank, H * self.v_dim),
+            l + "wo": w(H * self.v_dim, D),
+            l + "ffn_g": ones(D)}
+        if i < self.n_dense:
+            p.update({l + "w_gate": w(D, self.d_ff),
+                      l + "w_up": w(D, self.d_ff),
+                      l + "w_down": w(self.d_ff, D)})
+            return p
+        F, Fs = self.d_expert, self.d_expert * self.n_shared
+        p.update({
+            l + "router_w": w(D, self.n_experts, dtype=jnp.float32),
+            l + "router_b": jnp.zeros((self.n_experts,), jnp.float32),
+            l + "shared.w_gate": w(D, Fs), l + "shared.w_up": w(D, Fs),
+            l + "shared.w_down": w(Fs, D),
+            l + "experts.w_gate": w(E, D, F),
+            l + "experts.w_up": w(E, D, F),
+            l + "experts.w_down": w(E, F, D)})
         return p
 
     # -- pieces ----------------------------------------------------------
@@ -269,28 +377,114 @@ class LatentMoEDecoderLM:
                        jnp.float32)], -1)
         return q_nope, self._rotate(q_r, positions), row
 
+    # -- the residual streams --------------------------------------------
+    # With ``hc_mult`` 1 these are the plain residual path, ``h = h +
+    # F(norm(h))``, operation for operation.
+    def _streams(self, e):
+        """In: the token's embedding in every stream, ``(..., n, C)``."""
+        import jax.numpy as jnp
+        if self.hc == 1:
+            return e
+        return jnp.broadcast_to(e[..., None, :],
+                                e.shape[:-1] + (self.hc, e.shape[-1]))
+
+    def _merge(self, X):
+        """Out: the sum of the streams."""
+        return X if self.hc == 1 else X.sum(-2)
+
+    def _read(self, name, X, p):
+        """What sublayer ``name`` reads of the state ``X (..., n, C)``:
+        ``u = H_pre X``, and the coefficients of its write-back. The
+        coefficient path — the norm over all ``n C`` values (no gain),
+        the product at "highest", sigmoid, Sinkhorn — is float32, as the
+        router is; the mixes are multiply-adds on the VPU, never a
+        product the MXU would round to bfloat16."""
+        import jax
+        import jax.numpy as jnp
+        if self.hc == 1:
+            return X, None
+        n = self.hc
+        with jax.named_scope("mx_mhc"):
+            flat = X.reshape(X.shape[:-2] + (n * X.shape[-1],))
+            xt = flat * jax.lax.rsqrt(
+                jnp.mean(flat * flat, -1, keepdims=True) + self.eps)
+            z = jnp.dot(xt, p[name + "hc_w"], precision="highest")
+            a, b = p[name + "hc_a"], p[name + "hc_b"]
+            pre = jax.nn.sigmoid(a[0] * z[..., :n] + b[:n])
+            post = 2.0 * jax.nn.sigmoid(a[1] * z[..., n:2 * n]
+                                        + b[n:2 * n])
+            res = a[2] * z[..., 2 * n:].reshape(z.shape[:-1] + (n, n)) \
+                + b[2 * n:].reshape(n, n)
+            res = jnp.exp(jnp.clip(res, *self.hc_clamp))
+            for _ in range(self.hc_iters):      # rows, then columns
+                res = res / (res.sum(-1, keepdims=True) + self.hc_eps)
+                res = res / (res.sum(-2, keepdims=True) + self.hc_eps)
+            u = (pre[..., None] * X).sum(-2)
+        return u, (post, res)
+
+    def _write(self, X, y, mix):
+        """``X' = H_res X + H_post^T y``."""
+        import jax
+        if mix is None:
+            return X + y
+        post, res = mix
+        with jax.named_scope("mx_mhc"):
+            return (res[..., None] * X[..., None, :, :]).sum(-2) \
+                + post[..., None] * y[..., None, :]
+
+    def _block(self, i, X, p, attention, routed=None):
+        """Block ``i`` over the state: ``attention(i, u) -> (increment,
+        cached row)`` is the path's own (prefill or cached decode).
+        Returns ``(X, row, expert load or None)``."""
+        l = "l%d." % i
+        u, mix = self._read(l + "attn_", X, p)
+        out, row = attention(i, u)
+        X = self._write(X, out, mix)
+        u, mix = self._read(l + "ffn_", X, p)
+        x = self._rms(u, p[l + "ffn_g"])
+        out, load = self._ffn(i, x.reshape(-1, x.shape[-1]), p, routed)
+        return self._write(X, out.reshape(x.shape), mix), row, load
+
+    def _draft_in(self, p, hidden, tokens):
+        """The next-token module's input: ``W_p [RMSNorm(h_i);
+        RMSNorm(Emb(t_{i+1}))]``, ``h_i`` the main model's state summed
+        over the streams BEFORE its final norm."""
+        import jax.numpy as jnp
+        e = p["embed"][tokens].astype(jnp.float32)
+        return self._mm(jnp.concatenate(
+            [self._rms(hidden, p["mtp.h_g"]),
+             self._rms(e, p["mtp.e_g"])], -1), p["mtp.proj"])
+
+    @staticmethod
+    def _counters(loads):
+        import jax.numpy as jnp
+        if not loads:
+            return jnp.zeros((3,), jnp.int32)
+        load = jnp.stack(loads)                           # (layers, E)
+        return jnp.stack([load.sum(), (load > 0).sum(), load.max()])
+
     # -- the contract ----------------------------------------------------
     def prefill(self, params, tokens):
-        return self._forward(params, tokens)
+        logits, _h, rows = self._forward(params, tokens)
+        return logits, rows
 
     def routing(self, params, tokens):
-        """The router's choice at every expert layer over whole
-        sequences ``tokens (B, L)``, on the prefill path: ``(expert
-        layers, B * L, top_k)`` int32 — for a comparison with a
-        reference's choice, not for serving."""
+        """The router's choice at every expert layer of the main model
+        over whole sequences ``tokens (B, L)``, on the prefill path:
+        ``(expert layers, B * L, top_k)`` int32 — for a comparison with
+        a reference's choice, not for serving."""
         import jax.numpy as jnp
         routed = []
         self._forward(params, tokens, routed)
         return jnp.stack(routed)
 
-    def _forward(self, params, tokens, routed=None):
+    def _flash(self, p, pos):
+        """``attention(i, u)`` of the prefill path: the published form
+        over a whole sequence on the flash kernel."""
         import jax.numpy as jnp
         from ..parallel.flash_attention import flash_attention
-        p = params
-        B, L = tokens.shape
+        B, L = pos.shape
         H, R = self.n_heads, self.kv_rank
-        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
-        h = p["embed"][tokens].astype(jnp.float32)
         # the flash kernel takes head sizes of whole lane tiles: zero
         # columns change no score and give zero outputs
         wide = -(-max(self.nope + self.rope, self.v_dim) // 128) * 128
@@ -299,10 +493,9 @@ class LatentMoEDecoderLM:
             return jnp.pad(a.astype(jnp.bfloat16), (
                 (0, 0), (0, 0), (0, 0), (0, wide - a.shape[-1])))
 
-        rows = []
-        for i in range(self.n_layers):
+        def attention(i, u):
             l = "l%d." % i
-            x = self._rms(h, p[l + "attn_g"])
+            x = self._rms(u, p[l + "attn_g"])
             q_nope, q_r, row = self._latent(i, x, p, pos)
             row = row.astype(jnp.bfloat16)       # as the pool holds it
             c_kv, k_r = row[..., :R], row[..., R:self.latent]
@@ -317,50 +510,128 @@ class LatentMoEDecoderLM:
                                 scale=self.scale,
                                 force_pallas=self.use_pallas)
             a = a[..., :self.v_dim].reshape(B, L, H * self.v_dim)
-            h = h + self._mm(a, p[l + "wo"])
-            x = self._rms(h, p[l + "ffn_g"])
-            out, _ = self._ffn(i, x.reshape(B * L, -1), p, routed)
-            h = h + out.reshape(B, L, -1)
-            rows.append(row)
-        logits = self._mm(self._rms(h, p["out_g"]), p["head"])
-        return logits, jnp.stack(rows)
+            return self._mm(a, p[l + "wo"]), row
 
-    def decode(self, params, tokens, positions, attend):
+        return attention
+
+    def _forward(self, params, tokens, routed=None):
+        """``(logits, h, rows)`` over whole sequences: ``h (B, L, D)``
+        is the state summed over the streams before the final norm."""
         import jax.numpy as jnp
         p = params
-        B = tokens.shape[0]
-        H, R = self.n_heads, self.kv_rank
-        h = p["embed"][tokens].astype(jnp.float32)
-        rows, loads = [], []
+        B, L = tokens.shape
+        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+        X = self._streams(p["embed"][tokens].astype(jnp.float32))
+        attention = self._flash(p, pos)
+        rows = []
         for i in range(self.n_layers):
+            X, row, _ = self._block(i, X, p, attention, routed)
+            rows.append(row)
+        h = self._merge(X)
+        logits = self._mm(self._rms(h, p["out_g"]), p["head"])
+        return logits, h, jnp.stack(rows)
+
+    def _absorbed(self, p, positions, attend):
+        """``attention(i, u)`` of the cached path, the ABSORBED form:
+        the query goes through the key up-projection, the cache is
+        never expanded. ``u (..., D)`` at ``positions (...)``."""
+        import jax.numpy as jnp
+        H, R = self.n_heads, self.kv_rank
+
+        def attention(i, u):
             l = "l%d." % i
-            x = self._rms(h, p[l + "attn_g"])
+            x = self._rms(u, p[l + "attn_g"])
             q_nope, q_r, row = self._latent(i, x, p, positions)
-            # absorbed: the query goes through the key up-projection,
-            # the cache is never expanded
             wk = p[l + "wk_b"].reshape(R, H, self.nope)
-            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(wk.dtype),
+            q_lat = jnp.einsum("...hn,rhn->...hr", q_nope.astype(wk.dtype),
                                wk, preferred_element_type=jnp.float32)
-            q_row = jnp.pad(jnp.concatenate([q_lat, q_r], -1), (
-                (0, 0), (0, 0), (0, self.row_width - self.latent)))
+            q_row = jnp.pad(
+                jnp.concatenate([q_lat, q_r], -1),
+                ((0, 0),) * (q_lat.ndim - 1)
+                + ((0, self.row_width - self.latent),))
             o_lat = attend(i, q_row, row,
                            rank=R, scale=self.scale,
                            force_pallas=self.use_pallas)
             wv = p[l + "wv_b"].reshape(R, H, self.v_dim)
-            a = jnp.einsum("bhr,rhv->bhv", o_lat.astype(wv.dtype), wv,
+            a = jnp.einsum("...hr,rhv->...hv", o_lat.astype(wv.dtype), wv,
                            preferred_element_type=jnp.float32)
-            h = h + self._mm(a.reshape(B, H * self.v_dim), p[l + "wo"])
-            x = self._rms(h, p[l + "ffn_g"])
-            out, load = self._ffn(i, x, p)
-            h = h + out
+            return self._mm(a.reshape(u.shape[:-1] + (H * self.v_dim,)),
+                            p[l + "wo"]), row
+
+        return attention
+
+    def _cached(self, p, e, layers, positions, attend):
+        """Blocks ``layers`` over the embeddings ``e (..., D)`` through
+        the cache: ``(h summed over the streams, the layers' rows, the
+        expert layers' loads)``."""
+        X = self._streams(e)
+        attention = self._absorbed(p, positions, attend)
+        rows, loads = [], []
+        for i in layers:
+            X, row, load = self._block(i, X, p, attention)
             rows.append(row)
             if load is not None:
                 loads.append(load)
+        return self._merge(X), rows, loads
+
+    def decode(self, params, tokens, positions, attend):
+        import jax.numpy as jnp
+        p = params
+        h, rows, loads = self._cached(
+            p, p["embed"][tokens].astype(jnp.float32),
+            range(self.n_layers), positions, attend)
         logits = self._mm(self._rms(h, p["out_g"]), p["head"])
-        if loads:
-            load = jnp.stack(loads)                       # (layers, E)
-            counters = jnp.stack([load.sum(), (load > 0).sum(),
-                                  load.max()])
-        else:
-            counters = jnp.zeros((3,), jnp.int32)
+        counters = self._counters(loads)
         return logits, jnp.stack(rows), counters
+
+    # -- the speculative form (``num_nextn_predict_layers`` 1) ------------
+    def prefill_draft(self, params, tokens):
+        """:meth:`prefill` that also hands out what the next-token
+        module reads: ``(logits, h (B, L, D), rows)``."""
+        return self._forward(params, tokens)
+
+    def draft_prefill(self, params, hidden, tokens):
+        """The next-token module over a whole sequence: position ``i``
+        reads the main model's ``hidden[:, i]`` and the NEXT token
+        ``tokens[:, i]`` (= t_{i+1}) and predicts t_{i+2}. ``(logits (B,
+        L, V), rows (1, B, L, W))`` — the module's own cache layer."""
+        import jax.numpy as jnp
+        p = params
+        B, L = tokens.shape
+        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+        X = self._streams(self._draft_in(p, hidden, tokens))
+        X, row, _ = self._block(self.n_layers, X, p, self._flash(p, pos))
+        logits = self._mm(self._rms(self._merge(X), p["mtp.out_g"]),
+                          p["head"])
+        return logits, row[None]
+
+    def _span(self, tokens, positions):
+        import jax.numpy as jnp
+        return positions[:, None] + jnp.arange(tokens.shape[1],
+                                               dtype=jnp.int32)[None]
+
+    def verify(self, params, tokens, positions, attend):
+        """The main model over ``tokens (B, Q)`` at positions
+        ``positions[b] ..``, causal, through the cache: ``(logits (B, Q,
+        V), h (B, Q, D), rows (n_layers, B, Q, W), counters)``.
+        ``attend`` is the layout's causal block form."""
+        import jax.numpy as jnp
+        p = params
+        h, rows, loads = self._cached(
+            p, p["embed"][tokens].astype(jnp.float32),
+            range(self.n_layers), self._span(tokens, positions), attend)
+        logits = self._mm(self._rms(h, p["out_g"]), p["head"])
+        return logits, h, jnp.stack(rows), self._counters(loads)
+
+    def draft(self, params, hidden, tokens, positions, attend):
+        """The next-token module over ``Q`` positions through its cache
+        layer (``n_layers``): position ``j`` reads ``hidden[:, j]`` and
+        the token AFTER it, ``tokens[:, j]``. ``(logits (B, Q, V), rows
+        (1, B, Q, W), counters)``."""
+        import jax.numpy as jnp
+        p = params
+        h, rows, loads = self._cached(
+            p, self._draft_in(p, hidden, tokens), (self.n_layers,),
+            self._span(tokens, positions), attend)
+        logits = self._mm(self._rms(h, p["mtp.out_g"]), p["head"])
+        return logits, jnp.stack(rows), self._counters(loads)
