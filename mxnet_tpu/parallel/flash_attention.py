@@ -35,10 +35,15 @@ The serving decode step does not use ``flash_decode``'s contiguous
 cache: :func:`_pallas_paged_decode` (behind
 ``serving.kvcache.paged_attention``, which holds its plain reference)
 reads the paged KV pool's pages where they lie, steered by the page
-table in SMEM, so the step copies no cache. :func:`_pallas_block_decode`
-(behind ``serving.kvcache.paged_block_attention``) is its sibling for a
-step that runs a block of query positions a row over fewer key/value
-than query heads: one MXU product a key/value head and live page.
+table in SMEM, so the step copies no cache; its grid has a step a table
+column. The MXU paged kernels — :func:`_pallas_latent_decode` and
+:func:`_pallas_latent_verify` (one latent row a token, shared by every
+head) and :func:`_pallas_block_decode` (a block of query positions a
+row over fewer key/value than query heads: one MXU product a key/value
+head and live page) — have one grid step a ROW: the pools stay in HBM
+and the kernel walks the row's live pages itself
+(:func:`_walk_pages`), so a table's dead columns cost nothing and a
+table 10 wide and one 80 wide cost what their live pages cost.
 
 Per-row planes (logsumexp, rowsum(dO*O), segment ids, int8 scales)
 are rank-3 — ``(BH, T, 1)`` columns on the q side, ``(BH, 1, T)`` rows
@@ -890,45 +895,181 @@ def _jnp_latent_decode(q, kv, lengths, rank):
     return jnp.einsum("bht,btc->bhc", p, kf[..., :rank])
 
 
-def _mla_fold_page(j, length, q_ref, page_ref, o_ref, acc_ref, m_ref, l_ref,
-                   page_size, n_pages, rank):
-    """What both latent kernels do with table column ``j`` once the
-    step's own rows have opened the accumulation: fold the page's live
-    tokens into the running softmax (every query row of ``q_ref``
-    against the page in one product), and on the last column write the
-    normalised sum out."""
+_WALK_DEPTH = 6      # page buffers a pool: two being folded, four in flight
+
+
+def _lanes(x, n):
+    """``x (rows, 128)``, every lane of a row the same value, as ``(rows,
+    n)``: how the softmax state of the paged MXU kernels (running max,
+    running sum) meets a score tile or an accumulator ``n`` lanes wide.
+    Kept ``(rows, 1)``, a column fills one lane of as many vregs and
+    every use is a lane broadcast through the XLU (48 permutes a folded
+    page in the one-query latent kernel)."""
+    from jax.experimental.pallas import tpu as pltpu
+    if n <= x.shape[1]:
+        return x[:, :n]
+    return pltpu.repeat(x, n // x.shape[1], axis=1)
+
+
+def _walk_scratch(*pages):
+    """The scratch :func:`_walk_pages` needs for pools whose pages are
+    ``pages`` (``ShapeDtypeStruct``s): a ring of ``_WALK_DEPTH`` page
+    buffers a pool, a DMA semaphore a buffer, the head's four counters."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    return [pltpu.VMEM((_WALK_DEPTH,) + page.shape, page.dtype)
+            for page in pages] \
+        + [pltpu.SemaphoreType.DMA((len(pages), _WALK_DEPTH)),
+           pltpu.SMEM((4,), jnp.int32)]
+
+
+def _walk_pages(tbl_ref, len_ref, layer, pool_refs, buf_refs, sem_ref,
+                ring_ref, fold):
+    """The page walk of the MXU paged kernels, written once. Grid =
+    (rows,): program instance ``row`` folds its OWN live pages — the
+    first ``ceil(len_ref[row] / S)`` columns of its row of the flat
+    table ``tbl_ref`` — in column order, and nothing past them: a dead
+    column is never named, copied or branched over, and a row with
+    nothing in the pool waits for nothing.
+
+    ``pool_refs`` are the whole ``(L, P, S, ...)`` pools, left in HBM,
+    of which ``layer`` (a scalar, traced or not) is read; ``buf_refs``
+    one ``(depth, S, ...)`` VMEM ring a pool, ``sem_ref`` a ``(pools,
+    depth)`` array of DMA semaphores, ``ring_ref`` four SMEM counters
+    (:func:`_walk_scratch` declares all three). To the copies the live
+    pages of the whole call, row after row, are ONE sequence: the head
+    (``ring_ref``, SMEM: its row, its column, pages started, pages
+    folded) runs ``depth - 2`` pages in front of the fold and over the
+    rows' ends, so a row's first pages arrive under the row before's
+    folds. Every copy started is waited for by the row that owns the
+    page.
+
+    ``fold([(j, page_refs), ...])`` is the kernel's arithmetic on table
+    columns ``j``, in the order given. It is handed TWO pages a loop
+    body (and a last odd one alone): a page's fold is one dependent
+    chain — score product, row max, exponentials, value product,
+    rescale — and with two in one body the scheduler runs the second
+    page's score product under the first one's softmax."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    row, rows = pl.program_id(0), len_ref.shape[0]
+    n_pages = tbl_ref.shape[0] // rows
+    depth, page_size = buf_refs[0].shape[:2]
+
+    def n_live(r):
+        return (len_ref[r] + page_size - 1) // page_size
+
+    def copies(r, j, slot):
+        page = tbl_ref[r * n_pages + j]
+        return [pltpu.make_async_copy(pool.at[layer, page], buf.at[slot],
+                                      sem_ref.at[i, slot])
+                for i, (pool, buf) in enumerate(zip(pool_refs, buf_refs))]
+
+    def next_live(r):
+        """The first row at or after ``r`` with a page in the pool."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < rows, n_live(jnp.minimum(r, rows - 1)) == 0),
+            lambda r: r + 1, r)
+
+    def start_head():
+        r, c, started = ring_ref[0], ring_ref[1], ring_ref[2]
+
+        @pl.when(r < rows)
+        def _start():
+            for copy in copies(r, c, jax.lax.rem(started, depth)):
+                copy.start()
+            last = c + 1 >= n_live(r)
+            ring_ref[0] = jnp.where(last, next_live(r + 1), r)
+            ring_ref[1] = jnp.where(last, 0, c + 1)
+            ring_ref[2] = started + 1
+
+    @pl.when(row == 0)
+    def _prime():
+        ring_ref[0] = next_live(0)
+        ring_ref[1] = 0
+        ring_ref[2] = 0
+        ring_ref[3] = 0
+        for _ in range(depth - 2):
+            start_head()
+
+    def step(j, count):
+        """Fold this row's pages ``j .. j + count - 1`` (``count``
+        static): the head moves on as many, so ``depth - 2 + count``
+        pages at most are in their buffers or on their way."""
+        for _ in range(count):
+            start_head()
+        folded = ring_ref[3]
+        pages = []
+        for k in range(count):
+            slot = jax.lax.rem(folded + k, depth)
+            for copy in copies(row, j + k, slot):
+                copy.wait()
+            pages.append((j + k, [buf.at[slot] for buf in buf_refs]))
+        fold(pages)
+        ring_ref[3] = folded + count
+
+    n = n_live(row)
+
+    def pair(i, carry):
+        step(2 * i, 2)
+        return carry
+
+    jax.lax.fori_loop(0, n // 2, pair, 0)
+
+    @pl.when(n % 2 == 1)
+    def _odd():
+        step(n - 1, 1)
+
+
+def _mla_walk(tbl_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref, acc_ref,
+              m_ref, l_ref, buf_ref, sem_ref, ring_ref, page_size, rank):
+    """What both latent kernels do once the step's own rows have opened
+    the accumulation: walk the row's live pages (:func:`_walk_pages`),
+    folding each page's live tokens into the running softmax — every
+    query row of ``q_ref`` against the page in one product — and write
+    the normalised sum out."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    @pl.when(j * page_size < length)
-    def _step():
-        lat = page_ref[:, :rank]                              # (S, rank)
-        s = _dot(q_ref[...], page_ref[...], _NT)              # (rows, S)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        s = jnp.where(pos < length, s, _NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(lat.dtype),
-                                                   lat)
+    b = pl.program_id(0)
+    length = len_ref[b]
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        # the step's own first row is always live, so l >= its weight > 0
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    def fold(pages):
+        scores = []
+        for j, (page_ref,) in pages:
+            s = _dot(q_ref[...], page_ref[...], _NT)          # (rows, S)
+            pos = j * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page_size), 1)
+            scores.append(jnp.where(pos < length, s, _NEG))
+        for (_, (page_ref,)), s in zip(pages, scores):
+            lat = page_ref[:, :rank]                          # (S, rank)
+            m_prev = m_ref[...]                               # (rows, 128)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _lanes(m_new, page_size))
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * _lanes(alpha, rank) \
+                + _dot(p.astype(lat.dtype), lat)
+
+    _walk_pages(tbl_ref, len_ref, layer_ref[0], [pool_ref], [buf_ref],
+                sem_ref, ring_ref, fold)
+    # the step's own first row is always live, so l >= its weight > 0
+    o_ref[...] = (acc_ref[...] / _lanes(l_ref[...], rank)).astype(
+        o_ref.dtype)
 
 
-def _mla_decode_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page_size, n_pages, rank):
-    """Grid = (rows, table columns), columns innermost: one program
-    instance attends ALL heads of one row to ONE page of the latent pool,
-    read where it lies — ``page_ref`` is the page's ``(S, W)`` block, one
-    row a token, the same for every head. That is what makes this an MXU
+def _mla_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, new_ref, pool_ref,
+                       o_ref, acc_ref, m_ref, l_ref, buf_ref, sem_ref,
+                       ring_ref, *, page_size, rank):
+    """Grid = (rows,): one program instance attends ALL heads of one row
+    to the row's live pages of the latent pool, which it walks itself
+    (:func:`_walk_pages`) — a page is an ``(S, W)`` block, one row a
+    token, the same for every head. That is what makes this an MXU
     kernel where the per-head paged kernel is VPU arithmetic: the scores
     of a page are one ``(H, W) x (W, S)`` product and the weighted sum
     one ``(H, S) x (S, rank)``, bf16 operands, float32 accumulation,
@@ -949,69 +1090,99 @@ def _mla_decode_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
 
     ``len_ref[b]`` counts the row's tokens IN THE POOL; the step's own
     latent (``new_ref``, not in the pool yet) opens the accumulation as
-    the first key, as in the per-head paged kernel. Columns at or past
-    ``ceil(len / S)`` name the last live page again (nothing is
-    fetched) and compute nothing."""
+    the first key, as in the per-head paged kernel. The walk is
+    ``ceil(len / S)`` pages long whatever the table's width: a table
+    column past it is never looked at. The running max and sum
+    (``m_ref``, ``l_ref``) are ``(H, 128)``, every lane of a row the
+    same value (:func:`_lanes`)."""
+    import jax.numpy as jnp
+
+    new = new_ref[...].astype(jnp.float32)                    # (1, W)
+    m_ref[...] = jnp.broadcast_to(
+        jnp.sum(q_ref[...].astype(jnp.float32) * new, axis=-1,
+                keepdims=True), m_ref.shape)
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
+
+    _mla_walk(tbl_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref, acc_ref,
+              m_ref, l_ref, buf_ref, sem_ref, ring_ref, page_size, rank)
+
+
+@functools.cache
+def _traced_once(call, *static):
+    """``call`` as a jitted function of its own (through
+    ``compile_watch.jit``, as every jit of the package): a program that
+    calls it once a layer with the layer an OPERAND traces and lowers
+    the kernel inside once. Tracing a kernel's body is a few hundred
+    small operations of Python; with the layer index baked into it,
+    every layer's kernel was another one, and the walk's larger bodies
+    cost 6 s of a seven-layer model's warm-up (my chip run, PR 34)."""
+    from .. import compile_watch
+    return compile_watch.jit(call, "kernel:" + call.__name__.lstrip("_"),
+                             storm=False, static_argnames=static)
+
+
+def _pallas_latent(q, kv_new, kv_pages, layer, page_table, lengths, *, rank,
+                   n_new, interpret):
+    """The ``pallas_call`` of both latent kernels: ``q (B, n_new * H, W)``
+    and ``kv_new (B, n_new, W)`` a block a row; the WHOLE pool ``(L, P,
+    S, W)`` stays in HBM, where the kernel's own copies read it (no
+    operand is a slice of it that XLA would have to copy);
+    ``page_table (B, M)``, ``lengths (B,)`` and ``layer (1,)`` ride
+    scalar prefetch (the layer an operand: :func:`_traced_once`).
+    Returns ``(B, n_new * H, rank)`` float32."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    length = len_ref[b]
+    B, rows, W = q.shape
+    S = kv_pages.shape[2]
+    M = page_table.shape[1]
+    static = dict(page_size=S, rank=rank)
+    if n_new == 1:
+        kernel = functools.partial(_mla_decode_kernel, **static)
+    else:
+        kernel = functools.partial(_mla_verify_kernel, n_new=n_new,
+                                   n_heads=rows // n_new, **static)
 
-    @pl.when(j == 0)
-    def _init():
-        new = new_ref[...].astype(jnp.float32)                # (1, W)
-        m_ref[...] = jnp.sum(q_ref[...].astype(jnp.float32) * new,
-                             axis=-1, keepdims=True)
-        l_ref[...] = jnp.ones_like(l_ref)
-        acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
+    def row(shape):
+        return pl.BlockSpec((None,) + shape, lambda b, *prefetch: (b, 0, 0))
 
-    _mla_fold_page(j, length, q_ref, page_ref, o_ref, acc_ref, m_ref, l_ref,
-                   page_size, n_pages, rank)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[row((rows, W)), row((n_new, W)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row((rows, rank)),
+            scratch_shapes=[pltpu.VMEM((rows, rank), jnp.float32),
+                            pltpu.VMEM((rows, _LANES), jnp.float32),
+                            pltpu.VMEM((rows, _LANES), jnp.float32)]
+            + _walk_scratch(jax.ShapeDtypeStruct((S, W), kv_pages.dtype))),
+        out_shape=jax.ShapeDtypeStruct((B, rows, rank), jnp.float32),
+        # the walk's copies run over the rows' ends: rows in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        # rows x query positions x heads, the query positions a row, the
+        # table's full width in keys, the latent's width: what
+        # kernel_costs.shapes reads from the event
+        name="mx_mla_decode.bh%d.q%d.k%d.d%d.%s.r%d.paged" % (
+            B * rows, n_new, M * S, W, jnp.dtype(kv_pages.dtype).name, rank),
+    )(page_table.reshape(-1), lengths, layer, q, kv_new, kv_pages)
 
 
 def _pallas_latent_decode(q, kv_new, kv_pages, layer, page_table, lengths,
                           rank, interpret):
     """``q (B, H, W)`` scaled, in the pool's dtype; ``kv_new (B, 1, W)``;
-    the WHOLE pool ``(L, P, S, W)`` (``layer`` is picked in the index
-    map); ``page_table (B, M)`` and ``lengths (B,)`` ride scalar
-    prefetch. Returns ``(B, H, rank)`` float32."""
+    the WHOLE pool ``(L, P, S, W)`` and the ``layer`` to read;
+    ``page_table (B, M)`` and ``lengths (B,)``. Returns ``(B, H, rank)``
+    float32."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, W = q.shape
-    S = kv_pages.shape[2]
-    M = page_table.shape[1]
-    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
-    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
-    page_table = jnp.take_along_axis(page_table, columns, axis=1)
-
-    return pl.pallas_call(
-        functools.partial(_mla_decode_kernel, page_size=S, n_pages=M,
-                          rank=rank),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, M),
-            in_specs=[
-                pl.BlockSpec((None, H, W), lambda b, j, tbl, lens: (b, 0, 0)),
-                pl.BlockSpec((None, 1, W), lambda b, j, tbl, lens: (b, 0, 0)),
-                pl.BlockSpec((None, None, S, W),
-                             lambda b, j, tbl, lens:
-                             (layer, tbl[b * M + j], 0, 0))],
-            out_specs=pl.BlockSpec((None, H, rank),
-                                   lambda b, j, tbl, lens: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((H, rank), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
-        interpret=interpret,
-        # rows x heads, one query, the table's full width in keys, the
-        # latent's width: what kernel_costs.shapes reads from the event
-        name="mx_mla_decode.bh%d.q1.k%d.d%d.%s.r%d.paged" % (
-            B * H, M * S, W, jnp.dtype(kv_pages.dtype).name, rank),
-    )(page_table.reshape(-1), lengths, q, kv_new, kv_pages)
+    return _traced_once(_pallas_latent, "rank", "n_new", "interpret")(
+        q, kv_new, kv_pages, jnp.full((1,), layer, jnp.int32), page_table,
+        lengths, rank=rank, n_new=1, interpret=interpret)
 
 
 def _latent_write_kernel(pg_ref, slot_ref, new_ref, page_ref, out_ref):
@@ -1077,9 +1248,9 @@ def _jnp_latent_verify(q, kv, lengths, rank):
     return jnp.einsum("bqht,btc->bqhc", p, kf[..., :rank])
 
 
-def _mla_verify_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page_size, n_pages, rank,
-                       n_new, n_heads):
+def _mla_verify_kernel(tbl_ref, len_ref, layer_ref, q_ref, new_ref, pool_ref,
+                       o_ref, acc_ref, m_ref, l_ref, buf_ref, sem_ref,
+                       ring_ref, *, page_size, rank, n_new, n_heads):
     """:func:`_mla_decode_kernel` for ``n_new`` consecutive query
     positions a row: the ``n_new * n_heads`` query vectors of one row
     (position-major: row ``j * n_heads + h``) are the rows of ONE
@@ -1089,79 +1260,42 @@ def _mla_verify_kernel(tbl_ref, len_ref, q_ref, new_ref, page_ref, o_ref,
     visible to every query; the step's own ``n_new`` latents
     (``new_ref``, not in the pool yet) open the accumulation on the VPU,
     folded in TRIANGULARLY: new row ``k`` is visible to query ``j`` iff
-    ``k <= j`` (row 0 to both, row 1 to the second only)."""
+    ``k <= j`` (row 0 to both, row 1 to the second only). Then the same
+    walk over the row's live pages (:func:`_mla_walk`)."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    length = len_ref[b]
     f32 = jnp.float32
 
-    @pl.when(j == 0)
-    def _init():
-        q = q_ref[...].astype(f32)                            # (Q*H, W)
-        new = new_ref[...].astype(f32)                        # (Q, W)
-        query = jax.lax.broadcasted_iota(
-            jnp.int32, (n_new * n_heads, 1), 0) // n_heads
-        s = [jnp.where(query >= k,
-                       jnp.sum(q * new[k:k + 1], axis=-1, keepdims=True),
-                       _NEG) for k in range(n_new)]
-        m = functools.reduce(jnp.maximum, s)     # row 0 is always seen
-        p = [jnp.exp(sk - m) for sk in s]
-        m_ref[...] = m
-        l_ref[...] = functools.reduce(jnp.add, p)
-        acc_ref[...] = functools.reduce(
-            jnp.add, [pk * new[k:k + 1, :rank] for k, pk in enumerate(p)])
+    q = q_ref[...].astype(f32)                                # (Q*H, W)
+    new = new_ref[...].astype(f32)                            # (Q, W)
+    query = jax.lax.broadcasted_iota(
+        jnp.int32, (n_new * n_heads, 1), 0) // n_heads
+    s = [jnp.where(query >= k,
+                   jnp.sum(q * new[k:k + 1], axis=-1, keepdims=True),
+                   _NEG) for k in range(n_new)]
+    m = functools.reduce(jnp.maximum, s)         # row 0 is always seen
+    p = [jnp.exp(sk - m) for sk in s]
+    m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(functools.reduce(jnp.add, p), l_ref.shape)
+    acc_ref[...] = functools.reduce(
+        jnp.add, [pk * new[k:k + 1, :rank] for k, pk in enumerate(p)])
 
-    _mla_fold_page(j, length, q_ref, page_ref, o_ref, acc_ref, m_ref, l_ref,
-                   page_size, n_pages, rank)
+    _mla_walk(tbl_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref, acc_ref,
+              m_ref, l_ref, buf_ref, sem_ref, ring_ref, page_size, rank)
 
 
 def _pallas_latent_verify(q, kv_new, kv_pages, layer, page_table, lengths,
                           rank, interpret):
     """``q (B, Q, H, W)`` scaled, in the pool's dtype; ``kv_new (B, Q,
-    W)``; the WHOLE pool ``(L, P, S, W)``; ``page_table (B, M)`` and
-    ``lengths (B,)`` (the tokens in the pool, the same for every query
-    of a row) ride scalar prefetch. Returns ``(B, Q, H, rank)``
+    W)``; the WHOLE pool ``(L, P, S, W)`` and the ``layer`` to read;
+    ``page_table (B, M)`` and ``lengths (B,)`` (the tokens in the pool,
+    the same for every query of a row). Returns ``(B, Q, H, rank)``
     float32."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     B, Q, H, W = q.shape
-    S = kv_pages.shape[2]
-    M = page_table.shape[1]
-    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
-    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
-    page_table = jnp.take_along_axis(page_table, columns, axis=1)
-
-    out = pl.pallas_call(
-        functools.partial(_mla_verify_kernel, page_size=S, n_pages=M,
-                          rank=rank, n_new=Q, n_heads=H),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, M),
-            in_specs=[
-                pl.BlockSpec((None, Q * H, W),
-                             lambda b, j, tbl, lens: (b, 0, 0)),
-                pl.BlockSpec((None, Q, W), lambda b, j, tbl, lens: (b, 0, 0)),
-                pl.BlockSpec((None, None, S, W),
-                             lambda b, j, tbl, lens:
-                             (layer, tbl[b * M + j], 0, 0))],
-            out_specs=pl.BlockSpec((None, Q * H, rank),
-                                   lambda b, j, tbl, lens: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((Q * H, rank), jnp.float32),
-                            pltpu.VMEM((Q * H, 1), jnp.float32),
-                            pltpu.VMEM((Q * H, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, Q * H, rank), jnp.float32),
-        interpret=interpret,
-        # rows x query positions x heads, the query positions a row, the
-        # table's full width in keys, the latent's width
-        name="mx_mla_decode.bh%d.q%d.k%d.d%d.%s.r%d.paged" % (
-            B * Q * H, Q, M * S, W, jnp.dtype(kv_pages.dtype).name, rank),
-    )(page_table.reshape(-1), lengths, q.reshape(B, Q * H, W), kv_new,
-      kv_pages)
+    out = _traced_once(_pallas_latent, "rank", "n_new", "interpret")(
+        q.reshape(B, Q * H, W), kv_new, kv_pages,
+        jnp.full((1,), layer, jnp.int32), page_table, lengths, rank=rank,
+        n_new=Q, interpret=interpret)
     return out.reshape(B, Q, H, rank)
 
 
@@ -1246,83 +1380,88 @@ def _jnp_block_decode(q, kc, vc, k_new, v_new, lengths):
     return out.reshape(B, Q, Hq, D)
 
 
-def _block_decode_kernel(tbl_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref,
-                         v_ref, o_ref, acc_ref, m_ref, l_ref, *, page_size,
-                         n_pages, n_kv, head_dim, n_new):
-    """Grid = (rows, table columns), columns innermost: one program
-    instance attends ALL query positions and heads of one row to ONE
-    page, read where it lies. A token's row in the pool packs its
-    ``n_kv`` key (or value) heads side by side, ``n_kv * head_dim``
-    lanes, so key/value head ``h`` of the page is the lane-aligned
-    ``(S, head_dim)`` slice ``[:, h * head_dim:(h + 1) * head_dim]``,
-    and the ``Q * (Hq // Hkv)`` query vectors that read it — every
-    position of the block, every query head of the group — are the rows
-    of ONE ``(R, head_dim) x (head_dim, S)`` product on the MXU, the
-    weighted sum one ``(R, S) x (S, head_dim)``: operands in the pool's
-    dtype, float32 accumulation, float32 running softmax. (The per-head
-    paged kernel is VPU arithmetic for one query vector a head.)
+def _block_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, kn_ref, vn_ref,
+                         k_pool, v_pool, o_ref, acc_ref, m_ref, l_ref, k_buf,
+                         v_buf, sem_ref, ring_ref, *, page_size, n_kv,
+                         head_dim, n_new):
+    """Grid = (rows,): one program instance attends ALL query positions
+    and heads of one row to the row's live pages, which it walks itself
+    (:func:`_walk_pages`: a K and a V page a step, read where they lie).
+    A token's row in the pool packs its ``n_kv`` key (or value) heads
+    side by side, ``n_kv * head_dim`` lanes, so key/value head ``h`` of
+    the page is the lane-aligned ``(S, head_dim)`` slice ``[:, h *
+    head_dim:(h + 1) * head_dim]``, and the ``Q * (Hq // Hkv)`` query
+    vectors that read it — every position of the block, every query
+    head of the group — are the rows of ONE ``(R, head_dim) x
+    (head_dim, S)`` product on the MXU, the weighted sum one ``(R, S) x
+    (S, head_dim)``: operands in the pool's dtype, float32
+    accumulation, float32 running softmax. (The per-head paged kernel
+    is VPU arithmetic for one query vector a head.)
 
     ``len_ref[b]`` counts the row's keys IN THE POOL, all visible to
     every query of the block; the block's own ``n_new`` keys and values
     (``kn_ref``/``vn_ref``, not in the pool) open the accumulation, on
-    the VPU, each visible to every query. Columns at or past ``ceil(len
-    / S)`` name the last live page again (nothing is fetched) and
-    compute nothing."""
+    the VPU, each visible to every query. The walk is ``ceil(len / S)``
+    pages long whatever the table's width: a table column past it is
+    never looked at. The running max and sum are ``(Hkv, R, 128)``,
+    every lane of a row the same value (:func:`_lanes`)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
-    j = pl.program_id(1)
     length = len_ref[b]
     f32 = jnp.float32
 
-    @pl.when(j == 0)
-    def _init():
-        for h in range(n_kv):
-            q = q_ref[h].astype(f32)                          # (R, D)
-            kn = kn_ref[h].astype(f32)                        # (n_new, D)
-            vn = vn_ref[h].astype(f32)
-            s = [jnp.sum(q * kn[i:i + 1], axis=-1, keepdims=True)
-                 for i in range(n_new)]                       # (R, 1) each
-            m = functools.reduce(jnp.maximum, s)
-            p = [jnp.exp(si - m) for si in s]
-            m_ref[h] = m
-            l_ref[h] = functools.reduce(jnp.add, p)
-            acc_ref[h] = functools.reduce(
-                jnp.add, [pi * vn[i:i + 1] for i, pi in enumerate(p)])
+    for h in range(n_kv):
+        q = q_ref[h].astype(f32)                              # (R, D)
+        kn = kn_ref[h].astype(f32)                            # (n_new, D)
+        vn = vn_ref[h].astype(f32)
+        s = [jnp.sum(q * kn[i:i + 1], axis=-1, keepdims=True)
+             for i in range(n_new)]                           # (R, 1) each
+        m = functools.reduce(jnp.maximum, s)
+        p = [jnp.exp(si - m) for si in s]
+        m_ref[h] = jnp.broadcast_to(m, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(functools.reduce(jnp.add, p),
+                                    l_ref.shape[1:])
+        acc_ref[h] = functools.reduce(
+            jnp.add, [pi * vn[i:i + 1] for i, pi in enumerate(p)])
 
-    @pl.when(j * page_size < length)
-    def _step():
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        for h in range(n_kv):
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            k = k_ref[:, lanes]                               # (S, D)
-            v = v_ref[:, lanes]
-            s = _dot(q_ref[h], k, _NT)                        # (R, S)
-            s = jnp.where(pos < length, s, _NEG)
-            m_prev = m_ref[h]
+    def fold(pages):
+        # every score product first, then the softmax chains in (page,
+        # head) order: each product is free to run under another's chain
+        todo = []
+        for j, (k_ref, v_ref) in pages:
+            pos = j * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page_size), 1)
+            for h in range(n_kv):
+                lanes = slice(h * head_dim, (h + 1) * head_dim)
+                s = _dot(q_ref[h], k_ref[:, lanes], _NT)      # (R, S)
+                todo.append((h, v_ref, lanes,
+                             jnp.where(pos < length, s, _NEG)))
+        for h, v_ref, lanes, s in todo:
+            v = v_ref[:, lanes]                               # (S, D)
+            m_prev = m_ref[h]                                 # (R, 128)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - _lanes(m_new, page_size))
             m_ref[h] = m_new
             l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1,
                                                   keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + _dot(p.astype(v.dtype), v)
+            acc_ref[h] = acc_ref[h] * _lanes(alpha, head_dim) \
+                + _dot(p.astype(v.dtype), v)
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        # the block's own keys are always live, so l > 0
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    _walk_pages(tbl_ref, len_ref, layer_ref[0], [k_pool, v_pool],
+                [k_buf, v_buf], sem_ref, ring_ref, fold)
+    # the block's own keys are always live, so l > 0
+    for h in range(n_kv):
+        o_ref[h] = (acc_ref[h] / _lanes(l_ref[h], head_dim)).astype(
+            o_ref.dtype)
 
 
-def _pallas_block_decode(q, k_new, v_new, k_pages, v_pages, layer,
-                         page_table, lengths, interpret):
-    """``q (B, Hkv, R, D)`` scaled, in the pool's dtype, ``R`` = query
-    positions x query heads a group; ``k_new``/``v_new (B, Hkv, Q, D)``;
-    the WHOLE pools ``(L, P, S, Hkv * D)`` (``layer`` is picked in the
-    index map); ``page_table (B, M)`` and ``lengths (B,)`` ride scalar
-    prefetch. Returns ``(B, Hkv, R, D)`` float32."""
+def _pallas_block(q, k_new, v_new, k_pages, v_pages, layer, page_table,
+                  lengths, *, interpret):
+    """:func:`_pallas_block_decode` with the ``layer (1,)`` an operand
+    (:func:`_traced_once`)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1331,26 +1470,28 @@ def _pallas_block_decode(q, k_new, v_new, k_pages, v_pages, layer,
     Q = k_new.shape[2]
     S = k_pages.shape[2]
     M = page_table.shape[1]
-    last = jnp.maximum((lengths + S - 1) // S, 1) - 1
-    columns = jnp.minimum(jax.lax.iota(jnp.int32, M)[None], last[:, None])
-    page_table = jnp.take_along_axis(page_table, columns, axis=1)
 
-    row = pl.BlockSpec((None, Hkv, R, D), lambda b, j, tbl, lens: (b, 0, 0, 0))
-    new = pl.BlockSpec((None, Hkv, Q, D), lambda b, j, tbl, lens: (b, 0, 0, 0))
-    page = pl.BlockSpec((None, None, S, Hkv * D),
-                        lambda b, j, tbl, lens: (layer, tbl[b * M + j], 0, 0))
+    row = pl.BlockSpec((None, Hkv, R, D), lambda b, *prefetch: (b, 0, 0, 0))
+    new = pl.BlockSpec((None, Hkv, Q, D), lambda b, *prefetch: (b, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_block_decode_kernel, page_size=S, n_pages=M,
-                          n_kv=Hkv, head_dim=D, n_new=Q),
+        functools.partial(_block_decode_kernel, page_size=S, n_kv=Hkv,
+                          head_dim=D, n_new=Q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, M),
-            in_specs=[row, new, new, page, page],
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[row, new, new, pool, pool],
             out_specs=row,
             scratch_shapes=[pltpu.VMEM((Hkv, R, D), jnp.float32),
-                            pltpu.VMEM((Hkv, R, 1), jnp.float32),
-                            pltpu.VMEM((Hkv, R, 1), jnp.float32)]),
+                            pltpu.VMEM((Hkv, R, _LANES), jnp.float32),
+                            pltpu.VMEM((Hkv, R, _LANES), jnp.float32)]
+            + _walk_scratch(
+                jax.ShapeDtypeStruct((S, Hkv * D), k_pages.dtype),
+                jax.ShapeDtypeStruct((S, Hkv * D), v_pages.dtype))),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), jnp.float32),
+        # the walk's copies run over the rows' ends: rows in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # rows x query heads, the block's query positions, the table's
         # full width in keys, the head size, then the key/value heads:
@@ -1359,7 +1500,21 @@ def _pallas_block_decode(q, k_new, v_new, k_pages, v_pages, layer,
         name="mx_block_decode.bh%d.q%d.k%d.d%d.%s.kv%d.paged" % (
             B * Hkv * R // Q, Q, M * S, D,
             jnp.dtype(k_pages.dtype).name, Hkv),
-    )(page_table.reshape(-1), lengths, q, k_new, v_new, k_pages, v_pages)
+    )(page_table.reshape(-1), lengths, layer, q, k_new, v_new, k_pages,
+      v_pages)
+
+
+def _pallas_block_decode(q, k_new, v_new, k_pages, v_pages, layer,
+                         page_table, lengths, interpret):
+    """``q (B, Hkv, R, D)`` scaled, in the pool's dtype, ``R`` = query
+    positions x query heads a group; ``k_new``/``v_new (B, Hkv, Q, D)``;
+    the WHOLE pools ``(L, P, S, Hkv * D)``, left in HBM for the kernel's
+    own copies, and the ``layer`` to read; ``page_table (B, M)`` and
+    ``lengths (B,)``. Returns ``(B, Hkv, R, D)`` float32."""
+    import jax.numpy as jnp
+    return _traced_once(_pallas_block, "interpret")(
+        q, k_new, v_new, k_pages, v_pages, jnp.full((1,), layer, jnp.int32),
+        page_table, lengths, interpret=interpret)
 
 
 def _block_write_kernel(pg_ref, slot_ref, new_ref, page_ref, out_ref, *,

@@ -342,9 +342,10 @@ def paged_latent_attention(kv_pages, page_table, positions, layer, q,
     Operands in the pool's dtype, accumulation and softmax in float32.
     On the TPU, where ``rank`` and the page size are multiples of 128,
     the Pallas kernel ``mx_mla_decode`` reads each row's live pages
-    where they lie; elsewhere :func:`gather_pages` + jnp, the kernel's
-    test reference. Counted as ``mla_decode_pallas`` /
-    ``mla_decode_jnp``."""
+    where they lie — one grid step a row, the kernel walking that row's
+    ``ceil(positions / S)`` pages itself, so the table's width costs
+    nothing; elsewhere :func:`gather_pages` + jnp, the kernel's test
+    reference. Counted as ``mla_decode_pallas`` / ``mla_decode_jnp``."""
     import jax.numpy as jnp
     from ..parallel.flash_attention import (_dispatch, _jnp_latent_decode,
                                             _pallas_latent_decode)
@@ -383,7 +384,8 @@ def paged_latent_causal_attention(kv_pages, page_table, positions, layer,
 
     On the TPU, where ``rank`` and the page size are multiples of 128,
     the Pallas kernel ``mx_mla_decode...q<Q>`` reads each row's live
-    pages where they lie, all ``Q * H`` query vectors of a row in one
+    pages where they lie (one grid step a row, the same page walk as the
+    one-query kernel), all ``Q * H`` query vectors of a row in one
     product a page; elsewhere :func:`gather_pages` + jnp, the kernel's
     test reference. Counted as ``mla_verify_pallas`` /
     ``mla_verify_jnp``."""
@@ -467,8 +469,9 @@ def paged_block_attention(k_pages, v_pages, page_table, positions, layer,
     Hkv * D)`` (:class:`_PackedHeadKV`). Operands in the pool's dtype,
     softmax and accumulation in float32. On the TPU a packed pool whose
     head size and pages are multiples of 128 takes the Pallas kernel
-    ``mx_block_decode`` (one MXU product a key/value head and live page);
-    everything else :func:`gather_pages` + jnp, the kernel's test
+    ``mx_block_decode`` (one grid step a row, which walks its own live
+    pages: one MXU product a key/value head and live page); everything
+    else :func:`gather_pages` + jnp, the kernel's test
     reference. Counted as ``block_decode_pallas`` / ``block_decode_jnp``."""
     import jax.numpy as jnp
     from ..parallel.flash_attention import (_dispatch, _jnp_block_decode,

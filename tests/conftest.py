@@ -43,3 +43,25 @@ def _seed_all(request):
     rep = getattr(request.node, "rep_call", None)
     if rep is not None and rep.failed:
         print("\nTo reproduce: MXNET_TEST_SEED=%d" % seed)
+
+
+@pytest.fixture
+def ragged_pages():
+    """``build(S, room, widen=1, dead=0) -> (table, positions)`` for the
+    paged kernels' tests: six rows in one batch whose keys in the pool
+    number 0 (nothing to read), 1, ``S - 1``, ``S``, ``S + 1`` and all
+    that 4 pages hold but ``room`` (the step's own rows: every column
+    live). Live pages are 1..9, each row's its own; the table is ``4 *
+    widen`` columns wide and every dead column names page ``dead`` (a
+    pool of 11 pages leaves 10 to poison)."""
+    def build(S, room, widen=1, dead=0):
+        positions = [0, 1, S - 1, S, S + 1, 4 * S - room]
+        table = np.full((len(positions), 4 * widen), dead, np.int32)
+        page = 1
+        for row, n in enumerate(positions):
+            live = -(-n // S)
+            table[row, :live] = np.arange(page, page + live)
+            page += live
+        assert page == 10
+        return table, positions
+    return build

@@ -483,18 +483,27 @@ def test_a_packed_pool_serves_a_one_position_step_too():
 # the Pallas kernels, interpreted, against the jnp paths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("positions", [[40, 16, 0], [60, 4, 12], [0, 0, 0]])
-def test_block_decode_kernel_matches_gather_reference(positions):
-    L, P, S, Hq, Hkv, D, Bn, Q = 2, 9, 16, 8, 2, 128, 3, 4
+@pytest.mark.parametrize("positions", [[40, 16, 0], [60, 4, 12], [0, 0, 0],
+                                       "ragged", "poisoned_tail"])
+def test_block_decode_kernel_matches_gather_reference(positions,
+                                                      ragged_pages):
+    L, P, S, Hq, Hkv, D, Q = 2, 11, 16, 8, 2, 128, 4
+    # ragged rows; a dead table tail (page 0, never live)
+    table = [[1, 2, 3, 7], [4, 5, 0, 0], [6, 0, 0, 0]]
+    poisoned = positions == "poisoned_tail"
+    if isinstance(positions, str):
+        # 0, 1, S-1, S, S+1 keys and a full table in one batch; the
+        # poisoned table is 4x wider than any row needs
+        table, positions = ragged_pages(S, Q, widen=4 if poisoned else 1)
+        D = 256            # the running max and sum repeated over 2 tiles
+    Bn = len(positions)
     k = jax.random.split(jax.random.PRNGKey(0), 5)
     kp = jax.random.normal(k[0], (L, P, S, Hkv * D)).astype(jnp.bfloat16)
     vp = jax.random.normal(k[1], (L, P, S, Hkv * D)).astype(jnp.bfloat16)
     q = jax.random.normal(k[2], (Bn, Q, Hq, D))
     kn = jax.random.normal(k[3], (Bn, Q, Hkv, D))
     vn = jax.random.normal(k[4], (Bn, Q, Hkv, D))
-    # ragged rows; a dead table tail (page 0, never live)
-    table = jnp.asarray([[1, 2, 3, 7], [4, 5, 0, 0], [6, 0, 0, 0]],
-                        jnp.int32)
+    table = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     before = dict(profiler.counters())
     for layer in (0, 1):
@@ -506,6 +515,16 @@ def test_block_decode_kernel_matches_gather_reference(positions):
         assert b.dtype == jnp.float32
         # the kernel rounds the softmax weights to bf16 for the MXU
         assert np.abs(np.asarray(a) - np.asarray(b)).max() < 0.03
+        if poisoned:
+            # every dead column names a page of NaN: a masked fold would
+            # not do (0 x NaN survives the value product), the walk must
+            # not read it — and reads what the clean table's walk reads
+            nan = jnp.full_like(kp[:, 0], jnp.nan)
+            c = kvcache.paged_block_attention(
+                kp.at[:, P - 1].set(nan), vp.at[:, P - 1].set(nan),
+                jnp.where(table == 0, P - 1, table), pos, layer, q, kn, vn,
+                force_pallas=True)
+            assert bool(jnp.isfinite(c).all()) and bool((c == b).all())
         # against gather_pages + a masked softmax written out here
         kc = np.asarray(kvcache.gather_pages(kp[layer:layer + 1], table)[0],
                         np.float32).reshape(Bn, -1, Hkv, D)
@@ -525,7 +544,8 @@ def test_block_decode_kernel_matches_gather_reference(positions):
     after = profiler.counters()
     for path in ("jnp", "pallas"):
         key = "block_decode_" + path
-        assert after.get(key, 0) - before.get(key, 0) == 2
+        assert after.get(key, 0) - before.get(key, 0) \
+            == 2 + 2 * (poisoned and path == "pallas")
 
 
 def test_block_write_kernel_is_the_row_writes():

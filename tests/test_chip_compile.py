@@ -484,3 +484,72 @@ def test_speculative_latent_programs_compile_and_fit(chip, monkeypatch):
     planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert planned < 12.5e9, mem
+
+
+def _walk_calls(M, layer=1):
+    """The three paged MXU kernels at a window of 8 rows and a table
+    ``M`` columns wide, reading ``layer``, as (function, arguments)
+    pairs."""
+    Bn, S, W, R, P = 8, 128, 640, 512, 8 * 4 + 1
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    pool, tbl, lens = sds((2, P, S, W), bf16), sds((Bn, M), i32), \
+        sds((Bn,), i32)
+    packed = sds((2, P, S, 4 * 128), bf16)
+    return {
+        "mla_decode.q1": (
+            lambda q, n, p, t, l: fa._pallas_latent_decode(
+                q, n, p, layer, t, l, R, False),
+            (sds((Bn, 128, W), bf16), sds((Bn, 1, W), bf16), pool, tbl,
+             lens)),
+        "mla_decode.q2": (
+            lambda q, n, p, t, l: fa._pallas_latent_verify(
+                q, n, p, layer, t, l, R, False),
+            (sds((Bn, 2, 32, W), bf16), sds((Bn, 2, W), bf16), pool, tbl,
+             lens)),
+        "block_decode": (
+            lambda q, kn, vn, kp, vp, t, l: fa._pallas_block_decode(
+                q, kn, vn, kp, vp, layer, t, l, False),
+            (sds((Bn, 4, 32, 128), bf16), sds((Bn, 4, 4, 128), bf16),
+             sds((Bn, 4, 4, 128), bf16), packed, packed, tbl, lens)),
+    }
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` of a jaxpr, those inside a ``jit`` too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["mla_decode.q1", "mla_decode.q2",
+                                    "block_decode"])
+def test_paged_mxu_kernels_take_one_grid_step_a_row(kernel):
+    """The kernel, not the grid, walks the pages: a table 10 wide and a
+    table 40 wide give the same ``(rows,)`` grid (read from the jaxpr),
+    the pools stay where they are (no block of them is an operand of the
+    pipeline) and the table's width is left in the name alone."""
+    for M in (10, 40):
+        fn, args = _walk_calls(M)[kernel]
+        calls = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+        assert len(calls) == 1
+        mapping = calls[0].params["grid_mapping"]
+        assert tuple(mapping.grid) == (8,), (kernel, M, mapping.grid)
+        pools = [bm for bm in mapping.block_mappings
+                 if bm.array_aval.shape[:1] == (2,)]
+        assert len(pools) == (2 if kernel == "block_decode" else 1)
+        assert all("<any>" in str(bm.transformed_block_aval)
+                   for bm in pools), pools
+        assert ".k%d." % (M * 128) in calls[0].params["name"]
+    # a program's calls, one a layer, are ONE traced kernel: the layer is
+    # an operand (baked in, every layer traced and lowered its own, 6 s
+    # of a seven-layer model's warm-up)
+    both = jax.make_jaxpr(lambda *a: [
+        _walk_calls(10, layer)[kernel][0](*a) for layer in (0, 1)])(*args)
+    inner = [e.params["jaxpr"] for e in both.eqns
+             if "jaxpr" in e.params and _pallas_calls(e.params["jaxpr"].jaxpr)]
+    assert len(inner) == 2 and inner[0] is inner[1]

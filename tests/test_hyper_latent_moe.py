@@ -751,18 +751,28 @@ def test_what_a_self_drafting_model_cannot_do_is_refused_with_a_typed_error(
 # the Pallas kernels, interpreted, against the jnp paths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("positions", [[40, 15, 0], [63, 1, 31], [0, 0, 0]])
-def test_verify_kernel_matches_the_two_query_reference(positions):
+@pytest.mark.parametrize("positions", [[40, 15, 0], [63, 1, 31], [0, 0, 0],
+                                       "ragged", "poisoned_tail"])
+def test_verify_kernel_matches_the_two_query_reference(positions,
+                                                       ragged_pages):
     """Both queries of a row against each live page in one product, the
     two new rows folded in triangularly; 15, 31 and 63 put the second
-    new row on the far side of a page boundary."""
-    L, P, S, W, R, H, B, Q = 2, 11, 16, 256, 128, 4, 3, 2
+    new row on the far side of a page boundary. ``ragged``: 0, 1, S-1,
+    S, S+1 keys and a full table in one batch; ``poisoned_tail``: the
+    same rows under a table 4x wider whose dead columns name a page of
+    NaN, which a walk that read it would carry into the output (0 x NaN
+    survives the value product)."""
+    L, P, S, W, R, H, Q = 2, 11, 16, 256, 128, 4, 2
+    table = [[1, 2, 3, 7, 8], [4, 5, 9, 0, 0], [6, 10, 0, 0, 0]]
+    poisoned = positions == "poisoned_tail"
+    if isinstance(positions, str):
+        table, positions = ragged_pages(S, Q, widen=4 if poisoned else 1)
+    B = len(positions)
     k = jax.random.split(jax.random.PRNGKey(0), 4)
     pool = jax.random.normal(k[0], (L, P, S, W)).astype(jnp.bfloat16)
     q = jax.random.normal(k[1], (B, Q, H, W))
     new = jax.random.normal(k[2], (B, Q, W))
-    table = jnp.asarray([[1, 2, 3, 7, 8], [4, 5, 9, 0, 0], [6, 10, 0, 0, 0]],
-                        jnp.int32)
+    table = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     profiler.reset_counters()
     run = functools.partial(kvcache.paged_latent_causal_attention, pool,
@@ -774,6 +784,12 @@ def test_verify_kernel_matches_the_two_query_reference(positions):
                                atol=2e-2, rtol=2e-2)
     counts = profiler.counters()
     assert counts["mla_verify_jnp"] == counts["mla_verify_pallas"] == 1
+    if poisoned:
+        bad = kvcache.paged_latent_causal_attention(
+            pool.at[:, P - 1].set(jnp.nan), jnp.where(table == 0, P - 1,
+                                                      table),
+            pos, 1, q, new, rank=R, scale=0.11, force_pallas=True)
+        assert bool(jnp.isfinite(bad).all()) and bool((bad == got).all())
     # query 0 of the pair is the one-query form; query 1 sees new row 0
     one = kvcache.paged_latent_attention(pool, table, pos, 1, q[:, 0],
                                          new[:, 0], rank=R, scale=0.11)

@@ -548,15 +548,24 @@ def test_contract_errors_name_the_widened_contract():
 # the Pallas kernels, interpreted, against the jnp paths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("positions", [[40, 16, 0], [63, 1, 15], [0, 0, 0]])
-def test_latent_decode_kernel_matches_gather_reference(positions):
-    L, P, S, W, R, H, B = 2, 9, 16, 256, 128, 4, 3
+@pytest.mark.parametrize("positions", [[40, 16, 0], [63, 1, 15], [0, 0, 0],
+                                       "ragged", "poisoned_tail"])
+def test_latent_decode_kernel_matches_gather_reference(positions,
+                                                       ragged_pages):
+    L, P, S, W, R, H = 2, 11, 16, 256, 128, 4
+    table = [[1, 2, 3, 7], [4, 5, 0, 0], [6, 0, 0, 0]]
+    poisoned = positions == "poisoned_tail"
+    if isinstance(positions, str):
+        # 0, 1, S-1, S, S+1 keys and a full table in one batch; the
+        # poisoned table is 4x wider than any row needs
+        table, positions = ragged_pages(S, 1, widen=4 if poisoned else 1)
+        W, R = 384, 256    # the running max and sum repeated over 2 tiles
+    B = len(positions)
     k = jax.random.split(jax.random.PRNGKey(0), 4)
     pool = jax.random.normal(k[0], (L, P, S, W)).astype(jnp.bfloat16)
     q = jax.random.normal(k[1], (B, H, W))
     new = jax.random.normal(k[2], (B, W))
-    table = jnp.asarray([[1, 2, 3, 7], [4, 5, 0, 0], [6, 0, 0, 0]],
-                        jnp.int32)
+    table = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     before = dict(profiler.counters())
     for layer in (0, 1):
@@ -568,15 +577,24 @@ def test_latent_decode_kernel_matches_gather_reference(positions):
         assert a.shape == b.shape == (B, H, R) and b.dtype == jnp.float32
         # the kernel rounds the softmax weights to bf16 for the MXU
         assert np.abs(np.asarray(a) - np.asarray(b)).max() < 0.02
+        if poisoned:
+            # every dead column names a page of NaN: a masked fold would
+            # not do (0 x NaN survives the value product), the walk must
+            # not read it — and reads what the clean table's walk reads
+            bad = jnp.where(table == 0, P - 1, table)
+            c = kvcache.paged_latent_attention(
+                pool.at[:, P - 1].set(jnp.nan), bad, pos, layer, q, new,
+                rank=R, scale=0.05, force_pallas=True)
+            assert bool(jnp.isfinite(c).all()) and bool((c == b).all())
     after = profiler.counters()
     assert after.get("mla_decode_jnp", 0) - before.get("mla_decode_jnp", 0) \
         == 2
     assert after.get("mla_decode_pallas", 0) \
-        - before.get("mla_decode_pallas", 0) == 2
+        - before.get("mla_decode_pallas", 0) == 2 + 2 * poisoned
     # position 0: nothing in the pool, the new token attends to itself
-    if positions[2] == 0:
-        assert np.abs(np.asarray(b[2])
-                      - np.asarray(new[2, :R].astype(jnp.bfloat16),
+    for row in np.flatnonzero(np.asarray(positions) == 0):
+        assert np.abs(np.asarray(b[row])
+                      - np.asarray(new[row, :R].astype(jnp.bfloat16),
                                    np.float32)).max() < 1e-6
 
 
