@@ -387,6 +387,14 @@ def _render_decode(page):
                            ("tokens_out", "tokens generated")):
             page.add("mxnet_decode_%s_total" % key, st.get(key),
                      labels=lab, kind="counter", help_=help_)
+        page.add("mxnet_decode_readback_wait_seconds_total",
+                 st.get("readback_wait_s"), labels=lab, kind="counter",
+                 help_="time the scheduler stood waiting for the device "
+                       "(over wall time: the host's slack)")
+        page.add("mxnet_decode_host_throttled_seconds_total",
+                 (st.get("host") or {}).get("throttled_s"), labels=lab,
+                 kind="counter",
+                 help_="time the process's cgroup CPU quota throttled it")
         page.add("mxnet_decode_queue_depth", st.get("queue_depth"),
                  labels=lab)
         page.add("mxnet_decode_active", st.get("active"), labels=lab,

@@ -229,7 +229,9 @@ contract (``tests/test_decode.py``, on the jnp AND Pallas paths).
 from __future__ import annotations
 
 import itertools
+import os
 import queue as _queue_mod
+import resource
 import threading
 import time
 from collections import deque
@@ -271,16 +273,21 @@ class _Step:
     """One dispatched decode step whose tokens are still on the device:
     the rows it ran (the index is the row's slot), which of them emit a
     token (a mid-suffix feed's output is discarded), the device token
-    array, and what ``stats()`` counts when it is read back."""
+    array, its launch number (``seq``: what its ``decode.dispatch``
+    span ``launch`` carries, and the ``decode.readback`` span that waits
+    for it), and what ``stats()`` counts when it is read back."""
 
-    __slots__ = ("rows", "emits", "toks", "pages_live", "ahead", "slots")
+    __slots__ = ("rows", "emits", "toks", "pages_live", "ahead", "slots",
+                 "seq", "launch")
 
-    def __init__(self, rows, emits, toks, pages_live, ahead):
+    def __init__(self, rows, emits, toks, pages_live, ahead, seq, launch):
         self.rows = rows
         self.emits = emits
         self.toks = toks
         self.pages_live = pages_live
         self.ahead = ahead
+        self.seq = seq
+        self.launch = launch
         # {id(row): its slot} in this step's output
         self.slots = {id(r): i for i, r in enumerate(rows)}
 
@@ -734,7 +741,18 @@ class DecodeServer:
                        "cow_degraded": 0, "cross_preempts": 0,
                        "admitted": 0, "queue_wait_s": 0.0,
                        "prefill_s": 0.0, "decode_pages_live": 0,
-                       "decode_pages_table": 0}
+                       "decode_pages_table": 0,
+                       "readback_wait_s": 0.0,
+                       "prefill_read_wait_s": 0.0}
+        # every program the scheduler thread hands to the device takes
+        # the next number (from 1, never reused; warm-up's take none):
+        # the span that launches it says ``seq``, the span that waits
+        # for it ``waits``, and a reader of the device's trace joins the
+        # device's programs to them by order. Counted by program, with
+        # the seconds inside the launch spans, from the spans' own stamps
+        self._seq = 0
+        self._launches = {"step": 0, "prefill": 0, "cow": 0}
+        self._launch_s = {"step": 0.0, "prefill": 0.0, "cow": 0.0}
         self._shed_by_priority = {}
         self._counted = {}        # the model's step counters, summed
         # a block model's passes, summed over the rows of every step read
@@ -1643,11 +1661,16 @@ class DecodeServer:
         pt = _np.zeros((self._max_pages,), _np.int32)
         pt[:len(req.pages)] = req.pages
         with tracing.span("decode.prefill", rung=rung) as pre:
+            self._seq = seq = self._seq + 1
             try:
                 with self._pool.step_lock:
-                    out = self._adopt_pool(self._prefill_progs[rung](
-                        req.params.tree, tokens, _np.int32(first), pt,
-                        *self._pool.arrays))
+                    with tracing.span("decode.prefill.launch", seq=seq,
+                                      program="prefill",
+                                      rung=rung) as launch:
+                        out = self._prefill_progs[rung](
+                            req.params.tree, tokens, _np.int32(first), pt,
+                            *self._pool.arrays)
+                    out = self._adopt_pool(out)
             except Exception as exc:   # noqa: BLE001 — model errors
                 self._retire([req], exc)   # belong to the request
                 return True
@@ -1671,6 +1694,7 @@ class DecodeServer:
                 with self._cond:
                     self._stats["prefill_steps"] += 1
                     self._stats["prefill_s"] += tracing.now() - pre.t0
+                    self._note_launch_locked("prefill", launch)
                 return True
             if self._prefix_on:
                 # the prefill just wrote K/V for every prompt position:
@@ -1679,12 +1703,14 @@ class DecodeServer:
                 # reference)
                 self._pool.prefix_insert(self._namespace(ver),
                                          req.prompt, req.pages)
+            with tracing.span("decode.prefill.read", waits=seq) as read:
+                if self._spec:
+                    # the first token and the first draft, one read
+                    tok, req.draft = (int(t) for t in _np.asarray(out[0]))
+                else:
+                    tok = int(out[0])
             if self._spec:
-                # the first token and the first draft, one read
-                tok, req.draft = (int(t) for t in _np.asarray(out[0]))
                 req.drafts.append(-1)
-            else:
-                tok = int(out[0])
         req._last_emit = pre.t1
         if req.trace_args is not None:
             rtid = tracing.track("req %s" % req.trace_args["request_id"])
@@ -1697,6 +1723,8 @@ class DecodeServer:
         with self._cond:
             self._stats["prefill_steps"] += 1
             self._stats["prefill_s"] += pre.t1 - pre.t0
+            self._stats["prefill_read_wait_s"] += read.t1 - read.t0
+            self._note_launch_locked("prefill", launch)
             self._stats["tokens_out"] += 1
             self._ttft.append((pre.t1 - req.t_submit) * 1e3)
         req.generated.append(tok)
@@ -1804,14 +1832,18 @@ class DecodeServer:
             self._preempt(victim)
             pg = self._pool.alloc(1, owner=self._owner)
         old, new = int(r.pages[pidx]), int(pg[0])
+        self._seq = seq = self._seq + 1
         with self._pool.step_lock:
-            out = self._cow_prog(*self._pool.arrays,
-                                 _np.int32(old), _np.int32(new))
+            with tracing.span("decode.cow.launch", seq=seq,
+                              program="cow") as launch:
+                out = self._cow_prog(*self._pool.arrays,
+                                     _np.int32(old), _np.int32(new))
             self._adopt_pool(out)
         self._pool.cow_release(old)
         r.pages[pidx] = new
         with self._cond:
             self._stats["cow_splits"] += 1
+            self._note_launch_locked("cow", launch)
         return "ok"
 
     def _degrade_private(self, r):
@@ -1932,10 +1964,12 @@ class DecodeServer:
         in front of ``prev`` in the program's signature; ``said``: what
         else the ``decode.dispatch`` span carries), then read back the
         step before it."""
+        self._seq = seq = self._seq + 1
         try:
-            with tracing.span("decode.dispatch", pages_live=pages_live,
-                              ahead=int(prev is not None), **said), \
-                    self._pool.step_lock:
+            with tracing.span("decode.dispatch", seq=seq, program="step",
+                              pages_live=pages_live,
+                              ahead=int(prev is not None),
+                              **said) as launch, self._pool.step_lock:
                 toks = self._adopt_pool(self._decode_prog(
                     ver.tree, *feed,
                     self._no_prev if prev is None else prev.toks, src,
@@ -1949,7 +1983,7 @@ class DecodeServer:
         for r, emit in zip(rows, emits):
             r.unread += emit
         self._unread = _Step(rows, emits, toks, pages_live,
-                             prev is not None)
+                             prev is not None, seq, launch)
         if prev is not None:
             self._read(prev)
 
@@ -2034,7 +2068,7 @@ class DecodeServer:
         request ends when its last block settles, uncommitted."""
         D, B = self._window, self._block
         try:
-            with tracing.span("decode.readback") as back:
+            with tracing.span("decode.readback", waits=step.seq) as back:
                 toks, step.toks = _np.asarray(step.toks), None
                 out = toks[:D * (B + 2)].reshape(D, B + 2)
                 live = [(i, r) for i, r in enumerate(step.rows)
@@ -2081,7 +2115,7 @@ class DecodeServer:
             emit.set(emitted=len(pushed))
             with self._cond:
                 st, bl = self._stats, self._blocks
-                self._note_step_locked(step, model_counts)
+                self._note_step_locked(step, model_counts, back)
                 bl["commit_passes"] += counts["blocks_committed"]
                 bl["denoise_passes"] += \
                     len(live) - counts["blocks_committed"]
@@ -2149,7 +2183,7 @@ class DecodeServer:
         for r in step.rows:
             r.unread -= 1
         try:
-            with tracing.span("decode.readback") as back:
+            with tracing.span("decode.readback", waits=step.seq) as back:
                 toks, step.toks = _np.asarray(step.toks), None
                 out = toks[:D * _SPEC_OUT].reshape(D, _SPEC_OUT)
                 live = [(i, r) for i, r in enumerate(step.rows)
@@ -2181,7 +2215,7 @@ class DecodeServer:
             emit.set(emitted=len(pushed))
             with self._cond:
                 st, sp = self._stats, self._specs
-                self._note_step_locked(step, model_counts)
+                self._note_step_locked(step, model_counts, back)
                 sp["drafts_verified"] += len(live)
                 sp["drafts_accepted"] += accepted
                 sp["positions_run"] += len(live) * (self._spec + 1)
@@ -2236,7 +2270,7 @@ class DecodeServer:
         for r, emits in zip(step.rows, step.emits):
             r.unread -= emits
         try:
-            with tracing.span("decode.readback") as back:
+            with tracing.span("decode.readback", waits=step.seq) as back:
                 # the last reference of this side to the step's device
                 # token array goes here, so that freeing it (0.3 ms on
                 # the chip) is timed
@@ -2260,7 +2294,7 @@ class DecodeServer:
             emit.set(emitted=len(emitting))
             finished = []
             with self._cond:
-                self._note_step_locked(step, counts)
+                self._note_step_locked(step, counts, back)
                 for i, r in emitting:
                     self._stats["tokens_out"] += 1
                     if r._last_emit is not None:
@@ -2309,11 +2343,20 @@ class DecodeServer:
         return dict(zip(self._counters[1],
                         (int(c) for c in toks[first:])))
 
-    def _note_step_locked(self, step, counts):
+    def _note_launch_locked(self, program, launch):
+        """One program handed to the device into ``stats()`` (under
+        ``self._cond``), the seconds from its launch span's stamps."""
+        self._launches[program] += 1
+        self._launch_s[program] += launch.t1 - launch.t0
+
+    def _note_step_locked(self, step, counts, back):
         """One step READ BACK into ``stats()`` (under ``self._cond``):
-        the step itself, whether it was dispatched ahead, the pages it
-        had to stream, and the model's own counters."""
+        the step itself and its launch, how long its read-back span
+        ``back`` stood waiting for it, whether it was dispatched ahead,
+        the pages it had to stream, and the model's own counters."""
         st = self._stats
+        self._note_launch_locked("step", step.launch)
+        st["readback_wait_s"] += back.t1 - back.t0
         st["decode_steps"] += 1
         st["decode_steps_ahead"] += step.ahead
         st["decode_pages_live"] += step.pages_live
@@ -2349,7 +2392,19 @@ class DecodeServer:
         ``stop``; ``fault``: a planned ``serve_decode`` raise;
         ``cow_degraded``; ``error``: a dispatch or read-back raised).
         A token's stamp — ``inter_token_ms``, ``ttft_ms`` — is the
-        moment its step was read back."""
+        moment its step was read back. ``launches`` counts the programs
+        the scheduler handed to the device (``step``, counted like
+        ``decode_steps`` when read back; ``prefill``; ``cow``) and
+        ``launch_s`` the seconds inside their launch spans;
+        ``readback_wait_s`` is the time inside ``decode.readback``: how
+        long the scheduler, its own work done, stood waiting for the
+        device (over the elapsed time it is the host's slack: near 0 the
+        host paces the loop); ``prefill_read_wait_s`` the same for a
+        prefill's first token. ``host`` is read when this is called and
+        at no other time: the container's CPU throttling
+        (``throttled_s``, ``nr_throttled``; 0 where the cgroup keeps no
+        count and has no quota, left out where nothing says) and the
+        process's ``involuntary_switches``."""
         elapsed = max(tracing.now() - self._t0, 1e-9)
         with self._cond:
             s = dict(self._stats)
@@ -2366,6 +2421,8 @@ class DecodeServer:
             blocks = dict(self._blocks)
             specs = dict(self._specs)
             drains = dict(self._drains)
+            launches = dict(self._launches)
+            launch_s = dict(self._launch_s)
         steps = s["prefill_steps"] + s["decode_steps"]
         out = {
             "name": getattr(self, "_metrics_label", None)
@@ -2395,6 +2452,11 @@ class DecodeServer:
             "admitted": s["admitted"],
             "queue_wait_s": s["queue_wait_s"],
             "prefill_s": s["prefill_s"],
+            "launches": launches,
+            "launch_s": launch_s,
+            "readback_wait_s": s["readback_wait_s"],
+            "prefill_read_wait_s": s["prefill_read_wait_s"],
+            "host": _host_stats(),
             "decode_pages_live": s["decode_pages_live"],
             "decode_pages_table": s["decode_pages_table"],
             "kv": self._pool.stats(),
@@ -2459,6 +2521,47 @@ class DecodeServer:
             if "owners" in kv:
                 px["owners"] = kv["owners"]
             telemetry.prefix_cache_event(px)
+
+
+def _host_stats():
+    """What the host did to this process, cumulative: the seconds and
+    the number of periods its cgroup was throttled by a CPU quota (v2
+    ``throttled_usec``, v1 ``throttled_time``; 0 where the group keeps
+    no count and says it has no quota; left out where nothing says) and
+    the context switches it did not ask for."""
+    out = {"involuntary_switches":
+           resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw}
+
+    def read(path):
+        try:
+            with open(path) as f:
+                return f.read()
+        except OSError:
+            return ""
+
+    for line in read("/proc/self/cgroup").splitlines():
+        _, controllers, path = line.split(":", 2)
+        if controllers and "cpu" not in controllers.split(","):
+            continue
+        # under a cgroup namespace the process's own group is the mount
+        for sub in (path.lstrip("/"), ""):
+            group = os.path.join("/sys/fs/cgroup", controllers, sub)
+            stat = dict(row.split() for row in read(
+                os.path.join(group, "cpu.stat")).splitlines()
+                if len(row.split()) == 2)
+            if "throttled_usec" in stat:
+                out["throttled_s"] = int(stat["throttled_usec"]) / 1e6
+            elif "throttled_time" in stat:
+                out["throttled_s"] = int(stat["throttled_time"]) / 1e9
+            elif read(os.path.join(group, "cpu.max")).startswith("max") \
+                    or read(os.path.join(
+                        group, "cpu.cfs_quota_us")).strip() == "-1":
+                out["throttled_s"] = 0.0        # no quota to run out of
+            else:
+                continue
+            out["nr_throttled"] = int(stat.get("nr_throttled", 0))
+            return out
+    return out
 
 
 def req_deadline(deadline_s):

@@ -524,6 +524,10 @@ def test_decode_metrics_gauges():
             in page
         assert 'mxnet_decode_kv_pages{server="gauges"}' in page
         assert 'mxnet_decode_weight_version{server="gauges"} 1' in page
+        # the host's slack: three steps were read back and waited for
+        wait, = (line for line in page.splitlines() if line.startswith(
+            'mxnet_decode_readback_wait_seconds_total{server="gauges"}'))
+        assert float(wait.split()[-1]) > 0
     finally:
         srv.stop()
     # a stopped server leaves the scrape

@@ -131,6 +131,8 @@ PARENT = {
     "mx:decode.reap": "mx:decode.tick",
     "mx:decode.admit": "mx:decode.tick",
     "mx:decode.prefill": "mx:decode.admit",
+    "mx:decode.prefill.launch": "mx:decode.prefill",
+    "mx:decode.prefill.read": "mx:decode.prefill",
     "mx:decode.pages": "mx:decode.tick",
     "mx:decode.build": "mx:decode.tick",
     "mx:decode.dispatch": "mx:decode.tick",
@@ -212,6 +214,34 @@ def test_dispatch_carries_the_steps_live_pages(lines):
     assert sum(s["pages_live"] for s in steps) == 3 + 6 + 9
 
 
+def test_every_launch_carries_its_number_and_every_wait_names_one(lines):
+    """The scheduler's launches in the order it made them: numbers from 1
+    (warm-up's programs take none), each one more than the last; every
+    span that waits names a launch made before it, of its own kind, at
+    most once."""
+    lines, _ = lines
+    launched = sorted(
+        (ev for name in ("mx:decode.dispatch", "mx:decode.prefill.launch")
+         for _, ev in _named(lines, name)), key=lambda ev: ev[1])
+    assert [ev[3]["seq"] for ev in launched] \
+        == list(range(1, len(launched) + 1))
+    kinds = {"mx:decode.dispatch": "step",
+             "mx:decode.prefill.launch": "prefill"}
+    assert all(ev[3]["program"] == kinds[ev[0]] for ev in launched)
+    assert [ev[3]["rung"] for ev in launched
+            if ev[0] == "mx:decode.prefill.launch"] == [16, 16, 16]
+    by_seq = {ev[3]["seq"]: ev for ev in launched}
+    for name, kind in (("mx:decode.readback", "step"),
+                       ("mx:decode.prefill.read", "prefill")):
+        waits = [ev for _, ev in _named(lines, name)]
+        seqs = [ev[3]["waits"] for ev in waits]
+        assert len(set(seqs)) == len(seqs)
+        assert set(seqs) == {n for n, ev in by_seq.items()
+                             if ev[3]["program"] == kind}
+        # a wait begins after the launch it names has returned
+        assert all(by_seq[ev[3]["waits"]][2] <= ev[1] for ev in waits)
+
+
 def test_h2d_carries_bytes_and_the_array_name(lines):
     lines, _ = lines
     h2d = [ev[3] for _, ev in _named(lines, "mx:pipeline.h2d")]
@@ -226,16 +256,25 @@ def test_no_session_no_ring_nothing_recorded():
     telemetry.reset()
     tracing.reset()
     assert not TraceAnnotation.is_enabled()
-    seen = []
-    real = tracing._Annotation
+    seen, opened = [], set()
+    real, real_span = tracing._Annotation, tracing.span
     tracing._Annotation = type(
         "Spy", (), {"is_enabled": staticmethod(real.is_enabled),
                     "__init__": lambda self, *a, **k: seen.append(a)})
+
+    def spying(name, /, *a, **k):
+        opened.add(name)
+        return real_span(name, *a, **k)
+
+    tracing.span = spying
     try:
         _serve()
     finally:
-        tracing._Annotation = real
+        tracing._Annotation, tracing.span = real, real_span
     assert not seen and tracing.stats() is None
+    # the spans were opened all the same: they are what the counters read
+    assert {"decode.dispatch", "decode.readback", "decode.prefill.launch",
+            "decode.prefill.read"} <= opened
 
 
 # --- the decode scheduler's counters ---------------------------------------
@@ -262,7 +301,8 @@ def scripted(monkeypatch):
     srv.stop(drain=False)
 
 
-COUNTERS = ("admitted", "queue_wait_s", "prefill_s")
+COUNTERS = ("admitted", "queue_wait_s", "prefill_s", "readback_wait_s",
+            "prefill_read_wait_s")
 
 
 @pytest.mark.parametrize("key", COUNTERS)
@@ -346,3 +386,227 @@ def test_counters_survive_threads_asking(scripted):
             t.join(timeout=30)
     assert not bad and not any(t.is_alive() for t in threads)
     assert srv.stats()["admitted"] == 6
+
+
+# --- the launch numbers, by the scheduler's own spans ------------------------
+
+def _spied(srv, submit, between=None):
+    """Every span ``srv`` opens while it serves what ``submit`` hands it,
+    tick by tick, in the order they were opened. ``between`` is called
+    with the tick's number before each tick."""
+    spans = []
+    real_span = tracing.span
+
+    def spying(name, /, *a, **k):
+        sp = real_span(name, *a, **k)
+        spans.append(sp)
+        return sp
+
+    tracing.span = spying
+    try:
+        reqs = submit(srv)
+        n = 0
+        while not all(r.done() for r in reqs):
+            if between is not None:
+                between(n)
+            srv._tick()
+            n += 1
+            assert n < 800, "scheduler made no progress"
+        # a row that ended while its last step was unread ran one more
+        srv._tick()
+        assert srv._unread is None
+    finally:
+        tracing.span = real_span
+    return spans
+
+
+def _launches_and_waits(spans):
+    launches = [sp for sp in spans if "seq" in sp.args]
+    waits = [sp for sp in spans if "waits" in sp.args]
+    return launches, waits
+
+
+def _kind_server(kind):
+    """A tiny unstarted server of each form of the serving contract, and
+    what to ask of it."""
+    from mxnet_tpu.serving.block_diffusion import BlockDiffusionMoEDecoderLM
+    from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM
+    rs = np.random.RandomState(5)
+    if kind in ("token", "shared"):
+        model, params = _toy()
+        kw = dict(seq_ladder=(16,), page_size=4, pool_pages=32,
+                  prefix_cache=kind == "shared")
+        prompts = [np.arange(10, 22, dtype=np.int32)] * 3
+    elif kind == "block":
+        model = BlockDiffusionMoEDecoderLM(
+            vocab_size=96, hidden_size=64, num_hidden_layers=1,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+            moe_intermediate_size=128, num_experts=8,
+            num_experts_per_tok=2, rope_theta=1e6, block_length=4,
+            mask_token_id=95, denoising_steps=4,
+            remasking_strategy="low_confidence_dynamic",
+            confidence_threshold=0.9, rms_norm_eps=1e-6,
+            max_position_embeddings=512, use_pallas=False)
+        params = model.init_params(seed=3)
+        kw = dict(seq_ladder=(16,), page_size=16, pool_pages=24)
+        prompts = [rs.randint(0, 90, size=n).astype(np.int32)
+                   for n in (5, 9, 6)]
+    else:
+        yarn = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+                "mscale_all_dim": 1, "type": "yarn",
+                "original_max_position_embeddings": 4096}
+        model = LatentMoEDecoderLM(
+            vocab_size=256, hidden_size=128, num_hidden_layers=2,
+            num_attention_heads=4, q_lora_rank=64, kv_lora_rank=128,
+            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+            intermediate_size=256, moe_intermediate_size=128,
+            n_routed_experts=16, n_shared_experts=1,
+            num_experts_per_tok=4, n_group=1, topk_group=1,
+            routed_scaling_factor=2.0, first_k_dense_replace=1,
+            rope_theta=10000, rope_scaling=yarn, rms_norm_eps=1e-6,
+            max_position_embeddings=512, hc_mult=4, hc_sinkhorn_iters=20,
+            hc_eps=1e-6, mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30,
+            num_nextn_predict_layers=1, use_pallas=False)
+        params = model.init_params(seed=3)
+        kw = dict(seq_ladder=(32,), page_size=16, pool_pages=64)
+        prompts = [rs.randint(0, 256, size=n).astype(np.int32)
+                   for n in (5, 9, 6)]
+    srv = DecodeServer(model, params, max_new_tokens=8, window=2,
+                       start=False, name=kind, **kw)
+    return srv, lambda s: [s.submit(p, max_new_tokens=6) for p in prompts]
+
+
+@pytest.mark.parametrize("kind", ["token", "shared", "block", "spec"])
+def test_launch_numbers_in_every_form_of_the_contract(kind, monkeypatch):
+    """One-token, prefix-shared (a copy-on-write between the steps),
+    block and speculative serving on a clock of whole seconds: the
+    numbers run from 1 without a hole in launch order, a wait names a
+    launch of its kind that has returned, each at most once, steps are
+    read in the order they were launched, and ``stats()`` counts what
+    the spans say, to the second."""
+    clock = iter(range(1, 1000000))
+    monkeypatch.setattr(tracing, "now", lambda: float(next(clock)))
+    srv, submit = _kind_server(kind)
+    try:
+        srv.warmup()
+        spans = _spied(srv, submit)
+        st = srv.stats()
+    finally:
+        srv.stop(drain=False)
+    launches, waits = _launches_and_waits(spans)
+    assert [sp.args["seq"] for sp in launches] \
+        == list(range(1, len(launches) + 1))
+    names = {"step": "decode.dispatch", "prefill": "decode.prefill.launch",
+             "cow": "decode.cow.launch"}
+    assert all(sp.name == names[sp.args["program"]] for sp in launches)
+    by_seq = {sp.args["seq"]: sp for sp in launches}
+    kinds = {"decode.readback": "step", "decode.prefill.read": "prefill"}
+    seen = [sp.args["waits"] for sp in waits]
+    assert len(set(seen)) == len(seen)
+    for sp in waits:
+        launch = by_seq[sp.args["waits"]]
+        assert launch.args["program"] == kinds[sp.name]
+        assert launch.t1 <= sp.t0
+    steps = [n for n, sp in by_seq.items() if sp.args["program"] == "step"]
+    assert [sp.args["waits"] for sp in waits
+            if sp.name == "decode.readback"] == steps
+    prefills = [sp for sp in launches if sp.args["program"] == "prefill"]
+    reads = [sp for sp in waits if sp.name == "decode.prefill.read"]
+    if kind == "block":     # it emits no token: nothing to wait for
+        assert len(prefills) == 3 and not reads
+    elif kind == "shared":  # the second and third prompt hit the first's
+        assert len(prefills) == 1 == len(reads)
+        assert st["launches"]["cow"] >= 1
+    else:
+        assert [sp.args["waits"] for sp in reads] \
+            == [sp.args["seq"] for sp in prefills]
+    # the launch and the read lie inside the prefill span
+    outer = [sp for sp in spans if sp.name == "decode.prefill"]
+    assert all(any(o.t0 < sp.t0 and sp.t1 < o.t1 for o in outer)
+               for sp in prefills + reads)
+    # the counters, from the same stamps
+    for program in ("step", "prefill", "cow"):
+        mine = [sp for sp in launches if sp.args["program"] == program]
+        assert st["launches"][program] == len(mine)
+        assert st["launch_s"][program] == sum(sp.t1 - sp.t0 for sp in mine)
+    assert st["launches"]["step"] == st["decode_steps"]
+    assert st["readback_wait_s"] == sum(
+        sp.t1 - sp.t0 for sp in waits if sp.name == "decode.readback") > 0
+    assert st["prefill_read_wait_s"] == sum(sp.t1 - sp.t0 for sp in reads)
+
+
+def test_a_drain_reads_the_step_it_launched_last(scripted):
+    """A weight swap in mid-run makes the scheduler read the unread step
+    before it plans the next: that read-back names the last launch, and
+    the step after it is launched with nothing ahead of it."""
+    srv = scripted
+    model, params = _toy()
+
+    def swap(n):
+        if n == 3:
+            srv.swap_weights(params)
+
+    spans = _spied(srv, lambda s: [
+        s.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=4)
+        for _ in range(2)], between=swap)
+    assert srv.stats()["decode_drains"] == {"swap_weights": 1}
+    steps = [sp for sp in spans if sp.name in ("decode.dispatch",
+                                               "decode.readback")]
+    at, = (i for i, sp in enumerate(steps)
+           if sp.name == "decode.dispatch" and i
+           and steps[i - 1].name == "decode.readback"
+           and steps[i - 2].name == "decode.readback")
+    drained, before = steps[at - 1], steps[at - 2]
+    assert drained.args["waits"] == before.args["waits"] + 1
+    assert drained.args["waits"] == max(
+        sp.args["seq"] for sp in steps[:at] if sp.name == "decode.dispatch")
+    assert steps[at].args["ahead"] == 0
+    assert steps[at].args["seq"] > drained.args["waits"]
+
+
+# --- what the host did to the process ---------------------------------------
+
+def test_stats_says_what_the_host_did_to_the_process(scripted):
+    host = scripted.stats()["host"]
+    assert host["involuntary_switches"] >= 0
+    assert ("throttled_s" in host) == ("nr_throttled" in host)
+    if "throttled_s" in host:
+        assert host["throttled_s"] >= 0 and host["nr_throttled"] >= 0
+
+
+@pytest.mark.parametrize("layout,want", [
+    ("v2", {"throttled_s": 1.5, "nr_throttled": 12}),
+    ("v1", {"throttled_s": 0.25, "nr_throttled": 3}),
+    ("no_count_no_quota", {"throttled_s": 0.0, "nr_throttled": 0}),
+    ("no_count_a_quota", {}), ("no_cgroup", {})])
+def test_throttling_is_read_from_the_process_cgroup(monkeypatch, layout,
+                                                    want):
+    import io
+    from mxnet_tpu.serving import decode
+    files = {
+        "v2": {"/proc/self/cgroup": "0::/pod/box\n",
+               "/sys/fs/cgroup/pod/box/cpu.stat":
+               "usage_usec 9\nnr_periods 40\nnr_throttled 12\n"
+               "throttled_usec 1500000\n"},
+        # the group's own path is not mounted: the mount is the group
+        "v1": {"/proc/self/cgroup": "3:memory:/m\n2:cpu,cpuacct:/box\n",
+               "/sys/fs/cgroup/cpu,cpuacct/cpu.stat":
+               "nr_periods 9\nnr_throttled 3\nthrottled_time 250000000\n"},
+        # the chip's machine: a v1 group with no cpu.stat and no quota
+        "no_count_no_quota": {
+            "/proc/self/cgroup": "2:cpuacct:/box\n1:cpu:/box\n",
+            "/sys/fs/cgroup/cpu/cpu.cfs_quota_us": "-1\n"},
+        "no_count_a_quota": {"/proc/self/cgroup": "0::/\n",
+                             "/sys/fs/cgroup/cpu.stat": "usage_usec 9\n",
+                             "/sys/fs/cgroup/cpu.max": "200000 100000\n"},
+        "no_cgroup": {}}[layout]
+
+    def fake_open(path, *a, **k):
+        if path not in files:
+            raise FileNotFoundError(path)
+        return io.StringIO(files[path])
+
+    monkeypatch.setattr(decode, "open", fake_open, raising=False)
+    got = decode._host_stats()
+    assert got.pop("involuntary_switches") >= 0
+    assert got == want
