@@ -1517,15 +1517,15 @@ def _pallas_block_decode(q, k_new, v_new, k_pages, v_pages, layer,
         page_table, lengths, interpret=interpret)
 
 
-def _block_write_kernel(pg_ref, slot_ref, new_ref, page_ref, out_ref, *,
-                        n_new):
+def _block_write_kernel(pg_ref, slot_ref, layer_ref, new_ref, page_ref,
+                        out_ref, *, n_new):
     """One program instance puts one row's ``n_new`` new token rows of
     one layer into their page: the page comes in whole, leaves whole,
     and differs in rows ``slot .. slot + n_new - 1`` (selects over the
     block, so no store at a dynamic offset into packed 16-bit rows)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    del pg_ref
+    del pg_ref, layer_ref
     slot = slot_ref[pl.program_id(0)]
     rows = jax.lax.broadcasted_iota(jnp.int32, page_ref.shape, 0)
     out = page_ref[...]
@@ -1534,32 +1534,44 @@ def _block_write_kernel(pg_ref, slot_ref, new_ref, page_ref, out_ref, *,
     out_ref[...] = out
 
 
-def _pallas_block_write(pages, page_idx, slot, new, interpret):
-    """A block's new token rows ``new (L, B, Q, W)`` into the pool ``(L,
-    P, S, W)``, in place (the pool is aliased to the result): row ``b``'s
-    ``Q`` rows land in page ``page_idx[b]`` from ``slot[b]`` on, in every
-    layer — ``mx_latent_write`` for more than one row a row, and for the
-    same reason (XLA's own row writes into a 4-D pool copy it whole)."""
+def _pallas_block_rows(pages, page_idx, slot, layer, new, *, interpret):
+    """:func:`_pallas_block_write` with the first ``layer (1,)`` an
+    operand (:func:`_traced_once`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    L, P, S, W = pages.shape
-    B, Q = new.shape[1], new.shape[2]
+    S, W = pages.shape[2:]
+    n, B, Q = new.shape[:3]
     page = pl.BlockSpec((None, None, S, W),
-                        lambda b, l, pg, sl: (l, pg[b], 0, 0))
+                        lambda b, l, pg, sl, first: (first[0] + l, pg[b],
+                                                     0, 0))
     return pl.pallas_call(
         functools.partial(_block_write_kernel, n_new=Q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B, L),
+            num_scalar_prefetch=3, grid=(B, n),
             in_specs=[pl.BlockSpec((None, None, Q, W),
-                                   lambda b, l, pg, sl: (l, b, 0, 0)),
+                                   lambda b, l, pg, sl, first: (l, b, 0, 0)),
                       page],
             out_specs=page),
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
-        input_output_aliases={3: 0},
+        input_output_aliases={4: 0},
         interpret=interpret,
         name="mx_block_write.b%d.q%d.l%d.s%d.d%d.%s" % (
-            B, Q, L, S, W, pages.dtype.name),
-    )(page_idx, slot, new, pages)
+            B, Q, n, S, W, pages.dtype.name),
+    )(page_idx, slot, layer, new, pages)
+
+
+def _pallas_block_write(pages, page_idx, slot, new, layer, interpret):
+    """A block's new token rows ``new (n, B, Q, W)`` into the pool ``(L,
+    P, S, W)``, in place (the pool is aliased to the result): row ``b``'s
+    ``Q`` rows land in page ``page_idx[b]`` from ``slot[b]`` on, in the
+    ``n`` layers from ``layer`` on (all of them after a step's last
+    layer, or one from inside it) — ``mx_latent_write`` for more than
+    one row a row, and for the same reason (XLA's own row writes into a
+    4-D pool copy it whole)."""
+    import jax.numpy as jnp
+    return _traced_once(_pallas_block_rows, "interpret")(
+        pages, page_idx, slot, jnp.full((1,), layer, jnp.int32), new,
+        interpret=interpret)
 
 
 def flash_decode(q, k, v, lengths, scale=None, block_k=128,

@@ -36,10 +36,14 @@ positions by ``remasking_strategy``: ``low_confidence_static`` the
 ``low_confidence_dynamic`` every masked one with ``conf >
 confidence_threshold`` and, if those are fewer, the static rule's
 instead. An unmasked token is never masked again. Once nothing is
-masked a *commit pass* runs the final tokens and their keys and values
-are cached. So a forward pass is always ``block_length`` query positions
-a row, and the server's step program decides for each row whether it
-denoises or commits (``DecodeServer``'s docstring).
+masked the block is *committed*: a pass runs its final tokens and their
+keys and values are cached. The block that follows is known whole before
+that pass runs (all MASK, at known positions) and under the mask above
+its first denoising pass needs exactly what the commit writes, so the
+server runs the two as ONE pass over ``2 x block_length`` positions a
+row (:meth:`decode_block` over two blocks, the second dead for a row
+that is still denoising): a block's commit rides with the next block's
+first denoising pass (``DecodeServer``'s docstring).
 
 Prefill attention is the plain masked softmax in ``jax.numpy``: a
 256-token rung's scores are 8 MB, and the flash kernel would need a new
@@ -202,9 +206,13 @@ class BlockDiffusionMoEDecoderLM:
         k = self._rotate(self._rms(k, p[l + "k_g"]), positions)
         return q, k, v
 
-    def _ffn(self, i, x, p, routed=None):
+    def _ffn(self, i, x, p, routed=None, live=None):
         """``x (T, D)`` float32 -> ``(out (T, D), load (E_held,))``;
-        ``routed``, a list, is given the router's choice."""
+        ``routed``, a list, is given the router's choice. A position
+        that is not ``live (T,)`` chooses no expert: its choice is set
+        outside every chip's held range, which ``expert_ffn`` drops and
+        ``expert_load`` does not count, and its output is zero."""
+        import jax.numpy as jnp
         from ..parallel import moe
         l = "l%d." % i
         topi, topw = moe.route_softmax_topk(
@@ -212,6 +220,8 @@ class BlockDiffusionMoEDecoderLM:
             renormalize=self.renormalize)
         if routed is not None:
             routed.append(topi)
+        if live is not None:
+            topi = jnp.where(live[:, None], topi, self.n_experts)
         out = moe.expert_ffn(
             x, {n: p[l + "experts." + n]
                 for n in ("w_gate", "w_up", "w_down")},
@@ -280,17 +290,25 @@ class BlockDiffusionMoEDecoderLM:
         logits = self._mm(self._rms(h, p["out_g"]), p["head"])
         return logits, jnp.stack(ks), jnp.stack(vs)
 
-    def decode_block(self, params, tokens, positions, attend):
-        """One pass over a block a row: ``tokens (B, Q)`` at positions
-        ``positions[b] .. positions[b] + Q - 1``; ``attend(layer, q (B,
-        Q, Hq, Dh), k_new, v_new (B, Q, Hkv, Dh), scale=, force_pallas=)``
-        attends the row's committed keys and the block's own. Returns
-        ``(logits (B, Q, V) float32, k_new, v_new (n_layers, B, Q, Hkv,
-        Dh), counters)``."""
+    def decode_block(self, params, tokens, positions, attend, live=None,
+                     head=None):
+        """One pass over ``Q`` consecutive positions a row, a whole number
+        of blocks: ``tokens (B, Q)`` at positions ``positions[b] ..
+        positions[b] + Q - 1``; ``attend(layer, q (B, Q, Hq, Dh), k_new,
+        v_new (B, Q, Hkv, Dh), scale=, force_pallas=)`` attends what each
+        position may see (the server's step: ``kvcache._BlockStep``).
+        ``live (B, Q)`` bool: a position that is not live costs no expert
+        (nothing reads what it computes). ``head (B,)`` int32: only that
+        block of the row's ``Q // block_length`` reaches the final norm
+        and the head. Returns ``(logits (B, Q, V) float32 — ``(B,
+        block_length, V)`` under ``head`` —, k_new, v_new (n_layers, B,
+        Q, Hkv, Dh), counters)``."""
         import jax.numpy as jnp
         p = params
         B, Q = tokens.shape
         pos = positions[:, None] + jnp.arange(Q, dtype=jnp.int32)[None]
+        if live is not None:
+            live = live.reshape(B * Q)
         h = p["embed"][tokens].astype(jnp.float32)
         ks, vs, loads = [], [], []
         for i in range(self.n_layers):
@@ -300,11 +318,16 @@ class BlockDiffusionMoEDecoderLM:
                        force_pallas=self.use_pallas)
             h = h + self._mm(a.reshape(B, Q, -1), p[l + "wo"])
             x = self._rms(h, p[l + "ffn_g"])
-            out, load = self._ffn(i, x.reshape(B * Q, -1), p)
+            out, load = self._ffn(i, x.reshape(B * Q, -1), p, live=live)
             h = h + out.reshape(B, Q, -1)
             ks.append(k)
             vs.append(v)
             loads.append(load)
+        if head is not None:
+            blocks = h.reshape(B, Q // self.block_length,
+                               self.block_length, -1)
+            h = jnp.take_along_axis(
+                blocks, head[:, None, None, None], axis=1)[:, 0]
         logits = self._mm(self._rms(h, p["out_g"]), p["head"])
         return logits, jnp.stack(ks), jnp.stack(vs), self._counters(loads)
 

@@ -126,48 +126,71 @@ layout carries:
 BlockDiffusionMoEDecoderLM``): a model that generates by diffusion over
 blocks declares ``block_length`` and, in ``decode``'s place,
 
-- ``model.decode_block(params, tokens, positions, attend) -> (logits,
-  *new[, counters])`` — ``tokens (B, Q)`` a block a row at positions
-  ``positions[b] ..``; ``attend(layer, q (B, Q, Hq, D), k_new, v_new
-  (B, Q, Hkv, D), scale=, force_pallas=)`` attends the row's
-  ``positions[b]`` committed keys and the block's own, every one
-  visible to every query of the block, over fewer key/value than query
-  heads (``kvcache.paged_block_attention``); ``logits (B, Q, V)``; one
-  ``new`` a declared array, ``(n_layers, B, Q, *trailing)``;
+- ``model.decode_block(params, tokens, positions, attend, live=, head=)
+  -> (logits, *new[, counters])`` — ``tokens (B, Q)``, ``Q`` a whole
+  number of blocks a row at positions ``positions[b] ..``; ``attend(
+  layer, q (B, Q, Hq, D), k_new, v_new (B, Q, Hkv, D), scale=,
+  force_pallas=)`` attends what each position may see, over fewer
+  key/value than query heads (``kvcache._BlockStep`` over
+  ``kvcache.paged_block_attention``); a position that is not ``live (B,
+  Q)`` costs no expert; only block ``head[b]`` of a row reaches the
+  head: ``logits (B, block_length, V)``; one ``new`` a declared array,
+  ``(n_layers, B, Q, *trailing)``;
 - ``model.unmask(logits, tokens, masked) -> (tokens, masked)``, the
   model's own unmasking rule by its own settings, and
   ``model.mask_token_id``; ``model.prefill`` runs under the block-causal
   mask.
 
 The server's ONE step program (``_block_decode_fn``) then runs, for
-every row, one pass over its block: a row with something masked
-DENOISES (the rule unmasks positions in the program; nothing is
-written), a row with nothing masked COMMITS (its keys and values are
-written, ``Q`` rows a row). The row's block — tokens, what is masked —
-lives on the device from step to step and is fed from the unread
-step's output, because under a rule that decides on the device how many
-positions a pass unmasks the host does not know how a step ended when
-it dispatches the next; only the block's tokens and two flags a row
-leave the device. A block's tokens are pushed together, in order, when
-the denoising pass that settled its last position is read back (one
-step before the commit, which emits nothing); ``max_new_tokens`` and
-``eos_id`` cut the last block, whose commit is never run. The prefill
-commits the whole blocks of the prompt and emits no token — it is
-dispatched and not waited for; the prompt's remainder opens the first
-block of the answer as positions already unmasked. ``stats()["block"]``
-sums the passes (``denoise_passes``, ``commit_passes``,
-``tokens_unmasked``, ``blocks_committed``, ``max_passes_a_block``),
-``DecodeRequest.unmask_pass`` keeps for each token the pass of its block
-that unmasked it, ``mx:decode.dispatch`` says how many rows the host
-knows to be ``committing`` / ``denoising`` (``undecided``: the program
-decides from the unread step's output) and ``keys_live``,
-``mx:decode.readback`` the step's ``tokens_unmasked`` and
-``blocks_committed``. Weight swaps, cancellation, deadlines, priorities
-and preemption work as for any model (a block in the making is never
-cached, so a row ended in mid-block leaves nothing behind); prefix
-sharing (its suffix feed is one token a step), an int8 pool and a
-latent pool (whose block form is causal, not all-see-all) are refused
-with a typed error when the server is built.
+every row, one pass over TWO blocks' positions, ``[p, p + 2Q)`` for a row
+whose block starts at ``p``: a row with something masked DENOISES its
+block in the first half (the rule unmasks positions in the program;
+nothing is written; the second half is dead: it attends nothing, costs
+no expert, does not reach the head); a row with nothing masked COMMITS
+its block in the first half (its keys and values are written, ``Q`` rows
+a row, in each layer before the layer attends) and, in the same pass,
+the fresh block at ``p + Q`` — all MASK, known whole before the pass —
+has its FIRST DENOISING PASS in the second half, attending the row's
+committed keys, the block just committed and its own. So a block of
+``Q`` tokens that unmasks one position a pass costs ``Q`` passes, not
+``Q + 1``: its commit rides with the next block's first pass. Where the
+row ends at or before ``p + Q`` (prompt + ``max_new``, which the
+program is told) there is no fresh block and the commit runs by itself —
+the degenerate case, which the scheduler never plans (a row's last block
+ends the request when it settles, uncommitted) and only a step
+dispatched ahead of that read-back runs. The row's block — tokens, what
+is masked — lives on the device from step to step and is fed from the
+unread step's output, because under a rule that decides on the device
+how many positions a pass unmasks the host does not know how a step
+ended when it dispatches the next; only the tokens of the block that is
+now the row's and two flags a row leave the device (the kind of pass: 1
+denoised, 2 committed, 3 committed and denoised the block after). A
+block's tokens are pushed together, in order, when the denoising pass
+that settled its last position is read back (one step before the pass
+that caches them); ``max_new_tokens`` and ``eos_id`` cut the last block.
+The page of the block after the one a row works on is held one block
+early (a block lies in one page, the next may lie in the next), never a
+page past the row's end. The prefill commits the whole blocks of the
+prompt and emits no token — it is dispatched and not waited for; the
+prompt's remainder opens the first block of the answer as positions
+already unmasked. ``stats()["block"]`` sums the passes, one a row a step
+whatever it did (``denoise_passes``, a fused pass among them;
+``commit_passes``, the commits that ran by themselves;
+``fused_commits``; ``tokens_unmasked``; ``blocks_committed``, fused or
+not; ``max_passes_a_block``), ``DecodeRequest.unmask_pass`` keeps for
+each token the denoising pass of its block that unmasked it (0 the
+first: the fused pass is pass 0 of the new block),
+``mx:decode.dispatch`` says how many rows the host knows to be
+``committing`` / ``denoising`` (``undecided``: the program decides from
+the unread step's output) and ``keys_live`` (the committed keys the host
+KNOWS the step attends: a lower bound), ``mx:decode.readback`` the
+step's ``tokens_unmasked``, ``blocks_committed`` and ``blocks_fused``.
+Weight swaps, cancellation, deadlines, priorities and preemption work as
+for any model (a block in the making is never cached, so a row ended in
+mid-block leaves nothing behind); prefix sharing (its suffix feed is one
+token a step), an int8 pool and a latent pool (whose block form is
+causal, not all-see-all) are refused with a typed error when the server
+is built.
 
 **The speculative form of the contract** (``serving.latent_moe.
 LatentMoEDecoderLM`` with its next-token module): a model that drafts
@@ -697,8 +720,9 @@ class DecodeServer:
             donate = {"donate_argnums": tuple(range(4, 4 + n_pool))}
             # the step's pools come after the fed-back token array and
             # its slots, neither of which it may consume
-            # (a block step's after its block state, one array more)
-            first = 7 if self._block else 6
+            # (a block step's after its block state and its rows' ends,
+            # two arrays more)
+            first = 8 if self._block else 6
             step_donate = {"donate_argnums": tuple(range(first,
                                                          first + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
@@ -757,8 +781,8 @@ class DecodeServer:
         self._counted = {}        # the model's step counters, summed
         # a block model's passes, summed over the rows of every step read
         self._blocks = {"denoise_passes": 0, "commit_passes": 0,
-                        "tokens_unmasked": 0, "blocks_committed": 0,
-                        "max_passes_a_block": 0}
+                        "fused_commits": 0, "tokens_unmasked": 0,
+                        "blocks_committed": 0, "max_passes_a_block": 0}
         # a speculative model's steps, summed over the rows of every
         # step read
         self._specs = {"drafts_verified": 0, "drafts_accepted": 0,
@@ -886,43 +910,68 @@ class DecodeServer:
         _logits, *seqs = self._model.prefill(params, tokens)
         return layout.write_prefill(pools, page_table, seqs, n_valid)
 
-    def _block_decode_fn(self, params, x, state, positions, page_tables,
-                         prev, src, *pools):
-        """The block-step program: every row runs ONE pass over its
-        block of ``block_length`` positions, and the row's state says
-        which kind. ``x (D, B)`` are the block's tokens as they stand,
-        ``state (D,)`` the bits of the positions still masked (-1: no
-        row); a row whose state the host does not know yet — the step
-        before it, still unread, was a denoising pass under a rule that
-        decides on the device how many positions it unmasks — takes both
-        from that step's output where it lies, ``prev`` at slot
-        ``src[i]``. A row with something masked DENOISES: the model's
-        own rule (``model.unmask``) unmasks positions from the pass's
-        logits and nothing is written (its rows go to the dump page). A
-        row with nothing masked COMMITS: the block's keys and values are
-        written at its positions and its tokens stay. Out, a row: the
-        block's tokens, the bits still masked, the kind of pass (1
-        denoised, 2 committed, 0 no row); then the model's counters."""
+    def _block_decode_fn(self, params, x, state, positions, ends,
+                         page_tables, prev, src, *pools):
+        """The block-step program: every row runs ONE pass over ``2 x
+        block_length`` positions, ``[p, p + 2B)`` for a row whose block
+        starts at ``p = positions[i]``, and what the program finds in the
+        row's state says what the pass is. ``x (D, B)`` are the block's
+        tokens as they stand, ``state (D,)`` the bits of the positions
+        still masked (-1: no row), ``ends (D,)`` the position the row
+        ends at (prompt + ``max_new``); a row whose state the host does
+        not know yet — the step before it, still unread, was a denoising
+        pass under a rule that decides on the device how many positions
+        it unmasks — takes ``x`` and ``state`` from that step's output
+        where it lies, ``prev`` at slot ``src[i]``.
+
+        - A row with something masked DENOISES its block in the first
+          half: the model's own rule (``model.unmask``) unmasks positions
+          from the pass's logits and nothing is written (its rows go to
+          the dump page). Its second half is dead: it attends nothing,
+          costs no expert and does not reach the head.
+        - A row with nothing masked COMMITS its block in the first half —
+          the block's keys and values are written at its positions, in
+          every layer before the layer attends — and, in the same pass,
+          the FRESH block at ``p + B`` has its first denoising pass in the
+          second half: all MASK in, attending the row's committed keys,
+          the block just committed and its own; the rule unmasks on the
+          second half's logits. The row leaves the pass as the block at
+          ``p + B`` after one denoising pass.
+        - Where ``p + B`` is at or past the row's end there is no fresh
+          block: the commit runs by itself, the second half dead (what
+          the fused form degenerates to; the scheduler never plans it —
+          a row's last block ends the request when it settles — but a
+          step dispatched ahead of that read-back runs it).
+
+        Only the ``B`` positions a row that the rule reads reach the head.
+        Out, a row: the tokens of the block that is now the row's, the
+        bits still masked, the kind of pass (1 denoised, 2 committed, 3
+        committed and denoised the block after, 0 no row); then the
+        model's counters."""
         import jax.numpy as jnp
         D, B = self._window, self._block
         fed = prev[:D * (B + 2)].reshape(D, B + 2)[jnp.maximum(src, 0)]
         x = jnp.where((src >= 0)[:, None], fed[:, :B], x)
         state = jnp.where(src >= 0, fed[:, B], state)
-        bits = jnp.maximum(state, 0)
-        masked = ((bits[:, None] >> jnp.arange(B, dtype=jnp.int32)) & 1) \
-            .astype(bool)
+        bit = jnp.arange(B, dtype=jnp.int32)
+        masked = ((jnp.maximum(state, 0)[:, None] >> bit) & 1).astype(bool)
         commit = state == 0
+        fresh = jnp.logical_and(commit, positions + B < ends)
+        opened = jnp.full((D, B), self._model.mask_token_id, x.dtype)
+        live = jnp.repeat(jnp.stack([state >= 0, fresh], axis=1), B, axis=1)
         layout = kvcache.layout_for(self._model, pools)
-        attend = layout.attend_block(pools, page_tables, positions)
+        attend = layout.block_step(
+            pools, page_tables, positions, commit, fresh,
+            getattr(self._model, "use_pallas", False))
         logits, *new = self._model.decode_block(
-            params, x, positions, attend)
-        pools = layout.write_block(
-            pools, page_tables, positions, new[:len(layout.specs)],
-            commit, getattr(self._model, "use_pallas", False))
-        x_new, still = self._model.unmask(logits, x, masked)
-        left = jnp.sum(still.astype(jnp.int32)
-                       << jnp.arange(B, dtype=jnp.int32), axis=1)
-        kind = jnp.where(state < 0, 0, jnp.where(commit, 2, 1))
+            params, jnp.concatenate([x, opened], axis=1), positions, attend,
+            live=live, head=fresh.astype(jnp.int32))
+        x_new, still = self._model.unmask(
+            logits, jnp.where(fresh[:, None], opened, x),
+            jnp.logical_or(fresh[:, None], masked))
+        left = jnp.sum(still.astype(jnp.int32) << bit, axis=1)
+        kind = jnp.where(state < 0, 0,
+                         jnp.where(fresh, 3, jnp.where(commit, 2, 1)))
         out = jnp.concatenate(
             [x_new.astype(jnp.int32),
              jnp.where(state < 0, -1, left)[:, None],
@@ -930,7 +979,7 @@ class DecodeServer:
         if len(new) > len(layout.specs):
             out = jnp.concatenate(
                 [out, new[-1].astype(jnp.int32).reshape(-1)])
-        return (out, *pools)
+        return (out, *attend.pools)
 
     # -- the speculative forms (a model with ``draft_length``) -------------
     def _check_spec_model(self):
@@ -1176,7 +1225,8 @@ class DecodeServer:
                     # no rows (state -1): every write goes to the dump page
                     feed = (_np.zeros((self._window, self._block),
                                       _np.int32),
-                            _np.full((self._window,), -1, _np.int32), pos)
+                            _np.full((self._window,), -1, _np.int32), pos,
+                            pos)
                 elif self._spec:
                     feed = (_np.zeros((self._window, 2), _np.int32), pos)
                 else:
@@ -1747,14 +1797,15 @@ class DecodeServer:
                 continue               # preempted earlier in this pass
             failed = False
             while True:
-                # (a block model: the last position of the block the
-                # next step works on, which it may commit; a
+                # (a block model: the last position of the block AFTER
+                # the one the next step works on, whose first pass rides
+                # with that block's commit — held one block early — or of
+                # the block itself where the row ends before the next; a
                 # self-drafting one: the furthest its next step can
                 # write, two positions from where it starts, which is
                 # one or two past the start of a step still unread)
                 wp = r.pending_pos if r.pending \
-                    else self._next_block(r)[0] + self._block - 1 \
-                    if self._block \
+                    else self._last_block_position(r) if self._block \
                     else self._spec_position(r) + 1 + 2 * r.unread \
                     if self._spec \
                     else len(r.prompt) + len(r.generated) + r.unread - 1
@@ -1991,13 +2042,13 @@ class DecodeServer:
     # The host keeps each row's block AS OF THE LAST STEP READ BACK
     # (``DecodeRequest.blk_*``). The step being built runs while the step
     # before it is unread, and what that step did follows from the same
-    # state: with nothing masked it COMMITS the block, so the next step
-    # opens a fresh block whose state the host knows whole; with
-    # something masked it DENOISES, and under a rule that decides on the
-    # device how many positions a pass unmasks the host does not know how
-    # it ends — the next step takes the row's state from that step's
-    # output on the device, and the program decides there whether it
-    # denoises again or commits. Positions and pages are always known.
+    # state: with nothing masked it COMMITS the block and runs the first
+    # denoising pass of the block after it, so the next step works on
+    # that block; with something masked it DENOISES, and stays. Either
+    # way the row's state lies in the unread step's output — under a rule
+    # that decides on the device how many positions a pass unmasks the
+    # host does not know how a pass ends — and the program decides there
+    # what the next pass is. Positions and pages are always known.
     def _open_block(self, r, start, held=()):
         """Row ``r``'s state at the opening of the block at ``start``:
         ``held`` (prompt tokens) already unmasked, the rest masked."""
@@ -2012,15 +2063,27 @@ class DecodeServer:
 
     def _next_block(self, r):
         """``(start, known)`` of the block the NEXT step runs for ``r``:
-        where it starts, and ``"state"`` / ``"fresh"`` when the host
-        knows the block's state (as last read / a newly opened block),
-        None when it lies in the unread step's output."""
+        where it starts, and whether the host knows the block's state (as
+        last read) or it lies in the unread step's output."""
         prev = self._unread
         if prev is None or id(r) not in prev.slots:
-            return r.blk_start, "state"
-        if not any(r.blk_masked):         # the unread step commits it
-            return r.blk_start + self._block, "fresh"
-        return r.blk_start, None
+            return r.blk_start, True
+        # the unread step commits a block with nothing masked and leaves
+        # the row on the block after it, one denoising pass in
+        settled = not any(r.blk_masked)
+        return r.blk_start + (self._block if settled else 0), False
+
+    def _last_block_position(self, r):
+        """The last position the next step may run for ``r``: that of
+        the block after the one it works on, unless the row ends before
+        it (a block lies in one page, the next may lie in the next)."""
+        start = self._next_block(r)[0] + self._block
+        return start + (self._block if start < self._end(r) else 0) - 1
+
+    @staticmethod
+    def _end(r):
+        """The position ``r`` ends at: no block at or past it is run."""
+        return len(r.prompt) + r.max_new
 
     def _build_block_step(self, rows, prev):
         """The host's arrays of one block step, what its dispatch span
@@ -2030,42 +2093,57 @@ class DecodeServer:
             x = _np.zeros((D, B), _np.int32)
             state = _np.full((D,), -1, _np.int32)
             positions = _np.zeros((D,), _np.int32)
+            ends = _np.zeros((D,), _np.int32)
             pts = _np.zeros((D, M), _np.int32)
             src = _np.full((D,), -1, _np.int32)
             slots = {} if prev is None else prev.slots
-            fresh = (1 << B) - 1
             for i, r in enumerate(rows):
                 positions[i], known = self._next_block(r)
-                if known == "fresh":
-                    x[i], state[i] = self._model.mask_token_id, fresh
-                elif known:
+                ends[i] = self._end(r)
+                if known:
                     x[i] = r.blk_x
                     state[i] = sum(m << j for j, m
                                    in enumerate(r.blk_masked))
                 else:
-                    src[i], state[i] = slots[id(r)], fresh
+                    # any state but "no row": the program takes the
+                    # unread step's
+                    src[i], state[i] = slots[id(r)], (1 << B) - 1
                 pts[i, :len(r.pages)] = r.pages
             n = len(rows)
             pages_live = int(((positions[:n] + B - 1)
                               // self._pool.page_size + 1).sum())
-            fed = int((src[:n] >= 0).sum())
-            committing = int((state[:n] == 0).sum())
+            fed = src[:n] >= 0
+            committing = _np.logical_and(state[:n] == 0,
+                                         _np.logical_not(fed))
+            # the rows KNOWN to commit whose next block opens in the
+            # same pass, attending everything up to it
+            fusing = _np.logical_and(committing,
+                                     positions[:n] + B < ends[:n])
         # rows whose kind the program decides from the unread step's
         # output are neither yet: ``undecided``
-        said = {"committing": committing,
-                "denoising": n - committing - fed, "undecided": fed,
-                # the committed keys the step's rows attend to, in all
-                "keys_live": int(positions[:n].sum())}
-        return [0] * n, (x, state, positions, pts), src, pages_live, said
+        said = {"committing": int(committing.sum()),
+                "denoising": int(n - committing.sum() - fed.sum()),
+                "undecided": int(fed.sum()),
+                # the committed keys the host knows the step's rows
+                # attend to, in all: a lower bound (an undecided row
+                # that commits attends its fresh block's too)
+                "keys_live": int(positions[:n].sum()
+                                 + (positions[:n] + B)[fusing].sum())}
+        return [0] * n, (x, state, positions, ends, pts), src, \
+            pages_live, said
 
     def _read_block(self, step):
         """:meth:`_read` for a block step: every row's block after the
         pass. A denoising pass that leaves nothing masked SETTLES the
         block: its tokens are final (an unmasked token is never masked
         again) and are pushed together, in order, when that pass is read
-        back — one step before the commit that caches them, which emits
-        nothing. ``max_new`` and ``eos_id`` cut the last block; a
-        request ends when its last block settles, uncommitted."""
+        back — one step before the pass that caches them. That pass
+        (kind 3) commits the block and is the first denoising pass of
+        the block after it: the host opens the fresh block and reads what
+        the pass unmasked in it, which may settle it at once. A commit
+        that ran by itself (kind 2) emits nothing. ``max_new`` and
+        ``eos_id`` cut the last block; a request ends when its last
+        block settles, uncommitted."""
         D, B = self._window, self._block
         try:
             with tracing.span("decode.readback", waits=step.seq) as back:
@@ -2073,13 +2151,18 @@ class DecodeServer:
                 out = toks[:D * (B + 2)].reshape(D, B + 2)
                 live = [(i, r) for i, r in enumerate(step.rows)
                         if r.state == "active"]
+                kinds = [int(out[i, B + 1]) for i, _r in live]
+                # a committed-and-denoised row's block was all masked
                 unmasked = [
                     [j for j in range(B)
-                     if r.blk_masked[j] and not out[i, B] >> j & 1]
-                    if out[i, B + 1] == 1 else [] for i, r in live]
+                     if (kind == 3 or r.blk_masked[j])
+                     and not out[i, B] >> j & 1]
+                    if kind in (1, 3) else []
+                    for (i, r), kind in zip(live, kinds)]
                 counts = {"tokens_unmasked": sum(map(len, unmasked)),
-                          "blocks_committed": sum(
-                              int(out[i, B + 1] == 2) for i, _r in live)}
+                          "blocks_committed": sum(k in (2, 3)
+                                                  for k in kinds),
+                          "blocks_fused": kinds.count(3)}
                 model_counts = self._model_counts(toks, D * (B + 2))
                 back.set(**counts, **(model_counts or {}))
         except Exception as exc:       # noqa: BLE001 — the step's error
@@ -2090,9 +2173,10 @@ class DecodeServer:
         with tracing.span("decode.emit", rows=len(step.rows)) as emit:
             self._bill_step(step)
             pushed, finished = [], []
-            for (i, r), newly in zip(live, unmasked):
-                if out[i, B + 1] == 2:
+            for (i, r), kind, newly in zip(live, kinds, unmasked):
+                if kind in (2, 3):
                     self._open_block(r, r.blk_start + B)
+                if kind == 2:
                     continue
                 for j in newly:
                     r.blk_when[j] = r.blk_pass
@@ -2116,9 +2200,11 @@ class DecodeServer:
             with self._cond:
                 st, bl = self._stats, self._blocks
                 self._note_step_locked(step, model_counts, back)
-                bl["commit_passes"] += counts["blocks_committed"]
-                bl["denoise_passes"] += \
-                    len(live) - counts["blocks_committed"]
+                # a pass is one row's, whatever it did: a commit that
+                # rode with a denoising pass is that denoising pass
+                bl["commit_passes"] += kinds.count(2)
+                bl["denoise_passes"] += len(kinds) - kinds.count(2)
+                bl["fused_commits"] += counts["blocks_fused"]
                 bl["tokens_unmasked"] += counts["tokens_unmasked"]
                 bl["blocks_committed"] += counts["blocks_committed"]
                 for r in pushed:
@@ -2128,10 +2214,10 @@ class DecodeServer:
                     else:
                         self._ttft.append((now - r.t_submit) * 1e3)
                     r._last_emit = now
-                    # the passes of the block that just settled, with
-                    # the commit that follows
+                    # the passes the block that just settled took: its
+                    # commit rides with the next block's first
                     bl["max_passes_a_block"] = max(
-                        bl["max_passes_a_block"], r.blk_pass + 1)
+                        bl["max_passes_a_block"], r.blk_pass)
             self._retire(finished, None)
 
     # -- a self-drafting model's rows ---------------------------------------

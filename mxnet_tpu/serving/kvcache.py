@@ -44,8 +44,10 @@ fill a 16-bit dtype's sublane tile (grouped-query attention: 4
 key/value heads under 32 query heads) are packed into one row of ``H *
 D`` lanes a token, ``(n_layers, n_pages, page_size, H * D)``
 (:class:`_PackedHeadKV`, :func:`paged_block_attention`,
-:func:`write_block_rows` — which also serve a step that runs a BLOCK of
-query positions a row, over either per-head form). The layout alone knows which arrays a
+:func:`write_block_rows` — which also serve a step that runs BLOCKS of
+query positions a row, over either per-head form: a row's block and the
+block after it in one pass, the first committed from inside each layer
+where it is final, :class:`_BlockStep`). The layout alone knows which arrays a
 program carries, what ``attend`` a step hands its model and how a
 prefill's sequences and a step's new rows reach their pages; the
 server's three programs are written over it, once. The page accounting
@@ -517,12 +519,14 @@ def paged_block_attention(k_pages, v_pages, page_table, positions, layer,
 
 
 def write_block_rows(pages, page_table, positions, new, commit,
-                     force_pallas=False):
-    """A block step's new rows into the pool: ``new (L, B, Q, ...)``, row
-    ``b``'s ``Q`` token rows, land at positions ``positions[b] ..
-    positions[b] + Q - 1`` through its table row where ``commit[b]``,
-    and in the dump page where not (a denoising pass writes nothing: its
-    rows are not final). A block lies inside one page (the page size is a
+                     force_pallas=False, layer=0):
+    """A block step's new rows into the pool: ``new (n, B, Q, ...)``, row
+    ``b``'s ``Q`` token rows in the ``n`` layers from ``layer`` on (all
+    of them, written after a step's last layer; or one, written from
+    inside it), land at positions ``positions[b] .. positions[b] + Q -
+    1`` through its table row where ``commit[b]``, and in the dump page
+    where not (a block still being denoised writes nothing: its rows are
+    not final). A block lies inside one page (the page size is a
     multiple of the block length and blocks start at multiples of it).
     In-place row writes as :func:`scatter_token`'s; on the TPU a packed
     or latent pool ``(L, P, S, W)`` whose page tiles takes the Pallas
@@ -546,11 +550,11 @@ def write_block_rows(pages, page_table, positions, new, commit,
             rows = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)
             return jax.lax.dynamic_update_slice(
                 pages, rows,
-                (0, pidx[b], slot[b]) + (0,) * (pages.ndim - 3))
+                (layer, pidx[b], slot[b]) + (0,) * (pages.ndim - 3))
         return jax.lax.fori_loop(0, new.shape[1], write_row, pages)
 
     def kernel(interpret, pages, pidx, slot, new):
-        return _pallas_block_write(pages, pidx, slot, new, interpret)
+        return _pallas_block_write(pages, pidx, slot, new, layer, interpret)
 
     flat = pages.ndim == 4
     return _dispatch("block_write", pages.shape[-1] if flat else 1, (S,),
@@ -706,17 +710,78 @@ class _PerHeadKV:
     causal_blocks = False
 
     def attend_block(self, pools, page_tables, positions):
-        """The ``attend`` a block step hands its model."""
+        """The ``attend`` of a pass over ONE block a row: ``positions``
+        committed keys and the block's own."""
         return functools.partial(paged_block_attention, *pools,
                                  page_tables, positions)
 
     def write_block(self, pools, page_tables, positions, new, commit,
-                    force_pallas=False):
-        """A block step's new rows ``(L, B, Q, ...)`` an array into their
-        page where ``commit``, into the dump page where not."""
+                    force_pallas=False, layer=0):
+        """A pass's new rows ``(n, B, Q, ...)`` an array, of the ``n``
+        layers from ``layer`` on, into their page where ``commit``, into
+        the dump page where not."""
         return tuple(write_block_rows(pages, page_tables, positions, rows,
-                                      commit, force_pallas)
+                                      commit, force_pallas, layer)
                      for pages, rows in zip(pools, new))
+
+    def block_step(self, pools, page_tables, positions, commit, fresh,
+                   force_pallas=False):
+        """What a block step hands its model as ``attend``
+        (:class:`_BlockStep`)."""
+        return _BlockStep(self, pools, page_tables, positions, commit,
+                          fresh, force_pallas)
+
+
+class _BlockStep:
+    """The ``attend`` of a block step, which runs TWO blocks a row — the
+    row's block at ``positions[b]`` and the block after it — and the
+    pools as its layers leave them (``.pools``). Called once a layer with
+    ``q (B, 2Q, Hq, D)``, ``k_new``/``v_new (B, 2Q, Hkv, D)``:
+
+    - where ``commit[b]`` the first block's keys and values are FINAL and
+      are written into the layer's pages first (the dump page for every
+      other row), so that
+    - where ``fresh[b]`` the second block's queries read them there: they
+      attend ``positions[b] + Q`` keys in the pool — everything committed
+      before, and the block just committed, whole — and their own ``Q``;
+      where not, the second block is dead and attends none (length 0, and
+      its own keys, which nothing reads);
+    - the first block's queries attend ``positions[b]`` keys in the pool
+      and their own ``Q``, committing or not.
+
+    Both blocks go through :func:`paged_block_attention` together, as
+    ``2B`` rows of ``Q`` queries: one kernel call a layer."""
+
+    def __init__(self, layout, pools, page_tables, positions, commit,
+                 fresh, force_pallas):
+        import jax.numpy as jnp
+        self.layout, self.pools = layout, tuple(pools)
+        self.page_tables = jnp.asarray(page_tables, jnp.int32)
+        self.positions = jnp.asarray(positions, jnp.int32)
+        self.commit, self.fresh = commit, fresh
+        self.force_pallas = force_pallas
+        # both blocks of a row walk the row's table
+        self.both_tables = jnp.concatenate([self.page_tables] * 2)
+
+    def __call__(self, layer, q, k_new, v_new, *, scale=None,
+                 force_pallas=False):
+        import jax.numpy as jnp
+        B, Q = q.shape[0], q.shape[1] // 2
+
+        def rows(a):                # (B, 2Q, ...) -> (2B, Q, ...)
+            return jnp.concatenate([a[:, :Q], a[:, Q:]], axis=0)
+
+        self.pools = self.layout.write_block(
+            self.pools, self.page_tables, self.positions,
+            [k_new[None, :, :Q], v_new[None, :, :Q]], self.commit,
+            self.force_pallas, layer)
+        pos = self.positions
+        out = paged_block_attention(
+            *self.pools, self.both_tables,
+            jnp.concatenate([pos, jnp.where(self.fresh, pos + Q, 0)]),
+            layer, rows(q), rows(k_new), rows(v_new), scale=scale,
+            force_pallas=force_pallas)
+        return jnp.concatenate([out[:B], out[B:]], axis=1)
 
 
 class _PackedHeadKV(_PerHeadKV):

@@ -315,7 +315,9 @@ def test_block_diffusion_programs_compile_and_fit(chip, monkeypatch):
     pages of 128 tokens): the ONE block-step program and the 256-rung
     prefill compiled for one described v5e. In each: the Mosaic kernels
     under the names a profile's reader looks for — the paged block-decode
-    kernel a layer and the in-place block write an array (step), the two
+    kernel a layer, over a row's block and the block after it (2 x 32
+    kernel rows), and the in-place block write an array and layer, in
+    front of it (step), the two
     grouped matmuls of every expert layer a program needs (the prefill
     drops the last layer's: nothing reads its output) — the planned
     bytes inside the chip with room for the reference that decides
@@ -364,19 +366,26 @@ def test_block_diffusion_programs_compile_and_fit(chip, monkeypatch):
                           % kernel, text, re.M)
 
     step = jax.jit(lambda *a: DecodeServer._block_decode_fn(holder, *a),
-                   donate_argnums=(7, 8)).lower(
+                   donate_argnums=(8, 9)).lower(
         tree, spec((W, Q), jnp.int32), spec((W,), jnp.int32),
-        spec((W,), jnp.int32), spec((W, M), jnp.int32),
+        spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W, M), jnp.int32),
         spec((W * (Q + 2) + n_counts,), jnp.int32), spec((W,), jnp.int32),
         pool, pool).compile()
     text = step.as_text()
+    # a row's block and the block after it: 2 W kernel rows of Q queries
     assert len(named(text, "block_decode")) == L
     assert ".bh%d.q%d.k%d.d128.bfloat16.kv4.paged" % (
-        W * model.n_heads, Q, M * S) in text
-    assert len(named(text, "block_write")) == 2
+        2 * W * model.n_heads, Q, M * S) in text
+    # the committing rows' K and V, a layer, before the layer attends
+    assert len(named(text, "block_write")) == 2 * L
+    assert "mx_block_write.b%d.q%d.l1.s%d.d512.bfloat16" % (W, Q, S) in text
     assert len(named(text, "grouped_matmul")) == 2 * L
-    assert ".e128.m3072.k2048.n768.bfloat16.gated" in text
-    assert text.count('custom_call_target="tpu_custom_call"') == 3 * L + 2
+    assert ".e128.m4096.k2048.n768.bfloat16.gated" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 * L
+    # only Q positions a row reach the head
+    assert "f32[%d,%d,%d]" % (W, Q, model.vocab) in text
+    assert "f32[%d,%d,%d]" % (W, 2 * Q, model.vocab) not in text
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes, mem
     assert mem.temp_size_in_bytes < 0.1e9, mem      # no pool copy
