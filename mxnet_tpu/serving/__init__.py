@@ -29,15 +29,18 @@ KV cache, streaming, priorities, live weight swap) lives in
         for tok in req.tokens():                     # streams live
             ...
 
-Three models implement the decode-model contract: ``ToyDecoderLM``
+Four models implement the decode-model contract: ``ToyDecoderLM``
 (per-head K/V, one position a step), ``latent_moe.LatentMoEDecoderLM``
 (a latent cache, routed experts; with ``hc_mult`` > 1 several residual
 streams mixed by hyper-connections, and with its next-token module the
 SPECULATIVE form of the contract: the module drafts one token, a
-two-position verify step accepts it or overwrites it) and
+two-position verify step accepts it or overwrites it),
 ``block_diffusion.BlockDiffusionMoEDecoderLM`` (the BLOCK form of the
 contract: generation by diffusion over blocks, grouped-query K/V,
-softmax-routed experts).
+softmax-routed experts) and ``hybrid_linear_moe.
+HybridLinearMoEDecoderLM`` (the STATE form of the contract: delta-rule
+linear-attention layers whose state is a fixed array a row, held by the
+server beside the pages of a latent-attention layer a group).
 
 Fleet serving — a :class:`Router` fronting N decode replicas with
 per-tenant weighted-fair quotas, graceful drain, and transparent

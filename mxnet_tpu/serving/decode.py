@@ -244,6 +244,54 @@ sharing (its suffix feed is one token a step), an int8 pool and a
 per-head pool (no causal block form) are refused with a typed error
 when the server is built.
 
+**The state form of the contract** (``serving.hybrid_linear_moe.
+HybridLinearMoEDecoderLM``): a model whose layers are not all attention
+— a linear-attention layer carries a matrix a head and the last rows of
+a short convolution from token to token, the same bytes whatever the
+context — declares, beside ``cache_arrays``,
+
+- ``model.state_arrays = ((name, shape a row, dtype), ...)`` and
+  ``model.state_layers``: the server keeps one array a name, ``(state_
+  layers, window, *shape)``, behind the pool's pages (``serving.
+  kvcache``), donated through the same programs, counted in
+  ``stats()["state"]`` (``bytes``, ``rows``, ``rows_live``, ``writes``);
+  and ``model.cache_layers``, the layers that DO cache rows in pages,
+  which may be fewer than ``n_layers``: the model maps its layer to its
+  cache layer (what it asks ``attend`` for) or to its state layer;
+- ``model.prefill(params, tokens, lengths) -> (logits, *seqs, *state)``
+  — ``lengths (B,)`` the TRUE prompt lengths; one ``state`` a declared
+  array, ``(state_layers, B, *shape)``, as it stands after position
+  ``lengths - 1``: positions at or past it must leave it untouched;
+- ``model.decode(params, tokens, positions, attend, state) -> (logits,
+  *new, *state[, counters])`` — ``state`` is the step's
+  ``kvcache.RowState``: ``.arrays`` the declared arrays WHOLE, ``.slots
+  (B,)`` the row of them each row of the step works on (with its
+  ``.inverse``), ``.live (B,)``; the model returns the arrays updated
+  (in place, where its kernel aliases them), and a row that is not live
+  must leave its slot as it was.
+
+**A row's state belongs to its slot**: a request takes one of the
+window's rows (``DecodeRequest.slot``) when it is admitted and keeps it
+to its end; its prefill (``_state_prefill_fn``) writes the row WHOLE, so
+a slot's last tenant leaves nothing behind; every step
+(``_state_decode_fn``) is handed ``slots``, a permutation of ALL the
+window's rows — the live rows' own first, the rest in any order behind
+them, not live — so that two weight generations, each with its own step
+a pass, never touch each other's rows, and a weight swap leaves rows on
+old weights their state. Preemption fails the request like any other
+and frees its slot with its pages: whoever sends it again prefills
+again, and the state is rebuilt with the pages. A row that needs no
+further step (``_ending``) gives its slot to an admission at once — its
+last step is already dispatched, and the prefill orders itself behind
+it by the arrays, as page writes do. ``mx:decode.dispatch`` and
+``mx:decode.readback`` carry ``state_rows_live``, ``mx:decode.prefill``
+the ``state_slot`` it writes. The program set stays ``1 +
+len(ladder)``. Refused with a typed error when the server is built:
+prefix sharing (the index holds pages, not the state at a page
+boundary, and its suffix feed would have to start from one), the block
+and the speculative forms (a pass over several positions a row would
+have to keep the state after each until the verdict), an int8 pool.
+
 Sampling is greedy (argmax, in-program): deterministic by
 construction, which is what makes "prefill + stepwise cached decode
 reproduces the full-sequence forward token-for-token" a testable
@@ -330,7 +378,7 @@ class DecodeRequest:
                  "_t_trace", "pending", "pending_pos", "prefix_cached",
                  "unread", "blk_start", "blk_x", "blk_masked",
                  "blk_when", "blk_pass", "unmask_pass", "draft",
-                 "drafts")
+                 "drafts", "slot")
 
     def __init__(self, prompt, max_new, priority, deadline, eos_id,
                  request_id):
@@ -380,6 +428,9 @@ class DecodeRequest:
         # draft that was verified against it (-1: none)
         self.draft = 0
         self.drafts = []
+        # a state model's row: the row of the server's state arrays it
+        # holds from its admission to its end (None: none)
+        self.slot = None
 
     def done(self):
         return self._event.is_set()
@@ -620,6 +671,8 @@ class DecodeServer:
                     "draft_length has verify, draft, prefill_draft and "
                     "draft_prefill: serving.latent_moe)" % attr)
         specs, cache_dtype = kvcache.declared_arrays(model)
+        state, state_layers = kvcache.declared_state(model)
+        self._state = bool(state)
         # a model that drafts for itself caches its drafter's layers
         # behind its own
         n_layers = int(getattr(model, "cache_layers", model.n_layers))
@@ -627,6 +680,8 @@ class DecodeServer:
         self._model = model
         self.name = name
         self._device = device if device is not None else jax.devices()[0]
+        self._window = max(1, int(window) if window is not None
+                           else envs.get_int("MXNET_DECODE_WINDOW"))
 
         if seq_ladder is None:
             seq_ladder = BucketLadder.geometric(128, 16)
@@ -648,7 +703,8 @@ class DecodeServer:
                     "DecodeServer: page_size=%d does not match the "
                     "shared pool's %d" % (int(page_size),
                                           pool.page_size))
-            if (pool.n_layers, pool.array_specs) != (n_layers, specs):
+            if (pool.n_layers, pool.array_specs, pool.state_specs) \
+                    != (n_layers, specs, state):
                 raise MXNetError(
                     "DecodeServer: shared pool geometry (layers=%d, "
                     "arrays=%s) does not match the model's (%d, %s) — "
@@ -660,7 +716,9 @@ class DecodeServer:
                                      page_size=page_size,
                                      n_pages=pool_pages,
                                      dtype=cache_dtype,
-                                     device=self._device)
+                                     device=self._device, state=state,
+                                     state_layers=state_layers,
+                                     state_rows=self._window)
         self._owner = self._pool.attach(
             name or "model", quota=pool_quota, priority=pool_priority,
             preempt=self._pool_preempt_cb)
@@ -669,6 +727,8 @@ class DecodeServer:
             else envs.get_bool("MXNET_KV_PREFIX_CACHE")
         self._share_group = share_group
         self._preempt_asks = 0    # co-tenant give-back requests pending
+        if self._state:
+            self._check_state_model()
         if self._block:
             self._check_block_model()
         if self._spec:
@@ -699,8 +759,6 @@ class DecodeServer:
                 "MXNET_KV_POOL_PAGES or shrink the ladder/"
                 "max_new_tokens" % (self._max_pages,
                                     self._pool.usable_pages))
-        self._window = max(1, int(window) if window is not None
-                           else envs.get_int("MXNET_DECODE_WINDOW"))
         self._max_queue = max(1, int(max_queue))
         self._levels = max(1, envs.get_int("MXNET_SERVING_PRIORITIES"))
         self._default_deadline = (float(default_deadline_ms) / 1e3
@@ -717,12 +775,16 @@ class DecodeServer:
         donate = step_donate = cow_donate = {}
         n_pool = len(self._pool.arrays)
         if jax.default_backend() not in ("cpu",):
-            donate = {"donate_argnums": tuple(range(4, 4 + n_pool))}
+            # (a state model's prefill is told its slot in front of them)
+            first = 5 if self._state else 4
+            donate = {"donate_argnums": tuple(range(first,
+                                                    first + n_pool))}
             # the step's pools come after the fed-back token array and
             # its slots, neither of which it may consume
             # (a block step's after its block state and its rows' ends,
             # two arrays more)
-            first = 8 if self._block else 6
+            # (a state model's after its rows' slots and their number)
+            first = 8 if self._block or self._state else 6
             step_donate = {"donate_argnums": tuple(range(first,
                                                          first + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
@@ -731,7 +793,8 @@ class DecodeServer:
         # self-drafting model's the speculative ones
         self._decode_prog = compile_watch.jit(
             self._block_decode_fn if self._block
-            else self._spec_decode_fn if self._spec else self._decode_fn,
+            else self._spec_decode_fn if self._spec
+            else self._state_decode_fn if self._state else self._decode_fn,
             "%s:step" % site,
             statics=(site, self._window, self._max_pages), **step_donate)
         self._prefill_progs = {}
@@ -739,6 +802,7 @@ class DecodeServer:
             self._prefill_progs[rung] = compile_watch.jit(
                 self._block_prefill_fn if self._block
                 else self._spec_prefill_fn if self._spec
+                else self._state_prefill_fn if self._state
                 else self._prefill_fn,
                 "%s:prefill:s%d" % (site, rung),
                 statics=(site, "prefill", rung), **donate)
@@ -1074,6 +1138,76 @@ class DecodeServer:
                 [out, jnp.where(largest, jnp.maximum(a, b), a + b)])
         return (out, *pools)
 
+    # -- the state forms (a model with ``state_arrays``) -------------------
+    def _check_state_model(self):
+        """What fixed state a row makes impossible today is refused
+        here, when the server is built, with a typed error — never a
+        wrong token later."""
+        what = "a model that keeps fixed state a row (state_arrays)"
+        if self._block or self._spec:
+            raise MXNetError(
+                "DecodeServer: %s cannot be served in the %s form: a pass "
+                "over several positions a row moves the state at each, "
+                "and the state after each position is not kept until the "
+                "step knows which of them stand"
+                % (what, "block" if self._block else "speculative"))
+        if self._pool.dtype == "int8":
+            raise MXNetError(
+                "DecodeServer: %s over an int8 pool — int8 pages with "
+                "per-page scales exist beside no state" % what)
+        if self._prefix_on:
+            raise MXNetError(
+                "DecodeServer: prefix sharing hands a later prompt the "
+                "PAGES of a shared prefix, and %s would need the state as "
+                "it stood at that page boundary, which the index does not "
+                "hold — build it with prefix_cache=False" % what)
+        if self._pool.state_rows != self._window:
+            raise MXNetError(
+                "DecodeServer: the pool holds state for %d rows, the "
+                "window is %d — a step works on every row of the state "
+                "arrays" % (self._pool.state_rows, self._window))
+
+    def _state_prefill_fn(self, params, tokens, n_valid, page_table, slot,
+                          *pools):
+        """A state model's prefill: :meth:`_prefill_fn`, and the state
+        after the prompt's TRUE length written whole into row ``slot``
+        of the state arrays (nothing where ``n_valid`` is 0: a
+        warm-up)."""
+        import jax.numpy as jnp
+        layout = kvcache.layout_for(self._model, pools)
+        n = len(layout.specs)
+        logits, *out = self._model.prefill(
+            params, tokens, jnp.reshape(n_valid, (1,)))
+        pages = layout.write_prefill(pools, page_table, out[:n], n_valid)
+        state = layout.write_state(pools, slot, out[n:], n_valid > 0)
+        last = jnp.take(logits[0], n_valid - 1, axis=0)
+        token = jnp.argmax(last).astype(jnp.int32)
+        return (token, *pages, *state)
+
+    def _state_decode_fn(self, params, tokens, positions, slots, n_live,
+                         page_tables, prev, src, *pools):
+        """A state model's step program: :meth:`_decode_fn`, the model
+        handed the state of the window's rows — row ``i`` of the step
+        works on row ``slots[i]`` of the state arrays, the first
+        ``n_live`` of them live — and returning it among its results."""
+        import jax.numpy as jnp
+        tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+        layout = kvcache.layout_for(self._model, pools)
+        n, n_state = len(layout.specs), len(layout.state)
+        attend = layout.attend(pools, page_tables, positions)
+        state = layout.row_state(
+            pools, slots, jnp.arange(self._window) < n_live)
+        logits, *new = self._model.decode(
+            params, tokens, positions, attend, state)
+        pages = layout.write_tokens(
+            pools, page_tables, positions, new[:n],
+            getattr(self._model, "use_pallas", False))
+        tokens_out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if len(new) > n + n_state:
+            tokens_out = jnp.concatenate(
+                [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
+        return (tokens_out, *pages, *new[n:n + n_state])
+
     # copy-on-write page copy — the whole split is one traced program
     # (src/dst ride as traced scalars, so any page pair reuses it).
     # Axis 1 of every carried array is the page, so an int8 page's
@@ -1208,12 +1342,14 @@ class DecodeServer:
         try:
             n = 0
             zeros_pt = _np.zeros((self._max_pages,), _np.int32)
+            # a state model's prefill is told a slot; it writes nothing
+            slot = (_np.int32(0),) if self._state else ()
             with self._pool.step_lock:
                 for rung in self._seq_ladder.buckets:
                     toks = _np.zeros((1, rung), _np.int32)
                     out = self._prefill_progs[rung](
                         self._params.tree, toks, _np.int32(0),
-                        zeros_pt, *self._pool.arrays)
+                        zeros_pt, *slot, *self._pool.arrays)
                     jax.block_until_ready(out[0])
                     self._adopt_pool(out)
                     n += 1
@@ -1229,6 +1365,11 @@ class DecodeServer:
                             pos)
                 elif self._spec:
                     feed = (_np.zeros((self._window, 2), _np.int32), pos)
+                elif self._state:
+                    # no live row: every row's state stays as it is
+                    feed = (pos, pos,
+                            _np.arange(self._window, dtype=_np.int32),
+                            _np.int32(0))
                 else:
                     feed = (_np.zeros((self._window,), _np.int32), pos)
                 # twice: fed nothing, then fed its own token array, the
@@ -1575,6 +1716,11 @@ class DecodeServer:
                     self._namespace(req.params), run, req.pages)
             self._pool.free(req.pages)
             req.pages = []
+        if req.slot is not None:
+            # the row of the state arrays goes back as it is: its next
+            # tenant's prefill writes it whole
+            self._pool.release_row(req.slot)
+            req.slot = None
         with self._cond:
             if cancelled:
                 self._stats["cancelled"] += 1
@@ -1680,6 +1826,10 @@ class DecodeServer:
                     or req._cancelled:
                 # reaped or cancelled while we were allocating
                 pages_back = shared + pages
+            elif self._state and not self._take_slot_locked(req):
+                # every row of the state arrays is held by a request
+                # that still steps: wait, like for pages
+                pages_back = shared + pages
             else:
                 self._queue.popleft()
                 req.pages = shared + pages
@@ -1710,7 +1860,13 @@ class DecodeServer:
         tokens[0, :P] = req.prompt
         pt = _np.zeros((self._max_pages,), _np.int32)
         pt[:len(req.pages)] = req.pages
-        with tracing.span("decode.prefill", rung=rung) as pre:
+        slot = ()
+        if self._state:
+            slot = (_np.int32(req.slot),)
+            self._pool.note_state_write()
+        with tracing.span("decode.prefill", rung=rung,
+                          **({"state_slot": req.slot} if self._state
+                             else {})) as pre:
             self._seq = seq = self._seq + 1
             try:
                 with self._pool.step_lock:
@@ -1719,7 +1875,7 @@ class DecodeServer:
                                       rung=rung) as launch:
                         out = self._prefill_progs[rung](
                             req.params.tree, tokens, _np.int32(first), pt,
-                            *self._pool.arrays)
+                            *slot, *self._pool.arrays)
                     out = self._adopt_pool(out)
             except Exception as exc:   # noqa: BLE001 — model errors
                 self._retire([req], exc)   # belong to the request
@@ -1783,6 +1939,20 @@ class DecodeServer:
                 (req.eos_id is not None and tok == req.eos_id):
             self._retire([req], None)
         return True
+
+    def _take_slot_locked(self, req):
+        """A row of the state arrays for ``req`` (under ``self._cond``):
+        a free one, or the row of a request that needs no further step
+        — its last step is dispatched already, and the prefill that
+        writes the row runs behind it. False when every row steps on."""
+        slot = self._pool.take_row()
+        if slot is None:
+            for r in self._active:
+                if r.slot is not None and self._ending(r):
+                    slot, r.slot = r.slot, None
+                    break
+        req.slot = slot
+        return slot is not None
 
     def _ensure_pages(self, rows):
         """Grow each row's page table to cover its next write
@@ -2006,8 +2176,18 @@ class DecodeServer:
             # reads pages where they lie has to stream
             pages_live = int((positions[:len(rows)]
                               // self._pool.page_size + 1).sum())
-        self._dispatch_step(ver, rows, emits, (tokens, positions, pts),
-                            src, pages_live, {}, prev)
+            feed, said = (tokens, positions), {}
+            if self._state:
+                # every row of the state arrays, the live rows' own
+                # first: what another weight generation's rows hold, and
+                # what nobody holds, rides behind them, not live
+                own = [r.slot for r in rows]
+                rest = sorted(set(range(D)) - set(own))
+                feed += (_np.asarray(own + rest, _np.int32),
+                         _np.int32(len(rows)))
+                said = {"state_rows_live": len(rows)}
+        self._dispatch_step(ver, rows, emits, feed + (pts,), src,
+                            pages_live, said, prev)
 
     def _dispatch_step(self, ver, rows, emits, feed, src, pages_live,
                        said, prev):
@@ -2365,6 +2545,8 @@ class DecodeServer:
                 # exists only now: it rides this span, not the dispatch
                 counts = self._model_counts(toks, D)
                 back.set(**(counts or {}))
+                if self._state:
+                    back.set(state_rows_live=len(step.rows))
         except Exception as exc:       # noqa: BLE001 — the step's error
             self._retire(step.rows, exc)
             # whatever was fed from the failed step fails with it
@@ -2566,6 +2748,8 @@ class DecodeServer:
             }
         if self._counters is not None:
             out[self._counters[0]] = counted
+        if self._state:
+            out["state"] = out["kv"]["state"]
         if self._block:
             out["block"] = blocks
         if self._spec:

@@ -54,6 +54,24 @@ server's three programs are written over it, once. The page accounting
 below — alloc, free, refcounts, copy-on-write, preemption, the prefix
 index — never looks inside a page and is the same for every kind.
 
+**Fixed state a row, beside the pages.** A model whose layers are not
+all attention — a linear-attention layer keeps a matrix a head and the
+last rows of a short convolution, the same bytes whatever the context —
+declares ``state_arrays = ((name, shape a row, dtype), ...)`` and
+``state_layers`` (``DecodeServer``'s contract, the STATE form). The pool
+then carries, BEHIND its page arrays in ``.arrays`` and donated through
+the same programs, one array a declared name, ``(state_layers, rows,
+*shape)``, ``rows`` the server's decode window: a request holds ONE row
+of it (its slot, :meth:`KVCachePool.take_row`) from its prefill, which
+writes the row whole, to its end. Nothing of it is paged, shared or
+copied on write. The layout object answers for both kinds
+(:class:`_RowStateBeside` around the pages' own layout:
+``layout_for`` hands a program the same object the pool holds): it
+splits a program's ``pools`` into pages and state, hands a step the
+:class:`RowState` its model works on and writes a prefill's state into
+its row. ``stats()["state"]`` counts its bytes, its rows, the rows held
+and the prefills' writes.
+
 Page *accounting* is host-side and lives here too: an allocate/free
 free-list under a lock, with peak/eviction counters for the ``decode``
 telemetry record and the ``/metrics`` gauges. Page reclaim visits the
@@ -135,7 +153,7 @@ __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
            "paged_block_attention", "write_block_rows",
            "paged_latent_causal_attention", "write_latent_rows",
            "cache_layout",
-           "declared_arrays", "layout_for",
+           "declared_arrays", "declared_state", "layout_for", "RowState",
            "scatter_token", "scatter_prefill", "write_prefill_pages",
            "write_token_rows",
            "pages_for",
@@ -922,6 +940,106 @@ def declared_arrays(model):
     return specs, (dtypes.pop() if dtypes else None)
 
 
+def declared_state(model):
+    """What ``model`` declares as fixed state a row: ``(specs, layers)``,
+    ``specs`` the ``((name, shape a row, dtype), ...)`` of
+    ``model.state_arrays`` and ``layers`` its ``state_layers``; ``((),
+    0)`` for a model that keeps none."""
+    state = getattr(model, "state_arrays", None)
+    if not state:
+        return (), 0
+    return tuple((str(n), tuple(int(d) for d in shape), str(dtype))
+                 for n, shape, dtype in state), int(model.state_layers)
+
+
+class RowState:
+    """What a decode step hands its model of the fixed state a row:
+    ``arrays``, one a declared name, WHOLE — ``(state_layers, rows,
+    *shape)``, every row of the window — ``slots (B,)``, the row of them
+    each row of the step works on (a permutation of all of them: a dead
+    row of the step takes a row nobody else has), its ``inverse``, and
+    ``live (B,)``: a row that is not live leaves its state as it was.
+    The model returns the arrays, updated, among its results."""
+
+    __slots__ = ("arrays", "slots", "inverse", "live")
+
+    def __init__(self, arrays, slots, live):
+        import jax.numpy as jnp
+        self.arrays = tuple(arrays)
+        self.slots = jnp.asarray(slots, jnp.int32)
+        self.live = jnp.asarray(live, bool)
+        n = self.slots.shape[0]
+        self.inverse = jnp.zeros((n,), jnp.int32).at[self.slots].set(
+            jnp.arange(n, dtype=jnp.int32))
+
+
+class _RowStateBeside:
+    """A paged layout (``pages``: the latent one, or per-head K and V)
+    with fixed state a row beside it: a program's ``pools`` are the
+    pages' arrays and then one array a declared state, ``(state_layers,
+    rows, *shape)``. Attending and the pages' writes are the inner
+    layout's, over the arrays that are its own; the state is handed to a
+    step whole (:class:`RowState`) and written by a prefill into ONE
+    row. No block form: a pass over several positions a row would have
+    to keep the state after each."""
+
+    blocks = False
+    causal_blocks = False
+
+    def __init__(self, pages, state, layers):
+        self.pages, self.state, self.state_layers = pages, state, layers
+        self.specs, self.dtype = pages.specs, pages.dtype
+
+    def arrays(self, n_layers, n_pages, page_size):
+        return self.pages.arrays(n_layers, n_pages, page_size)
+
+    def token_bytes(self, n_layers):
+        return self.pages.token_bytes(n_layers)
+
+    def state_arrays(self, rows):
+        """``(name, shape, dtype)`` of the state arrays for a window of
+        ``rows``, in the order a program carries them behind the pages."""
+        return tuple((name, (self.state_layers, rows) + shape, dtype)
+                     for name, shape, dtype in self.state)
+
+    def split(self, pools):
+        """``pools`` as ``(the pages' arrays, the state arrays)``."""
+        n = len(pools) - len(self.state)
+        return tuple(pools[:n]), tuple(pools[n:])
+
+    def attend(self, pools, page_tables, positions):
+        return self.pages.attend(self.split(pools)[0], page_tables,
+                                 positions)
+
+    def write_prefill(self, pools, page_table_row, seqs, n_valid):
+        return self.pages.write_prefill(self.split(pools)[0],
+                                        page_table_row, seqs, n_valid)
+
+    def write_tokens(self, pools, page_tables, positions, new,
+                     force_pallas=False):
+        return self.pages.write_tokens(self.split(pools)[0], page_tables,
+                                       positions, new, force_pallas)
+
+    def row_state(self, pools, slots, live):
+        """The :class:`RowState` of a step over ``pools``."""
+        return RowState(self.split(pools)[1], slots, live)
+
+    def write_state(self, pools, slot, new, valid):
+        """A prefill's state ``new`` (one a declared array, ``(state_
+        layers, 1, *shape)``) into row ``slot``, whole, where ``valid``
+        (a warm-up's prefill writes nothing); returns the state arrays."""
+        import jax.numpy as jnp
+        return tuple(
+            a.at[:, slot].set(jnp.where(valid, n[:, 0].astype(a.dtype),
+                                        a[:, slot]))
+            for a, n in zip(self.split(pools)[1], new))
+
+
+@functools.lru_cache(maxsize=None)
+def _with_row_state(pages, state, layers):
+    return _RowStateBeside(pages, state, layers)
+
+
 @functools.lru_cache(maxsize=None)
 def cache_layout(specs, dtype):
     """THE choice of cache kind, from the two things that can be
@@ -957,8 +1075,12 @@ def cache_layout(specs, dtype):
 
 def layout_for(model, pools):
     """The layout of the ``pools`` a program of ``model``'s was handed —
-    the pool's own (:func:`cache_layout` is cached)."""
-    return cache_layout(declared_arrays(model)[0], pools[0].dtype)
+    the pool's own (:func:`cache_layout` is cached): the pages' layout,
+    and around it :class:`_RowStateBeside` for a model that declares
+    fixed state a row."""
+    pages = cache_layout(declared_arrays(model)[0], pools[0].dtype)
+    state, layers = declared_state(model)
+    return _with_row_state(pages, state, layers) if state else pages
 
 
 # ---------------------------------------------------------------------------
@@ -1035,7 +1157,7 @@ class KVCachePool:
 
     def __init__(self, n_layers, n_heads=None, head_dim=None, *,
                  arrays=None, page_size=None, n_pages=None, dtype=None,
-                 device=None):
+                 device=None, state=(), state_layers=0, state_rows=0):
         import jax.numpy as jnp
         self.page_size = int(page_size) if page_size is not None \
             else envs.get_int("MXNET_KV_PAGE_SIZE")
@@ -1069,6 +1191,18 @@ class KVCachePool:
         self.layout = cache_layout(self.array_specs, self.dtype)
         carried = self.layout.arrays(int(n_layers), self.n_pages,
                                      self.page_size)
+        # fixed state a row (``declared_state``), one array a name
+        # behind the pages' own: ``state_rows`` rows, a server's window
+        self.state_specs = tuple(state)
+        self.state_rows = int(state_rows) if state else 0
+        self.state_bytes = 0
+        if state:
+            self.layout = _with_row_state(self.layout, self.state_specs,
+                                          int(state_layers))
+            held = self.layout.state_arrays(self.state_rows)
+            self.state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                                   for _name, shape, dt in held)
+            carried += held
         # every array a program carries, in the layout's order (an int8
         # pool's scales among them). Allocated ON the target device: a
         # replica's pool must never be staged through the first chip's
@@ -1082,6 +1216,8 @@ class KVCachePool:
         # functional arrays — two schedulers must never fork the arrays
         self.step_lock = threading.Lock()
         self._free = list(range(self.n_pages - 1, 0, -1))  # pop() -> 1
+        self._rows_free = list(range(self.state_rows - 1, -1, -1))
+        self._state_writes = 0
         self._used_peak = 0
         self._evicted = 0
         self._alloc_failures = 0
@@ -1340,6 +1476,23 @@ class KVCachePool:
             out.append(page)
         return out
 
+    def take_row(self):
+        """A free row of the state arrays (lowest first), or None: the
+        slot a request holds from its prefill to its end."""
+        with self._lock:
+            return self._rows_free.pop() if self._rows_free else None
+
+    def release_row(self, row):
+        """Give a row back. Its content stays where it is: the next
+        tenant's prefill writes the row whole."""
+        with self._lock:
+            self._rows_free.append(int(row))
+            self._rows_free.sort(reverse=True)
+
+    def note_state_write(self):
+        with self._lock:
+            self._state_writes += 1
+
     def stats(self):
         with self._lock:
             free = len(self._free)
@@ -1361,6 +1514,14 @@ class KVCachePool:
                 "cow_splits": self._cow_splits,
                 "quota_denials": self._quota_denials,
             }
+            if self.state_specs:
+                out["state"] = {
+                    "bytes": self.state_bytes,
+                    "rows": self.state_rows,
+                    "rows_live": self.state_rows - len(self._rows_free),
+                    "writes": self._state_writes,
+                    "arrays": {n: list(shape)
+                               for n, shape, _dt in self.state_specs}}
             if self._clients:
                 out["owners"] = {
                     n: {"used": c["used"], "quota": c["quota"],

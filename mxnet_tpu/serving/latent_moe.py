@@ -33,7 +33,12 @@ slices neither) — through the server's ``attend``
 
 RoPE is YaRN (:func:`yarn_inv_freq`, :func:`yarn_mscale`); the score
 scale ``s = (nope + rope) ** -0.5 * mscale(factor, mscale_all_dim) **
-2``. The rotation is the half-split one (``rotate_half``); the
+2``. With ``rope_scaling`` null it is plain RoPE at ``rope_theta`` and
+``s = (nope + rope) ** -0.5``; with ``q_lora_rank`` null the query has
+no rank, ``q = x W_q``; with ``attention_gate`` the heads' outputs pass a
+head-wise sigmoid gate before ``W_o``, ``(softmax . v) * sigmoid(x
+W_g)_h`` (all three: ``serving.hybrid_linear_moe``'s latent layers). The
+rotation is the half-split one (``rotate_half``); the
 published checkpoint interleaves the pairs first, a fixed permutation
 of the rotary columns of ``W_qb``/``W_kva`` that random weights cannot
 tell.
@@ -155,14 +160,16 @@ class LatentMoEDecoderLM:
                  rms_norm_eps=1e-6, max_position_embeddings=4096,
                  hc_mult=1, hc_sinkhorn_iters=20, hc_eps=1e-6,
                  mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
-                 num_nextn_predict_layers=0, ep=(0, 1), use_pallas=False):
+                 num_nextn_predict_layers=0, attention_gate=False,
+                 cache_dtype="bfloat16", ep=(0, 1), use_pallas=False):
         from ..base import MXNetError
         from ..parallel.sharding_rules import held_experts
         self.vocab = int(vocab_size)
         self.d_model = int(hidden_size)
         self.n_layers = int(num_hidden_layers)
         self.n_heads = int(num_attention_heads)
-        self.q_rank, self.kv_rank = int(q_lora_rank), int(kv_lora_rank)
+        # no query rank (null in the published config): q = x W_q
+        self.q_rank, self.kv_rank = int(q_lora_rank or 0), int(kv_lora_rank)
         self.nope, self.rope = int(qk_nope_head_dim), int(qk_rope_head_dim)
         self.v_dim = int(v_head_dim)
         self.d_ff = int(intermediate_size)
@@ -176,6 +183,8 @@ class LatentMoEDecoderLM:
         self.eps = float(rms_norm_eps)
         self.max_len = int(max_position_embeddings)
         self.use_pallas = bool(use_pallas)
+        self.attn_gate = bool(attention_gate)
+        self.cache_dtype = str(cache_dtype)
         self.held = held_experts(self.n_experts, ep[1], ep[0])
         self.hc = int(hc_mult)
         self.hc_iters, self.hc_eps = int(hc_sinkhorn_iters), float(hc_eps)
@@ -194,7 +203,11 @@ class LatentMoEDecoderLM:
         # the module's block keeps its latent in a cache layer of its
         # own, behind the main model's
         self.cache_layers = self.n_layers + self.n_nextn
-        ys = dict(rope_scaling)
+        # no scaling (null in the published config) is plain RoPE: YaRN
+        # at factor 1 keeps every frequency and every gain is 1
+        ys = dict(rope_scaling or {"factor": 1,
+                                   "original_max_position_embeddings": 1,
+                                   "beta_fast": 1, "beta_slow": 1})
         self.inv_freq = yarn_inv_freq(
             self.rope, float(rope_theta), float(ys["factor"]),
             int(ys["original_max_position_embeddings"]),
@@ -213,7 +226,7 @@ class LatentMoEDecoderLM:
         # XLA's default layout for it is not row-major; see
         # flash_attention._mla_decode_kernel)
         self.row_width = -(-self.latent // 128) * 128
-        self.cache_arrays = (("kv", (self.row_width,), "bfloat16"),)
+        self.cache_arrays = (("kv", (self.row_width,), self.cache_dtype),)
 
     @property
     def n_moe_layers(self):
@@ -273,21 +286,36 @@ class LatentMoEDecoderLM:
         return p
 
     def _layer_params(self, i, w):
+        return {**self._attn_params(i, w), **self._ffn_params(i, w)}
+
+    def _attn_params(self, i, w):
         import jax.numpy as jnp
-        D, H, E = self.d_model, self.n_heads, self.held[1] - self.held[0]
+        D, H = self.d_model, self.n_heads
         ones = lambda n: jnp.ones((n,), jnp.float32)    # noqa: E731
         l = "l%d." % i
-        p = {
-            l + "attn_g": ones(D),
-            l + "wq_a": w(D, self.q_rank),
-            l + "q_g": ones(self.q_rank),
-            l + "wq_b": w(self.q_rank, H * (self.nope + self.rope)),
+        p = {l + "attn_g": ones(D)}
+        if self.q_rank:
+            p.update({
+                l + "wq_a": w(D, self.q_rank),
+                l + "q_g": ones(self.q_rank),
+                l + "wq_b": w(self.q_rank, H * (self.nope + self.rope))})
+        else:
+            p[l + "wq"] = w(D, H * (self.nope + self.rope))
+        p.update({
             l + "wkv_a": w(D, self.latent),
             l + "kv_g": ones(self.kv_rank),
             l + "wk_b": w(self.kv_rank, H * self.nope),
             l + "wv_b": w(self.kv_rank, H * self.v_dim),
-            l + "wo": w(H * self.v_dim, D),
-            l + "ffn_g": ones(D)}
+            l + "wo": w(H * self.v_dim, D)})
+        if self.attn_gate:
+            p[l + "wg"] = w(D, H)
+        return p
+
+    def _ffn_params(self, i, w):
+        import jax.numpy as jnp
+        D, E = self.d_model, self.held[1] - self.held[0]
+        l = "l%d." % i
+        p = {l + "ffn_g": jnp.ones((D,), jnp.float32)}
         if i < self.n_dense:
             p.update({l + "w_gate": w(D, self.d_ff),
                       l + "w_up": w(D, self.d_ff),
@@ -365,9 +393,12 @@ class LatentMoEDecoderLM:
         import jax.numpy as jnp
         l = "l%d." % i
         H = self.n_heads
-        cq = self._rms(self._mm(x, p[l + "wq_a"]), p[l + "q_g"])
-        q = self._mm(cq, p[l + "wq_b"]).reshape(
-            x.shape[:-1] + (H, self.nope + self.rope))
+        if self.q_rank:
+            cq = self._rms(self._mm(x, p[l + "wq_a"]), p[l + "q_g"])
+            q = self._mm(cq, p[l + "wq_b"])
+        else:
+            q = self._mm(x, p[l + "wq"])
+        q = q.reshape(x.shape[:-1] + (H, self.nope + self.rope))
         q_nope, q_r = q[..., :self.nope], q[..., self.nope:]
         ckv = self._mm(x, p[l + "wkv_a"])
         row = jnp.concatenate(
@@ -376,6 +407,14 @@ class LatentMoEDecoderLM:
              jnp.zeros(x.shape[:-1] + (self.row_width - self.latent,),
                        jnp.float32)], -1)
         return q_nope, self._rotate(q_r, positions), row
+
+    def _gate_heads(self, a, x, p, l):
+        """``a (..., H, v)`` through the head-wise output gate
+        ``sigmoid(x W_g)_h`` where the model has one."""
+        import jax
+        if not self.attn_gate:
+            return a
+        return a * jax.nn.sigmoid(self._mm(x, p[l + "wg"]))[..., None]
 
     # -- the residual streams --------------------------------------------
     # With ``hc_mult`` 1 these are the plain residual path, ``h = h +
@@ -490,14 +529,14 @@ class LatentMoEDecoderLM:
         wide = -(-max(self.nope + self.rope, self.v_dim) // 128) * 128
 
         def pad(a):
-            return jnp.pad(a.astype(jnp.bfloat16), (
+            return jnp.pad(a.astype(self.cache_dtype), (
                 (0, 0), (0, 0), (0, 0), (0, wide - a.shape[-1])))
 
         def attention(i, u):
             l = "l%d." % i
             x = self._rms(u, p[l + "attn_g"])
             q_nope, q_r, row = self._latent(i, x, p, pos)
-            row = row.astype(jnp.bfloat16)       # as the pool holds it
+            row = row.astype(self.cache_dtype)   # as the pool holds it
             c_kv, k_r = row[..., :R], row[..., R:self.latent]
             k_nope = self._mm(c_kv, p[l + "wk_b"]).reshape(B, L, H,
                                                            self.nope)
@@ -509,7 +548,8 @@ class LatentMoEDecoderLM:
             a = flash_attention(pad(q), pad(k), pad(v), causal=True,
                                 scale=self.scale,
                                 force_pallas=self.use_pallas)
-            a = a[..., :self.v_dim].reshape(B, L, H * self.v_dim)
+            a = self._gate_heads(a[..., :self.v_dim], x, p, l)
+            a = a.reshape(B, L, H * self.v_dim)
             return self._mm(a, p[l + "wo"]), row
 
         return attention
@@ -555,6 +595,7 @@ class LatentMoEDecoderLM:
             wv = p[l + "wv_b"].reshape(R, H, self.v_dim)
             a = jnp.einsum("...hr,rhv->...hv", o_lat.astype(wv.dtype), wv,
                            preferred_element_type=jnp.float32)
+            a = self._gate_heads(a, x, p, l)
             return self._mm(a.reshape(u.shape[:-1] + (H * self.v_dim,)),
                             p[l + "wo"]), row
 

@@ -562,3 +562,98 @@ def test_paged_mxu_kernels_take_one_grid_step_a_row(kernel):
     inner = [e.params["jaxpr"] for e in both.eqns
              if "jaxpr" in e.params and _pallas_calls(e.params["jaxpr"].jaxpr)]
     assert len(inner) == 2 and inner[0] is inner[1]
+
+
+def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
+    """``benchmark/configs/Ling-3.0-flash.json`` at its published widths
+    (2560 wide, 32 heads of 128, five delta-rule linear-attention layers
+    whose state is 32 x 128 x 128 float32 a row beside ONE latent layer of
+    rank 512, experts of 768, 128 of 512 held, 1 dense + 5 expert layers,
+    window 64, 1,152 bf16 pages of 128 x 640 in one cache layer): the
+    state form's ``decode:step`` and 1024-rung ``decode:prefill`` programs
+    compiled for one described v5e. In each: the Mosaic kernels under the
+    names a profile's reader looks for — the delta-rule step a linear
+    layer, the paged latent decode kernel and the in-place row write
+    (step), the flash kernel at 256-wide heads (prefill), the two grouped
+    matmuls of every expert layer — the planned bytes inside the chip
+    with room for the reference that decides ``correct`` beside the
+    weights, the donated pool AND the donated state arrays updated in
+    place, and NO copy of either among the temporaries (the state is 0.67
+    GB: one copy of it a layer would double the step)."""
+    from mxnet_tpu.serving import DecodeServer
+    from mxnet_tpu.serving.hybrid_linear_moe import HybridLinearMoEDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "Ling-3.0-flash.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    rung = max(srv["seq_ladder"])
+    M = -(-(rung + srv["max_new_tokens"]) // S)
+    model = HybridLinearMoEDecoderLM(**cfg["model"]["kwargs"])
+    assert model.held == (0, 128) and model.row_width == 640
+    assert (model.cache_layers, model.state_layers, model.chunk) \
+        == (1, 5, 16)
+    H, d, moe_layers = model.n_heads, model.head_dim, model.n_moe_layers
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert 8.70e9 < weights < 8.75e9
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    carried = (
+        spec((1, pages, S, model.row_width), jnp.bfloat16),
+        spec((5, W, H, d, d), jnp.float32),
+        spec((5, W, 3 * 3 * H * d), jnp.bfloat16))
+    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                        for a in carried)
+    assert 0.88e9 < carried_bytes < 0.89e9
+    holder = type("S", (), {"_model": model, "_window": W})()
+    n_counts = len(model.step_counters[1])
+
+    def named(text, kernel):
+        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                          % kernel, text, re.M)
+
+    step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
+                   donate_argnums=(8, 9, 10)).lower(
+        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W,), jnp.int32), spec((), jnp.int32), spec((W, M), jnp.int32),
+        spec((W + n_counts,), jnp.int32), spec((W,), jnp.int32),
+        *carried).compile()
+    text = step.as_text()
+    assert len(named(text, "kda_step")) == 5
+    assert "mx_kda_step.b%d.h%d.d%d" % (W, H, d) in text
+    assert len(named(text, "mla_decode")) == 1
+    assert len(named(text, "latent_write")) == 1
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    assert ".e128.m" in text and ".k2560.n768.bfloat16.gated" in text
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 5 + 2 + 2 * moe_layers
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried_bytes, mem
+    assert mem.temp_size_in_bytes < 0.1e9, mem      # no state or pool copy
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 9.5e9 < planned < 9.8e9, mem
+
+    prefill = jax.jit(lambda *a: DecodeServer._state_prefill_fn(holder, *a),
+                      donate_argnums=(5, 6, 7)).lower(
+        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
+        spec((M,), jnp.int32), spec((), jnp.int32), *carried).compile()
+    text = prefill.as_text()
+    assert len(named(text, "flash_fwd")) == 1
+    assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    # the chunkwise rule's walk: one loop a linear layer, carrying S
+    assert len(re.findall(r"%while[.\d]* = \(s32\[\][^,]*, "
+                          r"f32\[1,32,128,128\]", text)) == 5
+    mem = prefill.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried_bytes, mem
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert planned < 10.2e9, mem

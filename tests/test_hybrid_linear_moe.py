@@ -1,0 +1,634 @@
+"""Linear-attention layers whose state is a fixed array a row, beside one
+latent-attention layer's pages, over routed experts —
+``serving.hybrid_linear_moe`` on ``DecodeServer``'s STATE form of the
+model contract, the row state beside the pool (``serving.kvcache``) and
+the delta-rule kernels (``parallel.delta_rule``; Pallas in interpret
+mode), against the benchmark's plain float32 reference
+(``benchmark/reference/hybrid_linear_moe_lm.py``, the recurrence one
+token at a time) at a small size with seeded weights. The programs of
+the models that keep no such state are the parent's, jaxpr for jaxpr."""
+import functools
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+from benchmark.reference import hybrid_linear_moe_lm as ref    # noqa: E402
+from mxnet_tpu import compile_watch, fault, telemetry          # noqa: E402
+from mxnet_tpu.base import MXNetError                          # noqa: E402
+from mxnet_tpu.parallel import delta_rule, moe, sharding_rules  # noqa: E402
+from mxnet_tpu.serving import (DecodeServer, KVCachePool,       # noqa: E402
+                               ServerOverloadedError, ToyDecoderLM,
+                               kvcache)
+from mxnet_tpu.serving.block_diffusion import (                # noqa: E402
+    BlockDiffusionMoEDecoderLM)
+from mxnet_tpu.serving.hybrid_linear_moe import (              # noqa: E402
+    HybridLinearMoEDecoderLM)
+from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM    # noqa: E402
+
+# the published block's shape at a test's size: two groups of three
+# layers (two linear, one latent), 16 experts in 4 groups, 2 kept, top 4,
+# one dense layer in front; float32 matrices, pool and convolution rows
+CFG = dict(vocab_size=256, hidden_size=128, num_hidden_layers=6,
+           num_attention_heads=4, head_dim=32, layer_group_size=3,
+           kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=16,
+           v_head_dim=32, intermediate_size=256, moe_intermediate_size=64,
+           num_experts=16, num_shared_experts=1, num_experts_per_tok=4,
+           n_group=4, topk_group=2, routed_scaling_factor=2.5,
+           first_k_dense_replace=1, rope_theta=10000, kda_lower_bound=-5,
+           max_position_embeddings=512, dtype="float32")
+# heads of 128: what the Pallas step kernel tiles
+WIDE = dict(CFG, num_attention_heads=2, head_dim=128)
+
+
+@pytest.fixture(autouse=True)
+def _clean_state():
+    fault.reset()
+    telemetry.reset()
+    compile_watch.disable()
+    yield
+    fault.reset()
+    telemetry.reset()
+    compile_watch.disable()
+
+
+@functools.lru_cache(maxsize=None)
+def _model(use_pallas=False, seed=3, **over):
+    cfg = dict(WIDE if use_pallas else CFG, **over)
+    model = HybridLinearMoEDecoderLM(**cfg, use_pallas=use_pallas)
+    return model, model.init_params(seed=seed), cfg
+
+
+def _server(model, params, **kw):
+    kw = {"seq_ladder": [32], "max_new_tokens": 32, "page_size": 16,
+          "window": 4, "pool_pages": 64, "start": False, **kw}
+    return DecodeServer(model, params, **kw)
+
+
+def _drain(srv, *reqs, limit=800, each=None):
+    n = 0
+    while not all(r.done() for r in reqs):
+        if each is not None:
+            each(srv)
+        srv._tick()
+        n += 1
+        assert n < limit, "scheduler made no progress"
+
+
+def _prompts(seed, sizes, vocab=256):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, size=n).astype(np.int32) for n in sizes]
+
+
+def _serve(model, params, prompts, n=12, each=None, **kw):
+    srv = _server(model, params, **kw)
+    reqs = [srv.submit(p, max_new_tokens=n) for p in prompts]
+    _drain(srv, *reqs, each=each)
+    st = srv.stats()
+    srv.stop()
+    return [[int(t) for t in r.result()] for r in reqs], st
+
+
+# ---------------------------------------------------------------------------
+# the recurrence: chunkwise, and one token a row
+# ---------------------------------------------------------------------------
+
+def _rule_inputs(L, H=3, d=32, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q = unit(jax.random.normal(k[0], (L, H, d))) * d ** -0.5
+    g = -5.0 * jax.nn.sigmoid(4.0 * jax.random.normal(k[3], (L, H, d)))
+    return q, unit(jax.random.normal(k[1], (L, H, d))), \
+        jax.random.normal(k[2], (L, H, d)), g, \
+        jax.nn.sigmoid(jax.random.normal(k[4], (L, H)))
+
+
+@pytest.mark.parametrize("case", ["mixed_gates", "gates_at_the_bound",
+                                  "ragged_true_length"])
+def test_chunkwise_prefill_is_the_token_by_token_reference(case):
+    """``kda_chunk`` against the reference's ``lax.scan`` over tokens. At
+    the bound every channel decays by ``e^-5`` a position: 80 such
+    positions reach ``e^-400``, which a form that divides by the running
+    decay of a 64-long chunk cannot hold in float32. Past a ragged true
+    length (``beta = 0``, ``g = 0``) the state stays what it was."""
+    L, n = 96, 96
+    q, k, v, g, beta = _rule_inputs(L)
+    if case == "gates_at_the_bound":
+        g = g.at[:80].set(-5.0)
+    if case == "ragged_true_length":
+        n = 53
+        live = jnp.arange(L) < n
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+    o, S = delta_rule.kda_chunk(*(a[None] for a in (q, k, v, g, beta)))
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
+    want = ref.delta_rule(q, k, v, g, beta)
+    assert np.abs(np.asarray(o[0] - want))[:n].max() \
+        < 1e-4 * float(np.abs(want).max())
+    # the state after the true length, by the step form from zero
+    state = jnp.zeros((1, 1) + S.shape[1:])
+    for t in range(n):
+        _, state = delta_rule.kda_step(
+            state, 0, jnp.zeros((1,), jnp.int32),
+            *(a[t][None] for a in (q, k, v, g, beta)))
+    assert np.abs(np.asarray(S - state[0])).max() \
+        < 1e-5 * max(float(np.abs(state).max()), 1e-3)
+
+
+def test_a_chunk_whose_running_decay_overflows_is_refused():
+    q, k, v, g, beta = (a[None] for a in _rule_inputs(64))
+    with pytest.raises(MXNetError, match="overflows float32"):
+        delta_rule.kda_chunk(q, k, v, g, beta, chunk=64, g_floor=-5.0)
+    with pytest.raises(MXNetError, match="no multiple"):
+        delta_rule.kda_chunk(q[:, :40], k[:, :40], v[:, :40], g[:, :40],
+                             beta[:, :40])
+
+
+def test_step_kernel_agrees_with_jnp_in_place_and_leaves_dead_rows():
+    """The Pallas step (interpreted) against the jnp one on a window of
+    6 rows and 2 state layers: the same outputs, the rows' states
+    updated where they lie, every other layer and the rows that are not
+    live exactly as they were."""
+    B, H, d, layers = 6, 8, 128, 2
+    k = jax.random.split(jax.random.PRNGKey(1), 6)
+    state = jax.random.normal(k[0], (layers, B, H, d, d))
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q, kk, v = (jax.random.normal(k[i], (B, H, d)) for i in (1, 2, 3))
+    g = -5.0 * jax.nn.sigmoid(3.0 * jax.random.normal(k[4], (B, H, d)) - 2)
+    beta = jax.nn.sigmoid(jax.random.normal(k[5], (B, H)))
+    slots = jnp.asarray([3, 0, 5, 1, 4, 2], jnp.int32)
+    dead = jnp.asarray([False, False, True, False, True, True])
+    g = jnp.where(dead[:, None, None], 0.0, g)
+    beta = jnp.where(dead[:, None], 0.0, beta)
+    args = (state, 1, slots, q * d ** -0.5, unit(kk), v, g, beta)
+    o_j, s_j = delta_rule.kda_step(*args)
+    o_p, s_p = delta_rule.kda_step(*args, force_pallas=True)
+    assert np.abs(np.asarray(o_j - o_p)).max() < 1e-4
+    assert np.abs(np.asarray(s_j - s_p)).max() < 1e-5
+    for got in (s_j, s_p):
+        assert bool(jnp.isfinite(got).all())
+        assert bool((got[0] == state[0]).all())            # another layer
+        for row, gone in zip(np.asarray(slots), np.asarray(dead)):
+            same = bool((got[1, row] == state[1, row]).all())
+            assert same == bool(gone), row
+
+
+def test_the_gates_draw_remembers():
+    """``A_log`` and ``dt_bias`` as ``init_params`` draws them: a step's
+    ``alpha`` has its median over channels between 0.9 and 0.999, and is
+    neither 1 nor ``e^-5`` everywhere."""
+    model, params, _ = _model()
+    x = jax.random.normal(jax.random.PRNGKey(2), (64, model.d_model))
+    g, beta = model._gates(1, x, params, jnp.ones((64,), bool))
+    alpha = np.exp(np.asarray(g))
+    assert 0.9 < np.median(alpha) < 0.999
+    assert np.percentile(alpha, 2) < 0.8 and np.percentile(alpha, 98) > 0.995
+    assert alpha.min() > np.exp(-5.0) and alpha.max() < 1.0
+    assert 0.01 < float(beta.min()) and float(beta.max()) < 0.99
+    g, beta = model._gates(1, x, params, jnp.zeros((64,), bool))
+    assert float(jnp.abs(g).max()) == 0.0 and float(beta.max()) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the model against the reference
+# ---------------------------------------------------------------------------
+
+def _served_logits(model, params, tokens, n_prompt, page_size=16, window=4,
+                   slot=2):
+    """Logits of positions ``n_prompt - 1 ..`` from the SERVING path: one
+    prefill over the prompt, its rows written into a paged latent pool
+    and its state into row ``slot`` of the state arrays, then one decode
+    step a token through the layout's own ``attend``, row state and
+    writes — what ``DecodeServer``'s two state programs compute, with the
+    logits kept. The step runs a window of ``window`` rows of which one
+    is live."""
+    L = len(tokens)
+    rung = -(-n_prompt // page_size) * page_size
+    n_pages = -(-L // page_size) + 1
+    state, layers = kvcache.declared_state(model)
+    pool = KVCachePool(model.cache_layers,
+                       arrays=[c[:2] for c in model.cache_arrays],
+                       dtype=model.cache_arrays[0][2], page_size=page_size,
+                       n_pages=n_pages + 1, state=state, state_layers=layers,
+                       state_rows=window)
+    layout = pool.layout
+    assert layout is kvcache.layout_for(model, pool.arrays)
+    table = np.arange(1, n_pages + 1, dtype=np.int32)
+    padded = np.zeros((1, rung), np.int32)
+    padded[0, :n_prompt] = tokens[:n_prompt]
+
+    @jax.jit
+    def prefill(pools):
+        logits, rows, *st = model.prefill(params, padded,
+                                          jnp.asarray([n_prompt]))
+        return logits[0, n_prompt - 1], (
+            *layout.write_prefill(pools, table, [rows], n_prompt),
+            *layout.write_state(pools, slot, st, True))
+
+    slots = np.asarray([slot] + [s for s in range(window) if s != slot],
+                       np.int32)
+    tables = np.zeros((window, n_pages), np.int32)
+    tables[0] = table
+
+    @jax.jit
+    def step(pools, tok, pos):
+        toks = jnp.zeros((window,), jnp.int32).at[0].set(tok)
+        poss = jnp.zeros((window,), jnp.int32).at[0].set(pos)
+        attend = layout.attend(pools, tables, poss)
+        rows = layout.row_state(pools, slots, jnp.arange(window) < 1)
+        logits, new, *st = model.decode(params, toks, poss, attend, rows)
+        return logits[0], (
+            *layout.write_tokens(pools, tables, poss, [new],
+                                 model.use_pallas), *st[:len(state)])
+
+    first, pools = prefill(tuple(pool.arrays))
+    out = [np.asarray(first)]
+    for p in range(n_prompt, L):
+        lg, pools = step(pools, tokens[p], p)
+        out.append(np.asarray(lg))
+    return np.stack(out)
+
+
+# Matrices, pool and convolution rows are float32 here and so is the
+# reference: what separates them is float32 rounding in another order —
+# the chunkwise form against one token at a time, the absorbed attention
+# against the published one, a grouped matmul against a loop — a few
+# 1e-6 of a logit's deviation a product, a few dozen products deep: a
+# position's worst logit lies within 2e-4 deviations. The limit is 2e-3,
+# ten times that (a router's near-tie that flips costs a whole expert,
+# about one deviation: at float32 none does in these sequences). The
+# reference with ONLY its recurrent state kept in bfloat16 between tokens
+# is 0.02 deviations and more off at most positions.
+LOGIT_TOLERANCE = 2e-3
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_prefill_then_decode_agrees_with_the_reference_on_logits(use_pallas):
+    model, params, cfg = _model(use_pallas=use_pallas)
+    tokens = np.random.default_rng(1).integers(
+        0, model.vocab, size=58).astype(np.int32)
+    n_prompt = 21
+    n_rows = len(tokens) - n_prompt + 1
+    got = _served_logits(model, params, tokens, n_prompt)
+
+    def reference(control=None):
+        return ref.logits_rows(params, jnp.asarray(tokens), n_prompt - 1,
+                               n_rows, cfg, model.held, control=control)
+
+    want = reference()
+    err = np.abs(got - want).max(axis=1) / want.std()
+    assert err.max() < LOGIT_TOLERANCE, err
+    # tight enough that the state a precision down fails it
+    low = np.abs(reference("state_bf16") - want).max(axis=1) / want.std()
+    assert np.median(low) > 2 * LOGIT_TOLERANCE, low
+    assert np.abs(reference("float8") - want).max(axis=1).min() \
+        / want.std() > 20 * LOGIT_TOLERANCE
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Expert parallelism's contract at a small size: the 4 shares'
+    routed parts, and the shared expert counted ONCE, add up to what the
+    uncut reference gives for the whole layer."""
+    model, params, cfg = _model(ep=(0, 1))
+    assert model.held == (0, 16)
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, cfg["hidden_size"]))
+    whole, _ = ref.base.moe_layer(x, params, "l1.", cfg, (0, 16))
+    topi, topw = moe.route_grouped_sigmoid(
+        x, params["l1.router_w"], params["l1.router_b"], n_group=4,
+        topk_group=2, top_k=4, scaling=2.5)
+    shared = model._gated(x, params, "l1.shared.")
+    total = shared
+    for rank in range(4):
+        lo, hi = sharding_rules.held_experts(16, 4, rank)
+        share = {n: params["l1.experts." + n][lo:hi]
+                 for n in ("w_gate", "w_up", "w_down")}
+        total = total + moe.expert_ffn(x, share, topi, topw, (lo, hi))
+    assert np.abs(np.asarray(total - whole)).max() \
+        / np.asarray(whole).std() < 1e-3
+    one = shared + moe.expert_ffn(
+        x, {n: params["l1.experts." + n][:4]
+            for n in ("w_gate", "w_up", "w_down")}, topi, topw, (0, 4))
+    assert np.abs(np.asarray(one - whole)).max() \
+        / np.asarray(whole).std() > 0.3
+    # the chip's share of the published axis: groups 0 and 1 of 8
+    assert sharding_rules.held_experts(512, 4, 0) == (0, 128)
+
+
+# ---------------------------------------------------------------------------
+# the server: slots, tenants, preemption, the loop
+# ---------------------------------------------------------------------------
+
+def test_served_streams_are_the_models_own_greedy_streams():
+    model, params, _ = _model()
+    prompts = _prompts(0, (11, 5, 29, 17, 8, 3))
+    streams, st = _serve(model, params, prompts)
+    assert st["state"]["rows"] == 4 and st["state"]["rows_live"] == 0
+    assert st["state"]["writes"] == 6 == st["prefill_steps"]
+    assert st["state"]["bytes"] == 4 * 4 * (4 * 32 * 32 + 3 * 384) * 4
+    assert st["kv"]["used"] == 0 and st["decode_steps_ahead"] > 0
+    full = jax.jit(model.prefill)
+    for prompt, out in zip(prompts, streams):
+        seq = np.zeros((1, 48), np.int32)
+        seq[0, :len(prompt) + len(out)] = np.concatenate([prompt, out])
+        logits = np.asarray(full(params, seq, jnp.asarray(
+            [len(prompt) + len(out)]))[0][0])
+        assert (logits[len(prompt) - 1:len(prompt) + len(out) - 1]
+                .argmax(-1) == np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_a_slots_second_tenant_streams_what_it_streams_alone(use_pallas):
+    """A window of ONE row: every request is its slot's next tenant, and
+    the prefill writes the slot whole — a short prompt after a long one
+    inherits neither state nor convolution rows."""
+    model, params, _ = _model(use_pallas=use_pallas)
+    prompts = _prompts(4, (30, 2, 9))
+    together, st = _serve(model, params, prompts, n=8, window=1)
+    assert st["state"]["rows"] == 1 and st["state"]["writes"] == 3
+    for prompt, stream in zip(prompts, together):
+        alone, _ = _serve(model, params, [prompt], n=8, window=1)
+        assert alone[0] == stream
+
+
+def test_preemption_drops_the_state_and_a_second_prefill_rebuilds_it():
+    model, params, _ = _model()
+    prompts = _prompts(6, (12, 12, 12))
+    srv = _server(model, params, pool_pages=5, max_new_tokens=24)
+    low = [srv.submit(p, max_new_tokens=24, priority=0)
+           for p in prompts[:2]]
+    for _ in range(6):
+        srv._tick()
+    high = srv.submit(prompts[2], max_new_tokens=24, priority=1)
+    _drain(srv, high, *low)
+    assert srv.stats()["preempted"] >= 1
+    lost = [r for r in low if r._error is not None]
+    assert lost and all(isinstance(r._error, ServerOverloadedError)
+                        for r in lost)
+    assert srv.stats()["state"]["rows_live"] == 0
+    again = []
+    for r in lost:           # one at a time: the pool holds one such row
+        again.append(srv.submit(r.prompt, max_new_tokens=24))
+        _drain(srv, again[-1])
+    srv.stop()
+    for first, r in zip(lost, again):
+        alone, _ = _serve(model, params, [r.prompt], n=24)
+        assert [int(t) for t in r.result()] == alone[0]
+        # what the preempted run had streamed was the same stream's start
+        assert first.generated \
+            and alone[0][:len(first.generated)] == first.generated
+
+
+def test_one_step_ahead_and_drained_loops_give_the_same_stream():
+    model, params, _ = _model()
+    prompts = _prompts(7, (9, 20, 4, 15, 27))
+
+    def drain_every_pass(srv):
+        srv._drain_ask = "test"
+
+    ahead, st_a = _serve(model, params, prompts, n=16)
+    drained, st_d = _serve(model, params, prompts, n=16,
+                           each=drain_every_pass)
+    assert ahead == drained
+    assert st_a["decode_steps_ahead"] > 0.8 * st_a["decode_steps"]
+    assert st_d["decode_steps_ahead"] == 0
+
+
+def test_cancel_and_a_weight_swap_in_mid_stream():
+    """A cancelled row's slot comes back; rows on the old weights keep
+    their state through a swap and finish the stream they would have
+    finished without one."""
+    model, params, _ = _model()
+    other = model.init_params(seed=11)
+    prompts = _prompts(8, (10, 14, 6))
+    plain, _ = _serve(model, params, prompts[:2], n=20)
+    srv = _server(model, params)
+    a, b = (srv.submit(p, max_new_tokens=20) for p in prompts[:2])
+    for _ in range(6):
+        srv._tick()
+    assert srv.stats()["state"]["rows_live"] == 2
+    b.cancel()
+    srv.swap_weights(other)
+    c = srv.submit(prompts[2], max_new_tokens=10)
+    _drain(srv, a, b, c)
+    st = srv.stats()
+    srv.stop()
+    assert b.state == "cancelled" and st["swaps"] == 1
+    assert st["state"]["rows_live"] == 0 and st["kv"]["used"] == 0
+    assert [int(t) for t in a.result()] == plain[0]
+    new, _ = _serve(model, other, [prompts[2]], n=10)
+    assert [int(t) for t in c.result()] == new[0]
+
+
+def test_fixed_program_set_and_what_the_spans_say():
+    from mxnet_tpu import tracing
+    compile_watch.enable()
+    model, params, _ = _model()
+    srv = _server(model, params, seq_ladder=[16, 32], max_new_tokens=8,
+                  window=2, pool_pages=16, name="hyb")
+    assert srv.warmup() == 3
+    tracing.enable()
+    try:
+        reqs = [srv.submit(p, max_new_tokens=8)
+                for p in _prompts(9, (3, 16, 20, 31))]
+        _drain(srv, *reqs)
+        spans = [e for e in tracing.export()["traceEvents"]
+                 if e.get("ph") == "X"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    sites = compile_watch.site_stats("decode:hyb")
+    assert sorted(sites) == ["decode:hyb:prefill:s16",
+                             "decode:hyb:prefill:s32", "decode:hyb:step"]
+    assert all(s["count"] == 1 for s in sites.values())
+    srv.stop()
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp["name"], []).append(sp.get("args") or {})
+    assert all(0 <= a["state_slot"] < 2 for a in by_name["decode.prefill"])
+    assert len(by_name["decode.prefill"]) == 4
+    assert all(1 <= a["state_rows_live"] <= 2
+               for a in by_name["decode.dispatch"])
+    assert all("state_rows_live" in a and "experts_touched" in a
+               for a in by_name["decode.readback"])
+
+
+# ---------------------------------------------------------------------------
+# what is refused, when the model or the server is built
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("what", [
+    "prefix_sharing", "int8_pool", "speculative_form", "block_form",
+    "next_token_module", "clamped_swiglu", "the_original_gate",
+    "no_whole_group", "a_shared_pool_without_the_state"])
+def test_what_state_makes_impossible_is_refused_with_a_typed_error(what):
+    model, params, cfg = _model()
+    if what == "prefix_sharing":
+        with pytest.raises(MXNetError, match="prefix sharing"):
+            _server(model, params, prefix_cache=True)
+    elif what == "int8_pool":
+        class PerHead(ToyDecoderLM):
+            state_arrays = (("s", (2, 8, 8), "float32"),)
+            state_layers = 2
+        toy = PerHead(vocab=32, n_layers=2, n_heads=2, head_dim=8)
+        pool = KVCachePool(2, 2, 8, page_size=16, n_pages=8, dtype="int8",
+                           state=kvcache.declared_state(toy)[0],
+                           state_layers=2, state_rows=4)
+        with pytest.raises(MXNetError, match="int8 pool"):
+            DecodeServer(toy, toy.init_params(0), pool=pool, window=4,
+                         seq_ladder=[16], max_new_tokens=8, start=False)
+    elif what in ("speculative_form", "block_form"):
+        attr = {"speculative_form": "draft_length",
+                "block_form": "block_length"}[what]
+        fake = type("M", (HybridLinearMoEDecoderLM,), {
+            attr: 1, "verify": None, "draft": None, "prefill_draft": None,
+            "draft_prefill": None, "decode_block": None, "unmask": None,
+            "mask_token_id": 0})(**cfg)
+        with pytest.raises(MXNetError, match="fixed state a row"):
+            _server(fake, params, page_size=16)
+    elif what == "next_token_module":
+        with pytest.raises(MXNetError, match="num_nextn_predict_layers 1"):
+            HybridLinearMoEDecoderLM(**dict(cfg, num_nextn_predict_layers=1))
+    elif what == "clamped_swiglu":
+        with pytest.raises(MXNetError, match="clamped SwiGLU"):
+            HybridLinearMoEDecoderLM(**dict(
+                cfg, expert_swiglu_limit_list=[0, 0, 0, 0, 4, 4]))
+        # a limit on a layer that is not held is none of this chip's
+        HybridLinearMoEDecoderLM(**dict(
+            cfg, share_expert_swiglu_limit_list=[0] * 6 + [7, 7]))
+    elif what == "the_original_gate":
+        with pytest.raises(MXNetError, match="kda_safe_gate"):
+            HybridLinearMoEDecoderLM(**dict(cfg, kda_safe_gate=False))
+    elif what == "no_whole_group":
+        with pytest.raises(MXNetError, match="no whole group"):
+            HybridLinearMoEDecoderLM(**dict(cfg, num_hidden_layers=2))
+    else:
+        pool = KVCachePool(model.cache_layers,
+                           arrays=[c[:2] for c in model.cache_arrays],
+                           dtype="float32", page_size=16, n_pages=16)
+        with pytest.raises(MXNetError, match="shared pool geometry"):
+            _server(model, params, pool=pool, pool_pages=None,
+                    page_size=None)
+
+
+def test_null_rope_scaling_and_query_rank_are_plain_rope_and_one_matrix():
+    """The repair in ``LatentMoEDecoderLM``: ``rope_scaling`` null is
+    plain RoPE at ``rope_theta`` with the plain score scale, and
+    ``q_lora_rank`` null a query without a rank."""
+    model, params, cfg = _model()
+    want = 10000.0 ** (-np.arange(0, 16, 2) / 16.0)
+    assert np.abs(model.inv_freq - want).max() < 1e-7
+    assert model.rope_gain == 1.0 and model.scale == 48 ** -0.5
+    assert model.q_rank == 0 and "l2.wq" in params \
+        and "l2.wq_a" not in params and "l2.wg" in params
+    assert params["l2.wq"].shape == (128, 4 * 48)
+    assert model.cache_layers == 2 and model.state_layers == 4
+    assert [model.latent_layer(i) for i in range(6)] \
+        == [None, None, 0, None, None, 1]
+    assert [model.state_layer(i) for i in (0, 1, 3, 4)] == [0, 1, 2, 3]
+
+
+# ---------------------------------------------------------------------------
+# the models that keep no such state run the programs they ran
+# ---------------------------------------------------------------------------
+
+YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+        "type": "yarn"}
+LATENT = dict(vocab_size=256, hidden_size=128, num_hidden_layers=3,
+              num_attention_heads=4, q_lora_rank=64, kv_lora_rank=128,
+              qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+              intermediate_size=256, moe_intermediate_size=128,
+              n_routed_experts=32, n_shared_experts=1,
+              num_experts_per_tok=4, n_group=4, topk_group=2,
+              routed_scaling_factor=2.5, first_k_dense_replace=1,
+              rope_theta=10000, rope_scaling=YARN, rms_norm_eps=1e-6,
+              max_position_embeddings=512)
+BLOCK = dict(vocab_size=256, hidden_size=128, num_hidden_layers=2,
+             num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+             moe_intermediate_size=64, num_experts=8,
+             num_experts_per_tok=2, rope_theta=10000, block_length=4,
+             mask_token_id=255, max_position_embeddings=512)
+# sha1 of the jaxprs' text on the commit this PR starts from (4c0d884),
+# by ``_program_jaxprs`` there
+PARENT_PROGRAMS = {
+    "toy.prefill": "b5050806fa1fe556", "toy.step": "d4ebfb3472495617",
+    "dots.prefill": "c835abc3c5a64afd", "dots.step": "27958a0bc522e393",
+    "xing.prefill": "88d7eb23c53e935b", "xing.step": "b95905b5cd501578",
+    "sdar.prefill": "6abf986dd09b2147", "sdar.step": "288ffe9ffb32d218"}
+
+
+def _program_jaxprs():
+    """``{name: text of the jaxpr}`` of the prefill and step programs of
+    the four kinds of model that keep no state a row, at a test's size."""
+    W, M, S = 3, 6, 8
+
+    def text(fn, holder, *args):
+        return str(jax.make_jaxpr(functools.partial(fn, holder))(*args))
+
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)      # noqa: E731
+    out = {}
+    toy = ToyDecoderLM(vocab=32, n_layers=2, n_heads=2, head_dim=8)
+    dots = LatentMoEDecoderLM(**LATENT)
+    xing = LatentMoEDecoderLM(**dict(
+        LATENT, n_routed_experts=16, n_group=1, topk_group=1, hc_mult=4,
+        num_nextn_predict_layers=1))
+    sdar = BlockDiffusionMoEDecoderLM(**BLOCK)
+    for name, model, pools in (
+            ("toy", toy, (jnp.zeros((2, 24, S, 2, 8)),) * 2),
+            ("dots", dots, (jnp.zeros((3, 24, S, dots.row_width),
+                                      jnp.bfloat16),)),
+            ("xing", xing, (jnp.zeros((4, 24, S, xing.row_width),
+                                      jnp.bfloat16),)),
+            ("sdar", sdar, (jnp.zeros((2, 24, S, 2, 32), jnp.float32),) * 2)):
+        params = model.init_params(seed=0)
+        counters = getattr(model, "step_counters", None)
+        holder = type("S", (), {
+            "_model": model, "_window": W, "_counters": counters,
+            "_block": getattr(model, "block_length", 0),
+            "_step_fn": DecodeServer._step_fn})()
+        n_counts = len(counters[1]) if counters else 0
+        pre = (params, i32(1, 16), jnp.int32(8), i32(M), *pools)
+        if name == "sdar":
+            Q = model.block_length
+            out["sdar.prefill"] = text(DecodeServer._block_prefill_fn,
+                                       holder, *pre)
+            out["sdar.step"] = text(
+                DecodeServer._block_decode_fn, holder, params, i32(W, Q),
+                i32(W), i32(W), i32(W), i32(W, M),
+                i32(W * (Q + 2) + n_counts), i32(W), *pools)
+        elif name == "xing":
+            out["xing.prefill"] = text(DecodeServer._spec_prefill_fn,
+                                       holder, *pre)
+            out["xing.step"] = text(
+                DecodeServer._spec_decode_fn, holder, params, i32(W, 2),
+                i32(W), i32(W, M), i32(W * 5 + n_counts), i32(W), *pools)
+        else:
+            out[name + ".prefill"] = text(DecodeServer._prefill_fn, holder,
+                                          *pre)
+            out[name + ".step"] = text(
+                DecodeServer._decode_fn, holder, params, i32(W), i32(W),
+                i32(W, M), i32(W + n_counts), i32(W), *pools)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _program_hashes():
+    return {name: hashlib.sha1(text.encode()).hexdigest()[:16]
+            for name, text in _program_jaxprs().items()}
+
+
+@pytest.mark.parametrize("program", [
+    "toy.prefill", "toy.step", "dots.prefill", "dots.step", "xing.prefill",
+    "xing.step", "sdar.prefill", "sdar.step"])
+def test_models_without_row_state_run_the_parents_programs(program):
+    """``ToyDecoderLM``, the latent model with and without its streams
+    and module, and the block-diffusion model trace to the jaxprs they
+    traced to before a server could hold state beside its pages."""
+    assert _program_hashes()[program] == PARENT_PROGRAMS[program]
