@@ -556,7 +556,10 @@ def _serve_phase(cfg, seed, on_chip, device, default_err):
     check(all(len(s) == cfg["new_tokens"] for s in served),
           "serve: short generations %s" % [len(s) for s in served])
     check(pool_ok, "serve: KV pool is not on %s" % device)
-    want_programs = 1 + len(cfg["ladder"])
+    # the step and the mixed steps that carry a prompt's chunks; where a
+    # server keeps the whole-prompt prefill, the step and a rung each
+    want_programs = 1 + len(stats["chunk_sizes"] if stats["chunk"]
+                            else cfg["ladder"])
     check(n_programs == want_programs and len(sites) == want_programs
           and all(s["count"] == 1 for s in sites.values()),
           "serve: program set %s, expected %d programs compiled once"
@@ -564,12 +567,20 @@ def _serve_phase(cfg, seed, on_chip, device, default_err):
     check(total_compiles == warm_compiles,
           "serve: compiled after warmup (%d -> %d)"
           % (warm_compiles, total_compiles))
+    check(stats["prefill_programs"] == 0 and stats["chunk_tokens"]
+          == sum(len(p) for p in prompts) if stats["chunk"]
+          else stats["prefill_programs"] == len(reqs),
+          "serve: prompts did not ride the step as the server says: %s"
+          % {k: stats[k] for k in ("chunk", "chunk_tokens", "chunk_steps",
+                                   "prefill_programs")})
     if on_chip:
-        # prefill takes flash_attention, the step the paged decode
-        # kernel; the contiguous flash_decode is not on this path
+        # the step takes the paged decode kernel (a prompt's chunks ride
+        # it on lanes of composed attention; a server that keeps the
+        # prefill takes flash_attention there); the contiguous
+        # flash_decode is not on this path
         check(paths["flash_attention_jnp"] == 0
               and paths["paged_decode_jnp"] == 0
-              and paths["flash_attention_pallas"] > 0
+              and (stats["chunk"] or paths["flash_attention_pallas"] > 0)
               and paths["paged_decode_pallas"] > 0,
               "serve: attention did not take the kernels: %s" % paths)
     for c in checked:

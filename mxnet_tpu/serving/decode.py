@@ -18,6 +18,28 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   writes, all inside the compiled program. ``compile_watch.site_stats
   ("decode")`` is the oracle: ``1 + len(ladder)`` programs under ANY
   request mix, zero steady-state recompiles.
+- **A prompt rides the decode step in chunks** — on the plain step form
+  (a model that declares ``chunk_lanes``, below) over a layout that can
+  (per-head K and V, packed or not, and the latent array: every float
+  layout) there is NO prefill program: an admitted request holds its
+  pages and its prompt as tokens pending, and each decode step carries
+  a chunk of them — ``C`` consecutive positions of ONE request,
+  head-most first — on ``C`` more lanes of a MIXED step program
+  (``decode:step:chunk:c<C>``, ``_decode_fn_chunk``): the weights are
+  streamed once for the rows that decode and the prompt that arrives, no
+  row waits for a prompt, and the request's first token is the argmax of
+  its last chunk's last lane, fed to the next step where it lies on the
+  device. The plain program runs whenever no row has tokens pending.
+  A step's budget of prompt tokens is twice the ladder's smallest rung,
+  and the mixed program is built at every rung within it (256 and 512 of
+  a ladder 256 / 512 / 1024 / 1536; 256 of a ladder of 256): a chunk
+  takes the smallest that holds what is pending, the largest while more
+  is. The program set is then ``1 +`` those rungs, whatever the ladder.
+  The block, speculative and state forms, an int8 pool and a model that
+  does not declare it keep the whole-prompt prefill, ``1 + len(ladder)``.
+  ``stats()["chunk_steps"]`` / ``["chunk_tokens"]`` /
+  ``["prefill_programs"]`` count it; ``mx:decode.dispatch`` carries
+  ``chunk`` and ``chunk_of``.
 - **Paged KV cache** (``serving.kvcache``) — fixed-size pages, per
   request page tables, page 0 the masked dump page. Pages allocate on
   demand as generation crosses page boundaries; under pool pressure
@@ -27,7 +49,8 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   ``pool=``) — a completed prefill registers its page-aligned token
   run in the pool's content-hashed prefix index; a later prompt that
   matches enters decode on the SHARED refcounted pages and feeds only
-  the un-cached suffix through the one decode-step program (greedy
+  the un-cached suffix through the decode step, in chunks (one token a
+  step on its own row where the server runs none; greedy
   decode makes the shared stream token-identical to an unshared run —
   the same contract the stepwise-vs-full-forward oracle tests). The
   first write into a still-shared page copies it first (the ``:cow``
@@ -112,7 +135,15 @@ layout carries:
   force_pallas=False)`` (``kvcache.paged_latent_attention``).
   ``logits (B, V)``; one ``new`` a declared array, ``(n_layers, B,
   *trailing)``, which the server writes into the pool after the last
-  layer.
+  layer. A model that declares ``chunk_lanes = True`` lets a prompt
+  ride the step in chunks: its ``decode(..., head=None, live=None)``
+  is handed, by a mixed step, MORE lanes than rows
+  (``tokens``/``positions`` ``(B + C,)``; the model is row-wise in
+  everything but ``attend``, which the layout splits), ``head (B +
+  1,)``, the lanes whose logits are wanted — ``logits`` are theirs
+  alone, ``(B + 1, V)``, so that a chunk's lanes do not pay the head —
+  and ``live (B + C,)`` bool: a lane that is not live chooses no expert
+  (a model without routed experts has nothing to do with it).
 - ``model.step_counters`` (optional) — ``(group, (name, ...))``: the
   model's ``decode`` then returns, last, an int32 vector with one
   count a name (what the step's routing did, say). It leaves the
@@ -349,9 +380,10 @@ class _Step:
     for it), and what ``stats()`` counts when it is read back."""
 
     __slots__ = ("rows", "emits", "toks", "pages_live", "ahead", "slots",
-                 "seq", "launch")
+                 "seq", "launch", "chunk")
 
-    def __init__(self, rows, emits, toks, pages_live, ahead, seq, launch):
+    def __init__(self, rows, emits, toks, pages_live, ahead, seq, launch,
+                 chunk=None):
         self.rows = rows
         self.emits = emits
         self.toks = toks
@@ -359,6 +391,9 @@ class _Step:
         self.ahead = ahead
         self.seq = seq
         self.launch = launch
+        # a mixed step's chunk: ``(the row it fed, its tokens, the
+        # program's lanes)``
+        self.chunk = chunk
         # {id(row): its slot} in this step's output
         self.slots = {id(r): i for i, r in enumerate(rows)}
 
@@ -520,6 +555,10 @@ class ToyDecoderLM:
     Parameters are a FLAT ``{name: array}`` dict, so a checkpoint
     manifest round-trips them by name (the hot-swap recipe)."""
 
+    # ``decode`` takes ``head`` and ``live``: a prompt may ride the step
+    # in chunks
+    chunk_lanes = True
+
     def __init__(self, vocab=32, n_layers=2, n_heads=2, head_dim=8,
                  d_ff=None, max_len=256, use_pallas=False):
         self.vocab = int(vocab)
@@ -595,7 +634,8 @@ class ToyDecoderLM:
             @ params["wout"]
         return logits, jnp.stack(ks), jnp.stack(vs)
 
-    def decode(self, params, tokens, positions, attend):
+    def decode(self, params, tokens, positions, attend, head=None,
+               live=None):
         import jax
         import jax.numpy as jnp
         B = tokens.shape[0]
@@ -619,6 +659,10 @@ class ToyDecoderLM:
                 @ params["l%d.w2" % i]
             k_new.append(k)
             v_new.append(v)
+        if head is not None:
+            # a mixed step's chunk lanes do not pay the head: only the
+            # lanes whose logits are read reach it
+            h = h[head]
         logits = self._ln(h, params["out_g"], params["out_b"]) \
             @ params["wout"]
         return logits, jnp.stack(k_new), jnp.stack(v_new)
@@ -751,6 +795,23 @@ class DecodeServer:
                 "budget or raise the model's reach"
                 % (self._seq_ladder.max_batch, self._max_new,
                    self._max_context, model_reach))
+        # the plain step form over a layout that can, of a model that
+        # declares ``chunk_lanes``: a prompt rides the decode step in
+        # chunks and no prefill program is built; the block, speculative
+        # and state forms, an int8 pool and a model that does not
+        # declare it keep the whole-prompt prefill. A step's budget of
+        # prompt tokens is twice the ladder's smallest rung, and the
+        # mixed program is built at every rung within it (256 and 512 of
+        # 256 / 512 / 1024 / 1536): a chunk takes the smallest that
+        # holds what its prompt still has pending, so a short prompt
+        # pays no dead lanes and a long one half the steps
+        self._chunks = ()
+        if not (self._block or self._spec or self._state) \
+                and self._pool.layout.chunks \
+                and getattr(model, "chunk_lanes", False):
+            rungs = self._seq_ladder.buckets
+            self._chunks = tuple(r for r in rungs if r <= 2 * rungs[0])
+        self._chunk = self._chunks[-1] if self._chunks else 0
         self._max_pages = self._pool.pages_for(self._max_context)
         if self._max_pages > self._pool.usable_pages:
             raise MXNetError(
@@ -772,7 +833,7 @@ class DecodeServer:
         # donation makes each step update the pool in place on real
         # accelerators; the CPU PJRT client cannot donate (it would
         # only warn per compile), and correctness never depends on it
-        donate = step_donate = cow_donate = {}
+        donate = step_donate = chunk_donate = cow_donate = {}
         n_pool = len(self._pool.arrays)
         if jax.default_backend() not in ("cpu",):
             # (a state model's prefill is told its slot in front of them)
@@ -787,6 +848,8 @@ class DecodeServer:
             first = 8 if self._block or self._state else 6
             step_donate = {"donate_argnums": tuple(range(first,
                                                          first + n_pool))}
+            # (the mixed step's after its chunk, one array more)
+            chunk_donate = {"donate_argnums": tuple(range(7, 7 + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
         # ONE step program and one prefill program a rung, whatever the
         # kind of model: a block model's are the block forms, a
@@ -797,8 +860,15 @@ class DecodeServer:
             else self._state_decode_fn if self._state else self._decode_fn,
             "%s:step" % site,
             statics=(site, self._window, self._max_pages), **step_donate)
+        # beside it, where prompts ride the step: the mixed programs, the
+        # step's ``window`` lanes and a chunk's more — and no prefill
+        self._chunk_progs = {
+            C: compile_watch.jit(
+                self._decode_fn_chunk, "%s:step:chunk:c%d" % (site, C),
+                statics=(site, self._window, self._max_pages, C),
+                **chunk_donate) for C in self._chunks}
         self._prefill_progs = {}
-        for rung in self._seq_ladder.buckets:
+        for rung in () if self._chunks else self._seq_ladder.buckets:
             self._prefill_progs[rung] = compile_watch.jit(
                 self._block_prefill_fn if self._block
                 else self._spec_prefill_fn if self._spec
@@ -831,7 +901,8 @@ class DecodeServer:
                        "prefill_s": 0.0, "decode_pages_live": 0,
                        "decode_pages_table": 0,
                        "readback_wait_s": 0.0,
-                       "prefill_read_wait_s": 0.0}
+                       "prefill_read_wait_s": 0.0,
+                       "chunk_steps": 0, "chunk_tokens": 0}
         # every program the scheduler thread hands to the device takes
         # the next number (from 1, never reused; warm-up's take none):
         # the span that launches it says ``seq``, the span that waits
@@ -918,6 +989,55 @@ class DecodeServer:
         tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
         return self._step_fn(params, tokens, positions, page_tables,
                              *pools)
+
+    def _decode_fn_chunk(self, params, tokens, positions, page_tables,
+                         prev, src, chunk, *pools):
+        """The MIXED step program: :meth:`_decode_fn`'s ``window`` lanes
+        and, behind them, ``C`` lanes that are ``C`` consecutive
+        positions of ONE request's prompt — a decode step that carries a
+        chunk, the weights streamed once for both. ``chunk`` is the
+        host's: the lanes' tokens ``(C,)``, the request's page-table row
+        ``(max_pages,)``, the position of lane 0, how many lanes are
+        live and the request's slot among the step's rows. The model's
+        ``decode`` runs ``window + C`` rows — row-wise in everything but
+        ``attend``, which the layout splits (``attend_chunk``: the
+        decode rows keep their paged kernel, chunk lane ``j`` sees the
+        request's pages before the chunk and the chunk's own rows ``<=
+        j``) — and only ``window + 1`` of them reach the head: the
+        decode rows and the chunk's last live lane, whose argmax is the
+        request's first token once the chunk is its prompt's last. It is
+        put in the request's own slot of the ``(window,)`` token array,
+        so that ``prev`` keeps one shape and the next step takes it by
+        ``src`` on the device. Dead lanes (a last chunk shorter than
+        ``C``) write nothing and choose no expert."""
+        import jax.numpy as jnp
+        B, M = self._window, self._max_pages
+        C = chunk.shape[0] - M - 3
+        tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+        table, (start, n, slot) = chunk[C:C + M], chunk[C + M:]
+        lanes = jnp.arange(C, dtype=jnp.int32)
+        rows = jnp.arange(B, dtype=jnp.int32)
+        layout = kvcache.layout_for(self._model, pools)
+        attend = layout.attend_chunk(pools, page_tables, positions, table,
+                                     start)
+        logits, *new = self._model.decode(
+            params, jnp.concatenate([tokens, chunk[:C]]),
+            jnp.concatenate([positions, start + lanes]), attend,
+            head=jnp.concatenate([rows, B + jnp.maximum(n, 1)[None] - 1]),
+            live=jnp.concatenate([jnp.ones((B,), bool), lanes < n]))
+        n_arrays = len(layout.specs)
+        pools = layout.write_tokens(
+            pools, page_tables, positions,
+            [a[:, :B] for a in new[:n_arrays]],
+            getattr(self._model, "use_pallas", False))
+        pools = layout.write_chunk(
+            pools, table, start, n, [a[:, B:] for a in new[:n_arrays]])
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # (B + 1,)
+        tokens_out = jnp.where(rows == slot, out[B], out[:B])
+        if len(new) > n_arrays:
+            tokens_out = jnp.concatenate(
+                [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
+        return (tokens_out, *pools)
 
     def _step_fn(self, params, tokens, positions, page_tables, *pools):
         import jax.numpy as jnp
@@ -1325,8 +1445,10 @@ class DecodeServer:
 
     def warmup(self):
         """Compile the whole fixed program set (every prefill rung +
-        the decode step) before taking traffic, so no live request
-        ever pays an XLA compile. Warmup traffic writes only the dump
+        the decode step; where prompts ride the step in chunks, the step
+        and the mixed step, and no prefill) before taking traffic, so no
+        live request ever pays an XLA compile. Warmup traffic writes only
+        the dump
         page (``n_valid=0``, all-zero tables), so the pool's logical
         content is untouched; the returned pools are adopted (the
         programs may donate their pool inputs on real accelerators).
@@ -1345,7 +1467,7 @@ class DecodeServer:
             # a state model's prefill is told a slot; it writes nothing
             slot = (_np.int32(0),) if self._state else ()
             with self._pool.step_lock:
-                for rung in self._seq_ladder.buckets:
+                for rung in self._prefill_progs:
                     toks = _np.zeros((1, rung), _np.int32)
                     out = self._prefill_progs[rung](
                         self._params.tree, toks, _np.int32(0),
@@ -1374,14 +1496,23 @@ class DecodeServer:
                     feed = (_np.zeros((self._window,), _np.int32), pos)
                 # twice: fed nothing, then fed its own token array, the
                 # two kinds of ``prev`` a live step is handed
-                prev = self._no_prev
-                for _ in range(2):
-                    out = self._decode_prog(
-                        self._params.tree, *feed, pts, prev, src,
-                        *self._pool.arrays)
-                    jax.block_until_ready(out[0])
-                    prev = self._adopt_pool(out)[0]
-                n += 1
+                # (the mixed programs too, told a chunk of no live lane
+                # for no row: it writes nothing)
+                for C, prog in ((0, self._decode_prog),
+                                *self._chunk_progs.items()):
+                    chunk = ()
+                    if C:
+                        idle = _np.zeros((C + self._max_pages + 3,),
+                                         _np.int32)
+                        idle[-1] = -1
+                        chunk = (idle,)
+                    prev = self._no_prev
+                    for _ in range(2):
+                        out = prog(self._params.tree, *feed, pts, prev,
+                                   src, *chunk, *self._pool.arrays)
+                        jax.block_until_ready(out[0])
+                        prev = self._adopt_pool(out)[0]
+                    n += 1
                 if self._prefix_on:
                     # the COW copy joins the fixed set only when the
                     # prefix cache can actually trigger it; dump page
@@ -1604,19 +1735,23 @@ class DecodeServer:
 
     def _tick(self):
         """One scheduler pass: reap cancellations/deadlines, admit at
-        most ONE prefill, dispatch ONE decode step over every active
-        request, then read back and hand out the tokens of the step
+        most ONE request, dispatch ONE decode step over every active
+        request — where prompts ride the step, with up to ``chunk``
+        tokens of the head-most pending prompt on the mixed program's
+        lanes — then read back and hand out the tokens of the step
         dispatched a pass EARLIER — the interleave that keeps decode
-        from starving behind prefill bursts, one step ahead of the
+        from starving behind bursts of prompts, one step ahead of the
         host. In the device's order: build N+1 → dispatch N+1 → read
         back N → emit N → record → reap / admit / pages → build N+2;
         everything the host does with step N's tokens, and the next
         launch, run while step N+1 does. What is planned counts the
         token in flight (``DecodeRequest.unread``): positions, the page
-        a write lands in, the end by ``max_new``. A prefill is
-        dispatched behind the step in flight and its first token read
-        at once (it is the request's time to first token). Returns
-        True when any step ran or was read."""
+        a write lands in, the end by ``max_new``. Where a form keeps
+        the prefill program, it is dispatched behind the step in flight
+        and its first token read at once (it is the request's time to
+        first token); a prompt that rides the step launches nothing at
+        admission, and its first token is read with its last chunk's
+        step. Returns True when any step ran or was read."""
         with self._cond:
             warming = self._warming
         if warming:                    # warmup owns the pool arrays:
@@ -1716,6 +1851,8 @@ class DecodeServer:
                     self._namespace(req.params), run, req.pages)
             self._pool.free(req.pages)
             req.pages = []
+        # whatever of its prompt was still to be fed goes with the pages
+        req.pending = None
         if req.slot is not None:
             # the row of the state arrays goes back as it is: its next
             # tenant's prefill writes it whole
@@ -1780,7 +1917,10 @@ class DecodeServer:
 
     def _admit(self, req, ver, sp):
         """The head of the queue, under its ``decode.admit`` span
-        ``sp``: pages (shared prefix pages first), activation, prefill."""
+        ``sp``: pages (shared prefix pages first), activation, and then
+        the prompt — set pending for the decode step to carry in chunks
+        (nothing is launched), or run through the prefill program of its
+        rung where the model's form or the pool's layout keeps one."""
         P = len(req.prompt)
         rung = self._seq_ladder.bucket_for(P)
         shared, cached = [], 0
@@ -1843,17 +1983,27 @@ class DecodeServer:
         if pages_back is not None:
             self._pool.free(pages_back)
             return False
-        if shared:
-            # prefix hit: no prefill program at all. The un-cached
-            # suffix feeds through the decode-step program token by
-            # token (outputs discarded until the last, which IS the
-            # first generated token) — the stepwise≡full-forward
-            # greedy contract makes the stream token-identical to an
-            # unshared run. A fully-cached page-aligned prompt re-runs
-            # only its last token; its write COWs the shared page.
+        if shared or self._chunk:
+            # no prefill program at all: the prompt — after a prefix
+            # hit, its un-cached suffix — feeds through the decode step,
+            # ``chunk`` tokens a step on the lanes of the mixed program
+            # (one token a step on the row's own lane where the server
+            # has none), outputs discarded until the last, which IS the
+            # first generated token: the stepwise≡full-forward greedy
+            # contract makes the stream token-identical to a prefilled
+            # run. Nothing is launched here. A fully-cached page-aligned
+            # prompt re-runs only its last token; its write COWs the
+            # shared page.
             start = min(cached, P - 1)
             req.pending = deque(int(t) for t in req.prompt[start:])
             req.pending_pos = start
+            if req.trace_args is not None:
+                tracing.add(
+                    "queue", "decode", req._t_trace, sp.t0 - req._t_trace,
+                    tid=tracing.track(
+                        "req %s" % req.trace_args["request_id"]),
+                    args=req.trace_args)
+                req._t_trace = sp.t0
             return True
         # run the prefill program at the prompt's rung
         tokens = _np.zeros((1, rung), _np.int32)
@@ -1974,11 +2124,15 @@ class DecodeServer:
                 # self-drafting one: the furthest its next step can
                 # write, two positions from where it starts, which is
                 # one or two past the start of a step still unread)
-                wp = r.pending_pos if r.pending \
+                # (a row whose prompt rides the step in chunks: every
+                # position of its next chunk, ``first`` to ``wp``)
+                first = wp = r.pending_pos if r.pending \
                     else self._last_block_position(r) if self._block \
                     else self._spec_position(r) + 1 + 2 * r.unread \
                     if self._spec \
                     else len(r.prompt) + len(r.generated) + r.unread - 1
+                if r.pending and self._chunks:
+                    wp += min(self._chunk, len(r.pending)) - 1
                 needed = wp // self._pool.page_size + 1
                 while len(r.pages) < needed:
                     pg = self._pool.alloc(1, owner=self._owner)
@@ -2005,15 +2159,19 @@ class DecodeServer:
                         survivors.remove(victim)
                 if failed:
                     break
-                if self._prefix_on and \
-                        self._pool.ref(r.pages[wp // self._pool
-                                               .page_size]) > 1:
-                    got = self._cow_row(r, wp // self._pool.page_size)
+                shared = next(
+                    (i for i in range(first // self._pool.page_size,
+                                      needed)
+                     if self._pool.ref(r.pages[i]) > 1), None) \
+                    if self._prefix_on else None
+                if shared is not None:
+                    got = self._cow_row(r, shared)
                     if got == "died":
                         failed = True
                         break
-                    if got == "degraded":
-                        continue   # re-alloc from position 0
+                    # (split: the pages behind it may be shared too;
+                    # degraded: re-alloc from position 0)
+                    continue
                 break
             if not failed:
                 survivors.append(r)
@@ -2148,12 +2306,26 @@ class DecodeServer:
             src = _np.full((D,), -1, _np.int32)
             slots = {} if prev is None else prev.slots
             emits = []
+            chunk = None
+            decoding = _np.zeros((D,), bool)
             for i, r in enumerate(rows):
+                if r.pending and self._chunks:
+                    # its prompt rides the step in chunks: the
+                    # head-most such row is fed this step's (FIFO, one
+                    # request's chunk a step), the others wait; either
+                    # way the row's own lane stays out of the step (no
+                    # table: the dump page) until its last chunk is in
+                    if chunk is None:
+                        chunk = self._build_chunk(i, r)
+                    emits.append(chunk[0] is r and r.pending is None)
+                    continue
+                decoding[i] = True
                 if r.pending:
-                    # prefix-cache suffix feed: the next un-cached
-                    # token runs through the same step program at its
-                    # own absolute position. The feed advances here,
-                    # at dispatch: nothing it plans from is computed
+                    # prefix-cache suffix feed where no chunk runs: the
+                    # next un-cached token runs through the same step
+                    # program at its own absolute position. The feed
+                    # advances here, at dispatch: nothing it plans from
+                    # is computed
                     tokens[i] = r.pending.popleft()
                     positions[i] = r.pending_pos
                     r.pending_pos += 1
@@ -2174,9 +2346,14 @@ class DecodeServer:
             # the pages that hold this step's live keys, of the table
             # the step program is compiled for: what a kernel that
             # reads pages where they lie has to stream
-            pages_live = int((positions[:len(rows)]
+            pages_live = int((positions[decoding]
                               // self._pool.page_size + 1).sum())
             feed, said = (tokens, positions), {}
+            if chunk is not None:
+                fed, n, _array = chunk
+                pages_live += (fed.pending_pos - 1) \
+                    // self._pool.page_size + 1
+                said = {"chunk": n, "chunk_of": fed.request_id}
             if self._state:
                 # every row of the state arrays, the live rows' own
                 # first: what another weight generation's rows hold, and
@@ -2187,24 +2364,50 @@ class DecodeServer:
                          _np.int32(len(rows)))
                 said = {"state_rows_live": len(rows)}
         self._dispatch_step(ver, rows, emits, feed + (pts,), src,
-                            pages_live, said, prev)
+                            pages_live, said, prev, chunk)
+
+    def _build_chunk(self, slot, r):
+        """The next chunk of ``r``'s pending tokens, for the mixed step
+        in which ``r`` is row ``slot``: ``(r, its tokens, the program's
+        chunk array)`` — the lanes' tokens, ``r``'s page-table row, the
+        first lane's position, the live lanes and the slot; the array's
+        length says which of the mixed programs takes it, the smallest
+        that holds what is pending (the largest, while more is). The
+        feed advances here, at dispatch: nothing it plans from is
+        computed."""
+        M = self._max_pages
+        C = next((c for c in self._chunks if c >= len(r.pending)),
+                 self._chunk)
+        n = min(C, len(r.pending))
+        array = _np.zeros((C + M + 3,), _np.int32)
+        array[:n] = [r.pending.popleft() for _ in range(n)]
+        array[C:C + len(r.pages)] = r.pages
+        array[C + M:] = r.pending_pos, n, slot
+        r.pending_pos += n
+        if not r.pending:
+            r.pending = None
+        return r, n, array
 
     def _dispatch_step(self, ver, rows, emits, feed, src, pages_live,
-                       said, prev):
+                       said, prev, chunk=None):
         """Dispatch the step that was built (``feed``: the host's arrays
         in front of ``prev`` in the program's signature; ``said``: what
-        else the ``decode.dispatch`` span carries), then read back the
-        step before it."""
+        else the ``decode.dispatch`` span carries; ``chunk``: what
+        :meth:`_build_chunk` made, which takes the mixed program), then
+        read back the step before it."""
         self._seq = seq = self._seq + 1
+        lanes = len(chunk[2]) - self._max_pages - 3 if chunk else 0
+        prog, carried = (self._chunk_progs[lanes], (chunk[2],)) if chunk \
+            else (self._decode_prog, ())
         try:
             with tracing.span("decode.dispatch", seq=seq, program="step",
                               pages_live=pages_live,
                               ahead=int(prev is not None),
                               **said) as launch, self._pool.step_lock:
-                toks = self._adopt_pool(self._decode_prog(
+                toks = self._adopt_pool(prog(
                     ver.tree, *feed,
                     self._no_prev if prev is None else prev.toks, src,
-                    *self._pool.arrays))[0]
+                    *carried, *self._pool.arrays))[0]
         except Exception as exc:       # noqa: BLE001 — model errors
             # belong to the batch's requests, after what the step
             # before computed for them has been handed out
@@ -2214,7 +2417,18 @@ class DecodeServer:
         for r, emit in zip(rows, emits):
             r.unread += emit
         self._unread = _Step(rows, emits, toks, pages_live,
-                             prev is not None, seq, launch)
+                             prev is not None, seq, launch,
+                             chunk and (*chunk[:2], lanes))
+        if chunk is not None and self._prefix_on:
+            # the prompt's pages that this chunk completed are written
+            # (in the device's order) before whatever is dispatched
+            # later reads them: the NEXT same-prefix prompt shares them
+            # from here on (the index retains its own reference)
+            fed = chunk[0]
+            self._pool.prefix_insert(
+                self._namespace(ver), _np.concatenate(
+                    [fed.prompt, _np.asarray(fed.generated, _np.int32)]
+                )[:fed.pending_pos], fed.pages)
         if prev is not None:
             self._read(prev)
 
@@ -2569,10 +2783,22 @@ class DecodeServer:
                         self._intervals.append(
                             (now - r._last_emit) * 1e3)
                     else:
-                        # a prefix-hit row's FIRST token lands here,
-                        # not in a prefill — this is its
-                        # time-to-first-token
+                        # the FIRST token of a row whose prompt rode the
+                        # step (in chunks, or a prefix hit's suffix)
+                        # lands here, not in a prefill — this is its
+                        # time-to-first-token, and the end of the
+                        # ``prefill`` phase on its track
                         self._ttft.append((now - r.t_submit) * 1e3)
+                        if r.trace_args is not None \
+                                and r._t_trace is not None:
+                            tracing.add(
+                                "prefill", "decode", r._t_trace,
+                                now - r._t_trace, tid=tracing.track(
+                                    "req %s" % r.trace_args["request_id"]),
+                                args=dict(r.trace_args,
+                                          fed=len(r.prompt)
+                                          - r.prefix_cached))
+                            r._t_trace = now
                     r._last_emit = now
             for i, r in emitting:
                 tok = int(toks[i])
@@ -2593,14 +2819,19 @@ class DecodeServer:
         are ``stats()["tokens_out"]``'s to count."""
         if not metering.enabled():
             return
-        cost = compile_watch.last_dispatch("%s:step" % self._site)
-        live = [r for r in step.rows if r.state == "active"]
-        if cost is not None and live:
-            share = 1.0 / len(live)
-            for r in live:
+        fed, n, lanes = step.chunk or (None, 0, 0)
+        cost = compile_watch.last_dispatch(
+            "%s:step%s" % (self._site, ":chunk:c%d" % lanes if n else ""))
+        # (the row whose chunk a mixed step carried ran the chunk's
+        # positions, not one)
+        ran = [(r, n if r is fed else 1)
+               for r in step.rows if r.state == "active"]
+        total = sum(k for _r, k in ran)
+        if cost is not None and total:
+            for r, k in ran:
                 metering.request_flops(
                     metering.inner_key(self, r.request_id),
-                    cost["flops"] * share, cost["bytes"] * share)
+                    cost["flops"] * k / total, cost["bytes"] * k / total)
 
     def _model_counts(self, toks, first):
         """What the model counted in a step, by name, from the step's
@@ -2629,6 +2860,9 @@ class DecodeServer:
         st["decode_steps_ahead"] += step.ahead
         st["decode_pages_live"] += step.pages_live
         st["decode_pages_table"] += self._window * self._max_pages
+        if step.chunk:
+            st["chunk_steps"] += 1
+            st["chunk_tokens"] += step.chunk[1]
         if counts is not None:
             self._count_step(counts)
 
@@ -2650,7 +2884,13 @@ class DecodeServer:
         percentiles, prefill-vs-decode step mix, KV-pool occupancy,
         swap/version state — the ``decode`` telemetry record, the
         diagnose Decode table, and the /metrics gauges all render
-        this. Decode steps are counted when they are READ BACK, one
+        this. ``chunk`` is a step's budget of prompt tokens, the widest
+        of the mixed programs' lanes ``chunk_sizes`` (0, none: prompts
+        run a prefill program), ``chunk_steps`` of ``decode_steps`` carried a
+        chunk of a prompt, ``chunk_tokens`` prompt tokens in all (the
+        prompt tokens admitted less prefix hits, and a degraded row's
+        re-feed), ``prefill_programs`` the prefill programs launched (0
+        on a server that chunks). Decode steps are counted when they are READ BACK, one
         pass after their dispatch: ``decode_steps_ahead`` of
         ``decode_steps`` were dispatched while the step before them
         was still unread (the host's share of a token hidden under the
@@ -2709,6 +2949,11 @@ class DecodeServer:
             "active": active,
             "window": self._window,
             "prefill_steps": s["prefill_steps"],
+            "prefill_programs": launches["prefill"],
+            "chunk": self._chunk,
+            "chunk_sizes": list(self._chunks),
+            "chunk_steps": s["chunk_steps"],
+            "chunk_tokens": s["chunk_tokens"],
             "decode_steps": s["decode_steps"],
             "decode_steps_ahead": s["decode_steps_ahead"],
             "decode_drains": drains,

@@ -47,7 +47,14 @@ D`` lanes a token, ``(n_layers, n_pages, page_size, H * D)``
 :func:`write_block_rows` — which also serve a step that runs BLOCKS of
 query positions a row, over either per-head form: a row's block and the
 block after it in one pass, the first committed from inside each layer
-where it is final, :class:`_BlockStep`). The layout alone knows which arrays a
+where it is final, :class:`_BlockStep`). Every float layout also runs a
+CHUNK of one row's prompt beside a step's decode rows
+(``DecodeServer``'s mixed step; ``chunks``, ``attend_chunk``,
+:func:`paged_chunk_attention`, :func:`paged_latent_chunk_attention`,
+:func:`write_chunk_rows`: ``C`` consecutive positions of ONE row, lane
+``j`` over the row's pages before the chunk and the chunk's own rows
+``<= j``, written in place across page boundaries). The layout alone
+knows which arrays a
 program carries, what ``attend`` a step hands its model and how a
 prefill's sequences and a step's new rows reach their pages; the
 server's three programs are written over it, once. The page accounting
@@ -152,7 +159,8 @@ __all__ = ["KVCachePool", "PrefixIndex", "gather_pages",
            "paged_attention", "paged_latent_attention",
            "paged_block_attention", "write_block_rows",
            "paged_latent_causal_attention", "write_latent_rows",
-           "cache_layout",
+           "paged_chunk_attention", "paged_latent_chunk_attention",
+           "write_chunk_rows", "cache_layout",
            "declared_arrays", "declared_state", "layout_for", "RowState",
            "scatter_token", "scatter_prefill", "write_prefill_pages",
            "write_token_rows",
@@ -581,6 +589,190 @@ def write_block_rows(pages, page_table, positions, new, commit,
 
 
 # ---------------------------------------------------------------------------
+# a chunk of ONE row's prompt beside a step's decode rows
+# ---------------------------------------------------------------------------
+# ``C`` consecutive positions ``start .. start + C - 1`` of one request
+# run as ``C`` more lanes of a decode step (``DecodeServer``'s mixed
+# step): lane ``j`` sees the row's keys in the pool before ``start`` and
+# the chunk's own new rows ``<= j``. Composed ``jnp`` on every platform:
+# the row's pages are walked a block of keys at a time, as many blocks
+# as hold live keys (a trip count from ``start``, so a prompt's first
+# chunk reads nothing of the pool), under a running max and sum.
+
+_CHUNK_KEYS = 512      # pool keys a block of the walk holds, about
+
+
+def _chunk_softmax(table_row, start, page_size, lanes, scores, mix, own,
+                   gathered):
+    """The softmax of a chunk's ``lanes`` queries, un-normalised sum and
+    normaliser folded block by block: first over the chunk's ``own =
+    (keys, values)``, causal among themselves, then over the row's pages
+    — ``gathered(pages (n,)) -> (keys, values)`` of ``n`` pages at a
+    time, ``ceil(start / (n * S))`` times, keys at or past ``start``
+    masked. ``scores(keys) -> (..., lanes, T)`` float32, ``mix(p,
+    values)`` the weighted sum. A masked score is ``_NEG``: its weight is
+    an exact zero, because the running max is never below a lane's score
+    with its own new row. Returns ``mix``'s shape, normalised."""
+    import jax
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import _NEG
+    S = int(page_size)
+    n = max(1, _CHUNK_KEYS // S)
+    table = jnp.asarray(table_row, jnp.int32)
+    table = jnp.pad(table, (0, -table.shape[0] % n))
+    at = jnp.arange(n * S, dtype=jnp.int32)
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    s = jnp.where(lane[:, None] >= lane[None, :], scores(own[0]), _NEG)
+    m = jnp.max(s, axis=-1)
+    p = jnp.exp(s - m[..., None])
+
+    def block(j, carry):
+        m, l, acc = carry
+        keys, values = gathered(
+            jax.lax.dynamic_slice_in_dim(table, j * n, n))
+        s = jnp.where(j * (n * S) + at < start, scores(keys), _NEG)
+        m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+        a = jnp.exp(m - m2)
+        p = jnp.exp(s - m2[..., None])
+        return m2, l * a + jnp.sum(p, axis=-1), \
+            acc * a[..., None] + mix(p, values)
+
+    _m, l, acc = jax.lax.fori_loop(
+        0, (start + n * S - 1) // (n * S), block,
+        (m, jnp.sum(p, axis=-1), mix(p, own[1])))
+    return acc / l[..., None]
+
+
+def _block_pages(pool, layer, pages, start):
+    """``pool[layer, pages]`` for one block of the walk, ``(n, S, ...)``.
+    A float32 pool's keys are multiplied in bfloat16 passes, and XLA
+    moves that rounding up through the gather and out of the walk: it
+    rounds the WHOLE pool once a step (2.7 GB of temporaries and 8 GB of
+    traffic at the benchmark's sizes, sandbox compile). Adding a zero the
+    compiler cannot know (``start`` is never negative) keeps the rounding
+    behind the gather, on the block's pages alone."""
+    return pool[layer, pages] + (start < 0).astype(pool.dtype)
+
+
+def paged_chunk_attention(k_pages, v_pages, table_row, start, layer, q,
+                          k_new, v_new, *, scale=None):
+    """One layer's attention of a chunk over per-head K and V. ``q (C,
+    Hq, D)``, ``k_new``/``v_new (C, Hkv, D)``: ``C`` consecutive
+    positions of ONE row from ``start`` on, NOT in the pool yet
+    (:func:`write_chunk_rows` writes them at the step's end); lane ``j``
+    attends the row's ``start`` earlier keys through ``table_row (M,)``
+    and new rows ``0 .. j``. Query head ``i`` reads key/value head ``i
+    // (Hq // Hkv)``. The pools are per-head ``(L, P, S, Hkv, D)`` or
+    packed ``(L, P, S, Hkv * D)``. Scores and softmax in float32, the new
+    rows rounded to the pool's dtype as the pool will hold them. Returns
+    ``(C, Hq, D)`` float32. Lanes past the chunk's live ones compute
+    finite garbage nobody reads."""
+    import jax.numpy as jnp
+    C, Hq, D = q.shape
+    Hkv = k_new.shape[1]
+    f32 = jnp.float32
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    qg = (q * scale).reshape(C, Hkv, Hq // Hkv, D)
+
+    def scores(keys):
+        return jnp.einsum("chgd,thd->hgct", qg, keys,
+                          preferred_element_type=f32)
+
+    def mix(p, values):
+        return jnp.einsum("hgct,thd->hgcd", p, values,
+                          preferred_element_type=f32)
+
+    def gathered(pages):
+        return tuple(_block_pages(pool, layer, pages, start)
+                     .reshape(-1, Hkv, D) for pool in (k_pages, v_pages))
+
+    out = _chunk_softmax(
+        table_row, start, k_pages.shape[2], C, scores, mix,
+        (k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype)),
+        gathered)                                      # (Hkv, G, C, D)
+    return jnp.transpose(out, (2, 0, 1, 3)).reshape(C, Hq, D)
+
+
+def paged_latent_chunk_attention(kv_pages, table_row, start, layer, q,
+                                 kv_new, *, rank, scale):
+    """:func:`paged_chunk_attention` for a latent pool ``(L, P, S, W)``:
+    ``q (C, H, W)`` the absorbed queries, ``kv_new (C, W)`` the chunk's
+    own latents. Operands in the pool's dtype, scores and softmax in
+    float32; returns the weighted sum of the first ``rank`` columns,
+    ``(C, H, rank)`` float32."""
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    q = (q * scale).astype(kv_pages.dtype)
+    kv_new = kv_new.astype(kv_pages.dtype)
+
+    def scores(rows):
+        return jnp.einsum("chw,tw->hct", q, rows,
+                          preferred_element_type=f32)
+
+    def mix(p, rows):
+        return jnp.einsum("hct,tr->hcr", p, rows[:, :rank],
+                          preferred_element_type=f32)
+
+    def gathered(pages):
+        rows = _block_pages(kv_pages, layer, pages, start)
+        return (rows.reshape(-1, rows.shape[-1]),) * 2
+
+    out = _chunk_softmax(table_row, start, kv_pages.shape[2], q.shape[0],
+                         scores, mix, (kv_new, kv_new), gathered)
+    return jnp.swapaxes(out, 0, 1)                     # (C, H, rank)
+
+
+def write_chunk_rows(pages, table_row, start, n_live, new):
+    """A chunk's new rows into the pool, in place: ``new (L, C, ...)``,
+    the ``C`` rows of ONE row at positions ``start .. start + C - 1``, of
+    which the first ``n_live`` are written through ``table_row (M,)`` —
+    at any offset, across page boundaries. Page by page, as
+    :func:`write_prefill_pages` writes a prompt's (XLA's TPU scatter
+    widens a 16-bit pool to float32 and back, whole): each page the
+    chunk can touch is read, its rows in ``[start, start + n_live)``
+    replaced, and written back with ``dynamic_update_slice``, a layer at
+    a time; a page that holds none of them is the dump page's,
+    unchanged. Works on any float pool ``(L, P, S, ...)``, per-head,
+    packed or latent."""
+    import jax
+    import jax.numpy as jnp
+    S = pages.shape[2]
+    L, C = new.shape[:2]
+    trailing = pages.shape[3:]
+    table = jnp.asarray(table_row, jnp.int32)
+    new = new.astype(pages.dtype).reshape((L, C) + trailing)
+    touched = (C + S - 2) // S + 1          # pages C rows reach at most
+    off = start % S
+    tail = (0,) * len(trailing)
+    # the chunk as it lies on ``touched`` whole pages, lane 0 at ``off``
+    wide = jax.lax.dynamic_update_slice(
+        jnp.zeros((L, touched * S) + trailing, pages.dtype), new,
+        (0, off) + tail)
+    rows = jnp.arange(S, dtype=jnp.int32)
+    keep_shape = (1, 1, S) + (1,) * len(trailing)
+
+    def write_page(i, pages):
+        # one layer's page at a time: a block that is contiguous in the
+        # pool as it lies (a block over all layers makes XLA re-lay the
+        # whole pool out, page-major, and copy it back: 2.7 GB of
+        # temporaries at the benchmark's sizes, sandbox compile)
+        l, c = i // touched, i % touched
+        lane = c * S + rows - off
+        live = jnp.logical_and(lane >= 0, lane < n_live)
+        at = jnp.minimum(start // S + c, table.shape[0] - 1)
+        pidx = jnp.where(jnp.any(live), table[at], 0)
+        old = jax.lax.dynamic_slice(
+            pages, (l, pidx, 0) + tail, (1, 1, S) + trailing)
+        page = jax.lax.dynamic_slice(
+            wide, (l, c * S) + tail, (1, S) + trailing)
+        return jax.lax.dynamic_update_slice(
+            pages, jnp.where(live.reshape(keep_shape), page[:, None], old),
+            (l, pidx, 0) + tail)
+
+    return jax.lax.fori_loop(0, L * touched, write_page, pages)
+
+
+# ---------------------------------------------------------------------------
 # quantized (int8 + per-page fp32 scale) variants — same traced shapes
 # ---------------------------------------------------------------------------
 
@@ -749,6 +941,45 @@ class _PerHeadKV:
         return _BlockStep(self, pools, page_tables, positions, commit,
                           fresh, force_pallas)
 
+    # a chunk of ONE row's prompt beside a step's decode rows
+    # (``DecodeServer``'s mixed step): ``C`` consecutive positions from
+    # ``start`` on as ``C`` more lanes behind the step's ``B``. Every
+    # float layout has it; int8 pages (a chunk's rows would requantize
+    # theirs) and fixed state a row (a chunk of a recurrence is another
+    # recurrence) have not
+    chunks = True
+    _chunk_attention = staticmethod(paged_chunk_attention)
+
+    def attend_chunk(self, pools, page_tables, positions, table_row,
+                     start):
+        """The ``attend`` of a mixed step, called once a layer with the
+        model's own arguments, every array ``(B + C, ...)``: the first
+        ``B`` lanes are the decode rows', through :meth:`attend` as in
+        any step (they keep the paged kernels they have); the lanes
+        behind them one row's chunk, lane ``j`` over that row's keys
+        before ``start`` and the chunk's own new rows ``<= j``."""
+        import jax.numpy as jnp
+        B = len(positions)
+        rows = self.attend(pools, page_tables, positions)
+        chunk = functools.partial(self._chunk_attention, *pools, table_row,
+                                  start)
+
+        def attend(layer, *arrays, force_pallas=False, **how):
+            head = rows(layer, *(a[:B] for a in arrays),
+                        force_pallas=force_pallas, **how)
+            tail = chunk(layer, *(a[B:] for a in arrays), **how)
+            return jnp.concatenate([head, tail.astype(head.dtype)])
+
+        return attend
+
+    def write_chunk(self, pools, table_row, start, n_live, new):
+        """A chunk's new rows ``(L, C, ...)`` an array into their pages,
+        the first ``n_live`` of them, from position ``start`` on; they
+        may cross page boundaries."""
+        return tuple(write_chunk_rows(pages, table_row, start, n_live,
+                                      rows)
+                     for pages, rows in zip(pools, new))
+
 
 class _BlockStep:
     """The ``attend`` of a block step, which runs TWO blocks a row — the
@@ -853,6 +1084,8 @@ class _Latent(_PerHeadKV):
         return functools.partial(paged_latent_attention, *pools,
                                  page_tables, positions)
 
+    _chunk_attention = staticmethod(paged_latent_chunk_attention)
+
     def attend_causal(self, pools, page_tables, positions):
         """The ``attend`` a speculative step hands its model."""
         return functools.partial(paged_latent_causal_attention, *pools,
@@ -887,6 +1120,7 @@ class _PerHeadKVInt8(_PerHeadKV):
     quantize."""
 
     blocks = False      # a block's rows would requantize their page
+    chunks = False      # and so would a chunk's
 
     def arrays(self, n_layers, n_pages, page_size):
         pages = super().arrays(n_layers, n_pages, page_size)
@@ -985,6 +1219,7 @@ class _RowStateBeside:
 
     blocks = False
     causal_blocks = False
+    chunks = False
 
     def __init__(self, pages, state, layers):
         self.pages, self.state, self.state_layers = pages, state, layers

@@ -149,6 +149,9 @@ class LatentMoEDecoderLM:
     (``draft_length`` 1: ``verify`` / ``draft`` in ``decode``'s place)."""
 
     step_counters = ("moe", ("moe_slots", "experts_touched", "max_load"))
+    # ``decode`` takes ``head`` and ``live``: a prompt may ride the
+    # step in chunks (``DecodeServer``)
+    chunk_lanes = True
 
     def __init__(self, *, vocab_size, hidden_size, num_hidden_layers,
                  num_attention_heads, q_lora_rank, kv_lora_rank,
@@ -366,9 +369,12 @@ class LatentMoEDecoderLM:
         u = self._mm(x, p[prefix + "w_up"])
         return self._mm(jax.nn.silu(g) * u, p[prefix + "w_down"])
 
-    def _ffn(self, i, x, p, routed=None):
+    def _ffn(self, i, x, p, routed=None, live=None):
         """``x (T, D)`` float32 -> ``(out (T, D), load (E_held,) or
-        None)``; ``routed``, a list, is given the router's choice."""
+        None)``; ``routed``, a list, is given the router's choice. A
+        token that is not ``live (T,)`` chooses no expert: its choice is
+        put past the last expert, which nobody holds."""
+        import jax.numpy as jnp
         from ..parallel import moe
         l = "l%d." % i
         if i < self.n_dense:
@@ -377,6 +383,8 @@ class LatentMoEDecoderLM:
             x, p[l + "router_w"], p[l + "router_b"], n_group=self.n_group,
             topk_group=self.topk_group, top_k=self.top_k,
             scaling=self.route_scale)
+        if live is not None:
+            topi = jnp.where(live[:, None], topi, self.n_experts)
         if routed is not None:
             routed.append(topi)
         out = moe.expert_ffn(
@@ -471,7 +479,7 @@ class LatentMoEDecoderLM:
             return (res[..., None] * X[..., None, :, :]).sum(-2) \
                 + post[..., None] * y[..., None, :]
 
-    def _block(self, i, X, p, attention, routed=None):
+    def _block(self, i, X, p, attention, routed=None, live=None):
         """Block ``i`` over the state: ``attention(i, u) -> (increment,
         cached row)`` is the path's own (prefill or cached decode).
         Returns ``(X, row, expert load or None)``."""
@@ -481,7 +489,8 @@ class LatentMoEDecoderLM:
         X = self._write(X, out, mix)
         u, mix = self._read(l + "ffn_", X, p)
         x = self._rms(u, p[l + "ffn_g"])
-        out, load = self._ffn(i, x.reshape(-1, x.shape[-1]), p, routed)
+        out, load = self._ffn(i, x.reshape(-1, x.shape[-1]), p, routed,
+                              live)
         return self._write(X, out.reshape(x.shape), mix), row, load
 
     def _draft_in(self, p, hidden, tokens):
@@ -601,7 +610,7 @@ class LatentMoEDecoderLM:
 
         return attention
 
-    def _cached(self, p, e, layers, positions, attend):
+    def _cached(self, p, e, layers, positions, attend, live=None):
         """Blocks ``layers`` over the embeddings ``e (..., D)`` through
         the cache: ``(h summed over the streams, the layers' rows, the
         expert layers' loads)``."""
@@ -609,18 +618,25 @@ class LatentMoEDecoderLM:
         attention = self._absorbed(p, positions, attend)
         rows, loads = [], []
         for i in layers:
-            X, row, load = self._block(i, X, p, attention)
+            X, row, load = self._block(i, X, p, attention, live=live)
             rows.append(row)
             if load is not None:
                 loads.append(load)
         return self._merge(X), rows, loads
 
-    def decode(self, params, tokens, positions, attend):
+    def decode(self, params, tokens, positions, attend, head=None,
+               live=None):
+        """The one-token form; a mixed step (``DecodeServer``) hands it
+        more lanes than rows: ``head``, the lanes whose logits are
+        wanted (the others do not pay the head), and ``live (T,)``, the
+        lanes that are real (a dead one chooses no expert)."""
         import jax.numpy as jnp
         p = params
         h, rows, loads = self._cached(
             p, p["embed"][tokens].astype(jnp.float32),
-            range(self.n_layers), positions, attend)
+            range(self.n_layers), positions, attend, live)
+        if head is not None:
+            h = h[head]
         logits = self._mm(self._rms(h, p["out_g"]), p["head"])
         counters = self._counters(loads)
         return logits, jnp.stack(rows), counters

@@ -226,6 +226,87 @@ def test_decode_step_program_compiles_and_fits(chip, monkeypatch, shapes):
     assert mem.temp_size_in_bytes < 0.5e9, mem
 
 
+def _mixed_holder(model, window, max_pages):
+    """What the mixed step program needs of its server, unbound."""
+    return type("S", (), {"_model": model, "_window": window,
+                          "_max_pages": max_pages})()
+
+
+@pytest.mark.parametrize("config,C", [("opt-6.7b", 256), ("opt-6.7b", 512),
+                                      ("dots.vlm1.inst", 256)])
+def test_mixed_step_program_compiles_and_fits(chip, monkeypatch, config, C):
+    """The ``decode:step:chunk:c<C>`` programs — the step's ``window``
+    lanes and ``C`` more that are one prompt's chunk — at the benchmark's
+    own sizes, every rung within twice the ladder's smallest
+    (``benchmark/configs/opt-6.7b.json``: 264 and 520 lanes, float32,
+    per-head K and V; ``dots.vlm1.inst.json``: 320 lanes, bf16, the
+    latent pool, 16 of 256 experts), compiled for one described v5e: the
+    decode rows
+    keep their paged Mosaic kernel, one call a layer (and dots its row
+    write and its two grouped matmuls an expert layer), the donated
+    pools are updated in place, and NO copy of a pool is among the
+    temporaries. Two traps this compile found, both held here: a chunk's
+    page writes over all layers at once made XLA re-lay the whole
+    per-head pool out page-major and copy it back (2.7 GB of
+    temporaries: the writes go a layer at a time), and the bfloat16
+    rounding of a float32 pool's keys was moved up through the walk's
+    gather and out of it — the WHOLE pool rounded once a step, 2.7 GB of
+    temporaries and 8 GB of traffic (``kvcache._block_pages``)."""
+    from mxnet_tpu.serving import DecodeServer, ToyDecoderLM
+    from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    assert C in [r for r in srv["seq_ladder"]
+                 if r <= 2 * min(srv["seq_ladder"])]
+    M = -(-(max(srv["seq_ladder"]) + srv["max_new_tokens"]) // S)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    if config == "opt-6.7b":
+        model = ToyDecoderLM(**cfg["model"]["kwargs"])
+        pools = [spec((model.n_layers, pages, S, model.n_heads,
+                       model.head_dim), jnp.float32)] * 2
+        kernels = {"flash_decode": model.n_layers}
+        n_counts, temp = 0, 0.2e9
+    else:
+        model = LatentMoEDecoderLM(**cfg["model"]["kwargs"])
+        pools = [spec((model.n_layers, pages, S, model.row_width),
+                      jnp.bfloat16)]
+        kernels = {"mla_decode": model.n_layers, "latent_write": 1,
+                   "grouped_matmul": 2 * model.n_moe_layers}
+        n_counts, temp = len(model.step_counters[1]), 0.5e9
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    compiled = jax.jit(
+        lambda *a: DecodeServer._decode_fn_chunk(
+            _mixed_holder(model, W, M), *a),
+        donate_argnums=tuple(range(7, 7 + len(pools)))).lower(
+        jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params),
+        spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
+        spec((W,), jnp.int32), spec((C + M + 3,), jnp.int32),
+        *pools).compile()
+    text = compiled.as_text()
+    for kernel, calls in kernels.items():
+        assert len(re.findall(
+            r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\(" % kernel,
+            text, re.M)) == calls, kernel
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == sum(kernels.values())
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    assert mem.temp_size_in_bytes < temp, mem        # no pool copy
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < 14.5e9, mem
+
+
 def test_latent_moe_programs_compile_and_fit(chip, monkeypatch):
     """``benchmark/configs/dots.vlm1.inst.json`` at its published widths
     (7168 wide, 128 heads of 128+64 / 128, ranks 1536 and 512, experts

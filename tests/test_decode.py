@@ -191,9 +191,12 @@ def test_eos_stops_generation_early():
 # ---------------------------------------------------------------------------
 
 def test_mixed_stream_fixed_programs_zero_steady_recompiles():
-    """Under a mixed prefill/decode stream with varied prompt lengths,
-    site_stats("decode") holds exactly 1 + len(ladder) programs, each
-    compiled once, with ZERO steady-state recompiles."""
+    """Under a mixed prompt/decode stream with varied prompt lengths,
+    site_stats("decode") holds exactly three programs — the step and the
+    mixed step that carries a chunk of a prompt, at the ladder's two
+    rungs within a step's budget; no prefill rung is ever built where
+    prompts ride the step — each compiled once, with ZERO steady-state
+    recompiles."""
     compile_watch.enable()
     model, params = _toy()
     srv = DecodeServer(model, params, seq_ladder=[16, 32, 64],
@@ -202,8 +205,8 @@ def test_mixed_stream_fixed_programs_zero_steady_recompiles():
     try:
         srv.warmup()
         warm = compile_watch.site_stats("decode")
-        assert set(warm) == {"decode:step", "decode:prefill:s16",
-                             "decode:prefill:s32", "decode:prefill:s64"}
+        assert set(warm) == {"decode:step", "decode:step:chunk:c16",
+                             "decode:step:chunk:c32"}
         assert all(v["count"] == 1 for v in warm.values())
         rs = np.random.RandomState(2)
         reqs = [srv.submit(rs.randint(1, 32, size=rs.randint(2, 60)),
@@ -228,8 +231,9 @@ def test_streaming_iterator_and_cancel_frees_pages():
     try:
         pool_free0 = srv._pool.stats()["free"]
         req = srv.submit(np.arange(1, 8), max_new_tokens=16)
-        srv._tick()                           # prefill: first token
-        srv._tick()                           # one decode step
+        srv._tick()                           # the prompt's one chunk
+        srv._tick()                           # read: the first token
+        srv._tick()                           # one decode step read
         assert req.pages and srv._pool.stats()["free"] < pool_free0
         seen = []
         it = req.tokens(timeout=1)
@@ -241,13 +245,13 @@ def test_streaming_iterator_and_cancel_frees_pages():
         assert srv._pool.stats()["free"] == pool_free0   # reclaimed
         rest = list(it)                       # stream just ends
         got = [int(t) for t in req.result(timeout=1)]
-        # deterministic: each tick interleaves one prefill AND one
-        # decode step, read back a tick later: 2 ticks emitted the
-        # prefill's token and the first step's, and the second step,
-        # unread when the cancel landed, ran one step too many — its
-        # token is dropped, nothing is pushed after the end
+        # deterministic: each tick dispatches one step and reads the
+        # one before it: 3 ticks emitted the token of the step that
+        # carried the prompt and the first decode step's, and the third
+        # step, unread when the cancel landed, ran one step too many —
+        # its token is dropped, nothing is pushed after the end
         assert seen + rest == got and len(got) == 2
-        assert srv.stats()["decode_steps"] == 2
+        assert srv.stats()["decode_steps"] == 3
         assert srv.stats()["tokens_out"] == 2
         assert srv.stats()["cancelled"] == 1
         # admission covered positions 0..7 with one 8-slot page; the
@@ -496,7 +500,9 @@ def test_decode_telemetry_records_and_diagnose_table(tmp_path):
     last = dec[-1]
     assert last["name"] == "lm"
     assert last["completed"] == 3 and last["tokens_out"] == 12
-    assert last["prefill_steps"] == 3
+    # every prompt rode a decode step: no prefill program ran
+    assert last["prefill_steps"] == 0 == last["prefill_programs"]
+    assert last["chunk_steps"] == 3 and last["chunk_tokens"] == 15
     assert last["kv"]["evicted"] >= 3
     summary = [r for r in recs if r.get("type") == "summary"][-1]
     assert summary["decode"]["lm"]["completed"] == 3
@@ -693,10 +699,11 @@ def test_decode_stats_count_live_pages_of_the_table():
         req = srv.submit(np.arange(1, 7), max_new_tokens=5)   # 6 tokens
         _drain(srv, req)
         st = srv.stats()
-        # four decode steps write positions 6, 7 (one page) and 8, 9 (two)
-        assert st["decode_steps"] == 4
-        assert st["decode_pages_live"] == 1 + 1 + 2 + 2
-        assert st["decode_pages_table"] == 4 * 2 * srv._max_pages
+        # the step that carries the prompt writes positions 0..5 (one
+        # page), four decode steps positions 6, 7 (one) and 8, 9 (two)
+        assert st["decode_steps"] == 5 and st["chunk_steps"] == 1
+        assert st["decode_pages_live"] == 1 + 1 + 1 + 2 + 2
+        assert st["decode_pages_table"] == 5 * 2 * srv._max_pages
     finally:
         srv.stop()
 
@@ -929,7 +936,10 @@ def _behind_prefix_suffix_feed(monkeypatch):
         assert _served(hit) == _reference(model, params, other, 8)
         assert _served(mate) == _reference(model, params, mate_p, 20)
         st = srv.stats()
-        assert st["prefix"]["hits"] == 1 and st["prefill_steps"] == 2
+        # no prompt ran a prefill program: each rode the step in chunks,
+        # the hit's from its first un-cached position on
+        assert st["prefix"]["hits"] == 1 and st["prefill_programs"] == 0
+        assert st["chunk_tokens"] == len(base) + len(mate_p) + 5
         assert st["decode_drains"] == {}
     finally:
         srv.stop()
@@ -1117,14 +1127,25 @@ def _behind_prefix_insert_sees_no_stale_write(monkeypatch):
     pool, S = srv._pool, 8
     writes, published, finishing = [], [], []
     prog, insert, finish = srv._decode_prog, pool.prefix_insert, srv._finish
+    mixed, M = dict(srv._chunk_progs), srv._max_pages
 
     def spying_prog(tree, tokens, positions, pts, *rest):
         rows = np.flatnonzero(pts[:, 0])
         writes.append({int(pts[i, positions[i] // S]) for i in rows})
         return prog(tree, tokens, positions, pts, *rest)
 
+    def spying_mixed(tree, tokens, positions, pts, prev, src, chunk, *rest):
+        rows = np.flatnonzero(pts[:, 0])
+        C = len(chunk) - M - 3
+        start, n = (int(v) for v in chunk[C + M:C + M + 2])
+        writes.append({int(pts[i, positions[i] // S]) for i in rows}
+                      | {int(chunk[C + p // S])
+                         for p in range(start, start + n)})
+        return mixed[C](tree, tokens, positions, pts, prev, src, chunk,
+                        *rest)
+
     def spying_insert(ns, run, pages):
-        # (a prefill's own insert publishes pages it has just written
+        # (a chunk's own insert publishes pages it has just written
         # whole, after any stale write in the device's order)
         if finishing:
             unread = writes[srv.stats()["decode_steps"]:]
@@ -1141,6 +1162,7 @@ def _behind_prefix_insert_sees_no_stale_write(monkeypatch):
             finishing.pop()
 
     srv._decode_prog = spying_prog
+    srv._chunk_progs = dict.fromkeys(mixed, spying_mixed)
     pool.prefix_insert = spying_insert
     srv._finish = spying_finish
     try:
@@ -1213,10 +1235,12 @@ def _behind_step_raises(where, monkeypatch):
         with pytest.raises(RuntimeError, match="planned"):
             req.result(timeout=1)
         got = _served(req)
-        # the prefill's token and the three steps before the failed
-        # one; the step already dispatched behind a failed read-back
-        # is read at once and its output dropped
-        assert got == ref[:4] and srv._unread is None
+        # the token of the step that carried the prompt (the mixed
+        # program: not the one made to fail) and those of the steps
+        # before the failed one; the step already dispatched behind a
+        # failed read-back is read at once and its output dropped
+        assert got == ref[:4 if where == "dispatch" else 3]
+        assert srv._unread is None
         st = srv.stats()
         assert st["errors"] == 1 and st["decode_drains"] == {"error": 1}
         assert srv._pool.stats()["used"] == 0
@@ -1252,3 +1276,457 @@ _BEHIND = {
 @pytest.mark.parametrize("case", sorted(_BEHIND))
 def test_one_step_behind_serves_the_reference_tokens(case, monkeypatch):
     _BEHIND[case](monkeypatch)
+
+
+# ---------------------------------------------------------------------------
+# a prompt rides the decode step in chunks: the mixed step program, the
+# layouts' chunk operation, the scheduler's feed
+# ---------------------------------------------------------------------------
+
+CHUNK = 8
+
+
+def _chunk_srv(model, params, **kw):
+    """Pages of 4 under chunks of 8 (the ladder's smallest rung; its
+    next is past a step's budget of two of them): every chunk covers two
+    pages."""
+    kw.setdefault("seq_ladder", [CHUNK, 32])
+    kw.setdefault("max_new_tokens", 12)
+    kw.setdefault("window", 4)
+    kw.setdefault("page_size", 4)
+    kw.setdefault("pool_pages", 64)
+    return DecodeServer(model, params, start=False, **kw)
+
+
+def _long_prompts(sizes, seed=41):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 32, size=n).astype(np.int32) for n in sizes]
+
+
+def _fed(srv):
+    """Spy on the scheduler's chunks: ``[(request id, tokens), ...]`` in
+    the order the steps that carried them were built."""
+    fed, build = [], srv._build_chunk
+
+    def spying(slot, r):
+        out = build(slot, r)
+        fed.append((r.request_id, out[1]))
+        return out
+
+    srv._build_chunk = spying
+    return fed
+
+
+def _chunks_lengths(monkeypatch):
+    """Prompts of 1, C - 1, C, C + 1 and 3C + 7 tokens, one after
+    another: the reference's tokens, ceil(P / C) mixed steps a prompt,
+    every prompt token fed once and no prefill program."""
+    model, params = _toy(n_layers=2)
+    sizes = (1, CHUNK - 1, CHUNK, CHUNK + 1, 3 * CHUNK + 7)
+    srv = _chunk_srv(model, params)
+    try:
+        assert srv._prefill_progs == {} and srv.stats()["chunk"] == CHUNK
+        for p in _long_prompts(sizes):
+            before = srv.stats()
+            req = srv.submit(p, max_new_tokens=7)
+            _drain(srv, req)
+            assert _served(req) == _reference(model, params, p, 7)
+            st = srv.stats()
+            assert st["chunk_steps"] - before["chunk_steps"] \
+                == -(-len(p) // CHUNK)
+            assert st["chunk_tokens"] - before["chunk_tokens"] == len(p)
+            # the mixed steps are decode steps, counted once: the last
+            # of them emits the first token, six plain steps the rest
+            assert st["decode_steps"] - before["decode_steps"] \
+                == -(-len(p) // CHUNK) + 6
+        st = srv.stats()
+        assert st["prefill_programs"] == 0 == st["prefill_steps"]
+        assert st["launches"]["prefill"] == 0
+        assert st["chunk_tokens"] == sum(sizes)
+        assert srv._pool.stats()["used"] == 0
+    finally:
+        srv.stop()
+
+
+def _chunks_straddle_pages(how, monkeypatch):
+    """Chunks of three pages, of one, and of four or eight (a ladder
+    with two rungs inside a step's budget: the 29 tokens ride the wider
+    program whole, the mate's five the narrower), on the jnp and the
+    interpreted Pallas path of the decode rows. (A chunk starts inside a
+    page only where it is a fully cached prompt's one token: the
+    layouts' own test starts anywhere.)"""
+    model, params = _toy(n_layers=2, use_pallas=how == "pallas")
+    p, = _long_prompts((29,), seed=43)
+    ref = _reference(model, params, p, 9)
+    for page_size, ladder, steps in ((4, [12, 32], 1 + 3),
+                                    (8, [8, 32], 1 + 4),
+                                    (4, [16, 32], 1 + 1)):
+        srv = _chunk_srv(model, params, page_size=page_size,
+                         seq_ladder=ladder)
+        try:
+            mate = srv.submit(p[:5], max_new_tokens=12)
+            req = srv.submit(p, max_new_tokens=9)
+            _drain(srv, req, mate)
+            assert _served(req) == ref, (page_size, ladder)
+            assert _served(mate) == _reference(model, params, p[:5], 12)
+            assert srv.stats()["chunk_steps"] == steps
+        finally:
+            srv.stop()
+
+
+def _chunks_beside_rows_ahead(monkeypatch):
+    """A long prompt arrives while other rows decode one step ahead of
+    the host: its chunks ride their steps (nothing drains), its first
+    token is fed to the next step from the device, and every stream is
+    the reference's."""
+    model, params = _toy()
+    mates = _prompts(2, seed=47)
+    p, = _long_prompts((27,), seed=48)
+    srv = _chunk_srv(model, params)
+    try:
+        reqs = [srv.submit(m, max_new_tokens=12) for m in mates]
+        for _ in range(4):
+            srv._tick()
+        assert srv._unread is not None
+        late = srv.submit(p, max_new_tokens=8)
+        steps0 = srv.stats()["decode_steps"]
+        _drain(srv, late, *reqs)
+        assert _served(late) == _reference(model, params, p, 8)
+        for m, r in zip(mates, reqs):
+            assert _served(r) == _reference(model, params, m, 12)
+        st = srv.stats()
+        assert st["decode_drains"] == {}
+        assert st["decode_steps_ahead"] == st["decode_steps"] - 1
+        # the mates never waited for the prompt: they emitted a token
+        # in each of the four steps that carried it
+        assert st["chunk_steps"] == 2 + 4
+        assert st["decode_steps"] - steps0 <= 4 + 8
+    finally:
+        srv.stop()
+
+
+def _chunks_two_prompts_fifo(monkeypatch):
+    """Two prompts queued together: one request's chunk a step, the
+    head-most first; the second waits as it would for a prefill."""
+    model, params = _toy()
+    a, b = _long_prompts((20, 23), seed=53)
+    srv = _chunk_srv(model, params)
+    fed = _fed(srv)
+    try:
+        ra = srv.submit(a, max_new_tokens=6)
+        rb = srv.submit(b, max_new_tokens=6)
+        _drain(srv, ra, rb)
+        assert fed == [(ra.request_id, 8), (ra.request_id, 8),
+                       (ra.request_id, 4), (rb.request_id, 8),
+                       (rb.request_id, 8), (rb.request_id, 7)]
+        assert _served(ra) == _reference(model, params, a, 6)
+        assert _served(rb) == _reference(model, params, b, 6)
+        assert srv.stats()["chunk_steps"] == 6
+    finally:
+        srv.stop()
+
+
+def _chunks_row_ends(how, monkeypatch):
+    """A request cancelled / past its deadline / preempted / whose
+    server swaps weights while chunks of its prompt are pending: the
+    first three free its pages, drop the feed and push nothing; a swap
+    lets it finish on the weights it started with. The server goes on
+    serving."""
+    model, params = _toy(seed=3)
+    params_b = model.init_params(seed=99)
+    p, q = _long_prompts((30, 12), seed=59)
+    srv = _chunk_srv(model, params)
+    try:
+        srv.warmup()                  # no compile inside the deadline
+        free0 = srv._pool.stats()["free"]
+        req = srv.submit(p, max_new_tokens=6,
+                         deadline_ms=300 if how == "deadline" else None)
+        srv._tick()
+        srv._tick()
+        assert req.pending and req.pending_pos == 2 * CHUNK
+        assert srv._unread.chunk[0] is req
+        if how == "swap_weights":
+            srv.swap_weights(params_b)
+            after = srv.submit(q, max_new_tokens=6)
+            _drain(srv, req, after)
+            assert _served(req) == _reference(model, params, p, 6)
+            assert _served(after) == _reference(model, params_b, q, 6)
+            assert srv._pool.stats()["used"] == 0
+            return
+        if how == "cancel":
+            req.cancel()
+        elif how == "deadline":
+            time.sleep(0.35)
+        else:
+            with srv._cond:           # a co-tenant's give-back ask
+                srv._preempt_asks = 1
+        srv._tick()
+        assert req.done() and req.pending is None and not req.pages
+        assert req.state == ("cancelled" if how == "cancel" else "failed")
+        assert _served(req) == []
+        while srv._has_work():
+            srv._tick()
+        assert srv._pool.stats()["free"] == free0
+        after = srv.submit(q, max_new_tokens=6)
+        _drain(srv, after)
+        assert _served(after) == _reference(model, params, q, 6)
+        st = srv.stats()
+        assert st["tokens_out"] == 6
+        assert st["chunk_tokens"] == 2 * CHUNK + len(q)
+    finally:
+        srv.stop()
+
+
+def _chunks_prefix(how, monkeypatch):
+    """Prefix sharing over chunks. ``hit``: a prompt that shares two
+    full pages feeds its suffix in chunks from ``cached`` on, and the
+    pages a chunk completes are published as soon as it is dispatched (a
+    third prompt hits on them while the second still generates).
+    ``cow``: a fully cached page-aligned prompt re-runs its last token
+    as a chunk of one, whose write splits the shared page. ``degrade``:
+    a planned ``kv_cow`` raise re-feeds the whole row privately, in
+    chunks."""
+    model, params = _toy()
+    base = np.arange(1, 9, dtype=np.int32)             # two full pages
+    tail, = _long_prompts((13,), seed=61)
+    longer = np.concatenate([base, tail]).astype(np.int32)
+    srv = _chunk_srv(model, params, prefix_cache=True)
+    if how == "degrade":
+        fault.set_plan("kv_cow:step=1:raise")
+    try:
+        first = srv.submit(base, max_new_tokens=5)
+        _drain(srv, first)
+        assert _served(first) == _reference(model, params, base, 5)
+        fed = _fed(srv)
+        if how == "hit":
+            hit = srv.submit(longer, max_new_tokens=12)
+            for _ in range(3):
+                srv._tick()
+            # both of its chunks are in: five full pages are published
+            assert hit.prefix_cached == 8 and not hit.pending
+            third = srv.submit(longer, max_new_tokens=4)
+            _drain(srv, hit, third)
+            assert third.prefix_cached == 20
+            assert fed == [(hit.request_id, 8), (hit.request_id, 5),
+                           (third.request_id, 1)]
+            ref = _reference(model, params, longer, 12)
+            assert _served(hit) == ref and _served(third) == ref[:4]
+            assert srv.stats()["prefix"]["hit_tokens"] == 8 + 20
+        else:
+            again = srv.submit(base, max_new_tokens=9)
+            _drain(srv, again)
+            assert _served(again) == _reference(model, params, base, 9)
+            st = srv.stats()
+            assert st["prefix"]["cow_degraded"] == int(how == "degrade")
+            assert st["prefix"]["cow_splits"] == int(how == "cow")
+            assert fed == ([(again.request_id, 8)] if how == "degrade"
+                           else [(again.request_id, 1)])
+        assert srv.stats()["prefill_programs"] == 0
+    finally:
+        srv.stop()
+        fault.set_plan(None)
+
+
+def _chunks_fixed_programs(monkeypatch):
+    """``warmup()`` readies the step and the mixed step; no prompt mix
+    compiles anything after it, and ``stats()`` counts what rode."""
+    compile_watch.enable()
+    model, params = _toy()
+    srv = _chunk_srv(model, params, name="chunks")
+    try:
+        assert srv.warmup() == 2
+        warm = compile_watch.site_stats("decode:chunks")
+        assert sorted(warm) == ["decode:chunks:step",
+                                "decode:chunks:step:chunk:c8"]
+        sizes = (3, 8, 17, 32, 1, 25)
+        reqs = [srv.submit(p, max_new_tokens=5)
+                for p in _long_prompts(sizes, seed=67)]
+        _drain(srv, *reqs)
+        assert compile_watch.site_stats("decode:chunks") == warm
+        st = srv.stats()
+        assert st["completed"] == len(sizes)
+        assert st["chunk_tokens"] == sum(sizes)
+        assert st["chunk_steps"] == sum(-(-n // CHUNK) for n in sizes)
+        assert st["prefill_programs"] == 0 and st["admitted"] == len(sizes)
+    finally:
+        srv.stop()
+
+
+def _chunks_default_and_refusals(monkeypatch):
+    """The mixed program is built at every rung within twice the
+    ladder's smallest, and a chunk takes the smallest that holds what is
+    pending; a model that does not declare ``chunk_lanes``, and an int8
+    pool, keep the prefill."""
+    model, params = _toy()
+    compile_watch.enable()
+    srv = DecodeServer(model, params, seq_ladder=[8, 16, 64], page_size=8,
+                       pool_pages=32, max_new_tokens=4, name="two",
+                       start=False)
+    fed = _fed(srv)
+    try:
+        assert srv.stats()["chunk_sizes"] == [8, 16]
+        assert srv.stats()["chunk"] == 16 and srv.warmup() == 3
+        warm = compile_watch.site_stats("decode:two")
+        assert sorted(warm) == ["decode:two:step",
+                                "decode:two:step:chunk:c16",
+                                "decode:two:step:chunk:c8"]
+        for p in _long_prompts((40, 5, 13), seed=71):
+            req = srv.submit(p, max_new_tokens=4)
+            _drain(srv, req)
+            assert _served(req) == _reference(model, params, p, 4)
+        assert [n for _id, n in fed] == [16, 16, 8, 5, 13]
+        # (40 is 16 + 16 + 8: the last on the narrower program)
+        assert compile_watch.site_stats("decode:two") == {
+            "decode:two:step": warm["decode:two:step"],
+            "decode:two:step:chunk:c8": warm["decode:two:step:chunk:c8"],
+            "decode:two:step:chunk:c16":
+                warm["decode:two:step:chunk:c16"]}
+    finally:
+        srv.stop()
+        compile_watch.disable()
+    srv = DecodeServer(model, params, seq_ladder=[16, 64], page_size=8,
+                       pool_pages=32, start=False)
+    assert srv.stats()["chunk_sizes"] == [16]
+    srv.stop()
+
+    plain = ToyDecoderLM(vocab=32, n_layers=1, n_heads=2, head_dim=8,
+                         max_len=128)
+    plain.chunk_lanes = False
+    monkeypatch.setenv("MXNET_KV_DTYPE", "int8")
+    quant = DecodeServer(model, params, seq_ladder=[16], page_size=8,
+                         pool_pages=32, start=False)
+    monkeypatch.delenv("MXNET_KV_DTYPE")
+    for srv in (quant, DecodeServer(plain, params, seq_ladder=[16],
+                                    page_size=8, pool_pages=32,
+                                    start=False)):
+        try:
+            assert srv.stats()["chunk"] == 0 and not srv._chunk_progs
+            p = np.arange(1, 12, dtype=np.int32)
+            req = srv.submit(p, max_new_tokens=4)
+            _drain(srv, req)
+            st = srv.stats()
+            assert st["prefill_programs"] == 1 == st["prefill_steps"]
+            assert st["chunk_steps"] == 0
+        finally:
+            srv.stop()
+
+
+_CHUNKS = {
+    "lengths": _chunks_lengths,
+    "straddles_pages_jnp": functools.partial(_chunks_straddle_pages, "jnp"),
+    "straddles_pages_pallas": functools.partial(_chunks_straddle_pages,
+                                                "pallas"),
+    "beside_rows_one_step_ahead": _chunks_beside_rows_ahead,
+    "two_prompts_fifo": _chunks_two_prompts_fifo,
+    "cancel_pending": functools.partial(_chunks_row_ends, "cancel"),
+    "deadline_pending": functools.partial(_chunks_row_ends, "deadline"),
+    "preempt_pending": functools.partial(_chunks_row_ends, "preempt"),
+    "swap_weights_pending": functools.partial(_chunks_row_ends,
+                                              "swap_weights"),
+    "prefix_hit_then_chunks": functools.partial(_chunks_prefix, "hit"),
+    "cow_of_a_shared_last_page": functools.partial(_chunks_prefix, "cow"),
+    "degrade_private_in_chunks": functools.partial(_chunks_prefix,
+                                                   "degrade"),
+    "fixed_programs_and_counts": _chunks_fixed_programs,
+    "default_size_and_who_keeps_the_prefill": _chunks_default_and_refusals,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNKS))
+def test_a_prompt_in_chunks_serves_the_reference_tokens(case, monkeypatch):
+    _CHUNKS[case](monkeypatch)
+
+
+def _dense_chunk(q, k_new, v_new, kc, vc, start, scale):
+    """A chunk's attention the plain way: the row's gathered cache with
+    the chunk's rows put in at their positions, one causal softmax in
+    float64. ``q (C, Hq, D)``, ``kc``/``vc (T, Hkv, D)``."""
+    C, Hq, D = q.shape
+    Hkv = kc.shape[1]
+    kc, vc = np.array(kc, np.float64), np.array(vc, np.float64)
+    kc[start:start + C], vc[start:start + C] = k_new, v_new
+    out = np.zeros((C, Hq, D))
+    for j in range(C):
+        for h in range(Hq):
+            g = h // (Hq // Hkv)
+            s = kc[:start + j + 1, g] @ np.asarray(q[j, h], np.float64) \
+                * scale
+            w = np.exp(s - s.max())
+            out[j, h] = (w / w.sum()) @ vc[:start + j + 1, g]
+    return out
+
+
+@pytest.mark.parametrize("kind", ["per_head_f32", "per_head_bf16",
+                                  "packed_bf16"])
+def test_a_layouts_chunk_attends_and_writes_as_the_dense_form(kind):
+    """The layout's chunk operation against ``gather_pages`` and a dense
+    causal softmax: chunk lane ``j`` sees the row's pages before the
+    chunk and the chunk's own rows ``<= j``, the decode rows beside it
+    what the plain step's ``attend`` gives them, and the write lands the
+    live rows — across page boundaries, from inside a page — and leaves
+    every other row of the pool as it was."""
+    import jax.numpy as jnp
+    from mxnet_tpu.serving import kvcache
+    dtype = jnp.float32 if kind == "per_head_f32" else jnp.bfloat16
+    Hq, Hkv, D = (8, 4, 128) if kind == "packed_bf16" else (2, 2, 8)
+    L, P, S, M, B, C = 2, 12, 4, 6, 2, 7
+    layout = kvcache.cache_layout((("k", (Hkv, D)), ("v", (Hkv, D))),
+                                  jnp.dtype(dtype))
+    assert layout.chunks
+    assert type(layout).__name__ == ("_PackedHeadKV" if "packed" in kind
+                                     else "_PerHeadKV")
+    rs = np.random.RandomState(5)
+    pools = [jnp.asarray(rs.randn(*shape), dt) for _n, shape, dt
+             in layout.arrays(L, P, S)]
+    tables = np.zeros((B, M), np.int32)
+    tables[0, :3], tables[1, :2] = [7, 2, 9], [4, 11]
+    positions = np.asarray([9, 5], np.int32)
+    row = np.asarray([3, 10, 1, 8, 6, 0], np.int32)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    scale = 1.0 / np.sqrt(D)
+
+    def gathered(pool, table, layer):
+        got = kvcache.gather_pages(pool[layer:layer + 1],
+                                   jnp.asarray(table)[None])[0, 0]
+        return np.asarray(got.astype(jnp.float32)).reshape(-1, Hkv, D)
+
+    for start, n_live in ((0, 7), (5, 7), (10, 3), (13, 7)):
+        q = rs.randn(B + C, Hq, D).astype(np.float32)
+        k_new = rs.randn(B + C, Hkv, D).astype(np.float32)
+        v_new = rs.randn(B + C, Hkv, D).astype(np.float32)
+        attend = layout.attend_chunk(pools, jnp.asarray(tables),
+                                     jnp.asarray(positions),
+                                     jnp.asarray(row), jnp.int32(start))
+        plain = layout.attend(pools, jnp.asarray(tables),
+                              jnp.asarray(positions))
+        for layer in range(L):
+            got = np.asarray(attend(layer, jnp.asarray(q),
+                                    jnp.asarray(k_new), jnp.asarray(v_new),
+                                    scale=scale))
+            rows = np.asarray(plain(layer, jnp.asarray(q[:B]),
+                                    jnp.asarray(k_new[:B]),
+                                    jnp.asarray(v_new[:B]), scale=scale))
+            np.testing.assert_array_equal(got[:B], rows)
+            # (the pool holds, and so the chunk attends, rounded rows)
+            rounded = [np.asarray(jnp.asarray(a[B:], dtype)
+                                  .astype(jnp.float32))
+                       for a in (k_new, v_new)]
+            want = _dense_chunk(q[B:], *rounded,
+                                gathered(pools[0], row, layer),
+                                gathered(pools[1], row, layer), start,
+                                scale)
+            np.testing.assert_allclose(got[B:], want, atol=tol, rtol=tol)
+        new = [rs.randn(L, C, Hkv, D).astype(np.float32) for _ in pools]
+        after = layout.write_chunk(pools, jnp.asarray(row),
+                                   jnp.int32(start), jnp.int32(n_live),
+                                   [jnp.asarray(a) for a in new])
+        for pool, was, rows in zip(after, pools, new):
+            assert pool.shape == was.shape and pool.dtype == was.dtype
+            want = np.array(was.astype(jnp.float32))
+            for j in range(n_live):
+                page, slot = row[(start + j) // S], (start + j) % S
+                want[:, page, slot] = np.asarray(
+                    jnp.asarray(rows[:, j], dtype).astype(jnp.float32)
+                ).reshape(want[:, page, slot].shape)
+            np.testing.assert_array_equal(
+                np.asarray(pool.astype(jnp.float32)), want)

@@ -659,8 +659,10 @@ def test_int8_server_runs_the_programs_its_twins_ran(use_pallas):
 
 
 class _CountingLM(ToyDecoderLM):
-    """ToyDecoderLM that also declares a step counter."""
+    """ToyDecoderLM that also declares a step counter (its ``decode``
+    is the one-token contract's alone: no ``chunk_lanes``)."""
     step_counters = ("toy", ("rows", "max_batch"))
+    chunk_lanes = False
 
     def decode(self, params, tokens, positions, attend):
         import jax.numpy as jnp

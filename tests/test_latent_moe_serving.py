@@ -168,7 +168,132 @@ def test_absorbed_and_published_attention_forms_agree():
     assert (err > 0.05).mean() <= 0.05, err
 
 
-def test_served_tokens_are_the_references_own_or_near_ties():
+@pytest.mark.parametrize("draw", [4, 5])
+def test_a_chunks_logits_are_the_one_token_steps(draw):
+    """A prompt as ONE chunk of a mixed step against the same prompt a
+    token a step — the path every generated token takes, and a prefix
+    hit's suffix took before chunks: the same absorbed form over the
+    same rounded rows, so the logits of every position agree to a
+    quarter of what either lies off the reference (0.025 deviations:
+    the products run at other shapes), but for a router's near-tie
+    flipped (one position in twenty at the most)."""
+    model, params = _model()
+    rng = np.random.default_rng(draw)
+    S, C, errs = 16, 32, []
+    pool = KVCachePool(model.n_layers, arrays=[c[:2] for c in
+                                               model.cache_arrays],
+                       dtype=model.cache_arrays[0][2], page_size=S,
+                       n_pages=4)
+    table = jnp.asarray([1, 2], jnp.int32)
+    lanes = jnp.arange(C, dtype=jnp.int32)
+
+    @jax.jit
+    def step(pages, tok, pos):
+        attend = pool.layout.attend((pages,), table[None], pos)
+        logits, new, _ = model.decode(params, tok, pos, attend)
+        return logits[0], kvcache.write_token_rows(
+            pages, table[None], pos, new, model.use_pallas)
+
+    @jax.jit
+    def chunk(pages, tokens, n):
+        idle = jnp.zeros((1,), jnp.int32)
+        attend = pool.layout.attend_chunk(
+            (pages,), jnp.zeros((1, 2), jnp.int32), idle, table,
+            jnp.int32(0))
+        return model.decode(
+            params, jnp.concatenate([idle, tokens]),
+            jnp.concatenate([idle, lanes]), attend,
+            live=jnp.concatenate([jnp.ones((1,), bool), lanes < n]))[0][1:]
+
+    for P in (7, 19, 32):
+        prompt = rng.integers(0, model.vocab, size=P).astype(np.int32)
+        pages, one = pool.arrays[0], []
+        for p in range(P):
+            logits, pages = step(pages, jnp.asarray(prompt[p:p + 1]),
+                                 jnp.asarray([p], jnp.int32))
+            one.append(np.asarray(logits))
+        rode = np.asarray(chunk(
+            pool.arrays[0], jnp.asarray(np.pad(prompt, (0, C - P))),
+            jnp.int32(P)))[:P]
+        errs.append(_position_errors(rode, np.stack(one)))
+    err = np.concatenate(errs)
+    assert np.percentile(err, 90) < 0.025, err
+    assert (err > 0.025).mean() <= 0.05, err
+
+
+def router_flips(monkeypatch, hidden_states, model, params, cfg, prompt,
+                 eps=2e-3):
+    """``[(expert layer, position), ...]`` of ``prompt`` at which the
+    router of the path a prompt's chunk runs (the cached, absorbed form
+    over a mixed step's lanes: ``attend_chunk``, ``live``) leaves the
+    choice of the reference's (``hidden_states``, a module's of
+    ``benchmark/reference``: both go through ``latent_moe_lm.moe_layer``)
+    — each one asserted a near-tie OF THE REFERENCE'S: its own scores at
+    that position, moved by ``eps`` (a bfloat16 rounding of a score of
+    0.5..1) toward the experts the served path chose, choose them. A
+    flip of such a tie is no error, and it is the only thing that may
+    leave a row an expert off."""
+    seen = []
+    real = ref.moe_layer
+
+    def spy(x, params, prefix, *args, **kw):
+        out, ids = real(x, params, prefix, *args, **kw)
+        seen.append((prefix, x, np.asarray(ids)))
+        return out, ids
+
+    P, C = len(prompt), 32
+    seq = np.zeros((64,), np.int32)
+    seq[:P] = prompt
+    monkeypatch.setattr(ref, "moe_layer", spy)
+    hidden_states(params, jnp.asarray(seq), cfg, model.held)
+    monkeypatch.undo()
+    # the prompt as ONE chunk from an empty pool, beside one idle row
+    pool = KVCachePool(model.n_layers, arrays=[c[:2] for c in
+                                               model.cache_arrays],
+                       dtype=model.cache_arrays[0][2], page_size=16,
+                       n_pages=4)
+    lanes = np.arange(C)
+    tokens = np.concatenate([[0], seq[:C]]).astype(np.int32)
+
+    @jax.jit
+    def served(params):
+        attend = pool.layout.attend_chunk(
+            pool.arrays, jnp.zeros((1, 2), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jnp.asarray([1, 2], jnp.int32),
+            jnp.int32(0))
+        positions = jnp.concatenate([jnp.zeros((1,), jnp.int32), lanes])
+        X = model._streams(params["embed"][tokens].astype(jnp.float32))
+        attention = model._absorbed(params, positions, attend)
+        routed = []
+        for i in range(model.n_layers):
+            X, _row, _load = model._block(
+                i, X, params, attention, routed,
+                jnp.concatenate([jnp.ones((1,), bool), lanes < P]))
+        return jnp.stack(routed)[:, 1:1 + P]
+
+    flips = []
+    how = dict(n_group=cfg["n_group"], topk_group=cfg["topk_group"],
+               top_k=cfg["num_experts_per_tok"],
+               scaling=float(cfg["routed_scaling_factor"]))
+    for layer, (mine, (prefix, x, theirs)) in enumerate(
+            zip(np.asarray(served(params)), seen)):
+        for p in range(P):
+            got, want = set(mine[p].tolist()), set(theirs[p].tolist())
+            if got == want:
+                continue
+            toward = np.zeros((cfg["n_routed_experts"],), np.float32)
+            toward[sorted(got - want)] = eps
+            toward[sorted(want - got)] = -eps
+            moved, _w = ref.route(
+                x[p:p + 1], params[prefix + "router_w"],
+                params[prefix + "router_b"] + toward, **how)
+            assert set(np.asarray(moved)[0].tolist()) == got, \
+                (prefix, p, sorted(got), sorted(want))
+            flips.append((layer, p))
+    return flips
+
+
+def test_served_tokens_are_the_references_own_or_near_ties(monkeypatch):
     model, params = _model()
     srv = DecodeServer(model, params, seq_ladder=[32], max_new_tokens=24,
                        page_size=16, window=4, pool_pages=32, start=False)
@@ -177,22 +302,40 @@ def test_served_tokens_are_the_references_own_or_near_ties():
                for n in (7, 19, 32)]
     reqs = [srv.submit(p, max_new_tokens=24) for p in prompts]
     _drain(srv, *reqs)
+    flipped = []
     for prompt, req in zip(prompts, reqs):
         out = ref.teacher_forced(params, prompt, req.result(), 64, 24, CFG,
                                  model.held)
         # a served token is the reference's own, or lies a rounding
         # under it: the mean gap is a hundredth of a deviation at most
-        assert out["mean"] < 0.01 and out["exact"] >= 20, out
+        if out["mean"] < 0.01 and out["exact"] >= 20:
+            continue
+        # ... or the row is an expert off, from a position of its
+        # prompt at which the reference's own router holds a tie that
+        # the served path resolved the other way (shown per position):
+        # its tokens are the reference's own as often, none as far off
+        # as a wrong token lies (2-4 deviations)
+        flips = router_flips(monkeypatch, ref.hidden_states, model, params,
+                             CFG, prompt)
+        assert flips and out["exact"] >= 20 and out["worst"] < 0.5, \
+            (flips, out)
+        flipped.append((len(prompt), flips))
+    # (this draw holds ONE such tie: the seven-token prompt's last
+    # position, where two groups of experts lie 0.0011 apart)
+    assert flipped == [(7, [(0, 6)])], flipped
     st = srv.stats()
     assert st["kv"]["arrays"] == {"kv": [model.row_width]}
     assert st["kv"]["token_bytes"] == model.n_layers * model.row_width * 2
     assert st["kv"]["dtype"] == "bfloat16"
     moe_st = st["moe"]
     assert moe_st["steps"] == st["decode_steps"] > 0
-    # 4 rows x top 4 x 2 expert layers; a held share of 16 of 32
-    assert 0 < moe_st["moe_slots"] <= moe_st["steps"] * 4 * 4 * 2
+    # 4 rows, and a chunk's live lanes, x top 4 x 2 expert layers; a
+    # held share of 16 of 32
+    assert 0 < moe_st["moe_slots"] \
+        <= (moe_st["steps"] * 4 + st["chunk_tokens"]) * 4 * 2
     assert 0 < moe_st["experts_touched"] <= moe_st["steps"] * 16 * 2
-    assert 1 <= moe_st["max_load"] <= 4
+    # (a step's 4 rows, and the 32 lanes of a chunk it may carry)
+    assert 1 <= moe_st["max_load"] <= 4 + st["chunk"]
     assert set(moe_st["last"]) == {"moe_slots", "experts_touched",
                                    "max_load"}
     srv.stop()
@@ -498,9 +641,11 @@ def test_toy_decoder_same_program_set_same_tokens(use_pallas):
     _drain(srv, *reqs)
     assert [r.result().tolist() for r in reqs] == GOLDEN
     sites = compile_watch.site_stats("decode:" + name)
-    assert sorted(sites) == ["decode:%s:prefill:s16" % name,
-                             "decode:%s:prefill:s32" % name,
-                             "decode:%s:step" % name]
+    # (the tokens the prefill programs served; the prompts now ride the
+    # step in chunks, and no prefill rung is built)
+    assert sorted(sites) == ["decode:%s:step" % name,
+                             "decode:%s:step:chunk:c16" % name,
+                             "decode:%s:step:chunk:c32" % name]
     assert all(s["count"] == 1 for s in sites.values())
     assert "moe" not in srv.stats()
     srv.stop()
@@ -517,8 +662,8 @@ def test_latent_model_fixed_program_set():
                        max_new_tokens=8) for n in (3, 16, 20, 31)]
     _drain(srv, *reqs)
     sites = compile_watch.site_stats("decode:lat")
-    assert sorted(sites) == ["decode:lat:prefill:s16",
-                             "decode:lat:prefill:s32", "decode:lat:step"]
+    assert sorted(sites) == ["decode:lat:step", "decode:lat:step:chunk:c16",
+                             "decode:lat:step:chunk:c32"]
     assert all(s["count"] == 1 for s in sites.values())
     srv.stop()
 
@@ -658,3 +803,238 @@ def test_grouped_matmul_kernel_matches_ragged_dot(case):
     assert np.abs(np.asarray(b) - want).max() / scale < 2e-3
     if case == "none_held":
         assert not np.asarray(a).any() and not np.asarray(b).any()
+
+
+# ---------------------------------------------------------------------------
+# a prompt rides the decode step in chunks, over the latent pool
+# ---------------------------------------------------------------------------
+
+LCHUNK = 8
+
+
+def _chunk_srv(**kw):
+    """Chunks of 8, the ladder's smallest rung (its next is past a
+    step's budget of two of them), over pages of 8."""
+    model, params = _model()
+    kw.setdefault("seq_ladder", [LCHUNK, 32])
+    kw.setdefault("max_new_tokens", 12)
+    kw.setdefault("window", 3)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("pool_pages", 32)
+    return model, params, DecodeServer(model, params, start=False, **kw)
+
+
+def _lat_prompts(sizes, seed):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, CFG["vocab_size"], size=n).astype(np.int32)
+            for n in sizes]
+
+
+def _near_reference(model, params, prompt, served):
+    """Served tokens are the reference's own or lie a rounding under
+    them (random bf16 weights: a token for token oracle does not
+    exist; one flipped router near-tie costs a third of a deviation)."""
+    n = len(served)
+    out = ref.teacher_forced(params, prompt, np.asarray(served), 64, n, CFG,
+                             model.held)
+    assert out["mean"] < 0.03 and out["exact"] >= n - 2, out
+
+
+def _lat_lengths():
+    """1, C - 1, C, C + 1 and 3C + 7 tokens: the reference's tokens to a
+    near-tie, the SAME tokens whatever the chunk's size (8 a step, or
+    the prompt whole), ceil(P / C) mixed steps and no prefill program."""
+    sizes = (1, LCHUNK - 1, LCHUNK, LCHUNK + 1, 3 * LCHUNK + 7)
+    prompts = _lat_prompts(sizes, seed=5)
+    streams = {}
+    for chunk in (LCHUNK, 32):
+        model, params, srv = _chunk_srv(seq_ladder=sorted({chunk, 32}))
+        try:
+            reqs = []
+            for p in prompts:
+                reqs.append(srv.submit(p, max_new_tokens=10))
+                _drain(srv, reqs[-1])
+            streams[chunk] = [r.result().tolist() for r in reqs]
+            st = srv.stats()
+            assert st["prefill_programs"] == 0 == st["prefill_steps"]
+            assert st["chunk_tokens"] == sum(sizes)
+            assert st["chunk_steps"] == sum(-(-n // chunk) for n in sizes)
+            assert st["moe"]["steps"] == st["decode_steps"]
+        finally:
+            srv.stop()
+    assert streams[LCHUNK] == streams[32]
+    for p, served in zip(prompts, streams[LCHUNK]):
+        _near_reference(model, params, p, served)
+
+
+def _lat_straddles_and_rides_ahead():
+    """Chunks of 12 over pages of 4 (three pages a chunk; five chunks of
+    a 50-token prompt) arriving while two rows decode one step ahead:
+    nothing drains, both kinds of stream are the reference's, the mates
+    emit in every step that carries a chunk."""
+    model, params, srv = _chunk_srv(seq_ladder=[12, 64], page_size=4,
+                                    pool_pages=96)
+    mates = _lat_prompts((6, 9), seed=6)
+    long_p, = _lat_prompts((50,), seed=7)
+    try:
+        reqs = [srv.submit(m, max_new_tokens=12) for m in mates]
+        for _ in range(4):
+            srv._tick()
+        late = srv.submit(long_p, max_new_tokens=8)
+        _drain(srv, late, *reqs)
+        for p, r in zip(mates + [long_p], reqs + [late]):
+            _near_reference(model, params, p, r.result().tolist())
+        st = srv.stats()
+        assert st["decode_drains"] == {}
+        assert st["chunk_steps"] == 2 + 5 and st["chunk_tokens"] == 65
+        assert st["decode_steps_ahead"] == st["decode_steps"] - 1
+    finally:
+        srv.stop()
+
+
+def _lat_fifo_and_cancel():
+    """Two prompts queued: one request's chunk a step, head-most first.
+    The second is cancelled with chunks pending: its pages come back,
+    nothing is pushed, the first finishes as if alone."""
+    model, params, srv = _chunk_srv()
+    a, b = _lat_prompts((20, 27), seed=8)
+    fed, build = [], srv._build_chunk
+
+    def spying(slot, r):
+        out = build(slot, r)
+        fed.append((r.request_id, out[1]))
+        return out
+
+    srv._build_chunk = spying
+    try:
+        free0 = srv._pool.stats()["free"]
+        ra = srv.submit(a, max_new_tokens=6)
+        rb = srv.submit(b, max_new_tokens=6)
+        for _ in range(5):
+            srv._tick()
+        assert fed == [(ra.request_id, 8), (ra.request_id, 8),
+                       (ra.request_id, 4), (rb.request_id, 8),
+                       (rb.request_id, 8)]
+        assert rb.pending and rb.pending_pos == 16
+        rb.cancel()
+        _drain(srv, ra, rb)
+        assert rb.state == "cancelled" and rb.generated == []
+        assert rb.pending is None and not rb.pages
+        _near_reference(model, params, a, ra.result().tolist())
+        while srv._has_work():
+            srv._tick()
+        assert srv._pool.stats()["free"] == free0
+        assert srv.stats()["chunk_tokens"] == 20 + 16
+    finally:
+        srv.stop()
+
+
+def _lat_prefix_hit_and_cow():
+    """Prefix sharing over the latent pool: a hit feeds its suffix in
+    chunks from ``cached`` on — the same tokens as the unshared run —
+    and a fully cached page-aligned prompt re-runs its last token as a
+    chunk of one, whose write splits the shared page."""
+    model, params, srv = _chunk_srv(prefix_cache=True)
+    base, = _lat_prompts((16,), seed=9)                # two full pages
+    tail, = _lat_prompts((13,), seed=10)
+    longer = np.concatenate([base, tail]).astype(np.int32)
+    _, _, alone = _chunk_srv()
+    try:
+        want = []
+        for p, n in ((base, 6), (longer, 9)):
+            r = alone.submit(p, max_new_tokens=n)
+            _drain(alone, r)
+            want.append(r.result().tolist())
+        first = srv.submit(base, max_new_tokens=6)
+        _drain(srv, first)
+        again = srv.submit(base, max_new_tokens=6)
+        hit = srv.submit(longer, max_new_tokens=9)
+        _drain(srv, again, hit)
+        assert again.prefix_cached == 16 == hit.prefix_cached
+        assert first.result().tolist() == want[0]
+        assert again.result().tolist() == want[0]
+        assert hit.result().tolist() == want[1]
+        st = srv.stats()
+        assert st["prefix"]["cow_splits"] == 1
+        assert st["chunk_tokens"] == 16 + 1 + 13
+        assert st["prefill_programs"] == 0
+    finally:
+        srv.stop()
+        alone.stop()
+
+
+def _lat_fixed_programs():
+    compile_watch.enable()
+    model, params, srv = _chunk_srv(name="latchunk")
+    try:
+        assert srv.warmup() == 2
+        warm = compile_watch.site_stats("decode:latchunk")
+        assert sorted(warm) == ["decode:latchunk:step",
+                                "decode:latchunk:step:chunk:c8"]
+        reqs = [srv.submit(p, max_new_tokens=4)
+                for p in _lat_prompts((3, 8, 17, 32, 1), seed=11)]
+        _drain(srv, *reqs)
+        assert compile_watch.site_stats("decode:latchunk") == warm
+        assert all(v["count"] == 1 for v in warm.values())
+    finally:
+        srv.stop()
+
+
+def _lat_dead_lanes_choose_no_expert():
+    """A last chunk of ONE token: the mixed step's experts are the plain
+    step's and that token's, nothing for the chunk's seven dead lanes —
+    ``moe_slots`` adds up exactly, ``experts_touched`` is the union's,
+    and the dead lanes' rows are not written."""
+    model, params, srv = _chunk_srv()
+    W, C, M = srv._window, LCHUNK, srv._max_pages
+    mixed_prog = srv._chunk_progs[C]
+    tok = 77
+
+    def run(prog, tokens, chunk=()):
+        pos = np.zeros((W,), np.int32)
+        out = prog(srv._params.tree, np.asarray(tokens, np.int32), pos,
+                   np.zeros((W, M), np.int32), srv._no_prev,
+                   np.full((W,), -1, np.int32), *chunk, *srv._pool.arrays)
+        return dict(zip(model.step_counters[1],
+                        (int(c) for c in np.asarray(
+                            srv._adopt_pool(out)[0])[W:])))
+
+    try:
+        plain = run(srv._decode_prog, [0] * W)
+        # the token as a decode row at position 0: what it alone chooses
+        solo = run(srv._decode_prog, [tok] + [0] * (W - 1))
+        chunk = np.zeros((C + M + 3,), np.int32)
+        chunk[0], chunk[C], chunk[C + M:] = tok, 5, (0, 1, -1)
+        before = np.asarray(srv._pool.arrays[0].astype(jnp.float32))
+        mixed = run(mixed_prog, [0] * W, (chunk,))
+        after = np.asarray(srv._pool.arrays[0].astype(jnp.float32))
+        own = solo["moe_slots"] - plain["moe_slots"] * (W - 1) // W
+        assert plain["moe_slots"] % W == 0 and own > 0
+        assert mixed["moe_slots"] == plain["moe_slots"] + own
+        assert plain["experts_touched"] <= mixed["experts_touched"] \
+            <= plain["experts_touched"] + own
+        # live lanes alone: with the dead ones 7 x 8 slots more
+        everyone = dict(chunk=chunk.copy())
+        everyone["chunk"][C + M + 1] = C
+        full = run(mixed_prog, [0] * W, (everyone["chunk"],))
+        assert full["moe_slots"] > mixed["moe_slots"]
+        changed = (after != before).any(axis=(0, 3))
+        assert changed[5].tolist() == [True] + [False] * 7
+        assert not changed[1:5].any() and not changed[6:].any()
+    finally:
+        srv.stop()
+
+
+_LAT_CHUNKS = {
+    "lengths": _lat_lengths,
+    "straddles_pages_beside_rows_ahead": _lat_straddles_and_rides_ahead,
+    "two_prompts_fifo_then_cancel": _lat_fifo_and_cancel,
+    "prefix_hit_and_cow": _lat_prefix_hit_and_cow,
+    "fixed_programs": _lat_fixed_programs,
+    "dead_lanes_choose_no_expert": _lat_dead_lanes_choose_no_expert,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LAT_CHUNKS))
+def test_a_prompt_in_chunks_over_the_latent_pool(case):
+    _LAT_CHUNKS[case]()

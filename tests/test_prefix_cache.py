@@ -108,8 +108,11 @@ def test_shared_prefix_identical_to_unshared(use_pallas):
         assert st["prefix"]["hit_tokens"] == 24
         assert st["prefix"]["cow_splits"] == 1
         assert st["prefix"]["bytes_saved"] > 0
-        # a prefix hit never runs a prefill program
-        assert st["prefill_steps"] == 1
+        # no prompt runs a prefill program: the miss rode the step whole,
+        # a hit from its first un-cached position on (the fully cached
+        # one re-ran its last token)
+        assert st["prefill_programs"] == 0
+        assert st["chunk_tokens"] == len(BASE) + 1 + (len(LONGER) - 12)
     finally:
         srv.stop()
 
@@ -176,8 +179,8 @@ def test_kv_share_fault_is_a_deterministic_miss():
         assert st["prefix"]["hits"] == 1
         assert r3.prefix_cached == 12
         assert fault.stats()["injected"].get("kv_share") == 1
-        # the forced-miss request ran a REAL prefill
-        assert st["prefill_steps"] == 2
+        # the forced-miss request fed its WHOLE prompt again
+        assert st["chunk_tokens"] == 2 * len(BASE) + 1
     finally:
         srv.stop()
         fault.set_plan(None)
@@ -218,8 +221,8 @@ def test_fixed_program_set_with_cow_zero_steady_recompiles():
     try:
         srv.warmup()
         warm = compile_watch.site_stats("decode")
-        assert set(warm) == {"decode:step", "decode:prefill:s16",
-                             "decode:prefill:s32", "decode:cow"}
+        assert set(warm) == {"decode:step", "decode:step:chunk:c16",
+                             "decode:step:chunk:c32", "decode:cow"}
         assert all(v["count"] == 1 for v in warm.values())
         # page-aligned prompts so full-page hits force live COWs
         base = np.arange(1, 17)
@@ -498,3 +501,41 @@ def test_prefix_cache_telemetry_diagnose_and_metrics(tmp_path):
     assert "----------Prefix cache----------" in out.stdout
     assert "served from shared pages" in out.stdout
     assert "cow split" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# a hit's suffix arrives in chunks
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("chunk,steps", [(4, 5), (8, 3), (12, 2), (16, 1)])
+def test_a_hits_suffix_arrives_in_chunks_and_serves_the_same_tokens(
+        chunk, steps):
+    """After a prefix hit the un-cached suffix rides the decode step in
+    chunks (the ladder's smallest rung a step: one, two, three pages;
+    with 16 the ladder's 32 is within a step's budget too, and holds the
+    suffix whole), from the first un-cached position on, and the stream
+    is the unshared run's token for token, whatever the chunk's size;
+    the pages each chunk completes are shared by the next same-prefix
+    prompt."""
+    model, params = _toy(n_layers=2)
+    prompt = np.concatenate([BASE, np.arange(1, 18)]).astype(np.int32)
+    ref = _srv(model, params, prefix=False, seq_ladder=[32], name="ref")
+    try:
+        want, _ = _gen(ref, prompt)
+    finally:
+        ref.stop()
+    srv = _srv(model, params, seq_ladder=[chunk, 32])
+    try:
+        _gen(srv, BASE, n=3)                   # publishes 3 full pages
+        before = srv.stats()
+        got, req = _gen(srv, prompt)
+        assert got == want and req.prefix_cached == 12
+        st = srv.stats()
+        assert st["chunk_tokens"] - before["chunk_tokens"] == 17
+        assert st["chunk_steps"] - before["chunk_steps"] == steps
+        assert st["prefill_programs"] == 0
+        # every full page of the prompt is in the index now: 29 = 7 x 4 + 1
+        again, r2 = _gen(srv, prompt)
+        assert again == want and r2.prefix_cached == 28
+    finally:
+        srv.stop()

@@ -18,9 +18,20 @@ from mxnet_tpu.io.pipeline import AsyncInputPipeline
 from mxnet_tpu.serving import DecodeServer, ToyDecoderLM
 
 
-def _toy():
-    model = ToyDecoderLM(vocab=32, n_layers=1, n_heads=2, head_dim=8,
-                         max_len=128)
+class _PrefillLM(ToyDecoderLM):
+    """``ToyDecoderLM`` as a model that does not declare
+    ``chunk_lanes``: its server keeps the whole-prompt prefill program,
+    and with it the ``decode.prefill`` spans and counters that the
+    block, speculative and state forms still launch (a model that
+    declares them rides the step in chunks:
+    ``test_a_chunk_rides_the_dispatch_span``)."""
+
+    chunk_lanes = False
+
+
+def _toy(model=_PrefillLM):
+    model = model(vocab=32, n_layers=1, n_heads=2, head_dim=8,
+                  max_len=128)
     return model, model.init_params(seed=3)
 
 
@@ -610,3 +621,39 @@ def test_throttling_is_read_from_the_process_cgroup(monkeypatch, layout,
     got = decode._host_stats()
     assert got.pop("involuntary_switches") >= 0
     assert got == want
+
+
+def test_a_chunk_rides_the_dispatch_span():
+    """A server whose prompts ride the step in chunks launches no
+    prefill: ``mx:decode.dispatch`` of a mixed step says how many tokens
+    of whose prompt it carried (``chunk``, ``chunk_of``), every launch is
+    still numbered and every read-back names one, and ``stats()`` counts
+    the same steps and tokens."""
+    model, params = _toy(ToyDecoderLM)
+    srv = DecodeServer(model, params, seq_ladder=(8, 32),
+                       max_new_tokens=6, window=2, page_size=4,
+                       pool_pages=32, name="chunkspans", start=False)
+    prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (19, 5)]
+    try:
+        spans = _spied(srv, lambda s: [s.submit(p, max_new_tokens=4)
+                                       for p in prompts])
+        ids = ["d000001", "d000002"]
+        assert not [sp for sp in spans if sp.name.startswith(
+            "decode.prefill")]
+        steps = [sp for sp in spans if sp.name == "decode.dispatch"]
+        mixed = [(sp.args["chunk_of"], sp.args["chunk"]) for sp in steps
+                 if "chunk" in sp.args]
+        assert mixed == [(ids[0], 8), (ids[0], 8), (ids[0], 3), (ids[1], 5)]
+        assert all(sp.args["program"] == "step" for sp in steps)
+        launches, waits = _launches_and_waits(spans)
+        assert [sp.args["seq"] for sp in launches] \
+            == list(range(1, len(launches) + 1))
+        assert sorted(sp.args["waits"] for sp in waits) \
+            == [sp.args["seq"] for sp in launches]
+        st = srv.stats()
+        assert st["chunk_steps"] == 4 and st["chunk_tokens"] == 24
+        assert st["decode_steps"] == len(steps)
+        assert st["launches"] == {"step": len(steps), "prefill": 0,
+                                  "cow": 0}
+    finally:
+        srv.stop()
