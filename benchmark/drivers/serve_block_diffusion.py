@@ -123,8 +123,8 @@ def run(ctx):
                      and not isinstance(stats1[k], bool)},
         moe_delta=delta("moe", ("steps", "moe_slots", "experts_touched")),
         block_delta=delta("block", (
-            "denoise_passes", "commit_passes", "tokens_unmasked",
-            "blocks_committed")),
+            "denoise_passes", "commit_passes", "fused_commits",
+            "tokens_unmasked", "blocks_committed")),
         unnamed_gap="scheduler")
     judged = [r for r in streams if not r.cut]
     failed = [r for r in judged if r.error is not None

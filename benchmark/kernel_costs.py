@@ -27,19 +27,23 @@ def shapes(event_name):
     return m and {key: int(value) for key, value in m.groupdict().items()}
 
 
-def causal_attention_flops(bh, q, k, d):
+def causal_attention_flops(bh, q, k, d, v=None):
     """Floating-point operations of causal attention over ``bh`` heads:
-    the two products, scores and weighted values, over the key
-    positions a query may see. Query ``i`` of ``q`` sees the first
-    ``k - q + i + 1`` of ``k`` keys; a multiply-accumulate is two
-    operations."""
+    the two products, scores (queries and keys ``d`` wide) and weighted
+    values (``v`` wide; ``d`` where not given), over the key positions a
+    query may see. Query ``i`` of ``q`` sees the first ``k - q + i + 1``
+    of ``k`` keys; a multiply-accumulate is two operations. Where a
+    kernel is handed heads zero-padded to a wider tile, ``d`` and ``v``
+    are the widths the model has, not the call's: a product with a zero
+    is no operation the algorithm needs."""
     visible = q * (k - q) + q * (q + 1) // 2
-    return 2 * 2 * bh * visible * d
+    return 2 * bh * visible * (d + (d if v is None else v))
 
 
-def flash_decode_bytes(n_layers, d_model, live_tokens, kv_bytes=4):
-    """Bytes the decode-attention kernels of one step have to read at the
-    least: the K and V of the tokens that are live in the batch, in every
-    layer (as ``flops.decode_step_bytes`` counts them). The queries and
-    the outputs, one position a row, are left out."""
-    return 2 * n_layers * live_tokens * d_model * kv_bytes
+def paged_decode_bytes(n_layers, d_model, pages, page_size, kv_bytes=4):
+    """Bytes the paged decode-attention kernels of one step read: the
+    whole K and V pages that hold the step's live keys, in every layer
+    (``pages`` of ``page_size`` tokens: a page is read whole, its last
+    rows live or not). The queries and the outputs, one position a row,
+    are left out."""
+    return 2 * n_layers * pages * page_size * d_model * kv_bytes

@@ -133,12 +133,8 @@ def ops_in_modules(ctx, op_pattern, module_key):
     modules = _modules(ctx, module_key)
     if not modules:
         return 0.0, 0
-    inside = trace_reduce.union((s, e) for _, s, e in modules)
-    ops = trace_reduce.union((s, e) for _, s, e in ctx.trace.events(
-        ctx.trace.devices[0], trace_reduce.OPS_LINE, op_pattern))
-    covered = trace_reduce.total(ops) - trace_reduce.total(
-        trace_reduce.subtract(ops, inside))
-    return covered / 1e9, len(modules)
+    return ctx.trace.op_s(op_pattern, inside=[
+        (s, e) for _, s, e in modules]), len(modules)
 
 
 def kernel_s_per_step(ctx, kernel_key, module_key="step_module"):

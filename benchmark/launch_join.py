@@ -78,7 +78,7 @@ SILENCE_NS = 50e6       # no host event for so long: the process stood
 STOP_OVERLAP_NS = 5e6   # less idle than this inside a silence is no stop's
 NEIGHBOURHOOD_NS = 0.25e9
 DRIFT_NS = 0.3e6        # the lead's drift inside one profile
-ENQUEUE = program_spans.LAUNCH
+ENQUEUE = "DoEnqueueProgram"   # libtpu hands one program to the chip
 INF = float("inf")
 
 
@@ -381,3 +381,13 @@ def of(ctx):
         joined.idle().items(), key=lambda kv: -kv[1])]
     ctx.launch_join = joined
     return joined
+
+
+def idle_gaps(ctx, n=10):
+    """``[[what, seconds], ...]``, the ``n`` largest of ``Join.idle``:
+    the slice's idle time as the result line's ``breakdown.idle_gaps``
+    wants it, under the program's own spans. None where ``of`` gives
+    nothing (a training cell, a program that numbers no launch) or the
+    device was never idle between two programs."""
+    joined = of(ctx)
+    return (ctx.raw["idle_by_span"][:n] or None) if joined else None

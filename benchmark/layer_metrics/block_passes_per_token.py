@@ -2,9 +2,12 @@
 window: the server's ``stats()["block"]`` ``denoise_passes`` +
 ``commit_passes`` (a pass of one row each) over ``tokens_out``, after
 the window less before it. A block of 4 that unmasks one position a
-pass and then commits reads 1.25; a rule that unmasks more a pass reads
-less, down to 0.5. A program that counts no passes (every other model:
-one pass a token) leaves the metric out."""
+pass reads 1.0 since PR 36 (four passes for four tokens: the commit
+rides with the next block's first denoising pass and is no pass of its
+own; 1.0014 on the cell, ledger PR 40, with the pass a row spends before
+it is seen to have ended; 1.25 until then); a rule that unmasks more a
+pass reads less, down to 0.25. A program that counts no passes (every
+other model: one pass a token) leaves the metric out."""
 NAME, UNIT, LAYER = "block_passes_per_token", "ratio", "Decode scheduler"
 
 

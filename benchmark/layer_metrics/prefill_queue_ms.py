@@ -2,7 +2,7 @@
 program's start, moved onto the host's clock, less the end of its
 ``mx:decode.prefill.launch`` (``benchmark/launch_join.py``). With the
 launch, the program's device time and the read of its token it adds up
-to ``prefill_stall_ms``."""
+to the span ``mx:decode.prefill`` (PERF.md section 6, PR 35)."""
 import statistics
 
 from benchmark import launch_join

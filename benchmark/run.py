@@ -91,7 +91,7 @@ def main(argv=None):
         os.environ["JAX_PLATFORMS"] = "cpu"
     elif args.seconds is None:
         args.seconds = spec["run_seconds"]
-    from benchmark import harness, peaks, program_spans
+    from benchmark import harness, launch_join, peaks
     cell = harness.by_name(spec["workloads"], args.workload, "workload")
     config_entry = harness.by_name(spec["configs"], cell["config"],
                                    "configuration")
@@ -142,12 +142,12 @@ def main(argv=None):
               "failed": int(verdict["failed"]),
               "metrics": metrics, "device": device}
     if ctx.trace is not None and ctx.trace.devices:
-        # idle time under the program's own spans, the device's lead
-        # taken off, where the trace lets that be read; the benchmark's
-        # own spans where it does not
+        # idle time under the program's own spans, each program joined
+        # to its launch and moved by its lead, where the program numbers
+        # its launches; the benchmark's own spans where it does not
         result["breakdown"] = {
             "device_ops": ctx.trace.top_ops(10),
-            "idle_gaps": program_spans.idle_gaps(ctx, 10)
+            "idle_gaps": launch_join.idle_gaps(ctx, 10)
             or ctx.trace.idle_gaps(
                 10, unnamed=ctx.raw.get("unnamed_gap", "unattributed"))}
     if args.rehearse:

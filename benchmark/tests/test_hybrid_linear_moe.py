@@ -51,9 +51,9 @@ def _config():
 
 def test_the_configuration_keeps_every_published_number():
     spec = _json("BENCHMARK.json")
-    entry = spec["configs"][-1]
+    entry = next(c for c in spec["configs"] if c["name"] == CONFIG)
     cfg = _config()
-    assert entry["name"] == CONFIG and entry["source"] == cfg["source"]
+    assert entry["source"] == cfg["source"]
     assert entry["reduced"] == list(cfg["reduced_from"]) == list(REDUCED)
     assert cfg["reduced_from"] == REDUCED
     with open(CATALOG) as f:
@@ -102,9 +102,9 @@ def test_the_configuration_keeps_every_published_number():
 
 def test_the_cell_its_traffic_and_where_its_metrics_are_listed():
     spec = _json("BENCHMARK.json")
-    cell = spec["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, CONFIG, "hybrid-decode-batch-w64", 1)
+    cell = next(w for w in spec["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (CONFIG, "hybrid-decode-batch-w64", 1)
     mix = _json("benchmark", "traffic", cell["traffic"] + ".json")
     assert mix["driver"] == "serve_hybrid_linear_moe"
     assert mix["arrivals"] == {"kind": "closed", "clients": 128}
@@ -122,19 +122,23 @@ def test_the_cell_its_traffic_and_where_its_metrics_are_listed():
         listed = CELL in m.get("workloads", [CELL])
         assert listed == (m["name"] in ("serve_tok_per_s", "itl_p99_ms",
                                         "setup_s")), m["name"]
-    mine = [m for m in spec["per_layer"] if CELL in m["workloads"]]
-    assert [m["name"] for m in mine[-5:]] == [
-        "kda_step_ms_per_step", "kda_step_roofline_share", "kda_prefill_ms",
-        "recurrent_state_share", "hybrid_step_roofline_share"]
-    assert all(m["workloads"] == [CELL] for m in mine[-5:])
-    for m in spec["per_layer"][:-5]:
-        both = {"dots-decode-batch", "xing-specdecode-batch"} \
-            <= set(m["workloads"])
-        assert (CELL in m["workloads"]) == both, m["name"]
-        if both:
-            assert m["workloads"][-1] == CELL
-    assert not any(CELL in m["workloads"] for m in spec["per_layer"]
-                   if m["name"].startswith("gap_"))
+    layer = {m["name"]: m for m in spec["per_layer"]}
+    for name in ("kda_step_ms_per_step", "kda_step_roofline_share",
+                 "kda_prefill_ms", "recurrent_state_share",
+                 "hybrid_step_roofline_share"):
+        assert layer[name]["workloads"] == [CELL], name
+    # what the latent and speculative cells both read, this cell reads
+    # too (the state form keeps its prefill program: the prefill's
+    # readers stay, the chunk step's are not listed)
+    for name, m in layer.items():
+        if {"dots-decode-batch", "xing-specdecode-batch"} \
+                <= set(m["workloads"]):
+            assert CELL in m["workloads"], name
+    for name in ("prefill_device_ms", "prefill_queue_ms", "admit_idle_ms",
+                 "prefill_attn_ms"):
+        assert CELL in layer[name]["workloads"], name
+    for name in ("chunk_step_share", "chunk_step_device_ms"):
+        assert CELL not in layer[name]["workloads"], name
 
 
 # --- the costs -------------------------------------------------------------

@@ -18,9 +18,12 @@ LEAD = 1.2 * MS
 STEP, PREFILL = 10 * MS, 12 * MS
 STEP_NAME = "jit__decode_fn(1269473466103926323)"
 PREFILL_NAME = "jit__prefill_fn(7711405620211203961)"
+CHUNK_NAME = "jit__decode_fn_chunk(4417262033651180257)"
+KERNEL = ("%%mx_flash_decode.bh256.q1.k2048.d128.float32.paged.%d = "
+          "f32[8,32,128]{2,1,0} custom-call(f32[8,32,128]{2,1,0} %%q)")
 NAMES = {"step_module": "_decode_fn", "prefill_module": "_prefill_fn"}
-SERVING = ["opt-decode-batch", "opt-longprompt-steady", "dots-decode-batch",
-           "sdar-blockdiff-batch", "xing-specdecode-batch"]
+# the readers of a prefill PROGRAM: listed where a form keeps one
+OF_A_PREFILL = ("prefill_device_ms", "prefill_queue_ms", "admit_idle_ms")
 READERS = {
     "prefill_device_ms": ("program_span", "Model step", "itl_p99_ms"),
     "prefill_queue_ms": ("program_span", "Decode scheduler", "itl_p99_ms"),
@@ -54,6 +57,7 @@ class Slice:
     def __init__(self, numbered=True):
         self.numbered = numbered
         self.spans, self.modules, self.enqueues = [], [], []
+        self.ops = []
         self.stops = []
         self.t, self.free, self.seq, self.unread = 100 * MS, 0.0, 0, None
 
@@ -85,7 +89,10 @@ class Slice:
             done = max(done, self.stops[-1][1] + 0.05 * MS)
         self.span(name, (done - self.t) / MS, **stats)
 
-    def tick(self, admit=False, stop_ms=0.0, rung=256):
+    def tick(self, admit=False, stop_ms=0.0, rung=256, **said):
+        """``said``: what else the step's ``decode.dispatch`` carries
+        (``pages_live``, ``chunk``); a step that says its pages runs 4
+        ``mx_flash_decode`` calls of 0.5 ms each."""
         t0 = self.t
         self.span("decode.reap", 0.1)
         if admit:
@@ -102,8 +109,13 @@ class Slice:
         self.span("decode.pages", 0.1)
         self.span("decode.build", 0.3)
         prev, self.unread = self.unread, self.launch(
-            "decode.dispatch", "step", STEP_NAME, STEP, 1.0,
-            ahead=int(self.unread is not None))
+            "decode.dispatch", "step",
+            CHUNK_NAME if "chunk" in said else STEP_NAME, STEP, 1.0,
+            ahead=int(self.unread is not None), **said)
+        if "pages_live" in said:
+            start = self.modules[-1][1]
+            self.ops += [(KERNEL % k, start + (1 + 2 * k) * MS,
+                          start + (1.5 + 2 * k) * MS) for k in range(4)]
         if prev is not None:
             self.wait("decode.readback", prev, stop_ms)
             self.span("decode.emit", 0.4)
@@ -122,8 +134,11 @@ class Slice:
                 client.append(("bench:client", t, t + 0.2 * MS))
             t += 5 * MS
         planes = {
-            "/device:TPU:0": {trace_reduce.MODULES_LINE: [
-                ev for ev in self.modules if lo <= ev[1] + LEAD < hi]},
+            "/device:TPU:0": {
+                trace_reduce.MODULES_LINE: [
+                    ev for ev in self.modules if lo <= ev[1] + LEAD < hi],
+                trace_reduce.OPS_LINE: [
+                    ev for ev in self.ops if lo <= ev[1] + LEAD < hi]},
             "/host:CPU": {
                 "python3": [ev[:3] for ev in spans] + client
                 + [(trace_reduce.SLICE_SPAN, lo, hi)],
@@ -132,7 +147,9 @@ class Slice:
         return types.SimpleNamespace(
             trace=trace_reduce.Trace(planes),
             program_spans=program_spans.Spans([spans]), raw=raw,
-            config={"trace_names": NAMES})
+            config={"trace_names": NAMES, "bytes_per_value": {"kv": 4},
+                    "server": {"kwargs": {"page_size": 128}}},
+            peak={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
 
 
 def _idle_ns(joined):
@@ -455,6 +472,76 @@ def test_the_two_counters_of_stats():
     assert _reader("host_slack_share").compute(ctx) == pytest.approx(70.0)
 
 
+def test_the_paged_kernel_is_held_to_the_pages_of_the_steps_it_ran_in():
+    """Eight steps in the slice: five plain ones whose dispatch says 10,
+    20, 30, 40 and 50 live pages, two mixed ones (their count holds the
+    chunk's pages too: left out, kernel time and pages alike) and one
+    whose launch lies in front of the profile (no span: left out). Each
+    runs 4 kernel calls of 0.5 ms. At 4 layers of 4096 float32 a live
+    page is 128 x 2 x 4 x 4096 x 4 B = 16.8 MB: 150 pages at 819 GB/s
+    are 3.07 ms of the 10 ms the kernel took in those five steps."""
+    sl = Slice()
+    sl.tick(pages_live=99)
+    for pages in (10, 20):
+        sl.tick(pages_live=pages)
+    sl.tick(pages_live=70, chunk=512, chunk_of="d000007")
+    for pages in (30, 40):
+        sl.tick(pages_live=pages)
+    sl.tick(pages_live=80, chunk=256, chunk_of="d000007")
+    sl.tick(pages_live=50)
+    sl.tick()
+    starts = [s + LEAD for _, s, _ in sl.modules]
+    ctx = sl.ctx(window=(starts[0] - 0.05 * MS, sl.t + MS),
+                 model={"n_layers": 4, "d_model": 4096})
+    reader = _reader("flash_decode_roofline_share")
+    page = 128 * 2 * 4 * 4096 * 4
+    want = 100 * (150 * page / 819e9) / 10e-3
+    assert reader.compute(ctx) == pytest.approx(want)
+    assert 30 < want < 31
+    assert ctx.raw["flash_decode"] == {
+        "steps": 5, "pages_live": 150,
+        "bytes_per_step": pytest.approx(30 * page),
+        "kernel_s_per_step": pytest.approx(2e-3)}
+    # a kernel that streamed its pages AT the roofline reads 100%
+    even = Slice()
+    for _ in range(6):
+        even.tick(pages_live=30)
+    even.ops = [(n, s, s + 30 * page / 819e9 / 4 * 1e9)
+                for n, s, _ in even.ops]
+    ctx = even.ctx(model={"n_layers": 4, "d_model": 4096})
+    assert reader.compute(ctx) == pytest.approx(100.0)
+    # steps that say no pages (an earlier commit), a program that runs
+    # no such kernel, an untraced run: nothing
+    ctx = _steady().ctx(model={"n_layers": 4, "d_model": 4096})
+    assert reader.compute(ctx) is None and "flash_decode" not in ctx.raw
+    quiet = Slice()
+    for _ in range(4):
+        quiet.tick(pages_live=30)
+    quiet.ops = []
+    assert reader.compute(quiet.ctx(
+        model={"n_layers": 4, "d_model": 4096})) is None
+    ctx = _with_a_prefill(numbered=False).ctx()
+    assert reader.compute(ctx) is None
+
+
+def test_the_breakdowns_idle_gaps_name_the_programs_spans():
+    """``breakdown.idle_gaps`` of a serving cell is the join's
+    attribution, largest first, in seconds: the program's own spans,
+    not the ``bench:`` spans' "scheduler". Nothing where the program
+    numbers no launch (``run.py`` then falls back to the ``bench:``
+    spans) or the device never stood between two programs."""
+    ctx = _with_a_prefill().ctx()
+    gaps = launch_join.idle_gaps(ctx, 3)
+    assert [name for name, _ in gaps] == [
+        "decode.dispatch", "decode.build", "decode.prefill.read"]
+    assert gaps[0][1] == pytest.approx(1.0e-3)
+    assert sum(s for _, s in launch_join.idle_gaps(ctx, 10)) \
+        == pytest.approx(1.7e-3)
+    assert launch_join.idle_gaps(
+        _with_a_prefill(numbered=False).ctx(), 10) is None
+    assert launch_join.idle_gaps(_steady().ctx(), 10) is None
+
+
 # --- BENCHMARK.json ---------------------------------------------------------
 
 @pytest.mark.parametrize("name", sorted(READERS))
@@ -467,6 +554,11 @@ def test_the_entry_says_what_its_reader_says(name):
     assert (mod.NAME, mod.UNIT, mod.LAYER) \
         == (name, entry["unit"], entry["layer"]) and layer == mod.LAYER
     assert (entry["source"], entry["moves"]) == (source, moves)
+    # every serving cell that reports the metric it moves; a prefill's
+    # readers where prompts do not ride the step in chunks (PR 40)
     reporting = {m["name"]: m.get("workloads") for m in spec["end_to_end"]}
-    assert entry["workloads"] == [c for c in SERVING
-                                  if c in reporting[moves]]
+    chunked = next(m for m in spec["per_layer"]
+                   if m["name"] == "chunk_step_share")["workloads"]
+    serving = [c for c in reporting["itl_p99_ms"] if c in reporting[moves]]
+    assert entry["workloads"] == [c for c in serving
+                                  if name not in OF_A_PREFILL or c not in chunked]

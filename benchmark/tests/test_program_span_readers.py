@@ -38,12 +38,6 @@ def _ctx(which, **raw):
         peak={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
 
 
-def _idle(which):
-    ctx = _ctx(which)
-    idle = program_spans.idle(ctx)
-    return idle, ctx.raw["device_lead_ms"]
-
-
 def _reader(name):
     return importlib.import_module("benchmark.layer_metrics." + name)
 
@@ -72,94 +66,6 @@ def test_self_time_is_what_no_child_covers():
     assert admit.children[0].self_ns == admit.children[0].ns
 
 
-GAP_READERS = ("gap_admit_ms", "gap_build_ms", "gap_emit_ms",
-               "gap_unattributed_share")
-
-
-def _host_line(trace, name):
-    """The one line of the host plane that holds the events ``name``."""
-    line, = (evs for evs in trace.planes["/host:CPU"].values()
-             if any(n == name for n, _, _ in evs))
-    return line
-
-
-def test_the_devices_lead_is_measured_for_every_program():
-    """The slice's device events are stamped 1.5 ms early; the launch
-    and the notice of every program bound its lead to 1.4 .. 1.6."""
-    trace, _ = _load("serve")
-    leads = program_spans.device_leads(trace)
-    assert [start for start, _, _ in leads] == [8.5 * MS, 58.5 * MS,
-                                                106.5 * MS, 130.5 * MS]
-    assert all(abs(lead - 1.5 * MS) < 1 and abs(slack - 0.1 * MS) < 1
-               for _, lead, slack in leads)
-    # device-only readings stay on the device's clock
-    assert abs(trace.module_durations_s("_decode_fn")[0] - 0.045) < 1e-9
-
-
-def test_a_stray_launch_moves_one_programs_lead_and_no_other():
-    """A second launch just before a step's true start (a transfer, a
-    program of another server) is taken for the step's own: that step's
-    lead is read 0.05 ms high, and the median of the slice is as it
-    was."""
-    ctx = _ctx("serve")
-    _host_line(ctx.trace, program_spans.LAUNCH).append(
-        ("DoEnqueueProgram", 60.0 * MS, 60.05 * MS))
-    leads = program_spans.device_leads(ctx.trace)
-    assert [round(lead / MS, 6) for _, lead, _ in leads] \
-        == [1.5, 1.55, 1.5, 1.5]
-    program_spans.idle(ctx)
-    assert [round(ms, 6) for ms in ctx.raw["device_lead_ms"]] \
-        == [1.5, 1.5, 1.55, 0.1]
-
-
-def test_a_program_without_a_pair_takes_its_neighbours_lead():
-    trace, _ = _load("serve")
-    del _host_line(trace, program_spans.NOTICE)[1]
-    leads = program_spans.device_leads(trace)
-    assert [start for start, _, _ in leads] == [8.5 * MS, 106.5 * MS,
-                                                130.5 * MS]
-    ctx = _ctx("serve")
-    ctx.trace = trace
-    want, _ = _idle("serve")
-    assert program_spans.idle(ctx) == want
-
-
-@pytest.mark.parametrize("name", GAP_READERS)
-@pytest.mark.parametrize("why", ["no_launch_events", "device_late"])
-def test_no_split_of_the_gap_where_the_lead_cannot_be_measured(name, why):
-    """Without the lead the whole gap would fall under the span the host
-    was in when the device's early stamps say the step ended: no number
-    is better than that one."""
-    ctx = _ctx("serve", **_serve_raw())
-    if why == "no_launch_events":   # a runtime that names them otherwise
-        for line in ctx.trace.planes["/host:CPU"].values():
-            line[:] = [ev for ev in line if ev[0] not in (
-                program_spans.LAUNCH, program_spans.NOTICE)]
-    else:                   # stamped 3.2 ms later: past its own notice,
-        dev = ctx.trace.planes["/device:TPU:0"]   # 45 ms before the next
-        for line in dev.values():
-            line[:] = [(n, s + 3.2 * MS, e + 3.2 * MS) for n, s, e in line]
-    assert program_spans.device_leads(ctx.trace) is None
-    assert _reader(name).compute(ctx) is None
-    assert ctx.raw["device_lead_ms"] is None
-    # the device's own reading needs no lead
-    assert abs(_reader("decode_gap_ms").compute(ctx) - 3.5) < 1e-9
-
-
-def test_idle_time_goes_to_the_innermost_span_piece_by_piece():
-    idle, lead = _idle("serve")
-    assert [round(ms, 6) for ms in lead] == [1.5, 1.5, 1.5, 0.1]
-    want = {"decode.wait": 29.3, None: 0.2, "decode.tick": 0.2,
-            "decode.reap": 0.4, "decode.admit": 0.9, "decode.prefill": 1.5,
-            "decode.pages": 0.4, "decode.build": 1.1,
-            "decode.dispatch": 2.6, "decode.readback": 1.9,
-            "decode.emit": 3.3, "decode.record": 1.2}
-    assert set(idle) == set(want)
-    for name, ms in want.items():
-        assert abs(idle[name] - ms * MS) < 1, (name, idle[name])
-    assert abs(sum(idle.values()) - 43 * MS) < 1
-
-
 def test_a_gap_under_no_span_is_nobodys():
     spans = program_spans.Spans([[("mx:a", 10.0, 20.0, {})]])
     assert spans.charged(0.0, 5.0) is None
@@ -178,8 +84,6 @@ def test_of_two_lines_the_span_that_began_last_is_charged():
         assert abs(idle[name] - ms * MS) < 1, (name, idle[name])
     assert {sp.line for sp in spans.named("pipeline.h2d")} == {1}
     assert all(sp.parent is None for sp in spans.named("pipeline.h2d"))
-    # its host plane has no launch or notice: no lead, so no idle split
-    assert _idle("train") == (None, None)
 
 
 # --- kernel_costs ----------------------------------------------------------
@@ -202,25 +106,19 @@ def test_a_kernels_name_gives_its_shapes_and_costs():
     assert kernel_costs.causal_attention_flops(1, 4, 4, 8) == 4 * 10 * 8
     # a query block at the end of a longer key sequence sees all before it
     assert kernel_costs.causal_attention_flops(1, 2, 6, 1) == 4 * (5 + 6)
-    assert kernel_costs.flash_decode_bytes(4, 4096, 1000, 4) \
-        == 2 * 4 * 1000 * 4096 * 4
+    # heads handed to the kernel zero-padded: scores 192 wide, values 128
+    assert kernel_costs.causal_attention_flops(1, 4, 4, 192, 128) \
+        == 2 * 10 * (192 + 128)
+    # 10 live pages of 128 tokens, K and V, 4 layers of 4096 float32
+    assert kernel_costs.paged_decode_bytes(4, 4096, 10, 128, 4) \
+        == 2 * 4 * 1280 * 4096 * 4
 
 
 # --- the readers -----------------------------------------------------------
 
 SERVE = {
     "decode_gap_ms": 3.5,           # 5 after a step, 2 after the prefill
-    "gap_emit_ms": 6.4 / 3,
-    "gap_admit_ms": 2.8 / 3,
-    "gap_build_ms": 4.1 / 3,
-    "gap_unattributed_share": 100 * 0.4 / 43,
-    "prefill_stall_ms": 23.5,
     "queue_wait_mean_ms": 1e3 * 0.9 / 30,
-    "prefill_mean_ms": 1e3 * 0.6 / 25,
-    # 4 layers, 1000 live tokens a step: 131 MB at 819 GB/s = 0.16 ms,
-    # against 2 ms a step inside the kernel
-    "flash_decode_roofline_share":
-        100 * (2 * 4 * 1000 * 4096 * 4 / 819e9) / 2e-3,
     "flash_fwd_roofline_share":
         100 * (4 * 4 * (256 * 257 // 2) * 128 / 197e12) / 2e-3,
 }
@@ -242,10 +140,7 @@ def _serve_raw():
 
 @pytest.mark.parametrize("name", sorted(SERVE))
 def test_serving_reader_on_the_hand_written_slice(name):
-    # tokens 1 and 2 attended to 999 and 1000 positions: 1999 / 2 steps
     want = SERVE[name]
-    if name == "flash_decode_roofline_share":
-        want *= 999.5 / 1000
     got = _reader(name).compute(_ctx("serve", **_serve_raw()))
     assert abs(got - want) <= 1e-9 * max(1.0, abs(want)), (got, want)
 
@@ -282,30 +177,6 @@ def test_reader_leaves_its_metric_out_where_the_program_has_no_spans(name):
     assert _reader(name).compute(untraced) is None
 
 
-def test_the_breakdowns_idle_gaps_name_the_programs_spans():
-    """``breakdown.idle_gaps`` of a serving cell: the idle time of the
-    recorded slice by the program's innermost span, the lead taken off,
-    largest first; the benchmark's own spans said "scheduler" 40 ms and
-    "submit" 3. Nothing where the lead cannot be measured (the recorded
-    training slice) or the configuration names no step whose loop the
-    leads are paired for: ``run.py`` then falls back to the ``bench:``
-    spans, by what the trace and the configuration hold and by no flag."""
-    ctx = _ctx("serve")
-    gaps = program_spans.idle_gaps(ctx, 10)
-    assert [name for name, _ in gaps[:2]] == ["decode.wait", "decode.emit"]
-    assert gaps == sorted(gaps, key=lambda g: -g[1]) and len(gaps) == 10
-    by = dict(program_spans.idle_gaps(ctx, 99))
-    assert "scheduler" not in by and "unattributed" in by
-    assert abs(sum(by.values()) - 0.043) < 1e-9
-    emit = by["decode.readback"] + by["decode.emit"] + by["decode.record"]
-    assert abs(emit - 6.4e-3) < 1e-9            # gap_emit_ms's three spans
-    assert program_spans.idle_gaps(_ctx("train"), 10) is None
-    unnamed = _ctx("serve")
-    unnamed.config = {"trace_names": {}}
-    assert program_spans.idle_gaps(unnamed, 10) is None
-    assert ctx.trace.idle_gaps(10, unnamed="scheduler")[0][0] == "scheduler"
-
-
 def test_rehearsal_prints_the_programs_own_spans_as_null():
     """On the CPU the profile has no device, so what needs device 0 is
     left out; what the program's spans and counters alone give is there,
@@ -313,8 +184,8 @@ def test_rehearsal_prints_the_programs_own_spans_as_null():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    want = {"opt-longprompt-steady": {"prefill_stall_ms", "prefill_mean_ms",
-                                      "queue_wait_mean_ms"},
+    want = {"opt-longprompt-steady": {"queue_wait_mean_ms", "chunk_step_share",
+                                      "decode_ahead_share"},
             "resnet50-train-b256": {"h2d_ms_per_step", "step_dispatch_ms",
                                     "pipeline_wait_share"}}
     for cell, names in want.items():

@@ -70,43 +70,79 @@ def test_image_ring_repeats():
     assert abs(values.mean()) < 0.02
 
 
-def test_an_open_loop_offers_every_seed_the_same_work_in_another_order():
-    """``schedule``: every seed offers the same arrivals' gaps and the
-    same pairs of lengths, shuffled, and repeats itself; a closed loop's
-    clients draw their own. Seeds past 2**31 are seeds like any other."""
+def test_an_open_loop_offers_every_seed_the_same_schedule_of_other_tokens():
+    """``schedule``: every seed offers the same requests at the same
+    times in the same order, and differs in the token ids alone; a
+    closed loop's clients draw their own. Seeds past 2**31 are seeds
+    like any other."""
     spec = _load("longprompt-steady.json")
     horizon, rate = 32.0, spec["arrivals"]["rate_per_s"]
     runs = []
     for seed in (3, 3, 4, 2 ** 31 + 11):
         got = list(traffic.schedule(seed, spec, 50272, horizon))
+        # as many requests as the rate says, whatever the seed
         assert len(got) == round(rate * horizon)
         times = np.array([at for at, _, _ in got])
         assert 0.0 < times[0] and times[-1] < horizon
         assert (np.diff(times) > 0).all()
         assert all(p.dtype == np.int32 and 0 <= p.min() and p.max() < 50272
                    for _, p, _ in got)
-        runs.append((np.diff(times, prepend=0.0),
-                     [(len(p), k) for _, p, k in got], got[0][1]))
-    (gaps_a, pairs_a, first_a), again, (gaps_b, pairs_b, first_b), \
-        (gaps_c, pairs_c, _) = runs
-    assert np.array_equal(gaps_a, again[0]) and pairs_a == again[1] \
-        and np.array_equal(first_a, again[2])
-    assert pairs_a != pairs_b and sorted(pairs_a) == sorted(pairs_b) \
-        == sorted(pairs_c)
-    assert not np.array_equal(first_a[:32], first_b[:32])
-    assert not np.allclose(gaps_a, gaps_b)
-    assert np.allclose(np.sort(gaps_a), np.sort(gaps_b))
-    assert np.allclose(np.sort(gaps_a), np.sort(gaps_c))
+        runs.append((times, [(len(p), k) for _, p, k in got],
+                     [p for _, p, _ in got]))
+    (times_a, pairs_a, ids_a), again, (times_b, pairs_b, ids_b), \
+        (times_c, pairs_c, ids_c) = runs
+    # a seed repeats itself to the token
+    assert np.array_equal(times_a, again[0]) and pairs_a == again[1]
+    assert all(np.array_equal(p, q) for p, q in zip(ids_a, again[2]))
+    # two seeds: the same times, lengths and order ...
+    assert np.array_equal(times_a, times_b) \
+        and np.array_equal(times_a, times_c)
+    assert pairs_a == pairs_b == pairs_c
+    # ... and other token ids, request for request
+    assert not any(np.array_equal(p, q) for p, q in zip(ids_a, ids_b))
+    assert not any(np.array_equal(p, q) for p, q in zip(ids_a, ids_c))
     # the gaps are a Poisson process's: their deviation is their mean
-    assert 0.8 < gaps_a.std() / gaps_a.mean() < 1.25
-    assert abs(gaps_a.mean() - 1.0 / rate) < 0.01 / rate
+    gaps = np.diff(times_a, prepend=0.0)
+    assert 0.8 < gaps.std() / gaps.mean() < 1.25
+    assert abs(gaps.mean() - 1.0 / rate) < 0.01 / rate
     # the lengths keep to the file's distributions
     lens = np.array([n for n, _ in pairs_a])
     assert lens.min() >= spec["prompt_len"]["min"]
     assert lens.max() == spec["prompt_len"]["max"]
     assert abs(np.median(lens) - spec["prompt_len"]["median"]) < 90
+    assert len(set(pairs_a)) > 100           # a distribution, not a point
     # a closed loop's clients: fresh draws from the seed
     closed = _load("decode-batch.json")
     a = [len(p) for p, _ in _take(3, 0, closed, 200)]
     b = [len(p) for p, _ in _take(4, 0, closed, 200)]
     assert sorted(a) != sorted(b)
+
+
+@pytest.mark.parametrize("horizon", [8.0, 32.0, 62.0])
+def test_the_number_of_requests_is_rate_times_horizon(horizon):
+    spec = _load("longprompt-steady.json")
+    rate = spec["arrivals"]["rate_per_s"]
+    got = list(traffic.schedule(5, spec, 50272, horizon))
+    assert len(got) == int(round(rate * horizon))
+    assert got[-1][0] < horizon
+    # a longer horizon offers the shorter one's lengths first
+    short = list(traffic.schedule(5, spec, 50272, 4.0))
+    assert [(len(p), k) for _, p, k in short] \
+        == [(len(p), k) for _, p, k in got[:len(short)]]
+
+
+def test_the_tiny_preset_differs_by_seed_in_nothing_but_ids():
+    """What a rehearsal offers (the file's ``tiny`` block laid over it)
+    keeps the rule: two seeds, one schedule, other tokens."""
+    spec = _load("longprompt-steady.json")
+    tiny = {**spec, **spec["tiny"]}
+    a = list(traffic.schedule(11, tiny, 64, 2.5))
+    b = list(traffic.schedule(12, tiny, 64, 2.5))
+    assert len(a) == len(b) == round(
+        tiny["arrivals"]["rate_per_s"] * 2.5) > 20
+    assert [at for at, _, _ in a] == [at for at, _, _ in b]
+    assert [(len(p), k) for _, p, k in a] == [(len(p), k) for _, p, k in b]
+    assert any(not np.array_equal(p, q)
+               for (_, p, _), (_, q, _) in zip(a, b))
+    assert all(tiny["prompt_len"]["min"] <= len(p)
+               <= tiny["prompt_len"]["max"] for _, p, _ in a)

@@ -166,11 +166,16 @@ class Trace:
         return [(e - s) / 1e9 for _, s, e in self.events(
             device, MODULES_LINE, pattern)]
 
-    def op_s(self, pattern, device=None):
-        """Device seconds inside operations whose name matches."""
+    def op_s(self, pattern, device=None, inside=None):
+        """Device seconds inside operations whose name matches; with
+        ``inside`` (intervals, as of the programs they ran in) the part
+        of them that lies there."""
         device = device or self.devices[0]
-        return total(union((s, e) for _, s, e in self.events(
-            device, OPS_LINE, pattern))) / 1e9
+        ops = union((s, e) for _, s, e in self.events(
+            device, OPS_LINE, pattern))
+        if inside is not None:
+            return (total(ops) - total(subtract(ops, union(inside)))) / 1e9
+        return total(ops) / 1e9
 
     def exposed_collective_s(self, device=None):
         """Seconds in which a collective operation was under way on the

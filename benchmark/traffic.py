@@ -9,13 +9,15 @@ host ring. A distribution is ``{"dist": "uniform" | "lognormal", ...}``;
 every draw is an integer clipped to ``min``..``max``.
 
 A closed loop's clients draw their requests from ``--seed`` without
-end (``requests``). An open loop offers ONE fixed draw of work
+end (``requests``). An open loop offers ONE fixed schedule of work
 (``schedule``): the gaps of a Poisson process at the file's rate and as
 many pairs of lengths are drawn once from ``OPEN_LOOP_DRAW``, whatever
-the seed, and ``--seed`` only orders them (and draws the token ids).
-With a fresh draw for every seed, two seeds differ in how many requests a window
-holds and how many of them on the longest rung, which a tail reads as a
-difference between runs (PERF.md section 6, PR 26).
+the seed, and are offered in the order drawn; ``--seed`` draws the token
+ids (and the weights) only. With a fresh draw for every seed, two seeds
+differ in how many requests a window holds and how many of them on the
+longest rung (PR 26); with one draw in an order of the seed's, in which
+long prompts' deep chunks meet how many decoding rows (PR 42): a tail
+reads either as a difference between runs (PERF.md section 2).
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ def draw(gen, spec):
     return int(min(max(int(round(float(value))), spec["min"]), spec["max"]))
 
 
-OPEN_LOOP_DRAW = 26     # the one draw of an open loop's work (PR 26)
+OPEN_LOOP_DRAW = 26     # the one draw of an open loop's work (PR 26, PR 42)
 
 
 def requests(seed, stream, traffic, vocab):
@@ -58,19 +60,17 @@ def schedule(seed, traffic, vocab, horizon_s):
     order: ``(time, prompt int32 array, max_new_tokens)``, ``rate *
     horizon`` of them. The gaps between arrivals are those of a Poisson
     process of the rate ``arrivals.rate_per_s``, scaled so that the last
-    arrival falls inside the horizon; gaps and pairs of lengths are the
-    fixed draw, ``seed`` orders both and draws the token ids: every seed
-    offers as many requests, as long and as unevenly spaced."""
+    arrival falls inside the horizon; gaps, pairs of lengths and their
+    order are the fixed draw, ``seed`` draws the token ids: every seed
+    offers the same requests at the same times, of other tokens."""
     n = int(round(float(traffic["arrivals"]["rate_per_s"]) * horizon_s))
     gaps = rng(OPEN_LOOP_DRAW, 2).exponential(1.0, size=n)
     gaps *= horizon_s * n / (n + 1.0) / gaps.sum()
     lengths = rng(OPEN_LOOP_DRAW, 1, 0)
-    pairs = [(draw(lengths, traffic["prompt_len"]),
-              draw(lengths, traffic["output_len"])) for _ in range(n)]
-    times = np.cumsum(rng(seed, 2).permutation(gaps))
     gen = rng(seed, 1, 0)
-    for at, i in zip(times, gen.permutation(n)):
-        size, asked = pairs[i]
+    for at in np.cumsum(gaps):
+        size = draw(lengths, traffic["prompt_len"])
+        asked = draw(lengths, traffic["output_len"])
         yield float(at), gen.integers(0, vocab, size=size,
                                       dtype=np.int32), asked
 
