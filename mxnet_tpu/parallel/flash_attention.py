@@ -57,7 +57,7 @@ import math
 
 import jax
 
-__all__ = ["flash_attention", "flash_decode"]
+__all__ = ["flash_attention", "flash_decode", "ring_decode"]
 
 _NEG = -1e30
 
@@ -1574,6 +1574,354 @@ def _pallas_block_write(pages, page_idx, slot, new, layer, interpret):
         interpret=interpret)
 
 
+# ---------------------------------------------------------------------------
+# grouped-query forward, causal, optionally BANDED (a sliding window):
+# serving's prefill for fewer key/value than query heads
+# ---------------------------------------------------------------------------
+
+def _jnp_grouped(q, k, v, scale, window=None):
+    """The reference of :func:`_pallas_grouped_forward`: causal attention
+    of ``q (B, T, Hq, D)`` over ``k``/``v (B, T, Hkv, D)``, query head
+    ``i`` reading key/value head ``i // (Hq // Hkv)``; with ``window``
+    key ``t`` is visible to query ``s`` iff ``s - window < t <= s``.
+    Float32 scores and softmax, the weights rounded to ``v``'s dtype as
+    the kernel rounds them; returns ``(B, T, Hq, D)`` float32."""
+    import jax.numpy as jnp
+    B, T, Hq, D = q.shape
+    Hkv, f32 = k.shape[2], jnp.float32
+    qg = (q * scale).astype(k.dtype).astype(f32).reshape(
+        B, T, Hkv, Hq // Hkv, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(f32))
+    at = jax.lax.iota(jnp.int32, T)
+    seen = at[None, :] <= at[:, None]
+    if window is not None:
+        seen = jnp.logical_and(seen, at[None, :] > at[:, None] - window)
+    s = jnp.where(seen[None, None, None], s, _NEG)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype).astype(f32),
+                     v.astype(f32))
+    return out.reshape(B, T, Hq, D) \
+        / jnp.transpose(l[..., 0], (0, 3, 1, 2)).reshape(B, T, Hq, 1)
+
+
+def _band(i, block_q, block_k, window):
+    """The first and the last key block a query block ``i`` may see."""
+    import jax.numpy as jnp
+    first = 0 if window is None else \
+        jnp.maximum(i * block_q - window + 1, 0) // block_k
+    return first, ((i + 1) * block_q - 1) // block_k
+
+
+def _grouped_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                        *, block_q, block_k, n_steps, kv_len, window):
+    """Grid = (batch * query heads, q blocks, band steps), the band
+    innermost: step ``j`` of query block ``i`` folds key block ``first(i)
+    + j`` — under a window only the blocks the band touches are ever
+    named (two of 512 for a window of 512 over blocks of 512), the
+    blocks behind it are skipped, not masked. Operands as they come (the
+    cache's dtype, the queries scaled and rounded to it), float32 scores,
+    running softmax and accumulation."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(1), pl.program_id(2)
+    first, last = _band(qi, block_q, block_k, window)
+    kb = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(kb <= last)
+    def _step():
+        s = _dot(q_ref[...], k_ref[...], _NT)            # (bq, bk)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        seen = jnp.logical_and(k_pos < kv_len, q_pos >= k_pos)
+        if window is not None:
+            seen = jnp.logical_and(seen, k_pos > q_pos - window)
+        s = jnp.where(seen, s, _NEG)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha \
+            + _dot(p.astype(v_ref.dtype), v_ref[...])
+
+    @pl.when(j == n_steps - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
+            .astype(o_ref.dtype)
+
+
+def _pallas_grouped_forward(q, k, v, *, n_q_heads, window, block_q, block_k,
+                            kv_len, interpret):
+    """``q (B * Hq, T, D)`` scaled, in the keys' dtype; ``k``/``v (B *
+    Hkv, T, D)``, ``T`` padded to the blocks. Returns ``(B * Hq, T, D)``
+    float32. Named ``mx_grouped_fwd``, the key/value heads behind the
+    shapes and, under a window, ``.w<window>`` behind those."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, T, D = q.shape
+    Hq = n_q_heads
+    Hkv = k.shape[0] // (BH // Hq)
+    G = Hq // Hkv
+    n_qb, n_kb = T // block_q, T // block_k
+    # the widest band of any query block: the grid's innermost extent
+    n_steps = n_kb if window is None else max(
+        ((i + 1) * block_q - 1) // block_k
+        - max(i * block_q - window + 1, 0) // block_k + 1
+        for i in range(n_qb))
+
+    def kv_map(b, i, j):
+        first, last = _band(i, block_q, block_k, window)
+        # a step past the band names the band's last block again: no copy
+        return ((b // Hq) * Hkv + (b % Hq) // G,
+                jnp.minimum(first + j, last), 0)
+
+    q_spec = pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((None, block_k, D), kv_map)
+    name = "mx_grouped_fwd.bh%d.q%d.k%d.d%d.%s.kv%d" % (
+        BH, T, T, D, jnp.dtype(k.dtype).name, BH // Hq * Hkv)
+    if window is not None:
+        name += ".w%d" % window
+    return pl.pallas_call(
+        functools.partial(_grouped_fwd_kernel, block_q=block_q,
+                          block_k=block_k, n_steps=n_steps, kv_len=kv_len,
+                          window=window),
+        grid=(BH, n_qb, n_steps),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((BH, T, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
+        interpret=interpret,
+        name=name,
+    )(q, k, v)
+
+
+def _grouped_forward(q, k, v, scale, window, block_q, block_k, interpret):
+    """:func:`flash_attention`'s kernel path for grouped-query heads or a
+    window: forward only (serving's prefill; no backward kernels)."""
+    B, T, Hq, D = q.shape
+    t_pad, _, bq, bk = _blocks(T, T, block_q, block_k)
+    qf = _flatten(_pad_seq((q * scale).astype(k.dtype), t_pad))
+    out = _pallas_grouped_forward(
+        qf, _flatten(_pad_seq(k, t_pad)), _flatten(_pad_seq(v, t_pad)),
+        n_q_heads=Hq, window=window, block_q=bq, block_k=bk, kv_len=T,
+        interpret=interpret)
+    return _unflatten(out, B, Hq)[:, :T]
+
+
+# ---------------------------------------------------------------------------
+# ring decode: one query position a row over a RING of the row's last W
+# keys and values, grouped-query heads
+# ---------------------------------------------------------------------------
+
+def _jnp_ring_decode(q, ring_k, ring_v, k_new, v_new, positions):
+    """The ring-decode reference. ``q (B, Hq, D)`` scaled; ``ring_k``/
+    ``ring_v (B, W, Hkv, D)`` the rows' rings as they stand BEFORE the
+    step — key ``t`` lies in slot ``t % W`` — ``k_new``/``v_new (B, Hkv,
+    D)`` the step's own, not in the ring; ``positions (B,)``. A query at
+    position ``p`` sees its own key and, of the ring, the slots ``s < p``
+    but for slot ``p % W``, which holds key ``p - W`` (the one the step
+    overwrites): ``min(p, W - 1)`` keys, by the row's position alone.
+    Float32 softmax: ``(B, Hq, D)`` float32."""
+    import jax.numpy as jnp
+    B, Hq, D = q.shape
+    W, Hkv = ring_k.shape[1:3]
+    f32 = jnp.float32
+    qg = q.astype(f32).reshape(B, Hkv, Hq // Hkv, D)
+    pos = jnp.asarray(positions, jnp.int32)[:, None]
+    slot = jax.lax.iota(jnp.int32, W)[None, :]
+    seen = jnp.logical_and(slot < pos, slot != pos % W)
+    s = jnp.einsum("bhgd,bwhd->bhgw", qg, ring_k.astype(f32))
+    s = jnp.where(seen[:, None, None, :], s, _NEG)
+    own = jnp.einsum("bhgd,bhd->bhg", qg, k_new.astype(f32))[..., None]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), own)
+    p, p_own = jnp.exp(s - m), jnp.exp(own - m)
+    out = jnp.einsum("bhgw,bwhd->bhgd",
+                     p.astype(ring_v.dtype).astype(f32), ring_v.astype(f32)) \
+        + p_own * v_new.astype(f32)[:, :, None, :]
+    return (out / (jnp.sum(p, axis=-1, keepdims=True) + p_own)) \
+        .reshape(B, Hq, D)
+
+
+_RING_TILE = 16      # ring slots a write carries: a sublane tile of 16 bits
+
+
+def _ring_decode_kernel(slot_ref, pos_ref, live_ref, layer_ref, q_ref,
+                        kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
+                        *, window, n_kv, head_dim, tile):
+    """Grid = (rows,): one program instance attends ALL query heads of
+    one row to the row's ring — ``(W, Hkv * D)``, a token's key (or
+    value) heads side by side, one block, the same bytes whatever the
+    context — and puts the step's own key and value into slot ``p % W``.
+    Key/value head ``h`` is the lane-aligned slice ``[:, h * D:(h + 1) *
+    D]`` and the query heads of its group the rows of ONE ``(G, D) x (D,
+    W)`` product on the MXU; the weighted sum one ``(G, W) x (W, D)``.
+    What is visible follows from the row's position alone (:func:`_jnp_
+    ring_decode`); the own key opens the softmax on the VPU.
+
+    The rings are aliased to the results and only the ``tile`` slots
+    around ``p % W`` are written back (a select over the tile, so no
+    store at a dynamic offset into packed 16-bit rows); a row that is not
+    live writes its tile back as it was."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    del slot_ref, layer_ref
+    b = pl.program_id(0)
+    p = pos_ref[b]
+    at = jax.lax.rem(p, window)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
+    seen = jnp.logical_and(slots < p, slots != at)
+    f32 = jnp.float32
+    for h in range(n_kv):
+        lanes = slice(h * head_dim, (h + 1) * head_dim)
+        q = q_ref[h]                                          # (G, D)
+        kn, vn = kn_ref[:, lanes].astype(f32), vn_ref[:, lanes].astype(f32)
+        s = jnp.where(seen, _dot(q, k_ref[:, lanes], _NT), _NEG)  # (G, W)
+        own = jnp.sum(q.astype(f32) * kn, axis=-1, keepdims=True)
+        m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), own)
+        e, e_own = jnp.exp(s - m), jnp.exp(own - m)
+        v = v_ref[:, lanes]
+        acc = _dot(e.astype(v.dtype), v) + e_own * vn
+        o_ref[h] = (acc / (jnp.sum(e, axis=-1, keepdims=True) + e_own)) \
+            .astype(o_ref.dtype)
+    base = pl.multiple_of((at // tile) * tile, tile)
+    rows = base + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    put = jnp.logical_and(rows == at, live_ref[b] > 0)
+    ko_ref[...] = jnp.where(put, kn_ref[...], k_ref[pl.ds(base, tile), :])
+    vo_ref[...] = jnp.where(put, vn_ref[...], v_ref[pl.ds(base, tile), :])
+
+
+def _pallas_ring(q, k_new, v_new, ring_k, ring_v, layer, slots, positions,
+                 live, *, interpret):
+    """:func:`_pallas_ring_decode` with the ``layer (1,)`` an operand
+    (:func:`_traced_once`)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Hkv, G, D = q.shape
+    W, width = ring_k.shape[2:]
+    tile = min(_RING_TILE, W)
+
+    def row(shape):
+        return pl.BlockSpec((None,) + shape,
+                            lambda b, *prefetch: (b,) + (0,) * len(shape))
+
+    ring = pl.BlockSpec(
+        (None, None, W, width),
+        lambda b, slot, pos, live, layer: (layer[0], slot[b], 0, 0))
+    put = pl.BlockSpec(
+        (None, None, tile, width),
+        lambda b, slot, pos, live, layer: (
+            layer[0], slot[b], jax.lax.rem(pos[b], W) // tile, 0))
+    return pl.pallas_call(
+        functools.partial(_ring_decode_kernel, window=W, n_kv=Hkv,
+                          head_dim=D, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[row((Hkv, G, D)), row((1, width)), row((1, width)),
+                      ring, ring],
+            out_specs=[row((Hkv, G, D)), put, put]),
+        out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+                   jax.ShapeDtypeStruct(ring_k.shape, ring_k.dtype),
+                   jax.ShapeDtypeStruct(ring_v.shape, ring_v.dtype)],
+        input_output_aliases={7: 1, 8: 2},
+        interpret=interpret,
+        # rows x query heads, one query position, the ring's slots, the
+        # head size, then the key/value heads
+        name="mx_ring_decode.bh%d.q1.k%d.d%d.%s.kv%d" % (
+            B * Hkv * G, W, D, jnp.dtype(ring_k.dtype).name, Hkv),
+    )(slots, positions, live, layer, q, k_new, v_new, ring_k, ring_v)
+
+
+def _pallas_ring_decode(q, k_new, v_new, ring_k, ring_v, layer, slots,
+                        positions, live, interpret):
+    """``q (B, Hkv, G, D)`` scaled, in the rings' dtype; ``k_new``/
+    ``v_new (B, 1, Hkv * D)``; the WHOLE rings ``(layers, rows, W, Hkv *
+    D)`` and the ``layer`` to read; ``slots``, ``positions``, ``live
+    (B,)`` int32. Returns ``(out (B, Hkv, G, D) float32, ring_k,
+    ring_v)``, the rings updated in place."""
+    import jax.numpy as jnp
+    return _traced_once(_pallas_ring, "interpret")(
+        q, k_new, v_new, ring_k, ring_v, jnp.full((1,), layer, jnp.int32),
+        slots, positions, live, interpret=interpret)
+
+
+def ring_decode(q, k_new, v_new, ring_k, ring_v, layer, slots, positions,
+                live, scale=None, force_pallas=False):
+    """One decode step of SLIDING-WINDOW attention over rings: every row
+    keeps its last ``W`` keys and values in a ring of its own — ``ring_k``
+    / ``ring_v (layers, rows, W, Hkv * D)``, key ``t`` in slot ``t % W``,
+    a token's heads side by side — the same bytes whatever the context.
+
+    - ``q (B, Hq, D)``, ``k_new``/``v_new (B, Hkv, D)``: the step's one
+      position a row (query head ``i`` reads key/value head ``i // (Hq //
+      Hkv)``);
+    - ``slots (B,)``: the ring each row of the step works on, all
+      different; ``positions (B,)``; ``live (B,)``: a row that is not
+      live leaves its ring as it was (its output is nobody's).
+
+    A query at position ``p`` sees keys ``p - W < t <= p``: its own and
+    ``min(p, W - 1)`` of the ring — what is valid follows from the
+    position, never from the ring's content. The step's own key and value
+    then take slot ``p % W``. Returns ``(out (B, Hq, D) float32, ring_k,
+    ring_v)``. On the TPU, for a head size and a window of whole 128s,
+    the Pallas kernel ``mx_ring_decode`` (one grid step a row, the rings
+    updated in place); the jnp composition elsewhere. Counted as
+    ``ring_decode_pallas`` / ``ring_decode_jnp``."""
+    import jax.numpy as jnp
+    B, Hq, D = q.shape
+    Hkv = k_new.shape[1]
+    W = ring_k.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    dtype = ring_k.dtype
+    q = (q * scale).astype(dtype)
+    k_new = k_new.astype(dtype).reshape(B, 1, Hkv * D)
+    v_new = v_new.astype(dtype).reshape(B, 1, Hkv * D)
+    slots = jnp.asarray(slots, jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    live = jnp.asarray(live)
+
+    def composed(q, k_new, v_new, ring_k, ring_v, slots, pos, live):
+        out = _jnp_ring_decode(
+            q, ring_k[layer, slots].reshape(B, W, Hkv, D),
+            ring_v[layer, slots].reshape(B, W, Hkv, D),
+            k_new.reshape(B, Hkv, D), v_new.reshape(B, Hkv, D), pos)
+        at = pos % W
+        put = live[:, None]
+        ring_k = ring_k.at[layer, slots, at].set(
+            jnp.where(put, k_new[:, 0], ring_k[layer, slots, at]))
+        ring_v = ring_v.at[layer, slots, at].set(
+            jnp.where(put, v_new[:, 0], ring_v[layer, slots, at]))
+        return out, ring_k, ring_v
+
+    def kernel(interpret, q, k_new, v_new, ring_k, ring_v, slots, pos, live):
+        out, ring_k, ring_v = _pallas_ring_decode(
+            q.reshape(B, Hkv, Hq // Hkv, D), k_new, v_new, ring_k, ring_v,
+            layer, slots, pos, live.astype(jnp.int32), interpret)
+        return out.reshape(B, Hq, D), ring_k, ring_v
+
+    return _dispatch("ring_decode", D, (W,), force_pallas, kernel, composed,
+                     q, k_new, v_new, ring_k, ring_v, slots, pos, live)
+
+
 def flash_decode(q, k, v, lengths, scale=None, block_k=128,
                  force_pallas=False, k_scale=None, v_scale=None):
     """One autoregressive decode step of attention: a single cached-KV
@@ -1649,7 +1997,8 @@ def flash_decode(q, k, v, lengths, scale=None, block_k=128,
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
-                    block_k=512, force_pallas=False, segment_ids=None):
+                    block_k=512, force_pallas=False, segment_ids=None,
+                    window=None):
     """Attention over (B, T, H, D) tensors.
 
     The Pallas kernels (forward and backward) run on the TPU when
@@ -1666,8 +2015,30 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
     the kernels AND the jnp composition), and padding attends to
     nothing — its rows produce garbage a masked loss must (and does)
     ignore.
+
+    **Grouped-query heads and a sliding window** (serving's prefill;
+    causal self-attention, forward only): ``k``/``v`` may carry FEWER
+    heads than ``q`` — query head ``i`` reads key/value head ``i // (Hq
+    // Hkv)`` — and with ``window`` key ``t`` is visible to query ``s``
+    iff ``s - window < t <= s``. Either takes the grouped kernel
+    (``mx_grouped_fwd``; under a window ``...w<window>``, and the grid
+    names only the key blocks the band touches): operands in the keys'
+    dtype, the result float32.
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if window is not None or k.shape[2] != q.shape[2]:
+        if not causal or segment_ids is not None \
+                or q.shape[1] != k.shape[1] or q.shape[2] % k.shape[2]:
+            raise ValueError(
+                "flash_attention: grouped-query heads (%d over %d) or a "
+                "window are written for causal self-attention without "
+                "segments" % (q.shape[2], k.shape[2]))
+        bq, bk = _blocks(q.shape[1], k.shape[1], block_q, block_k)[2:]
+        return _dispatch(
+            "flash_grouped", q.shape[-1], (bq, bk), force_pallas,
+            lambda interpret, q, k, v: _grouped_forward(
+                q, k, v, scale, window, block_q, block_k, interpret),
+            lambda q, k, v: _jnp_grouped(q, k, v, scale, window), q, k, v)
     if segment_ids is not None and q.shape[1] != k.shape[1]:
         raise ValueError(
             "flash_attention: segment_ids requires self-attention "

@@ -29,7 +29,7 @@ KV cache, streaming, priorities, live weight swap) lives in
         for tok in req.tokens():                     # streams live
             ...
 
-Four models implement the decode-model contract: ``ToyDecoderLM``
+Five models implement the decode-model contract: ``ToyDecoderLM``
 (per-head K/V, one position a step), ``latent_moe.LatentMoEDecoderLM``
 (a latent cache, routed experts; with ``hc_mult`` > 1 several residual
 streams mixed by hyper-connections, and with its next-token module the
@@ -37,10 +37,14 @@ SPECULATIVE form of the contract: the module drafts one token, a
 two-position verify step accepts it or overwrites it),
 ``block_diffusion.BlockDiffusionMoEDecoderLM`` (the BLOCK form of the
 contract: generation by diffusion over blocks, grouped-query K/V,
-softmax-routed experts) and ``hybrid_linear_moe.
+softmax-routed experts), ``hybrid_linear_moe.
 HybridLinearMoEDecoderLM`` (the STATE form of the contract: delta-rule
 linear-attention layers whose state is a fixed array a row, held by the
-server beside the pages of a latent-attention layer a group).
+server beside the pages of a latent-attention layer a group) and
+``WindowMoEDecoderLM`` (``window_moe``; the state form again:
+sliding-window layers whose last keys and values are a RING a row beside
+the pages of the full-attention layers, two counts of gated query heads
+over one of key/value heads, softmax-routed experts and a shared one).
 
 Fleet serving — a :class:`Router` fronting N decode replicas with
 per-tenant weighted-fair quotas, graceful drain, and transparent
@@ -58,6 +62,7 @@ from .server import (InferenceServer, ServerOverloadedError,
                      validate_priority)
 from .kvcache import KVCachePool
 from .decode import DecodeServer, DecodeRequest, ToyDecoderLM
+from .window_moe import WindowMoEDecoderLM
 from .fleet import Replica, FleetMonitor
 from .router import Router, RouterRequest
 
@@ -65,5 +70,5 @@ __all__ = ["InferenceServer", "BucketLadder", "pad_batch", "slice_rows",
            "ServerOverloadedError", "RequestTimeoutError",
            "ServerClosedError", "validate_priority",
            "KVCachePool", "DecodeServer", "DecodeRequest",
-           "ToyDecoderLM", "Router", "RouterRequest", "Replica",
-           "FleetMonitor"]
+           "ToyDecoderLM", "WindowMoEDecoderLM", "Router", "RouterRequest",
+           "Replica", "FleetMonitor"]

@@ -276,10 +276,14 @@ per-head pool (no causal block form) are refused with a typed error
 when the server is built.
 
 **The state form of the contract** (``serving.hybrid_linear_moe.
-HybridLinearMoEDecoderLM``): a model whose layers are not all attention
-— a linear-attention layer carries a matrix a head and the last rows of
-a short convolution from token to token, the same bytes whatever the
-context — declares, beside ``cache_arrays``,
+HybridLinearMoEDecoderLM``, ``serving.window_moe.WindowMoEDecoderLM``): a
+model whose layers do not all cache a row a token — a linear-attention
+layer carries a matrix a head and the last rows of a short convolution
+from token to token; a sliding-window layer a RING of its last ``W``
+keys and one of its values, key ``t`` in slot ``t % W``, written whole
+by a prefill and masked by the row's POSITION, never by content: either
+way the same bytes whatever the context — declares, beside
+``cache_arrays``,
 
 - ``model.state_arrays = ((name, shape a row, dtype), ...)`` and
   ``model.state_layers``: the server keeps one array a name, ``(state_

@@ -220,6 +220,13 @@ def paged_attention(k_pages, v_pages, page_table, positions, layer, q,
                                             _pallas_paged_decode)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     quant = k_scale is not None
+    if q.shape[1] != k_new.shape[1] and not quant:
+        # fewer key/value than query heads over a per-head float pool (a
+        # packed pool attends through the block form already): a block
+        # of one position
+        return paged_block_attention(
+            k_pages, v_pages, page_table, positions, layer, q, k_new,
+            v_new, scale=scale, force_pallas=force_pallas)
     pos = jnp.asarray(positions, jnp.int32)
     table = jnp.asarray(page_table, jnp.int32)
     if not quant:
@@ -1193,13 +1200,16 @@ class RowState:
     each row of the step works on (a permutation of all of them: a dead
     row of the step takes a row nobody else has), its ``inverse``, and
     ``live (B,)``: a row that is not live leaves its state as it was.
-    The model returns the arrays, updated, among its results."""
+    The model returns the arrays, updated, among its results.
+    ``page_size`` is the pool's, for a model that counts the pages its
+    rows' keys occupy."""
 
-    __slots__ = ("arrays", "slots", "inverse", "live")
+    __slots__ = ("arrays", "slots", "inverse", "live", "page_size")
 
-    def __init__(self, arrays, slots, live):
+    def __init__(self, arrays, slots, live, page_size=None):
         import jax.numpy as jnp
         self.arrays = tuple(arrays)
+        self.page_size = page_size
         self.slots = jnp.asarray(slots, jnp.int32)
         self.live = jnp.asarray(live, bool)
         n = self.slots.shape[0]
@@ -1208,14 +1218,20 @@ class RowState:
 
 
 class _RowStateBeside:
-    """A paged layout (``pages``: the latent one, or per-head K and V)
-    with fixed state a row beside it: a program's ``pools`` are the
-    pages' arrays and then one array a declared state, ``(state_layers,
-    rows, *shape)``. Attending and the pages' writes are the inner
-    layout's, over the arrays that are its own; the state is handed to a
-    step whole (:class:`RowState`) and written by a prefill into ONE
-    row. No block form: a pass over several positions a row would have
-    to keep the state after each."""
+    """A paged layout (``pages``: the latent one, or per-head K and V,
+    packed or not) with fixed state a row beside it: a program's
+    ``pools`` are the pages' arrays and then one array a declared state,
+    ``(state_layers, rows, *shape)`` — a linear-attention layer's matrix
+    a head and its convolution rows (``serving.hybrid_linear_moe``), or a
+    sliding-window layer's RING of its last keys and one of its values
+    (``serving.window_moe``): whatever is the same bytes whatever the
+    context. Attending and the pages' writes are the inner layout's, over
+    the arrays that are its own and the model's ``cache_layers`` only;
+    the state is handed to a step whole (:class:`RowState`) and written
+    by a prefill into ONE row, whole — so what of a row is valid follows
+    from the row's position, never from what a slot's last tenant left.
+    No block form: a pass over several positions a row would have to keep
+    the state after each."""
 
     blocks = False
     causal_blocks = False
@@ -1257,7 +1273,8 @@ class _RowStateBeside:
 
     def row_state(self, pools, slots, live):
         """The :class:`RowState` of a step over ``pools``."""
-        return RowState(self.split(pools)[1], slots, live)
+        return RowState(self.split(pools)[1], slots, live,
+                        page_size=pools[0].shape[2])
 
     def write_state(self, pools, slot, new, valid):
         """A prefill's state ``new`` (one a declared array, ``(state_
@@ -1305,7 +1322,10 @@ def cache_layout(specs, dtype):
     raise MXNetError(
         "KVCachePool: no cache layout for %s — a model caches per-head "
         "K and V, two arrays of (n_heads, head_dim), or one latent "
-        "array of (row_width,)" % (specs,))
+        "array of (row_width,), in pages; what a layer keeps at a fixed "
+        "size a row (a recurrent state, a ring of a window's keys) is "
+        "declared beside them as state_arrays, not as a page shape"
+        % (specs,))
 
 
 def layout_for(model, pools):
