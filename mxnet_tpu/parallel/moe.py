@@ -27,6 +27,26 @@ TPU where the shapes tile (:func:`_gmm_tiles`), ``jax.lax.ragged_dot``
 elsewhere (the CPU, and the kernel's test reference); the choice is
 ``flash_attention._choose_path``'s, counted at trace time as
 ``grouped_matmul_pallas`` / ``grouped_matmul_jnp``.
+
+**The padded slot layout follows the slots it is handed.** A decode
+step hands :func:`expert_ffn` 2-10 slots an expert, a prompt hundreds,
+and the layout's two dimensions are read from those static shapes and
+from nothing else (no argument, no model's name):
+
+- *tile height* (:func:`_gmm_rows`): 16 rows (one bf16 sublane tile)
+  for a step, where a taller tile would be padding, up to 128 (the
+  MXU's own height) at prefill widths, where a 16-row tile feeds the
+  MXU an eighth of its rows. The height is in the kernel's name
+  (``.r128``) and counted at trace time as ``grouped_matmul_rows_<m>``.
+- *slot order*: slots are numbered choice-major (``j * T + t``), so the
+  rows gathered back are ``(k, T, D)`` as they lie, tokens and width on
+  the tiled dims, and the select of unheld slots, the weight and the sum
+  over the ``k`` choices are ONE pass over them. (Token-major, ``k`` sat
+  on the sublanes of an (8, 128) tiling: a relaid copy of the whole
+  array before the sum.)
+
+Whatever the height, ``R`` rows are laid out: the dropless worst case,
+every slot held and every expert's last tile all but empty.
 """
 from __future__ import annotations
 
@@ -194,8 +214,23 @@ def expert_load(topi, held):
                    axis=0).astype(jnp.int32)
 
 
-_GMM_ROWS = 16        # rows a tile of token slots holds (a bf16 sublane tile)
-_GMM_VMEM = 64 << 20  # of the chip's 128 MiB; the default scoped limit is 16
+_GMM_ROWS = (16, 128)  # a tile's least rows (a bf16 sublane tile) and most
+_GMM_VMEM = 64 << 20   # of the chip's 128 MiB; the default scoped limit is 16
+
+
+def _gmm_rows(n_slots, E):
+    """Rows a tile of token slots holds, from the static shapes alone:
+    the largest power of two not above HALF of ``n_slots // E`` (the
+    most slots a held expert can average), within ``_GMM_ROWS``. Half,
+    because an expert's last tile is half empty on average: at a height
+    of the average itself the padding would double the rows computed.
+    A decode step (at most 32 slots an expert in every served
+    configuration) keeps the 16 rows it was drawn for; a prompt of
+    thousands of tokens fills the MXU's 128."""
+    m, most = _GMM_ROWS
+    while m < most and 4 * m <= n_slots // E:
+        m *= 2
+    return m
 
 
 def _gmm_block_n(K, N, itemsize, n_weights):
@@ -219,12 +254,15 @@ def _gmm_tiles(K, N, itemsize=2, n_weights=1):
 
 def _gmm_kernel(tile_expert_ref, n_live_ref, x_ref, *refs, gated):
     """Grid = (column blocks, row tiles), tiles innermost. One program
-    instance multiplies one tile of ``_GMM_ROWS`` token slots, all of
-    one expert, by that expert's ``(K, bn)`` weight block: bf16
-    operands on the MXU, float32 accumulation. Consecutive tiles of one
-    expert name the same weight block, so it is fetched once a column
-    block; tiles at or past ``n_live`` name the last live tile's blocks
-    again (nothing is fetched) and compute nothing. ``gated``: two
+    instance multiplies one tile of token slots, all of one expert, by
+    that expert's ``(K, bn)`` weight block: bf16 operands on the MXU,
+    float32 accumulation. The tile's height is the row block's, which
+    :func:`_gmm_rows` chose from the slots the layer was handed; a row's
+    result does not depend on it (each row is its own product over
+    ``K``). Consecutive tiles of one expert name the same weight block,
+    so it is fetched once a column block; tiles at or past ``n_live``
+    name the last live tile's blocks again (nothing is fetched) and
+    compute nothing. ``gated``: two
     weights, ``silu(x @ w0) * (x @ w1)``."""
     import jax
     import jax.numpy as jnp
@@ -245,10 +283,10 @@ def _gmm_kernel(tile_expert_ref, n_live_ref, x_ref, *refs, gated):
 
 def _pallas_grouped_matmul(x, weights, tile_expert, n_live, out_dtype,
                            interpret):
-    """``x (R, K)`` rows in tiles of ``_GMM_ROWS``, tile ``i`` all of
-    expert ``tile_expert[i]``; ``weights``: one ``(E, K, N)`` stack, or
-    two for the gated form. Rows of tiles at or past ``n_live`` are
-    left unwritten."""
+    """``x (R, K)`` rows in as many tiles as ``tile_expert`` has
+    entries, tile ``i`` all of expert ``tile_expert[i]``; ``weights``:
+    one ``(E, K, N)`` stack, or two for the gated form. Rows of tiles
+    at or past ``n_live`` are left unwritten."""
     import functools
     import jax
     import jax.numpy as jnp
@@ -257,16 +295,17 @@ def _pallas_grouped_matmul(x, weights, tile_expert, n_live, out_dtype,
     R, K = x.shape
     E, _, N = weights[0].shape
     bn = _gmm_block_n(K, N, weights[0].dtype.itemsize, len(weights))
-    tiles = R // _GMM_ROWS
+    tiles = tile_expert.shape[0]
+    m = R // tiles
 
     def live(i, n_live):
         return jnp.minimum(i, jnp.maximum(n_live[0], 1) - 1)
 
-    rows = pl.BlockSpec((_GMM_ROWS, K),
+    rows = pl.BlockSpec((m, K),
                         lambda j, i, te, nl: (live(i, nl), 0))
     wblk = pl.BlockSpec((None, K, bn),
                         lambda j, i, te, nl: (te[live(i, nl)], 0, j))
-    out = pl.BlockSpec((_GMM_ROWS, bn),
+    out = pl.BlockSpec((m, bn),
                        lambda j, i, te, nl: (live(i, nl), j))
     return pl.pallas_call(
         functools.partial(_gmm_kernel, gated=len(weights) == 2),
@@ -277,8 +316,8 @@ def _pallas_grouped_matmul(x, weights, tile_expert, n_live, out_dtype,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_GMM_VMEM),
         interpret=interpret,
-        name="mx_grouped_matmul.e%d.m%d.k%d.n%d.%s%s" % (
-            E, R, K, N, jnp.dtype(weights[0].dtype).name,
+        name="mx_grouped_matmul.e%d.m%d.k%d.n%d.%s.r%d%s" % (
+            E, R, K, N, jnp.dtype(weights[0].dtype).name, m,
             ".gated" if len(weights) == 2 else ""),
     )(tile_expert, n_live, x, *weights)
 
@@ -296,12 +335,25 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     computed, whatever the load (no capacity).
 
     The slots are sorted by expert and each expert's group padded to
-    whole tiles of ``_GMM_ROWS`` rows, so that a tile belongs to one
-    expert: the grouped matmul then reads an expert's matrices once and
-    never those of an expert no token chose. The same layout feeds
-    ``jax.lax.ragged_dot`` on the plain path."""
+    whole tiles of ``m`` rows, so that a tile belongs to one expert: the
+    grouped matmul then reads an expert's matrices once and never those
+    of an expert no token chose. The same layout feeds
+    ``jax.lax.ragged_dot`` on the plain path.
+
+    The layout takes its dimensions from the static shapes it is handed
+    (``n_slots = T * k`` and the ``E`` held experts), not from a decode
+    step's: ``m`` is :func:`_gmm_rows`' (16 for a step's few slots an
+    expert, up to 128 for a prompt's hundreds; counted at trace time as
+    ``grouped_matmul_rows_<m>``), and ``R`` stays the dropless worst
+    case at that height. Slots are numbered choice-major (``j * T +
+    t``): the rows gathered back are then ``(k, T, D)`` as they lie, and
+    select, weight and the float32 sum over the ``k`` choices are one
+    pass over them (a reduction over the leading axis). A token's row of
+    the result depends neither on the height nor on what else is in the
+    batch."""
     import jax
     import jax.numpy as jnp
+    from .. import profiler
     from .flash_attention import _dispatch
     lo, hi = held
     E = hi - lo
@@ -311,12 +363,13 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     w_gate, w_up, w_down = (weights[n] for n in ("w_gate", "w_up",
                                                  "w_down"))
     F = w_gate.shape[-1]
-    m = _GMM_ROWS
+    m = _gmm_rows(n_slots, E)
+    profiler.increment_counter("grouped_matmul_rows_%d" % m)
     # the most rows the padded layout can need: every slot held, and
     # every group's last tile all but empty
     R = (-(-n_slots // m) + E) * m
 
-    local = topi.reshape(-1) - lo
+    local = topi.T.reshape(-1) - lo                     # slot j * T + t
     is_held = jnp.logical_and(local >= 0, local < E)
     key = jnp.where(is_held, local, E)                  # unheld last
     sizes = expert_load(topi, held)
@@ -330,8 +383,8 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     # a held slot's row in the padded layout; an unheld one's is R (dropped)
     row = jnp.where(is_held, start[safe] + rank - first[safe], R)
     src = jnp.zeros((R,), jnp.int32).at[row].set(
-        jnp.arange(n_slots, dtype=jnp.int32) // k, mode="drop")
-    xs = x[src].astype(w_gate.dtype)                    # (R, D)
+        jnp.arange(n_slots, dtype=jnp.int32) % T, mode="drop")
+    xs = x.astype(w_gate.dtype)[src]                    # (R, D)
     tile_ends = jnp.cumsum(padded) // m
     n_live = tile_ends[-1:].astype(jnp.int32)           # (1,)
     tile_expert = jnp.minimum(
@@ -361,8 +414,10 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     ys = _dispatch("grouped_matmul", 128 if tiles else 1, (), force_pallas,
                    kernel, composed, xs, w_gate, w_up, w_down, tile_expert,
                    n_live)
-    # back to (token, choice): rows of dead tiles are unwritten, so an
-    # unheld slot is masked by a select, never by a product
-    got = ys[jnp.minimum(row, R - 1)].reshape(T, k, D)
-    got = jnp.where(is_held.reshape(T, k, 1), got, 0.0)
-    return jnp.sum(got * topw[:, :, None].astype(jnp.float32), axis=1)
+    # back to (choice, token): rows of dead tiles are unwritten, so an
+    # unheld slot is masked by a select, never by a product; select,
+    # weight and sum fuse into one pass over the gathered rows
+    got = ys[jnp.minimum(row, R - 1)].reshape(k, T, D)
+    is_held = is_held.reshape(k, T, 1)
+    w = topw.T.astype(jnp.float32)[:, :, None]
+    return jnp.sum(jnp.where(is_held, got, 0.0) * w, axis=0)

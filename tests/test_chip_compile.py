@@ -272,7 +272,7 @@ def test_mixed_step_program_compiles_and_fits(chip, monkeypatch, config, C):
         pools = [spec((model.n_layers, pages, S, model.n_heads,
                        model.head_dim), jnp.float32)] * 2
         kernels = {"flash_decode": model.n_layers}
-        n_counts, temp = 0, 0.2e9
+        n_counts, temp, rows = 0, 0.2e9, ""
     else:
         model = LatentMoEDecoderLM(**cfg["model"]["kwargs"])
         pools = [spec((model.n_layers, pages, S, model.row_width),
@@ -280,6 +280,9 @@ def test_mixed_step_program_compiles_and_fits(chip, monkeypatch, config, C):
         kernels = {"mla_decode": model.n_layers, "latent_write": 1,
                    "grouped_matmul": 2 * model.n_moe_layers}
         n_counts, temp = len(model.step_counters[1]), 0.5e9
+        # W + C = 320 rows x top 8 on 16 held experts: 160 slots an
+        # expert can average, so tiles of 64 rows (a step's are 16)
+        rows = ".e16.m3584.k7168.n2048.bfloat16.r64.gated"
     params = jax.eval_shape(lambda: model.init_params(seed=0))
     compiled = jax.jit(
         lambda *a: DecodeServer._decode_fn_chunk(
@@ -291,6 +294,7 @@ def test_mixed_step_program_compiles_and_fits(chip, monkeypatch, config, C):
         spec((W,), jnp.int32), spec((C + M + 3,), jnp.int32),
         *pools).compile()
     text = compiled.as_text()
+    assert rows in text
     for kernel, calls in kernels.items():
         assert len(re.findall(
             r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\(" % kernel,
@@ -364,6 +368,8 @@ def test_latent_moe_programs_compile_and_fit(chip, monkeypatch):
         M * S, model.row_width, model.kv_rank) in text
     assert len(named(text, "latent_write")) == 1
     assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    # a step's 32 slots an expert keep the 16-row tiles they were drawn for
+    assert ".e16.m768.k7168.n2048.bfloat16.r16.gated" in text
     assert text.count('custom_call_target="tpu_custom_call"') \
         == L + 1 + 2 * moe_layers
     mem = step.memory_analysis()
@@ -462,7 +468,7 @@ def test_block_diffusion_programs_compile_and_fit(chip, monkeypatch):
     assert len(named(text, "block_write")) == 2 * L
     assert "mx_block_write.b%d.q%d.l1.s%d.d512.bfloat16" % (W, Q, S) in text
     assert len(named(text, "grouped_matmul")) == 2 * L
-    assert ".e128.m4096.k2048.n768.bfloat16.gated" in text
+    assert ".e128.m4096.k2048.n768.bfloat16.r16.gated" in text
     assert text.count('custom_call_target="tpu_custom_call"') == 5 * L
     # only Q positions a row reach the head
     assert "f32[%d,%d,%d]" % (W, Q, model.vocab) in text
@@ -550,7 +556,7 @@ def test_speculative_latent_programs_compile_and_fit(chip, monkeypatch):
     assert len(named(text, "latent_write")) == 1
     assert "mx_latent_write.b%d.q2.l%d.s%d" % (W, L, S) in text
     assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    assert ".e64.m" in text and ".k3584.n1024.bfloat16.gated" in text
+    assert ".e64.m1536.k3584.n1024.bfloat16.r16.gated" in text
     assert text.count('custom_call_target="tpu_custom_call"') \
         == L + 1 + 2 * moe_layers
     mem = step.memory_analysis()
@@ -711,7 +717,7 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     assert len(named(text, "mla_decode")) == 1
     assert len(named(text, "latent_write")) == 1
     assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    assert ".e128.m" in text and ".k2560.n768.bfloat16.gated" in text
+    assert ".e128.m2560.k2560.n768.bfloat16.r16.gated" in text
     assert text.count('custom_call_target="tpu_custom_call"') \
         == 5 + 2 + 2 * moe_layers
     mem = step.memory_analysis()
@@ -729,6 +735,8 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     assert len(named(text, "flash_fwd")) == 1
     assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
     assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    # the rung's 64 slots an expert take tiles of 32 rows
+    assert ".e128.m12288.k2560.n768.bfloat16.r32.gated" in text
     # the chunkwise rule's walk: one loop a linear layer, carrying S
     assert len(re.findall(r"%while[.\d]* = \(s32\[\][^,]*, "
                           r"f32\[1,32,128,128\]", text)) == 5
@@ -806,7 +814,7 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
         W * 48, M * S) in text
     assert len(named(text, "block_write")) == 2
     assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    assert ".e64.m" in text and ".k3072.n1024.bfloat16.gated" in text
+    assert ".e64.m1664.k3072.n1024.bfloat16.r16.gated" in text
     assert text.count('custom_call_target="tpu_custom_call"') \
         == 3 + 2 + 2 + 2 * moe_layers
     mem = step.memory_analysis()
@@ -816,7 +824,13 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 11.2e9 < planned < 11.4e9, mem
 
-    for rung, temp in ((512, 0.5e9), (8192, 3.2e9)):
+    # the slot layout follows the rung: 80 slots an expert can average on
+    # the 512 rung (tiles of 32 rows), 1,280 on the 8192 rung (128, the
+    # MXU's height); the 8192 rung's temporaries were 3.01 GB while the
+    # way back to token order relaid a (T, k, D) copy, and may not grow
+    for rung, temp, rows in (
+            (512, 0.5e9, ".e64.m7168.k3072.n1024.bfloat16.r32"),
+            (8192, 3.01e9, ".e64.m90112.k3072.n1024.bfloat16.r128")):
         prefill = jax.jit(
             lambda *a: DecodeServer._state_prefill_fn(holder, *a),
             donate_argnums=(5, 6, 7, 8)).lower(
@@ -830,9 +844,10 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
         assert "mx_grouped_fwd.bh48.q%d.k%d.d128.bfloat16.kv8" % (
             rung, rung) in text
         assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+        assert text.count(rows + ".gated") >= moe_layers, rung
         mem = prefill.memory_analysis()
         assert mem.alias_size_in_bytes >= carried_bytes, mem
-        assert mem.temp_size_in_bytes < temp, (rung, mem.temp_size_in_bytes)
+        assert mem.temp_size_in_bytes <= temp, (rung, mem.temp_size_in_bytes)
         planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                    + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
         assert planned < 14.5e9, (rung, planned)

@@ -555,13 +555,15 @@ BLOCK = dict(vocab_size=256, hidden_size=128, num_hidden_layers=2,
              moe_intermediate_size=64, num_experts=8,
              num_experts_per_tok=2, rope_theta=10000, block_length=4,
              mask_token_id=255, max_position_embeddings=512)
-# sha1 of the jaxprs' text on the commit this PR starts from (4c0d884),
-# by ``_program_jaxprs`` there
+# sha1 of the jaxprs' text on the commit PR 38 started from (4c0d884),
+# by ``_program_jaxprs`` there; the six expert programs re-pinned by
+# PR 45, whose ``expert_ffn`` numbers its slots choice-major (the toy
+# programs, which have no expert layer, are 4c0d884's still)
 PARENT_PROGRAMS = {
     "toy.prefill": "b5050806fa1fe556", "toy.step": "d4ebfb3472495617",
-    "dots.prefill": "c835abc3c5a64afd", "dots.step": "27958a0bc522e393",
-    "xing.prefill": "88d7eb23c53e935b", "xing.step": "b95905b5cd501578",
-    "sdar.prefill": "6abf986dd09b2147", "sdar.step": "288ffe9ffb32d218"}
+    "dots.prefill": "bac5948b77c693b1", "dots.step": "b8d32ee340413e73",
+    "xing.prefill": "e62dce4ccde6f20c", "xing.step": "c4a1637226543733",
+    "sdar.prefill": "da37af03131dfff8", "sdar.step": "0d68eba0c238c13c"}
 
 
 def _program_jaxprs():

@@ -760,49 +760,97 @@ def test_latent_row_write_kernel_is_the_row_writes():
         and changed[:, 0, 0].all()
 
 
-@pytest.mark.parametrize("case", ["spread", "one_expert", "none_held"])
-def test_grouped_matmul_kernel_matches_ragged_dot(case):
-    T, D, F, E = 24, 128, 256, 8
-    held = (8, 16)
+def _expert_case(T, topi_of):
+    """Tokens, bf16 expert stacks of the held range (8, 16) and a
+    router's choice over 32 experts for ``expert_ffn``'s tests."""
+    D, F, E = 128, 256, 8
     k = jax.random.split(jax.random.PRNGKey(2), 6)
     x = jax.random.normal(k[0], (T, D))
     w = {"w_gate": jax.random.normal(k[1], (E, D, F)) * D ** -0.5,
          "w_up": jax.random.normal(k[2], (E, D, F)) * D ** -0.5,
          "w_down": jax.random.normal(k[3], (E, F, D)) * F ** -0.5}
     w = {n: v.astype(jnp.bfloat16) for n, v in w.items()}
-    if case == "spread":
-        topi = jax.random.randint(k[4], (T, 4), 0, 32)
-    elif case == "one_expert":       # every token on one held expert:
-        topi = jnp.full((T, 4), 11).at[:, 1:].set(  # several row tiles
-            jnp.asarray([0, 1, 2]))
-    else:
-        topi = jax.random.randint(k[4], (T, 4), 16, 32)
     topw = jax.random.uniform(k[5], (T, 4)) + 0.1
+    return x, w, topi_of(k[4], T), topw
+
+
+_TOPI = {
+    "spread": lambda key, T: jax.random.randint(key, (T, 4), 0, 32),
+    # every token on one held expert: several row tiles
+    "one_expert": lambda key, T: jnp.full((T, 4), 11).at[:, 1:].set(
+        jnp.asarray([0, 1, 2])),
+    "none_held": lambda key, T: jax.random.randint(key, (T, 4), 16, 32),
+    # Laguna's ``live`` mask: the last third of the rung is padding, and
+    # a padded position chooses expert 32, past the last, which nobody holds
+    "padded": lambda key, T: jnp.where(
+        (jnp.arange(T) < T - T // 3)[:, None],
+        jax.random.randint(key, (T, 4), 0, 32), 32),
+}
+
+
+@pytest.mark.parametrize("case", [
+    "spread", "one_expert", "none_held", "padded",
+    "tall_spread", "tall_one_expert", "tall_none_held", "tall_padded"])
+def test_grouped_matmul_kernel_matches_ragged_dot(case):
+    """A step's width (12 slots an expert: tiles of 16 rows) and a
+    prompt's (256: the tallest tile, 128), each against the plain loop
+    on both paths."""
+    tall = case.startswith("tall_")
+    T, E, held = 512 if tall else 24, 8, (8, 16)
+    assert moe._gmm_rows(T * 4, E) == (128 if tall else 16)
+    x, w, topi, topw = _expert_case(T, _TOPI[case[5 * tall:]])
     before = dict(profiler.counters())
     a = moe.expert_ffn(x, w, topi, topw, held)
     b = moe.expert_ffn(x, w, topi, topw, held, force_pallas=True)
     after = profiler.counters()
-    assert after.get("grouped_matmul_jnp", 0) \
-        - before.get("grouped_matmul_jnp", 0) == 1
-    assert after.get("grouped_matmul_pallas", 0) \
-        - before.get("grouped_matmul_pallas", 0) == 1
+    for name, n in (("grouped_matmul_jnp", 1), ("grouped_matmul_pallas", 1),
+                    ("grouped_matmul_rows_%d" % (128 if tall else 16), 2),
+                    ("grouped_matmul_rows_%d" % (16 if tall else 128), 0)):
+        assert after.get(name, 0) - before.get(name, 0) == n, name
     # a plain loop, float32 from the same bf16 operands
-    want = np.zeros((T, D), np.float32)
+    want = np.zeros((T, x.shape[1]), np.float32)
     xb = np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))
     wf = {n: np.asarray(v.astype(jnp.float32)) for n, v in w.items()}
+    topi_h, topw_h = np.asarray(topi), np.asarray(topw)
     for t in range(T):
         for j in range(4):
-            e = int(topi[t, j]) - held[0]
+            e = int(topi_h[t, j]) - held[0]
             if 0 <= e < E:
                 g, u = xb[t] @ wf["w_gate"][e], xb[t] @ wf["w_up"][e]
-                h = np.asarray(jnp.asarray(g / (1 + np.exp(-g)) * u)
-                               .astype(jnp.bfloat16).astype(jnp.float32))
-                want[t] += float(topw[t, j]) * (h @ wf["w_down"][e])
+                h = (g / (1 + np.exp(-g)) * u).astype(
+                    jnp.bfloat16).astype(np.float32)
+                want[t] += float(topw_h[t, j]) * (h @ wf["w_down"][e])
     scale = max(np.abs(want).max(), 1.0)
     assert np.abs(np.asarray(a) - want).max() / scale < 2e-3
     assert np.abs(np.asarray(b) - want).max() / scale < 2e-3
-    if case == "none_held":
+    if case.endswith("none_held"):
         assert not np.asarray(a).any() and not np.asarray(b).any()
+    if case.endswith("padded"):
+        dead = T - T // 3
+        assert np.asarray(a)[:dead].any() and not np.asarray(a)[dead:].any()
+        assert not np.asarray(b)[dead:].any()
+
+
+@pytest.mark.parametrize("force_pallas", [True, False])
+def test_a_tokens_rows_do_not_depend_on_the_tile_height(force_pallas):
+    """The same 24 tokens alone (12 slots an expert: tiles of 16 rows)
+    and at the head of a prompt of 512 (256: tiles of 128) get the same
+    rows bit for bit, and the trace-time counter says which height each
+    program was given."""
+    held = (8, 16)
+    x, w, topi, topw = _expert_case(512, _TOPI["spread"])
+    before = dict(profiler.counters())
+    tall = moe.expert_ffn(x, w, topi, topw, held, force_pallas=force_pallas)
+    mid = dict(profiler.counters())
+    short = moe.expert_ffn(x[:24], w, topi[:24], topw[:24], held,
+                           force_pallas=force_pallas)
+    after = profiler.counters()
+    assert mid.get("grouped_matmul_rows_128", 0) \
+        - before.get("grouped_matmul_rows_128", 0) == 1
+    assert after.get("grouped_matmul_rows_16", 0) \
+        - mid.get("grouped_matmul_rows_16", 0) == 1
+    assert np.asarray(short).any()
+    assert (np.asarray(tall)[:24] == np.asarray(short)).all()
 
 
 # ---------------------------------------------------------------------------
