@@ -1579,11 +1579,15 @@ def _pallas_block_write(pages, page_idx, slot, new, layer, interpret):
 # serving's prefill for fewer key/value than query heads
 # ---------------------------------------------------------------------------
 
-def _jnp_grouped(q, k, v, scale, window=None):
+def _jnp_grouped(q, k, v, scale, window=None, q_offset=0, k_first=None):
     """The reference of :func:`_pallas_grouped_forward`: causal attention
     of ``q (B, T, Hq, D)`` over ``k``/``v (B, T, Hkv, D)``, query head
     ``i`` reading key/value head ``i // (Hq // Hkv)``; with ``window``
     key ``t`` is visible to query ``s`` iff ``s - window < t <= s``.
+    With ``q_offset`` the queries are the LAST of a longer run of keys:
+    query ``j`` stands at key ``q_offset + j`` (``k``/``v (B, Tk, Hkv,
+    D)``, ``Tk >= q_offset + T``), and with ``k_first`` (a traced scalar)
+    no key in front of that one is visible (:func:`ring_chunk`).
     Float32 scores and softmax, the weights rounded to ``v``'s dtype as
     the kernel rounds them; returns ``(B, T, Hq, D)`` float32."""
     import jax.numpy as jnp
@@ -1592,10 +1596,14 @@ def _jnp_grouped(q, k, v, scale, window=None):
     qg = (q * scale).astype(k.dtype).astype(f32).reshape(
         B, T, Hkv, Hq // Hkv, D)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(f32))
-    at = jax.lax.iota(jnp.int32, T)
-    seen = at[None, :] <= at[:, None]
+    # the key a query stands at, and every key
+    at = (q_offset + jax.lax.iota(jnp.int32, T))[:, None]
+    keys = jax.lax.iota(jnp.int32, k.shape[1])[None, :]
+    seen = keys <= at
     if window is not None:
-        seen = jnp.logical_and(seen, at[None, :] > at[:, None] - window)
+        seen = jnp.logical_and(seen, keys > at - window)
+    if k_first is not None:
+        seen = jnp.logical_and(seen, keys >= k_first)
     s = jnp.where(seen[None, None, None], s, _NEG)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -1605,28 +1613,41 @@ def _jnp_grouped(q, k, v, scale, window=None):
         / jnp.transpose(l[..., 0], (0, 3, 1, 2)).reshape(B, T, Hq, 1)
 
 
-def _band(i, block_q, block_k, window):
-    """The first and the last key block a query block ``i`` may see."""
+def _band(i, block_q, block_k, window, q_offset=0, n_kb=None):
+    """The first and the last key block a query block ``i`` (traced, or a
+    Python int) may see, its queries standing ``q_offset`` keys in (of
+    ``n_kb`` key blocks: the padding of an offset block of queries
+    reaches past the keys')."""
     import jax.numpy as jnp
-    first = 0 if window is None else \
-        jnp.maximum(i * block_q - window + 1, 0) // block_k
-    return first, ((i + 1) * block_q - 1) // block_k
+    most, least = (max, min) if isinstance(i, int) \
+        else (jnp.maximum, jnp.minimum)
+    lo = q_offset + i * block_q
+    first = 0 if window is None else most(lo - window + 1, 0) // block_k
+    last = (lo + block_q - 1) // block_k
+    return first, last if n_kb is None else least(last, n_kb - 1)
 
 
-def _grouped_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                        *, block_q, block_k, n_steps, kv_len, window):
+def _grouped_fwd_kernel(*refs, block_q, block_k, n_steps, n_kb, kv_len,
+                        window, q_offset, k_first):
     """Grid = (batch * query heads, q blocks, band steps), the band
     innermost: step ``j`` of query block ``i`` folds key block ``first(i)
     + j`` — under a window only the blocks the band touches are ever
     named (two of 512 for a window of 512 over blocks of 512), the
     blocks behind it are skipped, not masked. Operands as they come (the
     cache's dtype, the queries scaled and rounded to it), float32 scores,
-    running softmax and accumulation."""
+    running softmax and accumulation. With ``k_first`` the first
+    reference is a prefetched scalar, the first key that may be seen at
+    all: whatever lies in front of it is masked, never read for what it
+    holds."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    first_ref = None
+    if k_first:
+        first_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     qi, j = pl.program_id(1), pl.program_id(2)
-    first, last = _band(qi, block_q, block_k, window)
+    first, last = _band(qi, block_q, block_k, window, q_offset, n_kb)
     kb = first + j
 
     @pl.when(j == 0)
@@ -1640,11 +1661,13 @@ def _grouped_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         s = _dot(q_ref[...], k_ref[...], _NT)            # (bq, bk)
         k_pos = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, 1), 0)
         seen = jnp.logical_and(k_pos < kv_len, q_pos >= k_pos)
         if window is not None:
             seen = jnp.logical_and(seen, k_pos > q_pos - window)
+        if first_ref is not None:
+            seen = jnp.logical_and(seen, k_pos >= first_ref[0])
         s = jnp.where(seen, s, _NEG)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -1663,52 +1686,66 @@ def _grouped_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 
 def _pallas_grouped_forward(q, k, v, *, n_q_heads, window, block_q, block_k,
-                            kv_len, interpret):
-    """``q (B * Hq, T, D)`` scaled, in the keys' dtype; ``k``/``v (B *
-    Hkv, T, D)``, ``T`` padded to the blocks. Returns ``(B * Hq, T, D)``
-    float32. Named ``mx_grouped_fwd``, the key/value heads behind the
-    shapes and, under a window, ``.w<window>`` behind those."""
+                            kv_len, interpret, q_offset=0, k_first=None):
+    """``q (B * Hq, Tq, D)`` scaled, in the keys' dtype; ``k``/``v (B *
+    Hkv, Tk, D)``, both lengths padded to their blocks. Query ``j``
+    stands at key ``q_offset + j`` (0 and ``Tq == Tk``: a prompt over
+    itself); ``k_first (1,)`` int32, where given, is the first key that
+    may be seen. Returns ``(B * Hq, Tq, D)`` float32. Named
+    ``mx_grouped_fwd``, the key/value heads behind the shapes and, under
+    a window, ``.w<window>`` behind those (and ``.o<q_offset>``)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    BH, T, D = q.shape
+    BH, Tq, D = q.shape
+    Tk = k.shape[1]
     Hq = n_q_heads
     Hkv = k.shape[0] // (BH // Hq)
     G = Hq // Hkv
-    n_qb, n_kb = T // block_q, T // block_k
-    # the widest band of any query block: the grid's innermost extent
-    n_steps = n_kb if window is None else max(
-        ((i + 1) * block_q - 1) // block_k
-        - max(i * block_q - window + 1, 0) // block_k + 1
-        for i in range(n_qb))
+    n_qb, n_kb = Tq // block_q, Tk // block_k
 
-    def kv_map(b, i, j):
-        first, last = _band(i, block_q, block_k, window)
+    def band(i):
+        return _band(i, block_q, block_k, window, q_offset, n_kb)
+
+    # the widest band of any query block: the grid's innermost extent
+    n_steps = max(last - first + 1
+                  for first, last in map(band, range(n_qb)))
+
+    def kv_map(b, i, j, *_prefetched):
+        first, last = band(i)
         # a step past the band names the band's last block again: no copy
         return ((b // Hq) * Hkv + (b % Hq) // G,
                 jnp.minimum(first + j, last), 0)
 
-    q_spec = pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0))
+    q_spec = pl.BlockSpec((None, block_q, D),
+                          lambda b, i, j, *_prefetched: (b, i, 0))
     kv_spec = pl.BlockSpec((None, block_k, D), kv_map)
     name = "mx_grouped_fwd.bh%d.q%d.k%d.d%d.%s.kv%d" % (
-        BH, T, T, D, jnp.dtype(k.dtype).name, BH // Hq * Hkv)
+        BH, Tq, Tk, D, jnp.dtype(k.dtype).name, BH // Hq * Hkv)
     if window is not None:
         name += ".w%d" % window
+    if q_offset:
+        name += ".o%d" % q_offset
+    scratch = [pltpu.VMEM((block_q, D), jnp.float32),
+               pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, 1), jnp.float32)]
+    prefetched = () if k_first is None else (k_first,)
     return pl.pallas_call(
         functools.partial(_grouped_fwd_kernel, block_q=block_q,
-                          block_k=block_k, n_steps=n_steps, kv_len=kv_len,
-                          window=window),
-        grid=(BH, n_qb, n_steps),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32)],
+                          block_k=block_k, n_steps=n_steps, n_kb=n_kb,
+                          kv_len=kv_len, window=window, q_offset=q_offset,
+                          k_first=bool(prefetched)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetched),
+            grid=(BH, n_qb, n_steps),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), jnp.float32),
         interpret=interpret,
         name=name,
-    )(q, k, v)
+    )(*prefetched, q, k, v)
 
 
 def _grouped_forward(q, k, v, scale, window, block_q, block_k, interpret):
@@ -1920,6 +1957,87 @@ def ring_decode(q, k_new, v_new, ring_k, ring_v, layer, slots, positions,
 
     return _dispatch("ring_decode", D, (W,), force_pallas, kernel, composed,
                      q, k_new, v_new, ring_k, ring_v, slots, pos, live)
+
+
+def ring_chunk(q, k_new, v_new, ring_k, ring_v, layer, slot, start, n_live,
+               scale=None, force_pallas=False):
+    """A CHUNK of one row's prompt under sliding-window attention over
+    the row's ring: ``C`` consecutive positions ``start .. start + C - 1``
+    of ONE row, the first ``n_live`` of them live — what a mixed decode
+    step's chunk lanes run in a sliding layer (:func:`ring_decode` is the
+    step's one position a row).
+
+    - ``q (C, Hq, D)``, ``k_new``/``v_new (C, Hkv, D)``: the chunk's own,
+      not in the ring; ``ring_k`` / ``ring_v (layers, rows, W, Hkv * D)``
+      WHOLE, ``slot`` the row of them the request holds; ``start``,
+      ``n_live`` traced scalars.
+
+    Lane ``j`` at position ``p = start + j`` sees keys ``p - W < t <= p``:
+    of the ring AS IT STANDS BEFORE the chunk (slot ``s`` holds the last
+    ``t < start`` with ``t % W == s``; a slot whose ``t`` would be
+    negative is masked by position — a slot's last tenant's keys are
+    never read for what they hold) and the chunk's own rows ``<= j``. The
+    keys are laid out ``[the ring in position order ; the chunk's own]``,
+    ``W + C`` of them, key ``i`` at position ``start - W + i``, the
+    queries ``W`` keys in: the banded grouped forward with an offset
+    (``mx_grouped_fwd...w<W>.o<W>``, the blocks behind the band never
+    named) on the TPU for a head size of whole 128s, the ``jnp``
+    composition elsewhere; counted as ``ring_chunk_pallas`` /
+    ``ring_chunk_jnp``. Then the ring takes the LAST ``min(n_live, W)``
+    live lanes, lane ``j`` into slot ``(start + j) % W``, as one row
+    written in place (no lane: the row as it was). Holds for any ``C``
+    against ``W``. Returns ``(out (C, Hq, D) float32, ring_k, ring_v)``;
+    a lane at or past ``n_live`` computes finite garbage nobody reads."""
+    import jax.numpy as jnp
+    C, Hq, D = q.shape
+    Hkv = k_new.shape[1]
+    W, width = ring_k.shape[2:]
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    start = jnp.asarray(start, jnp.int32)
+    n_live = jnp.asarray(n_live, jnp.int32)
+
+    def laid_out(ring, new):
+        row = jax.lax.dynamic_slice(
+            ring, (layer, slot, 0, 0), (1, 1, W, width))[0, 0]
+        return jnp.concatenate([jnp.roll(row, -(start % W), axis=0),
+                                new.astype(ring.dtype).reshape(C, width)])
+
+    keys, values = laid_out(ring_k, k_new), laid_out(ring_v, v_new)
+    # the key at position 0: nothing in front of it was ever this row's
+    k_first = jnp.maximum(W - start, 0)
+
+    def heads(a):
+        return a.reshape(1, W + C, Hkv, D)
+
+    def composed(q, keys, values, k_first):
+        return _jnp_grouped(q[None], heads(keys), heads(values), scale,
+                            window=W, q_offset=W, k_first=k_first)[0]
+
+    tq_pad, tk_pad, bq, bk = _blocks(C, W + C, 512, 512)
+
+    def kernel(interpret, q, keys, values, k_first):
+        out = _pallas_grouped_forward(
+            _flatten(_pad_seq((q * scale).astype(keys.dtype)[None],
+                              tq_pad)),
+            _flatten(_pad_seq(heads(keys), tk_pad)),
+            _flatten(_pad_seq(heads(values), tk_pad)), n_q_heads=Hq,
+            window=W, block_q=bq, block_k=bk, kv_len=W + C,
+            interpret=interpret, q_offset=W,
+            k_first=jnp.reshape(k_first, (1,)))
+        return _unflatten(out, 1, Hq)[0, :C]
+
+    out = _dispatch("ring_chunk", D, (bq, bk), force_pallas, kernel,
+                    composed, q, keys, values, k_first)
+
+    def put(ring, laid):
+        # positions start + n_live - W .. start + n_live - 1, back into
+        # their slots
+        row = jnp.roll(jax.lax.dynamic_slice_in_dim(laid, n_live, W),
+                       (start + n_live) % W, axis=0)
+        return jax.lax.dynamic_update_slice(ring, row[None, None],
+                                            (layer, slot, 0, 0))
+
+    return out, put(ring_k, keys), put(ring_v, values)
 
 
 def flash_decode(q, k, v, lengths, scale=None, block_k=128,

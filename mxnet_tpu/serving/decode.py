@@ -19,9 +19,10 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   ("decode")`` is the oracle: ``1 + len(ladder)`` programs under ANY
   request mix, zero steady-state recompiles.
 - **A prompt rides the decode step in chunks** — on the plain step form
-  (a model that declares ``chunk_lanes``, below) over a layout that can
-  (per-head K and V, packed or not, and the latent array: every float
-  layout) there is NO prefill program: an admitted request holds its
+  and on the state form (a model that declares ``chunk_lanes``, below)
+  over a layout that can (per-head K and V, packed or not, and the
+  latent array: every float layout; beside fixed state a row, the pages'
+  own) there is NO prefill program: an admitted request holds its
   pages and its prompt as tokens pending, and each decode step carries
   a chunk of them — ``C`` consecutive positions of ONE request,
   head-most first — on ``C`` more lanes of a MIXED step program
@@ -35,8 +36,9 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   a ladder 256 / 512 / 1024 / 1536; 256 of a ladder of 256): a chunk
   takes the smallest that holds what is pending, the largest while more
   is. The program set is then ``1 +`` those rungs, whatever the ladder.
-  The block, speculative and state forms, an int8 pool and a model that
-  does not declare it keep the whole-prompt prefill, ``1 + len(ladder)``.
+  The block and speculative forms, an int8 pool and a model that does
+  not declare it — a state model whose state cannot take a chunk: a
+  recurrence — keep the whole-prompt prefill, ``1 + len(ladder)``.
   ``stats()["chunk_steps"]`` / ``["chunk_tokens"]`` /
   ``["prefill_programs"]`` count it; ``mx:decode.dispatch`` carries
   ``chunk`` and ``chunk_of``.
@@ -305,10 +307,33 @@ way the same bytes whatever the context — declares, beside
   (in place, where its kernel aliases them), and a row that is not live
   must leave its slot as it was.
 
+**Which state takes a chunk** is the model's to say, and the server
+observes it: a state model that declares ``chunk_lanes = True``
+(``WindowMoEDecoderLM``: a sliding layer's chunk is ``C`` more keys
+written into slots ``t % W`` of the request's ring and attended under
+the band — the ring's validity follows from position alone) is served by
+the step and the MIXED step (``_state_decode_fn_chunk``, beside
+``_state_decode_fn`` as ``_decode_fn_chunk`` is beside ``_decode_fn``),
+over pages that can (the layout beside the state passes on its pages'
+``chunks``), with the plain form's host code — FIFO, one request's chunk
+a step, the same chunk sizes — and no prefill program. Its ``decode(...,
+state, head=None, live=None, chunk=None)`` is handed ``chunk = (the
+request's row of the state arrays, the first lane's position, the live
+lanes)`` beside ``head`` and ``live``. The chunk's request is NOT a live
+row of that step: the rows that decode come first in ``slots``, a row
+whose prompt is still pending rides behind ``n_live``, and its state is
+written by the chunk's lanes alone (a lane of its own at position 0
+would write slot 0 of its ring). A model that does not declare it
+(``HybridLinearMoEDecoderLM``: a chunk of a delta rule is another
+recurrence from the row's state) keeps ``_state_prefill_fn``. The
+dispatch span of a mixed state step carries ``chunk``, ``chunk_of`` and
+``state_rows_live`` (the rows that decode).
+
 **A row's state belongs to its slot**: a request takes one of the
 window's rows (``DecodeRequest.slot``) when it is admitted and keeps it
-to its end; its prefill (``_state_prefill_fn``) writes the row WHOLE, so
-a slot's last tenant leaves nothing behind; every step
+to its end; its prefill (``_state_prefill_fn``) writes the row WHOLE —
+or its chunks do, from position 0 on, masking by position whatever they
+have not reached — so a slot's last tenant leaves nothing behind; every step
 (``_state_decode_fn``) is handed ``slots``, a permutation of ALL the
 window's rows — the live rows' own first, the rest in any order behind
 them, not live — so that two weight generations, each with its own step
@@ -321,7 +346,8 @@ last step is already dispatched, and the prefill orders itself behind
 it by the arrays, as page writes do. ``mx:decode.dispatch`` and
 ``mx:decode.readback`` carry ``state_rows_live``, ``mx:decode.prefill``
 the ``state_slot`` it writes. The program set stays ``1 +
-len(ladder)``. Refused with a typed error when the server is built:
+len(ladder)``, or ``1 +`` the chunk sizes where the state takes a chunk.
+Refused with a typed error when the server is built:
 prefix sharing (the index holds pages, not the state at a page
 boundary, and its suffix feed would have to start from one), the block
 and the speculative forms (a pass over several positions a row would
@@ -799,10 +825,11 @@ class DecodeServer:
                 "budget or raise the model's reach"
                 % (self._seq_ladder.max_batch, self._max_new,
                    self._max_context, model_reach))
-        # the plain step form over a layout that can, of a model that
-        # declares ``chunk_lanes``: a prompt rides the decode step in
-        # chunks and no prefill program is built; the block, speculative
-        # and state forms, an int8 pool and a model that does not
+        # the plain or the state step form over a layout that can, of a
+        # model that declares ``chunk_lanes`` (a state model: that its
+        # state can take a chunk): a prompt rides the decode step in
+        # chunks and no prefill program is built; the block and
+        # speculative forms, an int8 pool and a model that does not
         # declare it keep the whole-prompt prefill. A step's budget of
         # prompt tokens is twice the ladder's smallest rung, and the
         # mixed program is built at every rung within it (256 and 512 of
@@ -810,7 +837,7 @@ class DecodeServer:
         # holds what its prompt still has pending, so a short prompt
         # pays no dead lanes and a long one half the steps
         self._chunks = ()
-        if not (self._block or self._spec or self._state) \
+        if not (self._block or self._spec) \
                 and self._pool.layout.chunks \
                 and getattr(model, "chunk_lanes", False):
             rungs = self._seq_ladder.buckets
@@ -853,7 +880,9 @@ class DecodeServer:
             step_donate = {"donate_argnums": tuple(range(first,
                                                          first + n_pool))}
             # (the mixed step's after its chunk, one array more)
-            chunk_donate = {"donate_argnums": tuple(range(7, 7 + n_pool))}
+            first += 1
+            chunk_donate = {"donate_argnums": tuple(range(first,
+                                                          first + n_pool))}
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
         # ONE step program and one prefill program a rung, whatever the
         # kind of model: a block model's are the block forms, a
@@ -868,7 +897,9 @@ class DecodeServer:
         # step's ``window`` lanes and a chunk's more — and no prefill
         self._chunk_progs = {
             C: compile_watch.jit(
-                self._decode_fn_chunk, "%s:step:chunk:c%d" % (site, C),
+                self._state_decode_fn_chunk if self._state
+                else self._decode_fn_chunk,
+                "%s:step:chunk:c%d" % (site, C),
                 statics=(site, self._window, self._max_pages, C),
                 **chunk_donate) for C in self._chunks}
         self._prefill_progs = {}
@@ -1331,6 +1362,51 @@ class DecodeServer:
             tokens_out = jnp.concatenate(
                 [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
         return (tokens_out, *pages, *new[n:n + n_state])
+
+    def _state_decode_fn_chunk(self, params, tokens, positions, slots,
+                               n_live, page_tables, prev, src, chunk,
+                               *pools):
+        """A state model's MIXED step program: :meth:`_state_decode_fn`'s
+        ``window`` lanes and, behind them, the ``C`` lanes of ONE
+        request's chunk, as :meth:`_decode_fn_chunk` is beside
+        :meth:`_decode_fn` (``chunk``, the head, the request's first
+        token: as there). The chunk's request is NOT one of the step's
+        live rows — its row of the state arrays, ``slots[chunk's slot]``,
+        rides behind ``n_live`` — so the model writes that row from the
+        chunk's lanes alone (``decode(..., chunk=(the row, start,
+        n))``)."""
+        import jax.numpy as jnp
+        B, M = self._window, self._max_pages
+        C = chunk.shape[0] - M - 3
+        tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+        table, (start, n, slot) = chunk[C:C + M], chunk[C + M:]
+        lanes = jnp.arange(C, dtype=jnp.int32)
+        rows = jnp.arange(B, dtype=jnp.int32)
+        layout = kvcache.layout_for(self._model, pools)
+        n_arrays, n_state = len(layout.specs), len(layout.state)
+        attend = layout.attend_chunk(pools, page_tables, positions, table,
+                                     start)
+        state = layout.row_state(pools, slots, rows < n_live)
+        logits, *new = self._model.decode(
+            params, jnp.concatenate([tokens, chunk[:C]]),
+            jnp.concatenate([positions, start + lanes]), attend, state,
+            head=jnp.concatenate([rows, B + jnp.maximum(n, 1)[None] - 1]),
+            live=jnp.concatenate([state.live, lanes < n]),
+            chunk=(slots[jnp.maximum(slot, 0)], start, n))
+        held = tuple(new[n_arrays:n_arrays + n_state])
+        pages = layout.write_tokens(
+            pools, page_tables, positions,
+            [a[:, :B] for a in new[:n_arrays]],
+            getattr(self._model, "use_pallas", False))
+        pages = layout.write_chunk(
+            pages + held, table, start, n,
+            [a[:, B:] for a in new[:n_arrays]])
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # (B + 1,)
+        tokens_out = jnp.where(rows == slot, out[B], out[:B])
+        if len(new) > n_arrays + n_state:
+            tokens_out = jnp.concatenate(
+                [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
+        return (tokens_out, *pages, *held)
 
     # copy-on-write page copy — the whole split is one traced program
     # (src/dst ride as traced scalars, so any page pair reuses it).
@@ -2001,6 +2077,10 @@ class DecodeServer:
             start = min(cached, P - 1)
             req.pending = deque(int(t) for t in req.prompt[start:])
             req.pending_pos = start
+            if self._state:
+                # its row of the state arrays is written anew from its
+                # first chunk on
+                self._pool.note_state_write()
             if req.trace_args is not None:
                 tracing.add(
                     "queue", "decode", req._t_trace, sp.t0 - req._t_trace,
@@ -2312,6 +2392,11 @@ class DecodeServer:
             emits = []
             chunk = None
             decoding = _np.zeros((D,), bool)
+            if self._state and self._chunks:
+                # a state step's live rows are its first: the rows that
+                # decode, then those whose prompt is still pending (FIFO
+                # admits in that order anyway; this holds it)
+                rows.sort(key=lambda r: bool(r.pending))
             for i, r in enumerate(rows):
                 if r.pending and self._chunks:
                     # its prompt rides the step in chunks: the
@@ -2360,13 +2445,17 @@ class DecodeServer:
                 said = {"chunk": n, "chunk_of": fed.request_id}
             if self._state:
                 # every row of the state arrays, the live rows' own
-                # first: what another weight generation's rows hold, and
-                # what nobody holds, rides behind them, not live
+                # first: what a row whose prompt is still pending holds
+                # (the chunk's lanes alone write the fed one's: a lane of
+                # its own at position 0 would write slot 0 of its ring),
+                # what another weight generation's rows hold, and what
+                # nobody holds, rides behind them, not live
                 own = [r.slot for r in rows]
                 rest = sorted(set(range(D)) - set(own))
+                live = int(decoding.sum())
                 feed += (_np.asarray(own + rest, _np.int32),
-                         _np.int32(len(rows)))
-                said = {"state_rows_live": len(rows)}
+                         _np.int32(live))
+                said = dict(said, state_rows_live=live)
         self._dispatch_step(ver, rows, emits, feed + (pts,), src,
                             pages_live, said, prev, chunk)
 
@@ -2764,7 +2853,8 @@ class DecodeServer:
                 counts = self._model_counts(toks, D)
                 back.set(**(counts or {}))
                 if self._state:
-                    back.set(state_rows_live=len(step.rows))
+                    back.set(state_rows_live=step.launch.args[
+                        "state_rows_live"])
         except Exception as exc:       # noqa: BLE001 — the step's error
             self._retire(step.rows, exc)
             # whatever was fed from the failed step fails with it

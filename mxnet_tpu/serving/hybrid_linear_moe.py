@@ -109,6 +109,11 @@ class HybridLinearMoEDecoderLM(LatentMoEDecoderLM):
     TPU), ``dtype`` the matrices', the pool's and the convolution
     rows' (``"float32"`` for a test that compares logits)."""
 
+    # the latent model's ``decode`` takes a chunk's lanes; this one's
+    # state cannot, yet: a chunk of a delta rule is another recurrence
+    # from the row's state. Its server keeps the whole-prompt prefill
+    chunk_lanes = False
+
     def __init__(self, *, vocab_size, hidden_size, num_hidden_layers,
                  num_attention_heads, head_dim, layer_group_size,
                  kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,

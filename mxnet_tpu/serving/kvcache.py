@@ -952,8 +952,8 @@ class _PerHeadKV:
     # (``DecodeServer``'s mixed step): ``C`` consecutive positions from
     # ``start`` on as ``C`` more lanes behind the step's ``B``. Every
     # float layout has it; int8 pages (a chunk's rows would requantize
-    # theirs) and fixed state a row (a chunk of a recurrence is another
-    # recurrence) have not
+    # theirs) have not; beside fixed state a row (``_RowStateBeside``) the
+    # pages have it and the model says whether its state can take one
     chunks = True
     _chunk_attention = staticmethod(paged_chunk_attention)
 
@@ -1231,11 +1231,17 @@ class _RowStateBeside:
     by a prefill into ONE row, whole — so what of a row is valid follows
     from the row's position, never from what a slot's last tenant left.
     No block form: a pass over several positions a row would have to keep
-    the state after each."""
+    the state after each. A CHUNK of a prompt on a mixed step's lanes is
+    the pages' own to attend and write (``chunks`` is the inner layout's);
+    what the state does with a chunk is the model's, which says whether it
+    can by declaring ``chunk_lanes``: a ring takes one (more keys into
+    slots ``t % W``, attended under the band, valid by position as ever);
+    a recurrence does not, yet (a chunk of it is another recurrence from
+    the row's state), and keeps the whole-prompt prefill."""
 
     blocks = False
     causal_blocks = False
-    chunks = False
+    chunks = property(lambda self: self.pages.chunks)
 
     def __init__(self, pages, state, layers):
         self.pages, self.state, self.state_layers = pages, state, layers
@@ -1270,6 +1276,15 @@ class _RowStateBeside:
                      force_pallas=False):
         return self.pages.write_tokens(self.split(pools)[0], page_tables,
                                        positions, new, force_pallas)
+
+    def attend_chunk(self, pools, page_tables, positions, table_row,
+                     start):
+        return self.pages.attend_chunk(self.split(pools)[0], page_tables,
+                                       positions, table_row, start)
+
+    def write_chunk(self, pools, table_row, start, n_live, new):
+        return self.pages.write_chunk(self.split(pools)[0], table_row,
+                                      start, n_live, new)
 
     def row_state(self, pools, slots, live):
         """The :class:`RowState` of a step over ``pools``."""

@@ -48,6 +48,26 @@ position masks, never their content); a decode step
 position says are valid and puts its own key into slot ``p % W``, in
 place; a row that is not live leaves its ring as it was.
 
+**A prompt rides the step in chunks** (``chunk_lanes = True``: what
+``DecodeServer`` observes; the server then builds no prefill program and
+:meth:`prefill` is the oracle of the tests). ``decode`` is handed, behind
+the step's rows, ``C`` lanes that are consecutive positions ``start ..``
+of ONE request's prompt. Lane-wise everywhere but in attention: a
+full-attention layer's ``attend`` is the layout's split one (the rows
+their paged kernel, the chunk a walk of the request's pages under a
+running softmax); a sliding layer's chunk
+(:func:`parallel.flash_attention.ring_chunk`) sees, lane ``j`` at ``p =
+start + j``, keys ``p - W < t <= p`` of the request's ring as it stands
+and the chunk's own rows ``<= j`` — the banded grouped forward the
+prefill uses, its queries offset behind the ring's ``W`` keys laid out in
+position order, a slot whose position would be negative masked by
+position — and then puts the last ``min(n, W)`` live lanes into slots
+``(start + j) % W``, one row written in place. The chunk's request is no
+live row of the step, so nothing else writes its ring; the step counters
+``ring_rows_wrapped``, ``global_pages_live`` and ``ring_bytes`` count the
+rows that DECODE (what ``mx_ring_decode`` and the paged kernel read), the
+routed experts' three every live lane.
+
 **What the published keys do not settle** (``assumed``; the
 configuration's file says each again, with its reason): no norm over
 ``q`` and ``k`` (no key names one); ``attention_factor`` multiplies cos
@@ -137,6 +157,10 @@ class WindowMoEDecoderLM:
     (``dtype`` where not given; ``"float32"`` for a test that compares
     logits)."""
 
+    # ``decode`` takes ``head``, ``live`` and ``chunk``: a ring takes a
+    # chunk of a prompt (512 more keys into slots ``t % W``, attended under
+    # the band), so a prompt may ride the step in chunks
+    chunk_lanes = True
     step_counters = ("moe", ("moe_slots", "experts_touched", "max_load",
                              "ring_rows_wrapped", "global_pages_live",
                              "ring_bytes"))
@@ -481,17 +505,36 @@ class WindowMoEDecoderLM:
         return (logits, jnp.stack(ks), jnp.stack(vs),
                 *(jnp.stack(a) for a in zip(*rings)))
 
-    def decode(self, params, tokens, positions, attend, state):
+    def decode(self, params, tokens, positions, attend, state, head=None,
+               live=None, chunk=None):
         """One token a row: ``attend(cache layer, q (B, H, Dh), k_new,
         v_new (B, Hkv, Dh), scale=, force_pallas=)`` attends a
         full-attention layer's pages; ``state`` is the step's
         :class:`~mxnet_tpu.serving.kvcache.RowState` (``.arrays``: the
         rings, whole; ``.slots``; ``.live``). Returns ``(logits, k, v
-        (cache_layers, B, Hkv, Dh), ring_k, ring_v, counters)``."""
+        (cache_layers, B, Hkv, Dh), ring_k, ring_v, counters)``.
+
+        A MIXED step hands more lanes than rows: behind the ``B`` rows of
+        ``state``, ``C`` lanes that are consecutive positions of ONE
+        request's prompt, ``chunk = (its row of the rings, the first
+        lane's position, the live lanes)``. Everything is lane-wise but
+        attention: a full layer's ``attend`` is the layout's split one
+        (``attend_chunk``), a sliding layer runs the rows through
+        :func:`~mxnet_tpu.parallel.flash_attention.ring_decode` and the
+        chunk through :func:`~mxnet_tpu.parallel.flash_attention.
+        ring_chunk`, which writes the request's ring (the request is no
+        live row of the step). ``live (B + C,)``: a lane that is not live
+        chooses no expert; ``head (B + 1,)``: the lanes that reach the
+        head, ``logits`` theirs alone; the keys and values come back for
+        every lane, ``(cache_layers, B + C, Hkv, Dh)``, and the counters
+        count the ``B`` rows only (what ``mx_ring_decode`` and the paged
+        kernel read)."""
         import jax.numpy as jnp
-        from ..parallel.flash_attention import ring_decode
+        from ..parallel.flash_attention import ring_chunk, ring_decode
         p = params
-        B = tokens.shape[0]
+        B = state.slots.shape[0]
+        row_pos = positions[:B]
+        live = state.live if live is None else live
         ring_k, ring_v = state.arrays
         h = p["embed"][tokens].astype(jnp.float32)
         ks, vs, loads = [], [], []
@@ -501,30 +544,40 @@ class WindowMoEDecoderLM:
             q, k, v, g = self._qkv(i, x, p, positions)
             if self.kinds[i] == _SLIDING:
                 a, ring_k, ring_v = ring_decode(
-                    q, k, v, ring_k, ring_v, self.state_layer(i),
-                    state.slots, positions, state.live, scale=self.scale,
-                    force_pallas=self.use_pallas)
+                    q[:B], k[:B], v[:B], ring_k, ring_v,
+                    self.state_layer(i), state.slots, row_pos, state.live,
+                    scale=self.scale, force_pallas=self.use_pallas)
+                if chunk is not None:
+                    tail, ring_k, ring_v = ring_chunk(
+                        q[B:], k[B:], v[B:], ring_k, ring_v,
+                        self.state_layer(i), *chunk, scale=self.scale,
+                        force_pallas=self.use_pallas)
+                    a = jnp.concatenate([a, tail])
             else:
                 a = attend(self.cache_layer(i), q, k, v, scale=self.scale,
                            force_pallas=self.use_pallas)
                 ks.append(k)
                 vs.append(v)
-            a = (a.astype(jnp.float32) * g[..., None]).reshape(B, -1)
+            a = (a.astype(jnp.float32) * g[..., None]).reshape(
+                tokens.shape[0], -1)
             h = h + self._mm(a, p[l + "wo"])
             out, load = self._ffn(i, self._rms(h, p[l + "ffn_g"]), p,
-                                  live=state.live)
+                                  live=live)
             h = h + out
             if load is not None:
                 loads.append(load)
+        if head is not None:
+            # a chunk's lanes do not pay the head
+            h = h[head]
         logits = self._mm(self._rms(h, p["out_g"]), p["head"])
         load = jnp.stack(loads)                           # (layers, E)
         # a live row's keys: those the step's position has reached
-        seen = jnp.where(state.live, positions + 1, 0)
+        seen = jnp.where(state.live, row_pos + 1, 0)
         token = 2 * self.n_kv_heads * self.head_dim \
             * jnp.dtype(self.cache_dtype).itemsize
         counters = jnp.stack([
             load.sum(), (load > 0).sum(), load.max(),
-            jnp.sum(jnp.logical_and(state.live, positions >= self.window)),
+            jnp.sum(jnp.logical_and(state.live, row_pos >= self.window)),
             jnp.sum(-(-seen // state.page_size)),
             jnp.sum(jnp.minimum(seen, self.window))
             * (self.state_layers * token)])

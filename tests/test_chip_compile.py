@@ -748,22 +748,28 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     assert planned < 10.2e9, mem
 
 
-def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_window_moe_programs_compile_and_fit(chip, monkeypatch, program):
     """``benchmark/configs/Laguna-S-2.1.json`` at its published widths
     (3072 wide, 48 and 72 gated query heads over 8 key/value heads of
     128, three sliding-window layers whose last 512 keys and values are a
     ring a row beside two full-attention layers' packed pages, experts of
     1024, 64 of 256 held, 1 dense + 4 expert layers, window 64, 4,608
     bf16 pages of 128 x 1024 in two cache layers): the state form's
-    ``decode:step`` and its 512- and 8192-rung ``decode:prefill`` programs
-    compiled for one described v5e. In each: the Mosaic kernels under the
-    names a profile's reader looks for — the ring decode a sliding layer
-    (9 query heads a key head), the paged block kernel a full layer (6)
-    and its in-place row write (step), the grouped forward a layer,
-    banded under the window (prefill), the two grouped matmuls of every
-    expert layer — the planned bytes inside the chip, the donated pool
-    AND the donated rings updated in place, and NO copy of either among
-    the temporaries (the rings are 0.4 GB, the pool 4.8 GB)."""
+    ``decode:step`` and its MIXED step ``decode:step:chunk:c512`` — 64
+    lanes that decode and 512 that are one prompt's chunk; since PR 46
+    this server runs no prefill program, and the case that compiled its
+    512- and 8192-rung prefills compiles this — for one described v5e. In
+    each: the Mosaic kernels under the names a profile's reader looks for
+    — the ring decode a sliding layer (9 query heads a key head), the
+    paged block kernel a full layer (6) and its in-place row write, the
+    two grouped matmuls of every expert layer and, in the mixed step, the
+    banded grouped forward with its queries offset behind the ring's 512
+    keys, a sliding layer — the planned bytes inside the chip, the
+    donated pool AND the donated rings updated in place, and NO copy of
+    either among the temporaries (the rings are 0.4 GB, the pool 4.8 GB).
+    The mixed step's temporaries: 0.20 GB found (the 8192-rung prefill it
+    replaces planned 2.5 GB), held under 0.3."""
     from mxnet_tpu.serving import DecodeServer, WindowMoEDecoderLM
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     with open(os.path.join(ROOT, "benchmark", "configs",
@@ -772,9 +778,14 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
     srv = cfg["server"]["kwargs"]
     W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
     M = -(-(max(srv["seq_ladder"]) + srv["max_new_tokens"]) // S)
+    # the ladder 512 / 8192 gives one mixed program, of the first rung
+    C, = [r for r in sorted(srv["seq_ladder"])
+          if r <= 2 * min(srv["seq_ladder"])]
+    assert C == 512
     model = WindowMoEDecoderLM(**cfg["model"]["kwargs"])
     assert model.held == (0, 64) and model.heads == (48, 72, 72, 72, 48)
     assert (model.cache_layers, model.state_layers) == (2, 3)
+    assert model.chunk_lanes and model.window == 512
     moe_layers = model.n_moe_layers
     params = jax.eval_shape(lambda: model.init_params(seed=0))
     weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -793,20 +804,29 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
     carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                         for a in carried)
     assert 5.23e9 < carried_bytes < 5.24e9
-    holder = type("S", (), {"_model": model, "_window": W})()
+    holder = type("S", (), {"_model": model, "_window": W,
+                            "_max_pages": M})()
     n_counts = len(model.step_counters[1])
 
     def named(text, kernel):
         return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
                           % kernel, text, re.M)
 
-    step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
-                   donate_argnums=(8, 9, 10, 11)).lower(
-        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
-        spec((W,), jnp.int32), spec((), jnp.int32), spec((W, M), jnp.int32),
-        spec((W + n_counts,), jnp.int32), spec((W,), jnp.int32),
-        *carried).compile()
+    feed = (tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
+            spec((W,), jnp.int32), spec((), jnp.int32),
+            spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
+            spec((W,), jnp.int32))
+    if program == "step":
+        step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
+                       donate_argnums=(8, 9, 10, 11)).lower(
+            *feed, *carried).compile()
+    else:
+        step = jax.jit(
+            lambda *a: DecodeServer._state_decode_fn_chunk(holder, *a),
+            donate_argnums=(9, 10, 11, 12)).lower(
+            *feed, spec((C + M + 3,), jnp.int32), *carried).compile()
     text = step.as_text()
+    # the rows that decode keep their kernels beside a chunk
     assert len(named(text, "ring_decode")) == 3
     assert "mx_ring_decode.bh%d.q1.k512.d128.bfloat16.kv8" % (W * 72) in text
     assert len(named(text, "block_decode")) == 2
@@ -814,40 +834,28 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch):
         W * 48, M * S) in text
     assert len(named(text, "block_write")) == 2
     assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    assert ".e64.m1664.k3072.n1024.bfloat16.r16.gated" in text
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == 3 + 2 + 2 + 2 * moe_layers
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= carried_bytes, mem
-    assert mem.temp_size_in_bytes < 0.1e9, mem      # no pool or ring copy
     planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert 11.2e9 < planned < 11.4e9, mem
-
-    # the slot layout follows the rung: 80 slots an expert can average on
-    # the 512 rung (tiles of 32 rows), 1,280 on the 8192 rung (128, the
-    # MXU's height); the 8192 rung's temporaries were 3.01 GB while the
-    # way back to token order relaid a (T, k, D) copy, and may not grow
-    for rung, temp, rows in (
-            (512, 0.5e9, ".e64.m7168.k3072.n1024.bfloat16.r32"),
-            (8192, 3.01e9, ".e64.m90112.k3072.n1024.bfloat16.r128")):
-        prefill = jax.jit(
-            lambda *a: DecodeServer._state_prefill_fn(holder, *a),
-            donate_argnums=(5, 6, 7, 8)).lower(
-            tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
-            spec((M,), jnp.int32), spec((), jnp.int32), *carried).compile()
-        text = prefill.as_text()
-        assert len(named(text, "grouped_fwd")) == 5
-        assert len(re.findall(
-            r"%%mx_grouped_fwd\.bh72\.q%d\.k%d\.d128\.bfloat16\.kv8\.w512"
-            % (rung, rung), text)) >= 3
-        assert "mx_grouped_fwd.bh48.q%d.k%d.d128.bfloat16.kv8" % (
-            rung, rung) in text
-        assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-        assert text.count(rows + ".gated") >= moe_layers, rung
-        mem = prefill.memory_analysis()
-        assert mem.alias_size_in_bytes >= carried_bytes, mem
-        assert mem.temp_size_in_bytes <= temp, (rung, mem.temp_size_in_bytes)
-        planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                   + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-        assert planned < 14.5e9, (rung, planned)
+    if program == "step":
+        assert ".e64.m1664.k3072.n1024.bfloat16.r16.gated" in text
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            == 3 + 2 + 2 + 2 * moe_layers
+        assert mem.temp_size_in_bytes < 0.1e9, mem   # no pool or ring copy
+        assert 11.2e9 < planned < 11.4e9, mem
+        return
+    # the chunk's sliding layers: 512 queries behind the ring's 512 keys,
+    # two key blocks of 512 a query block, never a third
+    assert len(named(text, "grouped_fwd")) == 3
+    assert "mx_grouped_fwd.bh72.q%d.k%d.d128.bfloat16.kv8.w512.o512" % (
+        C, 512 + C) in text
+    # 576 lanes x 10 choices: 90 slots an expert, tiles of 32 rows
+    assert ".e64.m7808.k3072.n1024.bfloat16.r32.gated" in text
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 3 + 3 + 2 + 2 + 2 * moe_layers
+    # only the rows and ONE lane of the chunk reach the head
+    assert "f32[%d,%d]" % (W + 1, model.vocab) in text
+    assert "f32[%d,%d]" % (W + C, model.vocab) not in text
+    assert mem.temp_size_in_bytes < 0.3e9, mem       # no pool or ring copy
+    assert 11.3e9 < planned < 11.6e9, mem

@@ -218,6 +218,45 @@ def test_mixed_stream_fixed_programs_zero_steady_recompiles():
         srv.stop()
 
 
+@pytest.mark.parametrize("ladder,chunks", [((16, 64), (16,)),
+                                           ((8, 16, 64), (8, 16))],
+                         ids=["one_size", "two_sizes"])
+def test_the_window_forms_program_set_is_the_step_and_its_chunks(ladder,
+                                                                  chunks):
+    """The STATE form of a model whose state takes a chunk (a ring of a
+    window's keys: ``serving.window_moe``) has the plain form's program
+    set: ``1 + len(chunks)`` — the step and the mixed step at every rung
+    within twice the ladder's smallest, no prefill at any rung — each
+    compiled once by ``warmup()`` and never again under a mix of prompts
+    shorter than, equal to and several times the window."""
+    from mxnet_tpu.serving.window_moe import WindowMoEDecoderLM, tiny_config
+    compile_watch.enable()
+    model = WindowMoEDecoderLM(**tiny_config(), dtype="float32")
+    params = model.init_params(seed=0)
+    srv = DecodeServer(model, params, seq_ladder=list(ladder),
+                       max_new_tokens=6, window=3, page_size=8,
+                       pool_pages=64, prefix_cache=False, name="ring",
+                       start=False)
+    try:
+        assert srv.warmup() == 1 + len(chunks)
+        warm = compile_watch.site_stats("decode:ring")
+        assert set(warm) == {"decode:ring:step"} | {
+            "decode:ring:step:chunk:c%d" % c for c in chunks}
+        assert all(v["count"] == 1 for v in warm.values())
+        rs = np.random.RandomState(5)
+        sizes = (3, 8, 9, 16, 17, 40, 64, 27)
+        reqs = [srv.submit(rs.randint(1, 96, size=n), max_new_tokens=6)
+                for n in sizes]
+        _drain(srv, *reqs)
+        assert compile_watch.site_stats("decode:ring") == warm
+        st = srv.stats()
+        assert st["completed"] == len(sizes)
+        assert st["prefill_programs"] == 0
+        assert st["chunk_tokens"] == sum(sizes)
+    finally:
+        srv.stop()
+
+
 # ---------------------------------------------------------------------------
 # streaming + cancellation
 # ---------------------------------------------------------------------------

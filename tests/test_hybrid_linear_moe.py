@@ -459,6 +459,58 @@ def test_fixed_program_set_and_what_the_spans_say():
                for a in by_name["decode.readback"])
 
 
+def test_a_recurrence_keeps_its_whole_prompt_prefill():
+    """Who chunks is observed, not named: the pages beside the state can
+    take a chunk (the latent layout's ``chunks``, which the layout around
+    it passes on), but this model does not declare ``chunk_lanes`` — a
+    chunk of a delta rule is another recurrence from the row's state —
+    so its server builds ``_state_prefill_fn`` a rung and NO mixed
+    program, runs one prefill a request, and its step's span carries no
+    chunk. A model of the same class that did declare it would be given
+    the mixed programs: the declaration is the only switch."""
+    from mxnet_tpu import tracing
+    compile_watch.enable()
+    model, params, _ = _model()
+    assert not getattr(model, "chunk_lanes", False)
+    srv = _server(model, params, seq_ladder=[16, 32], max_new_tokens=6,
+                  window=2, pool_pages=16, name="rec")
+    assert srv.pool.layout.chunks and srv.pool.layout.pages.chunks
+    assert srv._chunk_progs == {} and sorted(srv._prefill_progs) == [16, 32]
+    assert all(prog._jitted.__wrapped__.__func__
+               is DecodeServer._state_prefill_fn
+               for prog in srv._prefill_progs.values())
+    st = srv.stats()
+    assert st["chunk"] == 0 and st["chunk_sizes"] == []
+    tracing.enable()
+    try:
+        reqs = [srv.submit(p, max_new_tokens=6)
+                for p in _prompts(11, (5, 30, 17))]
+        _drain(srv, *reqs)
+        said = [e.get("args") or {} for e in tracing.export()["traceEvents"]
+                if e.get("ph") == "X" and e["name"] == "decode.dispatch"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    st = srv.stats()
+    srv.stop()
+    assert st["prefill_programs"] == 3 and st["chunk_steps"] == 0 \
+        == st["chunk_tokens"]
+    assert said and not any("chunk" in a for a in said)
+    assert sorted(compile_watch.site_stats("decode:rec")) == [
+        "decode:rec:prefill:s16", "decode:rec:prefill:s32",
+        "decode:rec:step"]
+
+    class Declares(type(model)):
+        chunk_lanes = True
+
+    twin = Declares(**dict(CFG))
+    other = _server(twin, params, seq_ladder=[16, 32], window=2,
+                    pool_pages=16)
+    assert other.stats()["chunk_sizes"] == [16, 32] \
+        and other._prefill_progs == {}
+    other.stop()
+
+
 # ---------------------------------------------------------------------------
 # what is refused, when the model or the server is built
 # ---------------------------------------------------------------------------

@@ -83,6 +83,23 @@ def _jit_prefill(model):
     return jax.jit(model.prefill)
 
 
+def _pool_for(model, seqs, page_size):
+    """A pool with a row of the state arrays and a run of pages a
+    sequence: ``(its arrays, its layout, the page tables (rows, pages a
+    row))``."""
+    rows = len(seqs)
+    per_row = -(-max(len(t) for t, _ in seqs) // page_size)
+    state, layers = kvcache.declared_state(model)
+    pool = KVCachePool(model.cache_layers,
+                       arrays=[c[:2] for c in model.cache_arrays],
+                       dtype=model.cache_arrays[0][2], page_size=page_size,
+                       n_pages=rows * per_row + 1, state=state,
+                       state_layers=layers, state_rows=rows)
+    assert pool.layout is kvcache.layout_for(model, pool.arrays)
+    return tuple(pool.arrays), pool.layout, 1 + np.arange(
+        rows * per_row, dtype=np.int32).reshape(rows, per_row)
+
+
 def _served_logits(model, params, seqs, page_size=8, slots=None):
     """Logits from the SERVING path for several sequences at once,
     ``seqs = [(tokens, n_prompt), ...]``, one a row of the window: a
@@ -95,19 +112,8 @@ def _served_logits(model, params, seqs, page_size=8, slots=None):
     V))``."""
     rows = len(seqs)
     slots = list(slots or range(rows))
-    longest = max(len(t) for t, _ in seqs)
-    per_row = -(-longest // page_size)
-    state, layers = kvcache.declared_state(model)
-    pool = KVCachePool(model.cache_layers,
-                       arrays=[c[:2] for c in model.cache_arrays],
-                       dtype=model.cache_arrays[0][2], page_size=page_size,
-                       n_pages=rows * per_row + 1, state=state,
-                       state_layers=layers, state_rows=rows)
-    layout = pool.layout
-    assert layout is kvcache.layout_for(model, pool.arrays)
+    pools, layout, tables = _pool_for(model, seqs, page_size)
     n_pages = len(layout.specs)
-    tables = 1 + np.arange(rows * per_row, dtype=np.int32).reshape(
-        rows, per_row)
 
     def prefill(pools, tokens, n_prompt, table, slot):
         rung = -(-n_prompt // page_size) * page_size
@@ -130,7 +136,6 @@ def _served_logits(model, params, seqs, page_size=8, slots=None):
                                  model.use_pallas),
             *new[n_pages:n_pages + len(state.arrays)])
 
-    pools = tuple(pool.arrays)
     # the rows that decode longest come first: the live rows of a step
     # are its first
     order = sorted(range(rows), key=lambda r: len(seqs[r][0]) - seqs[r][1],
@@ -151,6 +156,84 @@ def _served_logits(model, params, seqs, page_size=8, slots=None):
             tails[r].append(np.asarray(logits[r]))
     return [(h, np.stack(t) if t else np.zeros((0, h.shape[1])))
             for h, t in zip(heads, tails)]
+
+
+def _chunked_logits(model, params, seqs, C, page_size=8, slots=None):
+    """:func:`_served_logits` with every prompt fed in CHUNKS of ``C``
+    lanes beside the rows that decode, as ``DecodeServer``'s mixed state
+    program runs them (``_state_decode_fn_chunk``, with the logits of
+    every lane kept): the requests are admitted in order, every step
+    carries the next chunk of the head-most prompt still pending (FIFO,
+    one request's chunk a step) on ``C`` lanes behind the window's rows,
+    the rows whose prompt is in decode a token each — the live rows
+    first, a pending request's row of the rings behind them, not live.
+    Returns what :func:`_served_logits` does, and a request's own row of
+    the rings as its last chunk left it."""
+    rows = len(seqs)
+    slots = list(slots or range(rows))
+    pools, layout, tables = _pool_for(model, seqs, page_size)
+    assert layout.chunks and model.chunk_lanes
+    n_pages, n_state = len(layout.specs), len(layout.state)
+
+    @jax.jit
+    def step(pools, toks, poss, pts, order, n_live, fed, table, start, n,
+             slot):
+        attend = layout.attend_chunk(pools, pts, poss, table, start)
+        state = layout.row_state(pools, order, jnp.arange(rows) < n_live)
+        lanes = jnp.arange(C, dtype=jnp.int32)
+        logits, *new = model.decode(
+            params, jnp.concatenate([toks, fed]),
+            jnp.concatenate([poss, start + lanes]), attend, state,
+            head=jnp.arange(rows + C),
+            live=jnp.concatenate([state.live, lanes < n]),
+            chunk=(slot, start, n))
+        held = tuple(new[n_pages:n_pages + len(state.arrays)])
+        pages = layout.write_tokens(
+            pools, pts, poss, [a[:, :rows] for a in new[:n_pages]],
+            model.use_pallas)
+        pages = layout.write_chunk(pages + held, table, start, n,
+                                   [a[:, rows:] for a in new[:n_pages]])
+        return logits, (*pages, *held)
+
+    fed_to = [0] * rows                   # prompt positions fed, a request
+    done = [n for _t, n in seqs]          # positions run in all
+    heads, tails = [[] for _ in seqs], [[] for _ in seqs]
+    rings = [None] * rows
+    while any(done[r] < len(seqs[r][0]) or fed_to[r] < seqs[r][1]
+              for r in range(rows)):
+        pending = [r for r in range(rows) if fed_to[r] < seqs[r][1]]
+        decoding = [r for r in range(rows) if fed_to[r] == seqs[r][1]
+                    and done[r] < len(seqs[r][0])]
+        order = decoding + [r for r in range(rows) if r not in decoding]
+        toks = np.zeros((rows,), np.int32)
+        poss = np.zeros((rows,), np.int32)
+        pts = np.zeros_like(tables)
+        for i, r in enumerate(decoding):
+            toks[i], poss[i], pts[i] = seqs[r][0][done[r]], done[r], \
+                tables[r]
+        fed = np.zeros((C,), np.int32)
+        start = n = 0
+        r = pending[0] if pending else order[-1]
+        if pending:
+            start = fed_to[r]
+            n = min(C, seqs[r][1] - start)
+            fed[:n] = seqs[r][0][start:start + n]
+        logits, pools = step(
+            pools, toks, poss, pts,
+            np.asarray([slots[o] for o in order], np.int32), len(decoding),
+            fed, tables[r], start, n, slots[r])
+        for i, d in enumerate(decoding):
+            tails[d].append(np.asarray(logits[i]))
+            done[d] += 1
+        if pending:
+            heads[r].append(np.asarray(logits[rows:rows + n]))
+            fed_to[r] += n
+            if fed_to[r] == seqs[r][1]:
+                rings[r] = [np.asarray(a[:, slots[r]])
+                            for a in pools[-n_state:]]
+    return [(np.concatenate(h), np.stack(t) if t
+             else np.zeros((0, h[0].shape[1])))
+            for h, t in zip(heads, tails)], rings
 
 
 def _reference(params, tokens, cfg, held, control=None, **over):
@@ -211,6 +294,71 @@ def test_the_pallas_path_is_the_reference_at_every_position(n_prompt):
     tokens = _tokens(n_prompt, n_prompt + 36)
     err, = _against_reference(model, params, cfg, [(tokens, n_prompt)],
                               page_size=128)
+    assert err.max() < LOGIT_TOLERANCE, err
+
+
+@pytest.mark.parametrize("n_prompt,C", [
+    (5, 16), (W, 16), (3 * W + 3, 16), (5 * W, 16), (16, 16), (3 * W + 3, W),
+    (5 * W, W), (3 * W + 3, 3)],
+    ids=["shorter-C2W", "equal-C2W", "3x-C2W", "5x-C2W", "one_chunk-C2W",
+         "3x-CisW", "5x-CisW", "3x-CunderW"])
+def test_chunks_then_decode_are_the_reference_at_every_position(n_prompt, C):
+    """The prompt through the mixed step's chunk lanes — shorter than,
+    equal to and 3-5 times the window, a length that is no multiple of
+    the chunk and one that is, ``C`` twice the window, the window, and
+    under it — then 30 decoded positions: EVERY position's logits are the
+    reference's, and the whole-prompt prefill's (the oracle), and the
+    rings the chunks leave are the rings the prefill writes, slot for
+    slot, wherever a position has reached."""
+    model, params, cfg = _model()
+    tokens = _tokens(n_prompt, n_prompt + 30)
+    ((head, tail),), (rings,) = _chunked_logits(
+        model, params, [(tokens, n_prompt)], C)
+    want = _reference(params, tokens, cfg, model.held)
+    err = _worst(np.concatenate([head, tail]), want)
+    assert len(err) == len(tokens) and err.max() < LOGIT_TOLERANCE, err
+    (o_head, o_tail), = _served_logits(model, params, [(tokens, n_prompt)])
+    assert _worst(head, o_head).max() < LOGIT_TOLERANCE
+    assert _worst(tail, o_tail).max() < LOGIT_TOLERANCE
+    # the rings as the last chunk left them, against the prefill's
+    padded = np.zeros((1, 64), np.int32)
+    padded[0, :n_prompt] = tokens[:n_prompt]
+    whole = _jit_prefill(model)(params, padded, jnp.asarray([n_prompt]))[3:]
+    reached = np.arange(W) < n_prompt
+    for got, want_ring in zip(rings, whole):
+        assert np.abs(got - np.asarray(want_ring[:, 0]))[
+            :, reached].max() < 1e-5
+
+
+def test_chunks_beside_rows_on_both_sides_of_the_window():
+    """Four requests admitted in order over a window of four, each on a
+    ring of its own (the slots a permutation): while one prompt's chunks
+    ride the step, the prompts before it decode — a row well past the
+    window, one that crosses its edge mid-answer, one that never reaches
+    it — and the prompt behind it waits, its row of the rings not live.
+    Every position of every request is the reference's."""
+    model, params, cfg = _model()
+    seqs = [(_tokens(10, 4 * W + 22), 4 * W), (_tokens(11, 3 + 20), 3),
+            (_tokens(12, W + 12), W), (_tokens(13, 2 * W + 5 + 4), 2 * W + 5)]
+    served, _ = _chunked_logits(model, params, seqs, 16, slots=[2, 0, 3, 1])
+    for (tokens, _n), (head, tail) in zip(seqs, served):
+        err = _worst(np.concatenate([head, tail]),
+                     _reference(params, tokens, cfg, model.held))
+        assert err.max() < LOGIT_TOLERANCE, err
+
+
+def test_the_pallas_path_through_chunks_is_the_reference():
+    """The same through the kernels (interpreted): the offset banded
+    grouped forward over ``[ring ; chunk]`` with 9 query heads a key head
+    and a window of 128, ``mx_ring_decode`` and the paged block kernel for
+    the rows beside it; a prompt of 300 wraps the ring twice, in chunks of
+    128 lanes."""
+    model, params, cfg = _model(use_pallas=True)
+    tokens = _tokens(300, 300 + 12)
+    (head, tail), = _chunked_logits(model, params, [(tokens, 300)], 128,
+                                    page_size=128)[0]
+    err = _worst(np.concatenate([head, tail]),
+                 _reference(params, tokens, cfg, model.held))
     assert err.max() < LOGIT_TOLERANCE, err
 
 
@@ -504,6 +652,89 @@ def test_grouped_forward_kernel_is_its_jnp_composition(heads, window):
         re.findall(r"grid=\([^)]*\)", jaxpr)
 
 
+def _dense_window_attention(q, k, v, window):
+    """Sliding-window attention over a whole sequence by a masked
+    softmax, in float64: ``q (T, Hq, D)``, ``k``/``v (T, Hkv, D)``."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    T, Hq, D = q.shape
+    G = Hq // k.shape[1]
+    s = np.einsum("qhd,khd->hqk", q, np.repeat(k, G, axis=1)) / np.sqrt(D)
+    at = np.arange(T)
+    seen = (at[None, :] <= at[:, None]) & (at[None, :] > at[:, None] - window)
+    s = np.where(seen, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True),
+                     np.repeat(v, G, axis=1))
+
+
+@pytest.mark.parametrize("path,D,Wd,C,T", [
+    ("jnp", 16, 8, 16, 5), ("jnp", 16, 8, 16, 8), ("jnp", 16, 8, 16, 29),
+    ("jnp", 16, 8, 8, 27), ("jnp", 16, 8, 8, 40), ("jnp", 16, 8, 3, 22),
+    ("pallas", 128, 128, 128, 300), ("pallas", 128, 128, 256, 300),
+    ("pallas", 128, 128, 64, 200), ("pallas", 128, 128, 128, 100)],
+    ids=["jnp-C2W-shorter", "jnp-C2W-equal", "jnp-C2W-3x", "jnp-CisW-3x",
+         "jnp-CisW-5x", "jnp-CunderW", "pallas-CisW", "pallas-C2W",
+         "pallas-CunderW", "pallas-shorter"])
+def test_ring_chunk_is_the_window_over_the_whole_prompt(path, D, Wd, C, T):
+    """A prompt of ``T`` positions fed ``C`` lanes a call through
+    ``ring_chunk`` (the banded grouped forward with the queries offset
+    behind the ring's ``W`` keys; Pallas interpreted, or its ``jnp``
+    composition), the last chunk short: every position's output is the
+    masked softmax over the whole prompt, whatever ``C`` is against
+    ``W``; the row's ring holds, after each call, the last ``W`` positions
+    in slots ``t % W`` and what no position reached is what the slot's
+    last tenant left — 1e4 times a key's size, so one stale key read
+    would show; no other row, no other layer is touched; and the kernel
+    is its composition."""
+    Hkv, G, rows, layer, slot = 2, 3, 3, 1, 2
+    keys = jax.random.split(jax.random.PRNGKey(T + C), 5)
+    q = jax.random.normal(keys[0], (T, Hkv * G, D))
+    k = jax.random.normal(keys[1], (T, Hkv, D))
+    v = jax.random.normal(keys[2], (T, Hkv, D))
+    stale_k = 1e4 * jax.random.normal(keys[3], (2, rows, Wd, Hkv * D))
+    stale_v = 1e4 * jax.random.normal(keys[4], (2, rows, Wd, Hkv * D))
+    want = _dense_window_attention(q, k, v, Wd)
+    ring_k, ring_v = stale_k, stale_v
+    run = jax.jit(functools.partial(fa.ring_chunk, layer=layer,
+                                    force_pallas=path == "pallas"))
+    for start in range(0, T, C):
+        n = min(C, T - start)
+
+        def lanes(a):
+            return jnp.zeros((C,) + a.shape[1:]).at[:n].set(
+                a[start:start + n])
+
+        args = (lanes(q), lanes(k), lanes(v), ring_k, ring_v)
+        how = dict(slot=jnp.int32(slot), start=jnp.int32(start),
+                   n_live=jnp.int32(n))
+        out, ring_k, ring_v = run(*args, **how)
+        assert np.abs(np.asarray(out[:n]) - want[start:start + n]).max() \
+            < 2e-5, (start, n)
+        if path == "pallas":
+            comp = fa.ring_chunk(*args, layer=layer, **how)
+            assert np.abs(np.asarray(out[:n] - comp[0][:n])).max() < 2e-5
+            assert bool((comp[1] == ring_k).all()) \
+                and bool((comp[2] == ring_v).all())
+        # the ring after ``start + n`` positions, slot by slot
+        for ring, stale, seq in ((ring_k, stale_k, k), (ring_v, stale_v, v)):
+            for s_ in range(Wd):
+                held = [t for t in range(start + n) if t % Wd == s_]
+                expect = np.asarray(seq[held[-1]]).reshape(-1) if held \
+                    else np.asarray(stale[layer, slot, s_])
+                assert (np.asarray(ring[layer, slot, s_]) == expect).all(), \
+                    (start, s_)
+            others = np.ones((2, rows), bool)
+            others[layer, slot] = False
+            assert (np.asarray(ring)[others] == np.asarray(stale)[others]) \
+                .all()
+    if path == "pallas":
+        jaxpr = str(jax.make_jaxpr(lambda *a: fa.ring_chunk(
+            *a, layer=layer, force_pallas=True, **how))(*args))
+        assert "mx_grouped_fwd.bh%d.q%d.k%d.d128.float32.kv%d.w%d.o%d" % (
+            Hkv * G, -(-C // 128) * 128, -(-(Wd + C) // 128) * 128, Hkv,
+            Wd, Wd) in jaxpr
+
+
 def test_grouped_forward_refuses_what_it_is_not_written_for():
     q = jnp.zeros((1, 16, 4, 8))
     kv = jnp.zeros((1, 16, 2, 8))
@@ -533,14 +764,16 @@ def _is_greedy(params, cfg, held, prompt, served, length=96):
 def test_served_streams_are_the_references_greedy_streams():
     """Short and long prompts in one queue over a two-rung ladder, more
     requests than rows: every stream is the reference's greedy stream,
-    the program set is one step and a prefill a rung, and the spans say
-    which slot a prefill writes and what the step counted."""
+    the program set is one step and one mixed step (no prefill: a ring
+    takes a chunk), ``stats()`` counts what rode, and the spans say whose
+    chunk a step carried, how many rows of the rings were live and what
+    the step counted."""
     from mxnet_tpu import tracing
     compile_watch.enable()
     model, params, cfg = _model()
     prompts = [_tokens(s, n) for s, n in enumerate((5, 40, 9, 33, 60, 3))]
     srv = _server(model, params, name="win")
-    assert srv.warmup() == 3
+    assert srv.warmup() == 2
     tracing.enable()
     try:
         reqs = [srv.submit(p, max_new_tokens=12) for p in prompts]
@@ -551,14 +784,17 @@ def test_served_streams_are_the_references_greedy_streams():
         tracing.disable()
         tracing.reset()
     sites = compile_watch.site_stats("decode:win")
-    assert sorted(sites) == ["decode:win:prefill:s16",
-                             "decode:win:prefill:s64", "decode:win:step"]
+    assert sorted(sites) == ["decode:win:step", "decode:win:step:chunk:c16"]
     assert all(site["count"] == 1 for site in sites.values())
     st = srv.stats()
     srv.stop()
     for p, r in zip(prompts, reqs):
         assert _is_greedy(params, cfg, model.held, p,
                           [int(t) for t in r.result()])
+    assert st["prefill_programs"] == 0 == st["prefill_steps"]
+    assert st["chunk_sizes"] == [16]
+    assert st["chunk_tokens"] == sum(len(p) for p in prompts)
+    assert st["chunk_steps"] == sum(-(-len(p) // 16) for p in prompts)
     # the window layers hold no pages: the pool's layers are the full
     # ones, the rings are state
     assert st["kv"]["arrays"]["k"][0] == model.cache_layers == 2
@@ -568,21 +804,129 @@ def test_served_streams_are_the_references_greedy_streams():
     assert st["kv"]["token_bytes"] == 2 * 2 * 32 * 4
     counted = st["moe"]
     assert counted["ring_rows_wrapped"] > 0 and counted["ring_bytes"] > 0
-    assert counted["global_pages_live"] >= counted["steps"]
     by_name = {}
     for sp in spans:
         by_name.setdefault(sp["name"], []).append(sp.get("args") or {})
-    assert len(by_name["decode.prefill"]) == 6
-    assert all(0 <= a["state_slot"] < 4 and a["rung"] in (16, 64)
-               for a in by_name["decode.prefill"])
+    assert "decode.prefill" not in by_name
+    ids = {r.request_id: len(p) for p, r in zip(prompts, reqs)}
+    fed = {}
+    for said in by_name["decode.dispatch"]:
+        # a mixed step's span carries all three; the chunk's request is
+        # none of the live rows (at most three of four while it is fed)
+        assert 0 <= said["state_rows_live"] <= 4
+        if "chunk" in said:
+            assert 1 <= said["chunk"] <= 16 and said["chunk_of"] in ids
+            assert said["state_rows_live"] <= 3
+            fed[said["chunk_of"]] = fed.get(said["chunk_of"], 0) \
+                + said["chunk"]
+    assert fed == ids
     for said in by_name["decode.readback"]:
         live = said["state_rows_live"]
         assert 0 <= said["ring_rows_wrapped"] <= live <= 4
         assert live <= said["global_pages_live"] <= live * 9
         # what the rings' visible keys and values weigh: at most W of
-        # them a live row, K and V of 2 x 16 float32, 3 sliding layers
-        assert 0 < said["ring_bytes"] <= live * W * 3 * 2 * 32 * 4
+        # them a live row, K and V of 2 x 16 float32, 3 sliding layers —
+        # of the rows that DECODE: a chunk's lanes are not in it
+        assert bool(live) == bool(said["ring_bytes"])
+        assert said["ring_bytes"] <= live * W * 3 * 2 * 32 * 4
         assert said["ring_bytes"] % (3 * 2 * 32 * 4) == 0
+
+
+class _WholePrompt(WindowMoEDecoderLM):
+    """The same model, not declaring that its state takes a chunk: its
+    server keeps the whole-prompt prefill (the oracle of the chunks)."""
+    chunk_lanes = False
+
+
+@pytest.mark.parametrize("ladder,chunks", [
+    ((16, 64), [16]), ((8, 32, 64), [8]), ((8, 16, 64), [8, 16])],
+    ids=["C_twice_W", "C_is_W", "two_sizes"])
+def test_served_tokens_through_chunks_are_the_prefill_paths(ladder, chunks):
+    """Prompts shorter than, equal to and 3-5 times the window, lengths
+    that are and are not multiples of the chunk, more requests than rows,
+    over chunks twice the window, of the window, and of two sizes: token
+    for token what the SAME model serves through the whole-prompt prefill
+    and the step (a server of a model that does not declare
+    ``chunk_lanes``), and the reference's greedy stream."""
+    sizes = (5, W, 3 * W + 3, 5 * W, 16, 3, 33, 2 * W)
+    prompts = [_tokens(40 + s, n) for s, n in enumerate(sizes)]
+    served = {}
+    for cls in (WindowMoEDecoderLM, _WholePrompt):
+        model, params, cfg = _model(cls=cls)
+        srv = _server(model, params, seq_ladder=list(ladder), window=3)
+        assert srv.stats()["chunk_sizes"] == \
+            (chunks if cls is WindowMoEDecoderLM else [])
+        reqs = [srv.submit(p, max_new_tokens=14) for p in prompts]
+        _drain(srv, *reqs)
+        st = srv.stats()
+        srv.stop()
+        assert st["completed"] == len(prompts)
+        if cls is _WholePrompt:
+            assert st["prefill_programs"] == len(prompts)
+            assert st["chunk_tokens"] == 0
+        else:
+            assert st["prefill_programs"] == 0
+            assert st["chunk_tokens"] == sum(sizes)
+        served[cls] = [[int(t) for t in r.result()] for r in reqs]
+    assert served[WindowMoEDecoderLM] == served[_WholePrompt]
+    for p, got in zip(prompts, served[WindowMoEDecoderLM]):
+        assert _is_greedy(params, cfg, model.held, p, got)
+
+
+def test_a_chunks_request_is_no_live_row_of_its_step():
+    """One row decodes; behind it a prompt of 20 rides two chunks (16 and
+    4 lanes) and a third request waits for its turn. Every row of the
+    rings is set to a sentinel first. In the mixed steps the span says ONE
+    row of the rings is live; the fed request's row changes by the chunk's
+    writes alone — after the first chunk it is what the model's own
+    whole-prompt prefill of 16 positions leaves, after the second the
+    slots of positions 16-19 moved on and the other four stayed — and the
+    waiting request's row keeps the sentinel in every slot (a dummy lane
+    at position 0, were the row live, would have written slot 0)."""
+    from mxnet_tpu import tracing
+    model, params, _ = _model()
+    srv = _server(model, params)
+    first = srv.submit(_tokens(1, 5), max_new_tokens=30)
+    while not first.generated:
+        srv._tick()
+    n_pages = len(srv.pool.layout.specs)
+    for i in range(n_pages, len(srv.pool.arrays)):
+        srv.pool.arrays[i] = jnp.full_like(srv.pool.arrays[i], 7.0)
+    fed_prompt = _tokens(2, 20)
+    fed = srv.submit(fed_prompt, max_new_tokens=4)
+    waiting = srv.submit(_tokens(3, 6), max_new_tokens=4)
+
+    def whole(n):
+        padded = np.zeros((1, 64), np.int32)
+        padded[0, :n] = fed_prompt[:n]
+        return [np.asarray(a[:, 0]) for a in _jit_prefill(model)(
+            params, padded, jnp.asarray([n]))[3:]]
+
+    tracing.enable()
+    try:
+        srv._tick()                       # admits ``fed``: chunk of 16
+        srv._tick()                       # admits ``waiting``: chunk of 4
+        assert fed.slot is not None and waiting.slot is not None
+        rings = [np.asarray(a) for a in srv.pool.arrays[n_pages:]]
+        said = [e["args"] for e in tracing.export()["traceEvents"]
+                if e.get("ph") == "X" and e["name"] == "decode.dispatch"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    assert [(a["chunk"], a["chunk_of"], a["state_rows_live"])
+            for a in said] == [(16, fed.request_id, 1),
+                               (4, fed.request_id, 1)]
+    after_16, after_20 = whole(16), whole(20)
+    for ring, a16, a20 in zip(rings, after_16, after_20):
+        assert np.abs(ring[:, fed.slot] - a20).max() < 1e-5
+        # positions 12-15 lie where the first chunk put them
+        assert np.abs(ring[:, fed.slot, 4:] - a16[:, 4:]).max() < 1e-5
+        assert (ring[:, waiting.slot] == 7.0).all()
+        free = [r for r in range(4)
+                if r not in (first.slot, fed.slot, waiting.slot)]
+        assert (ring[:, free] == 7.0).all()
+    _drain(srv, fed, waiting)
+    srv.stop()
 
 
 def test_a_slots_second_tenant_reads_nothing_of_the_first():
