@@ -65,3 +65,33 @@ def ragged_pages():
         assert page == 10
         return table, positions
     return build
+
+
+# the chip-compile files (tests/test_chip_compile*.py)
+
+@pytest.fixture(scope="module")
+def chip():
+    """A SingleDeviceSharding on one described v5e chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:                       # no libtpu here
+        pytest.skip("cannot describe a v5e topology: %s" % exc)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def _persistent_cache_off():
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it out (of
+    # every case of the chip-compile files, by their ``pytestmark``)
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()

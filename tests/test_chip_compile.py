@@ -21,31 +21,7 @@ import mxnet_tpu.parallel  # noqa: F401 — the package re-exports the
 fa = sys.modules["mxnet_tpu.parallel.flash_attention"]  # function
 
 
-@pytest.fixture(scope="module")
-def chip():
-    """A SingleDeviceSharding on one described v5e chip."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as exc:                       # no libtpu here
-        pytest.skip("cannot describe a v5e topology: %s" % exc)
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(autouse=True)
-def _persistent_cache_off():
-    # a compile for a described device is written to the persistent
-    # cache but cannot be read back without a chip: keep it out
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
+pytestmark = pytest.mark.usefixtures("_persistent_cache_off")
 
 B, H, D = 4, 8, 128
 SCALE = 1.0 / np.sqrt(D)
@@ -311,90 +287,6 @@ def test_mixed_step_program_compiles_and_fits(chip, monkeypatch, config, C):
     assert 0 < total < 14.5e9, mem
 
 
-def test_latent_moe_programs_compile_and_fit(chip, monkeypatch):
-    """``benchmark/configs/dots.vlm1.inst.json`` at its published widths
-    (7168 wide, 128 heads of 128+64 / 128, ranks 1536 and 512, experts
-    of 2048, 16 of 256 held, 1 dense + 5 expert layers, window 64, 768
-    bf16 pages of 128 x 640): the ``decode:step`` and the 256-rung
-    ``decode:prefill`` programs compiled for one described v5e. In each:
-    the Mosaic kernels under the names a profile's reader looks for —
-    the paged latent decode kernel and the in-place row write (step),
-    the flash kernel at 256-wide heads (prefill), the two grouped
-    matmuls of every expert layer — the planned bytes inside the chip
-    with room for the reference that decides ``correct`` beside the
-    weights, the donated pool updated in place and NO copy of it among
-    the temporaries (declared 576 wide, XLA laid the pool out token-minor
-    and copied 0.68 GB three times a step; written by XLA's own row
-    writes, it moved the layer axis next to the lanes and copied twice)."""
-    from mxnet_tpu.serving import DecodeServer
-    from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "dots.vlm1.inst.json")) as f:
-        cfg = json.load(f)
-    srv = cfg["server"]["kwargs"]
-    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
-    rung = max(srv["seq_ladder"])
-    M = -(-(rung + srv["max_new_tokens"]) // S)
-    model = LatentMoEDecoderLM(**cfg["model"]["kwargs"])
-    assert model.held == (0, 16) and model.row_width == 640
-    L, moe_layers = model.n_layers, model.n_moe_layers
-    params = jax.eval_shape(lambda: model.init_params(seed=0))
-    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                  for a in params.values())
-    assert 10.9e9 < weights < 11.1e9
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
-    pool = spec((L, pages, S, model.row_width), jnp.bfloat16)
-    pool_bytes = L * pages * S * model.row_width * 2
-    holder = _step_holder(model)
-    n_counts = len(model.step_counters[1])
-
-    def named(text, kernel):
-        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
-                          % kernel, text, re.M)
-
-    step = jax.jit(lambda *a: DecodeServer._decode_fn(holder, *a),
-                   donate_argnums=(6,)).lower(
-        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
-        spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
-        spec((W,), jnp.int32), pool).compile()
-    text = step.as_text()
-    assert len(named(text, "mla_decode")) == L
-    assert ".k%d.d%d.bfloat16.r%d.paged" % (
-        M * S, model.row_width, model.kv_rank) in text
-    assert len(named(text, "latent_write")) == 1
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    # a step's 32 slots an expert keep the 16-row tiles they were drawn for
-    assert ".e16.m768.k7168.n2048.bfloat16.r16.gated" in text
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == L + 1 + 2 * moe_layers
-    mem = step.memory_analysis()
-    assert mem.alias_size_in_bytes >= pool_bytes, mem
-    assert mem.temp_size_in_bytes < 0.1e9, mem      # no pool copy
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert 11.5e9 < planned < 12.5e9, mem
-
-    prefill = jax.jit(lambda *a: DecodeServer._prefill_fn(holder, *a),
-                      donate_argnums=(4,)).lower(
-        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
-        spec((M,), jnp.int32), pool).compile()
-    text = prefill.as_text()
-    assert len(named(text, "flash_fwd")) == L
-    assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    mem = prefill.memory_analysis()
-    assert mem.alias_size_in_bytes >= pool_bytes, mem
-    assert mem.temp_size_in_bytes < 0.5e9, mem
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert planned < 12.5e9, mem
-
-
 def test_block_diffusion_programs_compile_and_fit(chip, monkeypatch):
     """``benchmark/configs/SDAR-30B-A3B-Chat.json`` at its published
     widths (2048 wide, 32 query heads over 4 key/value heads of 128, 128
@@ -495,93 +387,6 @@ def test_block_diffusion_programs_compile_and_fit(chip, monkeypatch):
     assert planned < 11.1e9, mem
 
 
-def test_speculative_latent_programs_compile_and_fit(chip, monkeypatch):
-    """``benchmark/configs/Xing4.0-29B-A4B.json`` at its published widths
-    (3584 wide in 4 residual streams, 32 heads of 128+64 / 128, ranks 768
-    and 512, all 64 experts of 1024, 131,072 rows, 1 dense + 5 expert
-    layers and the next-token module's block, window 64, 768 bf16 pages
-    of 128 x 640 in 7 cache layers): the ONE speculative step program
-    and the 256-rung prefill compiled for one described v5e. In the
-    step: the two-query paged latent kernel a block (64 = 2 x 32 query
-    rows against a page in one product) under the name a profile's
-    reader looks for, ONE in-place write of both new rows over all 7
-    cache layers, the two grouped matmuls of every expert layer, the
-    module's among them; the planned bytes inside the chip with room
-    for the reference that decides ``correct`` beside the weights, the
-    donated pool updated in place and NO copy of it among the
-    temporaries. The table covers the two positions a step dispatched
-    ahead may write past a row's budget: 11 pages, not 10."""
-    from mxnet_tpu.serving import DecodeServer
-    from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "Xing4.0-29B-A4B.json")) as f:
-        cfg = json.load(f)
-    srv = cfg["server"]["kwargs"]
-    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
-    rung = max(srv["seq_ladder"])
-    M = -(-(rung + srv["max_new_tokens"] + 2) // S)
-    model = LatentMoEDecoderLM(**cfg["model"]["kwargs"])
-    assert model.held == (0, 64) and model.row_width == 640
-    assert (model.hc, model.draft_length, model.cache_layers) == (4, 1, 7)
-    L, moe_layers, H = model.cache_layers, model.n_moe_layers, model.n_heads
-    params = jax.eval_shape(lambda: model.init_params(seed=0))
-    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                  for a in params.values())
-    assert 11.1e9 < weights < 11.2e9
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
-    pool = spec((L, pages, S, model.row_width), jnp.bfloat16)
-    pool_bytes = L * pages * S * model.row_width * 2
-    holder = type("S", (), {"_model": model, "_window": W,
-                            "_counters": model.step_counters})()
-    n_counts = len(model.step_counters[1])
-
-    def named(text, kernel):
-        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
-                          % kernel, text, re.M)
-
-    step = jax.jit(lambda *a: DecodeServer._spec_decode_fn(holder, *a),
-                   donate_argnums=(6,)).lower(
-        tree, spec((W, 2), jnp.int32), spec((W,), jnp.int32),
-        spec((W, M), jnp.int32), spec((W * 5 + n_counts,), jnp.int32),
-        spec((W,), jnp.int32), pool).compile()
-    text = step.as_text()
-    assert len(named(text, "mla_decode")) == L
-    assert ".bh%d.q2.k%d.d%d.bfloat16.r%d.paged" % (
-        W * 2 * H, M * S, model.row_width, model.kv_rank) in text
-    assert len(named(text, "latent_write")) == 1
-    assert "mx_latent_write.b%d.q2.l%d.s%d" % (W, L, S) in text
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    assert ".e64.m1536.k3584.n1024.bfloat16.r16.gated" in text
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == L + 1 + 2 * moe_layers
-    mem = step.memory_analysis()
-    assert mem.alias_size_in_bytes >= pool_bytes, mem
-    assert mem.temp_size_in_bytes < 0.15e9, mem      # no pool copy
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert 11.9e9 < planned < 12.4e9, mem
-
-    prefill = jax.jit(lambda *a: DecodeServer._spec_prefill_fn(holder, *a),
-                      donate_argnums=(4,)).lower(
-        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
-        spec((M,), jnp.int32), pool).compile()
-    text = prefill.as_text()
-    assert len(named(text, "flash_fwd")) == L
-    assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    mem = prefill.memory_analysis()
-    assert mem.alias_size_in_bytes >= pool_bytes, mem
-    assert mem.temp_size_in_bytes < 0.5e9, mem
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert planned < 12.5e9, mem
-
-
 def _walk_calls(M, layer=1):
     """The three paged MXU kernels at a window of 8 rows and a table
     ``M`` columns wide, reading ``layer``, as (function, arguments)
@@ -649,213 +454,3 @@ def test_paged_mxu_kernels_take_one_grid_step_a_row(kernel):
     inner = [e.params["jaxpr"] for e in both.eqns
              if "jaxpr" in e.params and _pallas_calls(e.params["jaxpr"].jaxpr)]
     assert len(inner) == 2 and inner[0] is inner[1]
-
-
-def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
-    """``benchmark/configs/Ling-3.0-flash.json`` at its published widths
-    (2560 wide, 32 heads of 128, five delta-rule linear-attention layers
-    whose state is 32 x 128 x 128 float32 a row beside ONE latent layer of
-    rank 512, experts of 768, 128 of 512 held, 1 dense + 5 expert layers,
-    window 64, 1,152 bf16 pages of 128 x 640 in one cache layer): the
-    state form's ``decode:step`` and 1024-rung ``decode:prefill`` programs
-    compiled for one described v5e. In each: the Mosaic kernels under the
-    names a profile's reader looks for — the delta-rule step a linear
-    layer, the paged latent decode kernel and the in-place row write
-    (step), the flash kernel at 256-wide heads (prefill), the two grouped
-    matmuls of every expert layer — the planned bytes inside the chip
-    with room for the reference that decides ``correct`` beside the
-    weights, the donated pool AND the donated state arrays updated in
-    place, and NO copy of either among the temporaries (the state is 0.67
-    GB: one copy of it a layer would double the step)."""
-    from mxnet_tpu.serving import DecodeServer
-    from mxnet_tpu.serving.hybrid_linear_moe import HybridLinearMoEDecoderLM
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "Ling-3.0-flash.json")) as f:
-        cfg = json.load(f)
-    srv = cfg["server"]["kwargs"]
-    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
-    rung = max(srv["seq_ladder"])
-    M = -(-(rung + srv["max_new_tokens"]) // S)
-    model = HybridLinearMoEDecoderLM(**cfg["model"]["kwargs"])
-    assert model.held == (0, 128) and model.row_width == 640
-    assert (model.cache_layers, model.state_layers, model.chunk) \
-        == (1, 5, 16)
-    H, d, moe_layers = model.n_heads, model.head_dim, model.n_moe_layers
-    params = jax.eval_shape(lambda: model.init_params(seed=0))
-    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                  for a in params.values())
-    assert 8.70e9 < weights < 8.75e9
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
-    carried = (
-        spec((1, pages, S, model.row_width), jnp.bfloat16),
-        spec((5, W, H, d, d), jnp.float32),
-        spec((5, W, 3 * 3 * H * d), jnp.bfloat16))
-    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                        for a in carried)
-    assert 0.88e9 < carried_bytes < 0.89e9
-    holder = type("S", (), {"_model": model, "_window": W})()
-    n_counts = len(model.step_counters[1])
-
-    def named(text, kernel):
-        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
-                          % kernel, text, re.M)
-
-    step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
-                   donate_argnums=(8, 9, 10)).lower(
-        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
-        spec((W,), jnp.int32), spec((), jnp.int32), spec((W, M), jnp.int32),
-        spec((W + n_counts,), jnp.int32), spec((W,), jnp.int32),
-        *carried).compile()
-    text = step.as_text()
-    assert len(named(text, "kda_step")) == 5
-    assert "mx_kda_step.b%d.h%d.d%d" % (W, H, d) in text
-    assert len(named(text, "mla_decode")) == 1
-    assert len(named(text, "latent_write")) == 1
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    assert ".e128.m2560.k2560.n768.bfloat16.r16.gated" in text
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == 5 + 2 + 2 * moe_layers
-    mem = step.memory_analysis()
-    assert mem.alias_size_in_bytes >= carried_bytes, mem
-    assert mem.temp_size_in_bytes < 0.1e9, mem      # no state or pool copy
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert 9.5e9 < planned < 9.8e9, mem
-
-    prefill = jax.jit(lambda *a: DecodeServer._state_prefill_fn(holder, *a),
-                      donate_argnums=(5, 6, 7)).lower(
-        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
-        spec((M,), jnp.int32), spec((), jnp.int32), *carried).compile()
-    text = prefill.as_text()
-    assert len(named(text, "flash_fwd")) == 1
-    assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    # the rung's 64 slots an expert take tiles of 32 rows
-    assert ".e128.m12288.k2560.n768.bfloat16.r32.gated" in text
-    # the chunkwise rule's walk: one loop a linear layer, carrying S
-    assert len(re.findall(r"%while[.\d]* = \(s32\[\][^,]*, "
-                          r"f32\[1,32,128,128\]", text)) == 5
-    mem = prefill.memory_analysis()
-    assert mem.alias_size_in_bytes >= carried_bytes, mem
-    assert mem.temp_size_in_bytes < 0.5e9, mem
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert planned < 10.2e9, mem
-
-
-@pytest.mark.parametrize("program", ["step", "chunk"])
-def test_window_moe_programs_compile_and_fit(chip, monkeypatch, program):
-    """``benchmark/configs/Laguna-S-2.1.json`` at its published widths
-    (3072 wide, 48 and 72 gated query heads over 8 key/value heads of
-    128, three sliding-window layers whose last 512 keys and values are a
-    ring a row beside two full-attention layers' packed pages, experts of
-    1024, 64 of 256 held, 1 dense + 4 expert layers, window 64, 4,608
-    bf16 pages of 128 x 1024 in two cache layers): the state form's
-    ``decode:step`` and its MIXED step ``decode:step:chunk:c512`` — 64
-    lanes that decode and 512 that are one prompt's chunk; since PR 46
-    this server runs no prefill program, and the case that compiled its
-    512- and 8192-rung prefills compiles this — for one described v5e. In
-    each: the Mosaic kernels under the names a profile's reader looks for
-    — the ring decode a sliding layer (9 query heads a key head), the
-    paged block kernel a full layer (6) and its in-place row write, the
-    two grouped matmuls of every expert layer and, in the mixed step, the
-    banded grouped forward with its queries offset behind the ring's 512
-    keys, a sliding layer — the planned bytes inside the chip, the
-    donated pool AND the donated rings updated in place, and NO copy of
-    either among the temporaries (the rings are 0.4 GB, the pool 4.8 GB).
-    The mixed step's temporaries: 0.20 GB found (the 8192-rung prefill it
-    replaces planned 2.5 GB), held under 0.3."""
-    from mxnet_tpu.serving import DecodeServer, WindowMoEDecoderLM
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "Laguna-S-2.1.json")) as f:
-        cfg = json.load(f)
-    srv = cfg["server"]["kwargs"]
-    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
-    M = -(-(max(srv["seq_ladder"]) + srv["max_new_tokens"]) // S)
-    # the ladder 512 / 8192 gives one mixed program, of the first rung
-    C, = [r for r in sorted(srv["seq_ladder"])
-          if r <= 2 * min(srv["seq_ladder"])]
-    assert C == 512
-    model = WindowMoEDecoderLM(**cfg["model"]["kwargs"])
-    assert model.held == (0, 64) and model.heads == (48, 72, 72, 72, 48)
-    assert (model.cache_layers, model.state_layers) == (2, 3)
-    assert model.chunk_lanes and model.window == 512
-    moe_layers = model.n_moe_layers
-    params = jax.eval_shape(lambda: model.init_params(seed=0))
-    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                  for a in params.values())
-    assert 6.00e9 < weights < 6.02e9
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
-    carried = (
-        spec((2, pages, S, 1024), jnp.bfloat16),
-        spec((2, pages, S, 1024), jnp.bfloat16),
-        spec((3, W, 512, 1024), jnp.bfloat16),
-        spec((3, W, 512, 1024), jnp.bfloat16))
-    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                        for a in carried)
-    assert 5.23e9 < carried_bytes < 5.24e9
-    holder = type("S", (), {"_model": model, "_window": W,
-                            "_max_pages": M})()
-    n_counts = len(model.step_counters[1])
-
-    def named(text, kernel):
-        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
-                          % kernel, text, re.M)
-
-    feed = (tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
-            spec((W,), jnp.int32), spec((), jnp.int32),
-            spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
-            spec((W,), jnp.int32))
-    if program == "step":
-        step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
-                       donate_argnums=(8, 9, 10, 11)).lower(
-            *feed, *carried).compile()
-    else:
-        step = jax.jit(
-            lambda *a: DecodeServer._state_decode_fn_chunk(holder, *a),
-            donate_argnums=(9, 10, 11, 12)).lower(
-            *feed, spec((C + M + 3,), jnp.int32), *carried).compile()
-    text = step.as_text()
-    # the rows that decode keep their kernels beside a chunk
-    assert len(named(text, "ring_decode")) == 3
-    assert "mx_ring_decode.bh%d.q1.k512.d128.bfloat16.kv8" % (W * 72) in text
-    assert len(named(text, "block_decode")) == 2
-    assert "mx_block_decode.bh%d.q1.k%d.d128.bfloat16.kv8.paged" % (
-        W * 48, M * S) in text
-    assert len(named(text, "block_write")) == 2
-    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
-    mem = step.memory_analysis()
-    assert mem.alias_size_in_bytes >= carried_bytes, mem
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    if program == "step":
-        assert ".e64.m1664.k3072.n1024.bfloat16.r16.gated" in text
-        assert text.count('custom_call_target="tpu_custom_call"') \
-            == 3 + 2 + 2 + 2 * moe_layers
-        assert mem.temp_size_in_bytes < 0.1e9, mem   # no pool or ring copy
-        assert 11.2e9 < planned < 11.4e9, mem
-        return
-    # the chunk's sliding layers: 512 queries behind the ring's 512 keys,
-    # two key blocks of 512 a query block, never a third
-    assert len(named(text, "grouped_fwd")) == 3
-    assert "mx_grouped_fwd.bh72.q%d.k%d.d128.bfloat16.kv8.w512.o512" % (
-        C, 512 + C) in text
-    # 576 lanes x 10 choices: 90 slots an expert, tiles of 32 rows
-    assert ".e64.m7808.k3072.n1024.bfloat16.r32.gated" in text
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == 3 + 3 + 2 + 2 + 2 * moe_layers
-    # only the rows and ONE lane of the chunk reach the head
-    assert "f32[%d,%d]" % (W + 1, model.vocab) in text
-    assert "f32[%d,%d]" % (W + C, model.vocab) not in text
-    assert mem.temp_size_in_bytes < 0.3e9, mem       # no pool or ring copy
-    assert 11.3e9 < planned < 11.6e9, mem

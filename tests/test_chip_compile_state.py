@@ -1,0 +1,227 @@
+"""Sandbox compiles for the chip, continued from ``test_chip_compile.py``
+(a file of its own so that no file is the floor of a ``--dist loadfile``
+run): the state form's programs at ``Ling-3.0-flash``'s and
+``Laguna-S-2.1``'s published widths, compiled by the TPU's own
+compiler for a DESCRIBED v5e. A compile that passes is not a chip run."""
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from test_chip_compile import ROOT, fa
+
+pytestmark = pytest.mark.usefixtures("_persistent_cache_off")
+
+
+def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
+    """``benchmark/configs/Ling-3.0-flash.json`` at its published widths
+    (2560 wide, 32 heads of 128, five delta-rule linear-attention layers
+    whose state is 32 x 128 x 128 float32 a row beside ONE latent layer of
+    rank 512, experts of 768, 128 of 512 held, 1 dense + 5 expert layers,
+    window 64, 1,152 bf16 pages of 128 x 640 in one cache layer): the
+    state form's ``decode:step`` and 1024-rung ``decode:prefill`` programs
+    compiled for one described v5e. In each: the Mosaic kernels under the
+    names a profile's reader looks for — the delta-rule step a linear
+    layer, the paged latent decode kernel and the in-place row write
+    (step), the flash kernel at 256-wide heads (prefill), the two grouped
+    matmuls of every expert layer — the planned bytes inside the chip
+    with room for the reference that decides ``correct`` beside the
+    weights, the donated pool AND the donated state arrays updated in
+    place, and NO copy of either among the temporaries (the state is 0.67
+    GB: one copy of it a layer would double the step)."""
+    from mxnet_tpu.serving import DecodeServer
+    from mxnet_tpu.serving.hybrid_linear_moe import HybridLinearMoEDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "Ling-3.0-flash.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    rung = max(srv["seq_ladder"])
+    M = -(-(rung + srv["max_new_tokens"]) // S)
+    model = HybridLinearMoEDecoderLM(**cfg["model"]["kwargs"])
+    assert model.held == (0, 128) and model.row_width == 640
+    assert (model.cache_layers, model.state_layers, model.chunk) \
+        == (1, 5, 16)
+    H, d, moe_layers = model.n_heads, model.head_dim, model.n_moe_layers
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert 8.70e9 < weights < 8.75e9
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    carried = (
+        spec((1, pages, S, model.row_width), jnp.bfloat16),
+        spec((5, W, H, d, d), jnp.float32),
+        spec((5, W, 3 * 3 * H * d), jnp.bfloat16))
+    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                        for a in carried)
+    assert 0.88e9 < carried_bytes < 0.89e9
+    holder = type("S", (), {"_model": model, "_window": W})()
+    n_counts = len(model.step_counters[1])
+
+    def named(text, kernel):
+        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                          % kernel, text, re.M)
+
+    step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
+                   donate_argnums=(8, 9, 10)).lower(
+        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
+        spec((W,), jnp.int32), spec((), jnp.int32), spec((W, M), jnp.int32),
+        spec((W + n_counts,), jnp.int32), spec((W,), jnp.int32),
+        *carried).compile()
+    text = step.as_text()
+    assert len(named(text, "kda_step")) == 5
+    assert "mx_kda_step.b%d.h%d.d%d" % (W, H, d) in text
+    assert len(named(text, "mla_decode")) == 1
+    assert len(named(text, "latent_write")) == 1
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    assert ".e128.m2560.k2560.n768.bfloat16.r16.gated" in text
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 5 + 2 + 2 * moe_layers
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried_bytes, mem
+    assert mem.temp_size_in_bytes < 0.1e9, mem      # no state or pool copy
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 9.5e9 < planned < 9.8e9, mem
+
+    prefill = jax.jit(lambda *a: DecodeServer._state_prefill_fn(holder, *a),
+                      donate_argnums=(5, 6, 7)).lower(
+        tree, spec((1, rung), jnp.int32), spec((), jnp.int32),
+        spec((M,), jnp.int32), spec((), jnp.int32), *carried).compile()
+    text = prefill.as_text()
+    assert len(named(text, "flash_fwd")) == 1
+    assert ".q%d.k%d.d256.bfloat16" % (rung, rung) in text
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    # the rung's 64 slots an expert take tiles of 32 rows
+    assert ".e128.m12288.k2560.n768.bfloat16.r32.gated" in text
+    # the chunkwise rule's walk: one loop a linear layer, carrying S
+    assert len(re.findall(r"%while[.\d]* = \(s32\[\][^,]*, "
+                          r"f32\[1,32,128,128\]", text)) == 5
+    mem = prefill.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried_bytes, mem
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert planned < 10.2e9, mem
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_window_moe_programs_compile_and_fit(chip, monkeypatch, program):
+    """``benchmark/configs/Laguna-S-2.1.json`` at its published widths
+    (3072 wide, 48 and 72 gated query heads over 8 key/value heads of
+    128, three sliding-window layers whose last 512 keys and values are a
+    ring a row beside two full-attention layers' packed pages, experts of
+    1024, 64 of 256 held, 1 dense + 4 expert layers, window 64, 4,608
+    bf16 pages of 128 x 1024 in two cache layers): the state form's
+    ``decode:step`` and its MIXED step ``decode:step:chunk:c512`` — 64
+    lanes that decode and 512 that are one prompt's chunk; since PR 46
+    this server runs no prefill program, and the case that compiled its
+    512- and 8192-rung prefills compiles this — for one described v5e. In
+    each: the Mosaic kernels under the names a profile's reader looks for
+    — the ring decode a sliding layer (9 query heads a key head), the
+    paged block kernel a full layer (6) and its in-place row write, the
+    two grouped matmuls of every expert layer and, in the mixed step, the
+    banded grouped forward with its queries offset behind the ring's 512
+    keys, a sliding layer — the planned bytes inside the chip, the
+    donated pool AND the donated rings updated in place, and NO copy of
+    either among the temporaries (the rings are 0.4 GB, the pool 4.8 GB).
+    The mixed step's temporaries: 0.20 GB found (the 8192-rung prefill it
+    replaces planned 2.5 GB), held under 0.3."""
+    from mxnet_tpu.serving import DecodeServer, WindowMoEDecoderLM
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "Laguna-S-2.1.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    M = -(-(max(srv["seq_ladder"]) + srv["max_new_tokens"]) // S)
+    # the ladder 512 / 8192 gives one mixed program, of the first rung
+    C, = [r for r in sorted(srv["seq_ladder"])
+          if r <= 2 * min(srv["seq_ladder"])]
+    assert C == 512
+    model = WindowMoEDecoderLM(**cfg["model"]["kwargs"])
+    assert model.held == (0, 64) and model.heads == (48, 72, 72, 72, 48)
+    assert (model.cache_layers, model.state_layers) == (2, 3)
+    assert model.chunk_lanes and model.window == 512
+    moe_layers = model.n_moe_layers
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert 6.00e9 < weights < 6.02e9
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    carried = (
+        spec((2, pages, S, 1024), jnp.bfloat16),
+        spec((2, pages, S, 1024), jnp.bfloat16),
+        spec((3, W, 512, 1024), jnp.bfloat16),
+        spec((3, W, 512, 1024), jnp.bfloat16))
+    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                        for a in carried)
+    assert 5.23e9 < carried_bytes < 5.24e9
+    holder = type("S", (), {"_model": model, "_window": W,
+                            "_max_pages": M})()
+    n_counts = len(model.step_counters[1])
+
+    def named(text, kernel):
+        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                          % kernel, text, re.M)
+
+    feed = (tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
+            spec((W,), jnp.int32), spec((), jnp.int32),
+            spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
+            spec((W,), jnp.int32))
+    if program == "step":
+        step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
+                       donate_argnums=(8, 9, 10, 11)).lower(
+            *feed, *carried).compile()
+    else:
+        step = jax.jit(
+            lambda *a: DecodeServer._state_decode_fn_chunk(holder, *a),
+            donate_argnums=(9, 10, 11, 12)).lower(
+            *feed, spec((C + M + 3,), jnp.int32), *carried).compile()
+    text = step.as_text()
+    # the rows that decode keep their kernels beside a chunk
+    assert len(named(text, "ring_decode")) == 3
+    assert "mx_ring_decode.bh%d.q1.k512.d128.bfloat16.kv8" % (W * 72) in text
+    assert len(named(text, "block_decode")) == 2
+    assert "mx_block_decode.bh%d.q1.k%d.d128.bfloat16.kv8.paged" % (
+        W * 48, M * S) in text
+    assert len(named(text, "block_write")) == 2
+    assert len(named(text, "grouped_matmul")) == 2 * moe_layers
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= carried_bytes, mem
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    if program == "step":
+        assert ".e64.m1664.k3072.n1024.bfloat16.r16.gated" in text
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            == 3 + 2 + 2 + 2 * moe_layers
+        assert mem.temp_size_in_bytes < 0.1e9, mem   # no pool or ring copy
+        assert 11.2e9 < planned < 11.4e9, mem
+        return
+    # the chunk's sliding layers: 512 queries behind the ring's 512 keys,
+    # two key blocks of 512 a query block, never a third
+    assert len(named(text, "grouped_fwd")) == 3
+    assert "mx_grouped_fwd.bh72.q%d.k%d.d128.bfloat16.kv8.w512.o512" % (
+        C, 512 + C) in text
+    # 576 lanes x 10 choices: 90 slots an expert, tiles of 32 rows
+    assert ".e64.m7808.k3072.n1024.bfloat16.r32.gated" in text
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 3 + 3 + 2 + 2 + 2 * moe_layers
+    # only the rows and ONE lane of the chunk reach the head
+    assert "f32[%d,%d]" % (W + 1, model.vocab) in text
+    assert "f32[%d,%d]" % (W + C, model.vocab) not in text
+    assert mem.temp_size_in_bytes < 0.3e9, mem       # no pool or ring copy
+    assert 11.3e9 < planned < 11.6e9, mem

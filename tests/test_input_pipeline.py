@@ -21,7 +21,7 @@ def _ndarray_iter(n=40, dim=3, batch=8, shuffle=False):
     return NDArrayIter(x, y, batch_size=batch, shuffle=shuffle)
 
 
-def _drain(it):
+def _all_batches(it):
     out = []
     while True:
         try:
@@ -75,15 +75,15 @@ class TestOrderingAndEpochs:
     def test_ordered_multiworker_delivery(self):
         src = _JitterSource(n=12)
         pipe = AsyncInputPipeline(src, num_workers=4, prefetch_depth=3)
-        seqs = [float(b.data[0].asnumpy()[0, 0]) for b in _drain(pipe)]
+        seqs = [float(b.data[0].asnumpy()[0, 0]) for b in _all_batches(pipe)]
         pipe.close()
         assert seqs == [float(i) for i in range(12)]
 
     def test_bit_identical_vs_eager(self):
-        eager = [b.data[0].asnumpy() for b in _drain(_ndarray_iter())]
+        eager = [b.data[0].asnumpy() for b in _all_batches(_ndarray_iter())]
         pipe = AsyncInputPipeline(_ndarray_iter(), num_workers=4,
                                   prefetch_depth=2)
-        pooled = [b.data[0].asnumpy() for b in _drain(pipe)]
+        pooled = [b.data[0].asnumpy() for b in _all_batches(pipe)]
         pipe.close()
         assert len(eager) == len(pooled)
         for a, b in zip(eager, pooled):
@@ -92,13 +92,13 @@ class TestOrderingAndEpochs:
     def test_epoch_boundary_and_reset(self):
         pipe = AsyncInputPipeline(_ndarray_iter(n=40, batch=8),
                                   num_workers=2)
-        assert len(_drain(pipe)) == 5
+        assert len(_all_batches(pipe)) == 5
         # exhausted: StopIteration repeats without wedging
         for _ in range(3):
             with pytest.raises(StopIteration):
                 pipe.next()
         pipe.reset()
-        assert len(_drain(pipe)) == 5
+        assert len(_all_batches(pipe)) == 5
         pipe.close()
 
     def test_generic_iterator_without_split_protocol(self):
@@ -107,7 +107,7 @@ class TestOrderingAndEpochs:
         from mxnet_tpu.io import ResizeIter
         base = ResizeIter(_ndarray_iter(n=40, batch=8), size=3)
         pipe = AsyncInputPipeline(base, num_workers=4)
-        assert len(_drain(pipe)) == 3
+        assert len(_all_batches(pipe)) == 3
         pipe.close()
 
     def test_iter_next_protocol_serves_fetched_batch(self):
@@ -133,7 +133,7 @@ class TestOrderingAndEpochs:
 
         pipe = AsyncInputPipeline(NumpySource(n=4), num_workers=2,
                                   placement=jax.devices("cpu")[0])
-        batches = _drain(pipe)
+        batches = _all_batches(pipe)
         pipe.close()
         assert len(batches) == 4
         assert isinstance(batches[0].data[0], np.ndarray)
@@ -147,7 +147,7 @@ class TestOrderingAndEpochs:
 
         pipe = AsyncInputPipeline(Boom(n=6), num_workers=2)
         with pytest.raises(ValueError, match="decode exploded"):
-            _drain(pipe)
+            _all_batches(pipe)
         # the error also stops the producers — no zombie decode loop
         deadline = time.time() + 5
         while any(t.is_alive() for t in pipe._threads) and \
@@ -170,7 +170,7 @@ class TestOrderingAndEpochs:
 
         pipe = AsyncInputPipeline(NTSource(n=3), num_workers=2,
                                   placement=jax.devices("cpu")[0])
-        batches = _drain(pipe)
+        batches = _all_batches(pipe)
         pipe.close()
         assert len(batches) == 3
         assert isinstance(batches[0], Pair)
@@ -186,12 +186,12 @@ class TestPrefetchingIterWrapper:
         pre.reset()
         # the old implementation rebuilt the queue with maxsize=2 here
         assert pre._pipeline._ready_q.maxsize == 5
-        assert len(_drain(pre)) == 5
+        assert len(_all_batches(pre)) == 5
         pre.close()
 
     def test_multi_iter_merge(self):
         pre = PrefetchingIter([_ndarray_iter(), _ndarray_iter()])
-        batches = _drain(pre)
+        batches = _all_batches(pre)
         assert len(batches) == 5
         assert len(batches[0].data) == 2
         assert len(batches[0].label) == 2
@@ -201,7 +201,7 @@ class TestPrefetchingIterWrapper:
         baseline = threading.active_count()
         pre = PrefetchingIter(_ndarray_iter(), prefetch_depth=3)
         for _ in range(5):
-            assert len(_drain(pre)) == 5
+            assert len(_all_batches(pre)) == 5
             pre.reset()
         pre.close()
         del pre
@@ -228,7 +228,7 @@ class TestPrefetchingIterWrapper:
         pipes = [AsyncInputPipeline(_ndarray_iter(), num_workers=3)
                  for _ in range(4)]
         for p in pipes:
-            _drain(p)
+            _all_batches(p)
             p.close()
         del pipes
         gc.collect()
@@ -241,7 +241,7 @@ class TestDevicePlacement:
         dev = jax.devices("cpu")[0]
         pipe = AsyncInputPipeline(_ndarray_iter(), num_workers=2,
                                   placement=dev)
-        batches = _drain(pipe)
+        batches = _all_batches(pipe)
         pipe.close()
         for b in batches:
             assert b.data[0]._data.devices() == {dev}
@@ -255,7 +255,7 @@ class TestDevicePlacement:
             pytest.skip("needs the multi-device CPU mesh")
         mesh = Mesh(np.array(devs), ("dp",))
         pipe = make_sharded_pipeline(_ndarray_iter(n=32, batch=8), mesh)
-        batches = _drain(pipe)
+        batches = _all_batches(pipe)
         pipe.close()
         assert len(batches) == 4
         for b in batches:
@@ -268,7 +268,7 @@ class TestDevicePlacement:
         telemetry.start(run_id="h2d")
         pipe = AsyncInputPipeline(_ndarray_iter(), num_workers=2,
                                   placement=jax.devices("cpu")[0])
-        _drain(pipe)
+        _all_batches(pipe)
         pipe.close()
         rep = telemetry.stop()
         telemetry.reset()
@@ -316,7 +316,7 @@ class TestImageRecordPooledParity:
             preprocess_threads=2)
         src = AsyncInputPipeline(it, num_workers=3) if wrap else it
         out = [(b.data[0].asnumpy(), b.label[0].asnumpy())
-               for b in _drain(src)]
+               for b in _all_batches(src)]
         if wrap:
             src.close()
         it.close()
@@ -413,9 +413,9 @@ class TestZeroCopyInit:
     def test_ndarray_iter_from_ndarray_matches_numpy(self):
         x = np.random.RandomState(0).randn(10, 3).astype(np.float32)
         a = [b.data[0].asnumpy()
-             for b in _drain(NDArrayIter(mx.nd.array(x), batch_size=5))]
+             for b in _all_batches(NDArrayIter(mx.nd.array(x), batch_size=5))]
         b = [b.data[0].asnumpy()
-             for b in _drain(NDArrayIter(x, batch_size=5))]
+             for b in _all_batches(NDArrayIter(x, batch_size=5))]
         for u, v in zip(a, b):
             np.testing.assert_array_equal(u, v)
 
@@ -501,7 +501,7 @@ def _mesh(n=None):
 
 def _eager(make_source):
     return [(b.data[0].asnumpy(), b.label[0].asnumpy())
-            for b in _drain(make_source())]
+            for b in _all_batches(make_source())]
 
 
 def _generic_source():
@@ -557,7 +557,7 @@ class TestPlacedFromHostOnce:
                 pipe = AsyncInputPipeline(make(), num_workers=2,
                                           placement=dev)
                 on = (dev, dev)
-            got = _drain(pipe)
+            got = _all_batches(pipe)
             pipe.close()
         assert len(got) == len(want) == 4
         for b, (x, y) in zip(got, want):
@@ -594,7 +594,7 @@ class TestPlacedFromHostOnce:
         with _Probe(monkeypatch) as probe:
             pipe = AsyncInputPipeline(Committed(n=3, batch=8),
                                       num_workers=2, placement=dp)
-            got = _drain(pipe)
+            got = _all_batches(pipe)
             pipe.close()
         for seq, b in enumerate(got):
             assert b.data[0]._data.sharding == dp
@@ -618,7 +618,7 @@ class TestPlacedFromHostOnce:
         with _Probe(monkeypatch) as probe:
             pipe = AsyncInputPipeline(OnTarget(n=2), num_workers=1,
                                       placement=dev)
-            got = _drain(pipe)
+            got = _all_batches(pipe)
             pipe.close()
         assert probe.routes == ["resident", "host"] * 2
         assert (probe.from_host, probe.resharded) == (2, 0)
@@ -660,7 +660,7 @@ class TestPlacedFromHostOnce:
         with _Probe(monkeypatch) as probe:
             pipe = AsyncInputPipeline(WithExtra(), num_workers=2,
                                       placement=placement)
-            got = _drain(pipe)
+            got = _all_batches(pipe)
             pipe.close()
         for b in got:
             assert b.data[0]._data.sharding == shard
@@ -679,7 +679,7 @@ class TestPlacedFromHostOnce:
             pipe = AsyncInputPipeline(
                 _ndarray_iter(n=16, batch=8), num_workers=1,
                 placement=lambda name, arr: None)
-            got = _drain(pipe)
+            got = _all_batches(pipe)
             pipe.close()
         assert probe.routes == ["host"] * 4
         for b in got:
@@ -713,13 +713,13 @@ class TestPlacedFromHostOnce:
         assert first.data[0]._data.devices() == {dev}
         pipe.reset()
         assert current_placement() is None
-        assert len(_drain(pipe)) == 6
+        assert len(_all_batches(pipe)) == 6
         assert seen and all(where is dev for _, where in seen)
         assert all(name.startswith("mxio-") for name, _ in seen)
         del seen[:]
         pipe.set_placement(None)
         pipe.reset()
-        assert len(_drain(pipe)) == 6
+        assert len(_all_batches(pipe)) == 6
         assert seen and all(where is None for _, where in seen)
         pipe.close()
         assert current_placement() is None

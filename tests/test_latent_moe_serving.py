@@ -27,6 +27,7 @@ from mxnet_tpu.serving import (DecodeServer, KVCachePool,   # noqa: E402
                                kvcache)
 from mxnet_tpu.serving.latent_moe import (LatentMoEDecoderLM,  # noqa: E402
                                           yarn_inv_freq, yarn_mscale)
+from serving_common import drain as _drain, jit_prefill   # noqa: E402
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
@@ -61,14 +62,6 @@ def _model(ep=(0, 2), use_pallas=False, seed=3):
     return model, model.init_params(seed=seed)
 
 
-def _drain(srv, *reqs, limit=800):
-    n = 0
-    while not all(r.done() for r in reqs):
-        srv._tick()
-        n += 1
-        assert n < limit, "scheduler made no progress"
-
-
 def _cached_logits(model, params, tokens, n_prompt, page_size=16):
     """Logits of positions ``n_prompt - 1 ..`` from the SERVING path:
     one prefill over the prompt written into a paged latent pool, then
@@ -87,7 +80,7 @@ def _cached_logits(model, params, tokens, n_prompt, page_size=16):
     table[:] = np.arange(1, n_pages + 1)
     padded = np.zeros((1, rung), np.int32)
     padded[0, :n_prompt] = tokens[:n_prompt]
-    logits, rows = jax.jit(model.prefill)(params, padded)
+    logits, rows = jit_prefill(model)(params, padded)
     pages = kvcache.write_prefill_pages(pages, table, rows[:, 0], n_prompt)
     out = [np.asarray(logits[0, n_prompt - 1])]
 
@@ -162,7 +155,7 @@ def test_absorbed_and_published_attention_forms_agree():
     cached = _cached_logits(model, params, tokens, 9)
     padded = np.zeros((1, 48), np.int32)
     padded[0, :40] = tokens
-    full = np.asarray(jax.jit(model.prefill)(params, padded)[0][0, 8:40])
+    full = np.asarray(jit_prefill(model)(params, padded)[0][0, 8:40])
     err = _position_errors(cached, full)
     assert np.percentile(err, 90) < 0.05, err
     assert (err > 0.05).mean() <= 0.05, err

@@ -20,6 +20,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import compile_watch, fault, livemetrics, telemetry
 from mxnet_tpu.serving import DecodeServer, KVCachePool, ToyDecoderLM
+from serving_common import drain as _drain
 
 
 @pytest.fixture(autouse=True)
@@ -49,15 +50,6 @@ def _srv(model, params, prefix=True, **kw):
         kw.setdefault("pool_pages", 32)
     kw.setdefault("start", False)
     return DecodeServer(model, params, prefix_cache=prefix, **kw)
-
-
-def _drain(srv, *reqs, limit=500):
-    n = 0
-    while not all(r.done() for r in reqs):
-        srv._tick()
-        n += 1
-        assert n < limit, "scheduler made no progress"
-    return n
 
 
 def _gen(srv, prompt, n=8):

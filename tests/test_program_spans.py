@@ -16,6 +16,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon, telemetry, tracing
 from mxnet_tpu.io.pipeline import AsyncInputPipeline
 from mxnet_tpu.serving import DecodeServer, ToyDecoderLM
+from serving_common import drain as _drain
 
 
 class _PrefillLM(ToyDecoderLM):
@@ -289,14 +290,6 @@ def test_no_session_no_ring_nothing_recorded():
 
 
 # --- the decode scheduler's counters ---------------------------------------
-
-def _drain(srv, *reqs):
-    n = 0
-    while not all(r.done() for r in reqs):
-        srv._tick()
-        n += 1
-        assert n < 500, "scheduler made no progress"
-
 
 @pytest.fixture()
 def scripted(monkeypatch):

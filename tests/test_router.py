@@ -24,6 +24,7 @@ from mxnet_tpu.serving import (DecodeServer, FleetMonitor, Replica,
                                Router, ServerClosedError,
                                ServerOverloadedError, ToyDecoderLM)
 from mxnet_tpu.parallel.multihost import StrikeTracker
+from serving_common import greedy_reference
 
 
 @pytest.fixture(autouse=True)
@@ -56,16 +57,8 @@ def _router(n=2, **kw):
 
 
 def _reference(prompt, n):
-    """Greedy generation by one FULL-sequence forward at each length —
-    the oracle a failed-over stream must still reproduce."""
-    import jax
-    import jax.numpy as jnp
-    toks = [int(t) for t in prompt]
-    prefill = jax.jit(_MODEL.prefill)    # one program per length
-    for _ in range(n):
-        logits, _, _ = prefill(_PARAMS, jnp.asarray([toks], jnp.int32))
-        toks.append(int(np.argmax(np.asarray(logits)[0, len(toks) - 1])))
-    return toks[len(prompt):]
+    """The oracle a failed-over stream must still reproduce."""
+    return greedy_reference(_MODEL, _PARAMS, prompt, n)
 
 
 def _run(router, *reqs, limit=600, dt=0.01):

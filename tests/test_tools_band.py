@@ -11,7 +11,9 @@ def test_bandwidth_measure_runs_and_checks(tmp_path):
     assert len(rows) == 2
     for r in rows:
         assert r["error"] == 0
-        assert r["bandwidth_gbps"] > 0
+        # a CPU run supplies counts, never a rate: 264 bytes over a
+        # loaded machine's seconds round to 0.0 GB/s
+        assert r["time_s"] > 0
 
 
 def test_rec2idx_roundtrip(tmp_path):
