@@ -203,14 +203,16 @@ def route_softmax_topk(x, w_gate, *, top_k, renormalize=True):
         return topi.astype(jnp.int32), topw
 
 
-def expert_load(topi, held):
+def expert_load(topi, held, count=None):
     """Tokens each HELD expert was sent: ``(hi - lo,)`` int32 from the
     router's choice ``topi (T, k)`` — the step's own count, which the
-    serving model hands on as its counters."""
+    serving model hands on as its counters. ``count``: how many are held
+    (static), for a ``held`` whose ``lo`` is traced (:func:`expert_ffn`)."""
     import jax.numpy as jnp
     lo, hi = held
+    count = hi - lo if count is None else count
     local = topi.reshape(-1) - lo
-    return jnp.sum(local[:, None] == jnp.arange(hi - lo)[None, :],
+    return jnp.sum(local[:, None] == jnp.arange(count)[None, :],
                    axis=0).astype(jnp.int32)
 
 
@@ -332,7 +334,10 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     ``(T, D)`` float32: ``sum_j topw[t, j] * expert_{topi[t, j]}(x[t])``
     over the chosen experts that are held here; a token none of whose
     experts is held gets zeros. Every (token, held expert) pair is
-    computed, whatever the load (no capacity).
+    computed, whatever the load (no capacity). HOW MANY are held is the
+    stacks' leading dimension, static; WHICH may be traced: under
+    ``shard_map`` every chip runs one program, and ``lo`` is
+    ``jax.lax.axis_index(axis) * E_held`` there.
 
     The slots are sorted by expert and each expert's group padded to
     whole tiles of ``m`` rows, so that a tile belongs to one expert: the
@@ -355,13 +360,13 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     import jax.numpy as jnp
     from .. import profiler
     from .flash_attention import _dispatch
-    lo, hi = held
-    E = hi - lo
+    lo = held[0]
+    w_gate, w_up, w_down = (weights[n] for n in ("w_gate", "w_up",
+                                                 "w_down"))
+    E = w_gate.shape[0]
     T, D = x.shape
     k = topi.shape[1]
     n_slots = T * k
-    w_gate, w_up, w_down = (weights[n] for n in ("w_gate", "w_up",
-                                                 "w_down"))
     F = w_gate.shape[-1]
     m = _gmm_rows(n_slots, E)
     profiler.increment_counter("grouped_matmul_rows_%d" % m)
@@ -372,7 +377,7 @@ def expert_ffn(x, weights, topi, topw, held, force_pallas=False):
     local = topi.T.reshape(-1) - lo                     # slot j * T + t
     is_held = jnp.logical_and(local >= 0, local < E)
     key = jnp.where(is_held, local, E)                  # unheld last
-    sizes = expert_load(topi, held)
+    sizes = expert_load(topi, held, E)
     padded = -(-sizes // m) * m
     start = jnp.cumsum(padded) - padded                 # (E,) row starts
     order = jnp.argsort(key, stable=True)

@@ -721,9 +721,23 @@ class DecodeServer:
                  pool_priority=0, prefix_cache=None, share_group=None,
                  max_queue=64, default_deadline_ms=None,
                  record_every=None, name=None, device=None,
-                 start=True):
+                 mesh=None, start=True):
         import jax
         from .. import compile_watch
+        # over a mesh (given, or the one the model is bound to) the model
+        # says how a layer is shared (``sharded_over``: the whole model,
+        # bound; ``local()``: what one chip runs, under ``shard_map``)
+        mesh = mesh if mesh is not None else getattr(model, "mesh", None)
+        if mesh is not None:
+            if not hasattr(model, "sharded_over"):
+                raise MXNetError(
+                    "DecodeServer: mesh= needs a model that says how its "
+                    "layers are shared by the chips (sharded_over, local, "
+                    "param_specs: serving.window_moe) — %s does not"
+                    % type(model).__name__)
+            model = model.sharded_over(mesh)
+        self._mesh = mesh
+        self._shards = int(model.shards) if mesh is not None else 1
         self._block = int(getattr(model, "block_length", 0) or 0)
         self._spec = int(getattr(model, "draft_length", 0) or 0)
         for attr in ("prefill", "n_layers") + (
@@ -751,7 +765,10 @@ class DecodeServer:
         # behind its own
         n_layers = int(getattr(model, "cache_layers", model.n_layers))
         self._counters = getattr(model, "step_counters", None)
+        # ``_model`` is the whole model, the host's; ``_chip_model`` what
+        # a program traces: the same object, or under a mesh one chip's
         self._model = model
+        self._chip_model = model.local() if mesh is not None else model
         self.name = name
         self._device = device if device is not None else jax.devices()[0]
         self._window = max(1, int(window) if window is not None
@@ -784,6 +801,10 @@ class DecodeServer:
                     "arrays=%s) does not match the model's (%d, %s) — "
                     "co-tenant models must agree on the page shape"
                     % (pool.n_layers, pool.array_specs, n_layers, specs))
+            if pool.shards != self._shards:
+                raise MXNetError(
+                    "DecodeServer: the shared pool lies on %d chips, the "
+                    "server's mesh has %d" % (pool.shards, self._shards))
             self._pool = pool
         else:
             self._pool = KVCachePool(n_layers, arrays=specs,
@@ -792,7 +813,9 @@ class DecodeServer:
                                      dtype=cache_dtype,
                                      device=self._device, state=state,
                                      state_layers=state_layers,
-                                     state_rows=self._window)
+                                     state_rows=self._window,
+                                     shardings=self._pool_shardings
+                                     if mesh is not None else None)
         self._owner = self._pool.attach(
             name or "model", quota=pool_quota, priority=pool_priority,
             preempt=self._pool_preempt_cb)
@@ -807,6 +830,15 @@ class DecodeServer:
             self._check_block_model()
         if self._spec:
             self._check_spec_model()
+        if mesh is not None and not (
+                self._state and self._pool.layout.chunks
+                and getattr(model, "chunk_lanes", False)):
+            raise MXNetError(
+                "DecodeServer: over a mesh the state form whose prompts "
+                "ride the step in chunks is written (the step, the mixed "
+                "step and the page copy under shard_map) — the plain, "
+                "block and speculative forms and a whole-prompt prefill "
+                "are not, yet")
         # prompt rungs fill whole pages; the table width covers the
         # longest prompt plus the full generation budget, so any
         # admitted request fits its table by construction (a speculative
@@ -886,19 +918,22 @@ class DecodeServer:
             cow_donate = {"donate_argnums": tuple(range(n_pool))}
         # ONE step program and one prefill program a rung, whatever the
         # kind of model: a block model's are the block forms, a
-        # self-drafting model's the speculative ones
+        # self-drafting model's the speculative ones. Over a mesh the
+        # same functions under ``shard_map`` (``_over_mesh``): every chip
+        # one program over its part of the parameters and the pools
         self._decode_prog = compile_watch.jit(
             self._block_decode_fn if self._block
             else self._spec_decode_fn if self._spec
-            else self._state_decode_fn if self._state else self._decode_fn,
+            else self._over_mesh(self._state_decode_fn, 7) if self._state
+            else self._decode_fn,
             "%s:step" % site,
             statics=(site, self._window, self._max_pages), **step_donate)
         # beside it, where prompts ride the step: the mixed programs, the
         # step's ``window`` lanes and a chunk's more — and no prefill
         self._chunk_progs = {
             C: compile_watch.jit(
-                self._state_decode_fn_chunk if self._state
-                else self._decode_fn_chunk,
+                self._over_mesh(self._state_decode_fn_chunk, 8)
+                if self._state else self._decode_fn_chunk,
                 "%s:step:chunk:c%d" % (site, C),
                 statics=(site, self._window, self._max_pages, C),
                 **chunk_donate) for C in self._chunks}
@@ -914,14 +949,13 @@ class DecodeServer:
         # the copy-on-write page copy: one more fixed program, only
         # ever compiled when the prefix cache is on (warmup covers it)
         self._cow_prog = compile_watch.jit(
-            self._cow_fn, "%s:cow" % site, statics=(site, "cow"),
-            **cow_donate)
+            self._over_mesh(self._cow_fn, 2, params=False, tokens=False),
+            "%s:cow" % site, statics=(site, "cow"), **cow_donate)
 
         self._cond = threading.Condition()
         self._queue = deque()
         self._active = []
-        self._params = _ParamsVersion(
-            1, jax.device_put(params, self._device))
+        self._params = _ParamsVersion(1, self._placed(params))
         self._rid = itertools.count(1)
         self._stats = {"requests": 0, "completed": 0, "cancelled": 0,
                        "timeouts": 0, "shed": 0, "errors": 0,
@@ -965,12 +999,16 @@ class DecodeServer:
         self._unread = None
         self._drains = {}
         self._drain_ask = None
-        n_counts = len(self._counters[1]) if self._counters else 0
-        self._no_prev = jax.device_put(
+        # (over a mesh the counters of every chip ride a step's output,
+        # chip 0's first)
+        n_counts = len(self._counters[1]) * self._shards \
+            if self._counters else 0
+        self._no_prev = self._placed(
             _np.zeros((self._window * (self._block + 2 if self._block
                                        else _SPEC_OUT if self._spec
                                        else 1) + n_counts,), _np.int32),
-            self._device)
+            whole=True)
+        self._counted_by_chip = [{} for _ in range(self._shards)]
         ring = max(1, envs.get_int("MXNET_SERVING_LATENCY_RING"))
         self._intervals = deque(maxlen=ring)    # inter-token ms
         self._ttft = deque(maxlen=ring)         # submit -> first token
@@ -994,6 +1032,84 @@ class DecodeServer:
         ``.arrays`` are the live device arrays, every one the programs
         carry)."""
         return self._pool
+
+    # -- over a mesh -------------------------------------------------------
+    def _pool_specs(self, layout):
+        """One ``PartitionSpec`` a carried array of the pool
+        (``kvcache.shard_specs``: pages and rings by key/value head)."""
+        return tuple(kvcache.shard_specs(self._model, layout,
+                                         self._model.axis))
+
+    def _pool_shardings(self, layout):
+        from jax.sharding import NamedSharding
+        return [NamedSharding(self._mesh, spec)
+                for spec in self._pool_specs(layout)]
+
+    def _placed(self, tree, whole=False):
+        """``tree`` on the server's device; over a mesh a parameter tree
+        by the model's declaration (nothing moves where it already lies
+        so), and what is ``whole`` on every chip."""
+        import jax
+        if self._mesh is None:
+            return jax.device_put(tree, self._device)
+        from jax.sharding import NamedSharding, PartitionSpec
+        if whole:
+            return jax.device_put(
+                tree, NamedSharding(self._mesh, PartitionSpec()))
+        return jax.device_put(tree, self._model.param_shardings())
+
+    def _over_mesh(self, fn, n_host, params=True, tokens=True):
+        """``fn`` as it is, or over a mesh ``fn`` under ``shard_map``: a
+        program's arguments are the parameters (where it takes them),
+        ``n_host`` arrays of the host's, whole on every chip, and the
+        pool's arrays, each chip its heads' part; its results the
+        step's token array (where it gives one), whole, and the pool's
+        arrays."""
+        if self._mesh is None:
+            return fn
+        import functools
+        from jax.sharding import PartitionSpec as P
+        pools = self._pool_specs(self._pool.layout)
+        # (the page copy takes the pools first and the two page ids last)
+        args = (P(),) * n_host + pools if params else pools + (P(),) * n_host
+        out = ((P(),) if tokens else ()) + pools
+        if params:
+            mapped = self._model.on_mesh(fn, args, out)
+        else:
+            import jax
+            mapped = jax.shard_map(fn, mesh=self._mesh, in_specs=args,
+                                   out_specs=out, check_vma=False)
+        # the program keeps its name: a reader of the device's trace
+        # finds ``jit__state_decode_fn`` whatever runs it
+        return functools.wraps(fn)(lambda *a: mapped(*a))
+
+    def _greedy(self, logits):
+        """The greedy token a row, ``(rows,)`` int32 — over a mesh the
+        arg-max over the chips' (max, index) pairs, each of its own
+        columns of the head: the logits are never gathered. Ties go to
+        the lowest index, as ``argmax`` over the whole row would."""
+        import jax
+        import jax.numpy as jnp
+        best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if self._mesh is None:
+            return best
+        axis = self._model.axis
+        top = jnp.take_along_axis(logits, best[:, None], axis=-1)[:, 0]
+        best = best + jax.lax.axis_index(axis) * logits.shape[-1]
+        tops = jax.lax.all_gather(top, axis)              # (chips, rows)
+        bests = jax.lax.all_gather(best, axis)
+        chip = jnp.argmax(tops, axis=0)
+        return jnp.take_along_axis(bests, chip[None], axis=0)[0]
+
+    def _counts_out(self, counts):
+        """The model's step counters as they leave with the tokens: the
+        one vector, or over a mesh every chip's, chip 0's first."""
+        import jax
+        import jax.numpy as jnp
+        counts = counts.astype(jnp.int32).reshape(-1)
+        if self._mesh is None:
+            return counts
+        return jax.lax.all_gather(counts, self._model.axis).reshape(-1)
 
     # -- compiled programs -------------------------------------------------
     # Three, for every kind of cache: ``pools`` is whatever the pool's
@@ -1347,20 +1463,20 @@ class DecodeServer:
         ``n_live`` of them live — and returning it among its results."""
         import jax.numpy as jnp
         tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
-        layout = kvcache.layout_for(self._model, pools)
+        layout = kvcache.layout_for(self._chip_model, pools)
         n, n_state = len(layout.specs), len(layout.state)
         attend = layout.attend(pools, page_tables, positions)
         state = layout.row_state(
             pools, slots, jnp.arange(self._window) < n_live)
-        logits, *new = self._model.decode(
+        logits, *new = self._chip_model.decode(
             params, tokens, positions, attend, state)
         pages = layout.write_tokens(
             pools, page_tables, positions, new[:n],
-            getattr(self._model, "use_pallas", False))
-        tokens_out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            getattr(self._chip_model, "use_pallas", False))
+        tokens_out = self._greedy(logits)
         if len(new) > n + n_state:
             tokens_out = jnp.concatenate(
-                [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
+                [tokens_out, self._counts_out(new[-1])])
         return (tokens_out, *pages, *new[n:n + n_state])
 
     def _state_decode_fn_chunk(self, params, tokens, positions, slots,
@@ -1382,12 +1498,12 @@ class DecodeServer:
         table, (start, n, slot) = chunk[C:C + M], chunk[C + M:]
         lanes = jnp.arange(C, dtype=jnp.int32)
         rows = jnp.arange(B, dtype=jnp.int32)
-        layout = kvcache.layout_for(self._model, pools)
+        layout = kvcache.layout_for(self._chip_model, pools)
         n_arrays, n_state = len(layout.specs), len(layout.state)
         attend = layout.attend_chunk(pools, page_tables, positions, table,
                                      start)
         state = layout.row_state(pools, slots, rows < n_live)
-        logits, *new = self._model.decode(
+        logits, *new = self._chip_model.decode(
             params, jnp.concatenate([tokens, chunk[:C]]),
             jnp.concatenate([positions, start + lanes]), attend, state,
             head=jnp.concatenate([rows, B + jnp.maximum(n, 1)[None] - 1]),
@@ -1397,15 +1513,15 @@ class DecodeServer:
         pages = layout.write_tokens(
             pools, page_tables, positions,
             [a[:, :B] for a in new[:n_arrays]],
-            getattr(self._model, "use_pallas", False))
+            getattr(self._chip_model, "use_pallas", False))
         pages = layout.write_chunk(
             pages + held, table, start, n,
             [a[:, B:] for a in new[:n_arrays]])
-        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # (B + 1,)
+        out = self._greedy(logits)                           # (B + 1,)
         tokens_out = jnp.where(rows == slot, out[B], out[:B])
         if len(new) > n_arrays + n_state:
             tokens_out = jnp.concatenate(
-                [tokens_out, new[-1].astype(jnp.int32).reshape(-1)])
+                [tokens_out, self._counts_out(new[-1])])
         return (tokens_out, *pages, *held)
 
     # copy-on-write page copy — the whole split is one traced program
@@ -1760,7 +1876,7 @@ class DecodeServer:
                        _np.dtype(getattr(new, "dtype",
                                          _np.asarray(new).dtype)),
                        tuple(old.shape), _np.dtype(old.dtype)))
-        new_tree = jax.device_put(params, self._device)
+        new_tree = self._placed(params)
         # fully materialize the new generation BEFORE the flip: the
         # next step must never block on a half-loaded tree
         jax.block_until_ready(jax.tree_util.tree_leaves(new_tree))
@@ -2456,6 +2572,13 @@ class DecodeServer:
                 feed += (_np.asarray(own + rest, _np.int32),
                          _np.int32(live))
                 said = dict(said, state_rows_live=live)
+            if self._mesh is not None:
+                # what one chip hands the step's all-reduces, from the
+                # program's static shapes and the lanes of this step
+                lanes = D + (len(chunk[2]) - M - 3 if chunk else 0)
+                said = dict(said, mesh=self._shards,
+                            exchange_bytes=self._model.exchange_bytes(
+                                lanes))
         self._dispatch_step(ver, rows, emits, feed + (pts,), src,
                             pages_live, said, prev, chunk)
 
@@ -2852,6 +2975,9 @@ class DecodeServer:
                 # exists only now: it rides this span, not the dispatch
                 counts = self._model_counts(toks, D)
                 back.set(**(counts or {}))
+                if self._mesh is not None and counts:
+                    # every chip's own, beside chip 0's under the names
+                    self._count_chips(toks[D:])
                 if self._state:
                     back.set(state_rows_live=step.launch.args[
                         "state_rows_live"])
@@ -2936,6 +3062,15 @@ class DecodeServer:
         return dict(zip(self._counters[1],
                         (int(c) for c in toks[first:])))
 
+    def _count_chips(self, flat):
+        """One step's counters of every chip (``flat``: a vector a chip,
+        chip 0's first) into the chips' own running totals."""
+        names = self._counters[1]
+        with self._cond:
+            for chip, tot in enumerate(self._counted_by_chip):
+                self._count_step(dict(zip(names, (
+                    int(c) for c in flat[chip * len(names):]))), tot)
+
     def _note_launch_locked(self, program, launch):
         """One program handed to the device into ``stats()`` (under
         ``self._cond``), the seconds from its launch span's stamps."""
@@ -2960,11 +3095,11 @@ class DecodeServer:
         if counts is not None:
             self._count_step(counts)
 
-    def _count_step(self, counts):
+    def _count_step(self, counts, tot=None):
         """One decode step's model counters into the running totals
         (under ``self._cond``): a name that starts with ``max`` keeps
         the largest, the others add up; ``last`` is the step's own."""
-        tot = self._counted
+        tot = self._counted if tot is None else tot
         tot["steps"] = tot.get("steps", 0) + 1
         for name, value in counts.items():
             tot[name] = max(tot.get(name, 0), value) \
@@ -3020,6 +3155,7 @@ class DecodeServer:
             versions.add(id(self._params))
             shed_pri = dict(self._shed_by_priority)
             counted = dict(self._counted)
+            by_chip = [dict(c) for c in self._counted_by_chip]
             blocks = dict(self._blocks)
             specs = dict(self._specs)
             drains = dict(self._drains)
@@ -3087,6 +3223,13 @@ class DecodeServer:
             }
         if self._counters is not None:
             out[self._counters[0]] = counted
+        if self._mesh is not None:
+            # the chips that share every layer; the model's counters
+            # above are CHIP 0's (what a reader divides chip 0's kernel
+            # time by), every chip's own beside them
+            out["mesh"] = self._shards
+            if self._counters is not None:
+                out[self._counters[0] + "_by_chip"] = by_chip
         if self._state:
             out["state"] = out["kv"]["state"]
         if self._block:

@@ -885,6 +885,12 @@ class _PerHeadKV:
         self.specs = specs
         self.dtype = dtype
 
+    # the axis of every carried array along which a mesh splits the
+    # key/value heads — side by side in a packed row or on a dimension
+    # of their own, the heads lie on axis 3 — or None: a kind no mesh
+    # shards yet (a latent row has no heads; int8 pages' scales)
+    head_axis = 3
+
     def arrays(self, n_layers, n_pages, page_size):
         """``(name, shape, dtype)`` of every array a program carries, in
         the order it carries (and returns) them. A page copy
@@ -1086,6 +1092,7 @@ class _Latent(_PerHeadKV):
     # block of a diffusion model is not written for it
     blocks = False
     causal_blocks = True
+    head_axis = None
 
     def attend(self, pools, page_tables, positions):
         return functools.partial(paged_latent_attention, *pools,
@@ -1128,6 +1135,7 @@ class _PerHeadKVInt8(_PerHeadKV):
 
     blocks = False      # a block's rows would requantize their page
     chunks = False      # and so would a chunk's
+    head_axis = None    # a page's scale is over all its heads
 
     def arrays(self, n_layers, n_pages, page_size):
         pages = super().arrays(n_layers, n_pages, page_size)
@@ -1242,6 +1250,7 @@ class _RowStateBeside:
     blocks = False
     causal_blocks = False
     chunks = property(lambda self: self.pages.chunks)
+    head_axis = property(lambda self: self.pages.head_axis)
 
     def __init__(self, pages, state, layers):
         self.pages, self.state, self.state_layers = pages, state, layers
@@ -1353,6 +1362,36 @@ def layout_for(model, pools):
     return _with_row_state(pages, state, layers) if state else pages
 
 
+def shard_specs(model, layout, axis):
+    """One ``PartitionSpec`` a carried array of ``layout`` — the pages'
+    arrays, then the model's state arrays — for a mesh ``axis`` that
+    splits the key/value heads: a page array along the layout's
+    ``head_axis``, a state array ``(state_layers, rows, *shape)`` along
+    the dimension of its row shape the model names (``state_head_dims``).
+    A page id then names the same page on every chip, each holding its
+    heads' part; the host's page tables do not know. A kind no mesh
+    shards is refused with a typed error."""
+    from jax.sharding import PartitionSpec as P
+    if layout.head_axis is None:
+        raise MXNetError(
+            "KVCachePool: no mesh splits %s pages yet — per-head K and V "
+            "in a float dtype are split by key/value head"
+            % type(getattr(layout, "pages", layout)).__name__)
+
+    def along(at):
+        return P(*([None] * at + [axis]))
+
+    n_state = len(getattr(layout, "state", ()))
+    dims = tuple(getattr(model, "state_head_dims", ()))
+    if len(dims) != n_state:
+        raise MXNetError(
+            "KVCachePool: the model declares %d state arrays and names "
+            "the head dimension of %d (state_head_dims)"
+            % (n_state, len(dims)))
+    return (along(layout.head_axis),) * len(layout.specs) \
+        + tuple(along(2 + d) for d in dims)
+
+
 # ---------------------------------------------------------------------------
 # the prefix index
 # ---------------------------------------------------------------------------
@@ -1427,7 +1466,8 @@ class KVCachePool:
 
     def __init__(self, n_layers, n_heads=None, head_dim=None, *,
                  arrays=None, page_size=None, n_pages=None, dtype=None,
-                 device=None, state=(), state_layers=0, state_rows=0):
+                 device=None, state=(), state_layers=0, state_rows=0,
+                 shardings=None):
         import jax.numpy as jnp
         self.page_size = int(page_size) if page_size is not None \
             else envs.get_int("MXNET_KV_PAGE_SIZE")
@@ -1477,9 +1517,21 @@ class KVCachePool:
         # pool's scales among them). Allocated ON the target device: a
         # replica's pool must never be staged through the first chip's
         # memory on its way there
+        # Over a mesh (``shardings(layout)`` gives one ``NamedSharding`` a
+        # carried array: ``shard_specs``) every array is born sharded by
+        # key/value head, and what the pool says of bytes is ONE chip's
         self.names = tuple(name for name, _shape, _dtype in carried)
-        self.arrays = [jnp.zeros(shape, dt, device=device)
-                       for _name, shape, dt in carried]
+        self.shards = 1
+        if shardings is not None:
+            where = list(shardings(self.layout))
+            whole = carried[0][1]
+            self.shards = math.prod(whole) // math.prod(
+                where[0].shard_shape(whole))
+            self.state_bytes //= self.shards
+        else:
+            where = [device] * len(carried)
+        self.arrays = [jnp.zeros(shape, dt, device=at)
+                       for (_name, shape, dt), at in zip(carried, where)]
         self.n_layers = int(n_layers)
         self._lock = threading.Lock()
         # serializes co-tenant servers' compiled steps on the shared
@@ -1499,7 +1551,8 @@ class KVCachePool:
         self._cow_splits = 0
         self._quota_denials = 0
         self.prefix = PrefixIndex(self.page_size)
-        self.token_bytes = self.layout.token_bytes(self.n_layers)
+        self.token_bytes = self.layout.token_bytes(self.n_layers) \
+            // self.shards
 
     # the per-head kinds' pages by name, for tests and tools; the
     # programs take ``.arrays`` whole
@@ -1784,6 +1837,9 @@ class KVCachePool:
                 "cow_splits": self._cow_splits,
                 "quota_denials": self._quota_denials,
             }
+            if self.shards > 1:
+                # ``token_bytes`` and the state's ``bytes`` are a chip's
+                out["shards"] = self.shards
             if self.state_specs:
                 out["state"] = {
                     "bytes": self.state_bytes,

@@ -1,13 +1,22 @@
 """A decoder LM whose attention layers are mostly SLIDING-WINDOW — a
 layer keeps the last ``sliding_window`` keys and values of a row, the
 same bytes whatever the context — with a full-attention layer every few,
-grouped-query heads whose COUNT differs by the kind of layer, a per-head
-output gate, and softmax-routed experts beside a shared one: the
-published ``laguna`` block, for :class:`~mxnet_tpu.serving.DecodeServer`,
-named by what it computes. It is the second model of the STATE form of
-the decode-model contract (``serving.decode``'s docstring): beside the
-pages of its full-attention layers it declares ``state_arrays``, a RING
-of keys and one of values a row and sliding layer.
+grouped-query heads, and softmax-routed experts: TWO published blocks in
+one class, by the published ``model_type``, for
+:class:`~mxnet_tpu.serving.DecodeServer`, named by what they compute.
+
+- ``laguna`` (poolside/Laguna-S-2.1): a head COUNT that differs by the
+  kind of layer, a per-head output gate, a shared expert beside the
+  routed ones, leading dense layers, a scale on the routed weights.
+- ``mellum`` (JetBrains/Mellum2-12B-A2.5B-Instruct): ONE head count, no
+  gate, no shared expert, no dense layer, no scale; the whole head
+  rotated (``partial_rotary_factor`` absent = 1). A key of the other
+  block is refused at any value.
+
+It is the second model of the STATE form of the decode-model contract
+(``serving.decode``'s docstring): beside the pages of its full-attention
+layers it declares ``state_arrays``, a RING of keys and one of values a
+row and sliding layer.
 
 **The block** (pre-norm, RMSNorm, no biases): ``h = x + Attn_i(RMSNorm(
 x))``, ``y = h + FFN_i(RMSNorm(h))``; a final RMSNorm, an untied head.
@@ -24,8 +33,8 @@ x))``, ``y = h + FFN_i(RMSNorm(h))``; a final RMSNorm, an untied head.
   ``j`` reads key/value head ``j // (H_i / kv heads)``; causal, and in a
   ``sliding_attention`` layer key ``t`` is visible to query ``s`` iff ``s
   - sliding_window < t <= s`` (``sliding_window`` keys with its own).
-  The gate: ``g = sigmoid(x W_g)``, one value a head, from the layer's
-  normed input; ``o_j <- g_j o_j`` before ``W_o``.
+  The gate (``laguna``): ``g = sigmoid(x W_g)``, one value a head,
+  from the layer's normed input; ``o_j <- g_j o_j`` before ``W_o``.
 - *Feed-forward.* A layer of ``mlp_only_layers`` is a gated SiLU MLP of
   ``intermediate_size``. Every other: the router in float32 at
   "highest", ``p = softmax(x W_r)`` over all ``num_experts``, the
@@ -34,7 +43,38 @@ x))``, ``y = h + FFN_i(RMSNorm(h))``; a final RMSNorm, an untied head.
   the experts' outputs (``parallel.moe.route_softmax_topk``,
   ``expert_ffn`` told which experts this chip holds: ``ep=(rank,
   size)``); plus one shared expert of ``shared_expert_intermediate_
-  size``, added ungated to every token.
+  size``, added ungated to every token (``laguna``; ``mellum``: every
+  layer sparse, ``y = h + sum_{e in top8(softmax(h^ W_r))} w_e W_down,e
+  (silu(h^ W_gate,e) * h^ W_up,e)``, ``w`` renormalised over the chosen).
+
+**The sharded form** (``model.sharded_over(mesh)``; ``DecodeServer(model,
+params, mesh=mesh)`` asks for it): every layer shared by the ``n`` chips
+of ONE mesh axis, no pipeline stage left out.
+
+- *Attention* by key/value head: chip ``r`` holds key/value heads ``r *
+  Hkv / n ..`` and their query heads (``W_q``, ``W_k``, ``W_v``, the gate
+  by columns, ``W_o`` by rows: :meth:`WindowMoEDecoderLM.param_specs`),
+  its pages and rings hold those heads alone (``state_head_dims``;
+  ``serving.kvcache.shard_specs``) — a page id names the same page on
+  every chip; the ``W_o`` products are summed (``psum``, float32).
+- *Experts* by their leading dimension: chip ``r`` holds a contiguous
+  block of the held experts, the router is computed WHOLE on every chip
+  from the same bits, ``expert_ffn`` computes the chip's experts' part
+  for ALL lanes (``held``'s ``lo`` from ``jax.lax.axis_index``), the
+  parts are summed (``psum``, float32); what every chip computes alike
+  (a shared expert, a dense layer) is added once, behind the sum.
+- *The head* by columns: a chip's logits are its columns, the step's
+  greedy token the arg-max over the chips' (max, index) pairs; the
+  embedding, the router, norm gains, the residual (float32), RoPE
+  tables, page tables, positions and tokens are whole on every chip.
+
+Two all-reduces a layer (:meth:`~WindowMoEDecoderLM.exchange_bytes`).
+``model.local()`` is ONE chip's view — its head counts, its declaration
+of pages and rings — and what the server's programs trace under
+``shard_map`` (:meth:`~WindowMoEDecoderLM.on_mesh`); ``init_params``
+draws every array INTO its sharding, the same bits as on one chip;
+``prefill`` and ``routing`` run over the mesh too. The step counters are
+then over the experts, pages and rings a chip HOLDS.
 
 **What a row carries.** A full-attention layer caches per-head K and V
 in the server's pages — cache layer = the number of full layers before
@@ -87,7 +127,25 @@ Precision: matrices in ``dtype`` (bfloat16), the router float32, pages
 and rings in ``cache_dtype``; float32 accumulation, residual, norms,
 RoPE, softmax and gates. Parameters are a FLAT ``{name: array}`` dict.
 
-Ten lines that serve it (run by ``tests/test_window_moe.py``)::
+Ten lines that serve the sharded form on one host's four chips (run by
+``tests/test_mellum_moe.py`` on 4 host devices):
+
+.. code-block:: python
+
+    from mxnet_tpu.parallel.mesh import create_mesh
+    from mxnet_tpu.serving import DecodeServer, WindowMoEDecoderLM
+    from mxnet_tpu.serving.window_moe import tiny_config
+    mesh = create_mesh({"tp": 4})
+    model = WindowMoEDecoderLM(**tiny_config("mellum"),
+                               dtype="float32").sharded_over(mesh)
+    params = model.init_params(seed=0)         # born sharded
+    srv = DecodeServer(model, params, mesh=mesh, seq_ladder=[16, 64],
+                       max_new_tokens=24, page_size=8, window=4,
+                       pool_pages=64, prefix_cache=False)
+    print(list(srv.submit([5, 9, 2, 7] * 10).tokens(timeout=60)))
+    srv.stop()
+
+Ten lines that serve it on one chip (run by ``tests/test_window_moe.py``)::
 
     from mxnet_tpu.serving import DecodeServer, WindowMoEDecoderLM
     from mxnet_tpu.serving.window_moe import tiny_config
@@ -102,16 +160,29 @@ Ten lines that serve it (run by ``tests/test_window_moe.py``)::
 """
 from __future__ import annotations
 
+import functools
 import math
 
 __all__ = ["WindowMoEDecoderLM", "tiny_config"]
 
-# the keys whose published value is the only one written here
-_PUBLISHED = {"model_type": "laguna", "attention_bias": False,
-              "tie_word_embeddings": False, "gating": "per-head",
-              "decoder_sparse_step": 1,
-              "moe_apply_router_weight_on_input": False,
-              "moe_router_logit_softcapping": 0}
+# the keys whose published value is the only one written here, by the
+# published ``model_type``
+_PUBLISHED = {
+    "laguna": {"attention_bias": False, "tie_word_embeddings": False,
+               "gating": "per-head", "decoder_sparse_step": 1,
+               "moe_apply_router_weight_on_input": False,
+               "moe_router_logit_softcapping": 0},
+    "mellum": {"attention_bias": False, "tie_word_embeddings": False,
+               "hidden_act": "silu", "max_window_layers": 0,
+               "use_sliding_window": True}}
+# what only the ``laguna`` block has: a per-head output gate, one head
+# count a layer, a shared expert, leading dense layers, a scale on the
+# routed experts' weights. ``mellum`` names none of them and is refused
+# any: (keyword, the value that says "absent")
+_LAGUNA_ONLY = (("shared_expert_intermediate_size", None),
+                ("num_attention_heads_per_layer", None),
+                ("gating_types", None), ("mlp_only_layers", ()),
+                ("moe_routed_scaling_factor", 1.0))
 _FULL, _SLIDING = "full_attention", "sliding_attention"
 _ROPE_KEYS = {
     "yarn": {"rope_type", "rope_theta", "factor",
@@ -120,11 +191,30 @@ _ROPE_KEYS = {
     "default": {"rope_type", "rope_theta", "partial_rotary_factor"}}
 
 
-def tiny_config():
-    """The published keys at a size a CPU test runs: five layers in the
-    published pattern (one leading dense full-attention layer, then a
-    period of three sliding layers and a full one), a window of 8, 6 and
-    4 query heads over 2 key/value heads, 8 experts."""
+def tiny_config(model_type="laguna"):
+    """The published keys at a size a CPU test runs. ``laguna``: five
+    layers in the published pattern (one leading dense full-attention
+    layer, then a period of three sliding layers and a full one), a
+    window of 8, 6 and 4 query heads over 2 key/value heads, 8 experts.
+    ``mellum``: one period of four (three sliding layers, then a full
+    one), 8 query heads over 4 key/value heads — a group a chip on a mesh
+    of 4 — 8 experts, no gate, no shared expert, no dense layer."""
+    if model_type == "mellum":
+        return dict(
+            model_type="mellum", vocab_size=96, hidden_size=32,
+            intermediate_size=64, num_hidden_layers=4,
+            num_attention_heads=8, num_key_value_heads=4, head_dim=16,
+            max_position_embeddings=4096, rms_norm_eps=1e-6,
+            num_experts=8, num_experts_per_tok=3, moe_intermediate_size=16,
+            norm_topk_prob=True, sliding_window=8,
+            rope_parameters={
+                _FULL: {"rope_type": "yarn", "rope_theta": 500000,
+                        "factor": 16, "original_max_position_embeddings": 64,
+                        "beta_fast": 32, "beta_slow": 1,
+                        "attention_factor": 1.2772588722239782},
+                _SLIDING: {"rope_type": "default", "rope_theta": 500000}},
+            layer_types=[_SLIDING] * 3 + [_FULL],
+            mlp_layer_types=["sparse"] * 4)
     return dict(
         vocab_size=96, hidden_size=32, intermediate_size=64,
         num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=2,
@@ -144,6 +234,37 @@ def tiny_config():
         mlp_layer_types=["dense"] + ["sparse"] * 4,
         gating_types=["per_head"] * 5, moe_routed_scaling_factor=2.5,
         num_attention_heads_per_layer=[4, 6, 6, 6, 4])
+
+
+def _drawn(shape, dtype, std, sharding):
+    """``key -> normal(shape) * std`` in ``dtype``; with a ``sharding``
+    the normal draw is born sharded (one program a shape and sharding)
+    and scaled and cast where it lies, an operation at a time as without
+    one: the same bits whatever the mesh."""
+    import jax
+    import jax.numpy as jnp
+    normal = _sharded_normal(shape, sharding) if sharding is not None \
+        else functools.partial(jax.random.normal, shape=shape,
+                               dtype=jnp.float32)
+
+    def draw(key):
+        return (normal(key) * std).astype(dtype)
+
+    return draw
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_normal(shape, sharding):
+    import jax
+    import jax.numpy as jnp
+    from .. import compile_watch
+
+    def normal(key):
+        return jax.random.normal(key, shape, jnp.float32)
+
+    # polymorphic by design: one program a parameter shape
+    return compile_watch.jit(normal, "window_moe:init_params", storm=False,
+                             out_shardings=sharding)
 
 
 class WindowMoEDecoderLM:
@@ -169,26 +290,56 @@ class WindowMoEDecoderLM:
                  num_hidden_layers, num_attention_heads,
                  num_key_value_heads, head_dim, num_experts,
                  num_experts_per_tok, moe_intermediate_size,
-                 shared_expert_intermediate_size, sliding_window,
-                 rope_parameters, layer_types,
-                 num_attention_heads_per_layer, mlp_only_layers=(),
+                 sliding_window, rope_parameters, layer_types,
+                 shared_expert_intermediate_size=None,
+                 num_attention_heads_per_layer=None, mlp_only_layers=(),
                  mlp_layer_types=None, gating_types=None,
                  norm_topk_prob=True, moe_routed_scaling_factor=1.0,
                  rms_norm_eps=1e-6, max_position_embeddings=4096,
-                 dtype="bfloat16", cache_dtype=None, ep=(0, 1),
-                 use_pallas=False, **published):
+                 model_type="laguna", dtype="bfloat16", cache_dtype=None,
+                 ep=(0, 1), use_pallas=False, **published):
         from ..base import MXNetError
         from ..parallel.sharding_rules import held_experts
         me = type(self).__name__
+        if model_type not in _PUBLISHED:
+            raise MXNetError(
+                "%s: model_type %r — the published blocks written here "
+                "are %s" % (me, model_type, sorted(_PUBLISHED)))
+        self.model_type = str(model_type)
         for key, value in published.items():
-            if key not in _PUBLISHED:
+            if key not in _PUBLISHED[model_type]:
                 raise TypeError("%s: unexpected keyword %r" % (me, key))
-            if value != _PUBLISHED[key]:
+            if value != _PUBLISHED[model_type][key]:
                 raise MXNetError(
                     "%s: %s = %r — only the published %r is written (the "
                     "other form's equations are not settled by the "
                     "config: serving.window_moe's docstring)"
-                    % (me, key, value, _PUBLISHED[key]))
+                    % (me, key, value, _PUBLISHED[model_type][key]))
+        said = dict(
+            shared_expert_intermediate_size=shared_expert_intermediate_size,
+            num_attention_heads_per_layer=num_attention_heads_per_layer,
+            gating_types=gating_types,
+            mlp_only_layers=tuple(mlp_only_layers),
+            moe_routed_scaling_factor=float(moe_routed_scaling_factor))
+        # the output gate is the ``laguna`` block's alone
+        self.gated = model_type == "laguna"
+        if self.gated:
+            for key in ("shared_expert_intermediate_size",
+                        "num_attention_heads_per_layer"):
+                if said[key] is None:
+                    raise MXNetError("%s: model_type 'laguna' needs %s"
+                                     % (me, key))
+        for key, absent in () if self.gated else _LAGUNA_ONLY:
+            if said[key] != absent:
+                raise MXNetError(
+                    "%s: %s = %r — the published %r block has no such key "
+                    "(one head count, no output gate, no shared expert, "
+                    "no dense layer, no scale on the routed weights)"
+                    % (me, key, said[key], model_type))
+        if not self.gated:
+            shared_expert_intermediate_size = 0
+            num_attention_heads_per_layer = \
+                [int(num_attention_heads)] * int(num_hidden_layers)
         n = self.n_layers = int(num_hidden_layers)
         self.vocab = int(vocab_size)
         self.d_model = int(hidden_size)
@@ -257,6 +408,14 @@ class WindowMoEDecoderLM:
         # (frequencies, rotated width, the gain on cos and sin) a kind
         self.rope = {kind: self._rope_table(kind, rope_parameters)
                      for kind in (_FULL, _SLIDING)}
+        # no mesh: one chip runs the whole of what it holds
+        self.mesh = self.axis = self.on_chip = None
+        self.shards = 1
+        self._declare()
+
+    def _declare(self):
+        """What a row carries, from the key/value heads held (all of
+        them; under a mesh one chip's)."""
         width = self.n_kv_heads * self.head_dim
         self.cache_arrays = (
             ("k", (self.n_kv_heads, self.head_dim), self.cache_dtype),
@@ -264,6 +423,10 @@ class WindowMoEDecoderLM:
         self.state_arrays = (
             ("ring_k", (self.window, width), self.cache_dtype),
             ("ring_v", (self.window, width), self.cache_dtype))
+
+    # of a ring's row shape ``(W, heads side by side)``, the dimension a
+    # mesh splits by key/value head (``serving.kvcache.shard_axes``)
+    state_head_dims = (1, 1)
 
     def _rope_table(self, kind, rope_parameters):
         import numpy as np
@@ -313,47 +476,163 @@ class WindowMoEDecoderLM:
     def state_layer(self, i):
         return self.kinds[:i].count(_SLIDING)
 
+    # -- over a mesh -------------------------------------------------------
+    def sharded_over(self, mesh, axis=None):
+        """This model bound to ONE axis of ``mesh`` (its only one where
+        ``axis`` is not given): every layer shared by the axis's chips —
+        key/value heads, their query heads and the held experts in
+        contiguous blocks, the head by columns (:meth:`param_specs`) —
+        and :meth:`local`, one chip's view, what ``DecodeServer`` runs
+        under ``shard_map``. The model itself stays the whole one: its
+        declaration, ``init_params`` (drawn into the shardings),
+        ``prefill`` and ``routing`` (under ``shard_map``)."""
+        import copy
+        from ..base import MXNetError
+        me = type(self).__name__
+        if axis is None:
+            if len(mesh.axis_names) != 1:
+                raise MXNetError(
+                    "%s: mesh axes %s — say which ONE shares a layer"
+                    % (me, mesh.axis_names))
+            axis = mesh.axis_names[0]
+        if (self.mesh, self.axis) == (mesh, axis):
+            return self
+        if self.mesh is not None or self.on_chip:
+            raise MXNetError("%s: already bound to a mesh" % me)
+        n = int(mesh.shape[axis])
+        for what, count in (("key/value heads", self.n_kv_heads),
+                            ("held experts", self.held[1] - self.held[0]),
+                            ("vocabulary rows", self.vocab)):
+            if count % n:
+                raise MXNetError(
+                    "%s: %d %s do not divide over the %d chips of mesh "
+                    "axis %r" % (me, count, what, n, axis))
+        bound = copy.copy(self)
+        bound.mesh, bound.axis, bound.shards = mesh, axis, n
+        return bound
+
+    def local(self):
+        """One chip's view of a model bound to a mesh: the key/value
+        heads, query heads, pages and rings of ONE chip, the experts it
+        holds by its index on the axis; its products through ``W_o`` and
+        through its experts are summed over the axis (``psum``), its
+        logits are its columns of the head. To be run under
+        :meth:`on_mesh`."""
+        import copy
+        chip = copy.copy(self)
+        chip.mesh, chip.on_chip = None, True
+        chip.n_kv_heads = self.n_kv_heads // self.shards
+        chip.heads = tuple(h // self.shards for h in self.heads)
+        chip._declare()
+        return chip
+
+    def param_specs(self):
+        """``{name: PartitionSpec}``, the model's declaration of where
+        its parameters lie on the mesh axis: ``W_q``, ``W_k``, ``W_v``,
+        the gate and the head by columns, ``W_o`` by rows, an expert
+        stack by its leading dimension; the embedding, the router, norm
+        gains and whatever every chip computes alike (a shared expert, a
+        dense layer) whole on every chip."""
+        from jax.sharding import PartitionSpec as P
+        ax, out = self.axis, {}
+        for name in self._param_shapes():
+            leaf = name.rsplit(".", 1)[-1]
+            if ".experts." in name:
+                out[name] = P(ax, None, None)
+            elif name == "head" or leaf in ("wq", "wk", "wv", "wg"):
+                out[name] = P(None, ax)
+            elif leaf == "wo":
+                out[name] = P(ax, None)
+            else:
+                out[name] = P()
+        return out
+
+    def param_shardings(self):
+        from jax.sharding import NamedSharding
+        return {name: NamedSharding(self.mesh, spec)
+                for name, spec in self.param_specs().items()}
+
+    def on_mesh(self, fn, in_specs, out_specs):
+        """``fn(params, *args)`` under ``shard_map`` over the model's
+        mesh: the parameters by :meth:`param_specs`, the rest as said."""
+        import jax
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=(self.param_specs(), *in_specs),
+            out_specs=out_specs, check_vma=False)
+
+    def exchange_bytes(self, lanes):
+        """What ONE chip hands the all-reduces of a step of ``lanes``
+        lanes: a float32 ``(lanes, hidden)`` array behind every
+        attention layer and every expert layer."""
+        return (self.n_layers + self.n_moe_layers) * int(lanes) \
+            * self.d_model * 4
+
     # -- parameters --------------------------------------------------------
+    def _param_shapes(self):
+        """``{name: (shape, dtype, deviation or None for a gain)}`` in the
+        order the keys are drawn."""
+        import jax.numpy as jnp
+        dt = jnp.dtype(self.dtype)
+        D, Dh, Hkv = self.d_model, self.head_dim, self.n_kv_heads
+        E = self.held[1] - self.held[0]
+        out = {}
+
+        def w(name, *shape, dtype=dt, std=None):
+            out[name] = (shape, dtype,
+                         shape[-2] ** -0.5 if std is None else std)
+
+        def ones(name, n):
+            out[name] = ((n,), jnp.dtype(jnp.float32), None)
+
+        w("embed", self.vocab, D, std=1.0)
+        ones("out_g", D)
+        w("head", D, self.vocab)
+        for i, H in enumerate(self.heads):
+            l = "l%d." % i
+            ones(l + "attn_g", D)
+            w(l + "wq", D, H * Dh)
+            w(l + "wk", D, Hkv * Dh)
+            w(l + "wv", D, Hkv * Dh)
+            if self.gated:
+                w(l + "wg", D, H)
+            w(l + "wo", H * Dh, D)
+            ones(l + "ffn_g", D)
+            if self.dense[i]:
+                w(l + "w_gate", D, self.d_ff)
+                w(l + "w_up", D, self.d_ff)
+                w(l + "w_down", self.d_ff, D)
+                continue
+            F, Fs = self.d_expert, self.d_shared
+            w(l + "router_w", D, self.n_experts, dtype=jnp.float32)
+            w(l + "experts.w_gate", E, D, F)
+            w(l + "experts.w_up", E, D, F)
+            w(l + "experts.w_down", E, F, D)
+            if Fs:
+                w(l + "shared.w_gate", D, Fs)
+                w(l + "shared.w_up", D, Fs)
+                w(l + "shared.w_down", Fs, D)
+        return out
+
     def init_params(self, seed=0):
         """Matrices in ``dtype`` at ``fan_in ** -0.5`` (the embedding at
-        1), the router's matrix float32, norm gains 1."""
+        1), the router's matrix float32, norm gains 1. Over a mesh every
+        array is drawn INTO its sharding (:meth:`param_specs`: a layer's
+        experts never lie whole on one chip), the same values as on
+        one."""
         import jax
         import jax.numpy as jnp
         keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                      16 * self.n_layers + 4))
-        dt = jnp.dtype(self.dtype)
+        where = self.param_shardings() if self.mesh is not None else {}
 
-        def w(*shape, dtype=dt, std=None):
-            std = shape[-2] ** -0.5 if std is None else std
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * std).astype(dtype)
+        def draw(name, shape, dtype, std):
+            if std is None:
+                return jnp.ones(shape, jnp.float32, device=where.get(name))
+            return _drawn(shape, jnp.dtype(dtype).name, std,
+                          where.get(name))(next(keys))
 
-        D, Dh, Hkv = self.d_model, self.head_dim, self.n_kv_heads
-        E = self.held[1] - self.held[0]
-        ones = lambda n: jnp.ones((n,), jnp.float32)    # noqa: E731
-        p = {"embed": w(self.vocab, D, std=1.0), "out_g": ones(D),
-             "head": w(D, self.vocab)}
-        for i, H in enumerate(self.heads):
-            l = "l%d." % i
-            p.update({
-                l + "attn_g": ones(D), l + "wq": w(D, H * Dh),
-                l + "wk": w(D, Hkv * Dh), l + "wv": w(D, Hkv * Dh),
-                l + "wg": w(D, H), l + "wo": w(H * Dh, D),
-                l + "ffn_g": ones(D)})
-            if self.dense[i]:
-                p.update({l + "w_gate": w(D, self.d_ff),
-                          l + "w_up": w(D, self.d_ff),
-                          l + "w_down": w(self.d_ff, D)})
-                continue
-            F, Fs = self.d_expert, self.d_shared
-            p.update({
-                l + "router_w": w(D, self.n_experts, dtype=jnp.float32),
-                l + "experts.w_gate": w(E, D, F),
-                l + "experts.w_up": w(E, D, F),
-                l + "experts.w_down": w(E, F, D),
-                l + "shared.w_gate": w(D, Fs), l + "shared.w_up": w(D, Fs),
-                l + "shared.w_down": w(Fs, D)})
-        return p
+        return {name: draw(name, *spec)
+                for name, spec in self._param_shapes().items()}
 
     # -- pieces ------------------------------------------------------------
     def _rms(self, x, g):
@@ -398,9 +677,40 @@ class WindowMoEDecoderLM:
         k = self._mm(x, p[l + "wk"]).reshape(lead + (self.n_kv_heads, Dh))
         v = self._mm(x, p[l + "wv"]).reshape(lead + (self.n_kv_heads, Dh))
         cache = jnp.dtype(self.cache_dtype)
+        g = jax.nn.sigmoid(self._mm(x, p[l + "wg"])) if self.gated \
+            else None
         return self._rotate(kind, q, positions), \
             self._rotate(kind, k, positions).astype(cache), \
-            v.astype(cache), jax.nn.sigmoid(self._mm(x, p[l + "wg"]))
+            v.astype(cache), g
+
+    def _out(self, i, a, g, p):
+        """The attention layer's increment of the residual: the heads'
+        outputs ``a (..., H_i, Dh)``, gated where the block has a gate,
+        through ``W_o`` — under a mesh this chip's heads through its
+        rows of ``W_o``, summed over the chips."""
+        import jax.numpy as jnp
+        a = a.astype(jnp.float32)
+        if g is not None:
+            a = a * g[..., None]
+        return self._sum(self._mm(a.reshape(a.shape[:-2] + (-1,)),
+                                  p["l%d.wo" % i]))
+
+    def _sum(self, x):
+        """``x``, or under a mesh the sum of the chips' parts (float32):
+        every chip is handed the same bits."""
+        import jax
+        return x if self.on_chip is None else jax.lax.psum(x, self.axis)
+
+    def _held(self):
+        """``(lo, hi)`` of the experts this chip holds: ``held`` as
+        built, or under a mesh this chip's share of them, ``lo`` from
+        the chip's index on the axis (traced: one program for all)."""
+        import jax
+        if self.on_chip is None:
+            return self.held
+        n = (self.held[1] - self.held[0]) // self.shards
+        lo = self.held[0] + jax.lax.axis_index(self.axis) * n
+        return lo, lo + n
 
     def _gated(self, x, p, prefix):
         import jax
@@ -425,13 +735,19 @@ class WindowMoEDecoderLM:
             routed.append(topi)
         if live is not None:
             topi = jnp.where(live[:, None], topi, self.n_experts)
-        out = moe.expert_ffn(
-            x, {n: p[l + "experts." + n]
-                for n in ("w_gate", "w_up", "w_down")},
-            topi, topw * self.route_scale, self.held,
-            force_pallas=self.use_pallas)
-        return self._gated(x, p, l + "shared.") + out, \
-            moe.expert_load(topi, self.held)
+        held = self._held()
+        experts = {n: p[l + "experts." + n]
+                   for n in ("w_gate", "w_up", "w_down")}
+        # under a mesh: this chip's experts' part for ALL lanes, the
+        # parts summed; what every chip computes alike (the shared
+        # expert) is added once, behind the sum
+        out = self._sum(moe.expert_ffn(
+            x, experts, topi, topw * self.route_scale, held,
+            force_pallas=self.use_pallas))
+        if self.d_shared:
+            out = self._gated(x, p, l + "shared.") + out
+        return out, moe.expert_load(topi, held,
+                                    experts["w_gate"].shape[0])
 
     def _ring_of(self, seq, lengths):
         """A prompt's keys (or values) ``seq (B, L, Hkv, Dh)`` as the
@@ -454,19 +770,38 @@ class WindowMoEDecoderLM:
         ``(logits (B, L, V), k, v (cache_layers, B, L, Hkv, Dh), ring_k,
         ring_v (state_layers, B, W, Hkv * Dh))``, the rings as they stand
         after position ``lengths - 1``. A position at or past its true
-        length costs no expert (what it computes is nobody's)."""
-        return self._forward(params, tokens, lengths)
+        length costs no expert (what it computes is nobody's). Over a
+        mesh the same, every result sharded as the server's are: the
+        logits by columns, keys, values and rings by key/value head."""
+        if self.mesh is None:
+            return self._forward(params, tokens, lengths)
+        from jax.sharding import PartitionSpec as P
+        ax = self.axis
+        heads, lanes = P(None, None, None, ax, None), P(None, None, None, ax)
+        return self.on_mesh(
+            self.local()._forward, (P(), P()),
+            (P(None, None, ax), heads, heads, lanes, lanes))(
+                params, tokens, lengths)
 
     def routing(self, params, tokens):
         """The router's choice at every expert layer over whole sequences
         ``tokens (B, L)``, on the prefill path: ``(expert layers, B * L,
         top_k)`` int32 — for a comparison with a reference's choice."""
         import jax.numpy as jnp
-        routed = []
-        self._forward(params, tokens,
-                      jnp.full((tokens.shape[0],), tokens.shape[1],
-                               jnp.int32), routed)
-        return jnp.stack(routed)
+
+        def choices(model, params, tokens):
+            routed = []
+            model._forward(params, tokens,
+                           jnp.full((tokens.shape[0],), tokens.shape[1],
+                                    jnp.int32), routed)
+            return jnp.stack(routed)
+
+        if self.mesh is None:
+            return choices(self, params, tokens)
+        from jax.sharding import PartitionSpec as P
+        # every chip routes alike: chip 0's choice is everyone's
+        return self.on_mesh(functools.partial(choices, self.local()),
+                            (P(),), P())(params, tokens)
 
     def _forward(self, params, tokens, lengths, routed=None):
         import jax.numpy as jnp
@@ -490,8 +825,7 @@ class WindowMoEDecoderLM:
                 q, k, v, causal=True, scale=self.scale,
                 window=self.window if sliding else None,
                 force_pallas=self.use_pallas)
-            a = (a.astype(jnp.float32) * g[..., None]).reshape(B, L, -1)
-            h = h + self._mm(a, p[l + "wo"])
+            h = h + self._out(i, a, g, p)
             if sliding:
                 rings.append((self._ring_of(k, lengths),
                               self._ring_of(v, lengths)))
@@ -558,9 +892,7 @@ class WindowMoEDecoderLM:
                            force_pallas=self.use_pallas)
                 ks.append(k)
                 vs.append(v)
-            a = (a.astype(jnp.float32) * g[..., None]).reshape(
-                tokens.shape[0], -1)
-            h = h + self._mm(a, p[l + "wo"])
+            h = h + self._out(i, a, g, p)
             out, load = self._ffn(i, self._rms(h, p[l + "ffn_g"]), p,
                                   live=live)
             h = h + out

@@ -17,6 +17,17 @@ from test_chip_compile import ROOT, fa
 pytestmark = pytest.mark.usefixtures("_persistent_cache_off")
 
 
+def _state_holder(model, **attrs):
+    """What the state form's step programs read of their server, without
+    one (a described device holds no pool): no mesh, so the whole model
+    is what a program traces."""
+    from mxnet_tpu.serving import DecodeServer
+    holder = object.__new__(DecodeServer)
+    vars(holder).update(_model=model, _chip_model=model, _mesh=None,
+                        **attrs)
+    return holder
+
+
 def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     """``benchmark/configs/Ling-3.0-flash.json`` at its published widths
     (2560 wide, 32 heads of 128, five delta-rule linear-attention layers
@@ -64,7 +75,7 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                         for a in carried)
     assert 0.88e9 < carried_bytes < 0.89e9
-    holder = type("S", (), {"_model": model, "_window": W})()
+    holder = _state_holder(model, _window=W)
     n_counts = len(model.step_counters[1])
 
     def named(text, kernel):
@@ -170,8 +181,7 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch, program):
     carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                         for a in carried)
     assert 5.23e9 < carried_bytes < 5.24e9
-    holder = type("S", (), {"_model": model, "_window": W,
-                            "_max_pages": M})()
+    holder = _state_holder(model, _window=W, _max_pages=M)
     n_counts = len(model.step_counters[1])
 
     def named(text, kernel):
@@ -225,3 +235,122 @@ def test_window_moe_programs_compile_and_fit(chip, monkeypatch, program):
     assert "f32[%d,%d]" % (W + C, model.vocab) not in text
     assert mem.temp_size_in_bytes < 0.3e9, mem       # no pool or ring copy
     assert 11.3e9 < planned < 11.6e9, mem
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_sharded_window_moe_programs_compile_for_four_chips(
+        chip, monkeypatch, program):
+    """``benchmark/configs/Mellum2-12B-A2.5B-Instruct.json`` WHOLE — 28
+    layers, 64 experts, 98,304 rows of vocabulary, 24.3 GB — over a mesh
+    of the four described chips of a v5e 2x2: the state form's
+    ``decode:step`` and its MIXED step ``decode:step:chunk:c512`` under
+    ``shard_map`` (``DecodeServer._over_mesh``), as the server builds
+    them. On every chip: ONE key/value head's rings (``mx_ring_decode
+    ...kv1``, 8 query heads a group) and packed pages of 128 lanes
+    (``mx_block_decode...kv1.paged``), 16 experts' grouped matmuls
+    (``.e16...r16`` a step, ``.r128`` beside 512 chunk lanes), the
+    chunk's banded forward behind the ring's 1,024 keys — every one the
+    Pallas kernel, none fallen to ``jnp`` at the one-head shapes — two
+    all-reduces a layer and one for the arg-max's pairs, 8.4 GB of
+    arguments a chip (6.4 of weights, 1.9 of pool and rings, updated in
+    place) and no copy of pool or rings among the temporaries."""
+    import types
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu import profiler
+    from mxnet_tpu.serving import DecodeServer, WindowMoEDecoderLM, kvcache
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "Mellum2-12B-A2.5B-Instruct.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    M = -(-(max(srv["seq_ladder"]) + srv["max_new_tokens"]) // S)
+    C, = [r for r in sorted(srv["seq_ladder"])
+          if r <= 2 * min(srv["seq_ladder"])]
+    assert (C, M) == (512, 40)
+    # the four described chips of the fixture's topology, one axis
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("tp",))
+    model = WindowMoEDecoderLM(**cfg["model"]["kwargs"]).sharded_over(mesh)
+    shapes = jax.eval_shape(
+        WindowMoEDecoderLM(**cfg["model"]["kwargs"]).init_params, 0)
+    where = model.param_shardings()
+    tree = {n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where[n])
+            for n, a in shapes.items()}
+    layout = kvcache._with_row_state(
+        kvcache.cache_layout(kvcache.declared_arrays(model)[0],
+                             jnp.dtype("bfloat16")),
+        *kvcache.declared_state(model))
+
+    def spec(shape, dtype, at=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, at))
+
+    carried = tuple(
+        spec(shape, dt, at) for (_n, shape, dt), at in zip(
+            layout.arrays(model.cache_layers, pages, S)
+            + layout.state_arrays(W),
+            kvcache.shard_specs(model, layout, "tp")))
+    assert [a.shape for a in carried] == [
+        (7, pages, S, 512)] * 2 + [(21, W, 1024, 512)] * 2
+    holder = object.__new__(DecodeServer)
+    vars(holder).update(
+        _model=model, _chip_model=model.local(), _mesh=mesh, _shards=4,
+        _window=W, _max_pages=M,
+        _pool=types.SimpleNamespace(layout=layout))
+    n_counts = 4 * len(model.step_counters[1])
+    feed = (tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
+            spec((W,), jnp.int32), spec((), jnp.int32),
+            spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
+            spec((W,), jnp.int32))
+    before = dict(profiler.counters())
+    if program == "step":
+        step = jax.jit(holder._over_mesh(holder._state_decode_fn, 7),
+                       donate_argnums=(8, 9, 10, 11)).lower(
+            *feed, *carried).compile()
+    else:
+        step = jax.jit(
+            holder._over_mesh(holder._state_decode_fn_chunk, 8),
+            donate_argnums=(9, 10, 11, 12)).lower(
+            *feed, spec((C + M + 3,), jnp.int32), *carried).compile()
+    chose = {k: v - before.get(k, 0)
+             for k, v in profiler.counters().items()
+             if k.endswith(("_pallas", "_jnp")) and v != before.get(k, 0)}
+    assert not any(k.endswith("_jnp") for k in chose), chose
+    assert chose["ring_decode_pallas"] == 21
+    assert chose["grouped_matmul_pallas"] == 28
+    assert chose["block_decode_pallas"] == 7
+    text = step.as_text()
+
+    def named(kernel):
+        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                          % kernel, text, re.M)
+
+    assert len(named("ring_decode")) == 21
+    assert "mx_ring_decode.bh%d.q1.k1024.d128.bfloat16.kv1" % (W * 8) in text
+    assert len(named("block_decode")) == 7
+    assert "mx_block_decode.bh%d.q1.k%d.d128.bfloat16.kv1.paged" % (
+        W * 8, M * S) in text
+    assert len(named("grouped_matmul")) == 2 * 28
+    assert len(re.findall(r" all-reduce(?:-start)?\(", text)) in (56, 57, 58)
+    mem = step.memory_analysis()
+    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                        for a in carried) // 4
+    assert 1.93e9 < carried_bytes < 1.94e9
+    assert mem.alias_size_in_bytes >= carried_bytes, mem
+    assert 8.3e9 < mem.argument_size_in_bytes < 8.5e9, mem
+    assert mem.temp_size_in_bytes < 0.3e9, mem       # no pool or ring copy
+    if program == "step":
+        assert ".e16.m768.k2304.n896.bfloat16.r16.gated" in text
+        return
+    assert chose["ring_chunk_pallas"] == 21
+    assert len(named("grouped_fwd")) == 21
+    assert "mx_grouped_fwd.bh8.q%d.k%d.d128.bfloat16.kv1.w1024.o1024" % (
+        C, 1024 + C) in text
+    assert ".e16.m6656.k2304.n896.bfloat16.r128.gated" in text
+    # only the rows and ONE lane of the chunk reach a chip's columns of
+    # the head
+    assert "f32[%d,%d]" % (W + 1, model.vocab // 4) in text
+    assert "f32[%d,%d]" % (W + C, model.vocab // 4) not in text
