@@ -37,8 +37,7 @@ Orca/vLLM-style answer composed from machinery this tree already has:
   takes the smallest that holds what is pending, the largest while more
   is. The program set is then ``1 +`` those rungs, whatever the ladder.
   The block and speculative forms, an int8 pool and a model that does
-  not declare it — a state model whose state cannot take a chunk: a
-  recurrence — keep the whole-prompt prefill, ``1 + len(ladder)``.
+  not declare it keep the whole-prompt prefill, ``1 + len(ladder)``.
   ``stats()["chunk_steps"]`` / ``["chunk_tokens"]`` /
   ``["prefill_programs"]`` count it; ``mx:decode.dispatch`` carries
   ``chunk`` and ``chunk_of``.
@@ -308,26 +307,32 @@ way the same bytes whatever the context — declares, beside
   must leave its slot as it was.
 
 **Which state takes a chunk** is the model's to say, and the server
-observes it: a state model that declares ``chunk_lanes = True``
-(``WindowMoEDecoderLM``: a sliding layer's chunk is ``C`` more keys
-written into slots ``t % W`` of the request's ring and attended under
-the band — the ring's validity follows from position alone) is served by
-the step and the MIXED step (``_state_decode_fn_chunk``, beside
-``_state_decode_fn`` as ``_decode_fn_chunk`` is beside ``_decode_fn``),
-over pages that can (the layout beside the state passes on its pages'
-``chunks``), with the plain form's host code — FIFO, one request's chunk
-a step, the same chunk sizes — and no prefill program. Its ``decode(...,
-state, head=None, live=None, chunk=None)`` is handed ``chunk = (the
-request's row of the state arrays, the first lane's position, the live
-lanes)`` beside ``head`` and ``live``. The chunk's request is NOT a live
-row of that step: the rows that decode come first in ``slots``, a row
-whose prompt is still pending rides behind ``n_live``, and its state is
-written by the chunk's lanes alone (a lane of its own at position 0
-would write slot 0 of its ring). A model that does not declare it
-(``HybridLinearMoEDecoderLM``: a chunk of a delta rule is another
-recurrence from the row's state) keeps ``_state_prefill_fn``. The
-dispatch span of a mixed state step carries ``chunk``, ``chunk_of`` and
-``state_rows_live`` (the rows that decode).
+observes it: a state model that declares ``chunk_lanes = True`` — both
+in the tree do: ``WindowMoEDecoderLM`` (a sliding layer's chunk is ``C``
+more keys written into slots ``t % W`` of the request's ring and
+attended under the band — the ring's validity follows from position
+alone) and ``HybridLinearMoEDecoderLM`` (a linear-attention layer's chunk
+is the same delta rule over ``C`` more positions FROM THE ROW'S STATE:
+``kda_chunk(..., state=)`` on the request's row of ``s``, the
+convolution reaching back into its ``conv`` rows, zeros for both where
+the chunk starts the prompt, and the row written back at the last live
+lane) — is served by the step and the MIXED step
+(``_state_decode_fn_chunk``, beside ``_state_decode_fn`` as
+``_decode_fn_chunk`` is beside ``_decode_fn``), over pages that can (the
+layout beside the state passes on its pages' ``chunks``), with the plain
+form's host code — FIFO, one request's chunk a step, the same chunk
+sizes — and no prefill program. Its ``decode(..., state, head=None,
+live=None, chunk=None)`` is handed ``chunk = (the request's row of the
+state arrays, the first lane's position, the live lanes)`` beside
+``head`` and ``live``. The chunk's request is NOT a live row of that
+step: the rows that decode come first in ``slots``, a row whose prompt
+is still pending rides behind ``n_live``, and its state is written by
+the chunk's lanes alone (a lane of its own at position 0 would write
+slot 0 of its ring, or move its recurrence by a token that is not its
+prompt's). A state model that does not declare it (a test's twin of
+either) keeps ``_state_prefill_fn``. The dispatch span of a mixed state
+step carries ``chunk``, ``chunk_of`` and ``state_rows_live`` (the rows
+that decode).
 
 **A row's state belongs to its slot**: a request takes one of the
 window's rows (``DecodeRequest.slot``) when it is admitted and keeps it

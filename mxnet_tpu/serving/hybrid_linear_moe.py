@@ -36,6 +36,23 @@ rows are taken at the true end), and so does a dead row of the window.
 convolution, in prefill and decode alike, because that is what the
 state holds of the three rows before.
 
+**A prompt rides the step in chunks** (``chunk_lanes = True``: what
+``DecodeServer`` observes to build the mixed step programs and no
+prefill program): behind the ``B`` rows of a step, ``C`` lanes that are
+consecutive positions ``start .. start + C - 1`` of ONE request's
+prompt, the first ``n`` of them live. The lanes share the rows' matrices
+(one stream of weights for the rows and the prompt) and a linear layer
+does for them what the prefill does for a whole prompt, but FROM THE
+ROW'S STATE: the convolution's first ``kernel - 1`` inputs are the row's
+``conv`` rows and the rule starts from the row's ``S``
+(``kda_chunk(..., state=)``) — zeros for both where ``start`` is 0,
+whatever the slot's last tenant left — and the state after lane ``n -
+1``, with the rows before position ``start + n``, is written back into
+the request's row, which is no live row of that step. A latent layer's
+lanes go through the layout's split ``attend``; a lane that is not live
+chooses no expert; only the rows and the chunk's last live lane reach
+the head.
+
 **A latent-attention layer** (``(i + 1) % layer_group_size == 0``):
 ``serving.latent_moe``'s, with no query rank (``q = x W_q``), plain RoPE
 at ``rope_theta`` on the rotary columns (``rope_scaling`` null: score
@@ -109,10 +126,10 @@ class HybridLinearMoEDecoderLM(LatentMoEDecoderLM):
     TPU), ``dtype`` the matrices', the pool's and the convolution
     rows' (``"float32"`` for a test that compares logits)."""
 
-    # the latent model's ``decode`` takes a chunk's lanes; this one's
-    # state cannot, yet: a chunk of a delta rule is another recurrence
-    # from the row's state. Its server keeps the whole-prompt prefill
-    chunk_lanes = False
+    # ``decode`` takes ``head``, ``live`` and ``chunk``: a chunk of a
+    # delta rule is the same recurrence from the row's state
+    # (``kda_chunk(..., state=)``), so a prompt may ride the step in chunks
+    chunk_lanes = True
 
     def __init__(self, *, vocab_size, hidden_size, num_hidden_layers,
                  num_attention_heads, head_dim, layer_group_size,
@@ -322,32 +339,94 @@ class HybridLinearMoEDecoderLM(LatentMoEDecoderLM):
                          g_floor=self.g_floor)
         return self._out(i, o[:, :L], x, p), S, rows.reshape(B, -1)
 
-    def _linear_step(self, i, u, p, state, arrays):
+    def _linear_step(self, i, u, p, state, arrays, live=None, chunk=None):
         """Layer ``i`` over one token a row ``u (B, D)`` on the rows'
-        slots: ``(increment, (s, conv))``, the state arrays updated."""
+        slots: ``(increment, (s, conv))``, the state arrays updated.
+
+        On a MIXED step ``u`` is ``(B + C, D)``: behind the rows, the
+        ``C`` lanes of ONE request's chunk, ``chunk = (its row of the
+        state arrays, the first lane's position, the live lanes)`` and
+        ``live (B + C,)``. The lanes share the rows' matrices — one
+        stream of ``wqkv``, ``wf``, ``wb``, ``wg``, ``wo`` — and do what
+        :meth:`_linear_prefill` does for a whole prompt, FROM THE ROW'S
+        STATE (:meth:`_chunk_conv`, :meth:`_chunk_rule`). The request is
+        no live row of the step: the rows' kernel passes its row through,
+        and the chunk's lanes alone write it."""
         import jax.numpy as jnp
         from ..parallel.delta_rule import kda_step
         l = "l%d." % i
         s_all, conv_all = arrays
         j, K = self.state_layer(i), self.conv
-        B = u.shape[0]
+        B = state.slots.shape[0]
         x = self._rms(u, p[l + "attn_g"])
         raw = self._mm(x, p[l + "wqkv"]).astype(self.dtype)
         before = conv_all[j, state.slots]                 # (B, (K-1) C)
         window = jnp.concatenate(
-            [before.reshape(B, K - 1, self.qkv), raw[:, None]], axis=1)
+            [before.reshape(B, K - 1, self.qkv), raw[:B, None]], axis=1)
         y = (p[l + "conv_w"] * window.astype(jnp.float32)).sum(1)
         after = jnp.where(state.live[:, None],
                           window[:, 1:].reshape(B, -1), before)
         # the step's rows are ALL the window's, so the rows go back by a
         # gather through the inverse permutation and one whole-plane
         # write: a scatter would widen a 16-bit array to float32, whole
-        conv_all = conv_all.at[j].set(after[state.inverse])
-        g, beta = self._gates(i, x, p, state.live)
+        plane = after[state.inverse]
+        if chunk is not None:
+            # the request's row rides in the same write
+            tail, rows = self._chunk_conv(raw[B:], p[l + "conv_w"],
+                                          conv_all[j, chunk[0]], chunk)
+            y = jnp.concatenate([y, tail])
+            plane = jnp.where((jnp.arange(B) == chunk[0])[:, None],
+                              rows[None], plane)
+        conv_all = conv_all.at[j].set(plane)
+        g, beta = self._gates(i, x, p, state.live if live is None else live)
         q, k, v = self._qkv(y)
-        o, s_all = kda_step(s_all, j, state.slots, q, k, v, g, beta,
-                            force_pallas=self.use_pallas)
+        o, s_all = kda_step(s_all, j, state.slots, q[:B], k[:B], v[:B],
+                            g[:B], beta[:B], force_pallas=self.use_pallas)
+        if chunk is not None:
+            tail, s_all = self._chunk_rule(
+                j, (q[B:], k[B:], v[B:], g[B:], beta[B:]), s_all, chunk)
+            o = jnp.concatenate([o, tail])
         return self._out(i, o, x, p), (s_all, conv_all)
+
+    def _chunk_conv(self, raw, conv_w, held, chunk):
+        """The convolution over a chunk's lanes ``raw (C, 3 H d)``, its
+        first ``K - 1`` inputs the row's ``conv`` rows ``held`` — zeros
+        where the chunk starts the prompt: a slot's last tenant never
+        leaks, whatever it left. Returns ``(y (C, 3 H d), the rows before
+        position start + n)``: part of them the carried ones where ``n <
+        K - 1``, ``held`` as it was where ``n`` is 0 (a warm-up)."""
+        import jax
+        import jax.numpy as jnp
+        _row, start, n = chunk
+        K, C = self.conv, raw.shape[0]
+        carried = jnp.where(start > 0, held, jnp.zeros_like(held))
+        padded = jnp.concatenate([carried.reshape(K - 1, self.qkv), raw])
+        wide = padded.astype(jnp.float32)
+        y = sum(conv_w[t] * wide[t:t + C] for t in range(K))
+        rows = jax.lax.dynamic_slice_in_dim(padded, n, K - 1, axis=0)
+        return y, jnp.where(n > 0, rows.reshape(-1), held)
+
+    def _chunk_rule(self, j, qkvgb, s_all, chunk):
+        """The delta rule over a chunk's lanes from the row's ``S``
+        (zeros where the chunk starts the prompt; a ``where`` on the row
+        that was read — a ``cond`` would compile both branches over the
+        state): ``(o (C, H, d), s_all)``, the state after the last live
+        lane written into the request's row of state layer ``j``, in
+        place, and only that row. ``g`` and ``beta`` are 0 on the lanes
+        that are not live, so the state stops at the last that is."""
+        import jax.numpy as jnp
+        from ..parallel.delta_rule import kda_chunk
+        row, start, n = chunk
+        C = qkvgb[0].shape[0]
+        held = s_all[j, row]
+        # the chunkwise form takes whole chunks: lanes that change nothing
+        pad = -C % self.chunk
+        o, S = kda_chunk(
+            *(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))[None]
+              for a in qkvgb),
+            state=jnp.where(start > 0, held, 0.0)[None], chunk=self.chunk,
+            g_floor=self.g_floor)
+        return o[0, :C], s_all.at[j, row].set(jnp.where(n > 0, S[0], held))
 
     # -- the contract (STATE form) -----------------------------------------
     def prefill(self, params, tokens, lengths):
@@ -389,11 +468,26 @@ class HybridLinearMoEDecoderLM(LatentMoEDecoderLM):
         return logits, jnp.stack(rows), \
             tuple(jnp.stack(a) for a in zip(*states))
 
-    def decode(self, params, tokens, positions, attend, state):
+    def decode(self, params, tokens, positions, attend, state, head=None,
+               live=None, chunk=None):
         """One token a row: ``state`` is the step's
         :class:`~mxnet_tpu.serving.kvcache.RowState` (``.arrays``: ``s``
         and ``conv``, whole; ``.slots``; ``.live``). Returns ``(logits,
-        rows (cache_layers, B, W), s, conv, counters)``."""
+        rows (cache_layers, B, W), s, conv, counters)``.
+
+        A MIXED step hands more lanes than rows: behind the ``B`` rows of
+        ``state``, ``C`` lanes that are consecutive positions of ONE
+        request's prompt, ``chunk = (its row of the state arrays, the
+        first lane's position, the live lanes)``. Everything is lane-wise
+        but the two kinds of attention: a latent layer's ``attend`` is
+        the layout's split one (``attend_chunk``), a linear layer runs
+        the rows through ``kda_step`` and the chunk through ``kda_chunk``
+        from the request's row of ``s`` and ``conv``, which the chunk's
+        lanes write (:meth:`_linear_step`). ``live (B + C,)``: a lane
+        that is not live chooses no expert and moves no state; ``head (B
+        + 1,)``: the lanes that reach the head, ``logits`` theirs alone;
+        the latent rows come back for every lane, ``(cache_layers, B + C,
+        W)``."""
         import jax.numpy as jnp
         p = params
         absorbed = self._absorbed(
@@ -405,16 +499,20 @@ class HybridLinearMoEDecoderLM(LatentMoEDecoderLM):
             nonlocal arrays
             if self.latent_layer(i) is not None:
                 return absorbed(i, u)
-            out, arrays = self._linear_step(i, u, p, state, arrays)
+            out, arrays = self._linear_step(i, u, p, state, arrays, live,
+                                            chunk)
             return out, None
 
         X = p["embed"][tokens].astype(jnp.float32)
         rows, loads = [], []
         for i in range(self.n_layers):
-            X, row, load = self._block(i, X, p, attention)
+            X, row, load = self._block(i, X, p, attention, live=live)
             if row is not None:
                 rows.append(row)
             if load is not None:
                 loads.append(load)
+        if head is not None:
+            # a chunk's lanes do not pay the head
+            X = X[head]
         logits = self._mm(self._rms(X, p["out_g"]), p["head"])
         return (logits, jnp.stack(rows), *arrays, self._counters(loads))

@@ -1243,9 +1243,11 @@ class _RowStateBeside:
     the pages' own to attend and write (``chunks`` is the inner layout's);
     what the state does with a chunk is the model's, which says whether it
     can by declaring ``chunk_lanes``: a ring takes one (more keys into
-    slots ``t % W``, attended under the band, valid by position as ever);
-    a recurrence does not, yet (a chunk of it is another recurrence from
-    the row's state), and keeps the whole-prompt prefill."""
+    slots ``t % W``, attended under the band, valid by position as ever)
+    and so does a recurrence (the same rule over the chunk's positions
+    from the row's state — zeros where the chunk starts the prompt — and
+    the row written back at the last live lane); a model that does not
+    declare it keeps the whole-prompt prefill."""
 
     blocks = False
     causal_blocks = False

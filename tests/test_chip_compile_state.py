@@ -28,6 +28,58 @@ def _state_holder(model, **attrs):
     return holder
 
 
+def _named(text, kernel):
+    """The compiled program's calls of the Mosaic kernel ``mx_<kernel>``."""
+    return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
+                      % kernel, text, re.M)
+
+
+def _hybrid_linear_case(chip):
+    """``benchmark/configs/Ling-3.0-flash.json`` as both of its cases
+    compile it: ``(model, holder, W, rung, M, feed, carried, carried
+    bytes, spec)`` — ``feed`` the step programs' arguments in front of the
+    carried arrays (the mixed step's chunk goes between them)."""
+    import types
+    from mxnet_tpu.serving.hybrid_linear_moe import HybridLinearMoEDecoderLM
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "Ling-3.0-flash.json")) as f:
+        cfg = json.load(f)
+    srv = cfg["server"]["kwargs"]
+    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
+    rung, = srv["seq_ladder"]
+    M = -(-(rung + srv["max_new_tokens"]) // S)
+    model = HybridLinearMoEDecoderLM(**cfg["model"]["kwargs"])
+    assert model.held == (0, 128) and model.row_width == 640
+    assert (model.cache_layers, model.state_layers, model.chunk) \
+        == (1, 5, 16)
+    H, d = model.n_heads, model.head_dim
+    params = jax.eval_shape(lambda: model.init_params(seed=0))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in params.values())
+    assert 8.70e9 < weights < 8.75e9
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    carried = (
+        spec((1, pages, S, model.row_width), jnp.bfloat16),
+        spec((5, W, H, d, d), jnp.float32),
+        spec((5, W, 3 * 3 * H * d), jnp.bfloat16))
+    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                        for a in carried)
+    assert 0.88e9 < carried_bytes < 0.89e9
+    n_counts = len(model.step_counters[1])
+    feed = (jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params),
+            spec((W,), jnp.int32), spec((W,), jnp.int32),
+            spec((W,), jnp.int32), spec((), jnp.int32),
+            spec((W, M), jnp.int32), spec((W + n_counts,), jnp.int32),
+            spec((W,), jnp.int32))
+    return types.SimpleNamespace(
+        model=model, W=W, rung=rung, M=M, feed=feed, carried=carried,
+        carried_bytes=carried_bytes, spec=spec,
+        holder=_state_holder(model, _window=W, _max_pages=M))
+
+
 def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     """``benchmark/configs/Ling-3.0-flash.json`` at its published widths
     (2560 wide, 32 heads of 128, five delta-rule linear-attention layers
@@ -35,7 +87,10 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     rank 512, experts of 768, 128 of 512 held, 1 dense + 5 expert layers,
     window 64, 1,152 bf16 pages of 128 x 640 in one cache layer): the
     state form's ``decode:step`` and 1024-rung ``decode:prefill`` programs
-    compiled for one described v5e. In each: the Mosaic kernels under the
+    compiled for one described v5e (the prefill is what a server of a
+    model that does not declare ``chunk_lanes`` runs, and the oracle of
+    the chunks; since PR 49 this model's own server runs the mixed step
+    of the next case in its place). In each: the Mosaic kernels under the
     names a profile's reader looks for — the delta-rule step a linear
     layer, the paged latent decode kernel and the in-place row write
     (step), the flash kernel at 256-wide heads (prefill), the two grouped
@@ -45,49 +100,16 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     place, and NO copy of either among the temporaries (the state is 0.67
     GB: one copy of it a layer would double the step)."""
     from mxnet_tpu.serving import DecodeServer
-    from mxnet_tpu.serving.hybrid_linear_moe import HybridLinearMoEDecoderLM
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "Ling-3.0-flash.json")) as f:
-        cfg = json.load(f)
-    srv = cfg["server"]["kwargs"]
-    W, S, pages = srv["window"], srv["page_size"], srv["pool_pages"]
-    rung = max(srv["seq_ladder"])
-    M = -(-(rung + srv["max_new_tokens"]) // S)
-    model = HybridLinearMoEDecoderLM(**cfg["model"]["kwargs"])
-    assert model.held == (0, 128) and model.row_width == 640
-    assert (model.cache_layers, model.state_layers, model.chunk) \
-        == (1, 5, 16)
+    case = _hybrid_linear_case(chip)
+    model, holder, spec, named = case.model, case.holder, case.spec, _named
+    W, rung, M, carried = case.W, case.rung, case.M, case.carried
+    tree, carried_bytes = case.feed[0], case.carried_bytes
     H, d, moe_layers = model.n_heads, model.head_dim, model.n_moe_layers
-    params = jax.eval_shape(lambda: model.init_params(seed=0))
-    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                  for a in params.values())
-    assert 8.70e9 < weights < 8.75e9
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    tree = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
-    carried = (
-        spec((1, pages, S, model.row_width), jnp.bfloat16),
-        spec((5, W, H, d, d), jnp.float32),
-        spec((5, W, 3 * 3 * H * d), jnp.bfloat16))
-    carried_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                        for a in carried)
-    assert 0.88e9 < carried_bytes < 0.89e9
-    holder = _state_holder(model, _window=W)
-    n_counts = len(model.step_counters[1])
-
-    def named(text, kernel):
-        return re.findall(r"^\s*(?:ROOT )?%%mx_%s\.[\w.]* = .* custom-call\("
-                          % kernel, text, re.M)
 
     step = jax.jit(lambda *a: DecodeServer._state_decode_fn(holder, *a),
                    donate_argnums=(8, 9, 10)).lower(
-        tree, spec((W,), jnp.int32), spec((W,), jnp.int32),
-        spec((W,), jnp.int32), spec((), jnp.int32), spec((W, M), jnp.int32),
-        spec((W + n_counts,), jnp.int32), spec((W,), jnp.int32),
-        *carried).compile()
+        *case.feed, *carried).compile()
     text = step.as_text()
     assert len(named(text, "kda_step")) == 5
     assert "mx_kda_step.b%d.h%d.d%d" % (W, H, d) in text
@@ -120,6 +142,70 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     mem = prefill.memory_analysis()
     assert mem.alias_size_in_bytes >= carried_bytes, mem
     assert mem.temp_size_in_bytes < 0.5e9, mem
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert planned < 10.2e9, mem
+
+
+def test_hybrid_linear_mixed_step_compiles_and_fits(chip, monkeypatch):
+    """``benchmark/configs/Ling-3.0-flash.json``'s MIXED step
+    ``decode:step:chunk:c1024`` — the 64 lanes that decode and 1,024 that
+    are one prompt's chunk: the ladder's one rung, so every prompt of the
+    cell is one chunk; since PR 49 this server runs no prefill program —
+    for one described v5e. The rows keep their kernels beside a chunk
+    (``mx_kda_step`` a linear layer, the paged latent decode kernel and
+    its in-place row write, the grouped matmuls; nothing fell to ``jnp``),
+    the chunk's delta rule is one walk a linear layer carrying ONE row's
+    ``S``, only the rows and ONE lane of the chunk reach the head, and
+    the donated pool and state arrays are updated in place: the chunk's
+    row of ``s`` is read and written as a 2 MB slice a layer, and NO copy
+    of the whole array (0.67 GB: 1.6 ms a layer) lies among the
+    temporaries, which fit beside 9.6 GB of weights, pool and state."""
+    from mxnet_tpu import profiler
+    from mxnet_tpu.serving import DecodeServer
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    case = _hybrid_linear_case(chip)
+    model, W, C, M = case.model, case.W, case.rung, case.M
+    assert (W, C) == (64, 1024) and model.chunk_lanes
+    H, d, moe_layers = model.n_heads, model.head_dim, model.n_moe_layers
+    before = dict(profiler.counters())
+    step = jax.jit(
+        lambda *a: DecodeServer._state_decode_fn_chunk(case.holder, *a),
+        donate_argnums=(9, 10, 11)).lower(
+        *case.feed, case.spec((C + M + 3,), jnp.int32),
+        *case.carried).compile()
+    chose = {k: v - before.get(k, 0)
+             for k, v in profiler.counters().items()
+             if k.endswith(("_pallas", "_jnp")) and v != before.get(k, 0)}
+    assert not any(k.endswith("_jnp") for k in chose), chose
+    text = step.as_text()
+    assert chose["kda_step_pallas"] == 5
+    assert chose["grouped_matmul_pallas"] == moe_layers
+
+    assert len(_named(text, "kda_step")) == 5
+    assert "mx_kda_step.b%d.h%d.d%d" % (W, H, d) in text
+    assert len(_named(text, "mla_decode")) == 1
+    assert len(_named(text, "latent_write")) == 1
+    assert len(_named(text, "grouped_matmul")) == 2 * moe_layers
+    # 1,088 lanes x 8 choices over 128 held experts: tiles of 32 rows
+    assert re.search(r"\.e128\.m\d+\.k2560\.n768\.bfloat16\.r32\.gated",
+                     text)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 5 + 2 + 2 * moe_layers
+    # the chunkwise rule's walk: one loop a linear layer, carrying ONE
+    # row's S
+    assert len(re.findall(r"%while[.\d]* = \(s32\[\][^,]*, "
+                          r"f32\[1,32,128,128\]", text)) == 5
+    # only the rows and ONE lane of the chunk reach the head
+    assert "f32[%d,%d]" % (W + 1, model.vocab) in text
+    assert "f32[%d,%d]" % (W + C, model.vocab) not in text
+    # no copy of the state or of a plane of it: whatever holds all 64
+    # rows of S is the donated argument or its alias
+    assert not re.findall(r"= f32\[(?:5,|1,)?%d,%d,%d,%d\]\S* copy\("
+                          % (W, H, d, d), text)
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= case.carried_bytes, mem
+    assert mem.temp_size_in_bytes < 0.5e9, mem      # no state or pool copy
     planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert planned < 10.2e9, mem

@@ -5,7 +5,9 @@ model contract, the row state beside the pool (``serving.kvcache``) and
 the delta-rule kernels (``parallel.delta_rule``; Pallas in interpret
 mode), against the benchmark's plain float32 reference
 (``benchmark/reference/hybrid_linear_moe_lm.py``, the recurrence one
-token at a time) at a small size with seeded weights. The programs of
+token at a time) at a small size with seeded weights; a prompt as
+chunks on a mixed step's lanes, the delta rule from the row's state,
+against the whole-prompt prefill and the same reference. The programs of
 the models that keep no such state are the parent's, jaxpr for jaxpr.
 The server's cases but one are in ``test_hybrid_linear_moe_server.py``."""
 import functools
@@ -32,6 +34,7 @@ from mxnet_tpu.serving.block_diffusion import (                # noqa: E402
 from mxnet_tpu.serving.hybrid_linear_moe import (              # noqa: E402
     HybridLinearMoEDecoderLM)
 from mxnet_tpu.serving.latent_moe import LatentMoEDecoderLM    # noqa: E402
+from serving_common import jit_prefill                         # noqa: E402
 
 # the published block's shape at a test's size: two groups of three
 # layers (two linear, one latent), 16 experts in 4 groups, 2 kept, top 4,
@@ -202,24 +205,87 @@ def test_the_gates_draw_remembers():
 # the model against the reference
 # ---------------------------------------------------------------------------
 
+def _pool(model, n_pages, page_size=16, window=4):
+    state, layers = kvcache.declared_state(model)
+    return KVCachePool(model.cache_layers,
+                       arrays=[c[:2] for c in model.cache_arrays],
+                       dtype=model.cache_arrays[0][2], page_size=page_size,
+                       n_pages=n_pages + 1, state=state, state_layers=layers,
+                       state_rows=window)
+
+
+@functools.lru_cache(maxsize=None)
+def _steps(model):
+    """``(the plain step, the MIXED step)`` through the layout's own
+    ``attend``, row state and writes, as ``DecodeServer``'s two state
+    step programs run them (the mixed one with the logits of every lane
+    kept), jitted once a model: weights, tables, slots and pools are
+    arguments."""
+    @jax.jit
+    def plain(params, pools, toks, poss, pts, order, n_live):
+        layout = kvcache.layout_for(model, pools)
+        attend = layout.attend(pools, pts, poss)
+        state = layout.row_state(pools, order,
+                                 jnp.arange(len(order)) < n_live)
+        logits, new, *held = model.decode(params, toks, poss, attend, state)
+        return logits, (*layout.write_tokens(pools, pts, poss, [new],
+                                             model.use_pallas), *held[:2])
+
+    @jax.jit
+    def mixed(params, pools, toks, poss, pts, order, n_live, fed, table,
+              start, n, slot):
+        layout = kvcache.layout_for(model, pools)
+        rows, C = len(toks), len(fed)
+        attend = layout.attend_chunk(pools, pts, poss, table, start)
+        state = layout.row_state(pools, order, jnp.arange(rows) < n_live)
+        lanes = jnp.arange(C, dtype=jnp.int32)
+        logits, new, *held = model.decode(
+            params, jnp.concatenate([toks, fed]),
+            jnp.concatenate([poss, start + lanes]), attend, state,
+            head=jnp.arange(rows + C),
+            live=jnp.concatenate([state.live, lanes < n]),
+            chunk=(slot, start, n))
+        held = tuple(held[:2])
+        pages = layout.write_tokens(pools, pts, poss, [new[:, :rows]],
+                                    model.use_pallas)
+        pages = layout.write_chunk(pages + held, table, start, n,
+                                   [new[:, rows:]])
+        return logits, (*pages, *held)
+
+    return plain, mixed
+
+
+def _decode_from(model, params, pools, tokens, first, table, slot,
+                 window=4):
+    """One plain step a token of ``tokens[first:]`` on row ``slot``, the
+    only live row of a window of ``window``, from ``pools`` as a prefill
+    or a prompt's chunks left them: the steps' logits."""
+    plain = _steps(model)[0]
+    order = np.asarray([slot] + [r for r in range(window) if r != slot],
+                       np.int32)
+    pts = np.zeros((window, len(table)), np.int32)
+    pts[0] = table
+    out = []
+    for pos in range(first, len(tokens)):
+        toks, poss = (np.zeros((window,), np.int32) for _ in range(2))
+        toks[0], poss[0] = tokens[pos], pos
+        logits, pools = plain(params, pools, toks, poss, pts, order, 1)
+        out.append(np.asarray(logits[0]))
+    return out
+
+
 def _served_logits(model, params, tokens, n_prompt, page_size=16, window=4,
                    slot=2):
     """Logits of positions ``n_prompt - 1 ..`` from the SERVING path: one
     prefill over the prompt, its rows written into a paged latent pool
     and its state into row ``slot`` of the state arrays, then one decode
     step a token through the layout's own ``attend``, row state and
-    writes — what ``DecodeServer``'s two state programs compute, with the
-    logits kept. The step runs a window of ``window`` rows of which one
-    is live."""
-    L = len(tokens)
+    writes — what ``DecodeServer``'s state prefill and step programs
+    compute, with the logits kept. The step runs a window of ``window``
+    rows of which one is live."""
     rung = -(-n_prompt // page_size) * page_size
-    n_pages = -(-L // page_size) + 1
-    state, layers = kvcache.declared_state(model)
-    pool = KVCachePool(model.cache_layers,
-                       arrays=[c[:2] for c in model.cache_arrays],
-                       dtype=model.cache_arrays[0][2], page_size=page_size,
-                       n_pages=n_pages + 1, state=state, state_layers=layers,
-                       state_rows=window)
+    n_pages = -(-len(tokens) // page_size) + 1
+    pool = _pool(model, n_pages, page_size, window)
     layout = pool.layout
     assert layout is kvcache.layout_for(model, pool.arrays)
     table = np.arange(1, n_pages + 1, dtype=np.int32)
@@ -234,28 +300,9 @@ def _served_logits(model, params, tokens, n_prompt, page_size=16, window=4,
             *layout.write_prefill(pools, table, [rows], n_prompt),
             *layout.write_state(pools, slot, st, True))
 
-    slots = np.asarray([slot] + [s for s in range(window) if s != slot],
-                       np.int32)
-    tables = np.zeros((window, n_pages), np.int32)
-    tables[0] = table
-
-    @jax.jit
-    def step(pools, tok, pos):
-        toks = jnp.zeros((window,), jnp.int32).at[0].set(tok)
-        poss = jnp.zeros((window,), jnp.int32).at[0].set(pos)
-        attend = layout.attend(pools, tables, poss)
-        rows = layout.row_state(pools, slots, jnp.arange(window) < 1)
-        logits, new, *st = model.decode(params, toks, poss, attend, rows)
-        return logits[0], (
-            *layout.write_tokens(pools, tables, poss, [new],
-                                 model.use_pallas), *st[:len(state)])
-
     first, pools = prefill(tuple(pool.arrays))
-    out = [np.asarray(first)]
-    for p in range(n_prompt, L):
-        lg, pools = step(pools, tokens[p], p)
-        out.append(np.asarray(lg))
-    return np.stack(out)
+    return np.stack([np.asarray(first)] + _decode_from(
+        model, params, pools, tokens, n_prompt, table, slot, window))
 
 
 # Matrices, pool and convolution rows are float32 here and so is the
@@ -292,6 +339,165 @@ def test_prefill_then_decode_agrees_with_the_reference_on_logits(use_pallas):
     assert np.median(low) > 2 * LOGIT_TOLERANCE, low
     assert np.abs(reference("float8") - want).max(axis=1).min() \
         / want.std() > 20 * LOGIT_TOLERANCE
+
+
+# ---------------------------------------------------------------------------
+# a prompt on a mixed step's lanes: the delta rule from the row's state
+# ---------------------------------------------------------------------------
+
+def _tenants(pools, seed=5):
+    """``pools`` with every row of the state arrays holding what a last
+    tenant might have left: nothing of it may reach the next."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (pools[0], *(jax.random.normal(k, a.shape).astype(a.dtype)
+                        for k, a in zip(keys, pools[1:])))
+
+
+def _feed_chunks(model, params, pools, prompt, C, table, slot, window=4):
+    """``prompt`` through the mixed step's chunk lanes, ``C`` at a time,
+    into row ``slot`` — no row of the window decodes: ``(the prompt
+    positions' logits, pools)``."""
+    mixed = _steps(model)[1]
+    zeros = np.zeros((window,), np.int32)
+    out = []
+    for start in range(0, len(prompt), C):
+        n = min(C, len(prompt) - start)
+        fed = np.zeros((C,), np.int32)
+        fed[:n] = prompt[start:start + n]
+        logits, pools = mixed(
+            params, pools, zeros, zeros, np.zeros((window, len(table)),
+                                                  np.int32),
+            np.arange(window, dtype=np.int32), 0, fed, table, start, n, slot)
+        out.append(np.asarray(logits[window:window + n]))
+    return np.concatenate(out), pools
+
+
+@pytest.mark.parametrize("n_prompt,C", [(21, 16), (21, 32), (34, 16)],
+                         ids=["dead_lanes-C16", "one_rung-C32",
+                              "fewer_than_the_kernel-C16"])
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_chunks_are_the_prefill_and_the_reference(use_pallas, n_prompt, C):
+    """A prompt fed as chunks on a mixed step's lanes — two chunks whose
+    last has dead lanes behind it, one chunk of a whole rung, and three
+    whose last has 2 live lanes, fewer than the convolution reaches back
+    (its rows are partly the ones the chunk before left) — into a row
+    whose slot holds a last tenant's ``s`` and ``conv``: the logits of
+    every prompt position, then of the steps that decode from what the
+    chunks left, are the token-by-token reference's; ``s``, the ``conv``
+    rows and the latent rows are what ``prefill`` writes for the whole
+    prompt; no other row of the state moved."""
+    model, params, cfg = _model(use_pallas=use_pallas)
+    tokens = np.random.default_rng(1).integers(
+        0, model.vocab, size=n_prompt + 6).astype(np.int32)
+    L, S, window, slot = len(tokens), 16, 4, 2
+    n_pages = -(-L // S)
+    table = np.arange(1, n_pages + 1, dtype=np.int32)
+    start = _tenants(tuple(_pool(model, n_pages).arrays))
+    head, pools = _feed_chunks(model, params, start, tokens[:n_prompt], C,
+                               table, slot)
+    # what the whole-prompt prefill gives
+    rung = -(-n_prompt // S) * S
+    padded = np.zeros((1, rung), np.int32)
+    padded[0, :n_prompt] = tokens[:n_prompt]
+    logits, rows, s, conv = jit_prefill(model)(params, padded,
+                                               jnp.asarray([n_prompt]))
+    want = ref.logits_rows(params, jnp.asarray(tokens), 0, L, cfg,
+                           model.held)
+    std = want.std()
+    assert np.abs(head - want[:n_prompt]).max() / std < LOGIT_TOLERANCE
+    assert np.abs(head - np.asarray(logits[0, :n_prompt])).max() / std \
+        < LOGIT_TOLERANCE
+    for got, whole in ((pools[1][:, slot], s[:, 0]),
+                       (pools[2][:, slot], conv[:, 0]),
+                       (pools[0][:, 1:].reshape(model.cache_layers, -1,
+                                                model.row_width)[:, :n_prompt],
+                        rows[:, 0, :n_prompt])):
+        got, whole = np.asarray(got, np.float32), np.asarray(whole,
+                                                             np.float32)
+        assert np.abs(got - whole).max() < 1e-4 * np.abs(whole).max()
+    for before, after in zip(start[1:], pools[1:]):
+        others = [r for r in range(window) if r != slot]
+        assert bool((before[:, others] == after[:, others]).all())
+    # and the steps that decode from it
+    tail = _decode_from(model, params, pools, tokens, n_prompt, table, slot)
+    assert np.abs(np.stack(tail) - want[n_prompt:]).max() / std \
+        < LOGIT_TOLERANCE
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_the_rows_beside_a_chunk_step_as_they_step_alone(use_pallas):
+    """One mixed step — two rows that decode, in slots 3 and 0, and a
+    third request's second chunk (13 live lanes of 16, from position 16)
+    into slot 1 — against the plain step from the same pools. A linear
+    layer gives the rows BIT FOR BIT what it gives them alone: increment,
+    ``s`` and ``conv`` rows (layer 3, called by itself), and so does the
+    whole step as far as the first latent layer (state layers 0 and 1).
+    Behind it the rows agree to float32 rounding, not to the bit, on this
+    CPU: the latent layer's value up-projection (``_absorbed``'s batched
+    product, the parent class's, dots' since PR 40) rounds by the number
+    of lanes here. The slot nobody holds is as it was, and the chunk's
+    slot is what feeding the chunk with no row beside it leaves."""
+    model, params, _ = _model(use_pallas=use_pallas)
+    rng = np.random.default_rng(2)
+    window, per = 4, 3
+    seqs = [rng.integers(0, model.vocab, size=n).astype(np.int32)
+            for n in (19, 7, 29)]
+    slots = (3, 0, 1)
+    tables = np.arange(1, 3 * per + 1, dtype=np.int32).reshape(3, per)
+    pools = _tenants(tuple(_pool(model, 3 * per).arrays))
+    layout = kvcache.layout_for(model, pools)
+    assert layout.chunks and model.chunk_lanes
+    for seq, slot, table in zip(seqs[:2], slots, tables):
+        _, pools = _feed_chunks(model, params, pools, seq, 32, table, slot)
+    _, alone = _feed_chunks(model, params, pools, seqs[2], 16, tables[2], 1)
+    _, pools = _feed_chunks(model, params, pools, seqs[2][:16], 16,
+                            tables[2], 1)
+    plain, mixed = _steps(model)
+    toks, poss = (np.zeros((window,), np.int32) for _ in range(2))
+    toks[:2], poss[:2] = (5, 9), (19, 7)
+    pts = np.zeros((window, per), np.int32)
+    pts[:2] = tables[:2]
+    order = np.asarray([3, 0, 1, 2], np.int32)
+    fed = np.zeros((16,), np.int32)
+    fed[:13] = seqs[2][16:]
+    want, stepped = plain(params, pools, toks, poss, pts, order, 2)
+    got, both = mixed(params, pools, toks, poss, pts, order, 2, fed,
+                      tables[2], 16, 13, 1)
+
+    def close(a, b, eps=1e-5):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return np.abs(a - b).max() <= eps * np.abs(b).max()
+
+    assert close(got[:2], want[:2])
+    held = tables[:2].reshape(-1)
+    assert close(both[0][:, held], stepped[0][:, held])
+    assert close(both[0][:, tables[2]], alone[0][:, tables[2]])
+    for a, b, c, d in zip(pools[1:], stepped[1:], both[1:], alone[1:]):
+        assert bool((b[:2, [3, 0]] == c[:2, [3, 0]]).all())
+        assert close(c[:, [3, 0]], b[:, [3, 0]])
+        assert bool((a[:, 2] == c[:, 2]).all())
+        assert bool((a[:, 1] == b[:, 1]).all())           # not the plain's
+        assert close(c[:, 1], d[:, 1])
+
+    # one linear layer by itself, behind the latent layer
+    @functools.partial(jax.jit, static_argnums=1)
+    def layer(u, chunked):
+        state = layout.row_state(pools, order, jnp.arange(window) < 2)
+        if not chunked:
+            return model._linear_step(3, u[:window], params, state,
+                                      state.arrays)
+        return model._linear_step(
+            3, u, params, state, state.arrays,
+            jnp.concatenate([state.live, jnp.arange(16) < 13]), (1, 16, 13))
+
+    u = jax.random.normal(jax.random.PRNGKey(7), (window + 16,
+                                                  model.d_model))
+    (out_p, held_p), (out_m, held_m) = layer(u, False), layer(u, True)
+    assert bool((out_p[:2] == out_m[:2]).all())
+    for a, b, c in zip(pools[1:], held_p, held_m):
+        assert bool((b[:, [3, 0, 2]] == c[:, [3, 0, 2]]).all())
+        assert bool((a[2, 1] == b[2, 1]).all()) \
+            and not bool((a[2, 1] == c[2, 1]).all())
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer():
@@ -331,8 +537,9 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
 def test_a_slots_second_tenant_streams_what_it_streams_alone(use_pallas):
     """A window of ONE row: every request is its slot's next tenant, and
-    the prefill writes the slot whole — a short prompt after a long one
-    inherits neither state nor convolution rows."""
+    its first chunk starts from zeros whatever the slot holds — a short
+    prompt after a long one inherits neither state nor convolution
+    rows."""
     model, params, _ = _model(use_pallas=use_pallas)
     prompts = _prompts(4, (30, 2, 9))
     together, st = _serve(model, params, prompts, n=8, window=1)
