@@ -47,6 +47,24 @@ from nothing else (no argument, no model's name):
 
 Whatever the height, ``R`` rows are laid out: the dropless worst case,
 every slot held and every expert's last tile all but empty.
+
+**The group-limited router chooses by reductions, not by sorts.** On
+this chip a ``jax.lax.top_k`` over a short minor axis is a full
+``sort``: the three of :func:`route_grouped_sigmoid` (each group's 2
+best of 64, the 4 best of 8 group scores, the 8 best of 512) were
+``sort f32[1088,8,64]`` 0.69 ms and ``sort f32[1088,512]`` 0.14 ms a
+layer over a mixed step's 1,088 lanes, 5 ms of a 41.7 ms step
+(``PERF.md`` section 5), to pick 8 numbers of 512. Taking ``k <= 8`` of
+a row needs ``k`` maxima, so a group's two best are a maximum, the first
+lane that holds it masked, and a second maximum; the groups that stay
+are the ones fewer than ``topk_group`` others beat (one compare and
+count); and the ``top_k`` experts are ``top_k`` passes of arg-max, each
+masking the lane it took. The same float32 scores, order and ties as
+``jax.lax.top_k`` gives them, so no served token changes
+(``tests/test_latent_moe_serving.py`` keeps the three ``top_k`` as the
+oracle). :func:`route_softmax_topk` keeps its one full-width ``top_k``
+(no record shows it as a cost), and :func:`expert_ffn`'s sort ORDERS
+the slots: another job.
 """
 from __future__ import annotations
 
@@ -156,8 +174,21 @@ def route_grouped_sigmoid(x, w_gate, bias, *, n_group, topk_group, top_k,
     as published), and the ``top_k`` best experts of what is left are
     chosen. The weights are the chosen experts' ``s`` (without the
     bias) over their sum (+1e-20), times ``scaling``. Returns ``(topi
-    (T, top_k) int32, topw (T, top_k) float32)``; ties go to the lower
-    index (``jax.lax.top_k``)."""
+    (T, top_k) int32, topw (T, top_k) float32)``, ``topi`` in descending
+    score, ties to the lower index, as ``jax.lax.top_k`` gives them
+    (the order is :func:`expert_ffn`'s slot numbering and the order of
+    the weights' float32 sum).
+
+    The choice is made by reductions, never by a sort (a ``top_k`` over
+    a short minor axis is a full sort on this chip: 0.69 ms a layer at
+    1,088 lanes; the module's header). A group's two best: its maximum,
+    the FIRST lane that holds it masked, the maximum of the rest. The
+    groups that stay: those that fewer than ``topk_group`` groups beat,
+    a group of equal score and lower index beating it. The ``top_k``
+    best: ``top_k`` passes of arg-max (the first of equal values), each
+    putting the lane it took to ``-inf`` — free as a mark, the scores
+    being a sigmoid plus a finite bias, or 0. Where ``n_group ==
+    topk_group`` every group stays and the group stage is not traced."""
     import jax
     import jax.numpy as jnp
     T = x.shape[0]
@@ -168,15 +199,29 @@ def route_grouped_sigmoid(x, w_gate, bias, *, n_group, topk_group, top_k,
         with jax.default_matmul_precision("highest"):
             s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                        w_gate.astype(jnp.float32)))
-        choice = s + bias.astype(jnp.float32)
-        grouped = choice.reshape(T, n_group, E // n_group)
-        group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)   # (T, G)
-        _, keep = jax.lax.top_k(group_score, topk_group)
-        group_mask = jnp.zeros((T, n_group), bool).at[
-            jnp.arange(T)[:, None], keep].set(True)
-        masked = jnp.where(group_mask[:, :, None], grouped,
-                           0.0).reshape(T, E)
-        _, topi = jax.lax.top_k(masked, top_k)
+        masked = s + bias.astype(jnp.float32)
+        if n_group != topk_group:
+            grouped = masked.reshape(T, n_group, E // n_group)
+            first = jnp.argmax(grouped, axis=-1, keepdims=True)
+            second = jnp.max(
+                jnp.where(jnp.arange(E // n_group) == first, -jnp.inf,
+                          grouped), axis=-1)
+            group_score = jnp.max(grouped, axis=-1) + second     # (T, G)
+            mine = group_score[:, :, None]
+            other = group_score[:, None, :]
+            g = jnp.arange(n_group)
+            beats = (other > mine) | ((other == mine)
+                                      & (g[None, :] < g[:, None]))
+            stays = jnp.sum(beats, axis=-1) < topk_group         # (T, G)
+            masked = jnp.where(stays[:, :, None], grouped,
+                               0.0).reshape(T, E)
+        lane = jnp.arange(E)
+        topi = []
+        for _ in range(top_k):
+            best = jnp.argmax(masked, axis=-1, keepdims=True)
+            topi.append(best)
+            masked = jnp.where(lane == best, -jnp.inf, masked)
+        topi = jnp.concatenate(topi, axis=1)
         topw = jnp.take_along_axis(s, topi, axis=1)
         topw = topw / (topw.sum(-1, keepdims=True) + 1e-20) * scaling
         return topi.astype(jnp.int32), topw

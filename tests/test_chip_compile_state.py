@@ -34,6 +34,13 @@ def _named(text, kernel):
                       % kernel, text, re.M)
 
 
+def _sorted(text):
+    """The first operand's dimensions of every ``sort`` of a compiled
+    program, as its HLO line writes them (``"1088,512"``)."""
+    return re.findall(r"^\s*(?:ROOT )?%[\w.-]+ = \(?\w+\[([\d,]*)\][^=]* "
+                      r"sort\(", text, re.M)
+
+
 def _hybrid_linear_case(chip):
     """``benchmark/configs/Ling-3.0-flash.json`` as both of its cases
     compile it: ``(model, holder, W, rung, M, feed, carried, carried
@@ -119,6 +126,9 @@ def test_hybrid_linear_programs_compile_and_fit(chip, monkeypatch):
     assert ".e128.m2560.k2560.n768.bfloat16.r16.gated" in text
     assert text.count('custom_call_target="tpu_custom_call"') \
         == 5 + 2 + 2 * moe_layers
+    # the router sorts nothing (the next case's docstring): one sort an
+    # expert layer, expert_ffn's ordering of rows x k slots
+    assert _sorted(text) == ["%d" % (W * model.top_k)] * moe_layers
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= carried_bytes, mem
     assert mem.temp_size_in_bytes < 0.1e9, mem      # no state or pool copy
@@ -160,7 +170,15 @@ def test_hybrid_linear_mixed_step_compiles_and_fits(chip, monkeypatch):
     the donated pool and state arrays are updated in place: the chunk's
     row of ``s`` is read and written as a 2 MB slice a layer, and NO copy
     of the whole array (0.67 GB: 1.6 ms a layer) lies among the
-    temporaries, which fit beside 9.6 GB of weights, pool and state."""
+    temporaries, which fit beside 9.6 GB of weights, pool and state.
+    Since PR 51 the router chooses by reductions: this compiler made of
+    its three ``lax.top_k`` full sorts (``sort f32[1088,8,64]`` 0.69 ms
+    and ``sort f32[1088,512]`` 0.14 ms a layer on the chip, ``PERF.md``
+    section 5), and what the program still sorts is ``expert_ffn``'s
+    ordering of the 8,704 (lane, choice) slots, once an expert layer —
+    counted here in the COMPILED program, where the jaxpr's pin
+    (``test_latent_moe_serving.py``) cannot see what a lowering makes of
+    an arg-max."""
     from mxnet_tpu import profiler
     from mxnet_tpu.serving import DecodeServer
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
@@ -192,6 +210,9 @@ def test_hybrid_linear_mixed_step_compiles_and_fits(chip, monkeypatch):
                      text)
     assert text.count('custom_call_target="tpu_custom_call"') \
         == 5 + 2 + 2 * moe_layers
+    # no sort of lanes x 8 x 64, lanes x 8 or lanes x 512: the slot
+    # ordering's one sort an expert layer is all there is
+    assert _sorted(text) == ["%d" % ((W + C) * model.top_k)] * moe_layers
     # the chunkwise rule's walk: one loop a linear layer, carrying ONE
     # row's S
     assert len(re.findall(r"%while[.\d]* = \(s32\[\][^,]*, "

@@ -647,12 +647,18 @@ BLOCK = dict(vocab_size=256, hidden_size=128, num_hidden_layers=2,
              mask_token_id=255, max_position_embeddings=512)
 # sha1 of the jaxprs' text on the commit PR 38 started from (4c0d884),
 # by ``_program_jaxprs`` there; the six expert programs re-pinned by
-# PR 45, whose ``expert_ffn`` numbers its slots choice-major (the toy
-# programs, which have no expert layer, are 4c0d884's still)
+# PR 45, whose ``expert_ffn`` numbers its slots choice-major; the four
+# of the sigmoid router (dots, xing) re-made by PR 51, whose
+# ``route_grouped_sigmoid`` chooses by reductions where it sorted (held
+# to the three-``top_k`` form bit for bit by
+# ``test_latent_moe_serving.py``). sdar's programs, whose router is
+# ``route_softmax_topk``, are PR 45's still, and the toy programs, which
+# have no expert layer, 4c0d884's: the proof that PR 51 touched no other
+# router's cells
 PARENT_PROGRAMS = {
     "toy.prefill": "b5050806fa1fe556", "toy.step": "d4ebfb3472495617",
-    "dots.prefill": "bac5948b77c693b1", "dots.step": "b8d32ee340413e73",
-    "xing.prefill": "e62dce4ccde6f20c", "xing.step": "c4a1637226543733",
+    "dots.prefill": "0dcf420bbe7fae71", "dots.step": "c00a1e6e53e5e2b0",
+    "xing.prefill": "609d6a9023994f47", "xing.step": "0744fac67c03efa9",
     "sdar.prefill": "da37af03131dfff8", "sdar.step": "0d68eba0c238c13c"}
 
 

@@ -370,6 +370,115 @@ def test_router_against_the_reference_groups_scaling_and_ties():
     np.testing.assert_allclose(tw, 2.5 / 4, rtol=1e-6)
 
 
+def _route_by_three_top_k(x, w_gate, bias, *, n_group, topk_group, top_k,
+                          scaling=1.0):
+    """``route_grouped_sigmoid`` as it was until PR 51 — the choice by
+    three ``jax.lax.top_k`` (full sorts on the TPU) — kept as the oracle
+    of the one that chooses by reductions; ``grouped``, ``group_score``
+    and ``masked`` come back too, for a case to show the ties it holds."""
+    T = x.shape[0]
+    E = w_gate.shape[-1]
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                   w_gate.astype(jnp.float32)))
+    choice = s + bias.astype(jnp.float32)
+    grouped = choice.reshape(T, n_group, E // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)   # (T, G)
+    _, keep = jax.lax.top_k(group_score, topk_group)
+    group_mask = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    masked = jnp.where(group_mask[:, :, None], grouped,
+                       0.0).reshape(T, E)
+    _, topi = jax.lax.top_k(masked, top_k)
+    topw = jnp.take_along_axis(s, topi, axis=1)
+    topw = topw / (topw.sum(-1, keepdims=True) + 1e-20) * scaling
+    return topi.astype(jnp.int32), topw, grouped, group_score, masked
+
+
+def _primitives(jaxpr):
+    """The names of a jaxpr's primitives, those of inner jaxprs too (the
+    jaxpr's TEXT will not do: a gather's ``indices_are_sorted`` is in
+    it)."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found |= _primitives(sub)
+    return found
+
+
+def _router_case(scores, T, E):
+    """``(x, w_gate, bias)`` of one kind of scores. ``random``: a normal
+    gate. ``tied``: every score 0.5. ``boundary``: logits on a grid of
+    1/4 through an identity gate (exact at "highest") under a bias of
+    three levels — a group's two best equal, two groups' sums equal at
+    the last group kept, equal scores at the last expert chosen, in one
+    group and across two. ``zeros``: that, under a bias so negative that
+    the 0.0 of a dropped group beats every score that stayed."""
+    keys = jax.random.split(jax.random.PRNGKey(T + E), 3)
+    if scores == "random":
+        return (jax.random.normal(keys[0], (T, 48)),
+                jax.random.normal(keys[1], (48, E)) * 0.3,
+                jax.random.normal(keys[2], (E,)) * 0.05)
+    if scores == "tied":
+        return (jax.random.normal(keys[0], (T, 48)), jnp.zeros((48, E)),
+                jnp.zeros(E))
+    x = jnp.round(jax.random.normal(keys[0], (T, E)) * 4) / 4
+    b = jax.random.randint(keys[1], (E,), -1, 2) / 16.0
+    return (x, jnp.eye(E, dtype=jnp.float32),
+            b - 2.0 if scores == "zeros" else b)
+
+
+# the served shapes: ling's step and its mixed step, dots' mixed step,
+# xing's verify positions (one group: the group stage is not traced)
+@pytest.mark.parametrize("scores", ["random", "tied", "boundary", "zeros"])
+@pytest.mark.parametrize("T,E,n_group,topk_group,top_k", [
+    (64, 512, 8, 4, 8), (1088, 512, 8, 4, 8), (320, 256, 8, 4, 8),
+    (128, 64, 1, 1, 4)])
+def test_the_router_chooses_by_reductions_what_three_top_k_chose(
+        T, E, n_group, topk_group, top_k, scores):
+    """``topi`` element for element in order and ``topw`` bit for bit,
+    and no ``sort`` or ``top_k`` in the traced router."""
+    kw = dict(n_group=n_group, topk_group=topk_group, top_k=top_k,
+              scaling=2.5)
+    x, w, b = _router_case(scores, T, E)
+    ri, rw, grouped, group_score, masked = (
+        np.asarray(a) for a in jax.jit(functools.partial(
+            _route_by_three_top_k, **kw))(x, w, b))
+    route = functools.partial(moe.route_grouped_sigmoid, **kw)
+    ti, tw = (np.asarray(a) for a in jax.jit(route)(x, w, b))
+    assert ti.dtype == ri.dtype == np.int32
+    assert tw.dtype == rw.dtype == np.float32
+    assert np.array_equal(ti, ri)
+    assert np.array_equal(tw.view(np.uint32), rw.view(np.uint32))
+    # the case holds what it is there for
+    drops = n_group != topk_group
+    best = -np.sort(-masked, axis=-1)
+    if scores == "tied":
+        assert (masked == 0.5).sum(-1).min() >= top_k
+        assert (ti == np.arange(top_k)).all()
+    if scores == "boundary":
+        at_k = best[:, top_k - 1] == best[:, top_k]
+        assert at_k.any()
+        if drops:
+            two = -np.sort(-grouped, axis=-1)[..., :2]
+            assert (two[..., 0] == two[..., 1]).any()
+            rank = -np.sort(-group_score, axis=-1)
+            assert (rank[:, topk_group - 1] == rank[:, topk_group]).any()
+            # the k-th score is held in two groups of some row
+            holds = (masked == best[:, top_k - 1:top_k]).reshape(
+                T, n_group, -1).any(-1)
+            assert (holds.sum(-1)[at_k] > 1).any()
+    if scores == "zeros":
+        assert (best[:, 0] <= 0.0).all()
+        if drops:
+            assert (np.take_along_axis(masked, ti, 1) == 0.0).all()
+            assert (masked < 0).any(axis=1).all()
+    found = _primitives(jax.make_jaxpr(route)(x, w, b).jaxpr)
+    assert "argmax" in found
+    assert not {p for p in found if "sort" in p or "top_k" in p}, found
+
+
 def test_yarn_frequencies_and_scale_against_hand_values():
     f = yarn_inv_freq(64, 10000.0, 40.0, 4096, 32.0, 1.0)
     assert f.shape == (32,)
