@@ -29,7 +29,7 @@ KV cache, streaming, priorities, live weight swap) lives in
         for tok in req.tokens():                     # streams live
             ...
 
-Five models implement the decode-model contract: ``ToyDecoderLM``
+Six models implement the decode-model contract: ``ToyDecoderLM``
 (per-head K/V, one position a step), ``latent_moe.LatentMoEDecoderLM``
 (a latent cache, routed experts; with ``hc_mult`` > 1 several residual
 streams mixed by hyper-connections, and with its next-token module the
@@ -44,7 +44,12 @@ server beside the pages of a latent-attention layer a group) and
 ``WindowMoEDecoderLM`` (``window_moe``; the state form again:
 sliding-window layers whose last keys and values are a RING a row beside
 the pages of the full-attention layers, two counts of gated query heads
-over one of key/value heads, softmax-routed experts and a shared one).
+over one of key/value heads, softmax-routed experts and a shared one)
+and ``SSMHybridDecoderLM`` (``ssm_hybrid``; the state form a third time:
+state-space layers whose selective scan keeps ``(states, channels)``
+float32 and the last rows of a biased convolution a row, thirteen to
+each full-attention layer with ONE key/value head and no position
+encoding, a dense MLP, the head tied to the embedding).
 
 Fleet serving — a :class:`Router` fronting N decode replicas with
 per-tenant weighted-fair quotas, graceful drain, and transparent
@@ -63,6 +68,7 @@ from .server import (InferenceServer, ServerOverloadedError,
 from .kvcache import KVCachePool
 from .decode import DecodeServer, DecodeRequest, ToyDecoderLM
 from .window_moe import WindowMoEDecoderLM
+from .ssm_hybrid import SSMHybridDecoderLM
 from .fleet import Replica, FleetMonitor
 from .router import Router, RouterRequest
 
@@ -70,5 +76,6 @@ __all__ = ["InferenceServer", "BucketLadder", "pad_batch", "slice_rows",
            "ServerOverloadedError", "RequestTimeoutError",
            "ServerClosedError", "validate_priority",
            "KVCachePool", "DecodeServer", "DecodeRequest",
-           "ToyDecoderLM", "WindowMoEDecoderLM", "Router", "RouterRequest",
+           "ToyDecoderLM", "WindowMoEDecoderLM", "SSMHybridDecoderLM",
+           "Router", "RouterRequest",
            "Replica", "FleetMonitor"]

@@ -1507,13 +1507,15 @@ class KVCachePool:
         # behind the pages' own: ``state_rows`` rows, a server's window
         self.state_specs = tuple(state)
         self.state_rows = int(state_rows) if state else 0
-        self.state_bytes = 0
+        self.state_bytes, self.state_bytes_by_array = 0, {}
         if state:
             self.layout = _with_row_state(self.layout, self.state_specs,
                                           int(state_layers))
             held = self.layout.state_arrays(self.state_rows)
-            self.state_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
-                                   for _name, shape, dt in held)
+            self.state_bytes_by_array = {
+                name: math.prod(shape) * jnp.dtype(dt).itemsize
+                for name, shape, dt in held}
+            self.state_bytes = sum(self.state_bytes_by_array.values())
             carried += held
         # every array a program carries, in the layout's order (an int8
         # pool's scales among them). Allocated ON the target device: a
@@ -1530,6 +1532,9 @@ class KVCachePool:
             self.shards = math.prod(whole) // math.prod(
                 where[0].shard_shape(whole))
             self.state_bytes //= self.shards
+            self.state_bytes_by_array = {
+                name: n // self.shards
+                for name, n in self.state_bytes_by_array.items()}
         else:
             where = [device] * len(carried)
         self.arrays = [jnp.zeros(shape, dt, device=at)
@@ -1845,6 +1850,7 @@ class KVCachePool:
             if self.state_specs:
                 out["state"] = {
                     "bytes": self.state_bytes,
+                    "bytes_by_array": dict(self.state_bytes_by_array),
                     "rows": self.state_rows,
                     "rows_live": self.state_rows - len(self._rows_free),
                     "writes": self._state_writes,
