@@ -28,8 +28,19 @@ What a row carries from token to token is ``h`` (``(N, E)`` float32,
 channel-minor: ``parallel.selective_scan`` says why) and the last ``K -
 1`` rows of ``u~`` — ``state_arrays = (("h", (N, E), "float32"),
 ("conv", ((K - 1) E,), dtype))``, whatever the context. Decode:
-:func:`parallel.selective_scan.ssm_step` on the row's slot, in place; a
-prompt: :func:`parallel.selective_scan.ssm_chunk`. Positions at or past
+:func:`parallel.selective_scan.ssm_conv_step` then
+:func:`~parallel.selective_scan.ssm_step` over the layer's plane of each,
+in place; a prompt: :func:`parallel.selective_scan.ssm_chunk`.
+**:meth:`~SSMHybridDecoderLM.decode` runs in SLOT order**: a step's rows
+are a permutation of all the window's (``RowState.slots``), so the rows'
+lanes are brought into the order their state lies in ONCE, at the
+embedding (the token ids are permuted, not the residual), every
+lane-wise layer and every state-space mixer runs there — both kernels
+walk the arrays in aligned blocks, nothing gathered or scattered a layer
+— and the lanes go back to the step's order only where a row's identity
+is the server's: around an attention layer's ``attend`` (its page tables
+are by row), for the head, and in the keys and values returned. A
+chunk's lanes, behind the rows, never move. Positions at or past
 a prompt's true length leave both untouched (``delta = 0``; the
 convolution's rows are taken at the true end), and so does a dead row
 of the window. ``u~`` is rounded to the parameters' dtype before the
@@ -432,9 +443,12 @@ class SSMHybridDecoderLM:
             u, delta, b, c, a, zeros)
         return self._mix_out(i, y, u, z, p), S, rows.reshape(B, -1)
 
-    def _ssm_step(self, i, h, p, state, arrays, live=None, chunk=None):
-        """Layer ``i`` over one token a row ``h (B, D)`` on the rows'
-        slots: ``(increment, (h, conv))``, the state arrays updated.
+    def _ssm_step(self, i, h, p, arrays, live, chunk=None):
+        """Layer ``i`` over one token a row, in SLOT order: lane ``s`` of
+        ``h (B, D)`` and of ``live (B,)`` is the row whose state lies in
+        row ``s`` of the state arrays, so both kernels walk the arrays
+        themselves in aligned blocks. Returns ``(increment, (h, conv))``,
+        the state arrays updated in place.
 
         On a MIXED step ``h`` is ``(B + C, D)``: behind the rows, the
         ``C`` lanes of ONE request's chunk, ``chunk = (its row of the
@@ -443,42 +457,32 @@ class SSMHybridDecoderLM:
         stream of ``win``, ``wx``, ``wdt``, ``wout`` — and do what
         :meth:`_ssm_prefill` does for a whole prompt, FROM THE ROW'S
         STATE (:meth:`_chunk_conv`, :meth:`_chunk_scan`). The request is
-        no live row of the step: the rows' kernel passes its row through,
-        and the chunk's lanes alone write it."""
+        no live row of the step: the rows' kernels pass its row through,
+        and the chunk's lanes alone write it, behind them — ONE row by a
+        scalar index each, a slice's update and no scatter (which would
+        widen a 16-bit array to float32, whole)."""
         import jax
         import jax.numpy as jnp
-        from ..parallel.selective_scan import ssm_step
+        from ..parallel.selective_scan import ssm_conv_step, ssm_step
         l = "l%d." % i
         h_all, conv_all = arrays
-        j, K, E = self.state_layer(i), self.conv, self.d_inner
-        B = state.slots.shape[0]
+        j = self.state_layer(i)
+        B = h_all.shape[1]
         x = self._rms(h, p[l + "mix_g"])
         raw, z = self._split_in(i, x, p)
         with jax.named_scope("mx_ssm_conv"):
-            before = conv_all[j, state.slots]             # (B, (K-1) E)
-            window = jnp.concatenate(
-                [before.reshape(B, K - 1, E), raw[:B, None]], axis=1)
-            y = (p[l + "conv_w"] * window.astype(jnp.float32)).sum(1)
-            after = jnp.where(state.live[:, None],
-                              window[:, 1:].reshape(B, -1), before)
-            # the step's rows are ALL the window's, so the rows go back
-            # by a gather through the inverse permutation and one
-            # whole-plane write: a scatter would widen a 16-bit array to
-            # float32, whole
-            plane = after[state.inverse]
+            y, conv_all = ssm_conv_step(conv_all, j, raw[:B], live[:B],
+                                        p[l + "conv_w"],
+                                        force_pallas=self.use_pallas)
             if chunk is not None:
-                # the request's row rides in the same write
                 tail, rows = self._chunk_conv(raw[B:], p[l + "conv_w"],
                                               conv_all[j, chunk[0]], chunk)
                 y = jnp.concatenate([y, tail])
-                plane = jnp.where((jnp.arange(B) == chunk[0])[:, None],
-                                  rows[None], plane)
-            conv_all = conv_all.at[j].set(plane)
-        u, delta, b, c = self._scan_inputs(
-            i, y, p, state.live if live is None else live)
+                conv_all = conv_all.at[j, chunk[0]].set(rows)
+        u, delta, b, c = self._scan_inputs(i, y, p, live)
         a = -jnp.exp(p[l + "A_log"])
-        y, h_all = ssm_step(h_all, j, state.slots, u[:B], delta[:B], b[:B],
-                            c[:B], a, force_pallas=self.use_pallas)
+        y, h_all = ssm_step(h_all, j, u[:B], delta[:B], b[:B], c[:B], a,
+                            force_pallas=self.use_pallas)
         if chunk is not None:
             tail, h_all = self._chunk_scan(
                 j, (u[B:], delta[B:], b[B:], c[B:], a), h_all, chunk)
@@ -561,9 +565,12 @@ class SSMHybridDecoderLM:
         first lane's position, the live lanes)``. Everything is lane-wise
         but the two mixers: an attention layer's ``attend`` is the
         layout's split one (``attend_chunk``), a state-space layer runs
-        the rows through ``ssm_step`` and the chunk through ``ssm_chunk``
-        from the request's row of ``h`` and ``conv``, which the chunk's
-        lanes write (:meth:`_ssm_step`). ``live (B + C,)``: a lane that
+        the rows through ``ssm_conv_step`` and ``ssm_step`` and the chunk
+        through ``ssm_chunk`` from the request's row of ``h`` and
+        ``conv``, which the chunk's lanes write (:meth:`_ssm_step`). The
+        rows' lanes run in SLOT order between the embedding and the head
+        (the module docstring says where they go back); what is handed
+        in and what is returned is by row. ``live (B + C,)``: a lane that
         is not live moves no state; ``head (B + 1,)``: the lanes that
         reach the head, ``logits`` theirs alone; the keys and values come
         back for every lane, ``(cache_layers, B + C, Hkv, Dh)``.
@@ -573,22 +580,31 @@ class SSMHybridDecoderLM:
         del positions
         p = params
         arrays = tuple(state.arrays)
-        h = p["embed"][tokens].astype(jnp.float32)
+        # the rows' lanes in slot order (the chunk's, behind them, stay):
+        # lane ``s`` is the row of the step whose state is row ``s`` of
+        # the arrays, ``to_slot[s]`` of the lanes as they were handed
+        behind = jnp.arange(state.slots.shape[0], len(tokens),
+                            dtype=jnp.int32)
+        to_slot = jnp.concatenate([state.inverse, behind])
+        to_row = jnp.concatenate([state.slots, behind])
+        live = (state.live if live is None else live)[to_slot]
+        h = p["embed"][jnp.asarray(tokens)[to_slot]].astype(jnp.float32)
         ks, vs = [], []
         for i, attends in enumerate(self.kinds):
             if attends:
-                q, k, v = self._qkv(i, self._rms(h, p["l%d.mix_g" % i]), p)
+                # the server's ``attend`` knows a row by its place in the
+                # step: there and back, ``(B + C, D)`` each way
+                x = self._rms(h, p["l%d.mix_g" % i])[to_row]
+                q, k, v = self._qkv(i, x, p)
                 a = attend(self.cache_layer(i), q, k, v, scale=self.scale,
                            force_pallas=self.use_pallas)
-                h = h + self._attn_out(i, a, p)
+                h = h + self._attn_out(i, a, p)[to_slot]
                 ks.append(k)
                 vs.append(v)
             else:
-                out, arrays = self._ssm_step(i, h, p, state, arrays, live,
-                                             chunk)
+                out, arrays = self._ssm_step(i, h, p, arrays, live, chunk)
                 h = h + out
             h = h + self._mlp(i, h, p)
-        if head is not None:
-            # a chunk's lanes do not pay the head
-            h = h[head]
+        # a chunk's lanes do not pay the head
+        h = h[to_row if head is None else to_row[head]]
         return (self._logits(h, p), jnp.stack(ks), jnp.stack(vs), *arrays)

@@ -6,6 +6,7 @@ vocabulary — compiled by the TPU's own compiler for a DESCRIBED v5e. A
 compile that passes is not a chip run."""
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,9 +65,13 @@ def _case(chip):
 @pytest.mark.parametrize("lanes", [0, 512], ids=["step", "mixed-c512"])
 def test_ssm_hybrid_step_programs_compile_and_fit(chip, monkeypatch, lanes):
     """``decode:step`` and ``decode:step:chunk:c512`` of the whole model
-    for one described v5e: ``mx_ssm_step`` a state-space layer under the
-    name a profile's reader looks for (and ``mx_ssm_chunk`` beside it on
-    the mixed step: the rows keep their kernel beside a chunk), the
+    for one described v5e: ``mx_ssm_conv`` and ``mx_ssm_step`` a
+    state-space layer, the second under the name a profile's reader looks
+    for (and ``mx_ssm_chunk`` beside them on the mixed step: the rows
+    keep their kernels beside a chunk) — the step runs in slot order, so
+    NO operation outside a kernel gathers, shifts or writes a plane of
+    the ``conv`` rows (``bf16[128,15360]``) and none stacks ``delta`` on
+    ``delta u`` (``f32[128,2,5120]``): what PR 53 took out of a step — the
     20-over-1 attention layers through the packed pool's multi-query
     kernel and its in-place write, nothing fallen to ``jnp``; the donated
     pages AND the donated state arrays updated in place, NO copy of
@@ -92,11 +97,13 @@ def test_ssm_hybrid_step_programs_compile_and_fit(chip, monkeypatch, lanes):
              for k, v in profiler.counters().items()
              if k.endswith(("_pallas", "_jnp")) and v != before.get(k, 0)}
     assert not any(k.endswith("_jnp") for k in chose), chose
-    assert chose["ssm_step_pallas"] == 26
+    assert chose["ssm_step_pallas"] == chose["ssm_conv_pallas"] == 26
     assert chose["block_decode_pallas"] == 2
     text = step.as_text()
     assert len(_named(text, "ssm_step")) == 26
     assert "mx_ssm_step.b128.e5120.n16" in text
+    assert len(_named(text, "ssm_conv")) == 26
+    assert "mx_ssm_conv.b128.e5120.k4" in text
     assert len(_named(text, "block_decode")) == 2
     assert len(_named(text, "block_write")) == 2
     assert len(_named(text, "ssm_chunk")) == (26 if lanes else 0)
@@ -104,7 +111,13 @@ def test_ssm_hybrid_step_programs_compile_and_fit(chip, monkeypatch, lanes):
         assert chose["ssm_chunk_pallas"] == 26
         assert "mx_ssm_chunk.c512.e5120.n16" in text
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == 30 + (26 if lanes else 0)
+        == 56 + (26 if lanes else 0)
+    # what walking the state in slot order took away: no plane of the
+    # ``conv`` rows gathered, shifted or written outside the kernel, and
+    # no ``delta`` stacked on ``delta u``
+    assert not re.findall(r"^\s*(?:ROOT )?%[\w.-]+ = bf16\[128,15360\]", text,
+                          re.M)
+    assert "f32[128,2,5120]" not in text
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= case.carried_bytes, mem
     assert mem.temp_size_in_bytes < 0.2e9, mem      # no state or pool copy

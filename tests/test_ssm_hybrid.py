@@ -25,7 +25,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
 from benchmark.reference import ssm_hybrid_lm as ref            # noqa: E402
-from mxnet_tpu import compile_watch, fault, telemetry           # noqa: E402
+from mxnet_tpu import (compile_watch, fault, profiler,          # noqa: E402
+                       telemetry)
 from mxnet_tpu.base import MXNetError                           # noqa: E402
 from mxnet_tpu.parallel import selective_scan                   # noqa: E402
 from mxnet_tpu.serving import (DecodeServer, KVCachePool,        # noqa: E402
@@ -76,32 +77,110 @@ def _scan_inputs(T, E=128, N=16, seed=0):
             a)
 
 
-def test_step_kernel_agrees_with_jnp_in_place_and_leaves_dead_rows():
-    """The Pallas step (interpreted) against the jnp one on a window of 6
-    rows and 2 state layers: the same outputs, the rows' states updated
-    where they lie, every other layer and the rows that are not live
-    (``delta = 0``) exactly as they were."""
-    B, E, N, layers = 6, 256, 16, 2
+def _scrambled(B, seed):
+    """``(slots, inverse, dead)``: a step's rows on scrambled slots of a
+    window of ``B`` (row ``i`` works on slot ``slots[i]``; lane ``s`` of
+    the slot order is row ``inverse[s]``), a third of the rows not
+    live."""
+    rng = np.random.default_rng(seed)
+    slots = rng.permutation(B).astype(np.int32)
+    inverse = np.argsort(slots).astype(np.int32)
+    dead = np.zeros((B,), bool)
+    dead[rng.permutation(B)[:max(1, B // 3)]] = True
+    return slots, inverse, dead
+
+
+@pytest.mark.parametrize("B", [6, 16, 24, 20], ids=[
+    "one_block_of_6", "two_blocks", "three_blocks", "20_rows_one_block"])
+def test_step_kernel_agrees_with_jnp_in_place_and_leaves_dead_rows(B):
+    """The Pallas step (interpreted; blocks of 8 consecutive slots, or
+    ONE block of the whole window where it is not whole blocks) against
+    the jnp one and against the recurrence written by ROW through a
+    scrambled ``slots`` — the lanes reach either in slot order, ``x[
+    inverse]`` — on 2 state layers: the same outputs, a row's state
+    updated where it lies, every other layer and the rows that are not
+    live (``delta = 0``) exactly as they were."""
+    E, N, layers = 256, 16, 2
     u, delta, b, c, a = _scan_inputs(B, E, N, seed=1)
     state = jax.random.normal(jax.random.PRNGKey(9), (layers, B, N, E))
-    slots = jnp.asarray([3, 0, 5, 1, 4, 2], jnp.int32)
-    dead = jnp.asarray([False, False, True, False, True, True])
+    slots, inverse, dead = _scrambled(B, seed=B)
     delta = jnp.where(dead[:, None], 0.0, delta)
-    args = (state, 1, slots, u, delta, b, c, a)
+    # by row, through the slots: what the step computed before it walked
+    # the state in slot order
+    h = jnp.exp(delta[:, None, :] * a) * state[1, slots] \
+        + b[:, :, None] * (delta * u)[:, None, :]
+    y_row = jnp.sum(h * c[:, :, None], axis=1)
+    s_row = state.at[1, slots].set(h)
+    args = (state, 1, *(x[inverse] for x in (u, delta, b, c)), a)
+    before = dict(profiler.counters())
     y_j, s_j = selective_scan.ssm_step(*args)
     y_p, s_p = jax.jit(functools.partial(
         selective_scan.ssm_step, force_pallas=True),
         static_argnums=(1,))(*args)
+    assert profiler.counters()["ssm_step_jnp"] \
+        == before.get("ssm_step_jnp", 0) + 1
+    assert profiler.counters()["ssm_step_pallas"] \
+        == before.get("ssm_step_pallas", 0) + 1
     # float32 in another order of operations: a few 1e-7 of the largest
-    assert np.abs(np.asarray(y_j - y_p)).max() < 1e-5 * float(
-        np.abs(y_j).max())
-    assert np.abs(np.asarray(s_j - s_p)).max() < 1e-5
-    for got in (s_j, s_p):
-        assert bool(jnp.isfinite(got).all())
-        assert bool((got[0] == state[0]).all())            # another layer
-        for row, gone in zip(np.asarray(slots), np.asarray(dead)):
-            same = bool((got[1, row] == state[1, row]).all())
+    for y, s_ in ((y_j, s_j), (y_p, s_p)):
+        assert np.abs(np.asarray(y[slots] - y_row)).max() < 1e-5 * float(
+            np.abs(y_row).max())
+        assert np.abs(np.asarray(s_ - s_row)).max() < 1e-5
+        assert bool(jnp.isfinite(s_).all())
+        assert bool((s_[0] == state[0]).all())             # another layer
+        for row, gone in zip(slots, dead):
+            same = bool((s_[1, row] == state[1, row]).all())
             assert same == bool(gone), row
+
+
+@pytest.mark.parametrize("B", [6, 16, 32, 20], ids=[
+    "one_block_of_6", "one_block", "two_blocks", "20_rows_one_block"])
+@pytest.mark.parametrize("K", [4, 2])
+def test_conv_kernel_shifts_the_rows_in_place_bit_for_bit(K, B):
+    """``mx_ssm_conv`` (interpreted; blocks of 16 consecutive slots, or
+    ONE block of the whole window where it is not whole blocks) against
+    ``_jnp_conv_step`` and against the convolution written by ROW through
+    a scrambled ``slots``, on bfloat16 rows of 2 state layers: the same
+    float32 sums; a live slot's rows shifted by one with the step's input
+    behind them, bit for bit; a dead slot — the chunk's request row is
+    one: no live row of its step — and the other layer exactly as they
+    were."""
+    E, layers = 128, 2
+    keys = jax.random.split(jax.random.PRNGKey(K * 100 + B), 3)
+    conv = jax.random.normal(keys[0], (layers, B, (K - 1) * E)).astype(
+        jnp.bfloat16)
+    raw = jax.random.normal(keys[1], (B, E)).astype(jnp.bfloat16)
+    w = jax.random.normal(keys[2], (K, E))
+    slots, inverse, dead = _scrambled(B, seed=K + B)
+    live = jnp.asarray(~dead)
+    # by row, through the slots: the step's convolution before it walked
+    # the plane in slot order
+    held = conv[1, slots]
+    window = jnp.concatenate([held.reshape(B, K - 1, E), raw[:, None]], 1)
+    y_row = (w * window.astype(jnp.float32)).sum(1)
+    after = jnp.where(live[:, None], window[:, 1:].reshape(B, -1), held)
+    args = (conv, 1, raw[inverse], live[inverse], w)
+    before = dict(profiler.counters())
+    y_j, c_j = selective_scan.ssm_conv_step(*args)
+    y_p, c_p = jax.jit(functools.partial(
+        selective_scan.ssm_conv_step, force_pallas=True),
+        static_argnums=(1,))(*args)
+    assert profiler.counters()["ssm_conv_jnp"] \
+        == before.get("ssm_conv_jnp", 0) + 1
+    assert profiler.counters()["ssm_conv_pallas"] \
+        == before.get("ssm_conv_pallas", 0) + 1
+    for y, c_ in ((y_j, c_j), (y_p, c_p)):
+        assert y.dtype == jnp.float32 and c_.dtype == jnp.bfloat16
+        assert np.abs(np.asarray(y[slots] - y_row)).max() < 1e-6 * float(
+            np.abs(y_row).max())
+        assert bool((c_[1, slots] == after).all())         # bit for bit
+        assert bool((c_[0] == conv[0]).all())              # another layer
+        for row, gone in zip(slots, dead):
+            assert bool((c_[1, row] == conv[1, row]).all()) == bool(gone)
+    live_slots = slots[~dead]
+    assert bool((c_p[1, live_slots, (K - 2) * E:] == raw[~dead]).all())
+    assert bool((c_p[1, live_slots, :(K - 2) * E]
+                 == conv[1, live_slots, E:]).all())
 
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
@@ -121,8 +200,7 @@ def test_a_chunk_is_its_steps_from_the_rows_state(use_pallas, n):
     state = h0[None, None]
     for t in range(n):
         yt, state = selective_scan.ssm_step(
-            state, 0, jnp.zeros((1,), jnp.int32),
-            *(x[t:t + 1] for x in (u, delta, b, c)), a)
+            state, 0, *(x[t:t + 1] for x in (u, delta, b, c)), a)
         assert np.abs(np.asarray(yt[0] - y[t])).max() < 1e-5 * float(
             np.abs(y).max()), t
     assert np.abs(np.asarray(S - state[0, 0])).max() < 1e-5 * float(
@@ -170,19 +248,22 @@ def _pool(model, n_pages, page_size=16, window=4):
 
 
 @functools.lru_cache(maxsize=None)
-def _steps(model):
+def _steps(model, by_row=False):
     """``(the plain step, the MIXED step)`` through the layout's own
     ``attend``, row state and writes, as ``DecodeServer``'s two state
     step programs run them (the mixed one with the logits of every lane
-    kept), jitted once a model."""
+    kept), jitted once a model; ``by_row``: through
+    :func:`_row_order_decode` in the model's place."""
+    decode = functools.partial(_row_order_decode, model) if by_row \
+        else model.decode
+
     @jax.jit
     def plain(params, pools, toks, poss, pts, order, n_live):
         layout = kvcache.layout_for(model, pools)
         attend = layout.attend(pools, pts, poss)
         state = layout.row_state(pools, order,
                                  jnp.arange(len(order)) < n_live)
-        logits, k, v, *held = model.decode(params, toks, poss, attend,
-                                           state)
+        logits, k, v, *held = decode(params, toks, poss, attend, state)
         return logits, (*layout.write_tokens(pools, pts, poss, [k, v],
                                              model.use_pallas), *held)
 
@@ -194,7 +275,7 @@ def _steps(model):
         attend = layout.attend_chunk(pools, pts, poss, table, start)
         state = layout.row_state(pools, order, jnp.arange(rows) < n_live)
         lanes = jnp.arange(C, dtype=jnp.int32)
-        logits, k, v, *held = model.decode(
+        logits, k, v, *held = decode(
             params, jnp.concatenate([toks, fed]),
             jnp.concatenate([poss, start + lanes]), attend, state,
             head=jnp.arange(rows + C),
@@ -400,6 +481,112 @@ def test_a_dead_row_and_a_position_past_the_length_leave_state_untouched():
     for a, b in zip(*states):
         assert np.abs(np.asarray(a - b, np.float32)).max() \
             < 1e-5 * float(np.abs(np.asarray(a, np.float32)).max())
+
+
+# ---------------------------------------------------------------------------
+# the order a step runs in: slots, not rows
+# ---------------------------------------------------------------------------
+
+def _row_order_decode(model, p, tokens, positions, attend, state, head=None,
+                      live=None, chunk=None):
+    """``SSMHybridDecoderLM.decode`` as it ran before the mixers walked
+    the state in slot order: every lane in the STEP's order, a layer's
+    rows of ``conv`` and of ``h`` gathered through ``state.slots`` and
+    scattered back, no kernel — the oracle of the change of order."""
+    del positions
+    h_all, conv_all = state.arrays
+    slots, B = state.slots, state.slots.shape[0]
+    K, E = model.conv, model.d_inner
+    live = state.live if live is None else live
+    h = p["embed"][tokens].astype(jnp.float32)
+    ks, vs = [], []
+    for i, attends in enumerate(model.kinds):
+        l = "l%d." % i
+        x = model._rms(h, p[l + "mix_g"])
+        if attends:
+            q, k, v = model._qkv(i, x, p)
+            a = attend(model.cache_layer(i), q, k, v, scale=model.scale,
+                       force_pallas=False)
+            h = h + model._attn_out(i, a, p)
+            ks.append(k)
+            vs.append(v)
+        else:
+            j = model.state_layer(i)
+            raw, z = model._split_in(i, x, p)
+            before = conv_all[j, slots]
+            window = jnp.concatenate(
+                [before.reshape(B, K - 1, E), raw[:B, None]], axis=1)
+            y = (p[l + "conv_w"] * window.astype(jnp.float32)).sum(1)
+            conv_all = conv_all.at[j, slots].set(jnp.where(
+                state.live[:, None], window[:, 1:].reshape(B, -1), before))
+            if chunk is not None:
+                tail, rows = model._chunk_conv(
+                    raw[B:], p[l + "conv_w"], conv_all[j, chunk[0]], chunk)
+                y = jnp.concatenate([y, tail])
+                conv_all = conv_all.at[j, chunk[0]].set(rows)
+            u, delta, b, c = model._scan_inputs(i, y, p, live)
+            a = -jnp.exp(p[l + "A_log"])
+            S = jnp.exp(delta[:B, None, :] * a) * h_all[j, slots] \
+                + b[:B, :, None] * (delta[:B] * u[:B])[:, None, :]
+            y = jnp.sum(S * c[:B, :, None], axis=1)
+            h_all = h_all.at[j, slots].set(S)
+            if chunk is not None:
+                tail, h_all = model._chunk_scan(
+                    j, (u[B:], delta[B:], b[B:], c[B:], a), h_all, chunk)
+                y = jnp.concatenate([y, tail])
+            h = h + model._mix_out(i, y, u, z, p)
+        h = h + model._mlp(i, h, p)
+    if head is not None:
+        h = h[head]
+    return (model._logits(h, p), jnp.stack(ks), jnp.stack(vs), h_all,
+            conv_all)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("lanes", [0, 16], ids=["plain", "mixed-c16"])
+def test_a_step_in_slot_order_is_the_step_by_row(use_pallas, lanes):
+    """One step of a window of 16 whose rows sit on SCRAMBLED slots, 11
+    of them live, over a last tenant's state in every slot — plain, and
+    MIXED with a chunk of 16 lanes (13 live, from position 32: the
+    convolution's first inputs and the scan's start are the request's
+    row, which is a dead row's slot) — through ``decode``, which brings
+    the rows' lanes into slot order once and goes back around ``attend``
+    and for the head, against the same step run by row
+    (:func:`_row_order_decode`): the logits of every lane, the keys and
+    values as the pages hold them, ``h`` and ``conv`` whole — no other
+    slot moved, none took another's."""
+    model, params, _ = _model(use_pallas=use_pallas)
+    W, S, n_live, per = 16, 16, 11, 4
+    rng = np.random.default_rng(11)
+    pools = _tenants(tuple(_pool(model, W * per, S, W).arrays))
+    order = rng.permutation(W).astype(np.int32)
+    toks = rng.integers(0, model.vocab, size=W).astype(np.int32)
+    poss = np.where(np.arange(W) < n_live, rng.integers(3, 40, size=W),
+                    0).astype(np.int32)
+    pts = (1 + np.arange(W * per, dtype=np.int32)).reshape(W, per)
+    pts[n_live:] = 0
+    args = (params, pools, toks, poss, pts, order, n_live)
+    if lanes:
+        fed = rng.integers(0, model.vocab, size=lanes).astype(np.int32)
+        # the request's row: the slot of a row that is not live, its
+        # pages that row's
+        args += (fed, np.arange(W * per - per + 1, W * per + 1,
+                                dtype=np.int32), 32, 13,
+                 int(order[n_live + 2]))
+    got, want = (_steps(model, by_row)[bool(lanes)](*args)
+                 for by_row in (False, True))
+    std = float(np.asarray(want[0]).std())
+    assert np.abs(np.asarray(got[0] - want[0])).max() / std \
+        < LOGIT_TOLERANCE / 10
+    for name, a, b in zip(("k", "v", "h", "conv"), got[1], want[1]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() < 1e-5 * np.abs(b).max(), name
+    # the step moved the live rows' slots (and the request's) and no other
+    moved = set(order[:n_live].tolist()) | ({args[-1]} if lanes else set())
+    for before, after in zip(pools[2:], got[1][2:]):
+        for slot in range(W):
+            same = bool((before[:, slot] == after[:, slot]).all())
+            assert same == (slot not in moved), slot
 
 
 # ---------------------------------------------------------------------------
